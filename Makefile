@@ -4,7 +4,7 @@
 
 SHELL := /bin/bash
 
-.PHONY: tier1 tier1-verify tier1-multislice tier1-ckpt tier1-data tier1-sched tier1-optim tier1-quant tier1-analysis tier1-serve tier1-spec tier1-route tier1-conc tier1-disagg tier1-kvtier tier1-aot tier1-qos tier1-elastic tier1-publish tier1-slow quick test lint
+.PHONY: tier1 tier1-verify tier1-multislice tier1-ckpt tier1-data tier1-sched tier1-optim tier1-quant tier1-analysis tier1-serve tier1-spec tier1-route tier1-conc tier1-disagg tier1-kvtier tier1-aot tier1-qos tier1-elastic tier1-publish tier1-smoke tier1-e2e tier1-slow quick test lint
 
 # THE gate: the verbatim ROADMAP command, then the explicit multislice leg
 # (hierarchical ICI/DCN + ZeRO-3 paths on the simulated 2-slice mesh), the
@@ -15,7 +15,7 @@ SHELL := /bin/bash
 # regression there fails the make target by name, not just as one more
 # dot. Legs run SEQUENTIALLY (the no-concurrent-pytest rule: e2e timing
 # tests flake under CPU contention).
-tier1: tier1-verify tier1-multislice tier1-ckpt tier1-data tier1-sched tier1-optim tier1-quant tier1-analysis tier1-serve tier1-spec tier1-route tier1-conc tier1-disagg tier1-kvtier tier1-aot tier1-qos tier1-elastic tier1-publish
+tier1: tier1-verify tier1-multislice tier1-ckpt tier1-data tier1-sched tier1-optim tier1-quant tier1-analysis tier1-serve tier1-spec tier1-route tier1-conc tier1-disagg tier1-kvtier tier1-aot tier1-qos tier1-elastic tier1-publish tier1-smoke tier1-e2e
 
 # Exact ROADMAP.md "Tier-1 verify" command, verbatim.
 tier1-verify:
@@ -182,6 +182,30 @@ tier1-elastic:
 # full gate (slow included).
 tier1-publish:
 	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m publish -p no:cacheprovider -p no:xdist -p no:randomly
+
+# chip_smoke.py marker leg — run it BEFORE EVERY CHIP CALL. The cheap
+# part (also inside tier1-verify's selection): the smoke's parent and the
+# control plane never import jax, the compile-cache helper's fixed path,
+# the platform pin in a chip-granted task's env and the task dying
+# without its chip. The slow part is rehearsal 1+2 of the
+# on-chip-measurement guide: the whole `python chip_smoke.py --rehearse`
+# flow at llama-tiny size on the CPU — tony submit -> checkpoint -> tony
+# serve -> generate RPCs -> tony kill -> both check children, then
+# `--chips 4` on four virtual devices — which must go through every
+# phase, exit non-zero and never print `"ok": true`. The chip run
+# itself: `chiprun -- python chip_smoke.py` (`--chips 4` with
+# `chiprun --chips 4`).
+tier1-smoke:
+	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m smoke -p no:cacheprovider -p no:xdist -p no:randomly
+
+# Whole-fleet e2e leg: everything in the e2e tier (real AM, executor and
+# user processes through the MiniPod and the client), slow included — the
+# multi-process jax.distributed expert-parallel and pipeline-parallel
+# trainings are slow-marked to keep tier1-verify inside its 870 s (the dp
+# gang, the simplest of the family, stays in the gate). One pytest at a
+# time: these are CPU-greedy.
+tier1-e2e:
+	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m e2e -p no:cacheprovider -p no:xdist -p no:randomly
 
 # Source lints, machine-checked: (1) the jnp.concatenate/stack pack-site
 # lint (the jax-0.4 GSPMD concat-reshard footgun) — every call site

@@ -230,7 +230,7 @@ class TestDtypePolicy:
         assert not rules.dtype_findings(closed)
 
     def test_f64_promotion_fires(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(lambda x: x * 2.0)(
                 np.ones((4,), np.float64))
         hits = [f for f in rules.dtype_findings(closed)

@@ -10,20 +10,6 @@ from pathlib import Path
 
 import pytest
 
-
-# jaxlib's CPU client gained cross-process collectives after the 0.4 line;
-# on older wheels any multi-process GSPMD computation aborts with
-# "Multiprocess computations aren't implemented on the CPU backend", so the
-# jax-distributed e2e milestones cannot execute regardless of TonY's own
-# correctness (the control-plane path they ride is covered by the
-# standalone/tf/pytorch e2e tests). Version gate, not a runtime probe: the
-# probe would itself need a second process and a jax import.
-import jax as _jax
-
-needs_cpu_multiprocess = pytest.mark.skipif(
-    _jax.__version_info__ < (0, 5),
-    reason="jaxlib CPU backend lacks multi-process computations")
-
 from tony_tpu import constants
 from tony_tpu.minipod import MiniPod
 from tony_tpu.session import JobStatus, TaskStatus
@@ -336,7 +322,6 @@ def test_custom_credential_provider_e2e(pod, tmp_path, monkeypatch):
     assert int(creds.get("renewals", "0")) >= 1   # refresh hook fired
 
 
-@needs_cpu_multiprocess
 def test_jax_distributed_dp_training(pod):
     """The SURVEY.md §7 step-5 milestone: `--framework=jax` runs 2-process
     data-parallel training where jax.distributed rendezvous comes from the
@@ -360,7 +345,7 @@ def test_jax_distributed_dp_training(pod):
     assert data["losses"][-1] < data["losses"][0]
 
 
-@needs_cpu_multiprocess
+@pytest.mark.slow      # whole-fleet e2e: `make tier1-e2e` runs it
 def test_jax_distributed_expert_parallel_training(pod):
     """Expert parallelism across processes: 2 executors form one ep=2 mesh;
     the MoE dispatch all_to_all crosses the process boundary and the aux
@@ -383,7 +368,7 @@ def test_jax_distributed_expert_parallel_training(pod):
     assert all(a > 0 for a in data["aux"])
 
 
-@needs_cpu_multiprocess
+@pytest.mark.slow      # whole-fleet e2e: `make tier1-e2e` runs it
 def test_jax_distributed_pipeline_parallel_training(pod):
     """Pipeline parallelism across processes: 2 executors form one pp=2
     mesh; the GPipe ppermute ring crosses the process boundary."""
@@ -496,7 +481,6 @@ def test_pytorch_ddp_example_e2e(pod):
     assert data["world_size"] == 2
 
 
-@needs_cpu_multiprocess
 def test_horovod_on_ici_psum_e2e(pod):
     """Graduation config ④: HOROVOD_* contract + XLA cross-process reduce
     as the NCCL→ICI replacement, 2 live processes."""
@@ -726,7 +710,6 @@ def test_tpuvm_concurrent_gang_stages_each_host_once(tpuvm):
     assert all(v == 2 for v in per_host.values()), per_host  # conf + src
 
 
-@needs_cpu_multiprocess
 def test_tpuvm_jax_distributed_dp_training(tpuvm):
     """VERDICT r3 #4: the closest this environment gets to the v4-32 story —
     two 'hosts' behind the SSH substrate run REAL jax.distributed DP

@@ -361,9 +361,10 @@ class TestZero3:
 
     def test_accum_step_fused_matches_optax_path(self):
         """make_accum_train_step(update='fused_bucket') vs the optax
-        path: same microbatched reduce, so the whole 2-step trajectory is
-        bit-exact in f32 — reduce→update never leaving the bucket domain
-        changes nothing numerically."""
+        path: same microbatched reduce, so the loss is equal and the
+        2-step parameter trajectory agrees to 1e-7 absolute — reduce→update
+        never leaving the bucket domain changes nothing beyond f32
+        reassociation."""
         mesh = par.make_mesh(fsdp=4)
         model = get_model("mnist-mlp", hidden=32)
         kx, ky, kr = jax.random.split(jax.random.PRNGKey(1), 3)
@@ -389,7 +390,15 @@ class TestZero3:
         assert float(mf["loss"]) == float(mo["loss"])
         assert float(mf["grad_norm"]) == pytest.approx(
             float(mo["grad_norm"]), rel=1e-6)
-        assert _tree_leaves_bitexact(sf.params, so.params)
+        # Two different programs (bucket kernel vs per-leaf optax), so a
+        # tolerance, not bits: XLA-CPU contracts their f32 chains
+        # differently (measured max |diff| 7.5e-9 after 2 steps on jax
+        # 0.9.0). atol 1e-7 is 1e-4 of ONE AdamW step at lr 1e-3 — a
+        # wrong moment, bias correction or decay term is >= 1e-5.
+        for a, b in zip(jax.tree.leaves(sf.params),
+                        jax.tree.leaves(so.params)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=0, atol=1e-7)
         assert int(sf.opt_state["count"]) == 2 and int(sf.step) == 2
         rec = profiler.update_report()["accum_update"]
         assert rec["rule"] == "adamw" and rec["impl"] in ("pallas", "xla")

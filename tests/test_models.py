@@ -8,7 +8,6 @@ import optax
 import pytest
 
 from tony_tpu import parallel as par
-from tony_tpu.compat import mesh_context
 from tony_tpu import train
 from tony_tpu.models import get_model
 from tony_tpu.models.resnet import resnet50_flops
@@ -100,7 +99,7 @@ def test_ring_equals_reference_attention_in_model():
     variables = nn.unbox(ref_model.init(jax.random.PRNGKey(0), tokens))
     with nn.logical_axis_rules(par.RULES):
         ref_out = ref_model.apply(variables, tokens)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             ring_out = jax.jit(ring_model.apply)(variables, tokens)
     np.testing.assert_allclose(np.asarray(ref_out), np.asarray(ring_out),
                                atol=2e-4, rtol=2e-4)
@@ -122,7 +121,7 @@ def test_resnet_dp_train_step_on_mesh():
             x, train=True, mutable=["batch_stats"])
         return train.cross_entropy_loss(logits, y), updates["batch_stats"]
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         (loss, _), grads = jax.jit(
             jax.value_and_grad(loss_fn, has_aux=True))(
             variables["params"], variables["batch_stats"])

@@ -15,9 +15,10 @@ from tony_tpu import profiler, train
 from tony_tpu.models import get_model
 from tony_tpu.parallel import overlap
 from tony_tpu.parallel.overlap import (DEFAULT_BUCKET_BYTES, GradBuckets,
-                                       MULTISLICE_XLA_FLAGS,
-                                       OVERLAP_XLA_FLAGS, microbatch_grads,
-                                       overlap_xla_flags)
+                                       microbatch_grads)
+from tony_tpu.runtime.jax_runtime import (MULTISLICE_XLA_FLAGS,
+                                          OVERLAP_XLA_FLAGS,
+                                          overlap_xla_flags)
 
 
 def _tree():
@@ -407,7 +408,7 @@ class TestUnevenZero3:
 
     def test_microbatch_grads_match_full_batch(self, caplog):
         """Numerics pin: uneven ZeRO-3 grads (padded scatter + tail
-        gather/unpad) match plain full-batch jax.grad within 1e-5; even
+        gather/unpad) match plain full-batch jax.grad within 1e-6 relative; even
         leaves still exit in the shard layout, uneven ones whole — and
         the lost per-leaf memory saving is warned about loudly."""
         params, specs = self._tree_specs()
@@ -422,12 +423,15 @@ class TestUnevenZero3:
             return jnp.mean((out[:, :6] - mb["y"]) ** 2)
 
         profiler.reset_overlap_records()
-        loss, grads = microbatch_grads(
-            loss_fn, params, batch, mesh, microbatches=4,
-            bucket_bytes=1 << 20, param_specs=specs)
-        ref_loss, ref = jax.value_and_grad(
-            lambda p: loss_fn(p, batch))(params)
-        assert abs(float(loss) - float(ref_loss)) < 1e-5
+        loss, grads = jax.jit(lambda p, b: microbatch_grads(
+            loss_fn, p, b, mesh, microbatches=4,
+            bucket_bytes=1 << 20, param_specs=specs))(params, batch)
+        ref_loss, ref = jax.jit(jax.value_and_grad(
+            lambda p: loss_fn(p, batch)))(params)
+        # Loss runs ~6e2 (one f32 ulp there is 6.1e-5): microbatch-sum
+        # reassociation is worth a few ulp, so 1e-6 RELATIVE.
+        assert abs(float(loss) - float(ref_loss)) \
+            < 1e-6 * max(1.0, abs(float(ref_loss)))
         assert grads["v"].shape == (6, 16)          # whole, unpadded
         assert "fsdp" in str(grads["w"].sharding.spec)
         # Grad magnitudes run ~5e2 here: 1e-4 abs ≈ 2e-7 relative.
@@ -457,11 +461,11 @@ class TestUnevenZero3:
                              + jnp.diag(p["b"]))
             return jnp.mean((out[:, :6] - mb["y"]) ** 2)
 
-        loss, grads = microbatch_grads(
-            loss_fn, params, batch, mesh, microbatches=2,
-            bucket_bytes=1 << 20, param_specs=specs)
-        ref_loss, ref = jax.value_and_grad(
-            lambda p: loss_fn(p, batch))(params)
+        loss, grads = jax.jit(lambda p, b: microbatch_grads(
+            loss_fn, p, b, mesh, microbatches=2,
+            bucket_bytes=1 << 20, param_specs=specs))(params, batch)
+        ref_loss, ref = jax.jit(jax.value_and_grad(
+            lambda p: loss_fn(p, batch)))(params)
         assert abs(float(loss) - float(ref_loss)) < 1e-5
         # Grad magnitudes run ~5e2 here: 1e-4 abs ≈ 2e-7 relative.
         np.testing.assert_allclose(np.asarray(grads["v"]),

@@ -82,7 +82,7 @@ def test_ring_attention_grad_flows():
     def loss(q):
         return par.ring_attention_sharded(q, q, q, mesh).sum()
 
-    g = jax.grad(loss)(q)
+    g = jax.jit(jax.grad(loss))(q)   # jit: one compile, not one per op
     assert g.shape == q.shape
     assert bool(jnp.isfinite(g).all())
 
@@ -115,7 +115,7 @@ def test_ring_attention_gqa_grads_flow():
     def loss(q, k, v):
         return par.ring_attention_sharded(q, k, v, mesh).sum()
 
-    gq, gk, gv = jax.grad(loss, (0, 1, 2))(q, k, v)
+    gq, gk, gv = jax.jit(jax.grad(loss, (0, 1, 2)))(q, k, v)
     assert gq.shape == q.shape and gk.shape == k.shape and gv.shape == v.shape
     for g in (gq, gk, gv):
         assert bool(jnp.isfinite(g).all())

@@ -296,12 +296,12 @@ class TestQuantGather:
         _, gplan = overlap.step_plans(params, mesh, bucket_bytes=256,
                                       param_specs=specs)
         assert gplan.n_gather_buckets == 0
-        l0, g0 = overlap.microbatch_grads(
-            loss, params, batch, mesh, microbatches=2, bucket_bytes=256,
-            param_specs=specs)
-        l1, g1, hist = overlap.microbatch_grads(
-            loss, params, batch, mesh, microbatches=2, bucket_bytes=256,
-            param_specs=specs, quant_amax=[])
+        l0, g0 = jax.jit(lambda p, b: overlap.microbatch_grads(
+            loss, p, b, mesh, microbatches=2, bucket_bytes=256,
+            param_specs=specs))(params, batch)
+        l1, g1, hist = jax.jit(lambda p, b: overlap.microbatch_grads(
+            loss, p, b, mesh, microbatches=2, bucket_bytes=256,
+            param_specs=specs, quant_amax=[]))(params, batch)
         assert hist == []
         assert _bitexact(l0, l1)
         for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):

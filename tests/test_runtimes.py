@@ -80,7 +80,7 @@ def test_jax_chip_pinning():
     env = get_framework("jax").task_adapter().build_task_env(
         ctx_for("jax", "worker", 2, conf_extra={"tony.worker.tpus": "2"}))
     # worker:2 is the second task on h1 -> local_rank 1 -> chips 2,3
-    assert env[constants.ENV_TPU_VISIBLE_DEVICES] == "2,3"
+    assert env[constants.ENV_TPU_VISIBLE_CHIPS] == "2,3"
 
 
 def test_jax_host_subdivision_contract():
@@ -103,7 +103,7 @@ def test_jax_host_subdivision_contract():
         "h0:8476,h0:8477,h1:8478,h1:8479"
     assert env[constants.ENV_TPU_PROCESS_PORT] == "8479"    # base + rank 3
     assert env[constants.ENV_CLOUD_TPU_TASK_ID] == "3"
-    assert env[constants.ENV_TPU_VISIBLE_DEVICES] == "2,3"
+    assert env[constants.ENV_TPU_VISIBLE_CHIPS] == "2,3"
 
 
 def test_jax_subdivision_env_absent_when_not_subdividing():
@@ -140,7 +140,7 @@ def test_jax_mixed_tpus_cohort_gets_pinning_but_no_bounds():
     conf_extra = {"tony.chief.tpus": "4", "tony.worker.tpus": "2"}
     env = get_framework("jax").task_adapter().build_task_env(
         ctx_for("jax", "worker", 0, conf_extra=conf_extra))
-    assert env[constants.ENV_TPU_VISIBLE_DEVICES] == "4,5"
+    assert env[constants.ENV_TPU_VISIBLE_CHIPS] == "4,5"
     assert constants.ENV_TPU_PROCESS_BOUNDS not in env
 
 
@@ -151,18 +151,16 @@ def test_jax_injects_overlap_xla_flags_for_tpu_tasks():
         ctx_for("jax", "worker", 0,
                 conf_extra={"tony.worker.tpus": "2"}))
     assert "--xla_tpu_enable_latency_hiding_scheduler=true" \
-        in env[constants.ENV_XLA_FLAGS]
+        in env[constants.ENV_LIBTPU_INIT_ARGS]
     assert "--xla_tpu_enable_async_collective_fusion=true" \
-        in env[constants.ENV_XLA_FLAGS]
+        in env[constants.ENV_LIBTPU_INIT_ARGS]
 
 
 def test_jax_no_overlap_flags_without_tpus():
-    """Non-TPU tasks must NOT get the xla_tpu_* set: XLA aborts the
-    process on flags its build doesn't know (measured on the CPU wheel),
-    so default-injecting would kill every CPU-backend job."""
+    """Non-TPU tasks get no TPU compiler flags."""
     env = get_framework("jax").task_adapter().build_task_env(
         ctx_for("jax", "worker", 0))
-    assert constants.ENV_XLA_FLAGS not in env
+    assert constants.ENV_LIBTPU_INIT_ARGS not in env
 
 
 def test_jax_overlap_flags_forced_on_by_conf():
@@ -172,7 +170,7 @@ def test_jax_overlap_flags_forced_on_by_conf():
         ctx_for("jax", "worker", 0,
                 conf_extra={"tony.jax.overlap-xla-flags": "true"}))
     assert "--xla_tpu_enable_latency_hiding_scheduler=true" \
-        in env[constants.ENV_XLA_FLAGS]
+        in env[constants.ENV_LIBTPU_INIT_ARGS]
 
 
 def test_jax_overlap_flags_user_value_wins():
@@ -182,9 +180,9 @@ def test_jax_overlap_flags_user_value_wins():
         ctx_for("jax", "worker", 0, conf_extra={
             "tony.worker.tpus": "2",
             "tony.worker.env":
-                "XLA_FLAGS=--xla_tpu_enable_latency_hiding_scheduler"
+                "LIBTPU_INIT_ARGS=--xla_tpu_enable_latency_hiding_scheduler"
                 "=false"}))
-    flags = env[constants.ENV_XLA_FLAGS]
+    flags = env[constants.ENV_LIBTPU_INIT_ARGS]
     assert "--xla_tpu_enable_latency_hiding_scheduler=false" in flags
     assert "--xla_tpu_enable_latency_hiding_scheduler=true" not in flags
     assert "--xla_tpu_overlap_compute_collective_tc=true" in flags
@@ -195,7 +193,7 @@ def test_jax_overlap_flags_conf_gated_off():
         ctx_for("jax", "worker", 0,
                 conf_extra={"tony.worker.tpus": "2",
                             "tony.jax.overlap-xla-flags": "false"}))
-    assert constants.ENV_XLA_FLAGS not in env
+    assert constants.ENV_LIBTPU_INIT_ARGS not in env
 
 
 def test_jax_ckpt_env_exported_from_conf():
@@ -244,7 +242,7 @@ def test_jax_sidecar_gets_no_overlap_flags():
     env = get_framework("jax").task_adapter().build_task_env(
         ctx_for("jax", "tensorboard", 0, spec=spec,
                 conf_extra={"tony.tensorboard.instances": "1"}))
-    assert constants.ENV_XLA_FLAGS not in env
+    assert constants.ENV_LIBTPU_INIT_ARGS not in env
 
 
 def test_jax_rejects_ps():
@@ -287,13 +285,13 @@ def test_jax_multislice_adds_dcn_xla_flags():
                 conf_extra={"tony.worker.tpus": "2",
                             "tony.jax.slices": "2"}))
     assert "--xla_tpu_data_parallel_opt_different_sized_ops=true" \
-        in multi[constants.ENV_XLA_FLAGS]
+        in multi[constants.ENV_LIBTPU_INIT_ARGS]
     assert "--xla_tpu_enable_latency_hiding_scheduler=true" \
-        in multi[constants.ENV_XLA_FLAGS]
+        in multi[constants.ENV_LIBTPU_INIT_ARGS]
     single = get_framework("jax").task_adapter().build_task_env(
         ctx_for("jax", "worker", 0, conf_extra={"tony.worker.tpus": "2"}))
     assert "--xla_tpu_data_parallel_opt_different_sized_ops" \
-        not in single[constants.ENV_XLA_FLAGS]
+        not in single[constants.ENV_LIBTPU_INIT_ARGS]
 
 
 def test_jax_slices_must_divide_world():
@@ -422,10 +420,10 @@ def test_jax_chip_pinning_mixed_tpus():
     conf_extra = {"tony.chief.tpus": "4", "tony.worker.tpus": "2"}
     env = get_framework("jax").task_adapter().build_task_env(
         ctx_for("jax", "worker", 0, conf_extra=conf_extra))
-    assert env[constants.ENV_TPU_VISIBLE_DEVICES] == "4,5"
+    assert env[constants.ENV_TPU_VISIBLE_CHIPS] == "4,5"
     env = get_framework("jax").task_adapter().build_task_env(
         ctx_for("jax", "chief", 0, conf_extra=conf_extra))
-    assert env[constants.ENV_TPU_VISIBLE_DEVICES] == "0,1,2,3"
+    assert env[constants.ENV_TPU_VISIBLE_CHIPS] == "0,1,2,3"
 
 
 def test_global_rank_out_of_range_raises():

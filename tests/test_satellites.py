@@ -5,6 +5,8 @@ discovery analogue)."""
 import io
 from pathlib import Path
 
+import pytest
+
 from tony_tpu import conf as conf_mod
 from tony_tpu.azkaban import job_file_conf, parse_job_file
 from tony_tpu.cli import main as cli_main
@@ -62,11 +64,34 @@ def test_azkaban_cli_submits_end_to_end(tmp_path):
 
 def test_discovery_env_paths():
     assert _chips_from_env({"TPU_CHIPS_PER_HOST_BOUNDS": "2,2,1"}) == 4
-    assert _chips_from_env({"TPU_VISIBLE_DEVICES": "0,1,2"}) == 3
+    assert _chips_from_env({"TPU_VISIBLE_CHIPS": "0,1,2"}) == 3
     assert _chips_from_env({}) is None
     topo = discover_tpus()
     assert isinstance(topo, TpuTopology)
     assert topo.num_chips >= 0
+
+
+def test_discovery_devfs_counts_chips_only(tmp_path):
+    """What the one-chip v5e machine exposes: /dev/vfio/0 (the chip's
+    IOMMU group) beside the ``vfio`` control node; newer kernels add a
+    ``devices`` directory. Only numbered nodes are chips."""
+    from tony_tpu.discovery import _chips_from_devfs
+
+    assert _chips_from_devfs(str(tmp_path)) is None
+    (tmp_path / "vfio" / "devices").mkdir(parents=True)
+    (tmp_path / "vfio" / "vfio").touch()
+    assert _chips_from_devfs(str(tmp_path)) is None
+    (tmp_path / "vfio" / "0").touch()
+    assert _chips_from_devfs(str(tmp_path)) == 1
+    for n in (1, 2, 3):
+        (tmp_path / "vfio" / str(n)).touch()
+    assert _chips_from_devfs(str(tmp_path)) == 4
+    (tmp_path / "accel0").touch()
+    (tmp_path / "accel_ctl").touch()
+    assert _chips_from_devfs(str(tmp_path)) == 1
+    # The narrower env wins over the host bounds.
+    assert _chips_from_env({"TPU_CHIPS_PER_HOST_BOUNDS": "2,2,1",
+                            "TPU_VISIBLE_CHIPS": "0"}) == 1
 
 
 def test_am_rejects_tpu_ask_on_chipless_host(tmp_path, monkeypatch):
@@ -77,7 +102,7 @@ def test_am_rejects_tpu_ask_on_chipless_host(tmp_path, monkeypatch):
     from tony_tpu.conf import TonyConfig
     import tony_tpu.discovery as disc
     monkeypatch.setattr(disc, "discover_tpus",
-                        lambda use_jax=False: disc.TpuTopology(0, "none"))
+                        lambda: disc.TpuTopology(0, "none"))
     props = {"tony.worker.instances": "1", "tony.worker.tpus": "4",
              "tony.application.framework": "standalone"}
     with pytest.raises(ValueError, match="no TPU chips"):
@@ -233,15 +258,14 @@ def test_docker_wrap_command_unit():
             TonyConfig({"tony.docker.enabled": "true"}), argv)
 
 
-def test_remote_interpreter_site_flag_gated_on_pythonpath():
-    """-S (the sitecustomize latency cut) is legal remotely ONLY when
-    tony_tpu arrives via remote_pythonpath; a pip-installed remote needs
-    the site import to find tony_tpu at all."""
+@pytest.mark.parametrize("remote_pythonpath", ["/opt/tony", None])
+def test_remote_interpreter_runs_with_site(remote_pythonpath):
+    """Executors start as plain ``python -m`` (site import on), whether
+    tony_tpu arrives via remote_pythonpath or is pip-installed remotely:
+    a pip-installed remote needs the site import to find tony_tpu at
+    all, and skipping it saves nothing measurable."""
     launch = ContainerLaunch(job_type="w", index=0, env={})
-    with_pp = TpuVmScheduler(hosts=["a"], remote_workdir="/tmp/tt",
-                             remote_pythonpath="/opt/tony")
-    assert "-S -m tony_tpu.executor" in with_pp.build_remote_command(
-        launch, "a")[2]
-    without_pp = TpuVmScheduler(hosts=["a"], remote_workdir="/tmp/tt")
-    remote = without_pp.build_remote_command(launch, "a")[2]
-    assert "-S" not in remote and "-m tony_tpu.executor" in remote
+    sched = TpuVmScheduler(hosts=["a"], remote_workdir="/tmp/tt",
+                           remote_pythonpath=remote_pythonpath)
+    remote = sched.build_remote_command(launch, "a")[2]
+    assert " -S" not in remote and "-m tony_tpu.executor" in remote

@@ -73,7 +73,7 @@ class ApplicationMaster:
                 total_tpus = conf.get_int(conf_mod.SCHEDULER_TOTAL_TPUS, 0)
                 if total_tpus <= 0:
                     from tony_tpu.discovery import discover_tpus
-                    total_tpus = discover_tpus(use_jax=True).num_chips
+                    total_tpus = discover_tpus().num_chips
                 if total_tpus <= 0:
                     # 0 would mean "unlimited" to the scheduler — the
                     # opposite of what an unsatisfiable ask deserves.

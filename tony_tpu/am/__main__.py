@@ -6,10 +6,6 @@ import argparse
 import signal
 import sys
 
-from tony_tpu.util import restore_site_dirs
-
-restore_site_dirs()   # -S entry: see tony_tpu.util.ENV_SITE_DIRS
-
 from tony_tpu.am import ApplicationMaster
 from tony_tpu.conf import TonyConfig
 

@@ -17,10 +17,10 @@ from dataclasses import dataclass
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
-# The manual-collective primitive names on the jax 0.4.x line
-# (``jax.lax.psum_scatter`` binds the ``reduce_scatter`` primitive).
+# The manual-collective primitive names (``jax.lax.psum_scatter`` binds
+# the ``reduce_scatter`` primitive).
 COLLECTIVE_PRIMS: Tuple[str, ...] = (
     "psum", "all_gather", "reduce_scatter", "all_to_all", "ppermute",
     "pmax", "pmin", "pgather")

@@ -34,7 +34,7 @@ byte returns ``None``. The cache may cost a recompile, never a wrong
 program.
 
 Jax-free at import by the ckpt package's layering rule (the fingerprint
-helpers and the serialize/deserialize shims import lazily): the AM can
+helpers and the serialize/deserialize calls import lazily): the AM can
 name a cache dir in a grant without dragging the compute stack in.
 """
 
@@ -189,7 +189,8 @@ class AOTCache:
         """The loaded ``jax.stages.Compiled`` for ``fp``, or ``None``
         (counted miss) on: no entry, format/fingerprint drift, any
         chunk CRC mismatch, a truncated payload, or a backend that
-        cannot deserialize. Never raises, never mutates the store —
+        declines to deserialize (also counted in ``unsupported``).
+        Never mutates the store —
         a poison entry costs a recompile on every consult, not a
         crash (and never a wrong program: the payload only loads
         after the FULL fingerprint matched byte for byte).
@@ -231,9 +232,25 @@ class AOTCache:
                 pickle.UnpicklingError, EOFError):
             self.misses += 1
             return None
-        from tony_tpu.compat import deserialize_compiled
-        compiled = deserialize_compiled(bytes(payload), in_tree, out_tree)
-        if compiled is None:
+        import jax
+        from jax.experimental import serialize_executable as _se
+        # Load for the devices the executable was compiled for, in its
+        # own order: jax's default is every device of the backend, and
+        # a one-device program loaded for eight is called with the
+        # wrong shard count.
+        by_id = {d.id: d for d in jax.devices()}
+        try:
+            devices = [by_id[int(i)] for i in entry["device_ids"]]
+        except KeyError:
+            self.misses += 1
+            return None
+        try:
+            compiled = _se.deserialize_and_load(
+                bytes(payload), in_tree, out_tree,
+                execution_devices=devices)
+        except jax.errors.JaxRuntimeError:
+            # The backend declines to load serialized executables.
+            self.unsupported += 1
             self.misses += 1
             return None
         self.hits += 1
@@ -251,13 +268,20 @@ class AOTCache:
         if final.exists():
             self.put_races += 1
             return False
-        from tony_tpu.compat import serialize_compiled
-        triple = serialize_compiled(compiled)
-        if triple is None:
+        import jax
+        from jax.experimental import serialize_executable as _se
+        try:
+            payload, in_tree, out_tree = _se.serialize(compiled)
+        except (ValueError, NotImplementedError,
+                jax.errors.JaxRuntimeError):
+            # What jax raises for "cannot serialize": no unloaded
+            # executable / closed-over constants or refs / a PJRT
+            # client without executable serialization.
             self.unsupported += 1
             return False
-        payload, in_tree, out_tree = triple
         payload = bytes(payload)
+        device_ids = [int(d.id) for d in
+                      compiled._executable._unloaded_executable.device_list]
         try:
             trees_raw = pickle.dumps((in_tree, out_tree))
         except (pickle.PicklingError, AttributeError, TypeError):
@@ -283,6 +307,7 @@ class AOTCache:
             os.fsync(f.fileno())
         _atomic_write_json(staging / "entry.json", {
             "format": FORMAT, "fingerprint": fp, "chunks": table,
+            "device_ids": device_ids,
             "trees_b64": None if trees_raw is None
             else base64.b64encode(trees_raw).decode("ascii"),
             "trees_crc32": None if trees_raw is None

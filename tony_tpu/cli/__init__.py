@@ -70,7 +70,13 @@ def cmd_serve(args: argparse.Namespace) -> int:
     containers, each restoring the training checkpoint onto its own
     mesh (bf16 dtype policy by default) and running the continuous-
     batching engine behind the control-plane RPC wire. ``--max_replicas``
-    above ``--replicas`` arms the AM's heartbeat-driven autoscaler."""
+    above ``--replicas`` arms the AM's heartbeat-driven autoscaler.
+
+    A replica is told it owns chips the way any task is:
+    ``--conf tony.<jobtype>.tpus=N`` (jobtype ``serve``, or the role
+    names under ``--role``). The scheduler then accounts for the chips
+    and the replica's env pins ``JAX_PLATFORMS=tpu``, so a replica that
+    cannot get its chip dies instead of serving from the CPU."""
     import json as json_mod
     from pathlib import Path
 

@@ -198,18 +198,11 @@ class TonyClient:
         am_log = open(self.job_dir / "am.log", "ab")
         env = dict(os.environ)
         env["PYTHONPATH"] = child_pythonpath(env)
-        from tony_tpu.util import control_plane_site_env
-        env.update(control_plane_site_env())
         # Submit timestamp for the AM's submit→all-RUNNING latency metric.
         self.submit_time = time.time()
         env[constants.ENV_SUBMIT_TS] = repr(self.submit_time)
         self.am_proc = subprocess.Popen(
-            # -S: the AM is stdlib-only; skipping the site import (the ML
-            # stack's sitecustomize costs ~1.8 s) is pure submit→running
-            # latency. Lazy imports still work via TONY_SITE_DIRS
-            # (control_plane_site_env above + restore_site_dirs in the AM
-            # __main__) — NOT via PYTHONPATH, which reaches user processes.
-            [sys.executable, "-S", "-m", "tony_tpu.am",
+            [sys.executable, "-m", "tony_tpu.am",
              "--conf", str(self.job_dir / "client-conf.json"),
              "--app-id", self.app_id,
              "--job-dir", str(self.job_dir),

@@ -1,17 +1,6 @@
-"""JAX API compatibility: one place that absorbs the moving surface.
-
-The compute plane targets current JAX (``jax.shard_map``, ``jax.set_mesh``)
-but must also run on the 0.4.x line some images pin (where manual sharding
-lives in ``jax.experimental.shard_map`` and there is no ambient-mesh
-context — ``NamedSharding`` carries its mesh explicitly, so the context is
-simply not needed). Every module that manually shards goes through these
-two helpers instead of probing ``jax`` itself.
-"""
+"""The one ``shard_map`` spelling every manual-sharding module uses."""
 
 from __future__ import annotations
-
-import contextlib
-from typing import Any
 
 import jax
 
@@ -20,59 +9,5 @@ def shard_map(f, mesh, in_specs, out_specs):
     """``jax.shard_map`` with per-shard replication checking off — the
     schedules here build replication via explicit ``psum`` and assert it
     themselves (numerical pin tests), which the checker can't see."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
-def ambient_mesh_size() -> int:
-    """Device count of the ambient abstract mesh (``jax.set_mesh`` scope),
-    or 0 when none is set — including on 0.4.x, where no ambient-mesh
-    concept exists (and :func:`mesh_context` is a no-op, so code gating on
-    "am I inside the sharded train harness?" correctly sees 0 there)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return 0
-    m = get()
-    if m is None or m.empty:
-        return 0
-    return m.size
-
-
-def mesh_context(mesh) -> Any:
-    """Ambient-mesh scope for jitted GSPMD code: ``jax.set_mesh`` where it
-    exists, a no-op otherwise (on 0.4.x the shardings baked into the jitted
-    function are explicit ``NamedSharding``s, so no scope is required)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return contextlib.nullcontext()
-
-
-def serialize_compiled(compiled) -> Any:
-    """``(payload_bytes, in_tree, out_tree)`` of a ``jax.stages.Compiled``
-    via ``jax.experimental.serialize_executable`` — the AOT compile
-    cache's wire (:mod:`tony_tpu.ckpt.aot`). Returns ``None`` when this
-    jax/backend cannot serialize executables (older 0.4.x lines, or a
-    PJRT plugin without executable serialization): the cache degrades to
-    a counted miss, never a wrong program."""
-    try:
-        from jax.experimental import serialize_executable as _se
-        return _se.serialize(compiled)
-    except Exception:
-        return None
-
-
-def deserialize_compiled(payload: bytes, in_tree, out_tree) -> Any:
-    """Load a serialized executable back into a callable
-    ``jax.stages.Compiled`` — the other half of
-    :func:`serialize_compiled`. ``None`` on ANY failure (version skew,
-    plugin mismatch, torn payload): callers re-trace instead — a cold
-    start may cost a compile, never a wrong program."""
-    try:
-        from jax.experimental import serialize_executable as _se
-        return _se.deserialize_and_load(payload, in_tree, out_tree)
-    except Exception:
-        return None
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

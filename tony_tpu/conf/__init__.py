@@ -69,18 +69,18 @@ PYTHON_BINARY = "tony.application.python-binary"              # interpreter path
 # libtpu address BEFORE launch, so these can't be executor-reserved
 # ephemerals. Conf-keyed so concurrent jobs sharing hosts stay apart.
 LIBTPU_PORT_BASE = "tony.task.libtpu.port-base"
-# JAXRuntime injects the comm/compute-overlap XLA flags (latency-hiding
-# scheduler, async collective fusion — tony_tpu.parallel.overlap) into a jax
-# task's XLA_FLAGS, merged under any flags from tony.<jobtype>.env (user-set
-# flag names win). Unset: injected iff the task requests TPUs
-# (tony.<jobtype>.tpus > 0 — the xla_tpu_* set aborts non-TPU XLA builds).
+# JAXRuntime injects the comm/compute-overlap TPU compiler flags
+# (latency-hiding scheduler, async collective fusion —
+# tony_tpu.parallel.overlap) into a jax task's LIBTPU_INIT_ARGS, merged
+# under any flags from tony.<jobtype>.env (user-set flag names win).
+# Unset: injected iff the task requests TPUs (tony.<jobtype>.tpus > 0).
 # Explicit true/false forces it on (whole-host TPU jobs) / off.
 JAX_OVERLAP_XLA_FLAGS = "tony.jax.overlap-xla-flags"
 # Number of DCN-connected TPU slices the jax gang spans (>1 = multi-slice).
 # The rendezvous world is split contiguously into this many equal slices:
 # JAXRuntime derives each task's MEGASCALE_SLICE_ID from its global rank,
 # exports the megascale coordination env, and adds the DCN XLA flag set
-# (overlap.MULTISLICE_XLA_FLAGS) so the hierarchical per-bucket DCN
+# (jax_runtime.MULTISLICE_XLA_FLAGS) so the hierarchical per-bucket DCN
 # allreduces overlap. Must divide the rendezvous task count.
 JAX_SLICES = "tony.jax.slices"
 # Port for the megascale DCN transport/coordinator (same on every host;

@@ -97,7 +97,7 @@ ENV_DMLC_NUM_WORKER = "DMLC_NUM_WORKER"
 # TPU topology env injected by JAXRuntime on real pods (libtpu contract)
 ENV_TPU_WORKER_ID = "TPU_WORKER_ID"
 ENV_TPU_WORKER_HOSTNAMES = "TPU_WORKER_HOSTNAMES"
-ENV_TPU_VISIBLE_DEVICES = "TPU_VISIBLE_DEVICES"
+ENV_TPU_VISIBLE_CHIPS = "TPU_VISIBLE_CHIPS"
 ENV_TPU_CHIPS_PER_HOST_BOUNDS = "TPU_CHIPS_PER_HOST_BOUNDS"
 # Host-subdivision contract (several tasks sharing one host's chips):
 ENV_TPU_PROCESS_BOUNDS = "TPU_PROCESS_BOUNDS"
@@ -112,9 +112,16 @@ ENV_MEGASCALE_COORDINATOR_ADDRESS = "MEGASCALE_COORDINATOR_ADDRESS"
 ENV_MEGASCALE_NUM_SLICES = "MEGASCALE_NUM_SLICES"
 ENV_MEGASCALE_SLICE_ID = "MEGASCALE_SLICE_ID"
 ENV_MEGASCALE_PORT = "MEGASCALE_PORT"
-# XLA compiler knobs (JAXRuntime injects the comm/compute-overlap set —
-# latency-hiding scheduler + async collectives — unless disabled by conf)
-ENV_XLA_FLAGS = "XLA_FLAGS"
+# TPU compiler knobs (JAXRuntime injects the comm/compute-overlap set —
+# latency-hiding scheduler + async collectives — unless disabled by conf).
+# They travel in libtpu's own variable: jaxlib parses XLA_FLAGS itself on
+# every compile (``CompileOptions()``) and aborts the process on the
+# xla_tpu_* names, which only libtpu's flag registry knows.
+ENV_LIBTPU_INIT_ARGS = "LIBTPU_INIT_ARGS"
+# Platform pin for a task that was granted chips (tony.<jobtype>.tpus > 0):
+# unset, jax falls back to the CPU when the TPU fails to initialise and
+# the job "succeeds" there; pinned, the task dies instead.
+ENV_JAX_PLATFORMS = "JAX_PLATFORMS"
 
 # --- Well-known job types ---------------------------------------------------
 # (reference: open-ended; these are the conventional names used by the success

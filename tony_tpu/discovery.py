@@ -4,9 +4,12 @@ Mirrors ``com.linkedin.tony.util.gpu.GpuDiscoverer`` (upstream
 ``tony-core/src/main/java/com/linkedin/tony/util/gpu/``, unverified —
 SURVEY.md §0/§2.1): the reference shells out to ``nvidia-smi -q -x`` and
 parses XML so the AM can schedule/isolate GPUs pre-YARN-3.1. The TPU
-equivalent needs no subprocess: chips appear as ``/dev/accel*`` (TPU-VM) or
-``/dev/vfio/*`` device nodes, and the libtpu env describes the host's slice
-topology. The count feeds the scheduler's ``total_tpus`` so over-subscribed
+equivalent needs no subprocess: chips appear as ``/dev/accel<n>`` (TPU-VM) or
+``/dev/vfio/<n>`` device nodes, and the libtpu env describes the host's slice
+topology. Device nodes come first because they are what this process can
+open: the one-chip v5e machine exposes ``/dev/vfio/0`` alone while its
+inherited ``TPU_CHIPS_PER_HOST_BOUNDS`` still reads ``2,2,1``. The count
+feeds the scheduler's ``total_tpus`` so over-subscribed
 ``tony.<jobtype>.tpus`` asks fail at launch like an RM rejecting an
 unsatisfiable resource request.
 """
@@ -23,20 +26,27 @@ from typing import Optional
 @dataclass(frozen=True)
 class TpuTopology:
     num_chips: int
-    source: str          # devfs | env | jax | none
+    source: str          # devfs | env | none
 
 
-def _chips_from_devfs() -> Optional[int]:
-    accels = glob.glob("/dev/accel*")
+def _chips_from_devfs(dev: str = "/dev") -> Optional[int]:
+    accels = glob.glob(f"{dev}/accel[0-9]*")
     if accels:
         return len(accels)
-    vfio = [p for p in glob.glob("/dev/vfio/*") if p != "/dev/vfio/vfio"]
+    # One numbered IOMMU group node per chip. Beside them /dev/vfio holds
+    # the ``vfio`` control node and, on newer kernels, a ``devices``
+    # directory — neither is a chip.
+    vfio = [p for p in glob.glob(f"{dev}/vfio/*")
+            if os.path.basename(p).isdigit()]
     if vfio:
         return len(vfio)
     return None
 
 
 def _chips_from_env(env=os.environ) -> Optional[int]:
+    visible = env.get("TPU_VISIBLE_CHIPS")      # narrower than the bounds
+    if visible:
+        return len([c for c in visible.split(",") if c.strip() != ""])
     bounds = env.get("TPU_CHIPS_PER_HOST_BOUNDS")  # e.g. "2,2,1"
     if bounds:
         dims = [int(x) for x in re.findall(r"\d+", bounds)]
@@ -45,28 +55,18 @@ def _chips_from_env(env=os.environ) -> Optional[int]:
             for d in dims:
                 n *= d
             return n
-    visible = env.get("TPU_VISIBLE_DEVICES")
-    if visible:
-        return len([c for c in visible.split(",") if c.strip() != ""])
     return None
 
 
-def discover_tpus(use_jax: bool = False) -> TpuTopology:
-    """Count this host's TPU chips. Order: device nodes, libtpu env, then
-    (opt-in — importing jax initializes the runtime) jax itself."""
+def discover_tpus() -> TpuTopology:
+    """Count this host's TPU chips from device nodes, else the libtpu env.
+    Never through jax: the caller is the AM, and a control-plane process
+    that initialises the runtime takes the chip from the task it is about
+    to launch."""
     n = _chips_from_devfs()
     if n is not None:
         return TpuTopology(n, "devfs")
     n = _chips_from_env()
     if n is not None:
         return TpuTopology(n, "env")
-    if use_jax:
-        try:
-            import jax
-            devs = [d for d in jax.local_devices()
-                    if d.platform not in ("cpu",)]
-            if devs:
-                return TpuTopology(len(devs), "jax")
-        except Exception:
-            pass
     return TpuTopology(0, "none")

@@ -4,7 +4,7 @@ The JAXRuntime exports the coordinator triple (SURVEY.md §2.4 "rendezvous");
 user code simply calls::
 
     import tony_tpu.distributed as dist
-    dist.initialize()          # no-op outside a TonY job or for 1 process
+    dist.initialize()          # no rendezvous outside a TonY job / for 1 process
 
 which forwards to ``jax.distributed.initialize(coordinator_address,
 num_processes, process_id)`` — the TPU-native replacement for ``TF_CONFIG`` /
@@ -34,7 +34,12 @@ def initialize(local_device_ids: Optional[Sequence[int]] = None) -> bool:
     """Bring up the JAX coordination service from TonY env. Returns True if
     multi-process init happened, False for the single-process fallback.
     Also starts the per-task profiler server when the JAXRuntime enabled it
-    (``tony.task.profiler.enabled`` — SURVEY.md §5.1)."""
+    (``tony.task.profiler.enabled`` — SURVEY.md §5.1). As the training
+    entry it also turns on jax's persistent compile cache
+    (:func:`tony_tpu.util.enable_compile_cache`)."""
+    from tony_tpu.util import enable_compile_cache
+
+    enable_compile_cache()
     _maybe_start_profiler()
     spec = env_spec()
     if spec is None:

@@ -3,10 +3,6 @@
 
 import sys
 
-from tony_tpu.util import restore_site_dirs
-
-restore_site_dirs()   # -S entry: see tony_tpu.util.ENV_SITE_DIRS
-
 from tony_tpu.executor import main
 
 if __name__ == "__main__":

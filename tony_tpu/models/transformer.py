@@ -380,8 +380,8 @@ class Transformer(nn.Module):
             # keep the gather.
             if not get_logical_axis_rules():
                 return False
-            from tony_tpu.compat import ambient_mesh_size
-            return ambient_mesh_size() > 1
+            ambient = jax.sharding.get_abstract_mesh()
+            return not ambient.empty and ambient.size > 1
 
         if _sharded_training():
             # Sharded multi-device training only — on one device the

@@ -971,9 +971,10 @@ def _plan_dispatch(t, tk, block_q, block_k, causal):
     ``("pad_masked", bq, bk, (t_pad, tk_pad, kv_len))`` — any other
     ragged lengths (non-causal, or cross q/k): q and K/V zero-pad
     independently to tile-legal block multiples and the kernels mask the
-    padded keys via the static ``kv_len`` (the BENCH_r02 block-shape
-    constraint used to send these shapes to the reference fallback — the
-    T×T score materialization — instead).
+    padded keys via the static ``kv_len`` (after the chip's compiler
+    refused the first kernel's non-tile-aligned block shape, these
+    shapes were sent to the reference fallback — the T×T score
+    materialization — instead).
     """
     bq, bk = _fit_block(block_q, t), _fit_block(block_k, tk)
     if bq and bk:
@@ -989,18 +990,26 @@ def _plan_dispatch(t, tk, block_q, block_k, causal):
     return ("pad_masked", bq, bk, (t_pad, tk_pad, tk))
 
 
+class KernelFallbackWarning(UserWarning):
+    """A TPU run left the Pallas kernel for its XLA twin."""
+
+
 def _warn_fallback(reason: str) -> None:
     """One warning per distinct reason when a TPU run leaves the kernel
     path — the reference fallback materializes the T×T score matrix, an
-    OOM/perf cliff on long sequences that should never be silent."""
+    OOM/perf cliff on long sequences that should never be silent. The
+    message starts with a fixed colon-free prefix so a run that must not
+    fall back (``chip_smoke.py``) makes it fatal through the standard
+    ``PYTHONWARNINGS="error:kernel fallback"`` in every process it
+    starts."""
     import warnings
 
     if reason not in _warned:
         _warned.add(reason)
         warnings.warn(
-            f"flash_attention: falling back to reference attention "
-            f"({reason}); the full score matrix will materialize",
-            stacklevel=3)
+            f"kernel fallback to the XLA twin ({reason}); the full "
+            f"score matrix may materialize",
+            KernelFallbackWarning, stacklevel=3)
 
 
 _warned: set = set()

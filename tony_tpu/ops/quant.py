@@ -482,9 +482,9 @@ def gather_roundtrip_exact(params: Any, mesh: Mesh,
 
     flat_specs = jax.tree.leaves(p_specs,
                                  is_leaf=lambda x: isinstance(x, P))
-    out = compat.shard_map(
+    out = jax.jit(compat.shard_map(
         lambda *lv: spmd(jax.tree.unflatten(plan.treedef, list(lv))),
-        mesh, in_specs=tuple(flat_specs), out_specs=P())(
+        mesh, in_specs=tuple(flat_specs), out_specs=P()))(
             *jax.tree.leaves(params))
     return bool(jax.device_get(out))
 

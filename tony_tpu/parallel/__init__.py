@@ -179,7 +179,7 @@ from tony_tpu.parallel.ring_attention import (  # noqa: E402  (re-export)
 from tony_tpu.parallel.pipeline import (  # noqa: E402  (re-export)
     gpipe, gpipe_1f1b, pipelined_lm_logits, stage_split)
 from tony_tpu.parallel.overlap import (  # noqa: E402  (re-export)
-    GradBuckets, fsdp_param_specs, microbatch_grads, overlap_xla_flags)
+    GradBuckets, fsdp_param_specs, microbatch_grads)
 from tony_tpu.parallel.sched import (  # noqa: E402  (re-export)
     GatherPlan, moe_dispatch_ffn_combine)
 
@@ -191,6 +191,5 @@ __all__ = [
     "ring_attention", "ring_attention_sharded", "gpipe", "gpipe_1f1b",
     "pipelined_lm_logits", "stage_split",
     "GradBuckets", "fsdp_param_specs", "microbatch_grads",
-    "overlap_xla_flags",
     "GatherPlan", "moe_dispatch_ffn_combine",
 ]

@@ -27,11 +27,10 @@ builds that layer natively:
   :func:`tony_tpu.train.make_accum_train_step` wraps this into a drop-in
   train step and auto-detects the ZeRO-3 layout from the state's
   shardings.
-* :func:`overlap_xla_flags` — the latency-hiding-scheduler / async
-  collective flags (plus the DCN set for multi-slice jobs), merged into an
-  ``XLA_FLAGS`` string with user-set values winning;
-  :class:`tony_tpu.runtime.jax_runtime.JAXTaskAdapter` injects the result
-  so tony-submitted jobs get the overlap for free.
+* the latency-hiding-scheduler / async-collective TPU compiler flags that
+  make the overlap real live with their only caller,
+  :func:`tony_tpu.runtime.jax_runtime.overlap_xla_flags` (the executor
+  builds a task's env and must not import jax to do it).
 """
 
 from __future__ import annotations
@@ -57,47 +56,6 @@ _log = logging.getLogger(__name__)
 # sooner after the first grads materialize. 4 MiB is the planner default;
 # callers tune per model via ``bucket_bytes``.
 DEFAULT_BUCKET_BYTES = 4 << 20
-
-# The scheduler knobs (MaxText/XLA-team standard set): latency-hiding
-# scheduling so async collective pairs slide over compute, plus async
-# collective fusion so the per-bucket reduces actually become async pairs.
-# TPU-namespaced flags ONLY: XLA ABORTS the process on any flag its build
-# doesn't know (measured on the CPU wheel), so this set must never reach a
-# non-TPU jaxlib — the runtime injects it only for TPU-resourced tasks.
-OVERLAP_XLA_FLAGS: Tuple[str, ...] = (
-    "--xla_tpu_enable_latency_hiding_scheduler=true",
-    "--xla_tpu_enable_async_collective_fusion=true",
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-    "--xla_tpu_enable_async_collective_fusion_multiple_steps=true",
-    "--xla_tpu_overlap_compute_collective_tc=true",
-)
-
-# Multi-slice additions: let the scheduler split/overlap the DCN allreduces
-# that the hierarchical reduce issues per bucket (different-sized DCN ops
-# must not serialize behind each other). Same TPU-namespace-only rule.
-MULTISLICE_XLA_FLAGS: Tuple[str, ...] = (
-    "--xla_tpu_enable_data_parallel_all_reduce_opt=true",
-    "--xla_tpu_data_parallel_opt_different_sized_ops=true",
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_reduce=true",
-)
-
-
-def _flag_name(flag: str) -> str:
-    return flag.lstrip("-").split("=", 1)[0]
-
-
-def overlap_xla_flags(existing: str = "", *, multislice: bool = False) -> str:
-    """Merge :data:`OVERLAP_XLA_FLAGS` (and, for multi-slice jobs,
-    :data:`MULTISLICE_XLA_FLAGS`) into an ``XLA_FLAGS`` string.
-
-    A flag the caller already set (any value) is kept and ours dropped —
-    injection must never override an operator's explicit tuning.
-    """
-    ours = OVERLAP_XLA_FLAGS + (MULTISLICE_XLA_FLAGS if multislice else ())
-    present = {_flag_name(f) for f in existing.split() if f.startswith("-")}
-    merged = [f for f in ours if _flag_name(f) not in present]
-    return " ".join(filter(None, [existing.strip(), *merged])).strip()
-
 
 def sync_axes(mesh: Mesh) -> Tuple[str, ...]:
     """The gradient-sync mesh axes: the DCN slice axis plus both DP axes,
@@ -673,7 +631,7 @@ def microbatch_grads(loss_fn: Callable[[Any, Any], Any], params: Any,
     bucket, so the collective for microbatch *i* is in flight while
     microbatch *i+1*'s forward/backward computes (the Horovod overlap,
     expressed for XLA's latency-hiding scheduler — see
-    :func:`overlap_xla_flags`).
+    :func:`tony_tpu.runtime.jax_runtime.overlap_xla_flags`).
 
     **Fused optimizer update** (``fused`` =
     :class:`tony_tpu.ops.fused_optim.FusedOptimizer`, with ``opt_slots``

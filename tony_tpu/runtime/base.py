@@ -10,6 +10,7 @@ from __future__ import annotations
 import json
 from typing import Dict
 
+from tony_tpu import conf as conf_mod
 from tony_tpu import constants
 from tony_tpu.runtime import TaskContext, TaskExecutorAdapter
 
@@ -33,6 +34,10 @@ class MLGenericTaskAdapter(TaskExecutorAdapter):
             env[constants.ENV_TB_PORT] = str(ctx.tb_port)
         env.update(ctx.conf.task_env(ctx.job_type))
         env.update(self.framework_env(ctx))
+        if ctx.conf.get_int(conf_mod.tpus_key(ctx.job_type), 0) > 0:
+            # Whatever the framework (a `tony serve` replica is
+            # "standalone"): a task granted chips runs on them or dies.
+            env[constants.ENV_JAX_PLATFORMS] = "tpu"
         return env
 
     def framework_env(self, ctx: TaskContext) -> Dict[str, str]:

@@ -11,7 +11,7 @@ DCN across slices — there is no NCCL and no parameter server.
 
 On a real TPU pod the adapter additionally injects the libtpu topology env
 (``TPU_WORKER_ID``, ``TPU_WORKER_HOSTNAMES``, chip pinning via
-``TPU_VISIBLE_DEVICES`` when ``tony.<jobtype>.tpus`` subdivides a host) so
+``TPU_VISIBLE_CHIPS`` when ``tony.<jobtype>.tpus`` subdivides a host) so
 multiple tasks can share a host, each seeing only its chips.
 
 User code calls :func:`tony_tpu.distributed.initialize` (or passes the env
@@ -21,12 +21,57 @@ straight to ``jax.distributed.initialize``) and then uses plain
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Tuple
 
 from tony_tpu import constants
 from tony_tpu import conf as conf_mod
 from tony_tpu.runtime import ApplicationMasterAdapter, Framework, TaskContext
 from tony_tpu.runtime.base import MLGenericTaskAdapter
+
+# The comm/compute-overlap compiler knobs (tony_tpu.parallel.overlap is
+# what they schedule; they live HERE, with their only caller, because the
+# executor builds this env and must stay off jax, which importing the
+# compute plane would pull in). MaxText/XLA-team standard set: latency-hiding
+# scheduling so async collective pairs slide over compute, plus async
+# collective fusion so the per-bucket reduces actually become async pairs.
+# TPU-namespaced flags ONLY, and only libtpu's registry knows them: they
+# go in ``LIBTPU_INIT_ARGS``. jaxlib parses ``XLA_FLAGS`` itself on every
+# compile and ABORTS the process on a name it doesn't know, on any
+# platform — so this set must never be put there.
+OVERLAP_XLA_FLAGS: Tuple[str, ...] = (
+    "--xla_tpu_enable_latency_hiding_scheduler=true",
+    "--xla_tpu_enable_async_collective_fusion=true",
+    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
+    "--xla_tpu_enable_async_collective_fusion_multiple_steps=true",
+    "--xla_tpu_overlap_compute_collective_tc=true",
+)
+
+# Multi-slice additions: let the scheduler split/overlap the DCN allreduces
+# that the hierarchical reduce issues per bucket (different-sized DCN ops
+# must not serialize behind each other). Same TPU-namespace-only rule.
+MULTISLICE_XLA_FLAGS: Tuple[str, ...] = (
+    "--xla_tpu_enable_data_parallel_all_reduce_opt=true",
+    "--xla_tpu_data_parallel_opt_different_sized_ops=true",
+    "--xla_tpu_enable_async_collective_fusion_fuse_all_reduce=true",
+)
+
+
+def _flag_name(flag: str) -> str:
+    return flag.lstrip("-").split("=", 1)[0]
+
+
+def overlap_xla_flags(existing: str = "", *, multislice: bool = False) -> str:
+    """Merge :data:`OVERLAP_XLA_FLAGS` (and, for multi-slice jobs,
+    :data:`MULTISLICE_XLA_FLAGS`) into a ``LIBTPU_INIT_ARGS`` string.
+
+    A flag the caller already set (any value) is kept and ours dropped —
+    injection must never override an operator's explicit tuning.
+    """
+    ours = OVERLAP_XLA_FLAGS + (MULTISLICE_XLA_FLAGS if multislice else ())
+    present = {_flag_name(f) for f in existing.split() if f.startswith("-")}
+    merged = [f for f in ours if _flag_name(f) not in present]
+    return " ".join(filter(None, [existing.strip(), *merged])).strip()
+
 
 # Chip-count → rectangular libtpu bounds "x,y,z" for the chip grids TPU
 # hosts actually expose (v4: 4 chips 2x2; v5e: 1/4/8 chips; v5p: 4).
@@ -62,7 +107,7 @@ class JAXTaskAdapter(MLGenericTaskAdapter):
             first = sum(ctx.conf.get_int(f"tony.{jt}.tpus", 0)
                         for r, jt in ctx.host_cohort() if r < rank)
             chips = ",".join(str(first + i) for i in range(tpus))
-            env[constants.ENV_TPU_VISIBLE_DEVICES] = chips
+            env[constants.ENV_TPU_VISIBLE_CHIPS] = chips
             env[constants.ENV_LOCAL_DEVICE_IDS] = chips
         # libtpu multi-host topology (harmless off-pod; required on pods).
         # The documented contract (pinned by unit test — untestable on a
@@ -156,23 +201,23 @@ class JAXTaskAdapter(MLGenericTaskAdapter):
             env[constants.ENV_MEGASCALE_SLICE_ID] = str(rank // per_slice)
             env[constants.ENV_MEGASCALE_PORT] = str(ms_port)
         # Comm/compute overlap (tony_tpu.parallel.overlap): inject the
-        # latency-hiding-scheduler / async-collective XLA flags so
+        # latency-hiding-scheduler / async-collective flags so
         # tony-submitted TPU jobs overlap gradient sync with backward
         # compute by default — plus the DCN set for multi-slice jobs, so
-        # the per-bucket cross-slice allreduces overlap too. TPU-resourced
-        # tasks only unless forced by conf: XLA aborts on flags its build
-        # doesn't know, so the xla_tpu_* set would KILL a CPU-backend task
-        # at import. Merged UNDER any XLA_FLAGS from tony.<jobtype>.env
-        # (framework env wins the final build_task_env merge, so the merge
-        # happens here, with user flag names taking precedence).
+        # the per-bucket cross-slice allreduces overlap too. They ride
+        # LIBTPU_INIT_ARGS, never XLA_FLAGS (constants: jaxlib aborts on
+        # the xla_tpu_* names there). TPU-resourced tasks only unless
+        # forced by conf. Merged UNDER any LIBTPU_INIT_ARGS from
+        # tony.<jobtype>.env (framework env wins the final build_task_env
+        # merge, so the merge happens here, with user flag names taking
+        # precedence).
         overlap_set = ctx.conf.get(conf_mod.JAX_OVERLAP_XLA_FLAGS)
         inject = (ctx.conf.get_bool(conf_mod.JAX_OVERLAP_XLA_FLAGS)
                   if overlap_set is not None else tpus > 0)
         if inject:
-            from tony_tpu.parallel.overlap import overlap_xla_flags
             user_flags = ctx.conf.task_env(ctx.job_type).get(
-                constants.ENV_XLA_FLAGS, "")
-            env[constants.ENV_XLA_FLAGS] = overlap_xla_flags(
+                constants.ENV_LIBTPU_INIT_ARGS, "")
+            env[constants.ENV_LIBTPU_INIT_ARGS] = overlap_xla_flags(
                 user_flags, multislice=slices > 1)
         # Checkpoint plane (tony_tpu.ckpt): ship the conf-configured
         # durable dir + cadence to the user process so train_loop's

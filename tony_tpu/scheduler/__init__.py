@@ -14,7 +14,7 @@ reap — sit behind :class:`ContainerScheduler`, with two backends:
   Resource semantics follow the ``yarn.io/tpu`` resource-type model from the
   north star: a request carries ``tpus`` and the scheduler places tasks so
   chip assignments never overlap (the JAXRuntime then pins
-  ``TPU_VISIBLE_DEVICES`` per task).
+  ``TPU_VISIBLE_CHIPS`` per task).
 
 Preemption is a first-class verb (``preempt``) because the reference's
 failure machinery distinguishes preempted containers (re-request) from
@@ -36,7 +36,7 @@ from typing import Dict, List, Optional, Sequence
 
 from tony_tpu import constants
 from tony_tpu import conf as conf_mod
-from tony_tpu.util import child_pythonpath, control_plane_site_env
+from tony_tpu.util import child_pythonpath
 
 
 @dataclass
@@ -151,23 +151,13 @@ class LocalProcessScheduler(ContainerScheduler):
         env.update(task_env)
         env["PYTHONPATH"] = child_pythonpath(env)
         task_env["PYTHONPATH"] = env["PYTHONPATH"]
-        # -S: the executor is stdlib-only control plane; the USER process
-        # it spawns runs plain python with the full site (jax plugins
-        # registered normally). Site dirs for the executor's own lazy
-        # imports travel via TONY_SITE_DIRS (util.restore_site_dirs) —
-        # NOT for docker executors, whose tony_tpu lives in the IMAGE's
-        # site-packages: they need the plain site import (host paths mean
-        # nothing in the container).
         docker_on = self.conf is not None and self.conf.get_bool(
             conf_mod.DOCKER_ENABLED, False)
+        argv = [sys.executable, "-m", "tony_tpu.executor"]
         if docker_on:
-            argv = [sys.executable, "-m", "tony_tpu.executor"]
             argv = docker_wrap_command(self.conf, argv, env=task_env,
                                        workdir=str(workdir),
                                        mounts=[str(self.job_dir)])
-        else:
-            argv = [sys.executable, "-S", "-m", "tony_tpu.executor"]
-            env.update(control_plane_site_env())
         proc = subprocess.Popen(
             argv, env=env, cwd=workdir, stdout=log, stderr=subprocess.STDOUT,
             start_new_session=True)
@@ -369,12 +359,6 @@ class TpuVmScheduler(ContainerScheduler):
                 env[constants.ENV_VENV] = f"{wd}/venv-stage"
         if self.remote_pythonpath:
             env["PYTHONPATH"] = self.remote_pythonpath
-        # -S latency cut only when tony_tpu arrives via remote_pythonpath;
-        # a pip-installed remote (remote_pythonpath=None) NEEDS the site
-        # import to find tony_tpu at all. Remote site dirs are unknown
-        # here, so no TONY_SITE_DIRS: the executor's lazy jax census falls
-        # back to devfs/env — which is the real-TPU-host path anyway.
-        interp_flags = " -S" if self.remote_pythonpath else ""
         exports = " ".join(
             f"export {k}={shlex.quote(v)};" for k, v in sorted(env.items()))
         # setsid: the executor becomes leader of a fresh process group whose
@@ -383,7 +367,7 @@ class TpuVmScheduler(ContainerScheduler):
         # code (or 128+SIG after a remote kill) back through ssh.
         remote = (
             f"mkdir -p {wd}/pids && cd {wd} || exit 1; {exports} "
-            f"setsid {self.remote_python}{interp_flags} -m tony_tpu.executor "
+            f"setsid {self.remote_python} -m tony_tpu.executor "
             f"< /dev/null & pid=$!; echo $pid > pids/{cid}.pid; "
             f"wait $pid; rc=$?; rm -f pids/{cid}.pid; exit $rc")
         return self._ssh_argv(host, remote)

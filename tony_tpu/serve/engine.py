@@ -71,7 +71,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from tony_tpu._trace import trace_record
-from tony_tpu.compat import mesh_context
 from tony_tpu.serve import prefix as prefix_mod
 from tony_tpu.serve.disagg import HandoffError, decode_f32, encode_f32
 from tony_tpu.serve.kvcache import AdmissionError, PagedKVCache
@@ -319,7 +318,7 @@ class PagedModelRunner:
         jitted = self._fn(b, t)
         args = self._example_args(b, t)
         if self.mesh is not None:
-            with mesh_context(self.mesh):
+            with jax.set_mesh(self.mesh):
                 compiled = jitted.lower(*args).compile()
         else:
             compiled = jitted.lower(*args).compile()
@@ -388,7 +387,7 @@ class PagedModelRunner:
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(tables), jnp.asarray(flat_idx))
         if self.mesh is not None:
-            with mesh_context(self.mesh):
+            with jax.set_mesh(self.mesh):
                 logits, pk, pv = fn(*args)
         else:
             logits, pk, pv = fn(*args)
@@ -1523,7 +1522,7 @@ class ServeEngine(PagedModelRunner):
                 jnp.asarray(positions), jnp.asarray(tables),
                 jnp.asarray(flat))
         if self.mesh is not None:
-            with mesh_context(self.mesh):
+            with jax.set_mesh(self.mesh):
                 logits, _, _ = fn(*args)
         else:
             logits, _, _ = fn(*args)

@@ -222,7 +222,6 @@ class Replica:
         import jax.numpy as jnp
 
         from tony_tpu import ckpt
-        from tony_tpu.compat import mesh_context
 
         sample = jnp.zeros((1, q_block), jnp.int32)
 
@@ -230,14 +229,23 @@ class Replica:
             return nn.unbox(model.init(jax.random.PRNGKey(0),
                                        sample))["params"]
 
-        # Template init: structure/shapes only — every value is replaced
-        # by the restore below (and the restore is what the e2e test
-        # pins, so a template that accidentally survived would fail it).
+        # Template: structure/shapes only — every value is replaced by
+        # the restore below (and the restore is what the e2e test pins).
+        # Meshless, that is literally all it is: abstract shapes pinned to
+        # the default device; running the init for values nobody reads is
+        # minutes of compilation at a real model's width.
+        t0 = time.monotonic()
         if mesh is not None:
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 template = jax.jit(init)()
+            jax.block_until_ready(template)
         else:
-            template = init()
+            one = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+            template = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one),
+                jax.eval_shape(init))
+        t1 = time.monotonic()
         if step is None:
             step = ckpt.latest_step(ckpt_dir)
         if step is None:
@@ -248,6 +256,11 @@ class Replica:
         params = ckpt.restore_pytree(
             ckpt_dir, template, step=step, mesh=mesh,
             dtype_policy=dtype_policy, path_prefix=prefix)
+        jax.block_until_ready(params)
+        # Grant -> first token starts here: say where the seconds went.
+        print(f"[tony-serve-replica] restored step {step}: template "
+              f"{t1 - t0:.1f}s, read+place {time.monotonic() - t1:.1f}s",
+              flush=True)
         return params, step, prefix
 
     # -- request path ------------------------------------------------------
@@ -404,6 +417,13 @@ class Replica:
         print(f"[tony-serve-replica] listening on {server.address} "
               f"(ckpt step {self.restored_step})", flush=True)
         stop = stop or threading.Event()
+        import jax
+
+        devs = jax.devices()
+        # Every published window names the device it was measured on.
+        device = {"platform": devs[0].platform,
+                  "device_kind": devs[0].device_kind,
+                  "device_count": len(devs)}
 
         def publish() -> None:
             if not stats_path:
@@ -415,7 +435,7 @@ class Replica:
                 # not the serve RPC) — and the prefix digest
                 # rides the same payload for overlap scoring.
                 self.engine.write_stats(
-                    stats_path, extra={"rpc_port": server.port})
+                    stats_path, extra={"rpc_port": server.port, **device})
             except OSError:
                 pass
             if self._store is not None:
@@ -517,7 +537,9 @@ def main() -> int:
     (exported by the executor)."""
     from tony_tpu import constants
     from tony_tpu.conf import TonyConfig
+    from tony_tpu.util import enable_compile_cache
 
+    enable_compile_cache()
     conf_path = os.environ.get(constants.ENV_CONF_PATH)
     if not conf_path:
         print("[tony-serve-replica] no TONY_CONF_PATH; run under a tony "

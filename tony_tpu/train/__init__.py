@@ -32,7 +32,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tony_tpu import chaos, constants
 from tony_tpu import parallel as par
-from tony_tpu.compat import mesh_context
 from tony_tpu.parallel import overlap
 
 _log = logging.getLogger(__name__)
@@ -108,8 +107,8 @@ def create_train_state(model: nn.Module, tx: Any,
                        mesh: Optional[Mesh] = None,
                        rules=par.RULES) -> TrainState:
     """Initialize a TrainState; with a mesh, params are created already
-    sharded (jit + constraints — no host-memory detour) and the optimizer
-    state inherits the layout via GSPMD propagation.
+    sharded (jit + constraints — no host-memory detour) and every
+    param-shaped optimizer leaf is pinned to its param's layout.
 
     ``tx`` may be an optax ``GradientTransformation`` (leaf-major state,
     the default path) or a :class:`tony_tpu.ops.fused_optim
@@ -135,9 +134,19 @@ def create_train_state(model: nn.Module, tx: Any,
                               params, shardings)
         if fused:
             return params
-        return TrainState.create(apply_fn=model.apply, params=params, tx=tx)
+        state = TrainState.create(apply_fn=model.apply, params=params,
+                                  tx=tx)
+        # The moments are zeros with no data dependence on the params,
+        # so GSPMD propagates nothing to them: left alone they come out
+        # REPLICATED (a full copy per device, and a second compile of
+        # the step once its outputs shard them). Pin every param-shaped
+        # optimizer leaf to its param's layout.
+        opt_state = optax.tree_utils.tree_map_params(
+            tx, jax.lax.with_sharding_constraint, state.opt_state,
+            shardings)
+        return state.replace(opt_state=opt_state)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(make)(rng)
     if not fused:
         return out
@@ -206,7 +215,7 @@ def make_train_step(loss_of: Callable[[jax.Array, Dict[str, jax.Array]],
         return jitted
 
     def stepper(state, batch):
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             return jitted(state, batch)
     return stepper
 
@@ -238,7 +247,7 @@ def make_accum_train_step(loss_of: Callable[[jax.Array,
     the gradient reduction is issued per size-targeted bucket as each
     microbatch's backward finishes —
     :func:`tony_tpu.parallel.overlap.microbatch_grads` is the engine;
-    :func:`~tony_tpu.parallel.overlap.overlap_xla_flags` supplies the XLA
+    :func:`~tony_tpu.runtime.jax_runtime.overlap_xla_flags` supplies the XLA
     knobs that turn the structure into actual overlap on TPU.
 
     The parameter layout is detected from the state's committed shardings
@@ -465,7 +474,7 @@ def make_accum_train_step(loss_of: Callable[[jax.Array,
                     f"step's {bb} — the amax histories were sized for a "
                     f"different bucket plan; rebuild with "
                     f"with_gather_quant(bucket_bytes={bb})")
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             if aot_cache is not None:
                 return _compiled_for(state, batch)(state, batch)
             return _jitted_for(state)(state, batch)

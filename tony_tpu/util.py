@@ -25,58 +25,27 @@ def child_pythonpath(env: Dict[str, str]) -> str:
     than an installed package: prepend the package root, dedupe.
 
     Deliberately does NOT carry site-packages: PYTHONPATH reaches the USER
-    process, where host site dirs would shadow a job venv's packages. The
-    ``python -S`` control-plane processes get their site dirs via
-    :func:`control_plane_site_env` / :func:`restore_site_dirs` instead."""
+    process, where host site dirs would shadow a job venv's packages."""
     parts = [PKG_ROOT] + [p for p in env.get("PYTHONPATH", "").split(
         os.pathsep) if p and p != PKG_ROOT]
     return os.pathsep.join(parts)
 
 
-# AM/executor processes launch with `python -S`: the ML stack's
-# sitecustomize hooks cost ~1.8 s per interpreter start (measured: the
-# whole control-plane import tree is 0.15 s without them) — pure
-# submit→running latency for stdlib-only processes. Their LAZY heavyweight
-# imports (discovery's jax census, the trace collector's profiler client)
-# still need site-packages, carried in this env var and restored with
-# site.addsitedir (which, unlike PYTHONPATH, also processes .pth files —
-# pip --user and editable installs keep working).
-ENV_SITE_DIRS = "TONY_SITE_DIRS"
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; first statement of
+    every process that compiles (replica, training entry, bench, smoke
+    children). The directory is part of the cache key, so it never moves:
+    where ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it itself and
+    nothing is set in code; otherwise ``<checkout>/.jax_cache``. Returns
+    the directory in use."""
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if from_env:
+        return from_env
+    import jax
 
-
-def control_plane_site_env() -> Dict[str, str]:
-    """Env entry shipping this interpreter's site dirs to a ``-S`` child.
-    Reuses an inherited value (an AM is itself a ``-S`` child and must
-    forward what the client computed under full site)."""
-    import site
-
-    existing = os.environ.get(ENV_SITE_DIRS)
-    if existing:
-        return {ENV_SITE_DIRS: existing}
-    dirs = []
-    try:
-        dirs += site.getsitepackages()
-    except AttributeError:        # some embedded interpreters
-        pass
-    try:
-        user = site.getusersitepackages()
-        if user:
-            dirs.append(user)
-    except AttributeError:
-        pass
-    dirs = [d for d in dirs if os.path.isdir(d)]
-    return {ENV_SITE_DIRS: os.pathsep.join(dirs)} if dirs else {}
-
-
-def restore_site_dirs() -> None:
-    """First statement of a ``-S`` control-plane ``__main__``: register the
-    shipped site dirs so lazy imports resolve, WITHOUT running the
-    sitecustomize hooks ``-S`` exists to skip."""
-    import site
-
-    for d in os.environ.get(ENV_SITE_DIRS, "").split(os.pathsep):
-        if d:
-            site.addsitedir(d)
+    path = os.path.join(PKG_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def normalize_serve_telemetry(raw: Dict) -> Dict[str, object]:
