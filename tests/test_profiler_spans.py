@@ -1,0 +1,629 @@
+"""The program's own timeline (tony_tpu.profiler): spans, counters, build
+records, and the one path they take out of the process — timeline.json ->
+executor -> AM -> TASK_TIMELINE -> ``tony history`` — plus the hooks that
+use them in ``train_loop`` and the serve replica."""
+
+from __future__ import annotations
+
+import glob
+import json
+import logging
+import os
+import subprocess
+import sys
+import threading
+import time
+from pathlib import Path
+
+import pytest
+
+from tony_tpu import constants, events as ev, profiler
+from tony_tpu.minipod import MiniPod
+
+ROOT = Path(__file__).resolve().parent.parent
+WORKLOADS = Path(__file__).parent / "workloads"
+
+
+@pytest.fixture(autouse=True)
+def fresh_timeline():
+    profiler.reset_timeline()
+    yield
+    profiler.reset_timeline()
+
+
+def host_events(logdir) -> list:
+    """Names of every host-plane event of the newest trace under logdir."""
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(f"{logdir}/plugins/profile/*/*.xplane.pb"))[-1]
+    return [e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+# -- the facility ----------------------------------------------------------
+
+def test_setup_spans_nest_and_name_their_parent():
+    with profiler.span("tony:restore") as outer:
+        with profiler.span("tony:warm", programs=2):
+            pass
+        outer.attrs.update(step=7, bytes=1024)
+    warm, restore = profiler.timeline()["spans"]
+    assert (warm["name"], warm["parent"]) == ("tony:warm", "tony:restore")
+    assert (restore["name"], restore["parent"]) == ("tony:restore", None)
+    assert restore["attrs"] == {"step": 7, "bytes": 1024}
+    assert warm["attrs"] == {"programs": 2}
+    assert restore["t0"] <= warm["t0"] <= warm["t1"] <= restore["t1"]
+    # Epoch seconds: the clock the event log and the benchmark use.
+    assert abs(restore["t1"] - time.time()) < 60
+
+
+def test_hot_path_spans_never_reach_the_timeline():
+    for name in ("train:next_batch", "train:on_step", "train:save",
+                 "serve:launch", "serve:emit", "anything_else"):
+        assert name not in profiler.SETUP_SPANS
+        with profiler.span(name, step=1):
+            pass
+    assert profiler.timeline()["spans"] == []
+
+
+def test_span_as_decorator_records_every_call():
+    @profiler.span("tony:create_train_state")
+    def build(x):
+        return x + 1
+
+    assert build(1) == 2 and build(2) == 3
+    assert [s["name"] for s in profiler.timeline()["spans"]] == \
+        ["tony:create_train_state"] * 2
+
+
+def test_timeline_is_bounded_and_counters_never_stop():
+    for _ in range(profiler.MAX_SPANS + 10):
+        with profiler.span("tony:warm"):
+            pass
+    for _ in range(profiler.MAX_BUILDS + 10):
+        profiler._on_build("/jax/core/compile/backend_compile_duration", 0.5)
+    got = profiler.timeline()
+    assert len(got["spans"]) == profiler.MAX_SPANS
+    assert len(got["builds"]) == profiler.MAX_BUILDS
+    assert got["builds_dropped"] == 10
+    assert got["counters"]["programs_compiled"] == profiler.MAX_BUILDS + 10
+
+
+def test_counters_count_and_add_seconds():
+    profiler.count("saves")
+    profiler.count("saves", 2)
+    profiler.add_seconds("save_stall_s", 0.25)
+    profiler.add_seconds("save_stall_s", 0.5)
+    assert profiler.counters() == {"saves": 3, "save_stall_s": 0.75}
+
+
+def test_a_cache_load_is_one_build_not_also_a_compile():
+    # jax reports a persistent-cache hit as a retrieval AND, around it, a
+    # backend_compile duration on the same thread.
+    profiler._on_build("/jax/compilation_cache/cache_retrieval_time_sec", .02)
+    profiler._on_build("/jax/core/compile/backend_compile_duration", 0.03)
+    profiler._on_build("/jax/core/compile/backend_compile_duration", 2.0)
+    profiler._on_cache_event("/jax/compilation_cache/cache_hits")
+    profiler._on_build("/some/other/event", 9.0)
+    c = profiler.counters()
+    assert (c["programs_loaded"], c["programs_compiled"]) == (1, 1)
+    assert (c["load_s"], c["compile_s"], c["cache_hits"]) == (0.02, 2.0, 1)
+    assert [b["kind"] for b in profiler.timeline()["builds"]] == \
+        ["load", "compile"]
+    assert profiler.build_totals() == {"programs_built": 2, "build_s": 2.02}
+
+
+def test_short_traces_are_counted_but_not_recorded():
+    profiler._on_build("/jax/core/compile/jaxpr_trace_duration", 1e-5)
+    profiler._on_build("/jax/core/compile/jaxpr_trace_duration", 0.2)
+    profiler._on_build("/jax/core/compile/backend_compile_duration", 1e-5)
+    assert profiler.counters()["programs_traced"] == 2
+    assert [(b["kind"], b["s"]) for b in profiler.timeline()["builds"]] == \
+        [("trace", 0.2), ("compile", 1e-5)]
+
+
+def test_watch_builds_counts_a_jitted_function_once_per_shape():
+    import jax
+    import jax.numpy as jnp
+
+    profiler.watch_builds()
+    profiler.watch_builds()          # installed once, however often asked
+
+    def built():
+        c = profiler.counters()
+        return (c.get("programs_compiled", 0) + c.get("programs_loaded", 0),
+                c.get("programs_lowered", 0))
+
+    f = jax.jit(lambda x: jnp.tanh(x) * 3.0 + 1.0)
+    x4, x8 = jnp.ones((4,)), jnp.ones((8,))      # made before counting
+    jax.block_until_ready((x4, x8))
+    before, n0 = built(), len(profiler.timeline()["builds"])
+    f(x4).block_until_ready()
+    f(x4).block_until_ready()        # cached: nothing is built
+    once = built()
+    f(x8).block_until_ready()
+    twice = built()
+    assert (once[0] - before[0], once[1] - before[1]) == (1, 1)
+    assert (twice[0] - before[0], twice[1] - before[1]) == (2, 2)
+    kinds = [b["kind"] for b in profiler.timeline()["builds"][n0:]]
+    assert sum(k in ("compile", "load") for k in kinds) == 2
+
+
+def test_the_timeline_half_leaves_jax_out(tmp_path):
+    """The executor, the AM and the history plane import this module, and
+    a jax-free task may record and publish a timeline too."""
+    code = (
+        "import sys\n"
+        "import tony_tpu.executor, tony_tpu.am, tony_tpu.history\n"
+        "from tony_tpu import profiler\n"
+        "with profiler.span('tony:restore'):\n"
+        "    profiler.count('saves')\n"
+        "path = profiler.write_timeline()\n"
+        "assert profiler.read_timeline(path)['counters'] == {'saves': 1}\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n")
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=ROOT, capture_output=True,
+        text=True, timeout=120,
+        env={**os.environ,
+             constants.ENV_SERVE_STATS: str(tmp_path / "serve-stats.json")})
+    assert out.returncode == 0, out.stderr
+    # ... and the exit hook rewrote it on the way out.
+    assert (tmp_path / "timeline.json").is_file()
+
+
+def test_timeline_file_sits_beside_the_stats_file(tmp_path, monkeypatch):
+    monkeypatch.setenv(constants.ENV_SERVE_STATS,
+                       str(tmp_path / "serve-stats.json"))
+    assert profiler.timeline_path() == tmp_path / "timeline.json"
+    with profiler.span("tony:dist_initialize"):
+        pass
+    assert profiler.write_timeline() == tmp_path / "timeline.json"
+    assert sorted(p.name for p in tmp_path.iterdir()) == ["timeline.json"]
+    got = profiler.read_timeline(tmp_path / "timeline.json")
+    assert [s["name"] for s in got["spans"]] == ["tony:dist_initialize"]
+    (tmp_path / "timeline.json").write_text("{torn")
+    assert profiler.read_timeline(tmp_path / "timeline.json") is None
+    monkeypatch.delenv(constants.ENV_SERVE_STATS)
+    assert profiler.timeline_path() is None
+    assert profiler.write_timeline() is None
+
+
+# -- out of the process ----------------------------------------------------
+
+def test_executor_relays_the_timeline_only_when_it_was_rewritten(
+        tmp_path, monkeypatch):
+    from tony_tpu.executor import TaskExecutor
+
+    ex = TaskExecutor.__new__(TaskExecutor)
+    ex.log_dir = tmp_path
+    assert ex.timeline_path() == tmp_path / "timeline.json"
+    assert ex._timeline_since(0) == (None, 0)
+    profiler.count("saves")
+    profiler.write_timeline(ex.timeline_path())
+    first, mtime = ex._timeline_since(0)
+    assert first["counters"] == {"saves": 1} and mtime > 0
+    assert ex._timeline_since(mtime) == (None, mtime)
+    ex.timeline_path().write_text("{torn")
+    os.utime(ex.timeline_path(), ns=(mtime + 10**9, mtime + 10**9))
+    assert ex._timeline_since(mtime) == (None, mtime)   # retried next beat
+
+
+def _session_with_one_worker():
+    from tony_tpu.conf import TonyConfig
+    from tony_tpu.rpc import ApplicationRpcHandler
+    from tony_tpu.session import TonySession
+
+    session = TonySession(TonyConfig({"tony.worker.instances": "1",
+                                      "tony.application.executes": "x"}),
+                          app_id="app_test")
+    session.on_registered("worker", 0, "127.0.0.1", 1)
+    return session, ApplicationRpcHandler(session)
+
+
+def test_heartbeat_and_result_rpcs_carry_the_timeline():
+    session, handler = _session_with_one_worker()
+    assert handler.rpc_heartbeat("worker", 0) is True
+    assert session.task("worker", 0).timeline is None
+    handler.rpc_heartbeat("worker", 0, timeline={"spans": [1]},
+                          published={"version": 3, "step": 40})
+    task = session.task("worker", 0)
+    assert task.timeline == {"spans": [1]}
+    assert task.published == {"version": 3, "step": 40}
+    handler.rpc_heartbeat("worker", 0)                 # keeps the last one
+    assert task.timeline == {"spans": [1]}
+    handler.rpc_register_execution_result(
+        "worker", 0, 0, timeline={"spans": [1, 2]})
+    assert task.timeline == {"spans": [1, 2]}
+    # A task the AM already marked terminal still delivers its last word.
+    session.kill_remaining("test")
+    handler.rpc_register_execution_result(
+        "worker", 0, constants.EXIT_KILLED, timeline={"spans": [1, 2, 3]})
+    assert task.timeline == {"spans": [1, 2, 3]}
+
+
+def test_task_timeline_survives_log_rotation(tmp_path):
+    handler = ev.EventHandler(tmp_path, "app_rot", max_bytes=4000)
+    handler.task_timeline("worker", 0, {"spans": [{"name": "tony:restore"}]})
+    for i in range(200):
+        handler.task_metrics("worker", 0, {"cpu_pct": float(i)})
+    assert handler.rotations > 0
+    handler.close()
+    [job] = ev.list_jobs(tmp_path)
+    kept = [r for r in ev.read_events(job["path"])
+            if r["type"] == ev.TASK_TIMELINE]
+    assert len(kept) == 1
+    assert kept[0]["payload"]["timeline"]["spans"][0]["name"] == \
+        "tony:restore"
+
+
+def _job_detail(job):
+    from tony_tpu.history import job_detail
+
+    history = Path(job.am.job_dir) / "history"
+    [jhist] = (history / "finished").glob("*.jhist")
+    return job_detail({"app_id": job.am.app_id, "state": "finished",
+                       "path": str(jhist), "metadata": {}})
+
+
+def test_submitted_jobs_timeline_reaches_tony_history(tmp_path):
+    """timeline.json -> register_execution_result -> TASK_TIMELINE ->
+    history.job_detail["timelines"], through a real executor."""
+    from tony_tpu.history import render_show
+
+    job = MiniPod(tmp_path).run({
+        "tony.application.framework": "jax",
+        "tony.worker.instances": "1",
+        "tony.application.executes": "python timeline_train.py",
+        "tony.task.max-missed-heartbeats": "200",
+    }, src_dir=WORKLOADS, timeout=180)
+    assert job.exit_code == 0, job.session.final_message
+    detail = _job_detail(job)
+    timeline = detail["timelines"]["worker:0"]
+    names = [s["name"] for s in timeline["spans"]]
+    assert names[:2] == ["tony:dist_initialize", "tony:create_train_state"]
+    assert "tony:restore" in names
+    restore = next(s for s in timeline["spans"]
+                   if s["name"] == "tony:restore")
+    assert restore["attrs"] == {"step": None, "bytes": 0}
+    c = timeline["counters"]
+    # Compiled or, with jax's persistent cache warm, loaded.
+    assert c.get("programs_compiled", 0) + c.get("programs_loaded", 0) >= 1
+    assert c["saves"] == 1 and c["save_stall_s"] > 0
+    assert any(b["kind"] in ("compile", "load") for b in timeline["builds"])
+    shown = render_show(detail)
+    assert "task start timelines:" in shown
+    assert "tony:create_train_state" in shown
+    assert "program(s) built or loaded" in shown
+
+
+@pytest.fixture(scope="module")
+def tiny_ckpt(tmp_path_factory):
+    """A committed llama-tiny checkpoint for the replica to restore."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from tony_tpu import ckpt, train
+    from tony_tpu.models import get_model
+
+    root = tmp_path_factory.mktemp("ckpt")
+    state = train.create_train_state(
+        get_model("llama-tiny"), optax.adamw(1e-3),
+        jnp.zeros((2, 16), jnp.int32), jax.random.PRNGKey(0))
+    mgr = ckpt.AsyncCheckpointer(str(root))
+    mgr.save(state, step=1, block=True)
+    mgr.close()
+    return root
+
+
+def serve_props(ckpt_dir, **over) -> dict:
+    """What `tony serve --model llama-tiny --ckpt_dir ...` writes."""
+    from tony_tpu import conf as conf_mod
+
+    props = {
+        "tony.application.framework": "standalone",
+        conf_mod.APPLICATION_STOP_ON_FAILURE: "false",
+        conf_mod.instances_key("serve"): "1",
+        conf_mod.command_key("serve"): "python -m tony_tpu.serve.replica",
+        conf_mod.SERVE_MODEL: "llama-tiny",
+        conf_mod.SERVE_CKPT_DIR: str(ckpt_dir),
+        conf_mod.SERVE_CTX_MAX: "64",
+        conf_mod.SERVE_BLOCK_SIZE: "8",
+        conf_mod.SERVE_MAX_RUNNING: "4",
+        "tony.task.max-missed-heartbeats": "400",
+    }
+    props.update(over)
+    return props
+
+
+def wait_for_replica(job, timeout=180.0):
+    """The serve task's heartbeat carries its RPC port once it listens."""
+    def port():
+        if job.session is None:
+            return None
+        return job.session.task("serve", 0).serve_metrics.get("rpc_port")
+    return int(job.wait_for(port, timeout=timeout, what="replica listening"))
+
+
+def test_a_killed_serve_task_has_delivered_its_timeline(tmp_path, tiny_ckpt):
+    """`tony kill` SIGKILLs executor and replica together: no exit RPC.
+    The timeline written at the end of set-up went out with a heartbeat."""
+    job = MiniPod(tmp_path).submit(serve_props(tiny_ckpt))
+    try:
+        wait_for_replica(job)
+        job.wait_for(lambda: job.session.task("serve", 0).timeline,
+                     timeout=30, what="timeline relayed by a heartbeat")
+    finally:
+        job.kill()
+    job.wait(60)
+    detail = _job_detail(job)
+    task = next(t for t in detail["tasks"]
+                if (t["job_type"], t["index"]) == ("serve", 0))
+    assert task["status"] == "KILLED"
+    timeline = detail["timelines"]["serve:0"]
+    names = [s["name"] for s in timeline["spans"]]
+    assert "tony:restore" in names
+    restore = next(s for s in timeline["spans"]
+                   if s["name"] == "tony:restore")
+    assert restore["attrs"]["step"] == 1 and restore["attrs"]["bytes"] > 0
+    assert timeline["counters"]["programs_lowered"] >= 0
+
+
+def test_tony_profile_captures_a_live_replicas_serve_spans(tmp_path,
+                                                           tiny_ckpt):
+    """A `tony serve` job with tony.task.profiler.enabled gets a profiler
+    port (a replica is a "standalone" task), the replica listens on it,
+    and a capture taken while it decodes holds the serve:* spans."""
+    from tony_tpu.rpc import RpcClient
+
+    if profiler._trace_fn() is None:
+        pytest.skip("no profiler client (xprof / tensorflow) importable")
+    job = MiniPod(tmp_path).submit(
+        serve_props(tiny_ckpt, **{"tony.task.profiler.enabled": "true"}))
+    stop = threading.Event()
+    try:
+        port = wait_for_replica(job)
+        endpoints = profiler.endpoints_from_callback_info(
+            job.session.task_callback_info)
+        assert list(endpoints) == ["serve:0"]
+        if not profiler._wait_reachable(endpoints["serve:0"], 30.0):
+            pytest.skip("the jax profiler server never bound its port here")
+
+        def traffic():
+            with RpcClient(f"127.0.0.1:{port}", timeout=60) as c:
+                while not stop.is_set():
+                    c.call("generate", tokens=[1, 2, 3, 4, 5],
+                           max_new_tokens=12)
+
+        # Build the prefill and decode programs first: a span that is
+        # open when the capture ends (a launch that compiles) is lost.
+        with RpcClient(f"127.0.0.1:{port}", timeout=120) as c:
+            c.call("generate", tokens=[1, 2, 3, 4, 5], max_new_tokens=12)
+        t = threading.Thread(target=traffic, daemon=True)
+        t.start()
+        got = profiler.collect_traces(endpoints, tmp_path / "hist",
+                                      job.am.app_id, duration_ms=1500,
+                                      wait_reachable_s=5.0)
+        stop.set()
+        t.join(60)
+        assert not t.is_alive()
+        assert got, "no trace captured"
+        names = set(host_events(got[0]))
+        assert {"serve:admit", "serve:build_inputs", "serve:launch",
+                "serve:readback", "serve:emit"} <= names, sorted(
+                    n for n in names if ":" in n)
+    finally:
+        stop.set()
+        job.kill()
+    job.wait(60)
+
+
+# -- the hooks in the program ----------------------------------------------
+
+def test_train_loop_puts_every_step_on_a_profiler_trace(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from tony_tpu import train
+    from tony_tpu.models import get_model
+
+    state = train.create_train_state(
+        get_model("llama-tiny"), optax.adamw(1e-3),
+        jnp.zeros((2, 16), jnp.int32), jax.random.PRNGKey(0))
+    step = train.make_train_step(
+        loss_of=lambda logits, b: train.next_token_loss(logits, b["x"]))
+    batches = [{"x": jnp.full((2, 16), i, jnp.int32)} for i in range(3)]
+    seen = []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        state, metrics = train.train_loop(
+            state, step, batches=iter(batches), save_final=False,
+            on_step=lambda n, m: seen.append(n))
+        jax.block_until_ready(metrics["loss"])
+    finally:
+        jax.profiler.stop_trace()
+    names = host_events(tmp_path)
+    assert seen == [1, 2, 3]
+    assert names.count("train_step") == 3
+    assert names.count("train:on_step") == 3
+    # Three batches and the call that finds the iterator exhausted.
+    assert names.count("train:next_batch") == 4
+    assert "train:save" not in names and "train:drain_poll" not in names
+    assert [s["name"] for s in profiler.timeline()["spans"]] == \
+        ["tony:create_train_state"]
+
+
+def test_device_scopes_are_in_the_programs_the_step_and_kernels_lower_to():
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from tony_tpu import train
+    from tony_tpu.models import get_model
+    from tony_tpu.ops import flash_attention
+
+    state = train.create_train_state(
+        get_model("llama-tiny"), optax.adamw(1e-3),
+        jnp.zeros((2, 16), jnp.int32), jax.random.PRNGKey(0))
+    step = train.make_train_step(
+        loss_of=lambda logits, b: train.next_token_loss(logits, b["x"]),
+        donate=False)
+    text = step.lower(state, {"x": jnp.zeros((2, 16), jnp.int32)}).as_text(
+        debug_info=True)
+    for scope in ("embed", "mlp", "lm_head", "loss", "optimizer"):
+        assert f"/{scope}/" in text or f"({scope})" in text, scope
+    q = jnp.zeros((1, 2, 128, 64), jnp.bfloat16)
+    grad = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=True).astype(jnp.float32).sum(),
+        (0, 1, 2)))
+    text = grad.lower(q, q, q).as_text(debug_info=True)
+    for scope in ("attn_fwd", "attn_bwd_dq", "attn_bwd_dkv"):
+        assert scope in text, scope
+
+
+def test_profiler_server_failure_is_logged_with_its_port(monkeypatch,
+                                                         caplog):
+    import jax
+
+    from tony_tpu import distributed as dist
+
+    def refuse(port):
+        raise RuntimeError("address in use")
+
+    monkeypatch.setattr(jax.profiler, "start_server", refuse)
+    monkeypatch.setenv(constants.ENV_PROFILER_PORT, "9431")
+    with caplog.at_level(logging.INFO, logger="tony_tpu.distributed"):
+        dist._maybe_start_profiler()
+    assert "9431" in caplog.text and "address in use" in caplog.text
+    caplog.clear()
+    monkeypatch.setattr(jax.profiler, "start_server", lambda port: None)
+    with caplog.at_level(logging.INFO, logger="tony_tpu.distributed"):
+        dist._maybe_start_profiler()
+    assert "listening on port 9431" in caplog.text
+
+
+@pytest.mark.parametrize("framework", ["jax", "standalone"])
+def test_a_profiled_job_gives_every_task_a_profiler_port(framework):
+    from tony_tpu.conf import TonyConfig
+    from tony_tpu.runtime import TaskContext, get_framework
+
+    def ctx(enabled, port=None):
+        conf = TonyConfig({"tony.serve.instances": "1",
+                           "tony.task.profiler.enabled": enabled})
+        return TaskContext(conf=conf, job_type="serve", index=0,
+                           cluster_spec={"serve": ["127.0.0.1:1"]},
+                           am_address="127.0.0.1:2", app_id="a",
+                           attempt_id=1, profiler_port=port)
+
+    adapter = get_framework(framework).task_adapter()
+    assert adapter.need_reserve_profiler_port(ctx("true"))
+    assert not adapter.need_reserve_profiler_port(ctx("false"))
+    env = adapter.build_task_env(ctx("true", port=9431))
+    assert env[constants.ENV_PROFILER_PORT] == "9431"
+    assert constants.ENV_PROFILER_PORT not in \
+        adapter.build_task_env(ctx("true"))
+
+
+# -- the serve replica -----------------------------------------------------
+
+@pytest.fixture(scope="module")
+def tiny_engine_parts():
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    from tony_tpu.models import get_model
+
+    model = get_model("llama-tiny", n_layers=2)
+    params = nn.unbox(model.init(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 16), jnp.int32)))["params"]
+    return model, jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16) if a.dtype == jnp.float32 else a,
+        params)
+
+
+def make_engine(parts, **kw):
+    from tony_tpu.serve import ServeEngine
+
+    model, params = parts
+    return ServeEngine(model, params, ctx_max=64, block_size=8, q_block=16,
+                       decode_buckets=(2, 4), max_running=4, **kw)
+
+
+def test_completions_carry_one_time_per_token(tiny_engine_parts):
+    from tony_tpu.serve.engine import Request
+
+    eng = make_engine(tiny_engine_parts)
+    eng.submit(Request(rid="a", tokens=[1, 2, 3, 4, 5], max_new_tokens=6))
+    eng.submit(Request(rid="b", tokens=[7, 8, 9], max_new_tokens=1))
+    done = {c.rid: c for c in eng.run()}
+    for rid, n in (("a", 6), ("b", 1)):
+        c = done[rid]
+        assert len(c.tokens) == n == len(c.token_s)
+        assert all(a <= b for a, b in zip(c.token_s, c.token_s[1:]))
+        assert 0 < c.token_s[0] and c.token_s[-1] <= c.latency_s
+        wire = c.wire()
+        assert len(wire["token_ms"]) == n
+        assert wire["token_ms"][-1] <= wire["latency_ms"]
+        json.dumps(wire)
+
+
+def test_token_ms_rides_rpc_generate_and_the_router(tiny_engine_parts):
+    from tony_tpu.serve.engine import Completion, EngineFront
+    from tony_tpu.serve.replica import _ReplicaRpcHandler
+    from tony_tpu.serve.router import RequestRouter, _wire_completion
+
+    front = EngineFront(make_engine(tiny_engine_parts))
+
+    class Rep:                      # what _ReplicaRpcHandler fronts
+        engine = front.engine
+
+        def generate(self, tokens, max_new_tokens, rid=None, conv=None,
+                     tenant=None):
+            return front.generate(tokens, max_new_tokens, rid=rid)
+
+    wire = _ReplicaRpcHandler(Rep()).rpc_generate([1, 2, 3], 5, rid="r1")
+    assert len(wire["token_ms"]) == 5 == len(wire["tokens"])
+    assert wire["token_ms"] == sorted(wire["token_ms"])
+    # The router passes a replica's wire dict through untouched, and
+    # turns an in-process Completion into the same shape.
+    assert _wire_completion(wire, "r1") is wire
+    router = RequestRouter(block_size=8)
+    router.upsert_replica("rep0", client=Rep())
+    routed = router.dispatch([1, 2, 3], 4, rid="r2")
+    assert len(routed["token_ms"]) == 4 and routed["replica"] == "rep0"
+    old = Completion(rid="x", prompt=[1], tokens=[2], logits=None,
+                     latency_s=0.5)           # built by older code
+    assert old.wire()["token_ms"] == []
+    assert _wire_completion(old, "x")["token_ms"] == []
+
+
+def test_engine_stats_carry_the_new_counters_into_serve_window(
+        tiny_engine_parts, tmp_path):
+    from tony_tpu import util
+    from tony_tpu.serve.engine import Request
+
+    profiler.watch_builds()
+    eng = make_engine(tiny_engine_parts)
+    eng.restore_s = 1.5
+    assert eng.warm() == 2 and eng.warm_s > 0
+    eng.submit(Request(rid="a", tokens=[1, 2, 3], max_new_tokens=2))
+    eng.run()
+    stats = eng.stats()
+    assert stats["programs_built"] >= 2 and stats["build_s"] > 0
+    assert stats["restore_s"] == 1.5 and stats["warm_s"] == eng.warm_s
+    assert stats["memory_peak_bytes"] >= 0
+    assert [s["name"] for s in profiler.timeline()["spans"]] == ["tony:warm"]
+    # ... and ride rpc_serve_stats -> heartbeat -> SERVE_WINDOW unchanged.
+    beat = util.normalize_serve_telemetry(json.loads(json.dumps(stats)))
+    handler = ev.EventHandler(tmp_path, "app_sw")
+    handler.serve_window("serve", 0, beat)
+    handler.close()
+    [job] = ev.list_jobs(tmp_path)
+    [window] = [r["payload"]["stats"] for r in ev.read_events(job["path"])
+                if r["type"] == ev.SERVE_WINDOW]
+    for key in ("programs_built", "build_s", "restore_s", "warm_s",
+                "memory_peak_bytes"):
+        assert window[key] == stats[key], key

@@ -973,6 +973,9 @@ class ApplicationMaster:
                     self.events.task_finished(
                         t.job_type, t.index, t.status.value, t.exit_code,
                         t.diagnostics, t.metrics)
+                    if t.timeline:
+                        self.events.task_timeline(t.job_type, t.index,
+                                                  t.timeline)
         # Checkpoint plane: what the executors reported committed this
         # attempt (heartbeat piggyback) — the step the NEXT attempt's
         # restore_on_start will resume from after a gang restart.

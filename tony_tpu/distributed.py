@@ -13,10 +13,13 @@ c10d / Gloo rendezvous.
 
 from __future__ import annotations
 
+import logging
 import os
 from typing import Optional, Sequence
 
-from tony_tpu import constants
+from tony_tpu import constants, profiler
+
+_log = logging.getLogger(__name__)
 
 
 def env_spec() -> Optional[tuple[str, int, int]]:
@@ -30,16 +33,21 @@ def env_spec() -> Optional[tuple[str, int, int]]:
     return addr, int(n), int(pid)
 
 
+@profiler.span("tony:dist_initialize")
 def initialize(local_device_ids: Optional[Sequence[int]] = None) -> bool:
     """Bring up the JAX coordination service from TonY env. Returns True if
     multi-process init happened, False for the single-process fallback.
     Also starts the per-task profiler server when the JAXRuntime enabled it
     (``tony.task.profiler.enabled`` — SURVEY.md §5.1). As the training
     entry it also turns on jax's persistent compile cache
-    (:func:`tony_tpu.util.enable_compile_cache`)."""
+    (:func:`tony_tpu.util.enable_compile_cache`) and the program's build
+    counters (:func:`tony_tpu.profiler.watch_builds`); the whole call —
+    the jax import included, where this is the first to need it — is the
+    set-up span ``tony:dist_initialize``."""
     from tony_tpu.util import enable_compile_cache
 
     enable_compile_cache()
+    profiler.watch_builds()
     _maybe_start_profiler()
     spec = env_spec()
     if spec is None:
@@ -63,15 +71,18 @@ def initialize(local_device_ids: Optional[Sequence[int]] = None) -> bool:
 
 def _maybe_start_profiler() -> None:
     """``jax.profiler.start_server`` on the port the JAXRuntime assigned —
-    reachable through ``tony proxy``/TensorBoard for live traces."""
+    what ``tony profile`` and ``tony proxy``/TensorBoard capture from.
+    Logs once either way: the port, or why nothing listens on it."""
     port = os.environ.get(constants.ENV_PROFILER_PORT)
     if not port:
         return
     import jax
     try:
         jax.profiler.start_server(int(port))
-    except Exception:  # pragma: no cover — port race; profiling is advisory
-        pass
+    except Exception as e:  # noqa: BLE001 — port race; profiling is advisory
+        _log.warning("profiler server not started on port %s: %s", port, e)
+    else:
+        _log.info("profiler server listening on port %s", port)
 
 
 def process_id() -> int:

@@ -69,6 +69,11 @@ RESIZE = "RESIZE"
 # victims.
 PUBLISH = "PUBLISH"
 SWAP = "SWAP"
+# Where a task's start went (tony_tpu.profiler): its set-up spans, every
+# program it built or loaded, and its counters — one record per task per
+# attempt, written when the attempt ends. Low-rate: never a rotation
+# victim.
+TASK_TIMELINE = "TASK_TIMELINE"
 
 _METADATA = "METADATA"
 
@@ -194,6 +199,13 @@ class EventHandler:
         self.emit(TASK_FINISHED, job_type=job_type, index=index,
                   status=status, exit_code=exit_code,
                   diagnostics=diagnostics, metrics=metrics or {})
+
+    def task_timeline(self, job_type: str, index: int,
+                      timeline: Dict[str, Any]) -> None:
+        """What the task's ``timeline.json`` held when the executor last
+        relayed it, verbatim."""
+        self.emit(TASK_TIMELINE, job_type=job_type, index=index,
+                  timeline=dict(timeline))
 
     def application_finished(self, status: str, message: str = "") -> None:
         self.emit(APPLICATION_FINISHED, status=status, message=message)

@@ -36,7 +36,7 @@ import time
 from pathlib import Path
 from typing import Dict, List, Optional
 
-from tony_tpu import chaos, constants
+from tony_tpu import chaos, constants, profiler
 from tony_tpu import conf as conf_mod
 from tony_tpu import util
 from tony_tpu.conf import TonyConfig
@@ -219,6 +219,30 @@ class TaskExecutor:
         loop piggybacks whatever appears there to the AM."""
         return self.log_dir / "serve-stats.json"
 
+    def timeline_path(self) -> Path:
+        """The task's set-up timeline (``tony_tpu.profiler``): the user
+        process derives this path from ``TONY_SERVE_STATS`` and rewrites
+        the file at the end of set-up, when ``train_loop`` returns and at
+        exit. The heartbeat relays it whenever it changed (a task that is
+        killed never reaches the exit RPC); the exit RPC relays the last
+        word."""
+        return self.serve_stats_path().with_name(profiler.TIMELINE_FILE)
+
+    def _timeline_since(self, mtime_ns: int
+                        ) -> tuple[Optional[Dict[str, object]], int]:
+        """(the timeline file's content, its mtime_ns) if it was rewritten
+        since ``mtime_ns``, else (None, ``mtime_ns``). Failure-silent: it
+        rides the heartbeat."""
+        path = self.timeline_path()
+        try:
+            now_ns = path.stat().st_mtime_ns
+        except OSError:
+            return None, mtime_ns
+        if now_ns == mtime_ns:
+            return None, mtime_ns
+        out = profiler.read_timeline(path)
+        return out, (now_ns if out is not None else mtime_ns)
+
     def drain_file_path(self) -> Path:
         """The per-container drain flag: the executor exports this path
         (``TONY_DRAIN_FILE``) into the user env and CREATES the file when
@@ -380,6 +404,7 @@ class TaskExecutor:
                 return None
 
         failures = 0
+        timeline_seen = 0    # the timeline file's mtime_ns as last delivered
         try:
             while not self._hb_stop.wait(interval_s):
                 if chaos.drop_heartbeat():
@@ -398,9 +423,14 @@ class TaskExecutor:
                     pub = published()
                     if pub is not None:
                         extras["published"] = pub
+                    timeline, mtime_ns = self._timeline_since(
+                        timeline_seen)
+                    if timeline is not None:
+                        extras["timeline"] = timeline
                     resp = hb_client.call("heartbeat", job_type=self.job_type,
                                           index=self.index, **extras)
                     failures = 0
+                    timeline_seen = mtime_ns       # delivered
                     if isinstance(resp, dict) and resp.get("drain"):
                         try:
                             drain_path.touch()
@@ -542,13 +572,15 @@ class TaskExecutor:
             env[constants.ENV_SERVE_STATS] = str(
                 self.serve_stats_path().resolve())
             drain_path = self.drain_file_path()
-            try:
-                # Incremental-grant reuse relaunches into this same sandbox:
-                # a drain flag left by the PREVIOUS drain must not instantly
-                # drain the fresh worker.
-                drain_path.unlink()
-            except OSError:
-                pass
+            # Incremental-grant reuse relaunches into this same sandbox:
+            # a drain flag left by the PREVIOUS drain must not instantly
+            # drain the fresh worker, nor its timeline be taken for this
+            # one's.
+            for stale in (drain_path, self.timeline_path()):
+                try:
+                    stale.unlink()
+                except OSError:
+                    pass
             env[constants.ENV_DRAIN_FILE] = str(drain_path.resolve())
             if self.token:
                 env[ENV_JOB_TOKEN] = self.token
@@ -629,10 +661,15 @@ class TaskExecutor:
                 print(f"[tony-executor] skipping result RPC: {diagnostics}",
                       file=sys.stderr)
                 return exit_code
+            extras: Dict[str, object] = {}
+            timeline, _ = self._timeline_since(0)
+            if timeline is not None:
+                extras["timeline"] = timeline
             try:
                 self.client.call("register_execution_result",
                                  job_type=self.job_type, index=self.index,
-                                 exit_code=exit_code, diagnostics=diagnostics)
+                                 exit_code=exit_code, diagnostics=diagnostics,
+                                 **extras)
             except Exception as e:
                 print(f"[tony-executor] result RPC failed: {e}",
                       file=sys.stderr)

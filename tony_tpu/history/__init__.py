@@ -206,6 +206,13 @@ def job_detail(job: Dict[str, Any]) -> Dict[str, Any]:
                     for r in records if r["type"] == ev.PUBLISH]
     swaps = [dict(r["payload"], timestamp=r["timestamp"])
              for r in records if r["type"] == ev.SWAP]
+    # Where each task's start went (PR 25): its set-up spans, build
+    # records and counters as the task itself recorded them
+    # (tony_tpu.profiler); the last attempt's record wins.
+    task_timelines = {
+        f"{r['payload']['job_type']}:{r['payload']['index']}":
+        r["payload"].get("timeline") or {}
+        for r in records if r["type"] == ev.TASK_TIMELINE}
     serve_windows = {tid: _downsample(s) for tid, s in serve_windows.items()}
     train_steps = {tid: _downsample(s) for tid, s in train_steps.items()}
     # Per-tenant SLO rollup from each task's NEWEST window (qps/queued/
@@ -259,6 +266,7 @@ def job_detail(job: Dict[str, Any]) -> Dict[str, Any]:
         "scale_decisions": scale_decisions,
         "scale_replay": scale_replay,
         "traces": list_traces(history_root, job["app_id"]),
+        "timelines": task_timelines,
         "submit_to_running_s": (all_running or {}).get(
             "payload", {}).get("submit_to_running_s"),
         "events": records,
@@ -383,11 +391,40 @@ def render_show(detail: Dict[str, Any]) -> str:
         for tid, files in sorted(detail["traces"].items()):
             total = sum(f["bytes"] for f in files)
             out.append(f"    {tid}: {len(files)} file(s), {total} bytes")
+    if detail.get("timelines"):
+        out.append("  task start timelines:")
+        for tid, tl in sorted(detail["timelines"].items()):
+            out += _render_timeline(tid, tl)
     out.append("  events:")
     for r in detail["events"]:
         when = time.strftime("%H:%M:%S", time.localtime(r["timestamp"]))
         out.append(f"    {when} {r['type']}")
     return "\n".join(out)
+
+
+def _render_timeline(tid: str, tl: Dict[str, Any]) -> List[str]:
+    """One task's set-up spans in start order (nested ones indented
+    under their parent) and what it built, as text lines."""
+    from tony_tpu.profiler import build_totals
+
+    spans = sorted(tl.get("spans") or [], key=lambda s: s.get("t0", 0.0))
+    c = tl.get("counters") or {}
+    totals = build_totals(c)
+    out = [f"    {tid}: {len(spans)} span(s); "
+           f"{totals['programs_built']:.0f} program(s) "
+           f"built or loaded ({c.get('programs_compiled', 0):.0f} compiled, "
+           f"{c.get('programs_loaded', 0):.0f} from the cache) in "
+           f"{totals['build_s']:.2f}s of tracing, lowering, compiling and "
+           f"loading"]
+    t_first = spans[0]["t0"] if spans else 0.0
+    for sp in spans:
+        attrs = " ".join(f"{k}={v}" for k, v in sorted(
+            (sp.get("attrs") or {}).items()))
+        out.append(f"      {'  ' if sp.get('parent') else ''}"
+                   f"+{sp['t0'] - t_first:.2f}s {sp['name']} "
+                   f"{sp['t1'] - sp['t0']:.2f}s"
+                   + (f" ({attrs})" if attrs else ""))
+    return out
 
 
 def parse_when(s: Optional[str]) -> Optional[float]:

@@ -116,6 +116,7 @@ class MoEMLP(nn.Module):
     a2a_chunks: int = 2
 
     @nn.compact
+    @jax.named_scope("moe")   # the device scope, whatever the module's name
     def __call__(self, x):
         b, t, d = x.shape
         e, f = self.n_experts, self.ffn_hidden

@@ -222,15 +222,17 @@ class Attention(nn.Module):
             # mode="drop": rows whose position falls off the buffer end
             # (the trailing padding rows of a decode block near ctx_max)
             # simply don't write.
-            k_buf = k_buf.at[bidx, pos].set(k_rows, mode="drop")
-            v_buf = v_buf.at[bidx, pos].set(v_rows, mode="drop")
+            with jax.named_scope("attn_kv_scatter"):
+                k_buf = k_buf.at[bidx, pos].set(k_rows, mode="drop")
+                v_buf = v_buf.at[bidx, pos].set(v_rows, mode="drop")
             ctx = k_buf.shape[1]
             from tony_tpu.ops import flash_decode
-            out = flash_decode(
-                q4.transpose(0, 2, 1, 3),
-                k_buf.reshape(b, ctx, nkv, hd).transpose(0, 2, 1, 3),
-                v_buf.reshape(b, ctx, nkv, hd).transpose(0, 2, 1, 3),
-                pos)
+            with jax.named_scope("attn_decode"):
+                out = flash_decode(
+                    q4.transpose(0, 2, 1, 3),
+                    k_buf.reshape(b, ctx, nkv, hd).transpose(0, 2, 1, 3),
+                    v_buf.reshape(b, ctx, nkv, hd).transpose(0, 2, 1, 3),
+                    pos)
             out = out.transpose(0, 2, 1, 3).reshape(b, t, nh * hd)
             return (dense(cfg.dim, ("heads", "embed"), "wo", "o")(out),
                     (k_rows, v_rows))
@@ -383,24 +385,26 @@ class Transformer(nn.Module):
             ambient = jax.sharding.get_abstract_mesh()
             return not ambient.empty and ambient.size > 1
 
-        if _sharded_training():
-            # Sharded multi-device training only — on one device the
-            # one-hot costs ~18 ms/step of uncounted work at the bench
-            # shape (found as a 4.3-MFU-pt regression in r5; the train
-            # harness applies the rules context even unsharded): look up
-            # via one-hot matmul, not gather. The table is (vocab→model,
-            # embed→fsdp)-sharded while activations want batch over
-            # (data, fsdp) — GSPMD reshard s dots cleanly (psum over the
-            # contracted vocab axis + reduce-scatter) but a gather's
-            # embed-fsdp→batch-fsdp transition is an "involuntary full
-            # rematerialization": replicate-then-slice EVERY step, fwd and
-            # transpose (MULTICHIP_r04 tail; VERDICT r4 next-step #3). The
-            # one-hot term is 2·vocab·dim FLOPs/token ≈ 0.6% of a 7B step,
-            # and it rides the MXU.
-            x = jax.nn.one_hot(tokens, cfg.vocab, dtype=cfg.dtype) \
-                @ embed.astype(cfg.dtype)
-        else:
-            x = jnp.take(embed, tokens, axis=0).astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            if _sharded_training():
+                # Sharded multi-device training only — on one device the
+                # one-hot costs ~18 ms/step of uncounted work at the bench
+                # shape (found as a 4.3-MFU-pt regression in r5; the train
+                # harness applies the rules context even unsharded): look
+                # up via one-hot matmul, not gather. The table is
+                # (vocab→model, embed→fsdp)-sharded while activations want
+                # batch over (data, fsdp) — GSPMD reshard s dots cleanly
+                # (psum over the contracted vocab axis + reduce-scatter)
+                # but a gather's embed-fsdp→batch-fsdp transition is an
+                # "involuntary full rematerialization": replicate-then-
+                # slice EVERY step, fwd and transpose (MULTICHIP_r04 tail;
+                # VERDICT r4 next-step #3). The one-hot term is
+                # 2·vocab·dim FLOPs/token ≈ 0.6% of a 7B step, and it
+                # rides the MXU.
+                x = jax.nn.one_hot(tokens, cfg.vocab, dtype=cfg.dtype) \
+                    @ embed.astype(cfg.dtype)
+            else:
+                x = jnp.take(embed, tokens, axis=0).astype(cfg.dtype)
         x = nn.with_logical_constraint(x, ("batch", "act_seq", "act_embed"))
         if positions is None:
             positions = jnp.arange(t)
@@ -473,7 +477,8 @@ class Transformer(nn.Module):
             if targets is not None:
                 return chunked_next_token_xent(x, w, targets,
                                                cfg.xent_chunk, cfg.dtype)
-            logits = (x @ w.astype(cfg.dtype)).astype(jnp.float32)
+            with jax.named_scope("lm_head"):
+                logits = (x @ w.astype(cfg.dtype)).astype(jnp.float32)
             if kv is not None:
                 return logits, new_kv
             return logits

@@ -348,16 +348,17 @@ def _flash_backward_streamed(q, k, v, do, o, lse, causal, scale, block_q, block_
     lse_pin = pl.BlockSpec((None, block_q, _LSE_LANES),
                            lambda g, i, kb: (g, i, 0))
 
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
-                          kv_len=kv_len),
-        grid=(bh, pl.cdiv(t, block_q), pl.cdiv(tk, block_k)),
-        in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin],
-        out_specs=q_pin,
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(qr, kr, vr, dor, outr, lser)
+    with jax.named_scope("attn_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
+                              kv_len=kv_len),
+            grid=(bh, pl.cdiv(t, block_q), pl.cdiv(tk, block_k)),
+            in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin],
+            out_specs=q_pin,
+            out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            interpret=interpret,
+        )(qr, kr, vr, dor, outr, lser)
 
     # dkv grid: (b·hkv, kj, qx) — qx is the flattened (rep, q-block) sweep
     # (k-blocks pinned; dk/dv accumulate across ALL query heads this kv
@@ -380,18 +381,19 @@ def _flash_backward_streamed(q, k, v, do, o, lse, causal, scale, block_q, block_
         lse_str = pl.BlockSpec((None, block_q, _LSE_LANES),
                                lambda g, j, qx: (q_head(g, qx), qx % nqb, 0))
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
-                          nqb=nqb if reps > 1 else 0, kv_len=kv_len),
-        grid=(b * hkv, pl.cdiv(tk, block_k), reps * nqb),
-        in_specs=[q_str, k_pin, k_pin, q_str, q_str, lse_str],
-        out_specs=(k_pin, k_pin),
-        out_shape=(jax.ShapeDtypeStruct((b * hkv, tk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * hkv, tk, d), v.dtype)),
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
-    )(qr, kr, vr, dor, outr, lser)
+    with jax.named_scope("attn_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
+                              nqb=nqb if reps > 1 else 0, kv_len=kv_len),
+            grid=(b * hkv, pl.cdiv(tk, block_k), reps * nqb),
+            in_specs=[q_str, k_pin, k_pin, q_str, q_str, lse_str],
+            out_specs=(k_pin, k_pin),
+            out_shape=(jax.ShapeDtypeStruct((b * hkv, tk, d), k.dtype),
+                       jax.ShapeDtypeStruct((b * hkv, tk, d), v.dtype)),
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)],
+            interpret=interpret,
+        )(qr, kr, vr, dor, outr, lser)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
@@ -623,15 +625,16 @@ def _flash_backward_resident(q, k, v, do, o, lse, causal, scale, block_q, block_
     kv_full = pl.BlockSpec((None, tk, d), lambda g, i: (kv_of(g), 0, 0))
     lse_blk = pl.BlockSpec((None, block_q, _LSE_LANES), lambda g, i: (g, i, 0))
 
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel_resident, block_k=block_k,
-                          causal=causal, scale=scale, kv_len=kv_len),
-        grid=(bh, pl.cdiv(t, block_q)),
-        in_specs=[q_spec, kv_full, kv_full, q_spec, q_spec, lse_blk],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        interpret=interpret,
-    )(qr, kr, vr, dor, outr, lser)
+    with jax.named_scope("attn_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_flash_bwd_dq_kernel_resident, block_k=block_k,
+                              causal=causal, scale=scale, kv_len=kv_len),
+            grid=(bh, pl.cdiv(t, block_q)),
+            in_specs=[q_spec, kv_full, kv_full, q_spec, q_spec, lse_blk],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            interpret=interpret,
+        )(qr, kr, vr, dor, outr, lser)
 
     # dkv grid: (b·hkv, kj, rep) — rep streams in, one at a time, the query
     # heads this kv head serves; dk/dv accumulate in scratch across them.
@@ -644,17 +647,18 @@ def _flash_backward_resident(q, k, v, do, o, lse, causal, scale, block_q, block_
     k_spec = pl.BlockSpec((None, block_k, d), lambda g, j, r: (g, j, 0))
 
     dkv_kernel, dkv_scratch = _dkv_resident_scratch(reps, block_k, d)
-    dk, dv = pl.pallas_call(
-        functools.partial(dkv_kernel, block_q=block_q,
-                          causal=causal, scale=scale, kv_len=kv_len),
-        grid=(b * hkv, pl.cdiv(tk, block_k), reps),
-        in_specs=[q_full, k_spec, k_spec, q_full, q_full, lse_full],
-        out_specs=(k_spec, k_spec),
-        out_shape=(jax.ShapeDtypeStruct((b * hkv, tk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * hkv, tk, d), v.dtype)),
-        scratch_shapes=dkv_scratch,
-        interpret=interpret,
-    )(qr, kr, vr, dor, outr, lser)
+    with jax.named_scope("attn_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(dkv_kernel, block_q=block_q,
+                              causal=causal, scale=scale, kv_len=kv_len),
+            grid=(b * hkv, pl.cdiv(tk, block_k), reps),
+            in_specs=[q_full, k_spec, k_spec, q_full, q_full, lse_full],
+            out_specs=(k_spec, k_spec),
+            out_shape=(jax.ShapeDtypeStruct((b * hkv, tk, d), k.dtype),
+                       jax.ShapeDtypeStruct((b * hkv, tk, d), v.dtype)),
+            scratch_shapes=dkv_scratch,
+            interpret=interpret,
+        )(qr, kr, vr, dor, outr, lser)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
@@ -763,15 +767,16 @@ def _flash_backward_packed_resident(q, k, v, do, o, lse, heads, causal, scale,
     lse_blk = pl.BlockSpec((None, None, block_q, _LSE_LANES),
                            lambda bi, h, i: (bi, h, i, 0))
 
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel_resident, block_k=block_k,
-                          causal=causal, scale=scale, qi_axis=2),
-        grid=(b, heads, pl.cdiv(t, block_q)),
-        in_specs=[q_spec, kv_full, kv_full, q_spec, q_spec, lse_blk],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-        interpret=interpret,
-    )(q, k, v, do, o, lse)
+    with jax.named_scope("attn_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_flash_bwd_dq_kernel_resident, block_k=block_k,
+                              causal=causal, scale=scale, qi_axis=2),
+            grid=(b, heads, pl.cdiv(t, block_q)),
+            in_specs=[q_spec, kv_full, kv_full, q_spec, q_spec, lse_blk],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
+            interpret=interpret,
+        )(q, k, v, do, o, lse)
 
     # dkv grid: (b, hkv, kj, rep) — rep streams the query heads this kv
     # head serves; dk/dv accumulate in scratch (see the kernel docstring).
@@ -783,32 +788,35 @@ def _flash_backward_packed_resident(q, k, v, do, o, lse, heads, causal, scale,
                           lambda bi, hk, j, r: (bi, j, hk))
 
     dkv_kernel, dkv_scratch = _dkv_resident_scratch(reps, block_k, d)
-    dk, dv = pl.pallas_call(
-        functools.partial(dkv_kernel, block_q=block_q,
-                          causal=causal, scale=scale, qi_axis=2),
-        grid=(b, hkv, pl.cdiv(tk, block_k), reps),
-        in_specs=[q_full, k_spec, k_spec, q_full, q_full, lse_full],
-        out_specs=(k_spec, k_spec),
-        out_shape=(jax.ShapeDtypeStruct((b, tk, hkv * d), k.dtype),
-                   jax.ShapeDtypeStruct((b, tk, hkv * d), v.dtype)),
-        scratch_shapes=dkv_scratch,
-        interpret=interpret,
-    )(q, k, v, do, o, lse)
+    with jax.named_scope("attn_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(dkv_kernel, block_q=block_q,
+                              causal=causal, scale=scale, qi_axis=2),
+            grid=(b, hkv, pl.cdiv(tk, block_k), reps),
+            in_specs=[q_full, k_spec, k_spec, q_full, q_full, lse_full],
+            out_specs=(k_spec, k_spec),
+            out_shape=(jax.ShapeDtypeStruct((b, tk, hkv * d), k.dtype),
+                       jax.ShapeDtypeStruct((b, tk, hkv * d), v.dtype)),
+            scratch_shapes=dkv_scratch,
+            interpret=interpret,
+        )(q, k, v, do, o, lse)
     return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret,
            kv_len=None):
-    out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                            interpret, kv_len)
+    with jax.named_scope("attn_fwd"):
+        out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
+                                interpret, kv_len)
     return out
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                kv_len=None):
-    out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                              interpret, kv_len)
+    with jax.named_scope("attn_fwd"):
+        out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
+                                  interpret, kv_len)
     return out, (q, k, v, out, lse)
 
 
@@ -882,16 +890,17 @@ def _flash_backward_packed_streamed(q, k, v, do, o, lse, heads, causal, scale,
     lse_pin = pl.BlockSpec((None, None, block_q, _LSE_LANES),
                            lambda bi, h, i, kb: (bi, h, i, 0))
 
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
-                          qi_axis=2),
-        grid=(b, heads, pl.cdiv(t, block_q), pl.cdiv(tk, block_k)),
-        in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin],
-        out_specs=q_pin,
-        out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(q, k, v, do, o, lse)
+    with jax.named_scope("attn_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
+                              qi_axis=2),
+            grid=(b, heads, pl.cdiv(t, block_q), pl.cdiv(tk, block_k)),
+            in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin],
+            out_specs=q_pin,
+            out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            interpret=interpret,
+        )(q, k, v, do, o, lse)
 
     # dkv grid: (b, hkv, kj, qx) — qx flattens (rep, q-block), q-side
     # streamed innermost; dk/dv accumulate across every query head this
@@ -912,33 +921,36 @@ def _flash_backward_packed_streamed(q, k, v, do, o, lse, heads, causal, scale,
                                lambda bi, hk, j, qx:
                                (bi, hk * reps + qx // nqb, qx % nqb, 0))
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
-                          qi_axis=2, nqb=nqb if reps > 1 else 0),
-        grid=(b, hkv, pl.cdiv(tk, block_k), reps * nqb),
-        in_specs=[q_str, k_pin, k_pin, q_str, q_str, lse_str],
-        out_specs=(k_pin, k_pin),
-        out_shape=(jax.ShapeDtypeStruct((b, tk, hkv * d), k.dtype),
-                   jax.ShapeDtypeStruct((b, tk, hkv * d), v.dtype)),
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
-    )(q, k, v, do, o, lse)
+    with jax.named_scope("attn_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
+                              qi_axis=2, nqb=nqb if reps > 1 else 0),
+            grid=(b, hkv, pl.cdiv(tk, block_k), reps * nqb),
+            in_specs=[q_str, k_pin, k_pin, q_str, q_str, lse_str],
+            out_specs=(k_pin, k_pin),
+            out_shape=(jax.ShapeDtypeStruct((b, tk, hkv * d), k.dtype),
+                       jax.ShapeDtypeStruct((b, tk, hkv * d), v.dtype)),
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)],
+            interpret=interpret,
+        )(q, k, v, do, o, lse)
     return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_packed(q, k, v, heads, causal, scale, block_q, block_k,
                   interpret):
-    out, _ = _flash_forward_packed(q, k, v, heads, causal, scale, block_q,
-                                   block_k, interpret)
+    with jax.named_scope("attn_fwd"):
+        out, _ = _flash_forward_packed(q, k, v, heads, causal, scale,
+                                       block_q, block_k, interpret)
     return out
 
 
 def _flash_packed_fwd(q, k, v, heads, causal, scale, block_q, block_k,
                       interpret):
-    out, lse = _flash_forward_packed(q, k, v, heads, causal, scale,
-                                     block_q, block_k, interpret)
+    with jax.named_scope("attn_fwd"):
+        out, lse = _flash_forward_packed(q, k, v, heads, causal, scale,
+                                         block_q, block_k, interpret)
     return out, (q, k, v, out, lse)
 
 

@@ -28,6 +28,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tony_tpu import profiler
+
 # Mesh axis names, outermost (most DCN-friendly) to innermost (most
 # ICI-bandwidth-hungry). The slice axis IS the DCN boundary: collectives
 # over it cross slices, everything else stays on ICI. Data-parallel axes
@@ -96,7 +98,8 @@ class MeshSpec:
         return n_devices // rest
 
     def build(self, devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
-        devices = list(devices if devices is not None else jax.devices())
+        devices = list(devices if devices is not None
+                       else profiler.backend_devices())
         dp = self.resolved_dp(len(devices))
         shape = (self.slices, dp, self.fsdp, self.pp, self.ep, self.sp,
                  self.tp)

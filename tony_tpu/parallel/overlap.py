@@ -872,9 +872,10 @@ def microbatch_grads(loss_fn: Callable[[Any, Any], Any], params: Any,
                 if sched[b][0] == "rs":
                     a = jax.lax.all_gather(a, rs_axes, tiled=True)[:n]
                 g_bufs.append(a / denom)
-            new_leaves, new_slots, gnorm = fused.region_apply(
-                plan, jax.tree.leaves(params), g_bufs, slots, scal,
-                sharded=zero3 and plan.shard_size > 1)
+            with jax.named_scope("optimizer"):
+                new_leaves, new_slots, gnorm = fused.region_apply(
+                    plan, jax.tree.leaves(params), g_bufs, slots, scal,
+                    sharded=zero3 and plan.shard_size > 1)
             loss = jax.lax.psum(loss, axes) / denom
             aux = jax.lax.psum(aux, axes) / denom
             return (loss, aux,

@@ -1,21 +1,35 @@
-"""Trace collection: fetch device/host traces from live profiler endpoints
-into the job's history dir (SURVEY.md §5.1 — the TPU-build commitment is
-"hook + trace collection to the history dir"; the hook half lives in
-:mod:`tony_tpu.distributed`, this is the collection half).
+"""Observability from inside the program: spans and counters on one
+timeline, the trace-time plan registries, and trace collection.
 
-The reference's equivalent surface is per-framework (TensorBoard reading a
-profile plugin dir); here every rank's user process runs
-``jax.profiler.start_server`` on the port the JAXRuntime assigned, the
-executor pushes ``host:port`` to the AM via ``register_callback_info``, and
-this module pulls a trace from each endpoint over the XLA profiler gRPC
-service into ``<history>/traces/<app_id>/<task_id>/`` — next to the jhist,
-where the history portal lists it.
+Three halves, one module (imports no jax at module level: the executor,
+the AM and the history plane import it too):
 
-Two triggers, both optional:
-
-* ``tony profile <app_id>`` (client-side, any time while the job runs);
-* ``tony.task.profiler.collect-after-s`` (AM-side: one automatic capture
-  N seconds after the gang reaches RUNNING).
+* **Spans and counters** (:func:`span`, :func:`count`,
+  :func:`add_seconds`, :func:`watch_builds`). A span always enters a
+  ``jax.profiler.TraceAnnotation`` when jax is imported, so with a
+  profiler session active it lands on the host plane of the same
+  ``.xplane.pb`` as the device operations, on one clock; with none it
+  costs a TraceMe enter/exit. The few *set-up* spans (:data:`SETUP_SPANS`)
+  and every program jax builds or loads (``watch_builds``) are also kept
+  on a bounded in-memory timeline with process-local counters, and
+  :func:`write_timeline` puts that in ``timeline.json`` beside the stats
+  file the executor names (``TONY_SERVE_STATS``). The executor hands the
+  file to the AM, which logs one ``TASK_TIMELINE`` event per task:
+  ``tony history show`` then says where a task's start went, with no
+  profiler attached. Per-step and per-iteration spans go to the TraceMe
+  only — nothing is appended on the hot path.
+* **Plan registries** (``record_overlap`` ... ``record_locks``): what
+  the planners decided at jit-trace time, last plan per tag wins.
+* **Trace collection** (SURVEY.md §5.1): every task whose job set
+  ``tony.task.profiler.enabled`` runs ``jax.profiler.start_server`` on
+  the port the JAXRuntime assigned (training tasks from
+  ``distributed.initialize``, serve replicas from ``replica.main``), the
+  executor pushes ``host:port`` to the AM via ``register_callback_info``,
+  and :func:`collect_traces` pulls one synchronized trace over the XLA
+  profiler gRPC service into ``<history>/traces/<app_id>/`` — on
+  ``tony profile <app_id>`` (any time while the job runs) or
+  ``tony.task.profiler.collect-after-s`` (AM-side, once, N seconds after
+  the gang reaches RUNNING).
 
 The capture client is xprof's (version-matched to jax's tsl profiler
 service in this image); explicit tracer levels are passed because the
@@ -24,10 +38,18 @@ defaults collect nothing from a remote jax server.
 
 from __future__ import annotations
 
+import atexit
+import contextlib
+import json
 import logging
+import os
 import sys
+import threading
+import time
 from pathlib import Path
 from typing import Dict, List, Optional
+
+from tony_tpu import constants
 
 # Tracer levels: host TraceMe spans + python + device. Without these the
 # remote session returns "no trace data" (measured, not hypothetical).
@@ -36,6 +58,254 @@ _TRACE_OPTIONS = {
     "python_tracer_level": 1,
     "device_tracer_level": 1,
 }
+
+
+# ---------------------------------------------------------------------------
+# Spans and counters. Names are an interface: benchmark readers, `tony
+# history` and the README's Observability section find them by name.
+
+# The set-up spans: kept on the timeline (and so in the job's event log),
+# because they happen before any profiler session can start.
+SETUP_SPANS = frozenset({
+    "tony:dist_initialize", "tony:backend_init", "tony:create_train_state",
+    "tony:restore", "tony:warm"})
+TIMELINE_FILE = "timeline.json"
+MAX_SPANS = 256          # set-up spans kept (a task records a handful)
+MAX_BUILDS = 2048        # build records kept; the counters never stop
+MIN_BUILD_RECORD_S = 1e-3   # a shorter trace or lowering is only counted
+
+# jax.monitoring duration events -> the kind of a build record. Checked
+# against jax 0.9.0: backend_compile_duration wraps compile_or_get_cached,
+# so it also fires (after cache_retrieval_time_sec, on the same thread)
+# for a program that was only loaded from the persistent cache.
+_BUILD_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "load",
+}
+_BUILD_COUNTERS = {"trace": ("programs_traced", "trace_s"),
+                   "lower": ("programs_lowered", "lower_s"),
+                   "compile": ("programs_compiled", "compile_s"),
+                   "load": ("programs_loaded", "load_s")}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+                 "/jax/compilation_cache/cache_misses": "cache_misses"}
+
+
+class _Timeline:
+    """The process's set-up spans, build records and counters."""
+
+    def __init__(self) -> None:
+        self.lock = threading.Lock()
+        self.spans: List[Dict[str, object]] = []
+        self.builds: List[Dict[str, object]] = []
+        self.builds_dropped = 0
+        self.counters: Dict[str, float] = {}
+        self.local = threading.local()   # open set-up spans; pending load
+
+    def open_spans(self) -> List[str]:
+        stack = getattr(self.local, "stack", None)
+        if stack is None:
+            stack = self.local.stack = []
+        return stack
+
+
+_TIMELINE = _Timeline()
+_armed: set = set()      # "exit": atexit write registered; "builds": listening
+
+
+class span(contextlib.ContextDecorator):
+    """``with span(name, **attrs):`` (or ``@span(name)`` on a function) —
+    a ``jax.profiler.TraceAnnotation`` when jax is imported, and for the
+    names in :data:`SETUP_SPANS` also one timeline record ``{name, t0,
+    t1, parent, attrs}`` (epoch seconds; ``parent`` is the enclosing
+    set-up span of this thread). ``attrs`` may be filled in before the
+    span ends (``sp.attrs.update(step=...)``); the TraceMe sees only what
+    it was given at the start."""
+
+    def __init__(self, name: str, **attrs) -> None:
+        self.name, self.attrs = name, attrs
+        self._ann = None
+
+    def _recreate_cm(self) -> "span":
+        return span(self.name, **self.attrs)
+
+    def __enter__(self) -> "span":
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        if prof is not None:
+            self._ann = prof.TraceAnnotation(self.name, **self.attrs)
+            self._ann.__enter__()
+        if self.name in SETUP_SPANS:
+            stack = _TIMELINE.open_spans()
+            self._parent = stack[-1] if stack else None
+            stack.append(self.name)
+            self._t0 = time.time()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self.name in SETUP_SPANS:
+            t1 = time.time()
+            _TIMELINE.open_spans().pop()
+            with _TIMELINE.lock:
+                if len(_TIMELINE.spans) < MAX_SPANS:
+                    _TIMELINE.spans.append({
+                        "name": self.name, "t0": self._t0, "t1": t1,
+                        "parent": self._parent, "attrs": dict(self.attrs)})
+            _arm_exit_write()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+def backend_devices() -> list:
+    """``jax.devices()``; where this call is the one that starts the
+    backend (the first in the process), it is the set-up span
+    ``tony:backend_init`` — TPU start-up is seconds, and jax 0.9.0
+    reports it through no ``jax.monitoring`` event."""
+    import jax
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        return jax.devices()
+    with span("tony:backend_init"):
+        return jax.devices()
+
+
+def count(name: str, n: float = 1) -> None:
+    """Bump the process-local counter ``name``."""
+    with _TIMELINE.lock:
+        _TIMELINE.counters[name] = _TIMELINE.counters.get(name, 0) + n
+
+
+def add_seconds(name: str, s: float) -> None:
+    """Add ``s`` seconds to the process-local sum ``name``."""
+    count(name, float(s))
+
+
+def counters() -> Dict[str, float]:
+    with _TIMELINE.lock:
+        return dict(_TIMELINE.counters)
+
+
+def _on_build(event: str, secs: float, **_) -> None:
+    kind = _BUILD_EVENTS.get(event)
+    if kind is None:
+        return
+    if kind == "load":
+        _TIMELINE.local.loaded = True
+    elif kind == "compile" and getattr(_TIMELINE.local, "loaded", False):
+        _TIMELINE.local.loaded = False      # the load was the build
+        return
+    n_name, s_name = _BUILD_COUNTERS[kind]
+    count(n_name)
+    count(s_name, secs)
+    if secs < MIN_BUILD_RECORD_S and kind in ("trace", "lower"):
+        return          # every eager jnp call traces a jit: thousands
+    with _TIMELINE.lock:
+        if len(_TIMELINE.builds) < MAX_BUILDS:
+            _TIMELINE.builds.append(
+                {"t": time.time(), "kind": kind, "s": secs})
+        else:
+            _TIMELINE.builds_dropped += 1
+
+
+def _on_cache_event(event: str, **_) -> None:
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        count(name)
+
+
+def watch_builds() -> None:
+    """Count every program jax traces, lowers, compiles or loads from its
+    persistent cache in this process (``programs_traced`` /
+    ``programs_lowered`` / ``programs_compiled`` / ``programs_loaded``,
+    their ``trace_s`` / ``lower_s`` / ``compile_s`` / ``load_s`` sums,
+    ``cache_hits`` / ``cache_misses``) and keep one ``build`` record
+    ``{t, kind, s}`` (``t`` = when it ended) on the timeline for each
+    compile and load, and for each trace and lowering of a millisecond or
+    more.
+    One ``jax.monitoring`` listener pair, installed once a process
+    (``distributed.initialize``, ``replica.main``); it runs only when
+    something is built, so a steady window pays nothing. A nested jit's
+    trace is inside its caller's: ``trace_s`` counts it twice, the
+    records' intervals do not hide it."""
+    if "builds" in _armed:
+        return
+    _armed.add("builds")
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_build)
+    jax.monitoring.register_event_listener(_on_cache_event)
+    _arm_exit_write()
+
+
+def build_totals(c: Optional[Dict[str, float]] = None) -> Dict[str, float]:
+    """``{programs_built, build_s}`` of a counters dict (this process's so
+    far, by default): programs compiled or loaded, and the seconds of
+    tracing, lowering, compiling and loading."""
+    c = counters() if c is None else c
+    return {"programs_built": c.get("programs_compiled", 0)
+            + c.get("programs_loaded", 0),
+            "build_s": sum(c.get(k, 0.0) for k in
+                           ("trace_s", "lower_s", "compile_s", "load_s"))}
+
+
+def timeline() -> Dict[str, object]:
+    """A copy of what this process has recorded so far."""
+    with _TIMELINE.lock:
+        return {"pid": os.getpid(), "written": time.time(),
+                "spans": [dict(s) for s in _TIMELINE.spans],
+                "builds": [dict(b) for b in _TIMELINE.builds],
+                "builds_dropped": _TIMELINE.builds_dropped,
+                "counters": dict(_TIMELINE.counters)}
+
+
+def reset_timeline() -> None:
+    """Forget spans, build records and counters (tests); the listeners
+    and the exit hook stay installed."""
+    global _TIMELINE
+    _TIMELINE = _Timeline()
+
+
+def timeline_path() -> Optional[Path]:
+    """``timeline.json`` beside the stats file the executor named, or
+    None outside a tony task."""
+    stats = os.environ.get(constants.ENV_SERVE_STATS)
+    return Path(stats).parent / TIMELINE_FILE if stats else None
+
+
+def write_timeline(path: Optional[str | Path] = None) -> Optional[Path]:
+    """Publish :func:`timeline` through stage-and-rename (the stats
+    file's idiom). Called at the end of set-up, when ``train_loop``
+    returns and at exit; advisory — an unwritable path never fails the
+    task. Returns the path written, or None."""
+    target = Path(path) if path else timeline_path()
+    if target is None:
+        return None
+    tmp = target.with_name(f"{target.name}.tmp.{os.getpid()}")
+    try:
+        tmp.write_text(json.dumps(timeline()))
+        os.replace(tmp, target)
+    except OSError:
+        return None
+    return target
+
+
+def read_timeline(path: str | Path) -> Optional[Dict[str, object]]:
+    """What a task published, for the executor's relay: jax-free and
+    failure-silent (a torn or absent file is None)."""
+    try:
+        with open(path) as fh:
+            out = json.load(fh)
+    except (OSError, ValueError):
+        return None
+    return out if isinstance(out, dict) else None
+
+
+def _arm_exit_write() -> None:
+    if "exit" not in _armed:
+        _armed.add("exit")
+        atexit.register(write_timeline)
 
 
 def _snapshot(store: Dict[str, Dict[str, object]]
@@ -383,8 +653,6 @@ def traces_root(history_dir: str | Path, app_id: str) -> Path:
 def endpoints_from_callback_info(info: Dict[str, str]) -> Dict[str, str]:
     """``{task_id: host:port}`` of live profiler servers, from the per-task
     callback payloads the executors pushed (``register_callback_info``)."""
-    import json
-
     out: Dict[str, str] = {}
     for task_id, payload in dict(info).items():
         try:
@@ -401,7 +669,6 @@ def _wait_reachable(addr: str, timeout_s: float) -> bool:
     endpoint at user-process LAUNCH — the profiler server inside it only
     starts listening after the jax import, seconds later."""
     import socket
-    import time
 
     host, _, port = addr.rpartition(":")
     host = host.strip("[]")   # "[::1]:9431" → host "::1"
@@ -426,8 +693,6 @@ def collect_traces(endpoints: Dict[str, str], history_dir: str | Path,
     records task_id → endpoint so the portal can attribute the per-host
     xplane files. Unreachable ranks are reported and dropped from the
     session — a partial profile beats none."""
-    import json
-
     capture = _trace_fn()
     if capture is None:
         log("trace collection unavailable: no profiler client "
@@ -453,7 +718,6 @@ def collect_traces(endpoints: Dict[str, str], history_dir: str | Path,
     # before giving up — the operator asked for a trace, not for luck.
     # Success means a NEW xplane file: .pb files from an earlier capture
     # into the same dest must not mask an empty session.
-    import time
     before = {p for p in dest.rglob("*") if p.suffix == ".pb"}
     for attempt in range(3):
         try:
@@ -477,8 +741,6 @@ def list_traces(history_dir: str | Path,
     ``{task_id: [{file, bytes}, ...]}``. Files are attributed to tasks by
     matching the manifest's endpoint (``host_port`` appears in the xplane
     filename); unattributed files land under ``"session"``."""
-    import json
-
     root = traces_root(history_dir, app_id)
     if not root.is_dir():
         return {}

@@ -328,12 +328,17 @@ class ApplicationRpcHandler:
 
     def rpc_heartbeat(self, job_type: str, index: int,
                       ckpt_step: Optional[int] = None,
-                      serve: Optional[Dict[str, float]] = None) -> Any:
+                      serve: Optional[Dict[str, float]] = None,
+                      published: Optional[Dict[str, int]] = None,
+                      timeline: Optional[Dict[str, Any]] = None) -> Any:
         """Liveness + checkpoint progress + serving telemetry: executors
-        that see a ``tony.ckpt.dir`` piggyback the last COMMITTED step;
-        serve-replica executors piggyback the engine's published
-        qps/p99_ms/queue_depth (the autoscaler's signal). Both params
-        optional — seed-era executors send neither.
+        that see a ``tony.ckpt.dir`` piggyback the last COMMITTED step
+        and the publication pointer found there; serve-replica executors
+        piggyback the engine's published qps/p99_ms/queue_depth (the
+        autoscaler's signal); and a beat carries the task's set-up
+        timeline (``tony_tpu.profiler``) whenever the task rewrote it, so
+        a task that is later killed has delivered it. All optional —
+        seed-era executors send none.
 
         Returns bare ``True`` normally; when an elastic resize has the
         gang draining, returns ``{"ok": True, "drain": True}`` so the
@@ -341,7 +346,8 @@ class ApplicationRpcHandler:
         asymmetry keeps seed-era executors, which only truth-test the
         reply, working unchanged)."""
         self.session.on_heartbeat(job_type, index, ckpt_step=ckpt_step,
-                                  serve=serve)
+                                  serve=serve, published=published,
+                                  timeline=timeline)
         if self.session.drain_pending(job_type, index):
             return {"ok": True, "drain": True}
         return True
@@ -364,7 +370,11 @@ class ApplicationRpcHandler:
 
     def rpc_register_execution_result(self, job_type: str, index: int,
                                       exit_code: int,
-                                      diagnostics: str = "") -> bool:
+                                      diagnostics: str = "",
+                                      timeline: Optional[Dict[str, Any]]
+                                      = None) -> bool:
+        if timeline:
+            self.session.task(job_type, index).timeline = dict(timeline)
         self.session.on_task_result(job_type, index, exit_code, diagnostics)
         if self.on_result:
             self.on_result(job_type, index, exit_code, diagnostics)

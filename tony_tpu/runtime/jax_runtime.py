@@ -79,10 +79,6 @@ _TOPOLOGY_BOUNDS = {1: (1, 1, 1), 2: (1, 2, 1), 4: (2, 2, 1), 8: (2, 4, 1)}
 
 
 class JAXTaskAdapter(MLGenericTaskAdapter):
-    def need_reserve_profiler_port(self, ctx: TaskContext) -> bool:
-        return (not ctx.is_sidecar()
-                and ctx.conf.get_bool("tony.task.profiler.enabled", False))
-
     def framework_env(self, ctx: TaskContext) -> Dict[str, str]:
         if ctx.is_sidecar():
             # Sidecars (tensorboard/notebook/driver) are not part of the SPMD
@@ -253,14 +249,6 @@ class JAXTaskAdapter(MLGenericTaskAdapter):
         data_seed = ctx.conf.get(conf_mod.DATA_SEED)
         if data_seed is not None:
             env[constants.ENV_DATA_SEED] = str(data_seed)
-        # Profiler hook (SURVEY.md §5.1): tony_tpu.distributed.initialize
-        # starts jax.profiler.start_server on this port in the user
-        # process. The port is executor-reserved and EPHEMERAL (shipped to
-        # the AM via register_callback_info) — a conf-fixed base+rank
-        # collided across overlapping jobs on one host, and the trace
-        # client would dial a dying predecessor's server.
-        if ctx.profiler_port is not None:
-            env[constants.ENV_PROFILER_PORT] = str(ctx.profiler_port)
         return env
 
 

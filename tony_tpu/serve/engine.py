@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tony_tpu import profiler
 from tony_tpu._trace import trace_record
 from tony_tpu.serve import prefix as prefix_mod
 from tony_tpu.serve.disagg import HandoffError, decode_f32, encode_f32
@@ -103,12 +104,16 @@ class Request:
 class Completion:
     """One finished request: the generated tokens, per-position f32
     logits when the engine keeps them (``keep_logits=True`` — the test
-    pin surface), and the request's wall latency."""
+    pin surface), the request's wall latency, and when each token was
+    known to the host (``token_s``: seconds from submit on the engine's
+    clock, one per generated token, read once per launch — the first is
+    the time to first token, the differences the inter-token gaps)."""
     rid: Any
     prompt: List[int]
     tokens: List[int]
     logits: Optional[List[np.ndarray]]
     latency_s: float
+    token_s: Optional[List[float]] = None
 
     def wire(self) -> Dict[str, Any]:
         """THE serving wire form (the replica RPC verbs all speak it;
@@ -116,13 +121,15 @@ class Completion:
         ``router._wire_completion`` since it cannot import this
         class)."""
         return {"rid": self.rid, "tokens": list(self.tokens),
-                "latency_ms": round(1e3 * self.latency_s, 3)}
+                "latency_ms": round(1e3 * self.latency_s, 3),
+                "token_ms": [round(1e3 * t, 3)
+                             for t in self.token_s or ()]}
 
 
 class _Seq:
     __slots__ = ("rid", "tokens", "n_prompt", "remaining", "logits",
                  "t_submit", "pf_pos", "published", "hkey", "conv",
-                 "tenant", "qcharge")
+                 "tenant", "qcharge", "token_s")
 
     def __init__(self, req: Request, t_submit: float):
         self.rid = req.rid
@@ -136,6 +143,8 @@ class _Seq:
         self.remaining = int(req.max_new_tokens)
         self.logits: List[np.ndarray] = []
         self.t_submit = t_submit
+        # Seconds from submit at which each generated token was emitted.
+        self.token_s: List[float] = []
         # Prefill cursor: the next position whose row is still
         # uncomputed (admission sets it past an adopted shared prefix;
         # chunked prefill advances it chunk by chunk).
@@ -249,6 +258,10 @@ class PagedModelRunner:
         self.fresh_compiles = 0
         self.compile_ms = 0.0
         self.deserialize_ms = 0.0
+        # Start-up seconds: warm() adds its own; the replica sets what the
+        # params restore took.
+        self.warm_s = 0.0
+        self.restore_s = 0.0
         # Forward-launch counter (prefills + decode/verify steps): the
         # machine-independent cost of a schedule — on an accelerator the
         # forward dominates wall time, so fewer launches for the same
@@ -373,12 +386,17 @@ class PagedModelRunner:
         for p in prefill_pads:
             shapes.append((1, int(p)))
         n = 0
-        for key in dict.fromkeys(shapes):
-            if key not in self._aot_fns:
-                self._aot_fns[key] = (
-                    self._resolve_aot(*key) if self.aot_cache is not None
-                    else self._compile_step(*key))
-                n += 1
+        t0 = time.monotonic()
+        with profiler.span("tony:warm") as sp:
+            for key in dict.fromkeys(shapes):
+                if key not in self._aot_fns:
+                    self._aot_fns[key] = (
+                        self._resolve_aot(*key)
+                        if self.aot_cache is not None
+                        else self._compile_step(*key))
+                    n += 1
+            sp.attrs.update(programs=n)
+        self.warm_s += time.monotonic() - t0
         return n
 
     def _run_fn(self, b, t, tokens, positions, tables, flat_idx):
@@ -593,6 +611,7 @@ class ServeEngine(PagedModelRunner):
         self._emitted = 0              # every generated token, at emit
         self._t0 = time.monotonic()
         self._steps = 0
+        self._t_emit = time.monotonic()   # when the last launch's rows landed
         self.register_plan()
 
     # -- planner/profiler registration ------------------------------------
@@ -693,25 +712,28 @@ class ServeEngine(PagedModelRunner):
         c0 = seq.pf_pos
         t_real = c1 - c0
         n = len(seq.tokens)
-        tokens = np.zeros((1, t_pad), np.int32)
-        tokens[0, :t_real] = seq.tokens[c0:c1]
-        positions = (c0 + np.arange(t_pad, dtype=np.int32))[None].copy()
-        flat = np.full((1, t_pad), self.cache.oob_index, np.int32)
-        for j in range(t_real):
-            # write_index, not flat_index: a fully-matched admission's
-            # tail row lands in an adopted block — the writer must own a
-            # private copy first (COW; pre-copied at admission).
-            flat[0, j] = self.cache.write_index(seq.rid, c0 + j)
-        tables = self.cache.table_array([seq.rid], self.nb_max)
-        logits = self._run_fn(1, t_pad, tokens, positions, tables, flat)
-        self.prefill_launches += 1
-        self.prefill_rows += t_pad
-        seq.pf_pos = c1
-        if c1 >= n:
-            last = np.asarray(logits[0, n - 1 - c0], np.float32)
-            self._emit_token(seq, last)
-        else:
-            self._publish(seq)
+        with profiler.span("serve:prefill", step=self._steps, batch=1,
+                           rows=t_pad):
+            tokens = np.zeros((1, t_pad), np.int32)
+            tokens[0, :t_real] = seq.tokens[c0:c1]
+            positions = (c0 + np.arange(t_pad, dtype=np.int32))[None].copy()
+            flat = np.full((1, t_pad), self.cache.oob_index, np.int32)
+            for j in range(t_real):
+                # write_index, not flat_index: a fully-matched admission's
+                # tail row lands in an adopted block — the writer must own
+                # a private copy first (COW; pre-copied at admission).
+                flat[0, j] = self.cache.write_index(seq.rid, c0 + j)
+            tables = self.cache.table_array([seq.rid], self.nb_max)
+            logits = self._run_fn(1, t_pad, tokens, positions, tables, flat)
+            self.prefill_launches += 1
+            self.prefill_rows += t_pad
+            seq.pf_pos = c1
+            if c1 >= n:
+                last = np.asarray(logits[0, n - 1 - c0], np.float32)
+                self._t_emit = time.monotonic()
+                self._emit_token(seq, last)
+            else:
+                self._publish(seq)
 
     def _prefill(self, seq: _Seq) -> None:
         """Monolithic prefill of everything past the prefill cursor."""
@@ -788,28 +810,37 @@ class ServeEngine(PagedModelRunner):
         seqs = list(self._running)
         b = _bucket_of(self.decode_buckets, len(seqs))
         t = self.q_block
-        tokens = np.zeros((b, t), np.int32)
-        positions = np.zeros((b, t), np.int32)
-        tables = np.zeros((b, self.nb_max), np.int32)
-        flat = np.full((b, t), self.cache.oob_index, np.int32)
-        for i, s in enumerate(seqs):
-            p0 = len(s.tokens) - 1          # the newest, not-yet-fed token
-            tokens[i, 0] = s.tokens[-1]
-            positions[i] = p0 + np.arange(t, dtype=np.int32)
-            flat[i, 0] = self.cache.write_index(s.rid, p0)
-        # Tables AFTER the write-index pass: write_index may COW-repoint
-        # a table slot, and the gather must see the repointed table.
-        tables[:len(seqs)] = self.cache.table_array(
-            [s.rid for s in seqs], self.nb_max)
-        logits = self._run_fn(b, t, tokens, positions, tables, flat)
-        rows = np.asarray(logits[:len(seqs), 0], np.float32)
-        for i, s in enumerate(seqs):
-            self._emit_token(s, rows[i])
+        at = {"step": self._steps, "batch": len(seqs)}
+        with profiler.span("serve:build_inputs", **at):
+            tokens = np.zeros((b, t), np.int32)
+            positions = np.zeros((b, t), np.int32)
+            tables = np.zeros((b, self.nb_max), np.int32)
+            flat = np.full((b, t), self.cache.oob_index, np.int32)
+            for i, s in enumerate(seqs):
+                p0 = len(s.tokens) - 1      # the newest, not-yet-fed token
+                tokens[i, 0] = s.tokens[-1]
+                positions[i] = p0 + np.arange(t, dtype=np.int32)
+                flat[i, 0] = self.cache.write_index(s.rid, p0)
+            # Tables AFTER the write-index pass: write_index may COW-
+            # repoint a table slot, and the gather must see the repointed
+            # table.
+            tables[:len(seqs)] = self.cache.table_array(
+                [s.rid for s in seqs], self.nb_max)
+        with profiler.span("serve:launch", **at):
+            logits = self._run_fn(b, t, tokens, positions, tables, flat)
+        with profiler.span("serve:readback", **at):
+            rows = np.asarray(logits[:len(seqs), 0], np.float32)
+        self._t_emit = time.monotonic()
+        with profiler.span("serve:emit", **at):
+            for i, s in enumerate(seqs):
+                self._emit_token(s, rows[i])
 
     def _emit_token(self, seq: _Seq, row: np.ndarray) -> None:
         if self.keep_logits:
             seq.logits.append(row.copy())
         seq.tokens.append(int(np.argmax(row)))   # greedy: deterministic
+        # One clock read per launch (its rows read back), not per row.
+        seq.token_s.append(self._t_emit - seq.t_submit)
         seq.remaining -= 1
         self._emitted += 1
         self._publish(seq)
@@ -1125,7 +1156,7 @@ class ServeEngine(PagedModelRunner):
             rid=seq.rid, prompt=seq.tokens[:seq.n_prompt],
             tokens=seq.tokens[seq.n_prompt:],
             logits=seq.logits if self.keep_logits else None,
-            latency_s=now - seq.t_submit))
+            latency_s=now - seq.t_submit, token_s=seq.token_s))
 
     def _advance_prefill(self, results: List[Completion]) -> None:
         """One chunk for the oldest prefilling sequence (FIFO — one
@@ -1354,6 +1385,7 @@ class ServeEngine(PagedModelRunner):
                    time.monotonic())
         seq.pf_pos = n                     # the prompt arrived computed
         seq.tokens.append(first)
+        seq.token_s.append(0.0)            # known here on arrival
         seq.remaining -= 1                 # the prefill side emitted it
         if first_row is not None:
             seq.logits.append(first_row)
@@ -1454,7 +1486,9 @@ class ServeEngine(PagedModelRunner):
         sequence, evict what finished. Returns the completions this
         step produced."""
         results: List[Completion] = []
-        self._join(results)
+        with profiler.span("serve:admit", step=self._steps,
+                           batch=len(self._running)):
+            self._join(results)
         self._advance_prefill(results)
         if self._running:
             self._decode()
@@ -1677,6 +1711,18 @@ class ServeEngine(PagedModelRunner):
             "swapping": 1.0 if self.swapping else 0.0,
             "prompt_hist": {str(k): float(v)
                             for k, v in prompt_hist.items()},
+            # Start-up and build telemetry (PR 25): every program this
+            # process compiled or loaded and the seconds that took
+            # (profiler.watch_builds — zeros where nothing listens), the
+            # restore and warm-up seconds, and the device's peak bytes
+            # where the backend reports them. A build_s that grows while
+            # serving is a compile inside the engine loop.
+            **profiler.build_totals(),
+            "restore_s": float(self.restore_s),
+            "warm_s": float(self.warm_s),
+            "memory_peak_bytes": float(
+                (jax.local_devices()[0].memory_stats() or {})
+                .get("peak_bytes_in_use", 0)),
         }
         stats.update(self._extra_stats())
         _record(f"{self.tag}_stats", **stats)

@@ -35,6 +35,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
+from tony_tpu import profiler
 from tony_tpu.conf import (CKPT_DIR, SERVE_AOT_CACHE, SERVE_BLOCK_SIZE,
                            SERVE_CKPT_DIR, SERVE_CTX_MAX,
                            SERVE_DEMOTE_BATCH, SERVE_DEMOTE_WATERMARK,
@@ -100,9 +101,11 @@ class Replica:
         from tony_tpu.publish import latest_publication
 
         pub = latest_publication(ckpt_dir)
+        t_restore = time.monotonic()
         params, step, prefix = self._restore_params(
             self.model, ckpt_dir, dtype_policy=dtype_policy, mesh=mesh,
             q_block=q_block, step=pub["step"] if pub else None)
+        restore_s = time.monotonic() - t_restore
         self.restored_step = step
         if spec_k:
             # Speculative lane (tony_tpu.serve.spec): draft-and-verify.
@@ -149,6 +152,7 @@ class Replica:
         # the AM's rolling swap never re-swaps a replica that already
         # came up on the target.
         self.engine.weight_step = int(step)
+        self.engine.restore_s = restore_s
         if pub is not None and pub["step"] == step:
             self.engine.weight_version = pub["version"]
         trace_record("serve", "replica", model=model_name,
@@ -234,33 +238,37 @@ class Replica:
         # Meshless, that is literally all it is: abstract shapes pinned to
         # the default device; running the init for values nobody reads is
         # minutes of compilation at a real model's width.
-        t0 = time.monotonic()
-        if mesh is not None:
-            with jax.set_mesh(mesh):
-                template = jax.jit(init)()
-            jax.block_until_ready(template)
-        else:
-            one = jax.sharding.SingleDeviceSharding(jax.devices()[0])
-            template = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=one),
-                jax.eval_shape(init))
-        t1 = time.monotonic()
-        if step is None:
-            step = ckpt.latest_step(ckpt_dir)
-        if step is None:
-            raise FileNotFoundError(
-                f"no committed checkpoint under {ckpt_dir} — a replica "
-                f"serves a trained model, it does not initialize one")
-        prefix = ckpt.find_path_prefix(ckpt_dir, template, step=step)
-        params = ckpt.restore_pytree(
-            ckpt_dir, template, step=step, mesh=mesh,
-            dtype_policy=dtype_policy, path_prefix=prefix)
-        jax.block_until_ready(params)
-        # Grant -> first token starts here: say where the seconds went.
-        print(f"[tony-serve-replica] restored step {step}: template "
-              f"{t1 - t0:.1f}s, read+place {time.monotonic() - t1:.1f}s",
-              flush=True)
+        with profiler.span("tony:restore") as sp:
+            t0 = time.monotonic()
+            if mesh is not None:
+                with jax.set_mesh(mesh):
+                    template = jax.jit(init)()
+                jax.block_until_ready(template)
+            else:
+                one = jax.sharding.SingleDeviceSharding(
+                    profiler.backend_devices()[0])
+                template = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                   sharding=one),
+                    jax.eval_shape(init))
+            t1 = time.monotonic()
+            if step is None:
+                step = ckpt.latest_step(ckpt_dir)
+            if step is None:
+                raise FileNotFoundError(
+                    f"no committed checkpoint under {ckpt_dir} — a replica "
+                    f"serves a trained model, it does not initialize one")
+            prefix = ckpt.find_path_prefix(ckpt_dir, template, step=step)
+            params = ckpt.restore_pytree(
+                ckpt_dir, template, step=step, mesh=mesh,
+                dtype_policy=dtype_policy, path_prefix=prefix)
+            jax.block_until_ready(params)
+            sp.attrs.update(step=step, bytes=sum(
+                x.nbytes for x in jax.tree.leaves(params)))
+            # Grant -> first token starts here: say where the seconds went.
+            print(f"[tony-serve-replica] restored step {step}: template "
+                  f"{t1 - t0:.1f}s, read+place {time.monotonic() - t1:.1f}s",
+                  flush=True)
         return params, step, prefix
 
     # -- request path ------------------------------------------------------
@@ -537,9 +545,15 @@ def main() -> int:
     (exported by the executor)."""
     from tony_tpu import constants
     from tony_tpu.conf import TonyConfig
+    from tony_tpu.distributed import _maybe_start_profiler
     from tony_tpu.util import enable_compile_cache
 
     enable_compile_cache()
+    # What a training task gets from distributed.initialize: the build
+    # counters, and a profiler server where the job asked for one
+    # (tony.task.profiler.enabled), so `tony profile` captures a replica.
+    profiler.watch_builds()
+    _maybe_start_profiler()
     conf_path = os.environ.get(constants.ENV_CONF_PATH)
     if not conf_path:
         print("[tony-serve-replica] no TONY_CONF_PATH; run under a tony "
@@ -616,6 +630,7 @@ def main() -> int:
     history_dir = conf.get(HISTORY_LOCATION)
     if history_dir and (conf.get(SERVE_AOT_CACHE) or warm_standby):
         replica.tune_warm_pads(history_dir)
+    profiler.write_timeline()       # end of set-up: restore and warm done
     replica.serve_forever(
         port=conf.get_int(SERVE_PORT, 0),
         stats_path=os.environ.get(constants.ENV_SERVE_STATS))

@@ -55,7 +55,9 @@ def _wire_completion(out: Any, rid: Optional[Any]) -> Dict[str, Any]:
         return out
     return {"rid": getattr(out, "rid", rid),
             "tokens": list(out.tokens),
-            "latency_ms": round(1e3 * out.latency_s, 3)}
+            "latency_ms": round(1e3 * out.latency_s, 3),
+            "token_ms": [round(1e3 * t, 3)
+                         for t in getattr(out, "token_s", None) or ()]}
 
 
 class NoReplicaError(RuntimeError):

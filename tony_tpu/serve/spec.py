@@ -43,10 +43,13 @@ bytes-bound decode floor divides by that factor.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Any, Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
+from tony_tpu import profiler
 from tony_tpu._trace import trace_record
 from tony_tpu.serve.engine import (PagedModelRunner, ServeEngine,
                                    _bucket_of, _Seq)
@@ -364,7 +367,13 @@ class SpecEngine(ServeEngine):
                 flat[i, j] = self.cache.write_index(s.rid, p0 + j)
         tables[:len(seqs)] = self.cache.table_array(
             [s.rid for s in seqs], self.nb_max)
-        logits = self._run_fn(b, t, tokens, positions, tables, flat)
+        with profiler.span("serve:launch", step=self._steps,
+                           batch=len(seqs)):
+            logits = self._run_fn(b, t, tokens, positions, tables, flat)
+            jax.block_until_ready(logits)
+        # One clock read for the launch: its rows are ready, the accept
+        # loop below reads them back one by one.
+        self._t_emit = time.monotonic()
         self.verify_launches += 1
         for i, s in enumerate(seqs):
             p0 = len(s.tokens) - 1

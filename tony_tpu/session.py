@@ -98,6 +98,11 @@ class TonyTask:
         # prefix_digest key list and rpc_port — tony_tpu.serve): what
         # the AM's replica autoscaler and the request router decide on.
         self.serve_metrics: Dict[str, object] = {}
+        # The task's set-up timeline as it last published it (spans,
+        # build records, counters — tony_tpu.profiler), relayed by the
+        # executor; the AM logs it once, as TASK_TIMELINE, at the end of
+        # the attempt.
+        self.timeline: Optional[Dict[str, object]] = None
         self.metrics: Dict[str, float] = {}
         # Timeline of TaskMonitor samples (reference: the per-task metric
         # history MetricsRpc accumulates for the portal). Bounded: at the
@@ -292,9 +297,12 @@ class TonySession:
     def on_heartbeat(self, job_type: str, index: int,
                      ckpt_step: Optional[int] = None,
                      serve: Optional[Dict[str, float]] = None,
-                     published: Optional[Dict[str, int]] = None) -> None:
+                     published: Optional[Dict[str, int]] = None,
+                     timeline: Optional[Dict[str, object]] = None) -> None:
         t = self.task(job_type, index)
         t.touch()
+        if timeline:
+            t.timeline = dict(timeline)
         if ckpt_step is not None:
             t.ckpt_step = int(ckpt_step)
         if serve:

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import logging
 import os
+import time
 import weakref
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
@@ -30,7 +31,7 @@ import optax
 from flax.training.train_state import TrainState
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tony_tpu import chaos, constants
+from tony_tpu import chaos, constants, profiler
 from tony_tpu import parallel as par
 from tony_tpu.parallel import overlap
 
@@ -45,8 +46,10 @@ def cross_entropy_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:
 
 
 def next_token_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
-    """Causal-LM loss: predict token t+1 from position t."""
-    return cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+    """Causal-LM loss: predict token t+1 from position t (device scope
+    ``loss``)."""
+    with jax.named_scope("loss"):
+        return cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
 
 
 def chunked_next_token_xent(hidden: jax.Array, lm_head: jax.Array,
@@ -77,10 +80,12 @@ def chunked_next_token_xent(hidden: jax.Array, lm_head: jax.Array,
     @jax.checkpoint
     def body(acc, xs):
         hc, lc, mc = xs
-        logits = (hc @ wb).astype(jnp.float32)          # [chunk, V]
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        lab = jnp.take_along_axis(logits, lc[:, None], axis=-1)[:, 0]
-        return acc + ((lse - lab) * mc).sum(), None
+        with jax.named_scope("lm_head"):
+            logits = (hc @ wb).astype(jnp.float32)      # [chunk, V]
+        with jax.named_scope("loss"):
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            lab = jnp.take_along_axis(logits, lc[:, None], axis=-1)[:, 0]
+            return acc + ((lse - lab) * mc).sum(), None
 
     total, _ = jax.lax.scan(
         body, jnp.float32(0.0),
@@ -102,6 +107,7 @@ def param_shardings(model: nn.Module, sample_input: jax.Array, mesh: Mesh,
     return abstract["params"], shardings["params"]
 
 
+@profiler.span("tony:create_train_state")
 def create_train_state(model: nn.Module, tx: Any,
                        sample_input: jax.Array, rng: jax.Array,
                        mesh: Optional[Mesh] = None,
@@ -205,8 +211,9 @@ def make_train_step(loss_of: Callable[[jax.Array, Dict[str, jax.Array]],
 
         (loss, aux), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
-        new_state = state.apply_gradients(grads=grads)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            new_state = state.apply_gradients(grads=grads)
+            gnorm = optax.global_norm(grads)
         return new_state, {"loss": loss, "grad_norm": gnorm,
                            "aux_loss": aux}
 
@@ -356,11 +363,12 @@ def make_accum_train_step(loss_of: Callable[[jax.Array,
             # ZeRO-3: grads carry the fsdp shard layout here, so the
             # optimizer update and the norm reduction below run shard-
             # local with GSPMD inserting only the tiny norm psum.
-            new_state = state.apply_gradients(grads=grads)
+            with jax.named_scope("optimizer"):
+                new_state = state.apply_gradients(grads=grads)
+                gnorm = optax.global_norm(grads)
             if quant:
                 new_state = new_state.replace(
                     quant_state={"amax": outs[3]})
-            gnorm = optax.global_norm(grads)
             return new_state, {"loss": loss, "grad_norm": gnorm,
                                "aux_loss": aux}
 
@@ -593,6 +601,13 @@ def train_loop(state: TrainState, step_fn: Callable[[TrainState, Any],
     gang continuously feeds the replicas it shares a control plane
     with — no manual checkpoint copying.
 
+    On a profiler trace the loop shows as ``train_step`` (a
+    ``StepTraceAnnotation`` around each ``step_fn`` call),
+    ``train:next_batch``, ``train:on_step``, ``train:save`` and, with a
+    drain file, ``train:drain_poll``; the restore is the set-up span
+    ``tony:restore`` and the snapshot stalls add up in the counters
+    ``saves`` / ``save_stall_s`` (:mod:`tony_tpu.profiler`).
+
     Returns ``(state, last_metrics)``.
     """
     from tony_tpu import ckpt as ckpt_mod
@@ -622,37 +637,45 @@ def train_loop(state: TrainState, step_fn: Callable[[TrainState, Any],
 
         mgr = ckpt_mod.AsyncCheckpointer(ckpt_dir, keep=keep)
         if restore_on_start:
-            latest = ckpt_mod.latest_step(ckpt_dir)
-            if latest is not None and ckptio.has_iter_state(ckpt_dir,
-                                                           latest):
-                # Wrapped {model, data_iter} checkpoint: unwrap keyed on
-                # what the manifest CONTAINS, not on what this caller
-                # passed — a batches= run restoring a data= run's save
-                # must still get the model (the strict-mode tree-mismatch
-                # KeyError it would otherwise hit reads like a wrong
-                # model, not a wrapped checkpoint).
-                # encode/decode_portable: planes with topology-bound live
-                # state (the fused optimizer's bucket-resident moments)
-                # restore through their portable leaf-major form and are
-                # re-bound to THIS attempt's topology; identity for
-                # everything else.
-                state = ckpt_mod.decode_portable(ckpt_mod.restore_pytree(
-                    ckpt_dir,
-                    {ckptio.MODEL_KEY: ckpt_mod.encode_portable(state)},
-                    step=latest, mesh=mesh)[ckptio.MODEL_KEY], mesh)
-                if stateful_data:
-                    data.restore(ckptio.load_iter_state(ckpt_dir, latest))
+            with profiler.span("tony:restore") as sp:
+                latest = ckpt_mod.latest_step(ckpt_dir)
+                if latest is not None and ckptio.has_iter_state(ckpt_dir,
+                                                               latest):
+                    # Wrapped {model, data_iter} checkpoint: unwrap keyed
+                    # on what the manifest CONTAINS, not on what this
+                    # caller passed — a batches= run restoring a data=
+                    # run's save must still get the model (the strict-mode
+                    # tree-mismatch KeyError it would otherwise hit reads
+                    # like a wrong model, not a wrapped checkpoint).
+                    # encode/decode_portable: planes with topology-bound
+                    # live state (the fused optimizer's bucket-resident
+                    # moments) restore through their portable leaf-major
+                    # form and are re-bound to THIS attempt's topology;
+                    # identity for everything else.
+                    state = ckpt_mod.decode_portable(
+                        ckpt_mod.restore_pytree(
+                            ckpt_dir,
+                            {ckptio.MODEL_KEY:
+                             ckpt_mod.encode_portable(state)},
+                            step=latest, mesh=mesh)[ckptio.MODEL_KEY], mesh)
+                    if stateful_data:
+                        data.restore(
+                            ckptio.load_iter_state(ckpt_dir, latest))
+                    else:
+                        _log.warning(
+                            "checkpoint step %d carries data-iterator "
+                            "state but this train_loop has no stateful "
+                            "data=; the model resumes, the input stream "
+                            "starts from the beginning", latest)
                 else:
-                    _log.warning(
-                        "checkpoint step %d carries data-iterator state "
-                        "but this train_loop has no stateful data=; the "
-                        "model resumes, the input stream starts from the "
-                        "beginning", latest)
-            else:
-                state = ckpt_mod.decode_portable(
-                    ckpt_mod.restore_latest(
-                        ckpt_dir, ckpt_mod.encode_portable(state),
-                        mesh=mesh), mesh)
+                    state = ckpt_mod.decode_portable(
+                        ckpt_mod.restore_latest(
+                            ckpt_dir, ckpt_mod.encode_portable(state),
+                            mesh=mesh), mesh)
+                # step None: looked, found nothing to resume from.
+                sp.attrs.update(step=latest, bytes=0 if latest is None
+                                else sum(getattr(x, "nbytes", 0) for x in
+                                         jax.tree.leaves(state)))
 
     def payload():
         # Saves go through the same portable codec: manifests carry the
@@ -686,21 +709,48 @@ def train_loop(state: TrainState, step_fn: Callable[[TrainState, Any],
             publish_mod.publish_step(ckpt_dir, step)
         published_step = step
 
+    def save(step: int) -> None:
+        # What the loop pays for a save is the snapshot stall (slot wait +
+        # device->host extract); the commit overlaps later steps.
+        t0 = time.perf_counter()
+        with profiler.span("train:save", step=step):
+            mgr.save(payload(), step=step)
+        profiler.add_seconds("save_stall_s", time.perf_counter() - t0)
+        profiler.count("saves")
+
+    end = object()
+    feed = iter(batches)
     try:
-        for batch in batches:
-            state, metrics = step_fn(state, batch)
+        while True:
+            with profiler.span("train:next_batch"):
+                batch = next(feed, end)
+            if batch is end:
+                break
+            with jax.profiler.StepTraceAnnotation("train_step",
+                                                  step_num=done):
+                state, metrics = step_fn(state, batch)
             done += 1
+            if done == 1:
+                # End of set-up: the first step is traced, lowered and
+                # compiled (or loaded) by now, and every build is on the
+                # timeline.
+                profiler.write_timeline()
             chaos.kill_point(done)
             if on_step is not None:
-                on_step(done, metrics)
+                with profiler.span("train:on_step"):
+                    on_step(done, metrics)
             if mgr is not None and save_every and done % save_every == 0:
                 saved_at = int(jax.device_get(state.step)) \
                     if hasattr(state, "step") else done
-                mgr.save(payload(), step=saved_at)
+                save(saved_at)
                 saves += 1
                 if publish_every and saves % publish_every == 0:
                     maybe_publish(saved_at)
-            if drain_file is not None and os.path.exists(drain_file):
+            if drain_file is not None:
+                with profiler.span("train:drain_poll"):
+                    draining = os.path.exists(drain_file)
+                if not draining:
+                    continue
                 # Drain directive (elastic resize): commit model + cursor
                 # SYNCHRONOUSLY — wait() both drains the async queue and
                 # re-raises any pending writer failure, so EXIT_DRAINED
@@ -709,14 +759,14 @@ def train_loop(state: TrainState, step_fn: Callable[[TrainState, Any],
                     here = int(jax.device_get(state.step)) \
                         if hasattr(state, "step") else done
                     if here != saved_at:
-                        mgr.save(payload(), step=here)
+                        save(here)
                     mgr.wait()
                 raise SystemExit(constants.EXIT_DRAINED)
         if mgr is not None and save_final and done:
             final = int(jax.device_get(state.step)) \
                 if hasattr(state, "step") else done
             if final != saved_at:
-                mgr.save(payload(), step=final)
+                save(final)
             maybe_publish(final)
         if mgr is not None:
             mgr.wait()
@@ -728,6 +778,7 @@ def train_loop(state: TrainState, step_fn: Callable[[TrainState, Any],
         # idempotent and state() still reads the delivered cursor after).
         if data is not None and hasattr(data, "close"):
             data.close()
+        profiler.write_timeline()
     return state, metrics
 
 
