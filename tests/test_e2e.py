@@ -141,6 +141,69 @@ def test_env_contract_reaches_user_process(pod, tmp_path):
         assert e[constants.ENV_COORDINATOR_ADDRESS] == spec["worker"][0]
 
 
+SEE_SOURCES = """\
+import json, os, re
+import jax
+pat = jax.config.jax_hlo_source_file_canonicalization_regex
+names = {"script": __file__, "site": jax.__file__,
+         "in_cwd": os.path.join(os.getcwd(), "pkg", "model.py")}
+with open("seen.json", "w") as f:
+    json.dump({"cwd": os.getcwd(), "regex": pat, "names": names,
+               "env": os.environ.get("JAX_HLO_SOURCE_FILE_CANONICALIZATION_REGEX"),
+               # as jax's lowering canonicalises a file name
+               "written_as": {k: re.sub(pat, "", v) for k, v in names.items()}},
+              f)
+"""
+
+
+@pytest.mark.parametrize("case", ["src_dir", "no_src_dir", "conf_env",
+                                  "exported"])
+def test_source_names_lose_the_sandbox_prefix(pod, tmp_path, monkeypatch,
+                                              case):
+    """What a job's script sees of ISSUE 26's mechanism: jax takes this
+    container's sandbox off the names of the job's files (so the path
+    that no two containers share stays out of the programs' text, and of
+    the compile cache's key), a job without a src dir gets the container
+    directory, and a pattern the user set is left alone."""
+    from tony_tpu.executor import TaskExecutor
+
+    src = tmp_path / "job_src"
+    src.mkdir()
+    (src / "see_sources.py").write_text(SEE_SOURCES)
+    extra, src_dir, script = {}, src, "see_sources.py"
+    if case == "no_src_dir":
+        src_dir, script = None, str(src / "see_sources.py")
+    elif case == "conf_env":
+        extra["tony.worker.env"] = \
+            f"{constants.ENV_JAX_SOURCE_FILE_REGEX}=^/users/own/"
+    elif case == "exported":
+        monkeypatch.setenv(constants.ENV_JAX_SOURCE_FILE_REGEX,
+                           "^/users/own/")
+    job = pod.run(props(**{
+        "tony.worker.instances": "1",
+        "tony.application.executes": wl(script), **extra,
+    }), src_dir=src_dir)
+    assert job.exit_code == 0
+    [seen_file] = Path(job.am.job_dir).glob("containers/*/**/seen.json")
+    seen = json.loads(seen_file.read_text())
+    names, written_as = seen["names"], seen["written_as"]
+    if case in ("conf_env", "exported"):
+        assert seen["regex"] == seen["env"] == "^/users/own/"
+        assert written_as == names
+        return
+    sandbox = next(p for p in seen_file.resolve().parents
+                   if p.parent.name == "containers")
+    assert seen["regex"] == seen["env"] == \
+        TaskExecutor.source_prefix_regex(str(sandbox))
+    # Files outside the sandbox keep their names: site-packages, and the
+    # script of a job that localised no source.
+    in_src = case == "src_dir"
+    assert seen["cwd"] == str(sandbox / "src" if in_src else sandbox)
+    assert written_as == {
+        "in_cwd": "pkg/model.py", "site": names["site"],
+        "script": "see_sources.py" if in_src else names["script"]}
+
+
 def test_preemption_relaunches_task(pod):
     job = pod.submit(props(**{
         "tony.worker.instances": "2",

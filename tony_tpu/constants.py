@@ -122,6 +122,12 @@ ENV_LIBTPU_INIT_ARGS = "LIBTPU_INIT_ARGS"
 # unset, jax falls back to the CPU when the TPU fails to initialise and
 # the job "succeeds" there; pinned, the task dies instead.
 ENV_JAX_PLATFORMS = "JAX_PLATFORMS"
+# jax's own option: a regex removed from every source-file name it writes
+# into a program's locations. The executor sets it to its container's
+# sandbox prefix (``TaskExecutor.source_prefix_regex``) unless the user did:
+# a Pallas kernel's Mosaic module keeps those names, they survive jax's
+# strip of debug info, and so they are part of the compile cache's key.
+ENV_JAX_SOURCE_FILE_REGEX = "JAX_HLO_SOURCE_FILE_CANONICALIZATION_REGEX"
 
 # --- Well-known job types ---------------------------------------------------
 # (reference: open-ended; these are the conventional names used by the success

@@ -11,7 +11,9 @@ Sequence, faithfully carried over:
 3. ``register_worker_spec`` over RPC;
 4. poll ``get_cluster_spec`` until the AM has ALL registrations (gang barrier);
 5. build the framework env via the runtime adapter (``TF_CONFIG``, the JAX
-   coordinator triple, …), localize ``src_dir`` into the container workdir;
+   coordinator triple, …), localize ``src_dir`` into the container workdir
+   and tell jax to leave that workdir out of the source-file names it
+   writes into programs (``source_prefix_regex``);
 6. release the reserved sockets, fork the user process, pump its output to
    the container log;
 7. heartbeat + metrics threads while the user process runs;
@@ -26,6 +28,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import shutil
 import signal
 import socket
@@ -264,13 +267,33 @@ class TaskExecutor:
 
     def localize_src(self) -> Optional[Path]:
         """Per-container copy of the staged src dir (reference:
-        ``LocalizableResource`` download into the container sandbox)."""
+        ``LocalizableResource`` download into the container sandbox).
+        The copy is the user process's cwd, so every file of the job has
+        a path no other container shares; :meth:`source_prefix_regex`
+        keeps that path out of the programs the task builds."""
         if not self.src_dir or not Path(self.src_dir).is_dir():
             return None
         dest = Path.cwd() / "src"
         if not dest.exists():
             shutil.copytree(self.src_dir, dest)
         return dest
+
+    @staticmethod
+    def source_prefix_regex(sandbox: str) -> str:
+        """The pattern jax removes from source-file names
+        (``constants.ENV_JAX_SOURCE_FILE_REGEX``) in a task of this
+        container: the sandbox the executor made, and ``src/`` under it,
+        anchored and escaped. ``<sandbox>/src/train.py`` is written as
+        ``train.py`` and ``<sandbox>/venv/lib/...`` as ``venv/lib/...``;
+        a path outside the sandbox (the package, site-packages) is left
+        as it is. Without it the train step — the one program of a job
+        that holds Pallas kernels, whose Mosaic modules carry their call
+        stack's file names into the compile cache's key — is keyed by
+        the application and container ids and compiled afresh by every
+        submit, every relaunch and every worker of a gang."""
+        sep = re.escape(os.sep)
+        return ("^" + re.escape(sandbox.rstrip(os.sep)) + sep
+                + "(?:src" + sep + ")?")
 
     def localize_venv(self) -> Optional[Path]:
         """Localize the staged venv (dir or archive) into the container
@@ -588,6 +611,9 @@ class TaskExecutor:
             self.localize_resources(Path(cwd))
             pypath = [p for p in (cwd, env.get("PYTHONPATH")) if p]
             env["PYTHONPATH"] = os.pathsep.join(pypath)
+            # A value the user exported or gave in tony.<jobtype>.env wins.
+            env.setdefault(constants.ENV_JAX_SOURCE_FILE_REGEX,
+                           self.source_prefix_regex(os.getcwd()))
             # 6. release reserved ports, launch the user process.
             if self._am_lost:
                 # AM died while we were still in the barrier/localization

@@ -37,7 +37,20 @@ def enable_compile_cache() -> str:
     children). The directory is part of the cache key, so it never moves:
     where ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it itself and
     nothing is set in code; otherwise ``<checkout>/.jax_cache``. Returns
-    the directory in use."""
+    the directory in use.
+
+    The key itself is jax's: the program's text without its debug
+    locations, the jaxlib version, the platform, ``XLA_FLAGS`` and
+    ``LIBTPU_INIT_ARGS``, the compile options, the devices, the
+    compression. A Pallas kernel's Mosaic module keeps its locations (they
+    travel in the custom call's ``backend_config``, which the strip of
+    debug info leaves alone), so the file names of the call stack ARE in
+    the key of a program that holds one. Nothing is done about that here:
+    the one path that moves between runs, the container sandbox a task's
+    script is copied into, is taken off by the executor that made it
+    (``TaskExecutor.source_prefix_regex``, handed to the user process as
+    ``JAX_HLO_SOURCE_FILE_CANONICALIZATION_REGEX``), and a script run from
+    a fixed directory needs nothing."""
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env
