@@ -1,0 +1,16 @@
+"""Model FLOP/s utilisation of the layer-kind decoder: its own FLOPs per
+trained token (roofline_ssm.train_flops_per_token: causal and windowed
+attention, the scans, nothing recomputed) x the job's tokens/s over chips
+x the bf16 peak of the device kind."""
+
+from benchmark import roofline, roofline_ssm
+
+
+def read(art: dict, args: dict):
+    cfg = art.get("model_cfg") or {}
+    if art.get("kind") != "train" or not art.get("tok_s") \
+            or art["device"]["platform"] != "tpu" or "kinds" not in cfg:
+        return None
+    peak = roofline.peaks(art["device"]["kind"])["bf16_flops"]
+    flops = roofline_ssm.train_flops_per_token(cfg, art["job"]["seq"])
+    return 100.0 * flops * art["tok_s"] / (art["chips"] * peak)

@@ -9,6 +9,9 @@ adds two first-class model families this package owns:
 * :mod:`~tony_tpu.models.transformer` — a Llama-style decoder for the
   ``pjit``/GSPMD graduation config (SURVEY.md §6 config ⑤), with logical
   sharding axes wired for dp/fsdp/tp/sp meshes;
+* :mod:`~tony_tpu.models.hybrid` — a decoder built from a tuple of layer
+  kinds (state-space, windowed / full differential attention, gated memory
+  units, cross-attention to one shared K/V);
 * :mod:`~tony_tpu.models.mnist` — the small nets the examples train.
 
 All models are flax ``linen`` modules: params in f32, compute in bf16 by
@@ -30,9 +33,11 @@ def register(name: str):
 
 def get_model(name: str, **kw):
     """Build a registered model by name (``resnet50``, ``llama2-7b``,
-    ``llama-tiny``, ``mnist-mlp``, ``mnist-cnn``)."""
+    ``llama-tiny``, ``hybrid-decoder``, ``hybrid-tiny``, ``mnist-mlp``,
+    ``mnist-cnn``)."""
     # Import for registration side effects.
-    from tony_tpu.models import mnist, resnet, transformer  # noqa: F401
+    from tony_tpu.models import (hybrid, mnist, resnet,  # noqa: F401
+                                 transformer)
     if name not in _REGISTRY:
         raise ValueError(f"unknown model {name!r}; known: {sorted(_REGISTRY)}")
     return _REGISTRY[name](**kw)

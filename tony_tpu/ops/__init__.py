@@ -13,13 +13,14 @@ from tony_tpu.ops.attention import (
     flash_decode, reference_attention)
 from tony_tpu.ops.fused_optim import (FusedOptimizer, fused_bucket_update,
                                       fused_update_step)
+from tony_tpu.ops.ssm import causal_conv1d, selective_scan
 from tony_tpu.ops.quant import (QuantConfig, QuantDense, QuantTrainState,
                                 quant_dot, quant_dot_general,
                                 with_gather_quant)
 
 __all__ = ["flash_attention", "flash_attention_packed",
            "flash_attention_sharded", "flash_decode",
-           "reference_attention",
+           "reference_attention", "causal_conv1d", "selective_scan",
            "FusedOptimizer", "fused_bucket_update", "fused_update_step",
            "QuantConfig", "QuantDense", "QuantTrainState", "quant_dot",
            "quant_dot_general", "with_gather_quant"]

@@ -38,10 +38,12 @@ _LSE_LANES = 8
 
 def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = True,
-                        scale: Optional[float] = None) -> jax.Array:
+                        scale: Optional[float] = None,
+                        window: Optional[int] = None) -> jax.Array:
     """Plain attention over [B, H, T, D], f32 softmax accumulation.
     K/V may carry fewer heads (GQA); they are repeated up to H here —
-    this is the semantic spec the zero-copy kernels are tested against."""
+    this is the semantic spec the zero-copy kernels are tested against.
+    ``window`` (causal only): query t sees keys t-window+1 .. t."""
     d = q.shape[-1]
     scale = d ** -0.5 if scale is None else scale
     if k.shape[1] != q.shape[1]:
@@ -52,8 +54,9 @@ def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    k.astype(jnp.float32)) * scale
     if causal:
         t_q, t_k = q.shape[2], k.shape[2]
-        mask = (jax.lax.broadcasted_iota(jnp.int32, (t_q, t_k), 0)
-                >= jax.lax.broadcasted_iota(jnp.int32, (t_q, t_k), 1))
+        gap = (jax.lax.broadcasted_iota(jnp.int32, (t_q, t_k), 0)
+               - jax.lax.broadcasted_iota(jnp.int32, (t_q, t_k), 1))
+        mask = gap >= 0 if window is None else (gap >= 0) & (gap < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p,
@@ -66,14 +69,81 @@ def _causal_mask(s, qi, bq, kb, block_k):
     return jnp.where(q_pos >= k_pos, s, _NEG_INF)
 
 
-def _mask_s(s, qi, bq, kb, block_k, causal, kv_len):
+def _scope(name: str, window) -> str:
+    """Device scope of a kernel call: windowed calls carry ``_win`` (their
+    custom calls are then named ``%attn_fwd_win.N`` ...), so a trace tells
+    them from the full causal ones, whose work differs at equal shapes."""
+    return name if window is None else f"{name}_win"
+
+
+def _first_kb(qi, bq, block_k, window):
+    """First k-block a q-block sees under a causal window of ``window``
+    keys (query t sees keys t-window+1 .. t): the block holding the
+    earliest key of the block's first query. ``qi`` may be traced."""
+    return jnp.maximum(qi * bq - (window - 1), 0) // block_k
+
+
+def _last_qb(kj, bq, block_k, window):
+    """Last q-block (exclusive bound is the caller's) that sees k-block
+    ``kj`` under the window: the block of the latest query that still
+    sees the block's last key."""
+    return ((kj + 1) * block_k + window - 2) // bq
+
+
+def _window_spans(nq, nk, bq, block_k, window):
+    """Static grid extents of a windowed call: the most k-blocks any
+    q-block visits, and the most q-blocks any k-block is visited by."""
+    kspan = max(min(nk - 1, ((qi + 1) * bq - 1) // block_k)
+                - max(qi * bq - window + 1, 0) // block_k + 1
+                for qi in range(nq))
+    qspan = max(min(nq - 1, ((kj + 1) * block_k + window - 2) // bq)
+                - (kj * block_k) // bq + 1 for kj in range(nk))
+    return kspan, qspan
+
+
+def kv_blocks(t: int, tk: int, block_q: int = 256, block_k: int = 256,
+              causal: bool = True, window: Optional[int] = None) -> tuple:
+    """(visited, total) K/V blocks of one head's forward grid at the
+    blocks :func:`flash_attention` would pick for ``t`` x ``tk``: what
+    the causal triangle and the window leave of the ``nq * nk`` square.
+    Pure arithmetic (the counters ``attn:kv_blocks_visited`` / ``_total``
+    and the tests read it); the kernels skip exactly these blocks."""
+    _, bq, bk, extra = _plan_dispatch(t, tk, block_q, block_k, causal)
+    if isinstance(extra, tuple):
+        t, tk = extra[0], extra[1]
+    elif extra:
+        t = tk = extra
+    nq, nk = -(-t // bq), -(-tk // bk)
+    if window is not None and window >= tk:
+        window = None
+    visited = 0
+    for qi in range(nq):
+        hi = min(nk - 1, ((qi + 1) * bq - 1) // bk) if causal else nk - 1
+        lo = max(qi * bq - window + 1, 0) // bk if window else 0
+        visited += hi - lo + 1
+    return visited, nq * nk
+
+
+def _mask_s(s, qi, bq, kb, block_k, causal, kv_len, window=None):
     """Score masking shared by every kernel body: the causal triangle
     and/or the key-length mask for end-padded K/V (``kv_len`` = the REAL
-    key count, a static int — ``None`` means no padded keys to hide).
-    Both are resolved at trace time, so the unmasked paths compile to
-    exactly the pre-mask kernels. Padded keys never fully mask a k-block
-    (padding rounds up to the block size, so the last block keeps >= 1
-    real key) — the online-softmax max can't get stuck at -inf."""
+    key count, a static int — ``None`` means no padded keys to hide),
+    and/or the sliding window (``window`` keys back from the query, the
+    query's own included; causal calls only). All are resolved at trace
+    time, so the unmasked paths compile to exactly the pre-mask kernels.
+    Padded keys never fully mask a k-block (padding rounds up to the block
+    size, so the last block keeps >= 1 real key) — the online-softmax max
+    can't get stuck at -inf. Under a window a row may see nothing of the
+    first block its q-block visits; what it accumulates there at the
+    running max's floor is multiplied by exp(floor - real max) = 0 when
+    its first real block arrives (blocks are visited in ascending order
+    and the diagonal is always real)."""
+    if window is not None:
+        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32,
+                                                        s.shape, 1)
+        return jnp.where((q_pos >= k_pos) & (q_pos - k_pos < window), s,
+                         _NEG_INF)
     if causal:
         s = _causal_mask(s, qi, bq, kb, block_k)
     if kv_len is not None:
@@ -85,7 +155,8 @@ def _mask_s(s, qi, bq, kb, block_k, causal, kv_len):
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                   acc_scr, *, causal: bool, scale: float, qi_axis: int = 1,
-                  kv_len: Optional[int] = None):
+                  kv_len: Optional[int] = None,
+                  window: Optional[int] = None):
     """Streamed-KV flash forward: grid ``(..., qi, kb)`` with the k-block
     axis INNERMOST, so K/V arrive one ``[Bk, D]`` block at a time (VMEM
     stays O(block), any context length fits) while the online-softmax
@@ -97,14 +168,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     log-sum-exp rows the backward kernels reconstruct p from.
     ``qi_axis`` is which grid axis carries the q-block index (the k axis
     is ``qi_axis + 1``): 1 for the [B·H, T, D] layout's (bh, i, kb) grid,
-    2 for the packed [B, T, H·D] layout's (b, h, i, kb) grid."""
+    2 for the packed [B, T, H·D] layout's (b, h, i, kb) grid. Under a
+    ``window`` the k axis holds only the blocks a q-block can see (``_window_spans``): step 0 is
+    the q-block's first visible block, and blocks left of the window are
+    neither fetched nor computed."""
     bq, d = q_ref.shape
     bk = k_ref.shape[0]
     qi = pl.program_id(qi_axis)
-    kb = pl.program_id(qi_axis + 1)
+    kb = step = pl.program_id(qi_axis + 1)
     nkb = pl.num_programs(qi_axis + 1)
+    if window is not None:
+        kb = step + _first_kb(qi, bq, bk, window)
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -122,7 +198,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         s = jax.lax.dot_general(
             q, k_ref[:], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [Bq, Bk]
-        s = _mask_s(s, qi, bq, kb, bk, causal, kv_len)
+        s = _mask_s(s, qi, bq, kb, bk, causal, kv_len, window)
         m = m_scr[:, 0:1]
         l = l_scr[:, 0:1]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -135,7 +211,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(kb == nkb - 1)
+    @pl.when(step == nkb - 1)
     def _finalize():
         m = m_scr[:, 0:1]
         l = l_scr[:, 0:1]
@@ -147,7 +223,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
                          dq_scr, *, causal: bool, scale: float,
-                         qi_axis: int = 1, kv_len: Optional[int] = None):
+                         qi_axis: int = 1, kv_len: Optional[int] = None,
+                         window: Optional[int] = None):
     """dq, streamed like the forward (grid ``(..., qi, kb)``, k innermost,
     dq accumulated in VMEM scratch): recompute p from (q, k, lse) per
     k-block — ds = p·(dpᵀ−D); dq += ds·k·scale. No T×T buffer and no
@@ -155,10 +232,12 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     bq, d = q_ref.shape
     bk = k_ref.shape[0]
     qi = pl.program_id(qi_axis)
-    kb = pl.program_id(qi_axis + 1)
+    kb = step = pl.program_id(qi_axis + 1)
     nkb = pl.num_programs(qi_axis + 1)
+    if window is not None:
+        kb = step + _first_kb(qi, bq, bk, window)
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -174,7 +253,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
         s = jax.lax.dot_general(
             q, k_ref[:], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        s = _mask_s(s, qi, bq, kb, bk, causal, kv_len)
+        s = _mask_s(s, qi, bq, kb, bk, causal, kv_len, window)
         p = jnp.exp(s - lse)                              # exact softmax
         dp = jax.lax.dot_general(
             do, v_ref[:], (((1,), (1,)), ((), ())),
@@ -184,7 +263,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
             ds, k_ref[:], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
-    @pl.when(kb == nkb - 1)
+    @pl.when(step == nkb - 1)
     def _finalize():
         dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -192,7 +271,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
                           scale: float, qi_axis: int = 1, nqb: int = 0,
-                          kv_len: Optional[int] = None):
+                          kv_len: Optional[int] = None,
+                          window: Optional[int] = None, nq: int = 0):
     """dk/dv, streamed: grid ``(..., kj, qx)`` with the q-side axis
     INNERMOST — q/do/o/lse arrive one block at a time while this k-block's
     dk/dv accumulate in VMEM scratch (dv += pᵀ·do; dk += dsᵀ·q·scale).
@@ -202,13 +282,18 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     the FLATTENED (rep, q-block) index of size reps·nqb — the callers'
     q-side index maps decode it — and dk/dv accumulate across the whole
     sweep. ``nqb`` is the per-head q-block count (0 ⇒ no grouping: the
-    axis is plain q-blocks)."""
+    axis is plain q-blocks). Under a ``window`` the per-head sweep
+    holds only the q-blocks that can see this k-block (``nqb`` is then
+    that span, ``nq`` the real q-block count): it starts at the diagonal
+    and ends where the window does."""
     bk, d = k_ref.shape
     bq = q_ref.shape[0]
     kj = pl.program_id(qi_axis)
     qx = pl.program_id(qi_axis + 1)
     nqx = pl.num_programs(qi_axis + 1)
     qb = qx % nqb if nqb else qx
+    if window is not None:
+        qb = qb + (kj * bk) // bq
 
     @pl.when(qx == 0)
     def _init():
@@ -216,6 +301,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     contributes = ((qb + 1) * bq > kj * bk) if causal else (qb >= 0)
+    if window is not None:
+        contributes = (qb <= _last_qb(kj, bq, bk, window)) & (qb < nq)
 
     @pl.when(contributes)
     def _step():
@@ -225,7 +312,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         s = jax.lax.dot_general(
             q, k_ref[:], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        s = _mask_s(s, qb, bq, kj, bk, causal, kv_len)
+        s = _mask_s(s, qb, bq, kj, bk, causal, kv_len, window)
         p = jnp.exp(s - lse)                              # [Bq, Bk]
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -290,26 +377,49 @@ def _lane_of(reps: int):
     return lambda h: h // reps
 
 
+def _windowed_k(nq, nk, bq, bk, window):
+    """(extent of the k axis, (q-block, step) -> k-block) of a q-major
+    streamed grid. Without a window the whole row of blocks and the
+    identity; with one, only the span a q-block can see, counted from its
+    first visible block (clamped at the array's end, where the kernel's
+    causal predicate has already switched the step off)."""
+    if window is None:
+        return nk, lambda i, kb: kb
+    kspan, _ = _window_spans(nq, nk, bq, bk, window)
+    return kspan, lambda i, kb: jnp.minimum(
+        _first_kb(i, bq, bk, window) + kb, nk - 1)
+
+
+def _windowed_q(nq, nk, bq, bk, window):
+    """(per-head extent of the q sweep, (k-block, position) -> q-block)
+    of the k-major dk/dv grid under a window: from the diagonal to the
+    last q-block that sees the k-block."""
+    _, qspan = _window_spans(nq, nk, bq, bk, window)
+    return qspan, lambda j, x: jnp.minimum((j * bk) // bq + x, nq - 1)
+
+
 def _flash_forward_streamed(q, k, v, causal, scale, block_q, block_k, interpret,
-                            kv_len=None):
+                            kv_len=None, window=None):
     b, h, t, d = q.shape
     hkv, tk = k.shape[1], k.shape[2]
     kv_of = _kv_head_of(h, hkv)
-    grid = (b * h, pl.cdiv(t, block_q), pl.cdiv(tk, block_k))
+    nkw, kblk = _windowed_k(pl.cdiv(t, block_q), pl.cdiv(tk, block_k),
+                            block_q, block_k, window)
+    grid = (b * h, pl.cdiv(t, block_q), nkw)
     qr = q.reshape(b * h, t, d)
     kr = k.reshape(b * hkv, tk, d)
     vr = v.reshape(b * hkv, tk, d)
     kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
-                               kv_len=kv_len)
+                               kv_len=kv_len, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda g, i, kb: (g, i, 0)),
             pl.BlockSpec((None, block_k, d),
-                         lambda g, i, kb: (kv_of(g), kb, 0)),
+                         lambda g, i, kb: (kv_of(g), kblk(i, kb), 0)),
             pl.BlockSpec((None, block_k, d),
-                         lambda g, i, kb: (kv_of(g), kb, 0)),
+                         lambda g, i, kb: (kv_of(g), kblk(i, kb), 0)),
         ],
         out_specs=(
             pl.BlockSpec((None, block_q, d), lambda g, i, kb: (g, i, 0)),
@@ -331,12 +441,14 @@ def _flash_forward_streamed(q, k, v, causal, scale, block_q, block_k, interpret,
 
 
 def _flash_backward_streamed(q, k, v, do, o, lse, causal, scale, block_q, block_k,
-                    interpret, kv_len=None):
+                    interpret, kv_len=None, window=None):
     b, h, t, d = q.shape
     hkv, tk = k.shape[1], k.shape[2]
     reps = h // hkv
     kv_of = _kv_head_of(h, hkv)
     bh = b * h
+    nkw, kblk = _windowed_k(pl.cdiv(t, block_q), pl.cdiv(tk, block_k),
+                            block_q, block_k, window)
     qr = q.reshape(bh, t, d)
     kr, vr = k.reshape(b * hkv, tk, d), v.reshape(b * hkv, tk, d)
     dor, outr = do.reshape(bh, t, d), o.reshape(bh, t, d)
@@ -344,15 +456,15 @@ def _flash_backward_streamed(q, k, v, do, o, lse, causal, scale, block_q, block_
     # dq grid: (bh, qi, kb) — k streamed innermost (q-side blocks pinned).
     q_pin = pl.BlockSpec((None, block_q, d), lambda g, i, kb: (g, i, 0))
     k_str = pl.BlockSpec((None, block_k, d),
-                         lambda g, i, kb: (kv_of(g), kb, 0))
+                         lambda g, i, kb: (kv_of(g), kblk(i, kb), 0))
     lse_pin = pl.BlockSpec((None, block_q, _LSE_LANES),
                            lambda g, i, kb: (g, i, 0))
 
-    with jax.named_scope("attn_bwd_dq"):
+    with jax.named_scope(_scope("attn_bwd_dq", window)):
         dq = pl.pallas_call(
             functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
-                              kv_len=kv_len),
-            grid=(bh, pl.cdiv(t, block_q), pl.cdiv(tk, block_k)),
+                              kv_len=kv_len, window=window),
+            grid=(bh, pl.cdiv(t, block_q), nkw),
             in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin],
             out_specs=q_pin,
             out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
@@ -365,12 +477,24 @@ def _flash_backward_streamed(q, k, v, do, o, lse, causal, scale, block_q, block_
     # head serves). reps==1 keeps the original identity maps (no per-step
     # div/mod in the index computation).
     nqb = pl.cdiv(t, block_q)
+    nq_all = nqb
+    if window is not None:
+        # per-head sweep = the q-blocks that can see a k-block
+        nqb, qblk = _windowed_q(nq_all, pl.cdiv(tk, block_k), block_q,
+                                block_k, window)
 
     def q_head(g, qx):
         return (g // hkv) * h + (g % hkv) * reps + qx // nqb
 
     k_pin = pl.BlockSpec((None, block_k, d), lambda g, j, qx: (g, j, 0))
-    if reps == 1:
+    if window is not None:
+        q_str = pl.BlockSpec(
+            (None, block_q, d),
+            lambda g, j, qx: (q_head(g, qx), qblk(j, qx % nqb), 0))
+        lse_str = pl.BlockSpec(
+            (None, block_q, _LSE_LANES),
+            lambda g, j, qx: (q_head(g, qx), qblk(j, qx % nqb), 0))
+    elif reps == 1:
         q_str = pl.BlockSpec((None, block_q, d),
                              lambda g, j, qx: (g, qx, 0))
         lse_str = pl.BlockSpec((None, block_q, _LSE_LANES),
@@ -381,10 +505,11 @@ def _flash_backward_streamed(q, k, v, do, o, lse, causal, scale, block_q, block_
         lse_str = pl.BlockSpec((None, block_q, _LSE_LANES),
                                lambda g, j, qx: (q_head(g, qx), qx % nqb, 0))
 
-    with jax.named_scope("attn_bwd_dkv"):
+    with jax.named_scope(_scope("attn_bwd_dkv", window)):
         dk, dv = pl.pallas_call(
             functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
-                              nqb=nqb if reps > 1 else 0, kv_len=kv_len),
+                              nqb=nqb if reps > 1 or window else 0,
+                              kv_len=kv_len, window=window, nq=nq_all),
             grid=(b * hkv, pl.cdiv(tk, block_k), reps * nqb),
             in_specs=[q_str, k_pin, k_pin, q_str, q_str, lse_str],
             out_specs=(k_pin, k_pin),
@@ -409,7 +534,8 @@ def _flash_backward_streamed(q, k, v, do, o, lse, causal, scale, block_q, block_
 
 def _flash_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
                   causal: bool, scale: float, qi_axis: int = 1,
-                  kv_len: Optional[int] = None):
+                  kv_len: Optional[int] = None,
+                  window: Optional[int] = None):
     """One grid cell: q-block [Bq, D] against the full K/V [T, D] in VMEM,
     streamed in block_k chunks through the online-softmax recurrence. Also
     writes the log-sum-exp rows the backward kernels reconstruct p from.
@@ -445,7 +571,7 @@ def _flash_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [Bq, Bk]
-        s = _mask_s(s, qi, bq, kb, block_k, causal, kv_len)
+        s = _mask_s(s, qi, bq, kb, block_k, causal, kv_len, window)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -455,7 +581,8 @@ def _flash_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m, l, acc = jax.lax.fori_loop(0, num_kb, body, (m0, l0, a0))
+    kb0 = 0 if window is None else _first_kb(qi, bq, block_k, window)
+    m, l, acc = jax.lax.fori_loop(kb0, num_kb, body, (m0, l0, a0))
     l_safe = jnp.where(l > 0, l, 1.0)
     o_ref[:] = (acc / l_safe).astype(o_ref.dtype)
     lse_ref[:] = jnp.broadcast_to(m + jnp.log(l_safe), (bq, _LSE_LANES))
@@ -463,7 +590,8 @@ def _flash_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
 
 def _flash_bwd_dq_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
                          *, block_k: int, causal: bool, scale: float,
-                         qi_axis: int = 1, kv_len: Optional[int] = None):
+                         qi_axis: int = 1, kv_len: Optional[int] = None,
+                         window: Optional[int] = None):
     """dq for one q-block: recompute p from (q, k, lse) per k-block —
     ds = p·(dpᵀ−D); dq += ds·k·scale. No T×T buffer ever materializes."""
     bq, d = q_ref.shape
@@ -485,7 +613,7 @@ def _flash_bwd_dq_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, d
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        s = _mask_s(s, qi, bq, kb, block_k, causal, kv_len)
+        s = _mask_s(s, qi, bq, kb, block_k, causal, kv_len, window)
         p = jnp.exp(s - lse)                              # exact softmax
         dp = jax.lax.dot_general(
             do, v_blk, (((1,), (1,)), ((), ())),
@@ -495,14 +623,17 @@ def _flash_bwd_dq_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, d
             ds, k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
-    dq = jax.lax.fori_loop(0, num_kb, body, jnp.zeros((bq, d), jnp.float32))
+    kb0 = 0 if window is None else _first_kb(qi, bq, block_k, window)
+    dq = jax.lax.fori_loop(kb0, num_kb, body,
+                           jnp.zeros((bq, d), jnp.float32))
     dq_ref[:] = dq.astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, block_q: int,
                           causal: bool, scale: float, qi_axis: int = 1,
-                          kv_len: Optional[int] = None):
+                          kv_len: Optional[int] = None,
+                          window: Optional[int] = None):
     """dk/dv for one k-block: iterate q-blocks (from the diagonal down when
     causal): dv += pᵀ·do; dk += dsᵀ·q·scale.
 
@@ -527,6 +658,8 @@ def _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     v_blk = v_ref[:]
     num_qb = pl.cdiv(t, block_q)
     qb0 = (kj * bk) // block_q if causal else 0
+    if window is not None:
+        num_qb = jnp.minimum(num_qb, _last_qb(kj, block_q, bk, window) + 1)
 
     def body(qb, carry):
         dk, dv = carry
@@ -537,7 +670,7 @@ def _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        s = _mask_s(s, qb, block_q, kj, bk, causal, kv_len)
+        s = _mask_s(s, qb, block_q, kj, bk, causal, kv_len, window)
         p = jnp.exp(s - lse)                              # [Bq, Bk]
         dv_new = dv + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -575,7 +708,7 @@ def _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
 
 def _flash_forward_resident(q, k, v, causal, scale, block_q, block_k, interpret,
-                            kv_len=None):
+                            kv_len=None, window=None):
     b, h, t, d = q.shape
     hkv, tk = k.shape[1], k.shape[2]
     kv_of = _kv_head_of(h, hkv)
@@ -584,7 +717,8 @@ def _flash_forward_resident(q, k, v, causal, scale, block_q, block_k, interpret,
     kr = k.reshape(b * hkv, tk, d)
     vr = v.reshape(b * hkv, tk, d)
     kernel = functools.partial(_flash_kernel_resident, block_k=block_k,
-                               causal=causal, scale=scale, kv_len=kv_len)
+                               causal=causal, scale=scale, kv_len=kv_len,
+                               window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -611,7 +745,7 @@ def _flash_forward_resident(q, k, v, causal, scale, block_q, block_k, interpret,
 
 
 def _flash_backward_resident(q, k, v, do, o, lse, causal, scale, block_q, block_k,
-                    interpret, kv_len=None):
+                    interpret, kv_len=None, window=None):
     b, h, t, d = q.shape
     hkv, tk = k.shape[1], k.shape[2]
     reps = h // hkv
@@ -625,10 +759,11 @@ def _flash_backward_resident(q, k, v, do, o, lse, causal, scale, block_q, block_
     kv_full = pl.BlockSpec((None, tk, d), lambda g, i: (kv_of(g), 0, 0))
     lse_blk = pl.BlockSpec((None, block_q, _LSE_LANES), lambda g, i: (g, i, 0))
 
-    with jax.named_scope("attn_bwd_dq"):
+    with jax.named_scope(_scope("attn_bwd_dq", window)):
         dq = pl.pallas_call(
             functools.partial(_flash_bwd_dq_kernel_resident, block_k=block_k,
-                              causal=causal, scale=scale, kv_len=kv_len),
+                              causal=causal, scale=scale, kv_len=kv_len,
+                              window=window),
             grid=(bh, pl.cdiv(t, block_q)),
             in_specs=[q_spec, kv_full, kv_full, q_spec, q_spec, lse_blk],
             out_specs=q_spec,
@@ -647,10 +782,11 @@ def _flash_backward_resident(q, k, v, do, o, lse, causal, scale, block_q, block_
     k_spec = pl.BlockSpec((None, block_k, d), lambda g, j, r: (g, j, 0))
 
     dkv_kernel, dkv_scratch = _dkv_resident_scratch(reps, block_k, d)
-    with jax.named_scope("attn_bwd_dkv"):
+    with jax.named_scope(_scope("attn_bwd_dkv", window)):
         dk, dv = pl.pallas_call(
             functools.partial(dkv_kernel, block_q=block_q,
-                              causal=causal, scale=scale, kv_len=kv_len),
+                              causal=causal, scale=scale, kv_len=kv_len,
+                              window=window),
             grid=(b * hkv, pl.cdiv(tk, block_k), reps),
             in_specs=[q_full, k_spec, k_spec, q_full, q_full, lse_full],
             out_specs=(k_spec, k_spec),
@@ -676,45 +812,49 @@ def _resident_fits(tk: int, d: int, dtype) -> bool:
 
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   kv_len=None):
+                   kv_len=None, window=None):
     if _resident_fits(k.shape[2], k.shape[3], k.dtype):
         return _flash_forward_resident(q, k, v, causal, scale, block_q,
-                                       block_k, interpret, kv_len)
+                                       block_k, interpret, kv_len, window)
     return _flash_forward_streamed(q, k, v, causal, scale, block_q,
-                                   block_k, interpret, kv_len)
+                                   block_k, interpret, kv_len, window)
 
 
 def _flash_backward(q, k, v, do, o, lse, causal, scale, block_q, block_k,
-                    interpret, kv_len=None):
+                    interpret, kv_len=None, window=None):
     if _resident_fits(k.shape[2], k.shape[3], k.dtype):
         return _flash_backward_resident(q, k, v, do, o, lse, causal, scale,
-                                        block_q, block_k, interpret, kv_len)
+                                        block_q, block_k, interpret, kv_len,
+                                        window)
     return _flash_backward_streamed(q, k, v, do, o, lse, causal, scale,
-                                    block_q, block_k, interpret, kv_len)
+                                    block_q, block_k, interpret, kv_len,
+                                    window)
 
 
 def _flash_forward_packed(q, k, v, heads, causal, scale, block_q, block_k,
-                          interpret):
+                          interpret, window=None):
     if _resident_fits(k.shape[1], q.shape[2] // heads, k.dtype):
         return _flash_forward_packed_resident(q, k, v, heads, causal, scale,
-                                              block_q, block_k, interpret)
+                                              block_q, block_k, interpret,
+                                              window)
     return _flash_forward_packed_streamed(q, k, v, heads, causal, scale,
-                                          block_q, block_k, interpret)
+                                          block_q, block_k, interpret,
+                                          window)
 
 
 def _flash_backward_packed(q, k, v, do, o, lse, heads, causal, scale,
-                           block_q, block_k, interpret):
+                           block_q, block_k, interpret, window=None):
     if _resident_fits(k.shape[1], q.shape[2] // heads, k.dtype):
         return _flash_backward_packed_resident(
             q, k, v, do, o, lse, heads, causal, scale, block_q, block_k,
-            interpret)
+            interpret, window)
     return _flash_backward_packed_streamed(
         q, k, v, do, o, lse, heads, causal, scale, block_q, block_k,
-        interpret)
+        interpret, window)
 
 
 def _flash_forward_packed_resident(q, k, v, heads, causal, scale, block_q, block_k,
-                          interpret):
+                          interpret, window=None):
     """Forward over the packed [B, T, H·D] layout: grid (b, h, i) with the
     head carried as a lane offset (block index h on the last dim) — no
     [B, H, T, D] transpose ever materializes. Same kernel body. GQA: K/V
@@ -726,7 +866,8 @@ def _flash_forward_packed_resident(q, k, v, heads, causal, scale, block_q, block
     lane = _lane_of(reps)
     grid = (b, heads, pl.cdiv(t, block_q))
     kernel = functools.partial(_flash_kernel_resident, block_k=block_k,
-                               causal=causal, scale=scale, qi_axis=2)
+                               causal=causal, scale=scale, qi_axis=2,
+                               window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -754,7 +895,7 @@ def _flash_forward_packed_resident(q, k, v, heads, causal, scale, block_q, block
 
 
 def _flash_backward_packed_resident(q, k, v, do, o, lse, heads, causal, scale,
-                           block_q, block_k, interpret):
+                           block_q, block_k, interpret, window=None):
     b, t, hd = q.shape
     tk = k.shape[1]
     d = hd // heads
@@ -767,10 +908,11 @@ def _flash_backward_packed_resident(q, k, v, do, o, lse, heads, causal, scale,
     lse_blk = pl.BlockSpec((None, None, block_q, _LSE_LANES),
                            lambda bi, h, i: (bi, h, i, 0))
 
-    with jax.named_scope("attn_bwd_dq"):
+    with jax.named_scope(_scope("attn_bwd_dq", window)):
         dq = pl.pallas_call(
             functools.partial(_flash_bwd_dq_kernel_resident, block_k=block_k,
-                              causal=causal, scale=scale, qi_axis=2),
+                              causal=causal, scale=scale, qi_axis=2,
+                              window=window),
             grid=(b, heads, pl.cdiv(t, block_q)),
             in_specs=[q_spec, kv_full, kv_full, q_spec, q_spec, lse_blk],
             out_specs=q_spec,
@@ -788,10 +930,11 @@ def _flash_backward_packed_resident(q, k, v, do, o, lse, heads, causal, scale,
                           lambda bi, hk, j, r: (bi, j, hk))
 
     dkv_kernel, dkv_scratch = _dkv_resident_scratch(reps, block_k, d)
-    with jax.named_scope("attn_bwd_dkv"):
+    with jax.named_scope(_scope("attn_bwd_dkv", window)):
         dk, dv = pl.pallas_call(
             functools.partial(dkv_kernel, block_q=block_q,
-                              causal=causal, scale=scale, qi_axis=2),
+                              causal=causal, scale=scale, qi_axis=2,
+                              window=window),
             grid=(b, hkv, pl.cdiv(tk, block_k), reps),
             in_specs=[q_full, k_spec, k_spec, q_full, q_full, lse_full],
             out_specs=(k_spec, k_spec),
@@ -803,35 +946,35 @@ def _flash_backward_packed_resident(q, k, v, do, o, lse, heads, causal, scale,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-           kv_len=None):
-    with jax.named_scope("attn_fwd"):
+           kv_len=None, window=None):
+    with jax.named_scope(_scope("attn_fwd", window)):
         out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                                interpret, kv_len)
+                                interpret, kv_len, window)
     return out
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               kv_len=None):
-    with jax.named_scope("attn_fwd"):
+               kv_len=None, window=None):
+    with jax.named_scope(_scope("attn_fwd", window)):
         out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                                  interpret, kv_len)
+                                  interpret, kv_len, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, kv_len,
+def _flash_bwd(causal, scale, block_q, block_k, interpret, kv_len, window,
                residuals, g):
     q, k, v, out, lse = residuals
     return _flash_backward(q, k, v, g, out, lse, causal, scale, block_q,
-                           block_k, interpret, kv_len)
+                           block_k, interpret, kv_len, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _flash_forward_packed_streamed(q, k, v, heads, causal, scale, block_q, block_k,
-                          interpret):
+                          interpret, window=None):
     """Forward over the packed [B, T, H·D] layout: grid (b, h, i, kb) with
     the head carried as a lane offset (block index h on the last dim) — no
     [B, H, T, D] transpose ever materializes. Same streamed kernel body."""
@@ -840,9 +983,11 @@ def _flash_forward_packed_streamed(q, k, v, heads, causal, scale, block_q, block
     d = hd // heads
     reps = hd // k.shape[2]
     lane = _lane_of(reps)
-    grid = (b, heads, pl.cdiv(t, block_q), pl.cdiv(tk, block_k))
+    nkw, kblk = _windowed_k(pl.cdiv(t, block_q), pl.cdiv(tk, block_k),
+                            block_q, block_k, window)
+    grid = (b, heads, pl.cdiv(t, block_q), nkw)
     kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
-                               qi_axis=2)
+                               qi_axis=2, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -850,9 +995,9 @@ def _flash_forward_packed_streamed(q, k, v, heads, causal, scale, block_q, block
             pl.BlockSpec((None, block_q, d),
                          lambda bi, h, i, kb: (bi, i, h)),
             pl.BlockSpec((None, block_k, d),
-                         lambda bi, h, i, kb: (bi, kb, lane(h))),
+                         lambda bi, h, i, kb: (bi, kblk(i, kb), lane(h))),
             pl.BlockSpec((None, block_k, d),
-                         lambda bi, h, i, kb: (bi, kb, lane(h))),
+                         lambda bi, h, i, kb: (bi, kblk(i, kb), lane(h))),
         ],
         out_specs=(
             pl.BlockSpec((None, block_q, d),
@@ -875,7 +1020,7 @@ def _flash_forward_packed_streamed(q, k, v, heads, causal, scale, block_q, block
 
 
 def _flash_backward_packed_streamed(q, k, v, do, o, lse, heads, causal, scale,
-                           block_q, block_k, interpret):
+                           block_q, block_k, interpret, window=None):
     b, t, hd = q.shape
     tk = k.shape[1]
     d = hd // heads
@@ -885,16 +1030,18 @@ def _flash_backward_packed_streamed(q, k, v, do, o, lse, heads, causal, scale,
     q_pin = pl.BlockSpec((None, block_q, d),
                          lambda bi, h, i, kb: (bi, i, h))
     lane = _lane_of(reps)
+    nkw, kblk = _windowed_k(pl.cdiv(t, block_q), pl.cdiv(tk, block_k),
+                            block_q, block_k, window)
     k_str = pl.BlockSpec((None, block_k, d),
-                         lambda bi, h, i, kb: (bi, kb, lane(h)))
+                         lambda bi, h, i, kb: (bi, kblk(i, kb), lane(h)))
     lse_pin = pl.BlockSpec((None, None, block_q, _LSE_LANES),
                            lambda bi, h, i, kb: (bi, h, i, 0))
 
-    with jax.named_scope("attn_bwd_dq"):
+    with jax.named_scope(_scope("attn_bwd_dq", window)):
         dq = pl.pallas_call(
             functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
-                              qi_axis=2),
-            grid=(b, heads, pl.cdiv(t, block_q), pl.cdiv(tk, block_k)),
+                              qi_axis=2, window=window),
+            grid=(b, heads, pl.cdiv(t, block_q), nkw),
             in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin],
             out_specs=q_pin,
             out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
@@ -906,9 +1053,20 @@ def _flash_backward_packed_streamed(q, k, v, do, o, lse, heads, causal, scale,
     # streamed innermost; dk/dv accumulate across every query head this
     # kv head serves. reps==1 keeps identity (div/mod-free) index maps.
     nqb = pl.cdiv(t, block_q)
+    nq_all = nqb
+    if window is not None:
+        nqb, qblk = _windowed_q(nq_all, pl.cdiv(tk, block_k), block_q,
+                                block_k, window)
     k_pin = pl.BlockSpec((None, block_k, d),
                          lambda bi, hk, j, qx: (bi, j, hk))
-    if reps == 1:
+    if window is not None:
+        q_str = pl.BlockSpec(
+            (None, block_q, d), lambda bi, hk, j, qx:
+            (bi, qblk(j, qx % nqb), hk * reps + qx // nqb))
+        lse_str = pl.BlockSpec(
+            (None, None, block_q, _LSE_LANES), lambda bi, hk, j, qx:
+            (bi, hk * reps + qx // nqb, qblk(j, qx % nqb), 0))
+    elif reps == 1:
         q_str = pl.BlockSpec((None, block_q, d),
                              lambda bi, hk, j, qx: (bi, qx, hk))
         lse_str = pl.BlockSpec((None, None, block_q, _LSE_LANES),
@@ -921,10 +1079,12 @@ def _flash_backward_packed_streamed(q, k, v, do, o, lse, heads, causal, scale,
                                lambda bi, hk, j, qx:
                                (bi, hk * reps + qx // nqb, qx % nqb, 0))
 
-    with jax.named_scope("attn_bwd_dkv"):
+    with jax.named_scope(_scope("attn_bwd_dkv", window)):
         dk, dv = pl.pallas_call(
             functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
-                              qi_axis=2, nqb=nqb if reps > 1 else 0),
+                              qi_axis=2,
+                              nqb=nqb if reps > 1 or window else 0,
+                              window=window, nq=nq_all),
             grid=(b, hkv, pl.cdiv(tk, block_k), reps * nqb),
             in_specs=[q_str, k_pin, k_pin, q_str, q_str, lse_str],
             out_specs=(k_pin, k_pin),
@@ -937,28 +1097,28 @@ def _flash_backward_packed_streamed(q, k, v, do, o, lse, heads, causal, scale,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_packed(q, k, v, heads, causal, scale, block_q, block_k,
-                  interpret):
-    with jax.named_scope("attn_fwd"):
+                  interpret, window=None):
+    with jax.named_scope(_scope("attn_fwd", window)):
         out, _ = _flash_forward_packed(q, k, v, heads, causal, scale,
-                                       block_q, block_k, interpret)
+                                       block_q, block_k, interpret, window)
     return out
 
 
 def _flash_packed_fwd(q, k, v, heads, causal, scale, block_q, block_k,
-                      interpret):
-    with jax.named_scope("attn_fwd"):
+                      interpret, window=None):
+    with jax.named_scope(_scope("attn_fwd", window)):
         out, lse = _flash_forward_packed(q, k, v, heads, causal, scale,
-                                         block_q, block_k, interpret)
+                                         block_q, block_k, interpret, window)
     return out, (q, k, v, out, lse)
 
 
 def _flash_packed_bwd(heads, causal, scale, block_q, block_k, interpret,
-                      residuals, g):
+                      window, residuals, g):
     q, k, v, out, lse = residuals
     return _flash_backward_packed(q, k, v, g, out, lse, heads, causal,
-                                  scale, block_q, block_k, interpret)
+                                  scale, block_q, block_k, interpret, window)
 
 
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
@@ -1027,10 +1187,23 @@ def _warn_fallback(reason: str) -> None:
 _warned: set = set()
 
 
+def _check_window(window, causal, t, tk):
+    """A window is a causal self-attention mask; one that reaches the
+    first key is no window (``None``: today's kernels)."""
+    if window is None:
+        return None
+    if not causal or t != tk or window < 1:
+        raise ValueError(
+            f"window={window} needs causal self-attention (causal="
+            f"{causal}, t={t}, tk={tk}) and window >= 1")
+    return None if window >= tk else int(window)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: int = 256, block_k: int = 256,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Fused attention over ``[batch, heads, seq, head_dim]``.
 
     Dispatch: the pallas kernel on TPU backends (or when ``interpret=True``
@@ -1053,10 +1226,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     GQA is zero-copy: K/V may carry ``heads // reps`` heads — the kernels'
     index maps route query head h to kv head h·hkv/h, and the dk/dv grids
     group by kv head, so no repeated K/V ever materializes in HBM.
+
+    ``window`` (static, causal self-attention only): query t attends keys
+    t-window+1 .. t. K/V blocks wholly left of a q-block's window are
+    skipped like those above the diagonal — in the forward, dq and dk/dv
+    grids, streamed (the grid's inner axis shrinks to the visible span,
+    so they are not fetched either) and resident (the in-kernel loops
+    start and stop at the window) alike; :func:`kv_blocks` counts them.
+    ``window=None`` compiles to exactly the kernels without the argument.
     """
     d = q.shape[-1]
     scale = d ** -0.5 if scale is None else scale
     t, tk = q.shape[2], k.shape[2]
+    window = _check_window(window, causal, t, tk)
     if q.shape[1] % k.shape[1]:
         raise ValueError(
             f"query heads {q.shape[1]} not a multiple of kv heads "
@@ -1064,7 +1246,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if interpret is None:
         on_tpu = jax.default_backend() == "tpu"
         if not on_tpu:
-            return reference_attention(q, k, v, causal, scale)
+            return reference_attention(q, k, v, causal, scale, window)
         interpret = False
     if d % 8:
         # Head dim off the 8-row sublane tile: zero-pad the feature dim
@@ -1075,7 +1257,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return flash_attention(
             jnp.pad(q, widths), jnp.pad(k, widths), jnp.pad(v, widths),
             causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-            interpret=interpret)[..., :d]
+            interpret=interpret, window=window)[..., :d]
     # Blocks must divide the seq dims AND be sublane-tile-legal: the
     # in-kernel pl.ds(kb*block, block) K/V slices need block to be a
     # multiple of the sublane tile (8 for f32, 16 for bf16 — 16 covers
@@ -1087,17 +1269,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # don't pay for a full default-sized block (t=8 pads to 16, not 128).
     plan, bq, bk, extra = _plan_dispatch(t, tk, block_q, block_k, causal)
     if plan == "kernel":
-        return _flash(q, k, v, causal, scale, bq, bk, interpret, None)
+        return _flash(q, k, v, causal, scale, bq, bk, interpret, None,
+                      window)
     if plan == "pad":
         widths = ((0, 0), (0, 0), (0, extra - t), (0, 0))
         qp, kp, vp = (jnp.pad(x, widths) for x in (q, k, v))
-        out = _flash(qp, kp, vp, causal, scale, bq, bk, interpret, None)
+        out = _flash(qp, kp, vp, causal, scale, bq, bk, interpret, None,
+                     window)
         return out[:, :, :t, :]
     t_pad, tk_pad, kv_len = extra
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
     kvw = ((0, 0), (0, 0), (0, tk_pad - tk), (0, 0))
     out = _flash(qp, jnp.pad(k, kvw), jnp.pad(v, kvw), causal, scale,
-                 bq, bk, interpret, kv_len if tk_pad != tk else None)
+                 bq, bk, interpret, kv_len if tk_pad != tk else None, None)
     return out[:, :, :t, :]
 
 
@@ -1105,7 +1289,8 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
                            heads: int, causal: bool = True,
                            scale: Optional[float] = None,
                            block_q: int = 256, block_k: int = 256,
-                           interpret: Optional[bool] = None) -> jax.Array:
+                           interpret: Optional[bool] = None,
+                           window: Optional[int] = None) -> jax.Array:
     """Fused attention over the packed ``[batch, seq, heads·head_dim]``
     layout — the projection output's natural shape. The kernel reads each
     head as a lane offset (grid ``(b, h, i)``), so the ``[B, H, T, D]``
@@ -1114,9 +1299,11 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
     ``head_dim`` to be a multiple of 128 (lane-tile alignment for the
     per-head slices); otherwise use :func:`flash_attention`. GQA is
     zero-copy here too: K/V may be packed ``[B, T, Hkv·D]`` with
-    ``heads % Hkv == 0`` — query head h reads kv lane-block h·Hkv/heads."""
+    ``heads % Hkv == 0`` — query head h reads kv lane-block h·Hkv/heads.
+    ``window`` as in :func:`flash_attention`."""
     b, t, hd = q.shape
     tk = k.shape[1]
+    window = _check_window(window, causal, t, tk)
     if hd % heads:
         raise ValueError(
             f"packed dim {hd} is not divisible by heads={heads}")
@@ -1136,7 +1323,7 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
             return x.reshape(b, -1, x.shape[2] // d, d).transpose(0, 2, 1, 3)
         out = flash_attention(to4(q), to4(k), to4(v), causal=causal,
                               scale=scale, block_q=block_q, block_k=block_k,
-                              interpret=interpret)
+                              interpret=interpret, window=window)
         return out.transpose(0, 2, 1, 3).reshape(b, t, hd)
 
     if interpret is None:
@@ -1150,7 +1337,7 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
     plan, bq, bk, extra = _plan_dispatch(t, tk, block_q, block_k, causal)
     if plan == "kernel":
         return _flash_packed(q, k, v, heads, causal, scale, bq, bk,
-                             interpret)
+                             interpret, window)
     if plan == "pad_masked":
         # Ragged non-causal / cross lengths: route through the classic
         # layout, whose pad+mask path keeps the pallas kernel (the packed
@@ -1159,7 +1346,8 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
         return unpacked_fallback()
     widths = ((0, 0), (0, extra - t), (0, 0))
     qp, kp, vp = (jnp.pad(x, widths) for x in (q, k, v))
-    out = _flash_packed(qp, kp, vp, heads, causal, scale, bq, bk, interpret)
+    out = _flash_packed(qp, kp, vp, heads, causal, scale, bq, bk, interpret,
+                        window)
     return out[:, :t, :]
 
 
