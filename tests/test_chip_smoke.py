@@ -90,7 +90,9 @@ def test_smoke_full_size_is_the_registry_width():
     assert size["seq"] >= 2048 and size["ctx_max"] <= cfg.max_seq
     # The resident flash_decode kernel takes this cache (no twin).
     from tony_tpu.ops.attention import _resident_fits
-    assert _resident_fits(size["ctx_max"], cfg.head_dim, cfg.dtype)
+    import jax.numpy as jnp
+    assert _resident_fits(size["ctx_max"], cfg.head_dim,
+                          jnp.dtype(cfg.dtype).itemsize)
     lens = chip_smoke.PROMPT_LENS
     assert min(lens) < 16 < max(lens) and len(lens) >= 4
 

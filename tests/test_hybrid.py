@@ -214,20 +214,29 @@ def test_trains_through_the_normal_path():
             counters[f"attn:kv_blocks_total.{kind}"]
 
 
-def test_counters_make_the_skip_countable():
+@pytest.mark.parametrize("t,swa,causal", [
+    (4096, (512, 15, 64), (512, 36, 64)),       # K/V resident in VMEM
+    (8192, (512, 31, 256), (1024, 36, 64)),     # streamed: the cell
+])
+def test_counters_make_the_skip_countable(t, swa, causal):
     """At a length of several blocks the window visits fewer K/V blocks
-    than the causal layers, which visit the triangle."""
+    than the causal layers, which visit the triangle; the blocks are the
+    kernels' own rule's (the pair's head size 128, bfloat16: 512 a side
+    with K/V resident, streamed 1024 for the causal layers and 512 under
+    the 512-key window), recorded per kernel."""
     profiler.reset_timeline()
-    model = get_model("hybrid-tiny", window=512, n_heads=2, n_kv_heads=2)
+    model = get_model("hybrid-tiny", window=512, dim=256, n_heads=4,
+                      n_kv_heads=2)
     jax.eval_shape(model.init, jax.random.PRNGKey(0),
-                   jnp.zeros((1, 4096), jnp.int32))
+                   jnp.zeros((1, t), jnp.int32))
     c = profiler.counters()
-    # the window sweeps blocks of 512 (8 x 8), the causal layers of 1024
-    assert c["attn:kv_blocks_total.swa"] == 64
-    assert c["attn:kv_blocks_visited.swa"] == 15
-    assert c["attn:kv_blocks_total.full"] == 16
-    assert c["attn:kv_blocks_visited.full"] == 10
-    assert c["attn:kv_blocks_visited.cross"] == 10
+    for kind, (side, visited, total) in (("swa", swa), ("full", causal),
+                                         ("cross", causal)):
+        assert c[f"attn:kv_blocks_total.{kind}"] == total
+        assert c[f"attn:kv_blocks_visited.{kind}"] == visited
+        for kernel in ("fwd", "dq", "dkv"):
+            assert c[f"attn:block_q.{kernel}.{kind}"] == side
+            assert c[f"attn:block_k.{kernel}.{kind}"] == side
 
 
 def test_layer_order_is_checked():

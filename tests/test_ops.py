@@ -17,11 +17,19 @@ def rand_qkv(b=2, h=3, t=64, d=16, dtype=jnp.float32, tk=None):
             jax.random.normal(ks[2], (b, h, tk, d), dtype))
 
 
+# Explicit blocks (several to a side at these lengths) beside the rule's
+# own choice (``None``: one block at these lengths; the multi-block rule
+# paths have their own cases below).
+BLOCKS = pytest.mark.parametrize("block", [16, None],
+                                 ids=["blocks16", "rule"])
+
+
+@BLOCKS
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_kernel_matches_reference(causal):
+def test_flash_kernel_matches_reference(causal, block):
     q, k, v = rand_qkv()
-    out = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16,
-                          interpret=True)
+    out = flash_attention(q, k, v, causal=causal, block_q=block,
+                          block_k=block, interpret=True)
     ref = reference_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
@@ -89,23 +97,30 @@ def _assert_kernel_matches_reference(q, k, v, causal, block=32):
                                    atol=2e-5, rtol=2e-5)
 
 
-def test_flash_ragged_noncausal_pads_and_masks():
+RULE_OR_32 = pytest.mark.parametrize("block", [32, None],
+                                     ids=["blocks32", "rule"])
+
+
+@RULE_OR_32
+def test_flash_ragged_noncausal_pads_and_masks(block):
     # Non-causal ragged shapes used to fall back to the reference (end-
     # padded keys would soak up softmax mass); now the kernels mask the
     # padded keys via the static kv_len and stay on the kernel path.
     q, k, v = rand_qkv(t=48, tk=40)
-    _assert_kernel_matches_reference(q, k, v, causal=False)
+    _assert_kernel_matches_reference(q, k, v, causal=False, block=block)
 
 
+@RULE_OR_32
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_cross_lengths_run_kernel(causal):
+def test_flash_cross_lengths_run_kernel(causal, block):
     # Cross-attention lengths (t != tk, neither dividing the blocks).
     q, k, v = rand_qkv(b=1, h=2, t=40, d=16, tk=24)
-    _assert_kernel_matches_reference(q, k, v, causal=causal)
+    _assert_kernel_matches_reference(q, k, v, causal=causal, block=block)
 
 
+@RULE_OR_32
 @pytest.mark.parametrize("streamed", [False, True])
-def test_flash_ragged_streamed_kernels(streamed):
+def test_flash_ragged_streamed_kernels(streamed, block):
     # The same mask through the streamed-KV kernel family.
     from tony_tpu.ops import attention as att
 
@@ -113,19 +128,98 @@ def test_flash_ragged_streamed_kernels(streamed):
     att._RESIDENT_KV_BYTES = 0 if streamed else old
     try:
         q, k, v = rand_qkv(b=1, h=2, t=40, tk=24, d=16)
-        _assert_kernel_matches_reference(q, k, v, causal=False)
+        _assert_kernel_matches_reference(q, k, v, causal=False, block=block)
     finally:
         att._RESIDENT_KV_BYTES = old
 
 
+@pytest.mark.parametrize("causal,streamed", [(True, False), (True, True),
+                                             (False, False)])
+def test_rule_blocks_several_to_a_side(causal, streamed):
+    """The rule's choice where it is more than one block a side, values
+    and gradients: t = 1030 is ragged, pads to the next lane multiple
+    (1152) and runs three blocks of 384 a side — resident, and streamed
+    (where the rule's bound is 1024: two blocks of 576)."""
+    from tony_tpu.ops import attention as att
+
+    old = att._RESIDENT_KV_BYTES
+    att._RESIDENT_KV_BYTES = 0 if streamed else old
+    try:
+        want = 576 if streamed else 384
+        assert att._plan_dispatch(1030, 1030, None, None, causal, None, 16,
+                                  4)[1].dkv == (want, want)
+        q, k, v = rand_qkv(b=1, h=1, t=1030, d=16)
+        _assert_kernel_matches_reference(q, k, v, causal=causal, block=None)
+    finally:
+        att._RESIDENT_KV_BYTES = old
+
+
+@pytest.mark.parametrize("shape,want", [
+    # (t, tk, causal, window, head size, item size) -> plan, (bq, bk), pad
+    ((2048, 2048, True, None, 128, 2), ("kernel", (512, 512), None)),
+    ((512, 512, True, None, 128, 2), ("kernel", (512, 512), None)),
+    ((4096, 4096, True, None, 128, 2), ("kernel", (512, 512), None)),
+    ((8192, 8192, True, None, 128, 2), ("kernel", (1024, 1024), None)),
+    ((8192, 8192, True, 512, 128, 2), ("kernel", (512, 512), None)),
+    ((8192, 8192, True, 100, 128, 2), ("kernel", (512, 512), None)),
+    ((8192, 8192, True, 2000, 128, 2), ("kernel", (1024, 1024), None)),
+    ((2048, 2048, True, 512, 128, 2), ("kernel", (512, 512), None)),
+    ((8192, 8192, True, None, 128, 4), ("kernel", (512, 512), None)),
+    ((8192, 8192, True, None, 256, 2), ("kernel", (512, 512), None)),
+    ((16384, 16384, True, None, 64, 2), ("kernel", (1024, 1024), None)),
+    ((384, 384, True, None, 128, 2), ("kernel", (384, 384), None)),
+    ((640, 640, False, None, 128, 2), ("kernel", (320, 320), None)),
+    ((2048, 512, False, None, 128, 2), ("kernel", (512, 512), None)),
+    ((1000, 1000, True, None, 128, 2), ("pad", (512, 512), 1024)),
+    ((1030, 1030, True, None, 128, 2), ("pad", (384, 384), 1152)),
+    ((100, 100, True, None, 128, 2), ("pad", (112, 112), 112)),
+    ((8, 8, True, None, 128, 2), ("pad", (16, 16), 16)),
+    ((1000, 777, False, None, 128, 2),
+     ("pad_masked", (512, 448), (1024, 896, 777))),
+    ((40, 24, True, None, 16, 4), ("pad_masked", (48, 32), (48, 32, 24))),
+])
+def test_rule_picks_blocks_from_the_shape(shape, want):
+    """``block_q=None``: the blocks follow from the call's shapes — 512 a
+    side with K/V resident, 1024 streamed, never wider than a window (or
+    512), halved for rows wider than 128 x bf16, fitted to the (padded)
+    lengths — and all three kernels divide the lengths they run."""
+    from tony_tpu.ops import attention as att
+
+    t, tk, causal, window, d, itemsize = shape
+    plan, blocks, extra = att._plan_dispatch(t, tk, None, None, causal,
+                                             window, d, itemsize)
+    assert (plan, blocks.fwd, extra) == want
+    t_run, tk_run = (extra[:2] if isinstance(extra, tuple)
+                     else (extra or t, extra or tk))
+    for bq, bk in blocks:
+        assert t_run % bq == 0 and tk_run % bk == 0
+        assert bq % 16 == 0 and bk % 16 == 0
+    facts = att.block_facts(t, tk, None, None, causal, window, d, itemsize)
+    assert (facts["block_q.dkv"], facts["block_k.dkv"]) == blocks.dkv
+    assert facts["kv_blocks_visited"] <= facts["kv_blocks_total"] == \
+        (t_run // blocks.fwd[0]) * (tk_run // blocks.fwd[1])
+
+
+def test_explicit_blocks_are_honoured_and_must_come_in_pairs():
+    from tony_tpu.ops import attention as att
+
+    assert att._plan_dispatch(2048, 2048, 256, 128, True) == (
+        "kernel", att.Blocks(*[(256, 128)] * 3), None)
+    assert att._plan_dispatch(40, 40, 16, 16, True)[1:] == (
+        att.Blocks(*[(16, 16)] * 3), 48)
+    with pytest.raises(ValueError, match="both or neither"):
+        att._plan_dispatch(2048, 2048, 256, None, True)
+
+
+@RULE_OR_32
 @pytest.mark.parametrize("d", [20, 12])
-def test_flash_odd_head_dim_runs_kernel(d):
+def test_flash_odd_head_dim_runs_kernel(d, block):
     # head_dim off the 8-row sublane tile: zero-padded feature dim, still
     # the kernel path — values and grads exact, output dtype/shape kept.
     q, k, v = rand_qkv(b=1, h=2, t=32, d=d)
-    _assert_kernel_matches_reference(q, k, v, causal=True)
+    _assert_kernel_matches_reference(q, k, v, causal=True, block=block)
     q, k, v = rand_qkv(b=1, h=2, t=40, tk=24, d=d)
-    _assert_kernel_matches_reference(q, k, v, causal=False)
+    _assert_kernel_matches_reference(q, k, v, causal=False, block=block)
 
 
 def test_flash_kernel_bf16():
@@ -138,15 +232,16 @@ def test_flash_kernel_bf16():
                                atol=2e-2, rtol=2e-2)
 
 
+@BLOCKS
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_grad_matches_reference_grad(causal):
+def test_flash_grad_matches_reference_grad(causal, block):
     q, k, v = rand_qkv(b=1, h=2, t=32, d=8)
     # Non-uniform cotangent so dq/dk/dv all get exercised asymmetrically.
     w = jax.random.normal(jax.random.PRNGKey(7), (1, 2, 32, 8))
 
     def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, causal=causal, block_q=16,
-                                block_k=16, interpret=True) * w).sum()
+        return (flash_attention(q, k, v, causal=causal, block_q=block,
+                                block_k=block, interpret=True) * w).sum()
 
     def loss_ref(q, k, v):
         return (reference_attention(q, k, v, causal=causal) * w).sum()
@@ -158,7 +253,8 @@ def test_flash_grad_matches_reference_grad(causal):
                                    atol=2e-5, rtol=2e-5)
 
 
-def test_flash_padded_grad_matches_reference():
+@BLOCKS
+def test_flash_padded_grad_matches_reference(block):
     # Causal self-attention with T not divisible by the blocks takes the
     # zero-pad path (not the reference fallback); grads must stay exact
     # including the pad-slice boundary.
@@ -166,8 +262,8 @@ def test_flash_padded_grad_matches_reference():
     w = jax.random.normal(jax.random.PRNGKey(3), (1, 2, 40, 8))
 
     def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, causal=True, block_q=16,
-                                block_k=16, interpret=True) * w).sum()
+        return (flash_attention(q, k, v, causal=True, block_q=block,
+                                block_k=block, interpret=True) * w).sum()
 
     def loss_ref(q, k, v):
         return (reference_attention(q, k, v, causal=True) * w).sum()

@@ -290,11 +290,48 @@ def test_submitted_jobs_timeline_reaches_tony_history(tmp_path):
     # Compiled or, with jax's persistent cache warm, loaded.
     assert c.get("programs_compiled", 0) + c.get("programs_loaded", 0) >= 1
     assert c["saves"] == 1 and c["save_stall_s"] > 0
+    # the dense decoder's flash calls: one block of the 16-token sequence
+    # for each of the three kernels, recorded once by the first trace
+    assert {k: v for k, v in c.items() if k.startswith("attn:")} == {
+        "attn:kv_blocks_visited.dense": 1, "attn:kv_blocks_total.dense": 1,
+        **{f"attn:block_{side}.{kernel}.dense": 16 for side in "qk"
+           for kernel in ("fwd", "dq", "dkv")}}
     assert any(b["kind"] in ("compile", "load") for b in timeline["builds"])
     shown = render_show(detail)
     assert "task start timelines:" in shown
     assert "tony:create_train_state" in shown
     assert "program(s) built or loaded" in shown
+
+
+@pytest.mark.parametrize("kw,t,side,visited,total", [
+    # head size 128: the packed call; the Mistral cell's length
+    (dict(dim=256, n_heads=2, n_kv_heads=1, max_seq=2048), 2048, 512, 10,
+     16),
+    # head size 16: the classic layout; one block of a short sequence
+    (dict(), 48, 48, 1, 1),
+])
+def test_dense_attention_records_its_blocks_once(kw, t, side, visited,
+                                                 total):
+    """``Attention`` puts the tile shape the kernels' rule gave each of its
+    three flash kernels, and the K/V blocks its forward visits, on the
+    task's timeline at trace time — once, however often it is traced (the
+    scan traces the block, init and apply trace the model)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tony_tpu.models import get_model
+
+    model = get_model("llama-tiny", attention="flash", **kw)
+    toks = jnp.zeros((1, t), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), toks)
+    jax.eval_shape(model.apply, params, toks)
+    c = {k: v for k, v in profiler.timeline()["counters"].items()
+         if k.startswith("attn:")}
+    assert c == {
+        "attn:kv_blocks_visited.dense": visited,
+        "attn:kv_blocks_total.dense": total,
+        **{f"attn:block_{s}.{kernel}.dense": side for s in "qk"
+           for kernel in ("fwd", "dq", "dkv")}}
 
 
 @pytest.fixture(scope="module")

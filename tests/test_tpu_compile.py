@@ -42,7 +42,11 @@ def _one(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _packed(grad, hkv):
+def _packed(grad, hkv, t=T):
+    """The packed call with no blocks named: what the kernels' rule picks
+    for the length must compile and fit VMEM — K/V resident up to 4096
+    (blocks of 512), streamed at 8192 (1024); the batch shrinks as the
+    sequence grows so every case holds 8192 tokens."""
     from tony_tpu.ops import flash_attention_packed
 
     def fwd(q, k, v):
@@ -50,9 +54,9 @@ def _packed(grad, hkv):
                                       interpret=False)
 
     def build(topo):
-        sh = _one(topo)
-        q = jax.ShapeDtypeStruct((B, T, H * D), jnp.bfloat16, sharding=sh)
-        kv = jax.ShapeDtypeStruct((B, T, hkv * D), jnp.bfloat16,
+        sh, b = _one(topo), B * T // t
+        q = jax.ShapeDtypeStruct((b, t, H * D), jnp.bfloat16, sharding=sh)
+        kv = jax.ShapeDtypeStruct((b, t, hkv * D), jnp.bfloat16,
                                   sharding=sh)
         if not grad:
             return fwd, (q, kv, kv)
@@ -117,6 +121,10 @@ CASES = {
     "flash_packed_fwd_mha": _packed(grad=False, hkv=H),
     "flash_packed_fwd_bwd_mha": _packed(grad=True, hkv=H),
     "flash_packed_fwd_bwd_gqa8": _packed(grad=True, hkv=HKV),
+    "flash_packed_fwd_bwd_gqa8_t512": _packed(grad=True, hkv=HKV, t=512),
+    "flash_packed_fwd_bwd_gqa8_t1024": _packed(grad=True, hkv=HKV, t=1024),
+    "flash_packed_fwd_bwd_gqa8_t4096": _packed(grad=True, hkv=HKV, t=4096),
+    "flash_packed_fwd_bwd_gqa8_t8192": _packed(grad=True, hkv=HKV, t=8192),
     "flash_decode_mha_ctx4096": _decode(hkv=H),
     "flash_decode_gqa8_ctx4096": _decode(hkv=HKV),
     "quant_dot_4096x4096x11008": _quant_dot,

@@ -19,7 +19,7 @@ from tony_tpu import train
 from tony_tpu.models import get_model
 
 state = train.create_train_state(
-    get_model("llama-tiny"), optax.adamw(1e-3),
+    get_model("llama-tiny", attention="flash"), optax.adamw(1e-3),
     jnp.zeros((2, 16), jnp.int32), jax.random.PRNGKey(0))
 step = train.make_train_step(
     loss_of=lambda logits, b: train.next_token_loss(logits, b["x"]))

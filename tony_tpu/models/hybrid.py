@@ -136,13 +136,6 @@ class HybridConfig:
         return self.ssm_dt_rank or math.ceil(self.dim / 16)
 
 
-def _count_once(name: str, n: float) -> None:
-    """A trace-time fact on the task's timeline: recorded by the first
-    trace, not again by init, remat or a re-trace."""
-    if name not in profiler.counters():
-        profiler.count(name, n)
-
-
 def _dense(cfg, feats, name, bias=False):
     if cfg.quant:
         from tony_tpu.ops.quant import QuantDense
@@ -212,7 +205,8 @@ class Mamba(nn.Module):
             jnp.float32)
         d_skip = self.param("d_skip", nn.initializers.ones, (e,),
                             jnp.float32)
-        _count_once("ssm:chunks", ssm.n_chunks(u.shape[1], cfg.scan_chunk))
+        profiler.count_once("ssm:chunks",
+                            ssm.n_chunks(u.shape[1], cfg.scan_chunk))
         y = ssm.selective_scan(
             xc, dt, -jnp.exp(a_log), bm, cm, d_skip, chunk=cfg.scan_chunk,
             state_dtype=cfg.scan_dtype, interpret=cfg.interpret
@@ -267,15 +261,14 @@ class DiffAttention(nn.Module):
             qkv = _dense(cfg, (nh + 2 * nkv) * hd, "wqkv", bias=True)(u)
             q, k, v = jnp.split(qkv, (nh * hd, (nh + nkv) * hd), axis=-1)
         window = cfg.window if self.kind == "swa" else None
-        # Blocks by what the call sweeps (v5e, 1 x 8192 x 40 heads of 128,
-        # forward + backward, PR 27): the causal square 87.6 ms at 256,
-        # 38.5 at 512, 25.4 at 1024 — at 256 the 40,960 grid steps cost
-        # more than their matmuls; a 512-key window 12.8 / 7.7 / 9.9.
-        # Shorter sequences get the largest block that divides them.
-        block = 512 if window else 1024
-        visited, total = attn_ops.kv_blocks(t, t, block, block, True, window)
-        _count_once(f"attn:kv_blocks_visited.{self.kind}", visited)
-        _count_once(f"attn:kv_blocks_total.{self.kind}", total)
+        # Blocks are the kernels' own rule's (``ops.attention._pick_blocks``:
+        # at the cell's 8192, streamed, 1024 for the causal square and 512
+        # under the 512-key window; 512 with K/V resident): recorded here
+        # so that a timeline says which tile shape the run used.
+        for name, n in attn_ops.block_facts(
+                t, t, causal=True, window=window, head_dim=2 * hd,
+                itemsize=k.dtype.itemsize).items():
+            profiler.count_once(f"attn:{name}.{self.kind}", n)
         # [q_1, 0] and [0, q_2]: each half-head against the pair's 128-wide
         # [k_1, k_2].
         q4 = q.reshape(b, t, pairs, 2, hd)
@@ -289,8 +282,7 @@ class DiffAttention(nn.Module):
         qp = jnp.stack([first, second], axis=3)
         out = attn_ops.flash_attention_packed(
             qp.reshape(b, t, nh * 2 * hd), k, v, nh, causal=True,
-            scale=hd ** -0.5, block_q=block, block_k=block, window=window,
-            interpret=cfg.interpret)
+            scale=hd ** -0.5, window=window, interpret=cfg.interpret)
         out = out.reshape(b, t, pairs, 2, 2 * hd)
         lam0 = 0.8 - 0.6 * math.exp(-0.3 * self.index)
         vec = lambda name: self.param(
@@ -368,7 +360,8 @@ class HybridDecoder(nn.Module):
                 x, *(streams[s] for s in mixer.consumes))
             streams.update(zip(mixer.emits, emitted))
         for kind in KINDS:
-            _count_once(f"model:layers.{kind}", cfg.layers.count(kind))
+            profiler.count_once(f"model:layers.{kind}",
+                                cfg.layers.count(kind))
         x = LayerNorm(cfg.norm_eps, name="final_norm")(x)
         # Tied head: the same table, transposed.
         if cfg.xent_chunk and targets is not None:

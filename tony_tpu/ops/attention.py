@@ -11,14 +11,15 @@ dominant win for long sequences.
 
 Public entry :func:`flash_attention` dispatches: pallas kernel on TPU (or
 ``interpret=True`` for CPU tests), pure-JAX :func:`reference_attention`
-elsewhere; the backward pass is the reference VJP under ``jax.checkpoint``
-semantics (recompute, no saved T×T residuals).
+elsewhere; the backward is two more kernels (dq; dk/dv) that recompute p
+from the saved log-sum-exp rows (no saved T×T residuals).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,14 @@ _NEG_INF = -1e30
 # the array dims. Lane-replicating is the same layout the reference JAX
 # TPU flash kernel uses for its l/m residuals.
 _LSE_LANES = 8
+
+
+class Blocks(NamedTuple):
+    """``(block_q, block_k)`` of the forward, the dq and the dk/dv call
+    of one attention (static: a ``nondiff`` argument of the custom VJPs)."""
+    fwd: tuple
+    dq: tuple
+    dkv: tuple
 
 
 def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -63,12 +72,6 @@ def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _causal_mask(s, qi, bq, kb, block_k):
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
-
-
 def _scope(name: str, window) -> str:
     """Device scope of a kernel call: windowed calls carry ``_win`` (their
     custom calls are then named ``%attn_fwd_win.N`` ...), so a trace tells
@@ -76,11 +79,12 @@ def _scope(name: str, window) -> str:
     return name if window is None else f"{name}_win"
 
 
-def _first_kb(qi, bq, block_k, window):
-    """First k-block a q-block sees under a causal window of ``window``
-    keys (query t sees keys t-window+1 .. t): the block holding the
-    earliest key of the block's first query. ``qi`` may be traced."""
-    return jnp.maximum(qi * bq - (window - 1), 0) // block_k
+def _first_kb(q0, block_k, window):
+    """First k-block seen by a run of queries starting at position ``q0``
+    (may be traced) under a causal window of ``window`` keys (query t
+    sees keys t-window+1 .. t): the block holding the earliest key of
+    the first query."""
+    return jnp.maximum(q0 - (window - 1), 0) // block_k
 
 
 def _last_qb(kj, bq, block_k, window):
@@ -101,14 +105,32 @@ def _window_spans(nq, nk, bq, block_k, window):
     return kspan, qspan
 
 
-def kv_blocks(t: int, tk: int, block_q: int = 256, block_k: int = 256,
-              causal: bool = True, window: Optional[int] = None) -> tuple:
+def kv_blocks(t: int, tk: int, block_q: Optional[int] = None,
+              block_k: Optional[int] = None, causal: bool = True,
+              window: Optional[int] = None, head_dim: int = 128,
+              itemsize: int = 2) -> tuple:
     """(visited, total) K/V blocks of one head's forward grid at the
     blocks :func:`flash_attention` would pick for ``t`` x ``tk``: what
     the causal triangle and the window leave of the ``nq * nk`` square.
-    Pure arithmetic (the counters ``attn:kv_blocks_visited`` / ``_total``
-    and the tests read it); the kernels skip exactly these blocks."""
-    _, bq, bk, extra = _plan_dispatch(t, tk, block_q, block_k, causal)
+    Pure arithmetic (the tests read it); the kernels skip exactly these
+    blocks."""
+    facts = block_facts(t, tk, block_q, block_k, causal, window, head_dim,
+                        itemsize)
+    return facts["kv_blocks_visited"], facts["kv_blocks_total"]
+
+
+def block_facts(t: int, tk: int, block_q: Optional[int] = None,
+                block_k: Optional[int] = None, causal: bool = True,
+                window: Optional[int] = None, head_dim: int = 128,
+                itemsize: int = 2) -> dict:
+    """What a call of these shapes runs, as the counters a model records
+    once at trace time (``attn:<key>.<kind>``): the blocks each of the
+    three kernels gets (``block_q.fwd`` ... ``block_k.dkv``) and
+    :func:`kv_blocks`' two counts. Pure arithmetic: the same plan the
+    entry points make."""
+    _, blocks, extra = _plan_dispatch(t, tk, block_q, block_k, causal,
+                                      window, head_dim, itemsize)
+    bq, bk = blocks.fwd
     if isinstance(extra, tuple):
         t, tk = extra[0], extra[1]
     elif extra:
@@ -121,36 +143,112 @@ def kv_blocks(t: int, tk: int, block_q: int = 256, block_k: int = 256,
         hi = min(nk - 1, ((qi + 1) * bq - 1) // bk) if causal else nk - 1
         lo = max(qi * bq - window + 1, 0) // bk if window else 0
         visited += hi - lo + 1
-    return visited, nq * nk
+    facts = {"kv_blocks_visited": visited, "kv_blocks_total": nq * nk}
+    for kernel, (kq, kk) in blocks._asdict().items():
+        facts[f"block_q.{kernel}"] = kq
+        facts[f"block_k.{kernel}"] = kk
+    return facts
 
 
-def _mask_s(s, qi, bq, kb, block_k, causal, kv_len, window=None):
-    """Score masking shared by every kernel body: the causal triangle
-    and/or the key-length mask for end-padded K/V (``kv_len`` = the REAL
-    key count, a static int — ``None`` means no padded keys to hide),
-    and/or the sliding window (``window`` keys back from the query, the
-    query's own included; causal calls only). All are resolved at trace
-    time, so the unmasked paths compile to exactly the pre-mask kernels.
-    Padded keys never fully mask a k-block (padding rounds up to the block
-    size, so the last block keeps >= 1 real key) — the online-softmax max
-    can't get stuck at -inf. Under a window a row may see nothing of the
-    first block its q-block visits; what it accumulates there at the
-    running max's floor is multiplied by exp(floor - real max) = 0 when
-    its first real block arrives (blocks are visited in ascending order
-    and the diagonal is always real)."""
-    if window is not None:
-        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                        s.shape, 1)
-        return jnp.where((q_pos >= k_pos) & (q_pos - k_pos < window), s,
-                         _NEG_INF)
+def _mask_s(s, q0, k0, causal, kv_len, window=None):
+    """Score masking shared by every kernel body, for a tile whose first
+    query sits at position ``q0`` and first key at ``k0`` (either may be
+    traced): the causal triangle and/or the key-length mask for
+    end-padded K/V (``kv_len`` = the REAL key count, a static int —
+    ``None`` means no padded keys to hide), and/or the sliding window
+    (``window`` keys back from the query, the query's own included; causal
+    calls only). All are resolved at trace time, so the unmasked paths
+    compile to exactly the pre-mask kernels. Padded keys never fully mask
+    a k-block (padding rounds up to the block size, so the last block
+    keeps >= 1 real key) — the online-softmax max can't get stuck at
+    -inf. Under a window a row may see nothing of the first block its
+    q-block visits; what it accumulates there at the running max's floor
+    is multiplied by exp(floor - real max) = 0 when its first real block
+    arrives (blocks are visited in ascending order and the diagonal is
+    always real)."""
+    if not causal and kv_len is None:
+        return s
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     if causal:
-        s = _causal_mask(s, qi, bq, kb, block_k)
+        q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        keep = q_pos >= k_pos        # no subtraction where no window asks
+        if window is not None:
+            keep &= q_pos - k_pos < window
+        s = jnp.where(keep, s, _NEG_INF)
     if kv_len is not None:
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                        s.shape, 1)
         s = jnp.where(k_pos < kv_len, s, _NEG_INF)
     return s
+
+
+def _mask_at(q0, k0, causal, kv_len, window):
+    """``_mask_s`` with a tile's position and the call's statics bound."""
+    return functools.partial(_mask_s, q0=q0, k0=k0, causal=causal,
+                             kv_len=kv_len, window=window)
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))      # a @ b.T
+_NN = ((1,), (0,))      # a @ b
+_TN = ((0,), (0,))      # a.T @ b
+
+# The three tile expressions below are the whole arithmetic of the six
+# kernel bodies: a streamed body and its resident twin differ only in
+# where the blocks come from (the grid, or a loop over VMEM) and where
+# the running state lives (scratch, or the loop's carry). ``mask`` is
+# ``_mask_s`` with the tile's position bound. Matmul inputs stay in their
+# storage dtype (bf16): bf16 x bf16 products are exact in the MXU's f32
+# accumulator, so this loses nothing over upcast-then-dot — and doesn't
+# rely on Mosaic folding converts back out of an f32 matmul. The softmax
+# runs in f32; ``scale`` multiplies the f32 scores, never a bf16 operand.
+
+
+def _fwd_tile(q, k_blk, v_blk, carry, mask, scale):
+    """One online-softmax step: the ``[Bq, Bk]`` score tile folded into
+    the running (max m, normalizer l, accumulator acc); p casts back to
+    the storage dtype for p.v."""
+    m, l, acc = carry
+    s = mask(_dot(q, k_blk, _NT) * scale)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m - m_new)
+    l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_new = acc * alpha + _dot(p.astype(v_blk.dtype), v_blk, _NN)
+    return m_new, l_new, acc_new
+
+
+def _fwd_out(m, l, acc, o_ref, lse_ref):
+    l_safe = jnp.where(l > 0, l, 1.0)
+    o_ref[:] = (acc / l_safe).astype(o_ref.dtype)
+    lse_ref[:] = jnp.broadcast_to(m + jnp.log(l_safe), lse_ref.shape)
+
+
+def _row_dot(do, o):
+    """D = rowsum(do * o), the softmax backward's per-row term, [Bq, 1]."""
+    return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                   axis=-1, keepdims=True)
+
+
+def _dq_tile(q, do, lse, D, k_blk, v_blk, mask, scale):
+    """dq's share of one tile: p recomputed exactly from (q, k, lse),
+    ds = p * (dp - D), returns ds . k * scale in f32."""
+    p = jnp.exp(mask(_dot(q, k_blk, _NT) * scale) - lse)
+    dp = _dot(do, v_blk, _NT)
+    ds = (p * (dp - D)).astype(k_blk.dtype)
+    return _dot(ds, k_blk, _NN) * scale
+
+
+def _dkv_tile(q, do, o, lse, k_blk, v_blk, mask, scale):
+    """(dk, dv) shares of one tile, f32: dv = p^T . do, dk = ds^T . q *
+    scale, with p recomputed exactly from (q, k, lse)."""
+    p = jnp.exp(mask(_dot(q, k_blk, _NT) * scale) - lse)
+    dv = _dot(p.astype(do.dtype), do, _TN)
+    dp = _dot(do, v_blk, _NT)
+    ds = (p * (dp - _row_dot(do, o))).astype(q.dtype)
+    return _dot(ds, q, _TN) * scale, dv
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
@@ -178,7 +276,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     kb = step = pl.program_id(qi_axis + 1)
     nkb = pl.num_programs(qi_axis + 1)
     if window is not None:
-        kb = step + _first_kb(qi, bq, bk, window)
+        kb = step + _first_kb(qi * bq, bk, window)
 
     @pl.when(step == 0)
     def _init():
@@ -190,35 +288,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
     @pl.when(contributes)
     def _step():
-        # Matmul inputs stay in their storage dtype (bf16): bf16×bf16
-        # products are exact in the MXU's f32 accumulator, so this loses
-        # nothing over upcast-then-dot. Softmax math runs in f32; p casts
-        # back for the PV matmul.
-        q = q_ref[:]
-        s = jax.lax.dot_general(
-            q, k_ref[:], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [Bq, Bk]
-        s = _mask_s(s, qi, bq, kb, bk, causal, kv_len, window)
-        m = m_scr[:, 0:1]
-        l = l_scr[:, 0:1]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        mask = _mask_at(qi * bq, kb * bk, causal, kv_len, window)
+        m, l, acc = _fwd_tile(
+            q_ref[:], k_ref[:], v_ref[:],
+            (m_scr[:, 0:1], l_scr[:, 0:1], acc_scr[:]), mask, scale)
+        acc_scr[:] = acc
+        m_scr[:] = jnp.broadcast_to(m, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l, l_scr.shape)
 
     @pl.when(step == nkb - 1)
     def _finalize():
-        m = m_scr[:, 0:1]
-        l = l_scr[:, 0:1]
-        l_safe = jnp.where(l > 0, l, 1.0)
-        o_ref[:] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[:] = jnp.broadcast_to(m + jnp.log(l_safe),
-                                      (bq, _LSE_LANES))
+        _fwd_out(m_scr[:, 0:1], l_scr[:, 0:1], acc_scr[:], o_ref, lse_ref)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
@@ -235,7 +315,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     kb = step = pl.program_id(qi_axis + 1)
     nkb = pl.num_programs(qi_axis + 1)
     if window is not None:
-        kb = step + _first_kb(qi, bq, bk, window)
+        kb = step + _first_kb(qi * bq, bk, window)
 
     @pl.when(step == 0)
     def _init():
@@ -245,23 +325,11 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
 
     @pl.when(contributes)
     def _step():
-        q = q_ref[:]
+        mask = _mask_at(qi * bq, kb * bk, causal, kv_len, window)
         do = do_ref[:]
-        D = jnp.sum(do.astype(jnp.float32) * o_ref[:].astype(jnp.float32),
-                    axis=-1, keepdims=True)              # [Bq, 1]
-        lse = lse_ref[:, 0:1]                            # [Bq, 1]
-        s = jax.lax.dot_general(
-            q, k_ref[:], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _mask_s(s, qi, bq, kb, bk, causal, kv_len, window)
-        p = jnp.exp(s - lse)                              # exact softmax
-        dp = jax.lax.dot_general(
-            do, v_ref[:], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - D)).astype(k_ref.dtype)
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds, k_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        dq_scr[:] = dq_scr[:] + _dq_tile(
+            q_ref[:], do, lse_ref[:, 0:1], _row_dot(do, o_ref[:]),
+            k_ref[:], v_ref[:], mask, scale)
 
     @pl.when(step == nkb - 1)
     def _finalize():
@@ -306,26 +374,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
     @pl.when(contributes)
     def _step():
-        q = q_ref[:]
-        do = do_ref[:]
-        lse = lse_ref[:, 0:1]                             # [Bq, 1]
-        s = jax.lax.dot_general(
-            q, k_ref[:], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _mask_s(s, qb, bq, kj, bk, causal, kv_len, window)
-        p = jnp.exp(s - lse)                              # [Bq, Bk]
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_ref[:], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        D = jnp.sum(do.astype(jnp.float32) * o_ref[:].astype(jnp.float32),
-                    axis=-1, keepdims=True)
-        ds = (p * (dp - D)).astype(q.dtype)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        mask = _mask_at(qb * bq, kj * bk, causal, kv_len, window)
+        dk, dv = _dkv_tile(q_ref[:], do_ref[:], o_ref[:], lse_ref[:, 0:1],
+                           k_ref[:], v_ref[:], mask, scale)
+        dv_scr[:] = dv_scr[:] + dv
+        dk_scr[:] = dk_scr[:] + dk
 
     @pl.when(qx == nqx - 1)
     def _finalize():
@@ -387,7 +440,7 @@ def _windowed_k(nq, nk, bq, bk, window):
         return nk, lambda i, kb: kb
     kspan, _ = _window_spans(nq, nk, bq, bk, window)
     return kspan, lambda i, kb: jnp.minimum(
-        _first_kb(i, bq, bk, window) + kb, nk - 1)
+        _first_kb(i * bq, bk, window) + kb, nk - 1)
 
 
 def _windowed_q(nq, nk, bq, bk, window):
@@ -440,13 +493,14 @@ def _flash_forward_streamed(q, k, v, causal, scale, block_q, block_k, interpret,
     return out.reshape(b, h, t, d), lse   # lse: [b·h, t, _LSE_LANES]
 
 
-def _flash_backward_streamed(q, k, v, do, o, lse, causal, scale, block_q, block_k,
-                    interpret, kv_len=None, window=None):
+def _flash_backward_streamed(q, k, v, do, o, lse, causal, scale, blocks,
+                             interpret, kv_len=None, window=None):
     b, h, t, d = q.shape
     hkv, tk = k.shape[1], k.shape[2]
     reps = h // hkv
     kv_of = _kv_head_of(h, hkv)
     bh = b * h
+    block_q, block_k = blocks.dq
     nkw, kblk = _windowed_k(pl.cdiv(t, block_q), pl.cdiv(tk, block_k),
                             block_q, block_k, window)
     qr = q.reshape(bh, t, d)
@@ -476,6 +530,7 @@ def _flash_backward_streamed(q, k, v, do, o, lse, causal, scale, block_q, block_
     # (k-blocks pinned; dk/dv accumulate across ALL query heads this kv
     # head serves). reps==1 keeps the original identity maps (no per-step
     # div/mod in the index computation).
+    block_q, block_k = blocks.dkv
     nqb = pl.cdiv(t, block_q)
     nq_all = nqb
     if window is not None:
@@ -545,47 +600,21 @@ def _flash_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     bq, d = q_ref.shape
     t = k_ref.shape[0]
     qi = pl.program_id(qi_axis)
-    # Matmul inputs stay in their storage dtype (bf16): bf16×bf16 products
-    # are exact in the MXU's f32 accumulator, so this loses nothing over
-    # upcast-then-dot — and doesn't rely on Mosaic folding converts back
-    # out of an f32 matmul (measured parity on v5e: the fold does happen
-    # today, but it's the compiler's choice, not the kernel's contract).
-    # Softmax math (max/exp/normalizer) runs in f32; p casts back for the
-    # PV matmul.
     q = q_ref[:]
-
-    m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    a0 = jnp.zeros((bq, d), jnp.float32)
-
-    if causal:
-        # Only k-blocks touching or below the diagonal contribute.
-        num_kb = pl.cdiv((qi + 1) * bq, block_k)
-    else:
-        num_kb = pl.cdiv(t, block_k)
+    # Only k-blocks touching or below the diagonal contribute.
+    num_kb = pl.cdiv((qi + 1) * bq if causal else t, block_k)
 
     def body(kb, carry):
-        m, l, acc = carry
-        k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [Bq, Bk]
-        s = _mask_s(s, qi, bq, kb, block_k, causal, kv_len, window)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+        mask = _mask_at(qi * bq, kb * block_k, causal, kv_len, window)
+        return _fwd_tile(q, k_ref[pl.ds(kb * block_k, block_k), :],
+                         v_ref[pl.ds(kb * block_k, block_k), :], carry,
+                         mask, scale)
 
-    kb0 = 0 if window is None else _first_kb(qi, bq, block_k, window)
-    m, l, acc = jax.lax.fori_loop(kb0, num_kb, body, (m0, l0, a0))
-    l_safe = jnp.where(l > 0, l, 1.0)
-    o_ref[:] = (acc / l_safe).astype(o_ref.dtype)
-    lse_ref[:] = jnp.broadcast_to(m + jnp.log(l_safe), (bq, _LSE_LANES))
+    kb0 = 0 if window is None else _first_kb(qi * bq, block_k, window)
+    m, l, acc = jax.lax.fori_loop(kb0, num_kb, body, (
+        jnp.full((bq, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((bq, 1), jnp.float32), jnp.zeros((bq, d), jnp.float32)))
+    _fwd_out(m, l, acc, o_ref, lse_ref)
 
 
 def _flash_bwd_dq_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
@@ -597,33 +626,20 @@ def _flash_bwd_dq_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, d
     bq, d = q_ref.shape
     t = k_ref.shape[0]
     qi = pl.program_id(qi_axis)
-    # bf16 matmul operands / f32 accumulation + f32 softmax math — see the
-    # forward kernel's dtype note.
     q = q_ref[:]
     do = do_ref[:]
-    D = jnp.sum(do.astype(jnp.float32) * o_ref[:].astype(jnp.float32),
-                axis=-1, keepdims=True)                  # [Bq, 1]
+    D = _row_dot(do, o_ref[:])
     lse = lse_ref[:, 0:1]                                # [Bq, 1]
-    num_kb = pl.cdiv((qi + 1) * bq, block_k) if causal else pl.cdiv(
-        t, block_k)
+    num_kb = pl.cdiv((qi + 1) * bq if causal else t, block_k)
 
     def body(kb, dq):
-        k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _mask_s(s, qi, bq, kb, block_k, causal, kv_len, window)
-        p = jnp.exp(s - lse)                              # exact softmax
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - D)).astype(k_blk.dtype)
-        return dq + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        mask = _mask_at(qi * bq, kb * block_k, causal, kv_len, window)
+        return dq + _dq_tile(q, do, lse, D,
+                             k_ref[pl.ds(kb * block_k, block_k), :],
+                             v_ref[pl.ds(kb * block_k, block_k), :],
+                             mask, scale)
 
-    kb0 = 0 if window is None else _first_kb(qi, bq, block_k, window)
+    kb0 = 0 if window is None else _first_kb(qi * bq, block_k, window)
     dq = jax.lax.fori_loop(kb0, num_kb, body,
                            jnp.zeros((bq, d), jnp.float32))
     dq_ref[:] = dq.astype(dq_ref.dtype)
@@ -652,8 +668,6 @@ def _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         def _init():
             dk_scr[:] = jnp.zeros_like(dk_scr)
             dv_scr[:] = jnp.zeros_like(dv_scr)
-    # bf16 matmul operands / f32 accumulation + f32 softmax math — see the
-    # forward kernel's dtype note.
     k_blk = k_ref[:]
     v_blk = v_ref[:]
     num_qb = pl.cdiv(t, block_q)
@@ -662,29 +676,11 @@ def _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         num_qb = jnp.minimum(num_qb, _last_qb(kj, block_q, bk, window) + 1)
 
     def body(qb, carry):
-        dk, dv = carry
-        q = q_ref[pl.ds(qb * block_q, block_q), :]
-        do = do_ref[pl.ds(qb * block_q, block_q), :]
-        o = o_ref[pl.ds(qb * block_q, block_q), :]
-        lse = lse_ref[pl.ds(qb * block_q, block_q), 0:1]  # [Bq, 1]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _mask_s(s, qb, block_q, kj, bk, causal, kv_len, window)
-        p = jnp.exp(s - lse)                              # [Bq, Bk]
-        dv_new = dv + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        D = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-        ds = (p * (dp - D)).astype(q.dtype)
-        dk_new = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        return dk_new, dv_new
+        rows = pl.ds(qb * block_q, block_q)
+        mask = _mask_at(qb * block_q, kj * bk, causal, kv_len, window)
+        dk, dv = _dkv_tile(q_ref[rows, :], do_ref[rows, :], o_ref[rows, :],
+                           lse_ref[rows, 0:1], k_blk, v_blk, mask, scale)
+        return carry[0] + dk, carry[1] + dv
 
     if nreps == 1:
         # MHA / reps==1 fast path: register accumulation, one flush — no
@@ -744,13 +740,14 @@ def _flash_forward_resident(q, k, v, causal, scale, block_q, block_k, interpret,
     return out.reshape(b, h, t, d), lse   # lse: [b·h, t, _LSE_LANES]
 
 
-def _flash_backward_resident(q, k, v, do, o, lse, causal, scale, block_q, block_k,
-                    interpret, kv_len=None, window=None):
+def _flash_backward_resident(q, k, v, do, o, lse, causal, scale, blocks,
+                             interpret, kv_len=None, window=None):
     b, h, t, d = q.shape
     hkv, tk = k.shape[1], k.shape[2]
     reps = h // hkv
     kv_of = _kv_head_of(h, hkv)
     bh = b * h
+    block_q, block_k = blocks.dq
     qr = q.reshape(bh, t, d)
     kr, vr = k.reshape(b * hkv, tk, d), v.reshape(b * hkv, tk, d)
     dor, outr = do.reshape(bh, t, d), o.reshape(bh, t, d)
@@ -773,6 +770,8 @@ def _flash_backward_resident(q, k, v, do, o, lse, causal, scale, block_q, block_
 
     # dkv grid: (b·hkv, kj, rep) — rep streams in, one at a time, the query
     # heads this kv head serves; dk/dv accumulate in scratch across them.
+    block_q, block_k = blocks.dkv
+
     def q_head(g, r):
         return (g // hkv) * h + (g % hkv) * reps + r
 
@@ -807,33 +806,31 @@ def _flash_backward_resident(q, k, v, do, o, lse, causal, scale, block_q, block_
 _RESIDENT_KV_BYTES = 4096 * 128 * 2
 
 
-def _resident_fits(tk: int, d: int, dtype) -> bool:
-    return tk * d * jnp.dtype(dtype).itemsize <= _RESIDENT_KV_BYTES
+def _resident_fits(tk: int, d: int, itemsize: int) -> bool:
+    return tk * d * itemsize <= _RESIDENT_KV_BYTES
 
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
                    kv_len=None, window=None):
-    if _resident_fits(k.shape[2], k.shape[3], k.dtype):
+    if _resident_fits(k.shape[2], k.shape[3], k.dtype.itemsize):
         return _flash_forward_resident(q, k, v, causal, scale, block_q,
                                        block_k, interpret, kv_len, window)
     return _flash_forward_streamed(q, k, v, causal, scale, block_q,
                                    block_k, interpret, kv_len, window)
 
 
-def _flash_backward(q, k, v, do, o, lse, causal, scale, block_q, block_k,
+def _flash_backward(q, k, v, do, o, lse, causal, scale, blocks,
                     interpret, kv_len=None, window=None):
-    if _resident_fits(k.shape[2], k.shape[3], k.dtype):
+    if _resident_fits(k.shape[2], k.shape[3], k.dtype.itemsize):
         return _flash_backward_resident(q, k, v, do, o, lse, causal, scale,
-                                        block_q, block_k, interpret, kv_len,
-                                        window)
+                                        blocks, interpret, kv_len, window)
     return _flash_backward_streamed(q, k, v, do, o, lse, causal, scale,
-                                    block_q, block_k, interpret, kv_len,
-                                    window)
+                                    blocks, interpret, kv_len, window)
 
 
 def _flash_forward_packed(q, k, v, heads, causal, scale, block_q, block_k,
                           interpret, window=None):
-    if _resident_fits(k.shape[1], q.shape[2] // heads, k.dtype):
+    if _resident_fits(k.shape[1], q.shape[2] // heads, k.dtype.itemsize):
         return _flash_forward_packed_resident(q, k, v, heads, causal, scale,
                                               block_q, block_k, interpret,
                                               window)
@@ -843,14 +840,14 @@ def _flash_forward_packed(q, k, v, heads, causal, scale, block_q, block_k,
 
 
 def _flash_backward_packed(q, k, v, do, o, lse, heads, causal, scale,
-                           block_q, block_k, interpret, window=None):
-    if _resident_fits(k.shape[1], q.shape[2] // heads, k.dtype):
+                           blocks, interpret, window=None):
+    if _resident_fits(k.shape[1], q.shape[2] // heads, k.dtype.itemsize):
         return _flash_backward_packed_resident(
-            q, k, v, do, o, lse, heads, causal, scale, block_q, block_k,
-            interpret, window)
+            q, k, v, do, o, lse, heads, causal, scale, blocks, interpret,
+            window)
     return _flash_backward_packed_streamed(
-        q, k, v, do, o, lse, heads, causal, scale, block_q, block_k,
-        interpret, window)
+        q, k, v, do, o, lse, heads, causal, scale, blocks, interpret,
+        window)
 
 
 def _flash_forward_packed_resident(q, k, v, heads, causal, scale, block_q, block_k,
@@ -895,13 +892,14 @@ def _flash_forward_packed_resident(q, k, v, heads, causal, scale, block_q, block
 
 
 def _flash_backward_packed_resident(q, k, v, do, o, lse, heads, causal, scale,
-                           block_q, block_k, interpret, window=None):
+                                    blocks, interpret, window=None):
     b, t, hd = q.shape
     tk = k.shape[1]
     d = hd // heads
     hkv = k.shape[2] // d
     reps = heads // hkv
     lane = _lane_of(reps)
+    block_q, block_k = blocks.dq
     q_spec = pl.BlockSpec((None, block_q, d), lambda bi, h, i: (bi, i, h))
     kv_full = pl.BlockSpec((None, tk, d),
                            lambda bi, h, i: (bi, 0, lane(h)))
@@ -922,6 +920,7 @@ def _flash_backward_packed_resident(q, k, v, do, o, lse, heads, causal, scale,
 
     # dkv grid: (b, hkv, kj, rep) — rep streams the query heads this kv
     # head serves; dk/dv accumulate in scratch (see the kernel docstring).
+    block_q, block_k = blocks.dkv
     q_full = pl.BlockSpec((None, t, d),
                           lambda bi, hk, j, r: (bi, 0, hk * reps + r))
     lse_full = pl.BlockSpec((None, None, t, _LSE_LANES),
@@ -946,28 +945,26 @@ def _flash_backward_packed_resident(q, k, v, do, o, lse, heads, causal, scale,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-           kv_len=None, window=None):
-    with jax.named_scope(_scope("attn_fwd", window)):
-        out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                                interpret, kv_len, window)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, scale, blocks, interpret, kv_len=None,
+           window=None):
+    return _flash_fwd(q, k, v, causal, scale, blocks, interpret, kv_len,
+                      window)[0]
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               kv_len=None, window=None):
+def _flash_fwd(q, k, v, causal, scale, blocks, interpret, kv_len=None,
+               window=None):
     with jax.named_scope(_scope("attn_fwd", window)):
-        out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
+        out, lse = _flash_forward(q, k, v, causal, scale, *blocks.fwd,
                                   interpret, kv_len, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, kv_len, window,
-               residuals, g):
+def _flash_bwd(causal, scale, blocks, interpret, kv_len, window, residuals,
+               g):
     q, k, v, out, lse = residuals
-    return _flash_backward(q, k, v, g, out, lse, causal, scale, block_q,
-                           block_k, interpret, kv_len, window)
+    return _flash_backward(q, k, v, g, out, lse, causal, scale, blocks,
+                           interpret, kv_len, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1020,12 +1017,13 @@ def _flash_forward_packed_streamed(q, k, v, heads, causal, scale, block_q, block
 
 
 def _flash_backward_packed_streamed(q, k, v, do, o, lse, heads, causal, scale,
-                           block_q, block_k, interpret, window=None):
+                                    blocks, interpret, window=None):
     b, t, hd = q.shape
     tk = k.shape[1]
     d = hd // heads
     hkv = k.shape[2] // d
     reps = heads // hkv
+    block_q, block_k = blocks.dq
     # dq grid: (b, h, qi, kb) — k streamed innermost.
     q_pin = pl.BlockSpec((None, block_q, d),
                          lambda bi, h, i, kb: (bi, i, h))
@@ -1052,6 +1050,7 @@ def _flash_backward_packed_streamed(q, k, v, do, o, lse, heads, causal, scale,
     # dkv grid: (b, hkv, kj, qx) — qx flattens (rep, q-block), q-side
     # streamed innermost; dk/dv accumulate across every query head this
     # kv head serves. reps==1 keeps identity (div/mod-free) index maps.
+    block_q, block_k = blocks.dkv
     nqb = pl.cdiv(t, block_q)
     nq_all = nqb
     if window is not None:
@@ -1097,28 +1096,26 @@ def _flash_backward_packed_streamed(q, k, v, do, o, lse, heads, causal, scale,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash_packed(q, k, v, heads, causal, scale, block_q, block_k,
-                  interpret, window=None):
-    with jax.named_scope(_scope("attn_fwd", window)):
-        out, _ = _flash_forward_packed(q, k, v, heads, causal, scale,
-                                       block_q, block_k, interpret, window)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_packed(q, k, v, heads, causal, scale, blocks, interpret,
+                  window=None):
+    return _flash_packed_fwd(q, k, v, heads, causal, scale, blocks,
+                             interpret, window)[0]
 
 
-def _flash_packed_fwd(q, k, v, heads, causal, scale, block_q, block_k,
-                      interpret, window=None):
+def _flash_packed_fwd(q, k, v, heads, causal, scale, blocks, interpret,
+                      window=None):
     with jax.named_scope(_scope("attn_fwd", window)):
         out, lse = _flash_forward_packed(q, k, v, heads, causal, scale,
-                                         block_q, block_k, interpret, window)
+                                         *blocks.fwd, interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_packed_bwd(heads, causal, scale, block_q, block_k, interpret,
-                      window, residuals, g):
+def _flash_packed_bwd(heads, causal, scale, blocks, interpret, window,
+                      residuals, g):
     q, k, v, out, lse = residuals
     return _flash_backward_packed(q, k, v, g, out, lse, heads, causal,
-                                  scale, block_q, block_k, interpret, window)
+                                  scale, blocks, interpret, window)
 
 
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
@@ -1134,32 +1131,98 @@ def _fit_block(limit: int, t: int) -> int:
     return b if b >= 16 else 0
 
 
-def _plan_dispatch(t, tk, block_q, block_k, causal):
-    """Shared kernel-dispatch policy for both layouts:
-    ``("kernel", bq, bk, None)`` — tile-legal dividing blocks exist;
-    ``("pad", bq, bk, t_pad)`` — causal self-attention, zero-pad the seq
+def _pick_blocks(tk, window, d, itemsize) -> Blocks:
+    """Upper bounds of the three kernels' blocks when the caller names
+    none, from what the call shows (PERF.md §6 PR 28 has both tables).
+
+    A score tile costs fewer cycles the larger it is — per-row work
+    (running max, rescale, the accumulator's trip through VMEM) and the
+    per-step overhead are spread over more keys — until the tile's
+    temporaries no longer fit VMEM beside what the kernel holds: with K/V
+    resident (``_resident_fits``) that is a side of 512 (the resident
+    dk/dv kernel, which also holds a head's whole q, do and o, stops
+    compiling at 1024), streamed it is 1024 (2048 does not compile).
+    Against that stands the masked work a larger block drags along: one
+    causal block a side pays the whole square ((n+1)/n of the triangle
+    for n blocks a side), and under a window a block wider than the
+    window is mostly masked. Measured on the v5e, the larger tile wins
+    down to one block a side (t = 512: 1.06 / 1.28 / 1.65 ms at 512
+    against 1.46 / 1.59 / 2.45 at 256, forward / dq / dk-dv, 16 x 32
+    heads) but not beyond the window (8192 under a window of 512:
+    7.1 ms at 512 against 8.9 at 1024), so a window caps the side at
+    itself, never under 512. The three kernels' optima coincide within
+    3% at every measured shape, so they get the same side today; the
+    choice stays per kernel. Rows wider than 128 x bf16 halve the side
+    until a block is no larger in bytes than the calibrated one."""
+    side = 512 if _resident_fits(tk, d, itemsize) else 1024
+    while side > 128 and side * d * itemsize > 1024 * 128 * 2:
+        side //= 2
+    if window is not None:
+        side = min(side, max(512, 1 << (window - 1).bit_length()))
+    return Blocks(*[(side, side)] * 3)
+
+
+def _pad_len(t: int) -> int:
+    """Length a ragged sequence is zero-padded to when the rule picks the
+    blocks: the next lane multiple (128), so that blocks of whole lane
+    tiles divide it; a sequence under one lane tile, the next sublane
+    multiple (16), and is one block."""
+    return t + (-t) % (128 if t > 128 else 16)
+
+
+def _plan_dispatch(t, tk, block_q, block_k, causal, window=None, d=128,
+                   itemsize=2):
+    """Shared kernel-dispatch policy for both layouts; the second item is
+    the :class:`Blocks` of the three kernels:
+    ``("kernel", blocks, None)`` — tile-legal dividing blocks exist;
+    ``("pad", blocks, t_pad)`` — causal self-attention, zero-pad the seq
     (end-padded keys sit above every real query's diagonal, so the causal
     mask hides them for free);
-    ``("pad_masked", bq, bk, (t_pad, tk_pad, kv_len))`` — any other
+    ``("pad_masked", blocks, (t_pad, tk_pad, kv_len))`` — any other
     ragged lengths (non-causal, or cross q/k): q and K/V zero-pad
     independently to tile-legal block multiples and the kernels mask the
     padded keys via the static ``kv_len`` (after the chip's compiler
     refused the first kernel's non-tile-aligned block shape, these
     shapes were sent to the reference fallback — the T×T score
     materialization — instead).
+
+    ``block_q`` / ``block_k`` of ``None``: :func:`_pick_blocks` chooses
+    each kernel's blocks from the shapes, and a ragged length pads to
+    :func:`_pad_len` first, so the rule sees the length the kernels run.
+    Integers are one upper bound for all three kernels, as before the
+    rule existed.
     """
-    bq, bk = _fit_block(block_q, t), _fit_block(block_k, tk)
-    if bq and bk:
-        return ("kernel", bq, bk, None)
-    bq = min(max(16, block_q - block_q % 16), t + ((-t) % 16))
-    bk = min(max(16, block_k - block_k % 16), tk + ((-tk) % 16))
-    if causal and t == tk:
-        import math
-        t_pad = t + ((-t) % math.lcm(bq, bk))
-        return ("pad", bq, bk, t_pad)
-    t_pad = t + ((-t) % bq)
-    tk_pad = tk + ((-tk) % bk)
-    return ("pad_masked", bq, bk, (t_pad, tk_pad, tk))
+    if (block_q is None) != (block_k is None):
+        raise ValueError("block_q and block_k: name both or neither, got "
+                         f"{block_q} and {block_k}")
+
+    def fit(t, tk):
+        if block_q is None:
+            limits = _pick_blocks(tk, window, d, itemsize)
+        else:
+            limits = Blocks(*[(block_q, block_k)] * 3)
+        return Blocks(*[(_fit_block(lq, t), _fit_block(lk, tk))
+                        for lq, lk in limits])
+
+    blocks = fit(t, tk)
+    if all(bq and bk for bq, bk in blocks):
+        return ("kernel", blocks, None)
+    self_attn = causal and t == tk
+    if block_q is None:
+        t_pad = _pad_len(t)
+        tk_pad = t_pad if self_attn else _pad_len(tk)
+        blocks = fit(t_pad, tk_pad)
+    else:
+        bq = min(max(16, block_q - block_q % 16), t + ((-t) % 16))
+        bk = min(max(16, block_k - block_k % 16), tk + ((-tk) % 16))
+        blocks = Blocks(*[(bq, bk)] * 3)
+        if self_attn:
+            t_pad = tk_pad = t + ((-t) % math.lcm(bq, bk))
+        else:
+            t_pad, tk_pad = t + ((-t) % bq), tk + ((-tk) % bk)
+    if self_attn:
+        return ("pad", blocks, t_pad)
+    return ("pad_masked", blocks, (t_pad, tk_pad, tk))
 
 
 class KernelFallbackWarning(UserWarning):
@@ -1201,7 +1264,8 @@ def _check_window(window, causal, t, tk):
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, scale: Optional[float] = None,
-                    block_q: int = 256, block_k: int = 256,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None) -> jax.Array:
     """Fused attention over ``[batch, heads, seq, head_dim]``.
@@ -1218,10 +1282,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     feature dim (zero k-dims add nothing to scores, zero v-columns are
     sliced off). The reference only runs on non-TPU backends.
 
-    Default blocks are 256: 128² score tiles are MXU-pipeline-latency
-    dominated (measured 14.5→9.7 ms per layer fwd+bwd going 128→256 at
-    b32·h8·t512·d128 on v5e; 512 measured equal to 256 with more VMEM
-    pressure).
+    ``block_q`` / ``block_k`` of ``None`` (the default): the blocks of the
+    forward, the dq and the dk/dv call are chosen from the call's shapes
+    by :func:`_pick_blocks` — a side of 512 with K/V resident in VMEM,
+    1024 streamed, never wider than a window (or 512), halved for rows
+    wider than 128 x bf16 — and fitted to the lengths (the largest
+    tile-legal divisor; a ragged length pads to the next lane multiple
+    first). The larger tile wins down to ONE block a side: at t = 512 a
+    512 block pays the whole causal square and is still 1.3-1.5x faster
+    than four 256 blocks paying three quarters of it, because per-row work
+    and the store slot, not the matmuls, bound a 256-key tile (v5e, PR 28:
+    the compiler's static schedule, then the chip; tables in PERF.md §6).
+    Integers are honoured as one upper bound for all three kernels.
 
     GQA is zero-copy: K/V may carry ``heads // reps`` heads — the kernels'
     index maps route query head h to kv head h·hkv/h, and the dk/dv grids
@@ -1264,31 +1336,33 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # both), else Mosaic rejects the unaligned slice even when the block
     # equals the array dim. _plan_dispatch shrinks to the largest dividing
     # tile-legal block before resorting to padding, so e.g. t=384 runs
-    # the kernel unpadded at block 192 rather than padding to 512; the
-    # pad paths re-bound blocks by the padded length so short sequences
-    # don't pay for a full default-sized block (t=8 pads to 16, not 128).
-    plan, bq, bk, extra = _plan_dispatch(t, tk, block_q, block_k, causal)
+    # the kernel unpadded in one block of 384 rather than padding to 512;
+    # the pad paths bound blocks by the padded length so short sequences
+    # don't pay for a full-sized block (t=8 pads to 16, not 128).
+    plan, blocks, extra = _plan_dispatch(t, tk, block_q, block_k, causal,
+                                         window, d, k.dtype.itemsize)
     if plan == "kernel":
-        return _flash(q, k, v, causal, scale, bq, bk, interpret, None,
+        return _flash(q, k, v, causal, scale, blocks, interpret, None,
                       window)
     if plan == "pad":
         widths = ((0, 0), (0, 0), (0, extra - t), (0, 0))
         qp, kp, vp = (jnp.pad(x, widths) for x in (q, k, v))
-        out = _flash(qp, kp, vp, causal, scale, bq, bk, interpret, None,
+        out = _flash(qp, kp, vp, causal, scale, blocks, interpret, None,
                      window)
         return out[:, :, :t, :]
     t_pad, tk_pad, kv_len = extra
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
     kvw = ((0, 0), (0, 0), (0, tk_pad - tk), (0, 0))
     out = _flash(qp, jnp.pad(k, kvw), jnp.pad(v, kvw), causal, scale,
-                 bq, bk, interpret, kv_len if tk_pad != tk else None, None)
+                 blocks, interpret, kv_len if tk_pad != tk else None, None)
     return out[:, :, :t, :]
 
 
 def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
                            heads: int, causal: bool = True,
                            scale: Optional[float] = None,
-                           block_q: int = 256, block_k: int = 256,
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None,
                            interpret: Optional[bool] = None,
                            window: Optional[int] = None) -> jax.Array:
     """Fused attention over the packed ``[batch, seq, heads·head_dim]``
@@ -1334,9 +1408,10 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
         _warn_fallback(
             f"packed layout needs head_dim % 128 == 0, got {d}")
         return unpacked_fallback()
-    plan, bq, bk, extra = _plan_dispatch(t, tk, block_q, block_k, causal)
+    plan, blocks, extra = _plan_dispatch(t, tk, block_q, block_k, causal,
+                                         window, d, k.dtype.itemsize)
     if plan == "kernel":
-        return _flash_packed(q, k, v, heads, causal, scale, bq, bk,
+        return _flash_packed(q, k, v, heads, causal, scale, blocks,
                              interpret, window)
     if plan == "pad_masked":
         # Ragged non-causal / cross lengths: route through the classic
@@ -1346,8 +1421,8 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
         return unpacked_fallback()
     widths = ((0, 0), (0, extra - t), (0, 0))
     qp, kp, vp = (jnp.pad(x, widths) for x in (q, k, v))
-    out = _flash_packed(qp, kp, vp, heads, causal, scale, bq, bk, interpret,
-                        window)
+    out = _flash_packed(qp, kp, vp, heads, causal, scale, blocks,
+                        interpret, window)
     return out[:, :t, :]
 
 
@@ -1540,7 +1615,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             return _decode_xla(q, k, v, q_positions, scale, bk or ctx)
         interpret = False
     if not bk or t % 8 or d % 8 \
-            or not _resident_fits(ctx, d, k.dtype):
+            or not _resident_fits(ctx, d, k.dtype.itemsize):
         # Off-tile shapes / oversized caches leave the kernel path; the
         # fallback is the same math (and bit-identical where both run).
         _warn_fallback(
@@ -1553,7 +1628,8 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
 def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
                             mesh, causal: bool = True,
                             scale: Optional[float] = None,
-                            block_q: int = 256, block_k: int = 256,
+                            block_q: Optional[int] = None,
+                            block_k: Optional[int] = None,
                             model_axis: str = "model",
                             interpret: Optional[bool] = None) -> jax.Array:
     """Global-array entry point: shard_map the flash kernel over the mesh —

@@ -177,6 +177,13 @@ def count(name: str, n: float = 1) -> None:
         _TIMELINE.counters[name] = _TIMELINE.counters.get(name, 0) + n
 
 
+def count_once(name: str, n: float) -> None:
+    """A trace-time fact on the task's timeline: recorded by the first
+    trace, not again by init, remat or a re-trace."""
+    with _TIMELINE.lock:
+        _TIMELINE.counters.setdefault(name, n)
+
+
 def add_seconds(name: str, s: float) -> None:
     """Add ``s`` seconds to the process-local sum ``name``."""
     count(name, float(s))
