@@ -41,7 +41,7 @@ tier1-data:
 
 # Collective-scheduler marker leg (also inside tier1-verify's selection) —
 # forward-gather bucketing/prefetch bit-exactness, MoE explicit a2a vs
-# GSPMD, pipeline-edge records, unified collective_report schema.
+# GSPMD, pipeline-edge records, unified report("collective") schema.
 tier1-sched:
 	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'sched and not slow' -p no:cacheprovider -p no:xdist -p no:randomly
 

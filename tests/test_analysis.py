@@ -109,10 +109,10 @@ class TestShippedConfigsClean:
         assert 0 < gplan.window_nbytes() < sum(gplan.gather_nbytes)
 
     def test_report_banked_in_profiler(self):
-        profiler.reset_analysis_records()
+        profiler.reset_records("analysis")
         stepper, state, batch = target("zero3")
         analysis.analyze_accum_step(stepper, state, batch, tag="bank")
-        rep = profiler.analysis_report()
+        rep = profiler.report("analysis")
         assert rep["bank"]["ok"] is True
         assert rep["bank"]["findings"] == 0
         assert rep["bank"]["eqns"] > 0
@@ -120,7 +120,7 @@ class TestShippedConfigsClean:
         # the snapshot must not poison the live registry.
         rep["bank"]["findings_by_rule"]["poison"] = 1
         assert "poison" not in \
-            profiler.analysis_report()["bank"]["findings_by_rule"]
+            profiler.report("analysis")["bank"]["findings_by_rule"]
 
 
 class TestReplicationLeak:

@@ -1,6 +1,6 @@
 """Fused BatchNorm(+add)(+ReLU) kernels vs the reference math (reference
 tier: op unit tests, SURVEY.md §4; VERDICT r3 #1). Interpret mode on the
-CPU mesh — the kernels themselves are exercised compiled on TPU by bench.py."""
+CPU mesh — no cell of the benchmark runs them compiled on the chip."""
 
 import jax
 import jax.numpy as jnp

@@ -19,9 +19,9 @@ import pytest
 from tony_tpu import ckpt
 from tony_tpu import parallel as par
 from tony_tpu import profiler, train
-from tony_tpu.benchmark import fsdp_shard_state
 from tony_tpu.ckpt import format as fmt
 from tony_tpu.models import get_model
+from tony_tpu.train import fsdp_shard_state
 
 pytestmark = pytest.mark.ckpt
 
@@ -203,12 +203,12 @@ class TestAsync:
         c.close()
 
     def test_profiler_records_stall_and_write(self, tmp_path):
-        profiler.reset_ckpt_records()
+        profiler.reset_records("ckpt")
         state, _ = _state()
         c = ckpt.AsyncCheckpointer(tmp_path, keep=3)
         c.save(state, step=1, block=True)
         c.close()
-        rec = profiler.ckpt_report()["async_save"]
+        rec = profiler.report("ckpt")["async_save"]
         assert rec["step"] == 1
         assert rec["nbytes"] > 0 and rec["n_chunks"] >= 1
         assert rec["stall_s"] >= 0 and rec["write_s"] > 0

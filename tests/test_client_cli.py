@@ -517,6 +517,26 @@ def test_client_reports_submit_to_running_latency(tmp_path):
     assert "all tasks running" in out.getvalue()
 
 
+def test_failed_task_leaves_its_reason_in_stderr(tmp_path):
+    """A submitted task whose script exits non-zero with a message: the
+    client returns non-zero, the message is in the container's stderr
+    file, and the jhist still carries the submit->all-running latency
+    (the gang did start)."""
+    client = run_client(tmp_path, **{
+        "tony.application.executes": "python exit_with_reason.py"})
+    assert client.exit_code != 0
+    assert client.final_status == "FAILED"
+    [stderr] = Path(client.job_dir).glob(
+        f"containers/*/{constants.USER_STDERR_NAME}")
+    assert "the task refuses to run and says why" in stderr.read_text()
+    from tony_tpu.events import read_events
+    [jhist] = Path(client.job_dir).glob("history/finished/**/*.jhist")
+    all_running = [e for e in read_events(jhist)
+                   if e.get("type") == "ALL_TASKS_RUNNING"]
+    assert all_running
+    assert all_running[0]["payload"]["submit_to_running_s"] > 0
+
+
 @pytest.mark.slow
 def test_client_relaunches_crashed_am(tmp_path):
     """AM-attempt restart end-to-end (reference: the RM relaunches the AM
@@ -603,34 +623,3 @@ def test_containers_resources_duplicate_basename_rejected(tmp_path):
         src_dir=WORKLOADS, workdir=tmp_path / "jobs", stream=io.StringIO())
     with pytest.raises(ValueError, match="duplicate"):
         client.stage()
-
-
-def test_resnet_bench_job_via_submit(tmp_path):
-    """The north-star measurement path (BASELINE.md: "via tony-submit"):
-    examples/resnet_bench_job runs the bench.py step INSIDE a submitted
-    job. A measurement needs a chip: on the CPU the job FAILS with the
-    reason in the task's stderr instead of reporting a CPU wall under a
-    device metric's name; the jhist still carries the submit->all-running
-    latency. The real-chip numbers are recorded by a chip run."""
-    example = Path(__file__).parent.parent / "examples" / "resnet_bench_job"
-    client = TonyClient(
-        TonyConfig(base_props(**{
-            "tony.application.framework": "jax",
-            "tony.application.executes": "python train.py",
-            "tony.worker.env":
-                "BENCH_BATCH=4,BENCH_IMAGE=32,BENCH_STEPS=2,BENCH_WINDOWS=1",
-        })),
-        src_dir=example, workdir=tmp_path / "jobs", stream=io.StringIO())
-    assert client.run(timeout=240) != 0
-    assert not list(
-        Path(client.job_dir).glob("containers/*/src/bench_result.json"))
-    [stderr] = Path(client.job_dir).glob(
-        f"containers/*/{constants.USER_STDERR_NAME}")
-    assert "no TPU attached" in stderr.read_text()
-    # The latency metric exists in the event log (ALL_TASKS_RUNNING).
-    from tony_tpu.events import read_events
-    [jhist] = Path(client.job_dir).glob("history/finished/**/*.jhist")
-    evs = read_events(jhist)
-    all_running = [e for e in evs if e.get("type") == "ALL_TASKS_RUNNING"]
-    assert all_running
-    assert all_running[0]["payload"]["submit_to_running_s"] > 0

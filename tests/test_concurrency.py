@@ -334,7 +334,7 @@ class TestWitness:
         assert edges[0]["count"] == 1
         assert edges[0]["threads"] == [threading.current_thread().name]
         assert "test_concurrency" in edges[0]["where"]
-        rec = profiler.lock_report()["witness"]
+        rec = profiler.report("locks")["witness"]
         assert [(e["src"], e["dst"]) for e in rec["edges"]] \
             == [("w.a", "w.b")]
         assert rec["locks"] == ["w.a", "w.b"]
@@ -602,12 +602,12 @@ class TestTreeCleanAtHead:
             / "concurrency.json")
         assert report.ok, "\n".join(str(f) for f in report.findings)
 
-    def test_summary_banked_in_analysis_report(self, fresh_witness):
-        profiler.reset_analysis_records()
+    def test_summary_banked_in_analysis_records(self, fresh_witness):
+        profiler.reset_records("analysis")
         conc.analyze_concurrency(REPO / "tony_tpu")
-        rec = profiler.analysis_report()["concurrency"]
+        rec = profiler.report("analysis")["concurrency"]
         assert rec["findings"] == 0
-        profiler.reset_analysis_records()
+        profiler.reset_records("analysis")
 
     def test_make_lint_invocation_is_clean(self, fresh_witness):
         assert conc.main(
@@ -684,18 +684,18 @@ class TestTreeCleanAtHead:
 
 class TestLockRegistry:
     def test_record_report_reset(self):
-        profiler.reset_lock_records()
-        profiler.record_locks("t", locks=["a"], edges=[])
-        assert profiler.lock_report() == {"t": {"locks": ["a"],
-                                                "edges": []}}
-        profiler.reset_lock_records()
-        assert profiler.lock_report() == {}
+        profiler.reset_records("locks")
+        profiler.record("locks", "t", locks=["a"], edges=[])
+        assert profiler.report("locks") == {"t": {"locks": ["a"],
+                                                  "edges": []}}
+        profiler.reset_records("locks")
+        assert profiler.report("locks") == {}
 
-    def test_safe_record_routes_locks(self):
-        profiler.reset_lock_records()
-        profiler.safe_record("locks", "t", locks=["x"], edges=[])
-        assert profiler.lock_report()["t"]["locks"] == ["x"]
-        profiler.reset_lock_records()
+    def test_record_routes_locks(self):
+        profiler.reset_records("locks")
+        profiler.record("locks", "t", locks=["x"], edges=[])
+        assert profiler.report("locks")["t"]["locks"] == ["x"]
+        profiler.reset_records("locks")
 
 
 # ---------------------------------------------------------------------------

@@ -507,12 +507,12 @@ class TestPrefetch:
         assert not thread.is_alive()
 
     def test_input_stall_recorded_in_profiler(self):
-        profiler.reset_input_records()
+        profiler.reset_records("input")
         with data.DeviceIterator(_ds().iterator(data.ShardSpec(0, 1)),
                                  None, depth=1, tag="t_input") as dit:
             next(dit)
             next(dit)
-        report = profiler.input_report()
+        report = profiler.report("input")
         assert "t_input" in report
         rec = report["t_input"]
         assert rec["depth"] == 1 and rec["steps"] == 2
@@ -520,7 +520,7 @@ class TestPrefetch:
         assert rec["wait_s_total"] >= rec["wait_s_last"]
         # Deep-copied snapshot: mutating it must not alias the registry.
         rec["steps"] = -1
-        assert profiler.input_report()["t_input"]["steps"] == 2
+        assert profiler.report("input")["t_input"]["steps"] == 2
 
 
 def _mlp_state(key=2, hidden=32):
@@ -762,14 +762,3 @@ class TestGlobalBatchValidation:
         # check=False falls through to jax's own (opaque) error.
         with pytest.raises(Exception):
             train.global_batch(mesh, {"x": np.zeros((7, 4))}, check=False)
-
-
-class TestInputBench:
-    @pytest.mark.slow
-    def test_run_input_bench_smoke(self):
-        from tony_tpu.benchmark import run_input_bench
-
-        r = run_input_bench(steps=6, depths=(0, 1), feed_latency_ms=2.0)
-        assert set(r["per_depth"]) == {"0", "1"}
-        assert r["input_stall_ms_depth0"] > 0
-        assert "input_d1" in r["input_records"]

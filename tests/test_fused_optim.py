@@ -18,10 +18,10 @@ from tony_tpu import ckpt as ckpt_mod
 from tony_tpu import parallel as par
 from tony_tpu import profiler
 from tony_tpu import train as tr
-from tony_tpu.benchmark import fsdp_shard_state
 from tony_tpu.models import get_model
 from tony_tpu.ops import fused_optim as fo
 from tony_tpu.parallel.overlap import GradBuckets
+from tony_tpu.train import fsdp_shard_state
 
 pytestmark = pytest.mark.optim
 
@@ -378,7 +378,7 @@ class TestZero3:
                               mesh)
         so = fsdp_shard_state(tr.create_train_state(
             model, optax.adamw(1e-3, weight_decay=1e-2), x, kr), mesh)
-        profiler.reset_update_records()
+        profiler.reset_records("update")
         step_f = tr.make_accum_train_step(
             mesh=mesh, microbatches=4, bucket_bytes=1 << 16,
             update="fused_bucket", donate=False)
@@ -400,7 +400,7 @@ class TestZero3:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=0, atol=1e-7)
         assert int(sf.opt_state["count"]) == 2 and int(sf.step) == 2
-        rec = profiler.update_report()["accum_update"]
+        rec = profiler.report("update")["accum_update"]
         assert rec["rule"] == "adamw" and rec["impl"] in ("pallas", "xla")
         assert rec["n_buckets"] >= 1 and rec["n_scatter_buckets"] >= 1
 
@@ -531,10 +531,10 @@ class TestRecords:
         params = _params()
         fused = fo.FusedOptimizer(rule="sgd", lr=0.1, clip_norm=1.0)
         plan = fused.plan_for(params, None)
-        profiler.reset_update_records()
+        profiler.reset_records("update")
         fo.fused_update_step(fused, params, _grads(params),
                              fused.init_state(params), plan=plan)
-        rec = profiler.update_report()["fused_update"]
+        rec = profiler.report("update")["fused_update"]
         assert rec["rule"] == "sgd"
         assert rec["impl"] in ("pallas", "xla")
         assert rec["n_buckets"] == plan.n_buckets
@@ -543,13 +543,13 @@ class TestRecords:
         assert rec["clip_norm"] == 1.0
 
     def test_mutating_update_report_does_not_poison_store(self):
-        profiler.reset_update_records()
-        profiler.safe_record("update", "t", nested={"deep": [1, 2]},
-                             bucket_nbytes=[10, 20])
-        snap = profiler.update_report()
+        profiler.reset_records("update")
+        profiler.record("update", "t", nested={"deep": [1, 2]},
+                        bucket_nbytes=[10, 20])
+        snap = profiler.report("update")
         snap["t"]["nested"]["deep"].append(99)
         snap["t"]["bucket_nbytes"][0] = -1
         snap["injected"] = {}
-        assert profiler.update_report() == {
+        assert profiler.report("update") == {
             "t": {"nested": {"deep": [1, 2]}, "bucket_nbytes": [10, 20]}}
-        profiler.reset_update_records()
+        profiler.reset_records("update")

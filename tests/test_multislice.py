@@ -13,9 +13,9 @@ import pytest
 
 from tony_tpu import parallel as par
 from tony_tpu import profiler, train
-from tony_tpu.benchmark import fsdp_shard_state
 from tony_tpu.models import get_model
 from tony_tpu.parallel import overlap
+from tony_tpu.train import fsdp_shard_state
 
 pytestmark = pytest.mark.multislice
 
@@ -64,13 +64,13 @@ def test_hierarchical_profiler_level_records():
     """Per-level bucket plan records: the ICI level carries the full
     bucket bytes (psum_scatter input), the DCN level the scattered-chunk
     bytes — what actually crosses slices per bucket."""
-    profiler.reset_overlap_records()
+    profiler.reset_records("overlap")
     mesh = par.make_mesh(slices=2)
     state, batch = _mnist_setup()
     step = train.make_accum_train_step(
         mesh=mesh, microbatches=4, bucket_bytes=32 * 1024, donate=False)
     step(state, batch)
-    rec = profiler.overlap_report()["accum_step"]
+    rec = profiler.report("overlap")["accum_step"]
     assert rec["hierarchy"] == "hierarchical"
     by_level = {l["level"]: l for l in rec["levels"]}
     assert by_level["ici"]["op"] == "psum_scatter"
@@ -95,7 +95,7 @@ def test_zero3_on_two_slice_mesh():
     mono = train.make_train_step(mesh=mesh, donate=False)
     s1, m1 = mono(state, batch)
     zstate = fsdp_shard_state(state, mesh)
-    profiler.reset_overlap_records()
+    profiler.reset_records("overlap")
     for hierarchy in ("auto", "flat"):
         step = train.make_accum_train_step(
             mesh=mesh, microbatches=4, bucket_bytes=32 * 1024,
@@ -109,7 +109,7 @@ def test_zero3_on_two_slice_mesh():
                                        atol=1e-5)
         assert sum("fsdp" in str(leaf.sharding.spec)
                    for leaf in jax.tree.leaves(s2.params)) >= 4
-    rec = profiler.overlap_report()["accum_step"]
+    rec = profiler.report("overlap")["accum_step"]
     assert rec["zero3"] is True and rec["n_scatter_buckets"] >= 1
 
 
@@ -144,25 +144,3 @@ def test_create_train_state_fsdp_autodetects():
     flat = jax.tree.leaves(
         specs, is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
     assert any("fsdp" in str(s) for s in flat)
-
-
-def test_overlap_bench_hier_and_zero3_legs():
-    """Acceptance: the bench leg reports both modes with numerics intact
-    and per-level plans attached."""
-    import os
-
-    from tony_tpu.benchmark import run_overlap_bench
-
-    os.environ["BENCH_WINDOWS"] = "1"
-    try:
-        hier = run_overlap_bench(batch=64, hidden=64, steps=1,
-                                 bucket_bytes=32 * 1024, slices=2)
-        z = run_overlap_bench(batch=64, hidden=64, steps=1,
-                              bucket_bytes=32 * 1024, fsdp=4, zero3=True)
-    finally:
-        del os.environ["BENCH_WINDOWS"]
-    assert hier["numerics_ok"] and hier["hierarchy"] == "hierarchical"
-    assert [l["level"] for l in
-            hier["overlap_records"]["accum_step"]["levels"]].count("dcn") == 1
-    assert z["numerics_ok"] and z["zero3"] and z["n_scatter_buckets"] >= 1
-    assert z["accum_step_s"] > 0 and hier["accum_step_s"] > 0

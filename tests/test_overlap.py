@@ -283,8 +283,69 @@ def test_hierarchical_requires_multislice_mesh():
 
 def _zero3_state(state, mesh):
     """Shard the MLP state into the ZeRO-3 layout on ``mesh``."""
-    from tony_tpu.benchmark import fsdp_shard_state
-    return fsdp_shard_state(state, mesh)
+    return train.fsdp_shard_state(state, mesh)
+
+
+def test_fsdp_shard_state_shards_first_divisible_dim():
+    """Each leaf's first fsdp-divisible dimension is the sharded one; a
+    leaf with none stays replicated; the optimizer state is created
+    fresh, on the resharded params' layout."""
+    from flax.training.train_state import TrainState
+    from jax.sharding import PartitionSpec as P
+
+    mesh = par.make_mesh(fsdp=4)
+    shapes = {"first": (8, 3), "second": (6, 8), "both": (4, 12),
+              "none": (3, 5), "scalar": ()}
+    state = TrainState.create(
+        apply_fn=None, tx=optax.adam(1e-3),
+        params={k: jnp.ones(v) for k, v in shapes.items()})
+    state = state.replace(step=7)
+    out = _zero3_state(state, mesh)
+    want = {"first": P("fsdp", None), "second": P(None, "fsdp"),
+            "both": P("fsdp", None), "none": P(), "scalar": P()}
+    for name, spec in want.items():
+        got = out.params[name].sharding
+        assert got.mesh == mesh and got.spec == spec, name
+        # Each device holds 1/4 of a sharded leaf, all of a replicated one.
+        held = out.params[name].addressable_shards[0].data.size
+        assert held * (4 if "fsdp" in spec else 1) == \
+            max(int(np.prod(shapes[name])), 1), name
+        mu = out.opt_state[0].mu[name]
+        assert mu.sharding.is_equivalent_to(got, mu.ndim), name
+        assert not mu.any()
+    assert int(out.step) == 0 and out.tx is state.tx
+
+
+def test_fsdp_shard_state_rebuilds_fused_optimizer_state():
+    """With a FusedOptimizer the bucket-resident state is planned anew
+    from the resharded params — scatter buckets ``P("fsdp")`` on the mesh,
+    sized by the sharded plan — not carried over from the unsharded
+    state it was given."""
+    from jax.sharding import PartitionSpec as P
+
+    from tony_tpu.ops import fused_optim as fo
+
+    mesh = par.make_mesh(fsdp=4)
+    model = get_model("mnist-mlp", hidden=64)
+    x = jax.random.normal(jax.random.PRNGKey(0), (32, 784))
+    fused = fo.FusedOptimizer(rule="adamw", lr=1e-3, bucket_bytes=1 << 16)
+    state = train.create_train_state(model, fused, x, jax.random.PRNGKey(1))
+    out = _zero3_state(state, mesh)
+    plan = fused.plan_for(out.params, mesh)
+    specs = fused.bucket_specs(plan)
+    assert P("fsdp") in specs
+    assert set(out.opt_state["slots"]) == set(fused.slot_names)
+    for name, bufs in out.opt_state["slots"].items():
+        assert [b.shape for b in bufs] == \
+            [(n,) for n in plan.bucket_numel], name
+        for buf, spec in zip(bufs, specs):
+            assert buf.sharding.mesh == mesh and buf.sharding.spec == spec
+            assert not buf.any()
+    # The state it was given lives on one device, in the unsharded plan.
+    old = state.opt_state["slots"][fused.slot_names[0]]
+    assert all(len(b.sharding.device_set) == 1 for b in old)
+    assert int(out.opt_state["count"]) == 0 and int(out.step) == 0
+    assert out.tx is fused
 
 
 def test_zero3_accum_matches_replicated_and_monolithic():
@@ -339,7 +400,7 @@ def test_zero3_grads_never_leave_shard_layout():
         logits = zstate.apply_fn({"params": params}, mb["x"])
         return train.cross_entropy_loss(logits, mb["y"])
 
-    profiler.reset_overlap_records()
+    profiler.reset_records("overlap")
     with jax.sharding.Mesh(mesh.devices, mesh.axis_names):
         loss, grads = jax.jit(lambda p, b: microbatch_grads(
             loss_fn, p, b, mesh, microbatches=4, bucket_bytes=32 * 1024,
@@ -352,7 +413,7 @@ def test_zero3_grads_never_leave_shard_layout():
             assert "fsdp" in str(g.sharding.spec)
             sharded += 1
     assert sharded >= 4
-    rec = profiler.overlap_report()["accum_step"]
+    rec = profiler.report("overlap")["accum_step"]
     assert rec["zero3"] is True
     assert rec["n_scatter_buckets"] >= 1
     assert any(l["op"] == "psum_scatter" and l["axes"] == ["fsdp"]
@@ -422,7 +483,7 @@ class TestUnevenZero3:
                              + jnp.diag(p["b"]))
             return jnp.mean((out[:, :6] - mb["y"]) ** 2)
 
-        profiler.reset_overlap_records()
+        profiler.reset_records("overlap")
         loss, grads = jax.jit(lambda p, b: microbatch_grads(
             loss_fn, p, b, mesh, microbatches=4,
             bucket_bytes=1 << 20, param_specs=specs))(params, batch)
@@ -441,7 +502,7 @@ class TestUnevenZero3:
                                    np.asarray(ref["b"]), atol=1e-4)
         np.testing.assert_allclose(np.asarray(jax.device_get(grads["w"])),
                                    np.asarray(ref["w"]), atol=1e-4)
-        rec = profiler.overlap_report()["accum_step"]
+        rec = profiler.report("overlap")["accum_step"]
         assert rec["n_padded_buckets"] == 1
         assert "fsdp-indivisible" in caplog.text
 
@@ -489,13 +550,13 @@ def test_fsdp_param_specs_detection():
 
 
 def test_profiler_records_bucket_plan():
-    profiler.reset_overlap_records()
+    profiler.reset_records("overlap")
     mesh = par.make_mesh()
     state, batch = _mnist_setup()
     step = train.make_accum_train_step(mesh=mesh, microbatches=4,
                                        bucket_bytes=32 * 1024, donate=False)
     step(state, batch)
-    rec = profiler.overlap_report()
+    rec = profiler.report("overlap")
     assert "accum_step" in rec
     assert rec["accum_step"]["n_buckets"] >= 1
     assert sum(rec["accum_step"]["bucket_nbytes"]) == sum(
@@ -536,12 +597,12 @@ def test_record_failure_logs_debug_once(monkeypatch, caplog):
     nor stay silent — one DEBUG line on the first failure, then quiet."""
     import logging
 
-    monkeypatch.setattr(profiler, "_SAFE_RECORD_FAILED", set())
+    class Boom(dict):
+        def __setitem__(self, tag, fields):
+            raise RuntimeError("profiler wired wrong")
 
-    def boom(*a, **kw):
-        raise RuntimeError("profiler wired wrong")
-
-    monkeypatch.setattr(profiler, "record_overlap", boom)
+    monkeypatch.setattr(profiler, "_RECORD_FAILED", set())
+    monkeypatch.setitem(profiler._RECORDS, "overlap", Boom())
     with caplog.at_level(logging.DEBUG, logger="tony_tpu.profiler"):
         overlap._record("t1", n=1)      # must not raise
         overlap._record("t2", n=2)
@@ -566,24 +627,3 @@ def test_train_step_seq_axis_keeps_ring_sharding():
         mesh=mesh, seq_axis=True, donate=False)
     _, metrics = step(state, {"x": tokens, "w": jnp.ones((8,))})
     assert np.isfinite(float(metrics["loss"]))
-
-
-def test_run_overlap_bench_reports_and_matches():
-    """Acceptance: the bench leg on the 8-device CPU mesh reports numerics
-    matching the monolithic step and emits per-bucket bytes."""
-    import os
-
-    from tony_tpu.benchmark import run_overlap_bench
-
-    os.environ["BENCH_WINDOWS"] = "1"
-    try:
-        r = run_overlap_bench(batch=64, hidden=64, steps=1,
-                              bucket_bytes=32 * 1024)
-    finally:
-        del os.environ["BENCH_WINDOWS"]
-    assert r["numerics_ok"]
-    assert r["loss_delta"] < 1e-5 and r["grad_norm_delta"] < 1e-5
-    assert r["n_buckets"] == len(r["bucket_nbytes"]) >= 1
-    assert all(b > 0 for b in r["bucket_nbytes"])
-    assert r["mono_step_s"] > 0 and r["accum_step_s"] > 0
-    assert r["overlap_records"]["accum_step"]["n_buckets"] == r["n_buckets"]

@@ -17,11 +17,11 @@ from tony_tpu import ckpt as ckpt_mod
 from tony_tpu import parallel as par
 from tony_tpu import profiler
 from tony_tpu import train as tr
-from tony_tpu.benchmark import fsdp_shard_state
 from tony_tpu.models import get_model
 from tony_tpu.ops import fused_optim as fo
 from tony_tpu.ops import quant as q
 from tony_tpu.parallel import overlap
+from tony_tpu.train import fsdp_shard_state
 
 pytestmark = pytest.mark.quant
 
@@ -208,7 +208,7 @@ class TestLossPin:
                 model, optax.adamw(1e-3), data["x"],
                 jax.random.PRNGKey(2)), mesh)
 
-        profiler.reset_quant_records()
+        profiler.reset_records("quant")
         sp = fresh()
         sq = q.with_gather_quant(fresh(), mesh, window=4, bucket_bytes=bb)
         step_p = tr.make_accum_train_step(mesh=mesh, microbatches=4,
@@ -227,7 +227,7 @@ class TestLossPin:
         assert len(set(hist.tolist())) > 1
         # The trace banked the gather schedule: int8 wire = raw/4 for
         # f32 params, bytes_saved positive.
-        g = profiler.quant_report()["accum_gather"]
+        g = profiler.report("quant")["accum_gather"]
         assert g["bytes_saved"] > 0
         assert sum(g["raw_nbytes"]) == 4 * sum(g["int8_nbytes"])
         assert g["window"] == 4
@@ -483,22 +483,22 @@ class TestRecords:
         # QuantDense call sites bank their shapes + impl at trace time
         # (the accum_gather record is asserted where it is produced, in
         # TestLossPin.test_quant_gather_accum_tracks_unquantized).
-        profiler.reset_quant_records()
+        profiler.reset_records("quant")
         qmodel = get_model("mnist-mlp", hidden=16, quant=True)
         qmodel.init(jax.random.PRNGKey(0), jnp.ones((2, 784)))
-        dense = [v for k, v in profiler.quant_report().items()
+        dense = [v for k, v in profiler.report("quant").items()
                  if k.startswith("dense.")]
         assert dense and all(d["impl"] in ("pallas", "xla")
                              and d["k"] > 0 for d in dense)
 
     def test_mutating_quant_report_does_not_poison_store(self):
-        profiler.reset_quant_records()
-        profiler.safe_record("quant", "t", nested={"deep": [1, 2]},
-                             raw_nbytes=[10, 20])
-        snap = profiler.quant_report()
+        profiler.reset_records("quant")
+        profiler.record("quant", "t", nested={"deep": [1, 2]},
+                        raw_nbytes=[10, 20])
+        snap = profiler.report("quant")
         snap["t"]["nested"]["deep"].append(99)
         snap["t"]["raw_nbytes"][0] = -1
         snap["injected"] = {}
-        assert profiler.quant_report() == {
+        assert profiler.report("quant") == {
             "t": {"nested": {"deep": [1, 2]}, "raw_nbytes": [10, 20]}}
-        profiler.reset_quant_records()
+        profiler.reset_records("quant")

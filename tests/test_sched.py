@@ -2,7 +2,7 @@
 prefetched ZeRO-3 forward gathers pinned bit-exact against the per-leaf
 path, the static gather schedule (the hoisted spec test), MoE explicit
 per-capacity-chunk all_to_all vs the GSPMD einsum path, pipeline-edge
-registration, and the unified collective_report schema — on the virtual
+registration, and the unified collective record schema — on the virtual
 8-device CPU mesh. `make tier1-sched` runs this file by marker."""
 
 import jax
@@ -14,13 +14,13 @@ from jax.sharding import PartitionSpec as P
 
 from tony_tpu import parallel as par
 from tony_tpu import profiler, train
-from tony_tpu.benchmark import fsdp_shard_state
 from tony_tpu.compat import shard_map
 from tony_tpu.models import get_model
 from tony_tpu.models.moe import MoEMLP
 from tony_tpu.parallel import overlap, sched
 from tony_tpu.parallel.overlap import GradBuckets
 from tony_tpu.parallel.sched import GatherPlan, moe_dispatch_ffn_combine
+from tony_tpu.train import fsdp_shard_state
 
 pytestmark = pytest.mark.sched
 
@@ -197,9 +197,9 @@ class TestZero3ForwardGathers:
         step = train.make_accum_train_step(
             mesh=mesh, microbatches=4, bucket_bytes=32 * 1024,
             prefetch=2, donate=False)
-        profiler.reset_collective_records()
+        profiler.reset_records("collective")
         step(state, batch)
-        rec = profiler.collective_report()["accum.fwd_gather"]
+        rec = profiler.report("collective")["accum.fwd_gather"]
         assert rec["kind"] == "all_gather"
         assert rec["plane"] == "fwd_gather"
         assert rec["axes"] == ["fsdp"]
@@ -265,38 +265,53 @@ class TestPlanShardedEdgeCases:
 
 
 class TestReportAliasing:
-    """Satellite pin: every profiler report is a deep copy behind one
-    shared snapshot helper — mutating a returned report (including its
-    nested lists/dicts) must not poison the live store."""
+    """Satellite pin: every kind's report is a deep copy of the one
+    store — mutating a returned report (including its nested
+    lists/dicts) must not poison the live records."""
 
-    @pytest.mark.parametrize("kind,report,reset", [
-        ("overlap", profiler.overlap_report,
-         profiler.reset_overlap_records),
-        ("ckpt", profiler.ckpt_report, profiler.reset_ckpt_records),
-        ("input", profiler.input_report, profiler.reset_input_records),
-        ("collective", profiler.collective_report,
-         profiler.reset_collective_records),
-        ("update", profiler.update_report, profiler.reset_update_records),
-        ("quant", profiler.quant_report, profiler.reset_quant_records),
-        ("serve", profiler.serve_report, profiler.reset_serve_records),
-        ("analysis", profiler.analysis_report,
-         profiler.reset_analysis_records),
-        ("locks", profiler.lock_report, profiler.reset_lock_records),
-    ])
-    def test_mutating_report_does_not_poison_store(self, kind, report,
-                                                   reset):
-        reset()
-        profiler.safe_record(kind, "t", nested={"deep": [1, 2]},
-                             nbytes=[10, 20])
-        snap = report()
+    @pytest.mark.parametrize("kind", profiler.KINDS)
+    def test_mutating_report_does_not_poison_store(self, kind):
+        profiler.reset_records(kind)
+        profiler.record(kind, "t", nested={"deep": [1, 2]},
+                        nbytes=[10, 20])
+        snap = profiler.report(kind)
         snap["t"]["nested"]["deep"].append(99)
         snap["t"]["nbytes"][0] = -1
         snap["t"]["new_key"] = "poison"
         snap["injected"] = {}
-        clean = report()
+        clean = profiler.report(kind)
         assert clean == {"t": {"nested": {"deep": [1, 2]},
                                "nbytes": [10, 20]}}
-        reset()
+        profiler.reset_records(kind)
+
+    def test_unknown_kind_is_logged_once_and_reported_as_keyerror(
+            self, monkeypatch, caplog):
+        """The writer side never raises (a step must not sink on
+        bookkeeping) and says so once per kind; the reader side does."""
+        import logging
+
+        monkeypatch.setattr(profiler, "_RECORD_FAILED", set())
+        with caplog.at_level(logging.DEBUG, logger="tony_tpu.profiler"):
+            profiler.record("no_such_kind", "t1", n=1)
+            profiler.record("no_such_kind", "t2", n=2)
+        hits = [r for r in caplog.records if "no_such_kind" in r.message]
+        assert len(hits) == 1 and hits[0].levelno == logging.DEBUG
+        with pytest.raises(KeyError):
+            profiler.report("no_such_kind")
+        with pytest.raises(KeyError):
+            profiler.reset_records("no_such_kind")
+        assert "no_such_kind" not in profiler.KINDS
+
+    def test_reset_records_clears_one_kind_or_all(self):
+        profiler.reset_records()
+        for kind in profiler.KINDS:
+            profiler.record(kind, "t", n=1)
+        profiler.reset_records("quant")
+        assert profiler.report("quant") == {}
+        assert all(profiler.report(k) == {"t": {"n": 1}}
+                   for k in profiler.KINDS if k != "quant")
+        profiler.reset_records()
+        assert all(profiler.report(k) == {} for k in profiler.KINDS)
 
 
 class TestMoEExplicitA2A:
@@ -321,12 +336,12 @@ class TestMoEExplicitA2A:
         layer_s = MoEMLP(dim=32, ffn_hidden=64, n_experts=4, top_k=2,
                          dtype=jnp.float32, explicit_a2a=True, mesh=mesh,
                          a2a_chunks=chunks)
-        profiler.reset_collective_records()
+        profiler.reset_records("collective")
         y = layer_s.apply(variables, x)
         np.testing.assert_allclose(np.asarray(jax.device_get(y)),
                                    np.asarray(jax.device_get(y_ref)),
                                    atol=1e-5)
-        rec = profiler.collective_report()
+        rec = profiler.report("collective")
         # Per-issue PER-CHIP payload (same semantics as pipeline edges):
         # [E, B/dp, Cc, D] f32 summed over chunks = E * B/dp * C * D * 4.
         capacity = rec["moe.dispatch"]["capacity"]
@@ -404,13 +419,13 @@ def test_pipeline_edges_registered():
     def stage_fn(p, mb):
         return jnp.tanh(mb @ p["w"][0])
 
-    profiler.reset_collective_records()
+    profiler.reset_records("collective")
     y1 = gpipe(stage_fn, stage_split({"w": w}, 4), x, mesh,
                microbatches=4)
     y2 = gpipe_1f1b(stage_fn, stage_split({"w": w}, 4), x, mesh,
                     microbatches=4)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-6)
-    rec = profiler.collective_report()
+    rec = profiler.report("collective")
     fwd, fb = rec["gpipe.ppermute"], rec["gpipe_1f1b.ppermute"]
     # pp=4 mesh keeps data=2: each DP group's pipeline moves 16/2/4-row
     # microbatches of [*, 8] f32 per edge tick.
@@ -425,9 +440,9 @@ def test_pipeline_edges_registered():
 
 def test_collective_report_covers_all_planes():
     """ACCEPTANCE: every collective a ZeRO-3 + MoE + pipeline step issues
-    shows up in one collective_report() — forward gathers, gradient
+    shows up in one report("collective") — forward gathers, gradient
     scatter/reduce buckets, expert a2a, and pipeline edges."""
-    profiler.reset_collective_records()
+    profiler.reset_records("collective")
 
     # ZeRO-3 accum step (fwd all_gather + grad psum_scatter/all_reduce).
     mesh = par.make_mesh(fsdp=4)
@@ -461,7 +476,7 @@ def test_collective_report_covers_all_planes():
                jax.random.normal(jax.random.PRNGKey(5), (16, 8)),
                mesh_p, microbatches=4)
 
-    rec = profiler.collective_report()
+    rec = profiler.report("collective")
     kinds = {r["kind"] for r in rec.values()}
     assert {"all_gather", "psum_scatter", "all_to_all",
             "ppermute"} <= kinds
@@ -470,20 +485,3 @@ def test_collective_report_covers_all_planes():
     # Schema: every record carries kind/axes/nbytes.
     for tag, r in rec.items():
         assert {"kind", "axes", "nbytes"} <= set(r), tag
-
-
-def test_run_sched_bench_smoke(monkeypatch):
-    """The bench leg runs on the CPU mesh and reports bit-exact numerics
-    plus the unified records (the speedup itself is hardware-dependent
-    and not asserted here)."""
-    from tony_tpu.benchmark import run_sched_bench
-
-    monkeypatch.setenv("BENCH_WINDOWS", "1")
-    r = run_sched_bench(leaves=12, leaf_rows=8, leaf_cols=16,
-                        bucket_bytes=1024, steps=1)
-    assert r["gather_bitexact"] and r["zero3_bitexact"]
-    assert r["gather_per_leaf_s"] > 0 and r["gather_bucketed_s"] > 0
-    assert r["n_gather_buckets"] >= 1
-    assert r.get("moe_numerics_ok", True)
-    kinds = {rec.get("kind") for rec in r["collective_records"].values()}
-    assert "all_gather" in kinds

@@ -294,7 +294,7 @@ class TestEngine:
         from tony_tpu.executor import read_serve_stats
         from tony_tpu.serve import Request
 
-        profiler.reset_serve_records()
+        profiler.reset_records("serve")
         eng = make_engine(tiny, tag="serve_test")
         eng.submit(Request(rid="r", tokens=[1, 2, 3], max_new_tokens=2))
         eng.run()
@@ -309,12 +309,12 @@ class TestEngine:
         assert stats["tokens_per_forward"] == pytest.approx(
             2.0 / stats["forwards"])
         assert stats["acceptance_rate"] == 0.0
-        report = profiler.serve_report()
+        report = profiler.report("serve")
         assert report["serve_test"]["ctx_pad"] == eng.ctx_pad
         assert report["serve_test_stats"]["completed"] == 1.0
         # The planner registration landed in the unified collective
         # schema (ROADMAP: new step-path planes register day one).
-        assert profiler.collective_report()["serve_decode"]["plane"] \
+        assert profiler.report("collective")["serve_decode"]["plane"] \
             == "serve_decode"
         # Stats file round-trips through the executor's jax-free reader.
         path = tmp_path / "serve-stats.json"
@@ -341,17 +341,16 @@ class TestEngine:
     def test_mutating_serve_report_does_not_poison_store(self):
         from tony_tpu import profiler
 
-        profiler.reset_serve_records()
-        profiler.safe_record("serve", "t", nested={"deep": [1, 2]},
-                             n=1)
-        snap = profiler.serve_report()
+        profiler.reset_records("serve")
+        profiler.record("serve", "t", nested={"deep": [1, 2]}, n=1)
+        snap = profiler.report("serve")
         snap["t"]["nested"]["deep"].append(99)
         snap["t"]["poison"] = True
-        clean = profiler.serve_report()
+        clean = profiler.report("serve")
         assert clean["t"]["nested"] == {"deep": [1, 2]}
         assert "poison" not in clean["t"]
-        profiler.reset_serve_records()
-        assert profiler.serve_report() == {}
+        profiler.reset_records("serve")
+        assert profiler.report("serve") == {}
 
 
 # ---------------------------------------------------------------------------

@@ -453,7 +453,7 @@ class TestSpecTelemetry:
         from tony_tpu import profiler
         from tony_tpu.serve import Request
 
-        profiler.reset_serve_records()
+        profiler.reset_records("serve")
         eng = make_spec(tiny, spec_k=3, tag="spec_test")
         eng.submit(Request(rid="r", tokens=[1, 2, 3, 1, 2, 3],
                            max_new_tokens=5))
@@ -468,7 +468,7 @@ class TestSpecTelemetry:
         assert stats["tokens_per_forward"] > 0
         # One launch per iteration emits >= 1 token per sequence.
         assert stats["tokens_per_seq_round"] >= 1.0
-        report = profiler.serve_report()
+        report = profiler.report("serve")
         assert report["spec_test_spec"]["k"] == 3
         assert report["spec_test_spec"]["draft"] == "ngram"
         assert report["spec_test_stats"]["verify_launches"] == \
@@ -479,7 +479,7 @@ class TestSpecTelemetry:
         pstats = plain.stats()
         assert pstats["acceptance_rate"] == 0.0
         assert "tokens_per_forward" in pstats
-        profiler.reset_serve_records()
+        profiler.reset_records("serve")
 
     def test_executor_heartbeat_carries_effective_throughput(
             self, tmp_path):
@@ -536,16 +536,16 @@ class TestSpecTelemetry:
     def test_mutating_spec_report_does_not_poison_store(self):
         from tony_tpu import profiler
 
-        profiler.reset_serve_records()
-        profiler.safe_record("serve", "spec_t",
-                             nested={"accept": [1, 0, 1]}, k=4)
-        snap = profiler.serve_report()
+        profiler.reset_records("serve")
+        profiler.record("serve", "spec_t",
+                        nested={"accept": [1, 0, 1]}, k=4)
+        snap = profiler.report("serve")
         snap["spec_t"]["nested"]["accept"].append(9)
         snap["spec_t"]["poison"] = True
-        clean = profiler.serve_report()
+        clean = profiler.report("serve")
         assert clean["spec_t"]["nested"] == {"accept": [1, 0, 1]}
         assert "poison" not in clean["spec_t"]
-        profiler.reset_serve_records()
+        profiler.reset_records("serve")
 
 
 # ---------------------------------------------------------------------------

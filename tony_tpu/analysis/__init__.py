@@ -30,7 +30,7 @@ Rule suite (see :mod:`tony_tpu.analysis.rules`):
 
 Findings come back structured with a waiver mechanism
 (:class:`Waiver`); each run banks a summary into
-``tony_tpu.profiler.analysis_report()`` alongside the existing report
+``tony_tpu.profiler.report("analysis")`` alongside the existing report
 family. ``tony analyze`` (:mod:`tony_tpu.analysis.cli`) runs the suite
 over the shipped train-step configs; ``make lint`` runs the companion
 source lint (:mod:`tony_tpu.analysis.srclint`).

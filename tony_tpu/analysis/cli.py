@@ -92,7 +92,6 @@ def build_target(name: str, *, donate: bool = True):
 
     from tony_tpu import parallel as par
     from tony_tpu import train
-    from tony_tpu.benchmark import fsdp_shard_state
     from tony_tpu.models import get_model
     from tony_tpu.ops import fused_optim
 
@@ -120,7 +119,7 @@ def build_target(name: str, *, donate: bool = True):
         tx = optax.adamw(1e-3)
     state = train.create_train_state(model, tx, x, kr)
     if name != "bucketed":
-        state = fsdp_shard_state(state, mesh)
+        state = train.fsdp_shard_state(state, mesh)
     if name == "quant":
         from tony_tpu.ops import quant as quant_mod
 

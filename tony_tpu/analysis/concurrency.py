@@ -29,7 +29,7 @@ stays runnable on a gateway host:
    acquisitions across every module, merged with the edges a runtime
    *lock witness* observed (:class:`WitnessLock` — an instrumented
    Lock/RLock/Condition shim recording per-thread acquisition chains
-   into the profiler's ``lock_report()`` registry). Cycle detection
+   into the profiler's ``report("locks")`` registry). Cycle detection
    over the merged graph turns a potential deadlock into a NAMED
    finding with the full cycle and the first-observation sites — not a
    hung CI job.
@@ -59,7 +59,7 @@ from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
-from tony_tpu._trace import trace_record
+from tony_tpu import profiler
 # One definition of package-relative display paths and the default lint
 # root for BOTH source lints (jax-free like this module) — baseline
 # fingerprints and srclint's allowlist must never disagree on what a
@@ -512,7 +512,7 @@ def _held_stack() -> List[str]:
 
 class _WitnessGraph:
     """Process-global observed lock-order graph. New edges bank a fresh
-    snapshot into ``tony_tpu.profiler.lock_report()`` (registry
+    snapshot into ``tony_tpu.profiler.report("locks")`` (registry
     ``"locks"``, tag ``"witness"``) — banking only on NEW edges keeps
     the steady-state acquire path to one dict hit under this lock."""
 
@@ -557,8 +557,8 @@ class _WitnessGraph:
         self.bank()
 
     def bank(self, tag: str = "witness") -> None:
-        trace_record("locks", tag, locks=self.locks(),
-                     edges=self.edges())
+        profiler.record("locks", tag, locks=self.locks(),
+                        edges=self.edges())
 
 
 def _caller_site() -> str:
@@ -686,7 +686,7 @@ def reset_witness() -> None:
 
 def bank_witness(tag: str = "witness") -> None:
     """Bank the current observed graph into
-    ``tony_tpu.profiler.lock_report()`` under ``tag``."""
+    ``tony_tpu.profiler.report("locks")`` under ``tag``."""
     _GRAPH.bank(tag)
 
 
@@ -874,18 +874,18 @@ def analyze_concurrency(root: Optional[str | Path] = None,
     the installed package), lock-order cycle check over the static graph
     merged with the live witness graph, baseline applied. Banks a
     summary record next to the jaxpr analyzer's
-    (``profiler.analysis_report()``, tag ``"concurrency"``)."""
+    (``profiler.report("analysis")``, tag ``"concurrency"``)."""
     findings, edges = analyze_tree(root or default_root())
     observed = observed_edges() if include_witness else []
     findings.extend(check_lock_order(edges, observed))
     baseline = load_baseline(baseline_path) if baseline_path else {}
     active, blessed = apply_baseline(findings, baseline)
     report = ConcReport(active, blessed, edges, observed)
-    trace_record("analysis", "concurrency",
-                 findings=len(active), blessed=len(blessed),
-                 rules=sorted({f.rule for f in active}),
-                 static_edges=len(edges),
-                 witnessed_edges=len(observed))
+    profiler.record("analysis", "concurrency",
+                    findings=len(active), blessed=len(blessed),
+                    rules=sorted({f.rule for f in active}),
+                    static_edges=len(edges),
+                    witnessed_edges=len(observed))
     return report
 
 

@@ -15,7 +15,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
-from tony_tpu._trace import trace_record
+from tony_tpu import profiler
 from tony_tpu.analysis import jaxprwalk, rules, signature
 from tony_tpu.analysis.jaxprwalk import (CollectiveEqn, collect_collectives,
                                          live_high_water)
@@ -33,9 +33,8 @@ __all__ = [
     "step_signature",
 ]
 
-# Trace-time side channel into the profiler registry (shared shim
-# contract: lazy import, swallow-all, log-once — see tony_tpu._trace).
-_record = functools.partial(trace_record, "analysis")
+# Trace-time side channel into the profiler's plan registry.
+_record = functools.partial(profiler.record, "analysis")
 
 
 @dataclass(frozen=True)
@@ -265,7 +264,7 @@ def analyze_accum_step(stepper: Any, state: Any, batch: Any, *,
     step, the :class:`~tony_tpu.parallel.overlap.GradBuckets` /
     :class:`~tony_tpu.parallel.sched.GatherPlan` pair, and the config
     knobs; traces (never executes) the step; runs all five rules; banks
-    the result into ``profiler.analysis_report()``. ``signature_path``
+    the result into ``profiler.report("analysis")``. ``signature_path``
     additionally pins the digest against a committed snapshot
     (rule 5 — drift becomes a finding)."""
     info = stepper.inspect(state)

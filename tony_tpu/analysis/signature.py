@@ -8,11 +8,10 @@ collective census (count per kind + total payload bytes), the
 optimization-barrier count (the prefetch chain), and the donation-aware
 live-buffer high-water estimate. All of it is a pure function of the
 jaxpr, so two traces of the same code on the same jax pin produce
-byte-identical digests — structural claims of the BENCH_r10 kind
-("3467 → 890 eqns") become pin-able as committed files. The shipped pins
-under ``tests/signatures/`` cover the canonical ``tony analyze`` configs
-(the small mnist-mlp harness geometry, e.g. 305 eqns for the fused
-step), not the bench-sized tree.
+byte-identical digests — structural claims ("3467 → 890 eqns") become
+pin-able as committed files. The shipped pins under ``tests/signatures/``
+cover the canonical ``tony analyze`` configs (the small mnist-mlp harness
+geometry, e.g. 305 eqns for the fused step).
 
 Regenerating after an INTENDED change: run with ``TONY_UPDATE_SIGNATURES=1``
 (or ``tony analyze --signatures tests/signatures --update-signatures``)

@@ -2,9 +2,9 @@
 
 Extends the ``TONY_CKPT_CRASH`` idiom (:mod:`tony_tpu.ckpt.format`) from
 one checkpoint-commit fault to a vocabulary the whole control plane
-consults, so the elastic-resize pins are machine-checkable: a test (or
-``bench.py``) arms a fault schedule through ``TONY_CHAOS_*`` env vars
-and the production code paths fire it at the instrumented sites —
+consults, so the elastic-resize pins are machine-checkable: a test arms
+a fault schedule through ``TONY_CHAOS_*`` env vars and the production
+code paths fire it at the instrumented sites —
 
 * ``TONY_CHAOS_KILL_STEP=k`` — SIGKILL this process as TRAINING step
   ``k`` begins (:func:`tony_tpu.train.train_loop` consults

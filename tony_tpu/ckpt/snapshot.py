@@ -18,7 +18,7 @@ background thread:
   a THIRD save stalls until a slot frees. The stall time (slot wait +
   extract) is what the train loop actually pays — the profiler records it
   next to the blocking write time so the overlap is measurable
-  (:func:`tony_tpu.profiler.ckpt_report`, ``run_ckpt_bench``).
+  (``tony_tpu.profiler.report("ckpt")``).
 
 Writer errors never vanish: they surface on the next ``save``/``wait``.
 """
@@ -36,13 +36,12 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from tony_tpu._trace import trace_record
+from tony_tpu import profiler
 from tony_tpu.ckpt import format as fmt
 
 
-# Trace-side channel into the profiler registry (shared shim: lazy
-# import + swallow-all, log-once lives in profiler.safe_record).
-_record = functools.partial(trace_record, "ckpt")
+# Per-save channel into the profiler's plan registry.
+_record = functools.partial(profiler.record, "ckpt")
 
 
 def _is_saveable(leaf: Any) -> bool:
@@ -182,8 +181,7 @@ class AsyncCheckpointer:
     ``save(state, step)`` stalls the caller only for slot acquisition plus
     the device→host extract; serialization, fsync, and the atomic commit
     run on the writer thread so subsequent train steps overlap the I/O.
-    ``save(..., block=True)`` degrades to a blocking save (the comparison
-    leg ``run_ckpt_bench`` measures).
+    ``save(..., block=True)`` degrades to a blocking save.
 
     One live instance per process per directory: construction sweeps torn
     staging dirs from crashed predecessors, so a second concurrent

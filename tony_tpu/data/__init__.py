@@ -20,7 +20,7 @@ too. Four pieces:
   a background thread stages the next K batches host→device through
   ``train.global_batch`` so the step never blocks on the feed; the stall
   it does pay is recorded per step in
-  :func:`tony_tpu.profiler.input_report` (``run_input_bench`` measures);
+  ``tony_tpu.profiler.report("input")``;
 * **checkpointable iterator state** (:mod:`~tony_tpu.data.ckptio`) — the
   cursor rides the PR 3 ``ckpt`` manifest in the same atomic commit as
   the train state (``train_loop(data=...)``), and restores elastically

@@ -9,9 +9,9 @@ the devices via :func:`tony_tpu.train.global_batch`, so ``next()`` in the
 train loop returns a device-resident global batch immediately whenever
 the producer is keeping up. The time ``next()`` DOES block — the input
 stall the step actually pays — is recorded per step in the profiler
-(:func:`tony_tpu.profiler.input_report`), next to the overlap and ckpt
-records, so "the feed is hidden" is a measured number (``run_input_bench``
-serializes it; BENCH_r08).
+(``tony_tpu.profiler.report("input")``), next to the overlap and ckpt
+records, so whether the feed is hidden can be read off a run (not
+measured on the chip: no cell of the benchmark feeds from a dataset).
 
 Checkpoint correctness under prefetch: each staged batch carries the
 pipeline state taken AFTER producing it; :meth:`DeviceIterator.state`
@@ -35,10 +35,10 @@ import time
 import weakref
 from typing import Any, Dict, Mapping, Optional
 
-from tony_tpu._trace import trace_record
+from tony_tpu import profiler
 from tony_tpu.data.pipeline import PipelineIterator
 
-_record = functools.partial(trace_record, "input")
+_record = functools.partial(profiler.record, "input")
 
 
 class _Stop:
@@ -100,8 +100,8 @@ class DeviceIterator:
     * ``depth >= 1``: a background thread fetches, maps, and stages the
       next ``depth`` batches host→device; ``next()`` only blocks when the
       producer falls behind (the measured input stall).
-    * ``depth == 0``: fully synchronous — the comparison leg the input
-      bench measures the stall of.
+    * ``depth == 0``: fully synchronous — what a prefetching run is
+      compared with.
     * ``mesh=None``: batches stay host-side (single-process loops, tests);
       with a mesh each batch is assembled into the logically-global array
       via :func:`tony_tpu.train.global_batch` (sharded over the DP axes,

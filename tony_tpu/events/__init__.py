@@ -25,7 +25,7 @@ PR 18 makes the log LOAD-BEARING, not decorative — three widenings:
   per-tenant breakdown. The history portal's SLO dashboards and the
   per-tenant rollups render from exactly these records.
 * TRAIN_STEP — per-step wall time, collective bytes (from
-  ``profiler.collective_report()``) and an MFU estimate, fed through
+  ``profiler.report("collective")``) and an MFU estimate, fed through
   the executor's stats-file pickup like serve stats.
 * SCALE_DECISION — a SELF-VERIFYING autoscale record: the full decide()
   input (policy fields, active count, samples, clock, last action) plus
@@ -224,9 +224,8 @@ class EventHandler:
                    step_time_s: float, collective_bytes: float = 0.0,
                    mfu: float = 0.0) -> None:
         """One training step's cost triple: wall time, collective bytes
-        (``profiler.collective_report()``'s total for the step plane),
-        and the caller's MFU estimate — the portal's per-step trend
-        across BENCH rounds."""
+        (``profiler.report("collective")``'s total for the step plane),
+        and the caller's MFU estimate — the portal's per-step trend."""
         self.emit(TRAIN_STEP, job_type=job_type, index=index,
                   step=int(step), step_time_s=float(step_time_s),
                   collective_bytes=float(collective_bytes),

@@ -234,8 +234,7 @@ def s2d_stem_kernel(k7: jax.Array) -> jax.Array:
 
 
 def resnet50_flops(batch: int, image: int = 224) -> int:
-    """Analytic forward FLOPs (≈4.1 GFLOP @224²); training ≈3× forward.
-    Used by bench.py's MFU computation."""
+    """Analytic forward FLOPs (≈4.1 GFLOP @224²); training ≈3× forward."""
     # Standard figure: 4.089e9 MACs*2 fwd for 224x224.
     per_image = 8.2e9 * (image / 224) ** 2
     return int(per_image * batch)

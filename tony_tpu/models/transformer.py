@@ -404,17 +404,16 @@ class Transformer(nn.Module):
         with jax.named_scope("embed"):
             if _sharded_training():
                 # Sharded multi-device training only — on one device the
-                # one-hot costs ~18 ms/step of uncounted work at the bench
-                # shape (found as a 4.3-MFU-pt regression in r5; the train
-                # harness applies the rules context even unsharded): look
-                # up via one-hot matmul, not gather. The table is
+                # one-hot is uncounted work every step (the train harness
+                # applies the rules context even unsharded): look up via
+                # one-hot matmul, not gather. The table is
                 # (vocab→model, embed→fsdp)-sharded while activations want
                 # batch over (data, fsdp) — GSPMD reshard s dots cleanly
                 # (psum over the contracted vocab axis + reduce-scatter)
                 # but a gather's embed-fsdp→batch-fsdp transition is an
                 # "involuntary full rematerialization": replicate-then-
-                # slice EVERY step, fwd and transpose (MULTICHIP_r04 tail;
-                # VERDICT r4 next-step #3). The one-hot term is
+                # slice EVERY step, fwd and transpose (VERDICT r4
+                # next-step #3). The one-hot term is
                 # 2·vocab·dim FLOPs/token ≈ 0.6% of a 7B step, and it
                 # rides the MXU.
                 x = jax.nn.one_hot(tokens, cfg.vocab, dtype=cfg.dtype) \

@@ -60,13 +60,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tony_tpu._trace import trace_record
+from tony_tpu import profiler
 from tony_tpu.parallel import FSDP
 from tony_tpu.parallel.overlap import DEFAULT_BUCKET_BYTES, GradBuckets
 
-# Trace-time side channel into the profiler registry (shared shim contract:
-# lazy import, swallow-all, log-once — see tony_tpu._trace).
-_record = functools.partial(trace_record, "update")
+# Trace-time side channel into the profiler's plan registry.
+_record = functools.partial(profiler.record, "update")
 
 RULES: Tuple[str, ...] = ("adamw", "sgd", "adafactor")
 
@@ -483,7 +482,7 @@ class FusedOptimizer:
         return out
 
     def record(self, tag: str, plan: GradBuckets, **extra) -> None:
-        """Bank the update schedule into ``profiler.update_report()``."""
+        """Bank the update schedule into ``profiler.report("update")``."""
         _record(tag, rule=self.rule, impl=self.resolved_impl(),
                 n_buckets=plan.n_buckets,
                 n_scatter_buckets=plan.n_scatter_buckets,
@@ -502,7 +501,7 @@ def fused_update_step(fused: FusedOptimizer, params: Any, grads: Any,
                       param_specs: Optional[Any] = None
                       ) -> Tuple[Any, Dict[str, Any], jax.Array]:
     """Standalone leaf-major entry: pack ``grads`` into the plan's bucket
-    buffers and run the fused update — the optax pin / bench surface
+    buffers and run the fused update — the surface the optax pins test
     (``make_accum_train_step(update="fused_bucket")`` fuses the same
     :meth:`~FusedOptimizer.region_apply` into its accum region so the
     grads never leave the bucket domain at all).

@@ -59,11 +59,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tony_tpu._trace import trace_record
+from tony_tpu import profiler
 
-# Trace-time side channel into the profiler registry (shared shim
-# contract: lazy import, swallow-all, log-once — see tony_tpu._trace).
-_record = functools.partial(trace_record, "quant")
+# Trace-time side channel into the profiler's plan registry.
+_record = functools.partial(profiler.record, "quant")
 
 # Symmetric int8: values in [-127, 127] (the -128 code is unused so the
 # range is symmetric and negation is exact).

@@ -45,8 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tony_tpu import compat
-from tony_tpu._trace import trace_record
+from tony_tpu import compat, profiler
 from tony_tpu.parallel import DATA, FSDP, SLICE
 
 _log = logging.getLogger(__name__)
@@ -449,9 +448,8 @@ class GradBuckets:
         return self.unpack(out)
 
 
-# Trace-time side channel into the profiler registry (shared shim: lazy
-# import + swallow-all, log-once lives in profiler.safe_record).
-_record = functools.partial(trace_record, "overlap")
+# Trace-time side channel into the profiler's plan registry.
+_record = functools.partial(profiler.record, "overlap")
 
 
 def reduce_schedule(plan: "GradBuckets", mesh: Mesh, *,
@@ -763,7 +761,7 @@ def microbatch_grads(loss_fn: Callable[[Any, Any], Any], params: Any,
             levels=levels)
     # Mirror the whole schedule into the unified collective registry: the
     # reduce levels plus (ZeRO-3) the forward gathers, so every transfer
-    # in the step shows up in profiler.collective_report().
+    # in the step shows up in profiler.report("collective").
     sched_mod.record_reduce_levels("accum", levels)
     if zero3 and gplan.gather_leaves:
         if gather == "bucketed":
@@ -784,7 +782,7 @@ def microbatch_grads(loss_fn: Callable[[Any, Any], Any], params: Any,
     if quant:
         raw = list(gplan.gather_nbytes)
         q_nb = [plan.bucket_numel[b] for b in gplan.gather_buckets]
-        trace_record(
+        profiler.record(
             "quant", "accum_gather", n_buckets=gplan.n_gather_buckets,
             window=int(quant_amax[0].shape[0]) if quant_amax else 0,
             raw_nbytes=raw, int8_nbytes=q_nb,

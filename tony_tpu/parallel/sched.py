@@ -31,7 +31,7 @@ that planner into the general scheduler the ROADMAP names:
 * :func:`record_pipeline_edges` — registers ``gpipe``/``gpipe_1f1b``'s
   ``ppermute`` ring edges with the scheduler so pipeline traffic shares
   the same profiler record schema as everything else.
-* :func:`record_collective` / ``profiler.collective_report()`` — the one
+* :func:`record_collective` / ``profiler.report("collective")`` — the one
   record schema (kind, plane, axes, per-issue nbytes + freeform extras):
   every collective in a ZeRO-3 + MoE + pipeline step is either hidden or
   accounted for, inspectable from one report.
@@ -48,8 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tony_tpu import compat
-from tony_tpu._trace import trace_record
+from tony_tpu import compat, profiler
 from tony_tpu.parallel import DATA, EXPERT, FSDP, MODEL, PIPE, SEQ, SLICE
 from tony_tpu.parallel.overlap import GradBuckets
 
@@ -59,9 +58,9 @@ from tony_tpu.parallel.overlap import GradBuckets
 # gathers issue eagerly — max overlap, max transient replicated memory).
 DEFAULT_PREFETCH = 1
 
-# Trace-time side channel into the unified profiler registry (same shim
-# contract as overlap's _record: lazy import, swallow-all, log-once).
-record_collective = functools.partial(trace_record, "collective")
+# Trace-time side channel into the profiler's plan registry, kind
+# "collective": one schema for every inter-chip transfer the step issues.
+record_collective = functools.partial(profiler.record, "collective")
 
 
 @dataclass(frozen=True)
@@ -266,7 +265,7 @@ def moe_dispatch_ffn_combine(x: jax.Array, dispatch: jax.Array,
     itemsize = np.dtype(dtype).itemsize
     # Per-issue PER-CHIP payload (the [E, B_local, Cc, D] tensor each
     # chip exchanges) — same semantics as the pipeline-edge records, so
-    # collective_report() byte columns compare across planes.
+    # report("collective") byte columns compare across planes.
     chunk_nbytes = [
         e * (x.shape[0] // dp) * int(bounds[j + 1] - bounds[j])
         * x.shape[-1] * itemsize for j in range(n_chunks)]

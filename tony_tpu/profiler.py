@@ -1,5 +1,5 @@
 """Observability from inside the program: spans and counters on one
-timeline, the trace-time plan registries, and trace collection.
+timeline, the trace-time plan registry, and trace collection.
 
 Three halves, one module (imports no jax at module level: the executor,
 the AM and the history plane import it too):
@@ -18,8 +18,9 @@ the AM and the history plane import it too):
   ``tony history show`` then says where a task's start went, with no
   profiler attached. Per-step and per-iteration spans go to the TraceMe
   only — nothing is appended on the hot path.
-* **Plan registries** (``record_overlap`` ... ``record_locks``): what
-  the planners decided at jit-trace time, last plan per tag wins.
+* **The plan registry** (:func:`record`, :func:`report`,
+  :func:`reset_records` over :data:`KINDS`): what the planners decided at
+  jit-trace time, last plan per tag wins.
 * **Trace collection** (SURVEY.md §5.1): every task whose job set
   ``tony.task.profiler.enabled`` runs ``jax.profiler.start_server`` on
   the port the JAXRuntime assigned (training tasks from
@@ -40,6 +41,7 @@ from __future__ import annotations
 
 import atexit
 import contextlib
+import copy
 import json
 import logging
 import os
@@ -315,304 +317,76 @@ def _arm_exit_write() -> None:
         atexit.register(write_timeline)
 
 
-def _snapshot(store: Dict[str, Dict[str, object]]
-              ) -> Dict[str, Dict[str, object]]:
-    """THE report contract, shared by every registry below: a deep copy of
-    the store — including nested per-level/per-bucket lists — so callers
-    can serialize or mutate a report without poisoning the live records
-    (the report schemas had drifted; ``tests/test_sched.py`` pins all of
-    them on this one helper)."""
-    import copy
-
-    return {k: copy.deepcopy(v) for k, v in store.items()}
-
-
 # ---------------------------------------------------------------------------
-# Overlap-engine instrumentation (the comm/compute overlap tentpole): the
-# engine's planners call :func:`record_overlap` at TRACE time — once per
-# compile, not per step — so per-bucket collective sizes and schedule tick
-# counts are inspectable next to the xplane traces without parsing HLO.
-# Keyed by tag ("accum_step", "gpipe", "gpipe_1f1b"); last plan per tag
-# wins (a recompile IS a new plan). Hierarchical/ZeRO-3 plans additionally
-# carry a ``levels`` list — one entry per reduction level ("ici"/"dcn")
-# with the collective op, its mesh axes, and the bytes each bucket moves
-# AT THAT LEVEL (the DCN entry shows the scattered-chunk sizes, i.e. what
-# actually crosses slices per bucket).
-OVERLAP_RECORDS: Dict[str, Dict[str, object]] = {}
-
-
-def record_overlap(tag: str, **fields) -> None:
-    """Bank one overlap plan/schedule record (bucket count & bytes,
-    microbatches, reduce op, per-level plans, schedule tick count...)."""
-    OVERLAP_RECORDS[tag] = dict(fields)
-
-
-def overlap_report() -> Dict[str, Dict[str, object]]:
-    """Snapshot of every recorded overlap plan (deep-copied via
-    :func:`_snapshot`: callers serialize this into bench/metrics JSON and
-    must not alias the live registry)."""
-    return _snapshot(OVERLAP_RECORDS)
-
-
-def reset_overlap_records() -> None:
-    OVERLAP_RECORDS.clear()
-
-
-# ---------------------------------------------------------------------------
-# Unified collective instrumentation (the collective-scheduler tentpole):
-# ONE record schema for every inter-chip transfer the step issues —
-# forward param gathers, gradient scatter/allreduce buckets, MoE expert
-# all_to_all, pipeline ppermute edges — so "every collective is either
-# hidden or accounted for" is inspectable from one report instead of four
-# plane-specific ones. Writers go through :mod:`tony_tpu.parallel.sched`
-# (``record_collective``); keyed by tag, last plan per tag wins. Schema
-# (enforced by the sched-side writer, not here):
-#   kind   — all_gather | psum_scatter | all_reduce | all_to_all | ppermute
-#   plane  — fwd_gather | grad_reduce | moe | pipeline
-#   axes   — mesh axes the collective runs over
-#   nbytes — per-issue payload bytes (list)
-# plus freeform extras (prefetch depth, level, chunk count, measured
-# hidden/exposed seconds from the bench legs...).
-COLLECTIVE_RECORDS: Dict[str, Dict[str, object]] = {}
-
-
-def record_collective(tag: str, /, **fields) -> None:
-    """Bank one collective schedule record under the unified schema."""
-    COLLECTIVE_RECORDS[tag] = dict(fields)
-
-
-def collective_report() -> Dict[str, Dict[str, object]]:
-    """Snapshot of every scheduled collective (deep-copied via
-    :func:`_snapshot` — same aliasing contract as the other reports)."""
-    return _snapshot(COLLECTIVE_RECORDS)
-
-
-def reset_collective_records() -> None:
-    COLLECTIVE_RECORDS.clear()
-
-
-# ---------------------------------------------------------------------------
-# Checkpoint-plane instrumentation (tony_tpu.ckpt): the async snapshot
-# engine records per-save timing — the stall the train loop actually paid
-# (slot wait + device→host extract) vs the background write/commit time —
-# keyed by tag ("async_save", "blocking_save"); last save per tag wins.
-# run_ckpt_bench serializes this next to the overlap records so "async
-# saves overlap training" is a measured number, not a design claim.
-CKPT_RECORDS: Dict[str, Dict[str, object]] = {}
-
-
-def record_ckpt(tag: str, **fields) -> None:
-    """Bank one checkpoint-save record (stall/extract/write seconds,
-    payload bytes, chunk count...)."""
-    CKPT_RECORDS[tag] = dict(fields)
-
-
-def ckpt_report() -> Dict[str, Dict[str, object]]:
-    """Snapshot of every recorded checkpoint save (deep-copied via
-    :func:`_snapshot` — same aliasing contract as
-    :func:`overlap_report`)."""
-    return _snapshot(CKPT_RECORDS)
-
-
-def reset_ckpt_records() -> None:
-    CKPT_RECORDS.clear()
-
-
-# ---------------------------------------------------------------------------
-# Input-plane instrumentation (tony_tpu.data): the prefetching device
-# iterator records, per delivered batch, the time the train loop actually
-# blocked waiting on the feed (the input stall — the transfer T3 says must
-# hide under compute) plus rolling means of wait and host→device placement
-# time. Keyed by iterator tag (default "input"); last step per tag wins.
-# run_input_bench serializes this next to the overlap/ckpt records so
-# "prefetch hides the feed" is a measured number (BENCH_r08).
-INPUT_RECORDS: Dict[str, Dict[str, object]] = {}
-
-
-def record_input(tag: str, **fields) -> None:
-    """Bank one input-feed record (prefetch depth, steps, last/total wait
-    seconds, mean wait/placement ms...)."""
-    INPUT_RECORDS[tag] = dict(fields)
-
-
-def input_report() -> Dict[str, Dict[str, object]]:
-    """Snapshot of every recorded input feed (deep-copied via
-    :func:`_snapshot` — same aliasing contract as
-    :func:`overlap_report`)."""
-    return _snapshot(INPUT_RECORDS)
-
-
-def reset_input_records() -> None:
-    INPUT_RECORDS.clear()
-
-
-# ---------------------------------------------------------------------------
-# Fused-optimizer instrumentation (tony_tpu.ops.fused_optim): the update
-# plane records, at trace time, the bucket-major update schedule — bucket
-# count and per-bucket payload bytes, which kernel path ran (pallas vs the
-# pure-XLA fallback), the rule and its slot layout — keyed by tag
-# ("accum_update" from the in-region accum path, "fused_update" from the
-# standalone step); last plan per tag wins. run_optim_bench serializes
-# this next to the overlap records so "one launch per bucket" is an
-# inspectable number, not a design claim.
-UPDATE_RECORDS: Dict[str, Dict[str, object]] = {}
-
-
-def record_update(tag: str, /, **fields) -> None:
-    """Bank one fused-optimizer update record (rule, impl, bucket count &
-    bytes, slot layout, clip/decay config...)."""
-    UPDATE_RECORDS[tag] = dict(fields)
-
-
-def update_report() -> Dict[str, Dict[str, object]]:
-    """Snapshot of every recorded update schedule (deep-copied via
-    :func:`_snapshot` — same aliasing contract as the other reports)."""
-    return _snapshot(UPDATE_RECORDS)
-
-
-def reset_update_records() -> None:
-    UPDATE_RECORDS.clear()
-
-
-# ---------------------------------------------------------------------------
-# Quantized-lane instrumentation (tony_tpu.ops.quant): the int8 lane
-# records, at trace time, where quantization actually happened — per
-# quant_dot call site (shapes, impl, per-channel, int8 vs bf16 operand
-# bytes), the quantize-on-gather schedule (bucket count, delayed-scaling
-# window, raw vs int8 wire bytes = the 4×-fewer-gather-bytes claim as an
-# inspectable number), and the attach-time state geometry. Keyed by tag
-# ("dense.<name>", "accum_gather", "attach"); last plan per tag wins.
-# run_quant_bench serializes this next to the other records (BENCH_r11).
-QUANT_RECORDS: Dict[str, Dict[str, object]] = {}
-
-
-def record_quant(tag: str, /, **fields) -> None:
-    """Bank one quantized-lane record (matmul shapes/impl, scale-window
-    geometry, gather bytes saved...)."""
-    QUANT_RECORDS[tag] = dict(fields)
-
-
-def quant_report() -> Dict[str, Dict[str, object]]:
-    """Snapshot of every recorded quantization site (deep-copied via
-    :func:`_snapshot` — same aliasing contract as the other reports)."""
-    return _snapshot(QUANT_RECORDS)
-
-
-def reset_quant_records() -> None:
-    QUANT_RECORDS.clear()
-
-
-# ---------------------------------------------------------------------------
-# Serving-plane instrumentation (tony_tpu.serve): the engine records its
-# build-time geometry (context extent, block pool size, row block,
-# decode buckets, join policy) under the engine tag and its live
-# telemetry — the heartbeat triple qps/p99/queue-depth plus rates, and
-# since the speculative lane (serve.spec) also tokens_per_forward,
-# acceptance_rate, proposed/accepted token counts, and verify-launch
-# counts — under "<tag>_stats"; the speculative geometry (draft kind,
-# depth k) under "<tag>_spec"; the replica banks restore geometry under
-# "replica". Keyed by tag; last record per tag wins. run_serve_bench /
-# run_spec_bench serialize this next to the other records
-# (BENCH_r12/r13).
-SERVE_RECORDS: Dict[str, Dict[str, object]] = {}
-
-
-def record_serve(tag: str, /, **fields) -> None:
-    """Bank one serving-plane record (engine geometry, qps/p50/p99/
-    queue-depth telemetry, replica restore geometry...)."""
-    SERVE_RECORDS[tag] = dict(fields)
-
-
-def serve_report() -> Dict[str, Dict[str, object]]:
-    """Snapshot of every recorded serving-plane entry (deep-copied via
-    :func:`_snapshot` — same aliasing contract as the other reports)."""
-    return _snapshot(SERVE_RECORDS)
-
-
-def reset_serve_records() -> None:
-    SERVE_RECORDS.clear()
-
-
-# ---------------------------------------------------------------------------
-# Static-analysis instrumentation (tony_tpu.analysis): the jaxpr analyzer
-# banks one record per analyzed step — finding counts by rule, waived
-# count, the step-signature digest (eqn/collective counts, live-buffer
-# high-water estimate) — keyed by analysis tag (the config name passed to
-# `tony analyze` / analyze_accum_step); last run per tag wins. This is the
-# machine-readable face of `analysis_report()` the ISSUE names alongside
-# the existing report family.
-ANALYSIS_RECORDS: Dict[str, Dict[str, object]] = {}
-
-
-def record_analysis(tag: str, /, **fields) -> None:
-    """Bank one static-analysis record (findings by rule, waived count,
-    signature digest, collective census...)."""
-    ANALYSIS_RECORDS[tag] = dict(fields)
-
-
-def analysis_report() -> Dict[str, Dict[str, object]]:
-    """Snapshot of every recorded analysis run (deep-copied via
-    :func:`_snapshot` — same aliasing contract as the other reports)."""
-    return _snapshot(ANALYSIS_RECORDS)
-
-
-def reset_analysis_records() -> None:
-    ANALYSIS_RECORDS.clear()
-
-
-# ---------------------------------------------------------------------------
-# Lock-witness instrumentation (tony_tpu.analysis.concurrency): the runtime
-# witness banks the process-global observed lock-order graph — every (held,
-# acquired) edge any thread produced through an instrumented
-# Lock/RLock/Condition, with counts, thread names, and first-observation
-# sites — under tag "witness" (re-banked whenever a NEW edge appears), and
-# the concurrency lint banks its summary next to the jaxpr analyzer's in
-# analysis_report(). Cycle detection over this graph merged with the static
-# nested-`with` graph is what turns a potential deadlock into a named
-# finding instead of a hung CI job.
-LOCK_RECORDS: Dict[str, Dict[str, object]] = {}
-
-
-def record_locks(tag: str, /, **fields) -> None:
-    """Bank one lock-witness record (instrumented lock names, observed
-    acquisition-order edges with counts/threads/sites...)."""
-    LOCK_RECORDS[tag] = dict(fields)
-
-
-def lock_report() -> Dict[str, Dict[str, object]]:
-    """Snapshot of every recorded lock-witness entry (deep-copied via
-    :func:`_snapshot` — same aliasing contract as the other reports)."""
-    return _snapshot(LOCK_RECORDS)
-
-
-def reset_lock_records() -> None:
-    LOCK_RECORDS.clear()
-
-
-# One guarded entry point for the trace-side recorders (overlap grad sync,
-# ckpt snapshot, input prefetch): bookkeeping must never sink a step or a
-# save, and a broken wiring is logged once per registry at DEBUG — not per
-# trace — so it stays diagnosable without log spam.
-_SAFE_RECORD_FAILED: set = set()
-
-
-def safe_record(kind: str, tag: str, /, **fields) -> None:
-    """Record into the ``kind`` registry (``"overlap"``/``"ckpt"``/
-    ``"input"``/``"collective"``/``"update"``/``"quant"``/
-    ``"serve"``/``"analysis"``/``"locks"``), swallowing any failure."""
+# The plan registry. What a planner decided at jit-trace time — once per
+# compile, not per step — or an engine measured per save / per delivered
+# batch, inspectable next to the xplane traces without parsing HLO. One
+# dict of fields per (kind, tag); the last record per tag wins (a
+# recompile IS a new plan). Tests read it as the window on the planners;
+# the train heartbeat reads "collective" for ``collective_bytes``.
+KINDS = (
+    # "accum_step", "gpipe", "gpipe_1f1b": bucket count and bytes, ticks,
+    # per-level ("ici"/"dcn") plans — parallel/overlap.py, pipeline.py
+    "overlap",
+    # "<step>.grad.<level>.<op>", "accum.fwd_gather", "moe.dispatch|combine",
+    # "<tag>.ppermute", "serve_decode": kind, plane, axes, nbytes (a list,
+    # per issue) — parallel/sched.py's record_collective, serve/engine.py
+    "collective",
+    # "async_save": stall vs background write seconds, bytes, chunks of
+    # the last save — ckpt/snapshot.py
+    "ckpt",
+    # the iterator's tag (default "input"): depth, steps, last/total wait,
+    # mean wait and placement ms — data/prefetch.py
+    "input",
+    # "accum_update", "fused_update": rule, impl (pallas / xla), bucket
+    # count and bytes, slot layout — ops/fused_optim.py
+    "update",
+    # "dense.<name>", "accum_gather", "attach": matmul shapes and impl,
+    # scale-window geometry, raw vs int8 wire bytes — ops/quant.py, overlap.py
+    "quant",
+    # "<engine>", "<engine>_stats", "<engine>_spec", "replica": build-time
+    # geometry, live telemetry, draft kind and depth, restore geometry —
+    # serve/engine.py, serve/spec.py, serve/replica.py
+    "serve",
+    # the config name given to `tony analyze`, "concurrency": findings by
+    # rule, waived count, signature digest — analysis/core.py, concurrency.py
+    "analysis",
+    # "witness": instrumented lock names and the observed (held, acquired)
+    # edges with counts, threads, first sites — analysis/concurrency.py
+    "locks",
+)
+_RECORDS: Dict[str, Dict[str, Dict[str, object]]] = {k: {} for k in KINDS}
+_RECORD_FAILED: set = set()
+
+
+def record(kind: str, tag: str, /, **fields) -> None:
+    """Bank ``fields`` under ``(kind, tag)``. Never raises: bookkeeping
+    must not sink a step or a save, so an unknown kind or any other
+    failure is logged once per kind at DEBUG — not per trace.
+    ``kind`` and ``tag`` are positional-only: the collective schema has a
+    field named ``kind``."""
     try:
-        {"overlap": record_overlap, "ckpt": record_ckpt,
-         "input": record_input, "collective": record_collective,
-         "update": record_update, "quant": record_quant,
-         "serve": record_serve, "analysis": record_analysis,
-         "locks": record_locks}[kind](
-             tag, **fields)
+        _RECORDS[kind][tag] = dict(fields)
     except Exception:  # noqa: BLE001
-        if kind not in _SAFE_RECORD_FAILED:
-            _SAFE_RECORD_FAILED.add(kind)
+        if kind not in _RECORD_FAILED:
+            _RECORD_FAILED.add(kind)
             logging.getLogger(__name__).debug(
                 "%s profiler record %r failed; further failures "
                 "suppressed", kind, tag, exc_info=True)
+
+
+def report(kind: str) -> Dict[str, Dict[str, object]]:
+    """Every record of ``kind`` by tag, as a deep copy — nested per-level
+    and per-bucket lists included — so a caller can serialize or mutate
+    it without poisoning the live store. An unknown kind is a KeyError."""
+    return {k: copy.deepcopy(v) for k, v in _RECORDS[kind].items()}
+
+
+def reset_records(kind: Optional[str] = None) -> None:
+    """Forget the records of ``kind``, or of every kind."""
+    for store in ([_RECORDS[kind]] if kind is not None
+                  else _RECORDS.values()):
+        store.clear()
 
 
 def _trace_fn():

@@ -3,8 +3,8 @@ handoff over the RPC wire.
 
 PR 13 made the fleet the unit of throughput, but prefill and decode
 still shared a replica: a prefill burst and the decode floor contend
-for the same chips, and chunked prefill (BENCH_r14) is a mitigation,
-not an isolation. This module splits the two phases onto separate
+for the same chips, and chunked prefill is a mitigation, not an
+isolation. This module splits the two phases onto separate
 replica ROLES — Arax's framing (PAPERS 2305.01291: workloads decoupled
 from concrete accelerator instances) taken one phase deeper than the
 router already did:

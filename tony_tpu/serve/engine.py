@@ -47,7 +47,7 @@ default so the unrouted engine is byte-for-byte the PR 10/12 one:
   analyze signature.
 
 The decode step is registered with the collective planner at build time
-(:func:`tony_tpu.profiler.record_collective`, plane ``serve_decode``)
+(``tony_tpu.profiler.record("collective", ...)``, plane ``serve_decode``)
 with an EMPTY expected set: a replica's decode touches no inter-chip
 collective — its mesh exists for memory, not for cross-replica math —
 and ``tony analyze --config serve`` audits the traced step against that
@@ -71,12 +71,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from tony_tpu import profiler
-from tony_tpu._trace import trace_record
 from tony_tpu.serve import prefix as prefix_mod
 from tony_tpu.serve.disagg import HandoffError, decode_f32, encode_f32
 from tony_tpu.serve.kvcache import AdmissionError, PagedKVCache
 
-_record = functools.partial(trace_record, "serve")
+_record = functools.partial(profiler.record, "serve")
 
 
 @dataclasses.dataclass
@@ -621,10 +620,10 @@ class ServeEngine(PagedModelRunner):
         day-one registration ROADMAP asks of every new step-path plane;
         ``tony analyze --config serve`` audits the traced decode against
         exactly this promise."""
-        trace_record("collective", "serve_decode", kind="none",
-                     plane="serve_decode", axes=[], nbytes=[],
-                     note="replica-local decode: zero inter-chip "
-                          "collectives")
+        profiler.record("collective", "serve_decode", kind="none",
+                        plane="serve_decode", axes=[], nbytes=[],
+                        note="replica-local decode: zero inter-chip "
+                             "collectives")
         _record(self.tag, ctx_pad=self.ctx_pad,
                 block_size=self.block_size, nb_max=self.nb_max,
                 n_blocks=self.cache.n_blocks, q_block=self.q_block,

@@ -75,7 +75,6 @@ class Replica:
                  demote_watermark: float = 0.0,
                  demote_batch: int = 0,
                  qos: Optional[Any] = None):
-        from tony_tpu._trace import trace_record
         from tony_tpu.models import get_model
         from tony_tpu.serve.disagg import DecodeFront, PrefillFront
 
@@ -155,14 +154,14 @@ class Replica:
         self.engine.restore_s = restore_s
         if pub is not None and pub["step"] == step:
             self.engine.weight_version = pub["version"]
-        trace_record("serve", "replica", model=model_name,
-                     ckpt_step=step, path_prefix=prefix,
-                     dtype_policy=dtype_policy, spec_k=int(spec_k),
-                     draft_model=draft_model_name or
-                     ("ngram" if spec_k else None),
-                     prefix_cache=bool(prefix_cache),
-                     prefill_chunk=prefill_chunk, role=role,
-                     mesh_axes=dict(getattr(mesh, "shape", {}) or {}))
+        profiler.record("serve", "replica", model=model_name,
+                        ckpt_step=step, path_prefix=prefix,
+                        dtype_policy=dtype_policy, spec_k=int(spec_k),
+                        draft_model=draft_model_name or
+                        ("ngram" if spec_k else None),
+                        prefix_cache=bool(prefix_cache),
+                        prefill_chunk=prefill_chunk, role=role,
+                        mesh_axes=dict(getattr(mesh, "shape", {}) or {}))
         self.role = role
         self._front = EngineFront(self.engine)
         # Disaggregated handoff halves (tony_tpu.serve.disagg). Every

@@ -50,12 +50,11 @@ import jax
 import numpy as np
 
 from tony_tpu import profiler
-from tony_tpu._trace import trace_record
 from tony_tpu.serve.engine import (PagedModelRunner, ServeEngine,
                                    _bucket_of, _Seq)
 from tony_tpu.serve.kvcache import AdmissionError
 
-_record = functools.partial(trace_record, "serve")
+_record = functools.partial(profiler.record, "serve")
 
 
 # ---------------------------------------------------------------------------

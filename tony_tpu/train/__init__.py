@@ -162,6 +162,36 @@ def create_train_state(model: nn.Module, tx: Any,
                       opt_state=tx.init_state(out, mesh))
 
 
+def fsdp_shard_state(state: TrainState, mesh: Mesh) -> TrainState:
+    """Re-create a TrainState with params (and fresh optimizer state) in
+    the ZeRO-3 layout: each param's first fsdp-divisible dim is sharded
+    over the fsdp axis, the rest stay replicated — the manual analogue of
+    what ``create_train_state`` produces for models carrying "embed"
+    logical axes."""
+    from tony_tpu.ops import fused_optim
+
+    F = mesh.shape["fsdp"]
+
+    def spec_of(p):
+        for d, n in enumerate(p.shape):
+            if n % F == 0:
+                return P(*([None] * d + ["fsdp"]
+                           + [None] * (p.ndim - d - 1)))
+        return P()
+
+    shardings = jax.tree.map(
+        lambda p: NamedSharding(mesh, spec_of(p)), state.params)
+    params = jax.device_put(state.params, shardings)
+    if isinstance(state.tx, fused_optim.FusedOptimizer):
+        # Bucket-resident state is planned off committed shardings, so it
+        # must be rebuilt AFTER the reshard, not GSPMD-propagated.
+        return TrainState(step=0, apply_fn=state.apply_fn, params=params,
+                          tx=state.tx,
+                          opt_state=state.tx.init_state(params, mesh))
+    return TrainState.create(apply_fn=state.apply_fn, params=params,
+                             tx=state.tx)
+
+
 def make_train_step(loss_of: Callable[[jax.Array, Dict[str, jax.Array]],
                                       jax.Array] = None,
                     mesh: Optional[Mesh] = None,
@@ -788,7 +818,7 @@ def train_stats_writer(path: Optional[str] = None, *,
                        ) -> Callable[[int, Dict[str, Any]], None]:
     """An ``on_step`` callback for :func:`train_loop` that publishes
     per-step cost telemetry — wall time, collective bytes (summed from
-    :func:`tony_tpu.profiler.collective_report`'s planned per-issue
+    ``tony_tpu.profiler.report("collective")``'s planned per-issue
     payloads), and an MFU estimate (``flops_per_step / (step_time *
     peak_flops)`` when both are given) — to the executor's stats file
     through the atomic stage-and-rename idiom (tmp + ``os.replace``,
@@ -814,8 +844,7 @@ def train_stats_writer(path: Optional[str] = None, *,
             return
         nbytes = 0.0
         try:
-            from tony_tpu import profiler
-            for rec in profiler.collective_report().values():
+            for rec in profiler.report("collective").values():
                 nbytes += float(sum(rec.get("nbytes") or ()))
         except Exception:
             pass                       # telemetry is advisory

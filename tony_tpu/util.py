@@ -33,7 +33,7 @@ def child_pythonpath(env: Dict[str, str]) -> str:
 
 def enable_compile_cache() -> str:
     """Turn on jax's persistent compilation cache; first statement of
-    every process that compiles (replica, training entry, bench, smoke
+    every process that compiles (replica, training entry, smoke
     children). The directory is part of the cache key, so it never moves:
     where ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it itself and
     nothing is set in code; otherwise ``<checkout>/.jax_cache``. Returns
