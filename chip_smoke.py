@@ -53,9 +53,11 @@ TAG = "SMOKE "
 # Sizes. "full": the registry's own llama2-7b widths (dim 4096, 32 heads x
 # 128, ffn 11008, vocab 32000), depth cut to 2. From memory_analysis() of
 # the real step compiled for a described v5e (16 GiB HBM, 15.75 usable):
-# train L=2 b=4 s=2048 -> 12.9 GiB (L=3 -> 16.5, does not fit); the
-# reference-attention step fits only at b=2 (13.9 GiB), hence the check's
-# two half batches. ctx_max 4096 is the model's max_seq and the largest
+# train L=2 b=4 s=2048 -> 12.9 GiB with every block recomputed (L=3 ->
+# 16.5, does not fit); the reference-attention step fits only at b=2 (13.9
+# GiB), hence the check's two half batches. Since PR 30 the step keeps what
+# the chip has room for (tony_tpu.remat): on the v5e both steps take all
+# six names, 14.77 and 15.00 of 15.75 GiB (my chip run, PR 30). ctx_max 4096 is the model's max_seq and the largest
 # cache the resident flash_decode kernel takes (1 MiB per head).
 SIZES = {
     "full": dict(model="llama2-7b", layers=2, batch=4, seq=2048, vocab=32000,

@@ -80,9 +80,22 @@ def test_two_containers_share_the_key_of_the_train_step(keys):
 
 def test_without_the_pattern_the_sandbox_path_is_in_the_key(keys):
     """The control: if jax stops embedding the path, the mechanism is dead
-    code and this says so."""
-    assert keys["a"]["without"] != keys["b"]["without"]
-    assert keys["a"]["without"] != keys["a"]["as_started"]
+    code and this says so. Read on a kernel the script calls itself: jax
+    keeps the innermost ten frames of a location, and since PR 30 the
+    frames of ``remat.ChosenStep`` lie between the step and its caller, so
+    the job's script is no longer among the ten of the library's step."""
+    assert keys["a"]["own_without"] != keys["b"]["own_without"]
+    assert keys["a"]["own_without"] != keys["a"]["own_as_started"]
+    assert keys["a"]["own_as_started"] == keys["b"]["own_as_started"]
+
+
+def test_the_librarys_step_is_keyed_by_the_librarys_own_call_site(keys):
+    """``make_train_step`` returns a step that is traced from one line of
+    ``tony_tpu/remat.py`` (tests/test_remat.py pins why): that line, not
+    the script's, is the outermost frame in its kernels' locations, so
+    its key is the same from any sandbox even without the pattern."""
+    assert keys["a"]["without"] == keys["b"]["without"] \
+        == keys["a"]["as_started"]
 
 
 def test_a_program_built_outside_a_sandbox_keeps_its_key(keys):

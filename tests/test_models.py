@@ -8,7 +8,7 @@ import optax
 import pytest
 
 from tony_tpu import parallel as par
-from tony_tpu import train
+from tony_tpu import remat, train
 from tony_tpu.models import get_model
 from tony_tpu.models.resnet import resnet50_flops
 
@@ -237,20 +237,22 @@ def test_s2d_resnet_trains():
     assert out.shape == (2, 10)
 
 
-def test_remat_policy_variants():
-    """remat_policy selects a jax.checkpoint policy (dots = save matmul
-    outputs); all variants train and an unknown name fails loudly."""
+@pytest.mark.parametrize("rung", remat.LADDER + (remat.FLOOR,),
+                         ids=lambda r: "+".join(r) or "floor")
+def test_remat_rung_trains(rung):
+    """Every set of residuals the train step may choose (tony_tpu.remat;
+    the ``remat_policy`` option it replaced is gone) trains, on the
+    unrolled stack too, and keeps the floor's loss."""
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 16), 0, 256)
-    for policy in (None, "dots", "dots_no_batch"):
-        model = get_model("llama-tiny", remat=True, remat_policy=policy,
-                          scan_layers=False)
+    model = get_model("llama-tiny", remat=True, scan_layers=False)
+    step = train.make_train_step(
+        loss_of=lambda lg, b: train.next_token_loss(lg, b["x"]))
+    losses = []
+    for names in (rung, remat.FLOOR):
         state = train.create_train_state(
             model, optax.adam(1e-3), tokens, jax.random.PRNGKey(1))
-        step = train.make_train_step(
-            loss_of=lambda lg, b: train.next_token_loss(lg, b["x"]))
-        _, m = step(state, {"x": tokens})
-        assert jnp.isfinite(m["loss"]), policy
-    import pytest as _pytest
-    bad = get_model("llama-tiny", remat=True, remat_policy="nope")
-    with _pytest.raises(ValueError, match="remat_policy"):
-        bad.init(jax.random.PRNGKey(0), tokens)
+        _, m = step.build(remat.Saved(names))(state, {"x": tokens})
+        losses.append(float(m["loss"]))
+    assert np.isfinite(losses[0]) and losses[0] == losses[1]
+    with pytest.raises(TypeError, match="remat_policy"):
+        get_model("llama-tiny", remat=True, remat_policy="dots")

@@ -1,0 +1,427 @@
+"""The train step chooses what its backward keeps from the compiled step's
+memory against the device's (``tony_tpu.remat``, ISSUE 30).
+
+The chooser is driven against a faked compiler and device: no rung is
+compiled here, every "compile" is a row of a table. The names, the policy
+and the numerics are checked on the tiny decoders, on the CPU, where the
+real step keeps nothing (the backend reports no limit).
+"""
+
+import collections
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax._src.ad_checkpoint import saved_residuals
+
+from tony_tpu import profiler, remat, train
+from tony_tpu.models import get_model
+
+GiB = 1 << 30
+FULL, NO_WO, MLP, QKV = remat.LADDER
+RUNGS = remat.LADDER + (remat.FLOOR,)
+ALL_NAMES = set(FULL) | {"flash_out", "flash_lse"}
+
+
+class FakeCompiler:
+    """``build`` for a ChosenStep: a step "traces" to its rung and
+    "compiles" into ``table[rung]`` bytes, is refused where the table says
+    None, and fails with the error the table holds. The traced model has
+    the names ``model_names``."""
+
+    def __init__(self, table, model_names=ALL_NAMES, blocks=1):
+        self.table, self.names, self.blocks = table, model_names, blocks
+        self.traced, self.lowered, self.compiled = [], [], []
+
+    def __call__(self, saved):
+        outer = self
+
+        class Traced:
+            def lower(self):
+                outer.lowered.append(saved.names)
+                return self
+
+            def compile(self):
+                outer.compiled.append(saved.names)
+                total = outer.table[saved.names]
+                if isinstance(total, Exception):
+                    raise total
+                if total is None:
+                    raise jax.errors.JaxRuntimeError(
+                        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent "
+                        "error. Ran out of memory in memory space hbm.")
+                return FakeCompiled(total)
+
+        class Step:
+            names = saved.names
+
+            def trace(self, state, batch):
+                outer.traced.append(saved.names)
+                saved.met |= set(outer.names)
+                saved.blocks += outer.blocks
+                return Traced()
+
+            def __call__(self, state, batch):
+                return ("ran", saved.names)
+
+        return Step()
+
+
+class FakeCompiled:
+    def __init__(self, total):
+        self.total = total
+
+    def memory_analysis(self):
+        class M:
+            argument_size_in_bytes = 8 * GiB
+            output_size_in_bytes = 8 * GiB
+            alias_size_in_bytes = 8 * GiB
+            temp_size_in_bytes = self.total - 8 * GiB
+        return M()
+
+
+class FakeDevice:
+    device_kind = "TPU v5 lite"
+
+    class client:
+        platform_version = "fake libtpu 0.0.34"
+
+    def __init__(self, limit):
+        self.limit = limit
+
+    def memory_stats(self):
+        return None if self.limit is None else {"bytes_limit": self.limit}
+
+
+STATE = {"w": np.zeros((4, 8), np.float32)}
+BATCH = {"x": np.zeros((2, 16), np.int32)}
+LIMIT = int(15.75 * GiB)
+# The Mistral cell's compiles for a described v5e (ISSUE 30's table).
+MISTRAL = {FULL: int(15.321 * GiB), NO_WO: int(14.695 * GiB),
+           MLP: int(14.445 * GiB), QKV: int(13.821 * GiB),
+           remat.FLOOR: int(13.570 * GiB)}
+
+
+@pytest.fixture
+def memo_dir(tmp_path):
+    """The memo under ``tmp_path``; the timeline starts and ends empty."""
+    was = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_enable_compilation_cache)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_enable_compilation_cache", True)
+    profiler.reset_timeline()
+    profiler.reset_records("remat")
+    yield tmp_path
+    jax.config.update("jax_compilation_cache_dir", was[0])
+    jax.config.update("jax_enable_compilation_cache", was[1])
+    profiler.reset_timeline()
+    profiler.reset_records("remat")
+
+
+@pytest.fixture
+def chooser(monkeypatch, memo_dir):
+    """``choose(table, ...)`` -> (the chosen rung, the fake compiler)."""
+    def choose(table, limit=LIMIT, state=STATE, batch=BATCH, **fake):
+        monkeypatch.setattr(remat, "_device_of",
+                            lambda _state: FakeDevice(limit))
+        compiler = FakeCompiler(table, **fake)
+        step = remat.ChosenStep(compiler)
+        ran, names = step(state, batch)
+        assert ran == "ran"
+        assert step(state, batch) == ("ran", names)     # settled
+        return names, compiler
+    return choose
+
+
+def test_takes_the_richest_set_that_leaves_the_margin(chooser):
+    names, compiler = chooser(MISTRAL)
+    assert names == NO_WO
+    # 15.321 GiB compiles but leaves 0.43 GiB: tried and passed over.
+    assert compiler.compiled == [FULL, NO_WO]
+    assert MISTRAL[NO_WO] + remat.MARGIN <= LIMIT < MISTRAL[FULL] \
+        + remat.MARGIN
+    c = profiler.counters()
+    assert {n for n in ALL_NAMES if f"remat:saved.{n}" in c} == set(NO_WO)
+    assert c["remat:step_bytes"] == MISTRAL[NO_WO]
+    assert c["remat:bytes_limit"] == LIMIT
+    assert c["remat:step_bytes"] <= c["remat:bytes_limit"] - remat.MARGIN
+    assert (c["remat:rungs_tried"], c["remat:rungs_refused"],
+            c["remat:from_memo"]) == (2, 0, 0)
+    plan = profiler.report("remat")["train_step"]
+    assert [r["bytes"] for r in plan["rungs"]] == [MISTRAL[FULL],
+                                                   MISTRAL[NO_WO]]
+
+
+def test_steps_down_on_a_refused_compile(chooser):
+    names, compiler = chooser({**MISTRAL, FULL: None, NO_WO: None})
+    assert names == MLP
+    assert compiler.compiled == [FULL, NO_WO, MLP]
+    c = profiler.counters()
+    assert (c["remat:rungs_tried"], c["remat:rungs_refused"]) == (3, 2)
+
+
+def test_another_compile_error_is_not_a_step_down(chooser):
+    broken = jax.errors.JaxRuntimeError("INTERNAL: Mosaic failed to compile")
+    with pytest.raises(jax.errors.JaxRuntimeError, match="Mosaic"):
+        chooser({**MISTRAL, FULL: broken})
+
+
+def test_floor_is_taken_whatever_the_margin_says(chooser):
+    tight = {r: LIMIT - 1 for r in RUNGS}
+    names, compiler = chooser(tight)
+    assert names == remat.FLOOR
+    assert compiler.compiled == list(RUNGS)
+    assert profiler.counters()["remat:step_bytes"] == LIMIT - 1
+
+
+def test_no_limit_reported_keeps_nothing_and_compiles_nothing(chooser):
+    names, compiler = chooser(MISTRAL, limit=None)
+    assert names == remat.FLOOR
+    assert compiler.traced == [] and compiler.compiled == []
+    assert not [k for k in profiler.counters() if k.startswith("remat:")]
+
+
+def test_second_start_reads_the_memo_and_compiles_once(chooser):
+    chooser({**MISTRAL, FULL: None})
+    profiler.reset_timeline()
+    names, compiler = chooser({**MISTRAL, FULL: None})
+    assert names == NO_WO
+    # Nothing probed, the refused compile not repeated: the one program a
+    # warm start builds is the chosen step's own first call.
+    assert compiler.traced == [] and compiler.compiled == []
+    c = profiler.counters()
+    assert (c["remat:from_memo"], c["remat:rungs_tried"],
+            c["remat:rungs_refused"]) == (1, 0, 0)
+    assert c["remat:step_bytes"] == MISTRAL[NO_WO]
+    assert "remat:saved.gate" in c and "remat:saved.wo" not in c
+
+
+@pytest.mark.parametrize("other", ["batch", "state", "limit"])
+def test_memo_of_another_shape_or_limit_is_ignored(chooser, other):
+    chooser(MISTRAL)
+    kwargs = {"batch": {"batch": {"x": np.zeros((4, 16), np.int32)}},
+              "state": {"state": {"w": np.zeros((4, 16), np.float32)}},
+              "limit": {"limit": LIMIT + GiB}}[other]
+    names, compiler = chooser(MISTRAL, **kwargs)
+    assert compiler.compiled[0] == FULL             # the ladder again
+    assert names == (FULL if other == "limit" else NO_WO)
+
+
+def test_names_the_model_lacks_do_not_make_rungs(chooser):
+    """A decoder without gate/up (sparse experts) has two rungs and the
+    floor."""
+    table = {("q", "k", "v", "wo"): None, QKV: 14 * GiB,
+             remat.FLOOR: 13 * GiB, FULL: 99 * GiB}
+    names, compiler = chooser(table, model_names={"q", "k", "v", "wo"})
+    assert names == QKV
+    assert compiler.compiled == [("q", "k", "v", "wo"), QKV]
+
+
+@pytest.mark.parametrize("fake", [
+    {"model_names": {"flash_out", "flash_lse"}}, {"blocks": 0}],
+    ids=["none-of-the-names", "remat-off"])
+def test_a_model_with_nothing_to_keep_gets_the_floor(chooser, fake):
+    """The layer-kind decoder names none of the ladder's values; a model
+    with ``remat=False`` wraps no block. One compile, for the bytes."""
+    names, compiler = chooser({remat.FLOOR: 13 * GiB}, **fake)
+    assert names == remat.FLOOR
+    # The first trace tells; it is never lowered.
+    assert compiler.traced == [FULL, remat.FLOOR]
+    assert compiler.lowered == compiler.compiled == [remat.FLOOR]
+    c = profiler.counters()
+    assert (c["remat:rungs_tried"], c["remat:step_bytes"]) == (1, 13 * GiB)
+
+
+# --------------------------------------------------------------------------
+# The real models: names, policy, numerics.
+
+def _loss_fn(model_name, rung, **kw):
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 16), 0, 256)
+    model = get_model(model_name, remat=True, **kw)
+    state = train.create_train_state(model, optax.adam(1e-3), tokens,
+                                     jax.random.PRNGKey(1))
+    saved = remat.Saved(rung)
+
+    def loss(params):
+        with saved:
+            out = model.apply({"params": params}, tokens, targets=tokens) \
+                if kw.get("xent_chunk") else train.next_token_loss(
+                    model.apply({"params": params}, tokens), tokens)
+        return out
+    return loss, state.params, saved
+
+
+def _kept_shapes(rung):
+    loss, params, saved = _loss_fn("llama-tiny", rung,
+                                          scan_layers=False)
+    kept = saved_residuals(loss, params)
+    assert saved.met == set(remat.LADDER[0]) and saved.blocks
+    named = sum(bool(re.search(r"remat\.py:\d+:\d+ \(name\)", why))
+                for _, why in kept)
+    return collections.Counter(aval.shape for aval, _ in kept), named
+
+
+@pytest.mark.parametrize("rung", remat.LADDER, ids="+".join)
+def test_saved_residuals_are_exactly_the_named_values(rung):
+    """What survives the forward beside what the floor keeps (each block's
+    input): one value of each named width a layer, nothing else. (jax
+    keeps ``silu(gate)`` in ``gate``'s place: the same bytes.)"""
+    widths = {"q": 64, "k": 32, "v": 32, "wo": 64, "gate": 128, "up": 128}
+    floor, floor_named = _kept_shapes(remat.FLOOR)
+    kept, named = _kept_shapes(rung)
+    assert floor_named == 0 and (2, 16, 128) not in floor
+    assert kept - floor == collections.Counter(
+        2 * [(2, 16, widths[n]) for n in rung])            # two layers
+    assert not floor - kept
+    assert named == 2 * len(set(rung) - {"gate"})
+
+
+@pytest.mark.parametrize("model_name,kw", [
+    ("llama-tiny", {}), ("llama-tiny", {"scan_layers": False}),
+    ("hybrid-tiny", {"xent_chunk": 8})],
+    ids=["llama-tiny-scan", "llama-tiny-unrolled", "hybrid-tiny"])
+def test_every_rung_gives_the_floors_loss_and_gradients(model_name, kw):
+    want = None
+    for rung in (remat.FLOOR,) + remat.LADDER:
+        loss, params, _ = _loss_fn(model_name, rung, **kw)
+        got = jax.jit(jax.value_and_grad(loss))(params)
+        if want is None:
+            want = got
+            continue
+        assert float(got[0]) == float(want[0]), rung
+        for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-7, err_msg=rung)
+
+
+def test_names_are_inert_outside_a_step():
+    """``model.init``, the serve forward and a step on the CPU trace the
+    same modules with no set active: nothing is met, nothing is kept."""
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    model = get_model("llama-tiny", remat=True)
+    state = train.create_train_state(model, optax.adam(1e-3), tokens,
+                                     jax.random.PRNGKey(1))
+    step = train.make_train_step(
+        loss_of=lambda lg, b: train.next_token_loss(lg, b["x"]))
+    profiler.reset_timeline()
+    lowered = step.lower(state, {"x": tokens})            # as chip_smoke does
+    assert "remat" not in " ".join(profiler.counters())
+    assert lowered.compile() is not None
+    _, metrics = step(state, {"x": tokens})
+    assert np.isfinite(float(metrics["loss"]))
+    assert remat._ACTIVE.get() is None
+
+
+def test_real_step_on_a_device_with_room_builds_one_program_a_start(
+        memo_dir, monkeypatch):
+    """The whole path over the real compiler (the CPU's), the device's
+    answer faked: cold, the richest rung is compiled by the ladder and
+    the call that follows builds nothing more; warm, the memo names the
+    rung and its first call is the one build. The loss is the floor's."""
+    monkeypatch.setattr(remat, "_device_of", lambda _s: FakeDevice(1 << 40))
+    profiler.watch_builds()
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 16), 0, 256)
+    model = get_model("llama-tiny", remat=True)
+    losses = {}
+    for start in ("cold", "warm", "floor"):
+        state = train.create_train_state(model, optax.adam(1e-3), tokens,
+                                         jax.random.PRNGKey(1))
+        step = train.make_train_step(
+            loss_of=lambda lg, b: train.next_token_loss(lg, b["x"]))
+        if start == "floor":
+            step = step.build(remat.Saved())
+        profiler.reset_timeline()
+        state, metrics = step(state, {"x": tokens})
+        state, metrics = step(state, {"x": tokens})
+        losses[start] = float(metrics["loss"])
+        c = profiler.counters()
+        assert c.get("programs_compiled", 0) + c.get("programs_loaded", 0) \
+            == 1, (start, c)
+        if start != "floor":
+            assert {n for n in FULL if f"remat:saved.{n}" in c} == set(FULL)
+            assert c["remat:from_memo"] == (start == "warm")
+            assert c["remat:rungs_tried"] == (start == "cold")
+            assert 0 < c["remat:step_bytes"] < c["remat:bytes_limit"]
+    assert losses["cold"] == losses["warm"] == losses["floor"]
+    assert len(list((memo_dir / "tony_remat").glob("*.json"))) == 1
+
+
+def test_both_decoders_wrap_their_layers_in_the_one_helper():
+    import inspect
+
+    from tony_tpu.models import hybrid, transformer
+
+    for mod in (hybrid, transformer):
+        src = inspect.getsource(mod)
+        assert "remat.block(" in src and "nn.remat(" not in src, mod
+    assert not hasattr(transformer.TransformerConfig(), "remat_policy")
+
+
+def test_the_step_a_cold_start_compiled_is_the_step_a_warm_start_loads(
+        chooser, monkeypatch):
+    """A Pallas kernel's compile-cache key holds the call stack above it
+    (tests/test_compile_key.py). The ladder lowers its candidates and the
+    chosen step is run from one call site, so the program the cold start
+    compiled has the key the warm start asks the cache for — and a rung
+    is another program than the floor."""
+    import hashlib
+
+    from jax._src import cache_key
+
+    tokens = jnp.zeros((2, 256), jnp.int32)
+    model = get_model("llama-tiny", dim=256, n_heads=2, n_kv_heads=1,
+                      ffn_hidden=256, max_seq=256, attention="flash",
+                      remat=True)
+    state = train.create_train_state(model, optax.adamw(1e-3), tokens,
+                                     jax.random.PRNGKey(0))
+    real = train.make_train_step(
+        loss_of=lambda lg, b: train.next_token_loss(lg, b["x"]))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(remat, "_device_of", lambda _s: FakeDevice(LIMIT))
+    # jax keeps the innermost ten frames of a location; the stand-in
+    # below adds one, and the site has to be among them to be seen here.
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 16)
+    keys = []
+
+    def build(saved):
+        jitted = real.build(saved)
+
+        class Keyed:
+            """Lowered for the TPU (never compiled: no chip here), hashed
+            as jax hashes a program for its cache."""
+
+            def lower(self):
+                return self
+
+            def key(self, state, batch):
+                module = jitted.trace(state, batch).lower(
+                    lowering_platforms=("tpu",)).compiler_ir("stablehlo")
+                assert str(module).count("@tpu_custom_call") == 4
+                digest = hashlib.sha256(cache_key._canonicalize_ir(
+                    module, cache_key.IgnoreCallbacks.NO)).hexdigest()
+                keys.append((saved.names, digest))
+                return self
+
+            trace = __call__ = key
+
+            def compile(self):
+                return FakeCompiled(MISTRAL[saved.names])
+
+        return Keyed()
+
+    batch, starts = {"x": tokens}, []
+    try:
+        for _ in ("cold: the ladder", "warm: the memo"):
+            remat.ChosenStep(build)(state, batch)
+            starts.append(keys[:])
+            del keys[:]
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+    cold, warm = starts
+    assert [names for names, _ in cold] == [FULL, NO_WO, NO_WO]
+    assert cold[1] == cold[2] == warm[0] and len(warm) == 1
+    assert cold[0][1] != cold[1][1]

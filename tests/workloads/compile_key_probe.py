@@ -1,11 +1,12 @@
-"""What the compile cache's key is made of, for a job's train step: builds
-the small train step with the flash kernels forced on, calls it from THIS
-file, lowers it for the TPU (a lowering, never a compile: no chip and no
-libtpu needed) and prints the hash of the bytes
-``jax._src.cache_key._canonicalize_ir`` gives the key, first under the
-environment the process was started with and then with jax's source-file
-canonicalisation switched off. ``tests/test_compile_key.py`` copies it
-into sandboxes as the executor lays them out.
+"""What the compile cache's key is made of, for a job's train step and for
+a kernel the job's script calls itself: builds the small train step with
+the flash kernels forced on, calls it from THIS file, lowers it for the
+TPU (a lowering, never a compile: no chip and no libtpu needed) and prints
+the hash of the bytes ``jax._src.cache_key._canonicalize_ir`` gives the
+key, first under the environment the process was started with and then
+with jax's source-file canonicalisation switched off; then the same for
+``own_kernel`` below. ``tests/test_compile_key.py`` copies it into
+sandboxes as the executor lays them out.
 
 Prints one ``KEY {json}`` line.
 """
@@ -29,8 +30,16 @@ REGEX = "jax_hlo_source_file_canonicalization_regex"
 B, S = 2, 256
 
 
-def key_bytes_hash(step, state, batch) -> tuple:
-    lowered = step.trace(state, batch).lower(lowering_platforms=("tpu",))
+def own_kernel(q, k, v):
+    """A Pallas call two frames from the script: a job that brings its own
+    model calls the kernels from its own files."""
+    from tony_tpu.ops import flash_attention_packed
+
+    return flash_attention_packed(q, k, v, 2, causal=True, interpret=False)
+
+
+def key_bytes_hash(step, *args) -> tuple:
+    lowered = step.trace(*args).lower(lowering_platforms=("tpu",))
     module = lowered.compiler_ir("stablehlo")
     data = cache_key._canonicalize_ir(module, cache_key.IgnoreCallbacks.NO)
     return (hashlib.sha256(data).hexdigest(),
@@ -53,9 +62,15 @@ def main() -> None:
     as_started, calls = key_bytes_hash(step, state, batch)
     jax.config.update(REGEX, None)
     without, _ = key_bytes_hash(step, state, batch)
+    q = jnp.zeros((B, S, 256), jnp.bfloat16)
+    kv = jnp.zeros((B, S, 128), jnp.bfloat16)
+    own_without, _ = key_bytes_hash(jax.jit(own_kernel), q, kv, kv)
+    jax.config.update(REGEX, os.environ.get(REGEX.upper()))
+    own_as_started, _ = key_bytes_hash(jax.jit(own_kernel), q, kv, kv)
     print("KEY " + json.dumps({
         "file": __file__, "regex": os.environ.get(REGEX.upper()),
         "as_started": as_started, "without": without,
+        "own_as_started": own_as_started, "own_without": own_without,
         "tpu_custom_calls": calls}))
 
 

@@ -5,8 +5,9 @@ Phi-4-mini-flash-reasoning runs it).
 
 :class:`~tony_tpu.models.transformer.Transformer` folds ONE block kind with
 ``nn.scan``; here the kinds differ and two streams cross layers, so each
-layer is its own module (its own ``nn.remat``), and every mixer declares
-what it ``emits`` for later layers and what it ``consumes``:
+layer is its own module (its own ``nn.remat``, through ``remat.block``),
+and every mixer declares what it ``emits`` for later layers and what it
+``consumes``:
 
 ================  =========================  ===================  =========
 kind (scope)      mixer                      consumes             emits
@@ -60,7 +61,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tony_tpu import profiler
+from tony_tpu import profiler, remat
 from tony_tpu.models import register
 from tony_tpu.models.transformer import RMSNorm
 from tony_tpu.ops import attention as attn_ops
@@ -351,8 +352,7 @@ class HybridDecoder(nn.Module):
                            (cfg.vocab, cfg.dim), jnp.float32)
         with jax.named_scope("embed"):
             x = jnp.take(embed, tokens, axis=0).astype(cfg.dtype)
-        layer_cls = nn.remat(HybridLayer, prevent_cse=False) \
-            if cfg.remat else HybridLayer
+        layer_cls = remat.block(HybridLayer) if cfg.remat else HybridLayer
         streams: dict = {}
         for i, kind in enumerate(cfg.layers):
             mixer = MIXERS[kind]

@@ -15,7 +15,8 @@ TPU-first design:
   when the sequence axis is sharded (long context, SURVEY.md §5.7);
 * ``scan_layers`` folds the layer stack into one ``nn.scan`` (one trace +
   one compile of a single block) and ``remat`` wraps blocks in
-  ``jax.checkpoint`` to trade FLOPs for HBM.
+  ``jax.checkpoint`` to trade FLOPs for HBM (:func:`tony_tpu.remat.block`:
+  the train step says which named residuals the chip has room to keep).
 """
 
 from __future__ import annotations
@@ -27,6 +28,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tony_tpu import remat
 from tony_tpu.models import register
 from tony_tpu.ops import flash_attention, reference_attention
 
@@ -45,13 +47,10 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16
     attention: str = "flash"        # flash | ring | reference
     scan_layers: bool = True
+    # True: every block's backward recomputes it from its input, but for
+    # the named residuals the train step found room for (tony_tpu.remat).
+    # False keeps everything (the tests' and the rehearsal's setting).
     remat: bool = True
-    # Rematerialization policy: None = full recompute (max memory saving,
-    # ~4/3 extra executed FLOPs the matmul-only MFU accounting does not
-    # credit); "dots" = jax.checkpoint_policies.checkpoint_dots (save all
-    # matmul outputs, recompute only elementwise/norm/softmax — the
-    # standard transformer trade).
-    remat_policy: Optional[str] = None
     mesh: Optional[Any] = None      # required for attention="ring"
     # MoE (SURVEY.md §2.3 expert parallelism): >0 swaps the dense MLP for
     # an expert-parallel MoEMLP in every block.
@@ -202,9 +201,16 @@ class Attention(nn.Module):
         # logical-metadata unboxing under an active mesh.)
         dense = lambda feats, logical, name, lane: _proj_dense(
             cfg, lane, feats, logical, name)
-        q = dense(nh * hd, ("embed", "heads"), "wq", "qkv")(x)
-        k = dense(nkv * hd, ("embed", "kv_heads"), "wk", "qkv")(x)
-        v = dense(nkv * hd, ("embed", "kv_heads"), "wv", "qkv")(x)
+        # The residuals a train step may keep (tony_tpu.remat) are named
+        # where they are made; to any other trace a name is nothing.
+        q = remat.name(dense(nh * hd, ("embed", "heads"), "wq", "qkv")(x),
+                       "q")
+        k = remat.name(
+            dense(nkv * hd, ("embed", "kv_heads"), "wk", "qkv")(x), "k")
+        v = remat.name(
+            dense(nkv * hd, ("embed", "kv_heads"), "wv", "qkv")(x), "v")
+        wo = lambda out: remat.name(
+            dense(cfg.dim, ("heads", "embed"), "wo", "o")(out), "wo")
         if kv is not None:
             # Serve-mode forward (tony_tpu.serve): the t rows are NEW
             # tokens at per-sequence absolute ``positions`` [b, t]; the
@@ -247,8 +253,7 @@ class Attention(nn.Module):
                     v_buf.reshape(b, ctx, nkv, hd).transpose(0, 2, 1, 3),
                     pos)
             out = out.transpose(0, 2, 1, 3).reshape(b, t, nh * hd)
-            return (dense(cfg.dim, ("heads", "embed"), "wo", "o")(out),
-                    (k_rows, v_rows))
+            return wo(out), (k_rows, v_rows)
         if (cfg.attention == "flash" and cfg.mesh is None
                 and hd % 128 == 0):
             # Packed layout: the kernel reads heads as lane offsets from
@@ -268,7 +273,7 @@ class Attention(nn.Module):
             out = flash_attention_packed(
                 q4.reshape(b, t, nh * hd), k4.reshape(b, t, nkv * hd), v,
                 nh, causal=True)
-            return dense(cfg.dim, ("heads", "embed"), "wo", "o")(out)
+            return wo(out)
         # [B, T, H·D] → [B, H, T, D]
         q = q.reshape(b, t, nh, hd).transpose(0, 2, 1, 3)
         k = k.reshape(b, t, nkv, hd).transpose(0, 2, 1, 3)
@@ -302,7 +307,7 @@ class Attention(nn.Module):
         else:
             out = reference_attention(q, k, v, causal=True)
         out = out.transpose(0, 2, 1, 3).reshape(b, t, nh * hd)
-        return dense(cfg.dim, ("heads", "embed"), "wo", "o")(out)
+        return wo(out)
 
 
 class MLP(nn.Module):
@@ -313,8 +318,10 @@ class MLP(nn.Module):
         cfg = self.cfg
         dense = lambda feats, logical, name: _proj_dense(
             cfg, "mlp", feats, logical, name)
-        gate = dense(cfg.ffn_hidden, ("embed", "ffn"), "w_gate")(x)
-        up = dense(cfg.ffn_hidden, ("embed", "ffn"), "w_up")(x)
+        gate = remat.name(
+            dense(cfg.ffn_hidden, ("embed", "ffn"), "w_gate")(x), "gate")
+        up = remat.name(
+            dense(cfg.ffn_hidden, ("embed", "ffn"), "w_up")(x), "up")
         y = nn.silu(gate) * up
         return dense(cfg.dim, ("ffn", "embed"), "w_down")(y)
 
@@ -424,22 +431,7 @@ class Transformer(nn.Module):
         if positions is None:
             positions = jnp.arange(t)
 
-        block_cls = ScannedBlock
-        # Validated OUTSIDE the remat gate: a typo'd (or remat=False-
-        # orphaned) policy must fail loudly, not silently not-apply.
-        policy = None
-        if cfg.remat_policy == "dots":
-            policy = jax.checkpoint_policies.checkpoint_dots
-        elif cfg.remat_policy == "dots_no_batch":
-            policy = (jax.checkpoint_policies
-                      .dots_with_no_batch_dims_saveable)
-        elif cfg.remat_policy is not None:
-            raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}")
-        if cfg.remat_policy is not None and not cfg.remat:
-            raise ValueError("remat_policy set but remat=False")
-        if cfg.remat:
-            block_cls = nn.remat(block_cls, prevent_cse=False,
-                                 policy=policy)
+        block_cls = remat.block(ScannedBlock) if cfg.remat else ScannedBlock
         new_kv = None
         if cfg.scan_layers:
             if kv is not None:

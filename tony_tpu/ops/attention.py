@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tony_tpu import remat
+
 _NEG_INF = -1e30
 
 # The per-row log-sum-exp is carried as [rows, _LSE_LANES] with the value
@@ -957,6 +959,7 @@ def _flash_fwd(q, k, v, causal, scale, blocks, interpret, kv_len=None,
     with jax.named_scope(_scope("attn_fwd", window)):
         out, lse = _flash_forward(q, k, v, causal, scale, *blocks.fwd,
                                   interpret, kv_len, window)
+    out, lse = remat.name(out, "flash_out"), remat.name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 
@@ -1108,6 +1111,7 @@ def _flash_packed_fwd(q, k, v, heads, causal, scale, blocks, interpret,
     with jax.named_scope(_scope("attn_fwd", window)):
         out, lse = _flash_forward_packed(q, k, v, heads, causal, scale,
                                          *blocks.fwd, interpret, window)
+    out, lse = remat.name(out, "flash_out"), remat.name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 

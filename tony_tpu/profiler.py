@@ -354,6 +354,9 @@ KINDS = (
     # "witness": instrumented lock names and the observed (held, acquired)
     # edges with counts, threads, first sites — analysis/concurrency.py
     "locks",
+    # "train_step": the residuals the backward keeps, the compiled step's
+    # bytes against the device's limit, each rung's reading — remat.py
+    "remat",
 )
 _RECORDS: Dict[str, Dict[str, Dict[str, object]]] = {k: {} for k in KINDS}
 _RECORD_FAILED: set = set()

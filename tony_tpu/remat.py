@@ -1,0 +1,316 @@
+"""What the backward keeps instead of recomputing, chosen from the compiled
+step's own memory account (ISSUE 30).
+
+Every block of both decoders sits in ``nn.remat``: only its input survives
+the forward and the backward runs the block again. That was sized for a
+chip with no room; where there is room, keeping a matmul's output is worth
+the matmul. Which outputs fit cannot be reckoned from shapes (the costs do
+not add up: PERF.md §7), so the program asks the compiler:
+
+* the models **name** the candidates where they are made (:func:`name`, a
+  ``checkpoint_name``): ``q``, ``k``, ``v``, ``wo``, ``gate``, ``up``,
+  ``flash_out``, ``flash_lse``. A name costs nothing until a policy asks
+  for it;
+* :func:`block` is the one ``nn.remat`` both decoders wrap their layer in;
+  it keeps the names of the :class:`Saved` the step entered around the
+  model's trace, and nothing outside one (``model.init``, serving);
+* :class:`ChosenStep` is what ``make_train_step`` returns: at its first
+  call it walks :data:`LADDER`, richest set first, compiles each candidate
+  step and takes the first whose ``memory_analysis()`` total leaves
+  :data:`MARGIN` under the device's ``bytes_limit``. A refused compile
+  (``RESOURCE_EXHAUSTED``) is a step down; the empty set is the floor and
+  is today's program. The answer is remembered beside the persistent
+  compile cache, so a warm start builds one program and no refused compile
+  is repeated. A backend that reports no limit (the CPU) gets the floor.
+
+Processes of one job must agree on the set (they run one SPMD program):
+they do, because each reads the same compiler and the same kind of device.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import contextvars
+import functools
+import hashlib
+import json
+import logging
+import os
+import re
+from pathlib import Path
+from typing import Any, Callable, Iterable, Optional, Tuple
+
+import jax
+from jax.ad_checkpoint import checkpoint_name
+
+from tony_tpu import profiler
+
+_log = logging.getLogger(__name__)
+
+# Richest first, by the recomputation each removes in the dense block
+# (PERF.md §6 PR 30): gate/up are the MLP's two recomputed matmuls, q/k/v
+# the three projections, wo the fourth. The flash residuals are named but
+# on no rung: they cost 1.25 GiB for two Mistral layers and buy 3.4 ms.
+LADDER: Tuple[Tuple[str, ...], ...] = (
+    ("q", "k", "v", "wo", "gate", "up"),
+    ("q", "k", "v", "gate", "up"),
+    ("gate", "up"),
+    ("q", "k", "v"),
+)
+FLOOR: Tuple[str, ...] = ()
+
+# Bytes that must stay free between the compiled step's total (arguments +
+# temporaries + outputs - aliased: everything the step holds at its
+# fullest, the state included) and the device's ``bytes_limit``: room for
+# what the process holds beside the step while the runtime keeps the
+# step's scratch reserved. Read on the v5e (PERF.md §6 PR 30, limit 15.748
+# GiB): with 0.43 GiB left (Mistral cell, 15.321) a second copy of the
+# weights beside the state no longer fits and the runtime has to give the
+# reservation up and take it again; with 0.75 (chip_smoke's reference
+# check), 0.90 (the phi cell), 0.98 (chip_smoke's job, saving while it
+# trains) and 1.05 (the Mistral cell, 14.695: 0.24 GiB still free at that
+# point) everything fits. The constant is the smallest headroom that held.
+MARGIN = 768 << 20
+
+_ACTIVE: contextvars.ContextVar = contextvars.ContextVar(
+    "tony_remat_saved", default=None)
+
+
+class Saved:
+    """The names one step's backward keeps; entered around the model's
+    trace. Also the trace's witness: which names the model met under it
+    and how many layers :func:`block` wrapped."""
+
+    def __init__(self, names: Iterable[str] = FLOOR):
+        self.names = tuple(names)
+        self.met: set = set()
+        self.blocks = 0
+
+    def __enter__(self) -> "Saved":
+        self._token = _ACTIVE.set(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _ACTIVE.reset(self._token)
+
+    def effective(self, rung: Iterable[str]) -> Tuple[str, ...]:
+        """``rung`` without the names this trace never met (another
+        decoder's, or a model with ``remat=False``): keeping them is the
+        program without them."""
+        return tuple(n for n in rung if self.blocks and n in self.met)
+
+
+def name(x: jax.Array, tag: str) -> jax.Array:
+    """``x`` as the residual ``tag``: kept by a step whose set names it."""
+    active = _ACTIVE.get()
+    if active is not None:
+        active.met.add(tag)
+    return checkpoint_name(x, tag)
+
+
+def block(layer_cls):
+    """``layer_cls`` under ``nn.remat``: its backward recomputes the layer
+    from its input, but for the names the step's :class:`Saved` keeps."""
+    import flax.linen as nn
+
+    active = _ACTIVE.get()
+    names = active.names if active is not None else FLOOR
+    if active is not None:
+        active.blocks += 1
+    policy = jax.checkpoint_policies.save_only_these_names(*names) \
+        if names else None
+    return nn.remat(layer_cls, prevent_cse=False, policy=policy)
+
+
+def step_bytes(compiled) -> int:
+    """What a compiled step holds at its fullest, by the compiler."""
+    m = compiled.memory_analysis()
+    return int(m.argument_size_in_bytes + m.temp_size_in_bytes
+               + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _device_of(state) -> Any:
+    """The (first local) device the state lives on."""
+    for leaf in jax.tree.leaves(state):
+        if isinstance(leaf, jax.Array):
+            return min(leaf.sharding.addressable_devices, key=lambda d: d.id)
+    return jax.local_devices()[0]
+
+
+def _bytes_limit(device) -> Optional[int]:
+    return (device.memory_stats() or {}).get("bytes_limit")
+
+
+@functools.lru_cache(maxsize=1)
+def _package_digest() -> str:
+    """The package's source: a changed model is another step."""
+    h = hashlib.sha256()
+    root = Path(__file__).resolve().parent
+    for path in sorted(root.rglob("*.py")):
+        h.update(path.relative_to(root).as_posix().encode())
+        h.update(path.read_bytes())
+    return h.hexdigest()
+
+
+def memo_path(state, batch, mesh, device, limit: int,
+              extra: Any = None) -> Optional[Path]:
+    """Where the choice for this step is remembered: beside the persistent
+    compile cache, under a key of everything the choice depends on. None
+    where no cache directory is configured."""
+    cache_dir = jax.config.jax_compilation_cache_dir
+    if not (cache_dir and jax.config.jax_enable_compilation_cache):
+        return None
+    import jaxlib
+
+    leaves = jax.tree.leaves(state)
+    aval = lambda x: (tuple(jax.numpy.shape(x)),
+                      str(jax.numpy.result_type(x)))
+    apply_fn = getattr(state, "apply_fn", None)
+    model = getattr(apply_fn, "__self__", apply_fn)
+    key = json.dumps({
+        "model": re.sub(r" at 0x[0-9a-f]+", "", repr(model)),
+        "state": [aval(x) for x in leaves],
+        "state_bytes": sum(getattr(x, "nbytes", 0) for x in leaves),
+        "batch": [(jax.tree_util.keystr(p), aval(x)) for p, x in
+                  jax.tree_util.tree_leaves_with_path(batch)],
+        "mesh": None if mesh is None else sorted(mesh.shape.items()),
+        "device": device.device_kind, "bytes_limit": limit,
+        "versions": [jax.__version__, jaxlib.__version__,
+                     device.client.platform_version],
+        "ladder": LADDER, "margin": MARGIN, "extra": extra,
+        "source": _package_digest(),
+    }, sort_keys=True, default=str)
+    return Path(cache_dir) / "tony_remat" / (
+        hashlib.sha256(key.encode()).hexdigest() + ".json")
+
+
+def _read_memo(path: Optional[Path]) -> Optional[dict]:
+    if path is None:
+        return None
+    try:
+        found = json.loads(path.read_text())
+    except (OSError, ValueError):
+        return None
+    ok = isinstance(found, dict) and isinstance(found.get("saved"), list)
+    return found if ok else None
+
+
+def _write_memo(path: Optional[Path], choice: dict) -> None:
+    if path is None:
+        return
+    try:
+        path.parent.mkdir(parents=True, exist_ok=True)
+        tmp = path.with_suffix(f".{os.getpid()}.tmp")
+        tmp.write_text(json.dumps(choice))
+        os.replace(tmp, path)
+    except OSError as e:        # a read-only cache: choose again next time
+        _log.debug("remat choice not remembered: %s", e)
+
+
+def _publish(choice: dict, limit: int, from_memo: bool) -> None:
+    """The choice on the task's timeline (first step of the process wins)
+    and in the plan registry."""
+    for n in choice["saved"]:
+        profiler.count_once(f"remat:saved.{n}", 1)
+    profiler.count_once("remat:step_bytes", choice["step_bytes"])
+    profiler.count_once("remat:bytes_limit", limit)
+    profiler.count_once("remat:rungs_tried",
+                        0 if from_memo else len(choice["rungs"]))
+    profiler.count_once("remat:rungs_refused", 0 if from_memo else sum(
+        r["bytes"] is None for r in choice["rungs"]))
+    profiler.count_once("remat:from_memo", int(from_memo))
+    profiler.record("remat", "train_step", bytes_limit=limit, margin=MARGIN,
+                    from_memo=from_memo, **choice)
+    _log.info("remat: backward keeps %s; step %.3f of %.3f GiB%s",
+              list(choice["saved"]) or "nothing",
+              choice["step_bytes"] / 2**30, limit / 2**30,
+              " (remembered)" if from_memo else "")
+
+
+class ChosenStep:
+    """A train step ``(state, batch) -> (state, metrics)`` that settles,
+    at its first call, which residuals its backward keeps.
+
+    ``build(saved)`` gives the jitted step whose model traces under
+    ``saved`` (public: a test or a probe builds one rung with it). Calls,
+    ``lower`` and ``trace`` go to the chosen step; the choice is made
+    once, for the first call's shapes."""
+
+    def __init__(self, build: Callable[[Saved], Any], mesh=None,
+                 memo_extra: Any = None):
+        self.build = build
+        self._mesh = mesh
+        self._memo_extra = memo_extra
+        self._fn = None
+
+    def __call__(self, state, batch):
+        return self._enter("__call__", state, batch)
+
+    def lower(self, state, batch):
+        return self._enter("lower", state, batch)
+
+    def trace(self, state, batch):
+        return self._enter("trace", state, batch)
+
+    def _enter(self, how: str, state, batch):
+        """The one call site of the jitted steps. A candidate is traced
+        and the chosen step is run from the SAME line of the same frame:
+        the call stack above a Pallas kernel is part of its compile-cache
+        key (``util.enable_compile_cache``), so the program the ladder
+        compiled cold is the program a warm start loads."""
+        fn, picking = (self._fn, None) if self._fn is not None \
+            else self._advance(self._pick(state, batch), None)
+        with (jax.set_mesh(self._mesh) if self._mesh is not None
+              else contextlib.nullcontext()):
+            while True:
+                out = getattr(fn, "trace" if picking else how)(state, batch)
+                if picking is None:
+                    return out
+                fn, picking = self._advance(picking, out)
+
+    def _advance(self, picking, traced):
+        try:
+            return picking.send(traced), picking
+        except StopIteration as picked:
+            self._fn = picked.value
+            return self._fn, None
+
+    def _pick(self, state, batch):
+        """Generator: yields candidate steps, is sent each one's trace,
+        returns the chosen step."""
+        device = _device_of(state)
+        limit = _bytes_limit(device)
+        if not limit:
+            return self.build(Saved())
+        path = memo_path(state, batch, self._mesh, device, limit,
+                         self._memo_extra)
+        found = _read_memo(path)
+        if found is not None:
+            _publish(found, limit, from_memo=True)
+            return self.build(Saved(found["saved"]))
+        # The first candidate's trace also tells which names this model
+        # has; rungs that differ only in names it lacks are one rung.
+        first = Saved(LADDER[0])
+        fn = self.build(first)
+        trace = yield fn
+        readings = []
+        for rung in dict.fromkeys(map(first.effective, LADDER + (FLOOR,))):
+            if rung != first.names:
+                fn = self.build(Saved(rung))
+                trace = yield fn
+            try:
+                total = step_bytes(trace.lower().compile())
+            except jax.errors.JaxRuntimeError as e:
+                if "RESOURCE_EXHAUSTED" not in str(e) or rung == FLOOR:
+                    raise
+                total = None
+            readings.append({"saved": list(rung), "bytes": total})
+            if rung == FLOOR or (total is not None
+                                 and total + MARGIN <= limit):
+                break
+        choice = {"saved": list(rung), "step_bytes": total,
+                  "rungs": readings}
+        _write_memo(path, choice)
+        _publish(choice, limit, from_memo=False)
+        return fn
+

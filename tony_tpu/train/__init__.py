@@ -31,7 +31,7 @@ import optax
 from flax.training.train_state import TrainState
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tony_tpu import chaos, constants, profiler
+from tony_tpu import chaos, constants, profiler, remat
 from tony_tpu import parallel as par
 from tony_tpu.parallel import overlap
 
@@ -212,49 +212,52 @@ def make_train_step(loss_of: Callable[[jax.Array, Dict[str, jax.Array]],
     the model (e.g. ``targets`` for a model with a fused head+loss —
     ``loss_of`` then receives the model's scalar loss as its first
     argument).
+
+    What the backward keeps of a remat'd block instead of recomputing it
+    is settled at the first call, from the compiled step's memory against
+    the device's (:class:`tony_tpu.remat.ChosenStep`, which is what comes
+    back: call it, ``lower`` it or ``trace`` it like the jitted step). On
+    a backend that reports no memory limit (the CPU) nothing is kept.
     """
     if loss_of is None:
         loss_of = lambda logits, batch: cross_entropy_loss(logits, batch["y"])
 
-    def step(state: TrainState, batch: Dict[str, jax.Array]):
-        if mesh is not None:
-            batch = jax.tree.map(
-                lambda x: jax.lax.with_sharding_constraint(
-                    # The (batch, seq) spec is rank-2: rank-1 leaves
-                    # (labels, weights) take the plain batch sharding.
-                    x, par.batch_sharding(
-                        mesh, seq_axis=seq_axis and x.ndim >= 2)), batch)
+    def build(saved: remat.Saved):
+        def step(state: TrainState, batch: Dict[str, jax.Array]):
+            if mesh is not None:
+                batch = jax.tree.map(
+                    lambda x: jax.lax.with_sharding_constraint(
+                        # The (batch, seq) spec is rank-2: rank-1 leaves
+                        # (labels, weights) take the plain batch sharding.
+                        x, par.batch_sharding(
+                            mesh, seq_axis=seq_axis and x.ndim >= 2)), batch)
 
-        def loss_fn(params):
-            extra = apply_kwargs_of(batch) if apply_kwargs_of else {}
-            with nn.logical_axis_rules(rules):
-                # mutable="losses": models that sow auxiliary objectives
-                # (e.g. the MoE load-balancing loss) contribute them here;
-                # dense models return an empty collection.
-                logits, sown = state.apply_fn(
-                    {"params": params}, batch["x"], mutable="losses",
-                    **extra)
-            aux = sum((leaf.sum() for leaf in
-                       jax.tree.leaves(sown.get("losses", {}))),
-                      start=jnp.float32(0.0))
-            return loss_of(logits, batch) + aux, aux
+            def loss_fn(params):
+                extra = apply_kwargs_of(batch) if apply_kwargs_of else {}
+                with nn.logical_axis_rules(rules), saved:
+                    # mutable="losses": models that sow auxiliary objectives
+                    # (e.g. the MoE load-balancing loss) contribute them here;
+                    # dense models return an empty collection.
+                    logits, sown = state.apply_fn(
+                        {"params": params}, batch["x"], mutable="losses",
+                        **extra)
+                aux = sum((leaf.sum() for leaf in
+                           jax.tree.leaves(sown.get("losses", {}))),
+                          start=jnp.float32(0.0))
+                return loss_of(logits, batch) + aux, aux
 
-        (loss, aux), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(state.params)
-        with jax.named_scope("optimizer"):
-            new_state = state.apply_gradients(grads=grads)
-            gnorm = optax.global_norm(grads)
-        return new_state, {"loss": loss, "grad_norm": gnorm,
-                           "aux_loss": aux}
+            (loss, aux), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(state.params)
+            with jax.named_scope("optimizer"):
+                new_state = state.apply_gradients(grads=grads)
+                gnorm = optax.global_norm(grads)
+            return new_state, {"loss": loss, "grad_norm": gnorm,
+                               "aux_loss": aux}
 
-    jitted = jax.jit(step, donate_argnums=(0,) if donate else ())
-    if mesh is None:
-        return jitted
+        return jax.jit(step, donate_argnums=(0,) if donate else ())
 
-    def stepper(state, batch):
-        with jax.set_mesh(mesh):
-            return jitted(state, batch)
-    return stepper
+    return remat.ChosenStep(build, mesh, memo_extra={
+        "donate": donate, "seq_axis": seq_axis})
 
 
 def make_accum_train_step(loss_of: Callable[[jax.Array,
