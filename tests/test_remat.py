@@ -21,8 +21,11 @@ from tony_tpu import profiler, remat, train
 from tony_tpu.models import get_model
 
 GiB = 1 << 30
-FULL, NO_WO, MLP, QKV = remat.LADDER
-RUNGS = remat.LADDER + (remat.FLOOR,)
+# The dense decoder's rungs: the ladder's without the name it never meets
+# (``sel``, a learned selection's), which leaves its last rung the floor.
+FULL, NO_WO, MLP, QKV, _ = (tuple(n for n in rung if n != "sel")
+                            for rung in remat.LADDER)
+RUNGS = (FULL, NO_WO, MLP, QKV, remat.FLOOR)
 ALL_NAMES = set(FULL) | {"flash_out", "flash_lse"}
 
 
@@ -30,7 +33,9 @@ class FakeCompiler:
     """``build`` for a ChosenStep: a step "traces" to its rung and
     "compiles" into ``table[rung]`` bytes, is refused where the table says
     None, and fails with the error the table holds. The traced model has
-    the names ``model_names``."""
+    the names ``model_names``; a rung is known here, in the table and in
+    the three logs, by the names of it that the model has (the program it
+    is)."""
 
     def __init__(self, table, model_names=ALL_NAMES, blocks=1):
         self.table, self.names, self.blocks = table, model_names, blocks
@@ -38,15 +43,17 @@ class FakeCompiler:
 
     def __call__(self, saved):
         outer = self
+        rung = tuple(n for n in saved.names
+                     if self.blocks and n in self.names)
 
         class Traced:
             def lower(self):
-                outer.lowered.append(saved.names)
+                outer.lowered.append(rung)
                 return self
 
             def compile(self):
-                outer.compiled.append(saved.names)
-                total = outer.table[saved.names]
+                outer.compiled.append(rung)
+                total = outer.table[rung]
                 if isinstance(total, Exception):
                     raise total
                 if total is None:
@@ -56,16 +63,14 @@ class FakeCompiler:
                 return FakeCompiled(total)
 
         class Step:
-            names = saved.names
-
             def trace(self, state, batch):
-                outer.traced.append(saved.names)
+                outer.traced.append(rung)
                 saved.met |= set(outer.names)
                 saved.blocks += outer.blocks
                 return Traced()
 
             def __call__(self, state, batch):
-                return ("ran", saved.names)
+                return ("ran", rung)
 
         return Step()
 
@@ -220,6 +225,23 @@ def test_names_the_model_lacks_do_not_make_rungs(chooser):
     assert compiler.compiled == [("q", "k", "v", "wo"), QKV]
 
 
+@pytest.mark.parametrize("sel_bytes, want", [
+    (14.92, ("sel",)), (15.2, remat.FLOOR)], ids=["sel-fits", "sel-does-not"])
+def test_a_learned_selection_is_the_last_residual_to_go(
+        chooser, sel_bytes, want):
+    """A decoder with an indexer and sparse experts (no gate/up): the
+    selection's bits are kept or recomputed by the compiled step's bytes,
+    as every other residual is, after everything richer was refused."""
+    names = {"sel", "q", "k", "v", "wo"}
+    table = {("sel", "q", "k", "v", "wo"): None, ("sel", "q", "k", "v"): None,
+             ("sel",): int(sel_bytes * GiB), remat.FLOOR: int(14.7 * GiB)}
+    got, compiler = chooser(table, model_names=names)
+    assert got == want
+    assert compiler.compiled == list(table)[:3 + (want == remat.FLOOR)]
+    # one trace a rung: the first candidate is its own effective rung
+    assert compiler.traced[:2] == list(table)[:2]
+
+
 @pytest.mark.parametrize("fake", [
     {"model_names": {"flash_out", "flash_lse"}}, {"blocks": 0}],
     ids=["none-of-the-names", "remat-off"])
@@ -228,8 +250,8 @@ def test_a_model_with_nothing_to_keep_gets_the_floor(chooser, fake):
     with ``remat=False`` wraps no block. One compile, for the bytes."""
     names, compiler = chooser({remat.FLOOR: 13 * GiB}, **fake)
     assert names == remat.FLOOR
-    # The first trace tells; it is never lowered.
-    assert compiler.traced == [FULL, remat.FLOOR]
+    # The first trace tells, and is the floor's program: traced once.
+    assert compiler.traced == [remat.FLOOR]
     assert compiler.lowered == compiler.compiled == [remat.FLOOR]
     c = profiler.counters()
     assert (c["remat:rungs_tried"], c["remat:step_bytes"]) == (1, 13 * GiB)
@@ -258,13 +280,13 @@ def _kept_shapes(rung):
     loss, params, saved = _loss_fn("llama-tiny", rung,
                                           scan_layers=False)
     kept = saved_residuals(loss, params)
-    assert saved.met == set(remat.LADDER[0]) and saved.blocks
+    assert saved.met == set(FULL) and saved.blocks
     named = sum(bool(re.search(r"remat\.py:\d+:\d+ \(name\)", why))
                 for _, why in kept)
     return collections.Counter(aval.shape for aval, _ in kept), named
 
 
-@pytest.mark.parametrize("rung", remat.LADDER, ids="+".join)
+@pytest.mark.parametrize("rung", RUNGS[:-1], ids="+".join)
 def test_saved_residuals_are_exactly_the_named_values(rung):
     """What survives the forward beside what the floor keeps (each block's
     input): one value of each named width a layer, nothing else. (jax
@@ -295,6 +317,23 @@ def test_every_rung_gives_the_floors_loss_and_gradients(model_name, kw):
         for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-5, atol=1e-7, err_msg=rung)
+
+
+@pytest.mark.parametrize("model_name,kw,has", [
+    ("llama-tiny", {}, FULL), ("hybrid-tiny", {"xent_chunk": 8}, ())],
+    ids=["llama-tiny", "hybrid-tiny"])
+def test_a_name_the_model_lacks_leaves_its_program_alone(model_name, kw, has):
+    """The ladder's first candidate is traced with every name and run, if
+    chosen, without a second trace: it has to be the program a warm start
+    builds from the memo's names (the ones the model has; none at all for
+    the layer-kind decoder, whose floor has no policy)."""
+    texts = []
+    for rung in (remat.LADDER[0], has):
+        loss, params, saved = _loss_fn(model_name, rung, **kw)
+        texts.append(jax.jit(jax.value_and_grad(loss)).lower(
+            params).as_text())
+        assert saved.effective(remat.LADDER[0]) == has
+    assert texts[0] == texts[1]
 
 
 def test_names_are_inert_outside_a_step():
@@ -403,13 +442,13 @@ def test_the_step_a_cold_start_compiled_is_the_step_a_warm_start_loads(
                 assert str(module).count("@tpu_custom_call") == 4
                 digest = hashlib.sha256(cache_key._canonicalize_ir(
                     module, cache_key.IgnoreCallbacks.NO)).hexdigest()
-                keys.append((saved.names, digest))
+                keys.append((saved.effective(saved.names), digest))
                 return self
 
             trace = __call__ = key
 
             def compile(self):
-                return FakeCompiled(MISTRAL[saved.names])
+                return FakeCompiled(MISTRAL[saved.effective(saved.names)])
 
         return Keyed()
 

@@ -117,7 +117,93 @@ def _sharded(topo):
             jnp.float32).sum(), (0, 1, 2)), (q, kv, kv)
 
 
+# The keye-vl-2.0-30b-a3b cell's shapes: one sequence of 16384, 32 x 128
+# heads over 4 KV heads, a 16 x 64 indexer, query rows 1024 at a time.
+SEL_T, SEL_HKV, IDX_J, IDX_E, IDX_ROWS = 16384, 4, 16, 64, 1024
+
+
+def _selected(grad):
+    from tony_tpu.ops.attention import SEL_SPAN, flash_attention_selected
+
+    def fwd(q, k, v, sel):
+        return flash_attention_selected(q, k, v, sel, H, interpret=False)
+
+    def build(topo):
+        sh = _one(topo)
+        q = jax.ShapeDtypeStruct((1, SEL_T, H * D), jnp.bfloat16, sharding=sh)
+        kv = jax.ShapeDtypeStruct((1, SEL_T, SEL_HKV * D), jnp.bfloat16,
+                                  sharding=sh)
+        sel = jax.ShapeDtypeStruct((1, SEL_T // SEL_SPAN, SEL_T, 128),
+                                   jnp.int32, sharding=sh)
+        if not grad:
+            return fwd, (q, kv, kv, sel)
+        return jax.grad(lambda q, k, v, s: fwd(q, k, v, s)[0].astype(
+            jnp.float32).sum(), (0, 1, 2)), (q, kv, kv, sel)
+    return build
+
+
+def _index_scores(topo):
+    """The last row block: 1024 queries against all 16384 keys."""
+    from tony_tpu.ops import indexer
+
+    sh = _one(topo)
+    qi = jax.ShapeDtypeStruct((1, IDX_ROWS, IDX_J, IDX_E), jnp.bfloat16,
+                              sharding=sh)
+    w = jax.ShapeDtypeStruct((1, IDX_ROWS, IDX_J), jnp.float32, sharding=sh)
+    ki = jax.ShapeDtypeStruct((1, SEL_T, IDX_E), jnp.bfloat16, sharding=sh)
+    return (lambda qi, w, ki: indexer.index_scores(
+        qi, w, ki, SEL_T - IDX_ROWS, interpret=False), (qi, w, ki))
+
+
+def _head_probs(topo):
+    from tony_tpu.ops.attention import SEL_SPAN, selected_head_probs
+
+    sh = _one(topo)
+    q = jax.ShapeDtypeStruct((1, IDX_ROWS, H * D), jnp.bfloat16, sharding=sh)
+    k = jax.ShapeDtypeStruct((1, SEL_T, SEL_HKV * D), jnp.bfloat16,
+                             sharding=sh)
+    lse = jax.ShapeDtypeStruct((1, H, IDX_ROWS), jnp.float32, sharding=sh)
+    sel = jax.ShapeDtypeStruct((1, SEL_T // SEL_SPAN, IDX_ROWS, 128),
+                               jnp.int32, sharding=sh)
+    return (lambda q, k, lse, sel: selected_head_probs(
+        q, k, lse, sel, H, SEL_T - IDX_ROWS, interpret=False),
+        (q, k, lse, sel))
+
+
+def _grouped_experts(grad):
+    """The dropless layer's grouped matmul (``ops.gmm``) at one chunk's
+    worst case, 8192 sorted rows over 16 experts: into the expert width
+    (2048 x 768, as ``w_gate`` / ``w_up``) and back (768 x 2048, as
+    ``w_down``); with ``grad`` the transposed and the weight-gradient
+    kernels too."""
+    from tony_tpu.ops.gmm import grouped_matmul
+
+    def fwd(x, w_in, w_out, sizes):
+        h = grouped_matmul(x, w_in, sizes, interpret=False)
+        return grouped_matmul(h, w_out, sizes, interpret=False)
+
+    def build(topo):
+        sh = _one(topo)
+        x = jax.ShapeDtypeStruct((8192, 2048), jnp.bfloat16, sharding=sh)
+        w_in = jax.ShapeDtypeStruct((16, 2048, 768), jnp.bfloat16,
+                                    sharding=sh)
+        w_out = jax.ShapeDtypeStruct((16, 768, 2048), jnp.bfloat16,
+                                     sharding=sh)
+        sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=sh)
+        if not grad:
+            return fwd, (x, w_in, w_out, sizes)
+        return jax.grad(lambda x, a, b, s: fwd(x, a, b, s).astype(
+            jnp.float32).sum(), (0, 1, 2)), (x, w_in, w_out, sizes)
+    return build
+
+
 CASES = {
+    "selected_fwd_gqa4_t16384": _selected(grad=False),
+    "selected_fwd_bwd_gqa4_t16384": _selected(grad=True),
+    "index_scores_1024x16384": _index_scores,
+    "head_probs_1024x16384": _head_probs,
+    "grouped_experts_fwd_8192x16": _grouped_experts(grad=False),
+    "grouped_experts_fwd_bwd_8192x16": _grouped_experts(grad=True),
     "flash_packed_fwd_mha": _packed(grad=False, hkv=H),
     "flash_packed_fwd_bwd_mha": _packed(grad=True, hkv=H),
     "flash_packed_fwd_bwd_gqa8": _packed(grad=True, hkv=HKV),

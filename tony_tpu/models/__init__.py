@@ -8,7 +8,9 @@ adds two first-class model families this package owns:
 * :mod:`~tony_tpu.models.resnet` — ResNet-50 for the ImageNet DP target;
 * :mod:`~tony_tpu.models.transformer` — a Llama-style decoder for the
   ``pjit``/GSPMD graduation config (SURVEY.md §6 config ⑤), with logical
-  sharding axes wired for dp/fsdp/tp/sp meshes;
+  sharding axes wired for dp/fsdp/tp/sp meshes; the same block with
+  q/k-norm, a learned sparse-attention indexer and dropless experts of
+  which a chip holds a range (``keye-vl-2.0-30b-a3b``);
 * :mod:`~tony_tpu.models.hybrid` — a decoder built from a tuple of layer
   kinds (state-space, windowed / full differential attention, gated memory
   units, cross-attention to one shared K/V);
@@ -33,7 +35,8 @@ def register(name: str):
 
 def get_model(name: str, **kw):
     """Build a registered model by name (``resnet50``, ``llama2-7b``,
-    ``llama-tiny``, ``hybrid-decoder``, ``hybrid-tiny``, ``mnist-mlp``,
+    ``llama-tiny``, ``mixtral-8x7b``, ``keye-vl-2.0-30b-a3b``,
+    ``keye-tiny``, ``hybrid-decoder``, ``hybrid-tiny``, ``mnist-mlp``,
     ``mnist-cnn``)."""
     # Import for registration side effects.
     from tony_tpu.models import (hybrid, mnist, resnet,  # noqa: F401

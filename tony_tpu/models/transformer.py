@@ -66,14 +66,34 @@ class TransformerConfig:
     # Quantized compute lane (tony_tpu.ops.quant): which projection
     # groups run int8×int8→int32 matmuls with f32 rescale. True =
     # ("qkv", "o", "mlp"); a tuple selects explicitly ("lm_head" opts
-    # the unembed in). Embedding and norms stay bf16/f32 by policy. The
+    # the unembed in). "qkv" takes an indexer's three projections along;
+    # under "mlp" dropless experts multiply their operands' int8 image. Embedding and norms stay bf16/f32 by policy. The
     # lane is loss-pin gated: tests/test_quant.py holds the quantized
     # tiny-transformer curve against bf16 within a committed tolerance.
     quant: Any = None
+    # Width of one attention head where it is not dim / n_heads (0).
+    attn_head_dim: int = 0
+    # RMSNorm over each q and k head before the rotation.
+    qk_norm: bool = False
+    # Learned sparse attention (tony_tpu.ops.indexer): >0 gives every
+    # block an indexer of this many heads of index_dim with one key head;
+    # each query then attends the index_topk keys it scores highest (one
+    # selection a query, shared by the heads), and the indexer's KL loss
+    # is sown into ``losses``.
+    index_heads: int = 0
+    index_dim: int = 64
+    index_topk: int = 2048
+    # Dropless experts (models.moe.DroplessMoE) instead of the capacity
+    # path: routes over moe_experts, holds the contiguous range
+    # [moe_expert_offset, moe_expert_offset + moe_experts_held) of them
+    # (0 held: all) and returns their part of the result.
+    moe_dropless: bool = False
+    moe_experts_held: int = 0
+    moe_expert_offset: int = 0
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.attn_head_dim or self.dim // self.n_heads
 
     def quant_lanes(self) -> frozenset:
         """The validated set of quantized projection groups."""
@@ -96,23 +116,39 @@ class TransformerConfig:
 
     def flops_per_token(self) -> int:
         """≈6·N_matmul FLOPs per trained token (fwd+bwd), plus attention's
-        12·L·dim·seq term — matmul-FLOPs-only MFU accounting. The input
-        embedding is a gather (backward: scatter-add) and contributes zero
-        matmul FLOPs, so only the unembed projection counts toward the
-        vocab term. For MoE, only the top-k experts' FFN params are
-        active per token."""
+        12·L·heads·head_dim·seq term — matmul-FLOPs-only MFU accounting.
+        The input embedding is a gather (backward: scatter-add) and
+        contributes zero matmul FLOPs, so only the unembed projection
+        counts toward the vocab term. For MoE, only the top-k experts' FFN
+        params are active per token — of a held range, the share of them
+        that an even routing sends here. With an indexer: its three
+        projections, its scores over the causal half (forward and
+        backward), and attention over the selected pairs only (a token
+        sees min(position + 1, index_topk) keys)."""
         ffn_active = 3 * self.dim * self.ffn_hidden
         if self.moe_experts > 0:
-            ffn_active = (self.moe_top_k * ffn_active
-                          + self.dim * self.moe_experts)  # + router
+            share = (self.moe_experts_held or self.moe_experts) \
+                / self.moe_experts if self.moe_dropless else 1.0
+            ffn_active = int(self.moe_top_k * share * ffn_active
+                             + self.dim * self.moe_experts)  # + router
+        qdim = self.n_heads * self.head_dim
         n_params = (
             self.vocab * self.dim  # unembed only; embed gather = 0 matmul FLOPs
             + self.n_layers * (
                 self.dim * self.head_dim
                 * (self.n_heads + 2 * self.n_kv_heads)   # wq, wk, wv
-                + self.n_heads * self.head_dim * self.dim  # wo
+                + qdim * self.dim                          # wo
                 + ffn_active))
-        return 6 * n_params + 12 * self.n_layers * self.dim * self.max_seq
+        t = self.max_seq
+        if not self.index_heads:
+            return 6 * n_params + 12 * self.n_layers * qdim * t
+        k = min(self.index_topk, t)
+        keys_seen = (k * (k + 1) // 2 + (t - k) * k) / t   # mean a token
+        index = self.index_heads * self.index_dim
+        n_params += self.n_layers * self.dim * (
+            index + self.index_dim + self.index_heads)
+        return int(6 * n_params + self.n_layers * (
+            12 * qdim * keys_seen + 6 * index * (t + 1) / 2))
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float,
@@ -187,6 +223,31 @@ def _count_blocks(t: int, head_dim: int, itemsize: int) -> None:
         profiler.count_once(f"attn:{name}.dense", n)
 
 
+def _count_selection(t: int, topk: int, head_dim: int, itemsize: int) -> None:
+    """The selected-attention calls' trace-time facts, once:
+    ``attn:index_topk``, ``attn:selected_pairs`` / ``attn:causal_pairs``
+    (pairs of one sequence: what the selection leaves of the triangle),
+    ``attn:block_q.<fwd|dq|dkv>.sel`` / ``attn:block_k.<...>.sel`` and
+    ``attn:kv_blocks_visited.sel`` / ``attn:kv_blocks_total.sel`` (the
+    kernels visit every tile at or below the diagonal: none is skipped
+    for holding no selected key)."""
+    from tony_tpu import profiler
+    from tony_tpu.ops.attention import selection_blocks
+
+    k = min(topk, t)
+    profiler.count_once("attn:index_topk", topk)
+    profiler.count_once("attn:selected_pairs", k * (k + 1) // 2 + (t - k) * k)
+    profiler.count_once("attn:causal_pairs", t * (t + 1) // 2)
+    blocks = selection_blocks(t, head_dim, itemsize)
+    for kernel, (bq, bk) in blocks._asdict().items():
+        profiler.count_once(f"attn:block_q.{kernel}.sel", bq)
+        profiler.count_once(f"attn:block_k.{kernel}.sel", bk)
+    bq, bk = blocks.fwd
+    profiler.count_once("attn:kv_blocks_visited.sel", sum(
+        ((qi + 1) * bq - 1) // bk + 1 for qi in range(t // bq)))
+    profiler.count_once("attn:kv_blocks_total.sel", (t // bq) * (t // bk))
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -229,6 +290,9 @@ class Attention(nn.Module):
             # launch, and the in-buffer overwrite of positions >= each
             # row's own block start is what makes rolled-back (stale)
             # pool rows unreadable by construction.
+            if cfg.qk_norm or cfg.index_heads:
+                raise ValueError("serve mode has no q/k-norm and no "
+                                 "selection inside paged decode")
             k_buf, v_buf = kv
             pos = positions.astype(jnp.int32)
             q4 = rope(q.reshape(b, t, nh, hd), pos, cfg.rope_theta,
@@ -254,6 +318,11 @@ class Attention(nn.Module):
                     pos)
             out = out.transpose(0, 2, 1, 3).reshape(b, t, nh * hd)
             return wo(out), (k_rows, v_rows)
+        if cfg.qk_norm or cfg.index_heads:
+            if cfg.mesh is not None:
+                raise ValueError("q/k-norm and the indexer have no sharded "
+                                 "attention path")
+            return wo(self._selected(x, q, k, v, positions))
         if (cfg.attention == "flash" and cfg.mesh is None
                 and hd % 128 == 0):
             # Packed layout: the kernel reads heads as lane offsets from
@@ -310,6 +379,51 @@ class Attention(nn.Module):
         return wo(out)
 
 
+    def _selected(self, x, q, k, v, positions):
+        """Training-mode attention with q/k-norm and/or a learned
+        selection, over the packed layout: returns the ``[B, T, H·D]``
+        output before ``wo`` and sows the indexer's loss."""
+        from tony_tpu.ops import attention as att
+        from tony_tpu.ops import indexer
+
+        cfg = self.cfg
+        b, t, _ = x.shape
+        hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+        q4, k4 = q.reshape(b, t, nh, hd), k.reshape(b, t, nkv, hd)
+        if cfg.qk_norm:
+            q4 = RMSNorm(cfg.norm_eps, name="q_norm")(q4)
+            k4 = RMSNorm(cfg.norm_eps, name="k_norm")(k4)
+        q3 = rope(q4, positions, cfg.rope_theta, seq_axis=1).reshape(
+            b, t, nh * hd)
+        k3 = rope(k4, positions, cfg.rope_theta, seq_axis=1).reshape(
+            b, t, nkv * hd)
+        if not cfg.index_heads:
+            return att.flash_attention_packed(q3, k3, v, nh, causal=True)
+        # The indexer reads the block's input and gives it no gradient:
+        # its three projections learn from their own loss alone.
+        xi = jax.lax.stop_gradient(x)
+        nj, ne = cfg.index_heads, cfg.index_dim
+        proj = lambda feats, name: _proj_dense(
+            cfg, "qkv", feats, ("embed", None), name)(xi)
+        with jax.named_scope("attn_index"):
+            qi = rope(proj(nj * ne, "index_wq").reshape(b, t, nj, ne),
+                      positions, cfg.rope_theta, seq_axis=1)
+            ki = rope(proj(ne, "index_wk").reshape(b, t, 1, ne), positions,
+                      cfg.rope_theta, seq_axis=1).reshape(b, t, ne)
+            wi = proj(nj, "index_w").astype(jnp.float32) * (nj * ne) ** -0.5
+        # Kernels on a TPU, their jax.numpy twins elsewhere (the ops
+        # decide by backend, as flash_attention does).
+        sel = remat.name(indexer.select(qi, wi, ki, cfg.index_topk), "sel")
+        if t % 128 == 0:
+            _count_selection(t, cfg.index_topk, hd, v.dtype.itemsize)
+        out, lse = att.flash_attention_selected(q3, k3, v, sel, nh)
+        loss = indexer.index_loss(qi, wi, ki, sel, q3, k3, lse, nh)
+        self.sow("losses", "index_kl", loss,
+                 reduce_fn=lambda a, c: a + c,
+                 init_fn=lambda: jnp.float32(0.0))
+        return out
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
 
@@ -338,7 +452,16 @@ class Block(nn.Module):
         if kv is not None:
             attn_out, new_kv = attn_out
         x = x + attn_out
-        if cfg.moe_experts > 0:
+        if cfg.moe_experts > 0 and cfg.moe_dropless:
+            from tony_tpu.models.moe import DroplessMoE
+            mlp = DroplessMoE(cfg.dim, cfg.ffn_hidden, cfg.moe_experts,
+                              top_k=cfg.moe_top_k,
+                              experts_held=cfg.moe_experts_held,
+                              expert_offset=cfg.moe_expert_offset,
+                              dtype=cfg.dtype,
+                              quant="mlp" in cfg.quant_lanes(),
+                              name="moe_mlp")
+        elif cfg.moe_experts > 0:
             from tony_tpu.models.moe import MoEMLP
             mlp = MoEMLP(cfg.dim, cfg.ffn_hidden, cfg.moe_experts,
                          top_k=cfg.moe_top_k,
@@ -441,7 +564,7 @@ class Transformer(nn.Module):
                 # so no pack site.
                 x, new_kv = nn.scan(
                     block_cls,
-                    variable_axes={"params": 0, "losses": 0},
+                    variable_axes={"params": 0, "losses": 0, "stats": 0},
                     split_rngs={"params": True},
                     in_axes=(nn.broadcast, 0),
                     length=cfg.n_layers,
@@ -450,7 +573,7 @@ class Transformer(nn.Module):
             else:
                 x, _ = nn.scan(
                     block_cls,
-                    variable_axes={"params": 0, "losses": 0},
+                    variable_axes={"params": 0, "losses": 0, "stats": 0},
                     split_rngs={"params": True},
                     in_axes=nn.broadcast,
                     length=cfg.n_layers,
@@ -535,3 +658,35 @@ def llama_moe_tiny(**kw) -> Transformer:
     defaults.update(kw)
     return Transformer(TransformerConfig(**defaults))
 
+
+
+@register("keye-vl-2.0-30b-a3b")
+def keye_vl2_30b_a3b(**kw) -> Transformer:
+    """Keye-VL-2.0-30B-A3B's language model (the text path; no input
+    tower): GQA 32 x 128 over 4 KV heads with q/k-norm, a 16 x 64 indexer
+    that selects 2048 keys a query, 128 experts of width 768, 8 a token,
+    dropless. ``moe_experts_held`` / ``moe_expert_offset`` say which
+    experts live here."""
+    defaults = dict(vocab=151936, dim=2048, n_layers=48, n_heads=32,
+                    n_kv_heads=4, attn_head_dim=128, ffn_hidden=768,
+                    max_seq=16384, rope_theta=1e7, norm_eps=1e-6,
+                    qk_norm=True, index_heads=16, index_dim=64,
+                    index_topk=2048, moe_experts=128, moe_top_k=8,
+                    moe_dropless=True, xent_chunk=1024)
+    defaults.update(kw)
+    return Transformer(TransformerConfig(**defaults))
+
+
+@register("keye-tiny")
+def keye_tiny(**kw) -> Transformer:
+    """Test-scale twin of ``keye-vl-2.0-30b-a3b``: the same code path at
+    toy shapes (the kernels' jax.numpy twins on the CPU)."""
+    defaults = dict(vocab=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
+                    attn_head_dim=32, ffn_hidden=32, max_seq=64,
+                    rope_theta=1e7, norm_eps=1e-6, qk_norm=True,
+                    index_heads=2, index_dim=16, index_topk=16,
+                    moe_experts=8, moe_top_k=2,
+                    moe_dropless=True, attention="reference",
+                    remat=False, xent_chunk=32)
+    defaults.update(kw)
+    return Transformer(TransformerConfig(**defaults))

@@ -188,6 +188,42 @@ def _mask_at(q0, k0, causal, kv_len, window):
                              kv_len=kv_len, window=window)
 
 
+# A learned selection (one per query, shared by the heads) reaches the
+# kernels as bits: ``sel[b, w, t, lane]`` (int32) holds, in bit ``j``,
+# whether query t sees key ``(32 w + j) * 128 + lane`` — 128-key blocks
+# along the bits, so a tile's mask is a scalar shift of one [rows, 128]
+# word block and one word block serves ``SEL_SPAN`` consecutive keys
+# (1/8 byte a pair; :func:`pack_selection` writes the layout).
+SEL_LANES = 128
+SEL_SPAN = 32 * SEL_LANES
+
+
+def _sel_keep(words, kb, block_k: int):
+    """bool [rows, block_k]: the selection's bits for the tile whose
+    k-block index (in blocks of ``block_k`` keys; may be traced) is ``kb``,
+    from the [rows, 128] word block covering it."""
+    chunks = block_k // SEL_LANES
+    bit0 = (kb * chunks) % 32
+    keep = [jax.lax.shift_right_logical(
+        words, jnp.full(words.shape, bit0 + c, words.dtype)) & 1
+        for c in range(chunks)]
+    # packsite: region-local — lane tiles of one kernel tile's mask, inside
+    # the Pallas body (VMEM values, no sharded array in sight).
+    return (keep[0] if chunks == 1 else jnp.concatenate(keep, axis=1)) != 0
+
+
+def _mask_sel(s, words, kb):
+    """``_mask_s``'s counterpart for a selection. The selection is causal
+    by construction, so no position is compared."""
+    return jnp.where(_sel_keep(words, kb, s.shape[1]), s, _NEG_INF)
+
+
+def _sel_word(block_k: int):
+    """k-block index -> index of the selection word block holding it."""
+    per = SEL_SPAN // block_k
+    return lambda kb: kb // per
+
+
 def _dot(a, b, dims):
     return jax.lax.dot_general(a, b, (dims, ((), ())),
                                preferred_element_type=jnp.float32)
@@ -256,7 +292,7 @@ def _dkv_tile(q, do, o, lse, k_blk, v_blk, mask, scale):
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                   acc_scr, *, causal: bool, scale: float, qi_axis: int = 1,
                   kv_len: Optional[int] = None,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None, sel_ref=None):
     """Streamed-KV flash forward: grid ``(..., qi, kb)`` with the k-block
     axis INNERMOST, so K/V arrive one ``[Bk, D]`` block at a time (VMEM
     stays O(block), any context length fits) while the online-softmax
@@ -271,7 +307,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     2 for the packed [B, T, H·D] layout's (b, h, i, kb) grid. Under a
     ``window`` the k axis holds only the blocks a q-block can see (``_window_spans``): step 0 is
     the q-block's first visible block, and blocks left of the window are
-    neither fetched nor computed."""
+    neither fetched nor computed. ``sel_ref`` (the ``_sel`` wrappers): the
+    word block of a learned selection, which then is the mask."""
     bq, d = q_ref.shape
     bk = k_ref.shape[0]
     qi = pl.program_id(qi_axis)
@@ -290,7 +327,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
     @pl.when(contributes)
     def _step():
-        mask = _mask_at(qi * bq, kb * bk, causal, kv_len, window)
+        mask = _mask_at(qi * bq, kb * bk, causal, kv_len, window) \
+            if sel_ref is None else functools.partial(
+                _mask_sel, words=sel_ref[:], kb=kb)
         m, l, acc = _fwd_tile(
             q_ref[:], k_ref[:], v_ref[:],
             (m_scr[:, 0:1], l_scr[:, 0:1], acc_scr[:]), mask, scale)
@@ -306,7 +345,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
                          dq_scr, *, causal: bool, scale: float,
                          qi_axis: int = 1, kv_len: Optional[int] = None,
-                         window: Optional[int] = None):
+                         window: Optional[int] = None, sel_ref=None):
     """dq, streamed like the forward (grid ``(..., qi, kb)``, k innermost,
     dq accumulated in VMEM scratch): recompute p from (q, k, lse) per
     k-block — ds = p·(dpᵀ−D); dq += ds·k·scale. No T×T buffer and no
@@ -327,7 +366,9 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
 
     @pl.when(contributes)
     def _step():
-        mask = _mask_at(qi * bq, kb * bk, causal, kv_len, window)
+        mask = _mask_at(qi * bq, kb * bk, causal, kv_len, window) \
+            if sel_ref is None else functools.partial(
+                _mask_sel, words=sel_ref[:], kb=kb)
         do = do_ref[:]
         dq_scr[:] = dq_scr[:] + _dq_tile(
             q_ref[:], do, lse_ref[:, 0:1], _row_dot(do, o_ref[:]),
@@ -342,7 +383,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
                           scale: float, qi_axis: int = 1, nqb: int = 0,
                           kv_len: Optional[int] = None,
-                          window: Optional[int] = None, nq: int = 0):
+                          window: Optional[int] = None, nq: int = 0,
+                          sel_ref=None):
     """dk/dv, streamed: grid ``(..., kj, qx)`` with the q-side axis
     INNERMOST — q/do/o/lse arrive one block at a time while this k-block's
     dk/dv accumulate in VMEM scratch (dv += pᵀ·do; dk += dsᵀ·q·scale).
@@ -376,7 +418,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
     @pl.when(contributes)
     def _step():
-        mask = _mask_at(qb * bq, kj * bk, causal, kv_len, window)
+        mask = _mask_at(qb * bq, kj * bk, causal, kv_len, window) \
+            if sel_ref is None else functools.partial(
+                _mask_sel, words=sel_ref[:], kb=kj)
         dk, dv = _dkv_tile(q_ref[:], do_ref[:], o_ref[:], lse_ref[:, 0:1],
                            k_ref[:], v_ref[:], mask, scale)
         dv_scr[:] = dv_scr[:] + dv
@@ -1125,6 +1169,309 @@ def _flash_packed_bwd(heads, causal, scale, blocks, interpret, window,
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
 
 
+# --------------------------------------------------------------------
+# Attention over a learned selection: every query sees the keys one
+# selection names (shared by its heads), packed as ``_mask_sel`` reads
+# them. The three streamed packed grids with the selection's word block as
+# one more operand; tiles above the diagonal are skipped as under the
+# causal mask, tiles below it are all visited (whether a tile no query
+# selects can be skipped is not settled here: ``attn:kv_blocks_visited.sel``
+# counts what is visited).
+# --------------------------------------------------------------------
+
+
+def pack_selection(keep: jax.Array) -> jax.Array:
+    """bool ``[B, T, Tk]`` -> the selection's words, int32
+    ``[B, ceil(Tk / SEL_SPAN), T, 128]``."""
+    b, t, tk = keep.shape
+    w = -(-tk // SEL_SPAN)
+    bits = jnp.pad(keep, ((0, 0), (0, 0), (0, w * SEL_SPAN - tk))).reshape(
+        b, t, w, 32, SEL_LANES).astype(jnp.uint32)
+    words = jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32)[:, None],
+                    axis=3, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(words, jnp.int32).transpose(
+        0, 2, 1, 3)
+
+
+def unpack_selection(sel: jax.Array, tk: int) -> jax.Array:
+    """The inverse of :func:`pack_selection`: bool ``[B, T, tk]``."""
+    b, w, t, _ = sel.shape
+    words = jax.lax.bitcast_convert_type(
+        sel.transpose(0, 2, 1, 3), jnp.uint32)[:, :, :, None, :]
+    bits = (words >> jnp.arange(32, dtype=jnp.uint32)[:, None]) & 1
+    return bits.reshape(b, t, w * SEL_SPAN)[:, :, :tk] != 0
+
+
+def selection_blocks(t: int, d: int = 128, itemsize: int = 2) -> Blocks:
+    """The blocks of the three selected-attention kernels at length ``t``:
+    :func:`_pick_blocks`' side, fitted, in whole 128-key lane blocks that
+    divide a selection word's span."""
+    if t % SEL_LANES:
+        raise ValueError(f"selected attention needs a length that is a "
+                         f"multiple of {SEL_LANES}, got {t}")
+    side = _pick_blocks(t, None, d, itemsize).fwd[0]
+    while t % side:
+        side //= 2
+    return Blocks(*[(side, side)] * 3)
+
+
+def _sel_fwd_kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref, m_scr,
+                    l_scr, acc_scr, **kw):
+    _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                  acc_scr, sel_ref=sel_ref, **kw)
+
+
+def _sel_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, sel_ref,
+                   dq_ref, dq_scr, **kw):
+    _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                         dq_ref, dq_scr, sel_ref=sel_ref, **kw)
+
+
+def _sel_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, sel_ref,
+                    dk_ref, dv_ref, dk_scr, dv_scr, **kw):
+    _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                          dk_ref, dv_ref, dk_scr, dv_scr, sel_ref=sel_ref,
+                          **kw)
+
+
+def _selected_forward(q, k, v, sel, heads, scale, blocks, interpret):
+    b, t, hd = q.shape
+    d = hd // heads
+    lane = _lane_of(hd // k.shape[2])
+    bq, bk = blocks.fwd
+    word = _sel_word(bk)
+    q_pin = pl.BlockSpec((None, bq, d), lambda bi, h, i, kb: (bi, i, h))
+    k_str = pl.BlockSpec((None, bk, d),
+                         lambda bi, h, i, kb: (bi, kb, lane(h)))
+    with jax.named_scope("attn_fwd_sel"):
+        return pl.pallas_call(
+            functools.partial(_sel_fwd_kernel, causal=True, scale=scale,
+                              qi_axis=2),
+            grid=(b, heads, t // bq, t // bk),
+            in_specs=[q_pin, k_str, k_str,
+                      pl.BlockSpec((None, None, bq, SEL_LANES),
+                                   lambda bi, h, i, kb: (bi, word(kb), i, 0))],
+            out_specs=(q_pin,
+                       pl.BlockSpec((None, None, bq, _LSE_LANES),
+                                    lambda bi, h, i, kb: (bi, h, i, 0))),
+            out_shape=(
+                jax.ShapeDtypeStruct((b, t, hd), q.dtype),
+                jax.ShapeDtypeStruct((b, heads, t, _LSE_LANES), jnp.float32)),
+            scratch_shapes=_fwd_scratch(bq, d),
+            interpret=interpret,
+        )(q, k, v, sel)
+
+
+def _selected_backward(q, k, v, sel, do, o, lse, heads, scale, blocks,
+                       interpret):
+    b, t, hd = q.shape
+    d = hd // heads
+    hkv = k.shape[2] // d
+    reps = heads // hkv
+    lane = _lane_of(reps)
+    bq, bk = blocks.dq
+    word = _sel_word(bk)
+    q_pin = pl.BlockSpec((None, bq, d), lambda bi, h, i, kb: (bi, i, h))
+    k_str = pl.BlockSpec((None, bk, d),
+                         lambda bi, h, i, kb: (bi, kb, lane(h)))
+    lse_pin = pl.BlockSpec((None, None, bq, _LSE_LANES),
+                           lambda bi, h, i, kb: (bi, h, i, 0))
+    with jax.named_scope("attn_bwd_dq_sel"):
+        dq = pl.pallas_call(
+            functools.partial(_sel_dq_kernel, causal=True, scale=scale,
+                              qi_axis=2),
+            grid=(b, heads, t // bq, t // bk),
+            in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin,
+                      pl.BlockSpec((None, None, bq, SEL_LANES),
+                                   lambda bi, h, i, kb: (bi, word(kb), i, 0))],
+            out_specs=q_pin,
+            out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            interpret=interpret,
+        )(q, k, v, do, o, lse, sel)
+    # dk/dv: (b, hkv, kj, qx), qx the flattened (rep, q-block) sweep.
+    bq, bk = blocks.dkv
+    word = _sel_word(bk)
+    nqb = t // bq
+    k_pin = pl.BlockSpec((None, bk, d), lambda bi, hk, j, qx: (bi, j, hk))
+    q_str = pl.BlockSpec((None, bq, d), lambda bi, hk, j, qx:
+                         (bi, qx % nqb, hk * reps + qx // nqb))
+    lse_str = pl.BlockSpec((None, None, bq, _LSE_LANES),
+                           lambda bi, hk, j, qx:
+                           (bi, hk * reps + qx // nqb, qx % nqb, 0))
+    with jax.named_scope("attn_bwd_dkv_sel"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_sel_dkv_kernel, causal=True, scale=scale,
+                              qi_axis=2, nqb=nqb),
+            grid=(b, hkv, t // bk, reps * nqb),
+            in_specs=[q_str, k_pin, k_pin, q_str, q_str, lse_str,
+                      pl.BlockSpec((None, None, bq, SEL_LANES),
+                                   lambda bi, hk, j, qx:
+                                   (bi, word(j), qx % nqb, 0))],
+            out_specs=(k_pin, k_pin),
+            out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype)),
+            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                            pltpu.VMEM((bk, d), jnp.float32)],
+            interpret=interpret,
+        )(q, k, v, do, o, lse, sel)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_selected(q, k, v, sel, heads, scale, blocks, interpret):
+    return _flash_selected_fwd(q, k, v, sel, heads, scale, blocks,
+                               interpret)[0]
+
+
+def _flash_selected_fwd(q, k, v, sel, heads, scale, blocks, interpret):
+    out, lse = _selected_forward(q, k, v, sel, heads, scale, blocks,
+                                 interpret)
+    out, lse = remat.name(out, "flash_out"), remat.name(lse, "flash_lse")
+    return (out, lse), (q, k, v, sel, out, lse)
+
+
+def _flash_selected_bwd(heads, scale, blocks, interpret, residuals, g):
+    q, k, v, sel, out, lse = residuals
+    dq, dk, dv = _selected_backward(q, k, v, sel, g[0], out, lse, heads,
+                                    scale, blocks, interpret)
+    return dq, dk, dv, None
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
+
+
+def selected_attention_reference(q, k, v, keep, heads,
+                                 scale: Optional[float] = None):
+    """The semantic spec of :func:`flash_attention_selected` over the
+    packed layout, with the membership as a bool ``[B, T, T]``: returns
+    ``(out [B, T, H·D], lse [B, H, T])``."""
+    b, t, hd = q.shape
+    d = hd // heads
+    hkv = k.shape[2] // d
+    scale = d ** -0.5 if scale is None else scale
+    to4 = lambda x, n: x.reshape(b, t, n, d).transpose(0, 2, 1, 3).astype(
+        jnp.float32)
+    q4 = to4(q, heads)
+    k4 = jnp.repeat(to4(k, hkv), heads // hkv, axis=1)
+    v4 = jnp.repeat(to4(v, hkv), heads // hkv, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q4, k4) * scale
+    s = jnp.where(keep[:, None], s, _NEG_INF)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    out = jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v4)
+    return (out.transpose(0, 2, 1, 3).reshape(b, t, hd).astype(q.dtype),
+            lse)
+
+
+def flash_attention_selected(q: jax.Array, k: jax.Array, v: jax.Array,
+                             sel: jax.Array, heads: int,
+                             scale: Optional[float] = None,
+                             interpret: Optional[bool] = None):
+    """Causal self-attention over the packed ``[B, T, H·D]`` layout in
+    which query t sees the keys the selection ``sel`` names
+    (:func:`pack_selection`; one selection a query, shared by the heads,
+    every selected key at or before the query). Returns ``(out, lse)``:
+    ``lse`` is ``[B, H, T]`` float32, the log-sum-exp of each row's
+    selected scores, for :func:`selected_head_probs` (it carries no
+    gradient; neither does ``sel``). Pallas kernels on a TPU or with
+    ``interpret=True``, :func:`selected_attention_reference` elsewhere."""
+    b, t, hd = q.shape
+    d = hd // heads
+    scale = d ** -0.5 if scale is None else scale
+    if interpret is None:
+        if jax.default_backend() != "tpu":
+            return selected_attention_reference(
+                q, k, v, unpack_selection(sel, t), heads, scale)
+        interpret = False
+    if d % 128:
+        raise ValueError(f"selected attention reads heads as lane blocks: "
+                         f"head_dim {d} is not a multiple of 128")
+    out, lse = _flash_selected(q, k, v, sel, heads, scale,
+                               selection_blocks(t, d, k.dtype.itemsize),
+                               interpret)
+    return out, lse[..., 0]
+
+
+# Query rows of a probabilities tile; its keys are twice as many.
+_PROBS_ROWS = 512
+
+
+def _head_probs_kernel(q_ref, k_ref, lse_ref, sel_ref, p_ref, *, scale,
+                       row0: int):
+    """Grid ``(b, qi, kb, h)``, heads innermost: the output tile stays in
+    VMEM while every head adds its probabilities ``exp(q.k scale - lse)``
+    to it, and the selection masks the sum at the last head."""
+    bq, bk = p_ref.shape
+    qi, kb, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    below = kb * bk <= row0 + (qi + 1) * bq - 1
+
+    @pl.when(h == 0)
+    def _init():
+        p_ref[:] = jnp.zeros_like(p_ref)
+
+    @pl.when(below)
+    def _step():
+        s = _dot(q_ref[:], k_ref[:], _NT) * scale
+        p_ref[:] = p_ref[:] + jnp.exp(s - lse_ref[:, 0:1])
+
+    @pl.when(below & (h == pl.num_programs(3) - 1))
+    def _finalize():
+        p_ref[:] = jnp.where(_sel_keep(sel_ref[:], kb, bk), p_ref[:], 0.0)
+
+
+def selected_head_probs(q: jax.Array, k: jax.Array, lse: jax.Array,
+                        sel: jax.Array, heads: int, row0: int = 0,
+                        scale: Optional[float] = None,
+                        interpret: Optional[bool] = None) -> jax.Array:
+    """The main attention's probabilities summed over the heads, on the
+    selected keys and zero elsewhere: float32 ``[B, R, Tk]`` for the ``R``
+    query rows ``q [B, R, H·D]`` that start at position ``row0`` (with
+    their ``lse [B, H, R]`` and ``sel [B, W, R, 128]``) against the keys
+    ``k [B, Tk, Hkv·D]``, ``Tk >= row0 + R`` not required: keys past a
+    row's position are never selected. Nothing here is differentiated."""
+    b, r, hd = q.shape
+    tk = k.shape[1]
+    d = hd // heads
+    scale = d ** -0.5 if scale is None else scale
+    if interpret is None:
+        if jax.default_backend() != "tpu":
+            keep = unpack_selection(sel, tk)
+            hkv = k.shape[2] // d
+            q4 = q.reshape(b, r, heads, d).astype(jnp.float32)
+            k4 = jnp.repeat(k.reshape(b, tk, hkv, d), heads // hkv,
+                            axis=2).astype(jnp.float32)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q4, k4) * scale
+            p = jnp.exp(s - lse[..., None]).sum(axis=1)
+            return jnp.where(keep, p, 0.0)
+        interpret = False
+    bq = _fit_block(_PROBS_ROWS, r)
+    bk = _fit_lane_block(2 * _PROBS_ROWS, tk)
+    while bk > SEL_LANES and SEL_SPAN % bk:
+        bk = _fit_lane_block(bk - SEL_LANES, tk)
+    if not bq or bk % SEL_LANES or SEL_SPAN % bk:
+        raise ValueError(f"no legal blocks for {r} rows x {tk} keys")
+    lane = _lane_of(hd // k.shape[2])
+    word = _sel_word(bk)
+    lse8 = jnp.broadcast_to(lse[..., None], (*lse.shape, _LSE_LANES))
+    with jax.named_scope("attn_probs_sel"):
+        return pl.pallas_call(
+            functools.partial(_head_probs_kernel, scale=scale, row0=row0),
+            grid=(b, r // bq, tk // bk, heads),
+            in_specs=[
+                pl.BlockSpec((None, bq, d), lambda bi, i, kb, h: (bi, i, h)),
+                pl.BlockSpec((None, bk, d),
+                             lambda bi, i, kb, h: (bi, kb, lane(h))),
+                pl.BlockSpec((None, None, bq, _LSE_LANES),
+                             lambda bi, i, kb, h: (bi, h, i, 0)),
+                pl.BlockSpec((None, None, bq, SEL_LANES),
+                             lambda bi, i, kb, h: (bi, word(kb), i, 0))],
+            out_specs=pl.BlockSpec((None, bq, bk),
+                                   lambda bi, i, kb, h: (bi, i, kb)),
+            out_shape=jax.ShapeDtypeStruct((b, r, tk), jnp.float32),
+            interpret=interpret,
+        )(q, k, lse8, sel)
+
+
 def _fit_block(limit: int, t: int) -> int:
     """Largest block ≤ limit that divides ``t`` and is a multiple of the
     16-row sublane tile; 0 if none exists (ragged ``t``)."""
@@ -1133,6 +1480,18 @@ def _fit_block(limit: int, t: int) -> int:
     while b >= 16 and t % b:
         b -= 16
     return b if b >= 16 else 0
+
+
+def _fit_lane_block(limit: int, t: int) -> int:
+    """Largest block ≤ limit that divides ``t`` in whole 128-lane tiles
+    (a block along an array's minor dimension); ``t`` itself where it is
+    shorter than one tile; 0 if none exists."""
+    if t <= SEL_LANES:
+        return t
+    b = min(limit, t) // SEL_LANES * SEL_LANES
+    while b and t % b:
+        b -= SEL_LANES
+    return b
 
 
 def _pick_blocks(tk, window, d, itemsize) -> Blocks:

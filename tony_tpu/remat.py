@@ -9,7 +9,7 @@ not add up: PERF.md §7), so the program asks the compiler:
 
 * the models **name** the candidates where they are made (:func:`name`, a
   ``checkpoint_name``): ``q``, ``k``, ``v``, ``wo``, ``gate``, ``up``,
-  ``flash_out``, ``flash_lse``. A name costs nothing until a policy asks
+  ``flash_out``, ``flash_lse``, ``sel``. A name costs nothing until a policy asks
   for it;
 * :func:`block` is the one ``nn.remat`` both decoders wrap their layer in;
   it keeps the names of the :class:`Saved` the step entered around the
@@ -51,11 +51,17 @@ _log = logging.getLogger(__name__)
 # (PERF.md §6 PR 30): gate/up are the MLP's two recomputed matmuls, q/k/v
 # the three projections, wo the fourth. The flash residuals are named but
 # on no rung: they cost 1.25 GiB for two Mistral layers and buy 3.4 ms.
+# ``sel``, a learned selection's bits (tony_tpu.ops.indexer: 1/8 byte a
+# causal pair against a second scoring and 32-pass threshold of the whole
+# triangle in the backward), is on every rung and is the last to go: a
+# model without an indexer never meets the name, and its rungs are these
+# without it (``Saved.effective``).
 LADDER: Tuple[Tuple[str, ...], ...] = (
-    ("q", "k", "v", "wo", "gate", "up"),
-    ("q", "k", "v", "gate", "up"),
-    ("gate", "up"),
-    ("q", "k", "v"),
+    ("sel", "q", "k", "v", "wo", "gate", "up"),
+    ("sel", "q", "k", "v", "gate", "up"),
+    ("sel", "gate", "up"),
+    ("sel", "q", "k", "v"),
+    ("sel",),
 )
 FLOOR: Tuple[str, ...] = ()
 
@@ -289,13 +295,14 @@ class ChosenStep:
             _publish(found, limit, from_memo=True)
             return self.build(Saved(found["saved"]))
         # The first candidate's trace also tells which names this model
-        # has; rungs that differ only in names it lacks are one rung.
+        # has; rungs that differ only in names it lacks are one rung, and
+        # the first candidate is the program of its own effective rung.
         first = Saved(LADDER[0])
         fn = self.build(first)
         trace = yield fn
         readings = []
         for rung in dict.fromkeys(map(first.effective, LADDER + (FLOOR,))):
-            if rung != first.names:
+            if rung != first.effective(LADDER[0]):
                 fn = self.build(Saved(rung))
                 trace = yield fn
             try:

@@ -126,7 +126,12 @@ def create_train_state(model: nn.Module, tx: Any,
 
     fused = isinstance(tx, fused_optim.FusedOptimizer)
     if mesh is None:
-        params = nn.unbox(model.init(rng, sample_input))["params"]
+        # One program, and only the initialisers' part of it: under jit the
+        # forward pass ``model.init`` traces is dead code (eagerly it runs
+        # — a whole 16k-token forward of dozens of programs, the scanned
+        # layer stack compiled for nothing else).
+        params = jax.jit(lambda rng, x: nn.unbox(
+            model.init(rng, x))["params"])(rng, sample_input)
         if fused:
             return TrainState(step=0, apply_fn=model.apply, params=params,
                               tx=tx, opt_state=tx.init_state(params))
@@ -238,21 +243,27 @@ def make_train_step(loss_of: Callable[[jax.Array, Dict[str, jax.Array]],
                     # mutable="losses": models that sow auxiliary objectives
                     # (e.g. the MoE load-balancing loss) contribute them here;
                     # dense models return an empty collection.
+                    # ``stats``: what a model sows to be read, not
+                    # trained on (a dropless expert layer's row counts);
+                    # it comes back as ``metrics["stats"]``.
                     logits, sown = state.apply_fn(
-                        {"params": params}, batch["x"], mutable="losses",
-                        **extra)
+                        {"params": params}, batch["x"],
+                        mutable=["losses", "stats"], **extra)
                 aux = sum((leaf.sum() for leaf in
                            jax.tree.leaves(sown.get("losses", {}))),
                           start=jnp.float32(0.0))
-                return loss_of(logits, batch) + aux, aux
+                return loss_of(logits, batch) + aux, (
+                    aux, sown.get("stats", {}))
 
-            (loss, aux), grads = jax.value_and_grad(
+            (loss, (aux, stats)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params)
             with jax.named_scope("optimizer"):
                 new_state = state.apply_gradients(grads=grads)
                 gnorm = optax.global_norm(grads)
-            return new_state, {"loss": loss, "grad_norm": gnorm,
-                               "aux_loss": aux}
+            metrics = {"loss": loss, "grad_norm": gnorm, "aux_loss": aux}
+            if jax.tree.leaves(stats):
+                metrics["stats"] = stats
+            return new_state, metrics
 
         return jax.jit(step, donate_argnums=(0,) if donate else ())
 
