@@ -107,7 +107,7 @@ def test_two_adamw_steps_match_the_reference():
                                                    rel=2e-3), leaf
     # every held expert's rows of step 2, sown for the step's metrics
     stats = jax.tree.leaves(metrics["stats"])
-    assert len(stats) == 3 and all(s.shape == (CFG["layers"],) for s in stats)
+    assert len(stats) == 4 and all(s.shape == (CFG["layers"],) for s in stats)
 
 
 @pytest.mark.parametrize("objective, zero, live", [

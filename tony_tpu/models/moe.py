@@ -28,14 +28,22 @@ experts run as one grouped matmul over ragged row groups
 (:func:`tony_tpu.ops.gmm.grouped_matmul`: on the TPU Pallas kernels that
 walk the groups and cost the rows that are there; XLA's own
 ``jax.lax.ragged_dot`` costs the whole row buffer on the v5e, by an amount
-that follows the routing — PERF.md §5), and the result is put back in
-token order.
+that follows the routing — PERF.md §5), and each row is added, times its
+gate, into its token's row of the result.
+The buffers around the kernels cost every row they have, held or not, so
+they hold the rows this chip's experts can be **expected** to get
+(:func:`rows_buffer`: the held share of a chunk's routed rows, twice over),
+not the rows they could: held rows sort first, a pass takes one buffer of
+them, and a chunk that was sent more takes the rest in further passes of
+the same size — a loop whose count follows the routing, so dropless stays
+exact and a chunk within its expectation runs one pass (PERF.md §6, PR 32).
 On one chip the layer runs without its exchange; nothing stands in for the
 absent chips.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -43,7 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from tony_tpu import profiler
-from tony_tpu.ops.gmm import grouped_matmul
+from tony_tpu.ops.gmm import ROW_TILE, grouped_matmul
 
 
 def router_assignment(gates: jax.Array, top_k: int, capacity: int):
@@ -214,12 +222,104 @@ def _int8_image(x: jax.Array, axis: int) -> jax.Array:
     return x + jax.lax.stop_gradient(image - x)
 
 
-# Tokens a dropless layer routes at a time (fewer where a call has fewer).
-# A chunk's buffers hold ``CHUNK * top_k`` rows of the model width, the
-# worst case of all of them held here: at 8 experts a token and width 2048
-# that is 32 MiB a buffer, which the keye-vl-2.0-30b-a3b step has room for
-# beside its state; the one value that has run on the chip (PERF.md §5).
+# Tokens a dropless layer routes at a time (fewer where a call has fewer):
+# the one value that has run on the chip (PERF.md §5).
 CHUNK = 1024
+# A pass's row buffers hold this many times the rows a chunk's held experts
+# expect (``chunk * top_k * held / n_experts``). Seeded routing sends a
+# chunk 0.5-1.4 times its expectation, and one chunk of 1920 read 1.98
+# (PERF.md §6, PR 32), so at 2 a chunk all but never needs a second pass,
+# and every buffer of the model width is still a quarter of the worst case
+# where an eighth of the experts is held.
+HELD_SLACK = 2
+
+
+def rows_buffer(chunk: int, top_k: int, held: int, n_experts: int) -> int:
+    """Rows a pass of the dropless layer works on, from shapes alone:
+    ``HELD_SLACK`` times the chunk's expected held rows, in whole
+    ``ops.gmm.ROW_TILE`` (whole 8 below one tile), never more than the
+    ``chunk * top_k`` rows there are — which is what a layer that holds
+    every expert gets."""
+    want = -(-HELD_SLACK * chunk * top_k * held // n_experts)
+    tile = ROW_TILE if want >= ROW_TILE else 8
+    return min(-(-want // tile) * tile, chunk * top_k)
+
+
+def _one_pass(p, acc, xc, gates, weights, order, sizes, rows, quant):
+    """``acc [chunk, D]`` float32 plus what the rows at the sorted
+    positions ``[p * rows, (p + 1) * rows)`` give: ``xc [chunk, D]`` the
+    chunk's tokens, ``gates [chunk, k]``, ``order`` its ``chunk * k``
+    routed rows sorted by held expert (padded to whole passes) and
+    ``sizes [held]`` the groups' sizes. An expert whose rows straddle the
+    window's edge is a group here and a group in the next pass."""
+    w_gate, w_up, w_down = weights
+    rows_in = (lambda a: _int8_image(a, 1)) if quant else (lambda a: a)
+    with jax.named_scope("moe_dispatch"):
+        ends = jnp.cumsum(sizes)
+        window = lambda at: jnp.clip(at, p * rows, (p + 1) * rows)
+        sizes = (window(ends) - window(ends - sizes)).astype(jnp.int32)
+        at = jax.lax.dynamic_slice(order, (p * rows,), (rows,))
+        token = at // gates.shape[1]
+        # Past the last group a grouped kernel leaves what it found
+        # (forward and backward): those rows are NAMED zero on the way in
+        # and on the way out, never multiplied by it.
+        live = (jnp.arange(rows) < sizes.sum())[:, None]
+        xs = jnp.where(live, jnp.take(xc, token, axis=0), 0)
+    with jax.named_scope("moe_experts"):
+        xs = rows_in(xs)
+        h = nn.silu(grouped_matmul(xs, w_gate, sizes)) \
+            * grouped_matmul(xs, w_up, sizes)
+        out = grouped_matmul(rows_in(h), w_down, sizes)
+    with jax.named_scope("moe_combine"):
+        out = jnp.where(live, out, 0)
+        gate = jnp.take(gates.reshape(-1), at).astype(out.dtype)
+        part = out.astype(jnp.float32) * gate.astype(jnp.float32)[:, None]
+        if acc is None:
+            acc = jnp.zeros(xc.shape, jnp.float32)
+        return acc.at[token].add(part)
+
+
+def _passes(sizes, rows):
+    """Passes a chunk with groups ``sizes`` needs: one, and one more for
+    every further ``rows`` rows it holds."""
+    return jnp.maximum(1, -(-sizes.sum() // rows)).astype(jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_rows(rows, quant, xc, gates, weights, order, sizes):
+    """``y [chunk, D]`` float32: the chunk's held rows through their
+    experts, ``rows`` sorted rows a pass. The first pass always runs; the
+    loop after it has a trip count the routing gives, so it is a real
+    branch on the chip and a pass that has no rows costs nothing.
+    Differentiated by hand for that loop's sake, and because a ``lax.cond``
+    a pass would write a skipped pass's zero weight gradients out in full
+    (three ``[held, D, F]`` arrays a pass a chunk): here the gradients of
+    the further passes are added where the first pass's are, in the loop's
+    carry."""
+    one = functools.partial(_one_pass, xc=xc, gates=gates, weights=weights,
+                            order=order, sizes=sizes, rows=rows, quant=quant)
+    return jax.lax.fori_loop(1, _passes(sizes, rows), one, one(0, None))
+
+
+def _held_rows_fwd(rows, quant, *args):
+    return _held_rows(rows, quant, *args), args
+
+
+def _held_rows_bwd(rows, quant, saved, dy):
+    xc, gates, weights, order, sizes = saved
+
+    def grads(p):
+        one = functools.partial(_one_pass, p, None, order=order, sizes=sizes,
+                                rows=rows, quant=quant)
+        return jax.vjp(one, xc, gates, weights)[1](dy)
+
+    total = jax.lax.fori_loop(
+        1, _passes(sizes, rows),
+        lambda p, total: jax.tree.map(jnp.add, total, grads(p)), grads(0))
+    return (*total, None, None)
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 
 
 class DroplessMoE(nn.Module):
@@ -228,17 +328,20 @@ class DroplessMoE(nn.Module):
 
     Input ``[B, T, D]``; the output is ``sum_{e in top_k(t), e held}
     gate[t, e] * FFN_e(x_t)`` — with every expert held, the whole layer.
-    Tokens are taken :data:`CHUNK` at a time: a chunk's sorted rows are
-    sized for the worst case (every one of its ``CHUNK * top_k`` routed
-    rows held here), the grouped matmul computes only the rows that are,
-    and a chunk is recomputed in the backward instead of kept.
+    Tokens are taken :data:`CHUNK` at a time: a chunk's routed rows are
+    sorted by held expert, and everything of the model or expert width
+    works on :func:`rows_buffer` of them a pass (all of them where every
+    expert is held); the grouped matmul computes only the rows that are
+    there, a chunk sent more than a buffer takes further passes, and a
+    chunk is recomputed in the backward instead of kept.
 
     Device scopes ``moe`` > ``moe_route``, ``moe_dispatch``,
     ``moe_experts``, ``moe_combine``. With the ``stats`` collection
     mutable (a train step's is), ``moe_rows_held``,
-    ``moe_rows_max_expert`` and ``moe_groups_fed`` (the (chunk, held
-    expert) pairs that got a row) of the call are sown (what a dropless
-    layer has to carry: nothing bounds them but the routing)."""
+    ``moe_rows_max_expert``, ``moe_groups_fed`` (the (chunk, held
+    expert) pairs that got a row) and ``moe_passes_run`` (the chunk count
+    when no chunk overflowed its buffer) of the call are sown (what a
+    dropless layer has to carry: nothing bounds them but the routing)."""
     dim: int
     ffn_hidden: int
     n_experts: int
@@ -259,9 +362,6 @@ class DroplessMoE(nn.Module):
         if not 0 <= self.expert_offset <= e - held:
             raise ValueError(f"experts [{self.expert_offset}, "
                              f"{self.expert_offset + held}) of {e}")
-        profiler.count_once("moe:experts_total", e)
-        profiler.count_once("moe:experts_held", held)
-        profiler.count_once("moe:top_k", k)
         wr = self.param("w_router", nn.with_logical_partitioning(
             nn.initializers.lecun_normal(), ("embed", "expert_dim")),
             (d, e), jnp.float32)
@@ -269,21 +369,23 @@ class DroplessMoE(nn.Module):
             name, nn.with_logical_partitioning(
                 nn.initializers.lecun_normal(batch_axis=(0,)), logical),
             shape, jnp.float32).astype(self.dtype)
-        w_gate = stacked("w_gate", (held, d, f), ("expert", "embed", "ffn"))
-        w_up = stacked("w_up", (held, d, f), ("expert", "embed", "ffn"))
-        w_down = stacked("w_down", (held, f, d), ("expert", "ffn", "embed"))
-
+        weights = (
+            stacked("w_gate", (held, d, f), ("expert", "embed", "ffn")),
+            stacked("w_up", (held, d, f), ("expert", "embed", "ffn")),
+            stacked("w_down", (held, f, d), ("expert", "ffn", "embed")))
         if self.quant:
-            w_gate, w_up, w_down = (_int8_image(w, 1)
-                                    for w in (w_gate, w_up, w_down))
-        rows_in = (lambda a: _int8_image(a, 1)) if self.quant else (
-            lambda a: a)
+            weights = tuple(_int8_image(w, 1) for w in weights)
 
         n = b * t
         chunk = min(CHUNK, n)
         if n % chunk:
             raise ValueError(f"{n} tokens are not whole chunks of {chunk}")
-        rows = x.reshape(n // chunk, chunk, d)
+        rows = rows_buffer(chunk, k, held, e)
+        passes = -(-chunk * k // rows)
+        for name, fact in (("experts_total", e), ("experts_held", held),
+                           ("top_k", k), ("rows_buffer", rows),
+                           ("passes_max", passes), ("chunks", n // chunk)):
+            profiler.count_once("moe:" + name, fact)
 
         @jax.checkpoint
         def one_chunk(xc):
@@ -294,34 +396,18 @@ class DroplessMoE(nn.Module):
                 # Rows of experts held elsewhere sort behind every group.
                 local = jnp.where(mine, local, held).reshape(-1)
             with jax.named_scope("moe_dispatch"):
-                order = jnp.argsort(local, stable=True)
+                order = jnp.pad(jnp.argsort(local, stable=True),
+                                (0, passes * rows - chunk * k))
                 sizes = jnp.bincount(local, length=held + 1)[:held].astype(
                     jnp.int32)
-                # Past the last group a grouped kernel leaves what it found
-                # (forward and backward): those rows are NAMED zero on the
-                # way in and on the way out, never multiplied by it.
-                live = (jnp.arange(chunk * k) < sizes.sum())[:, None]
-                xs = jnp.where(live, jnp.take(xc, order // k, axis=0), 0)
-            with jax.named_scope("moe_experts"):
-                xs = rows_in(xs)
-                h = nn.silu(grouped_matmul(xs, w_gate, sizes)) \
-                    * grouped_matmul(xs, w_up, sizes)
-                out = grouped_matmul(rows_in(h), w_down, sizes)
-            with jax.named_scope("moe_combine"):
-                out = jnp.where(live, out, 0)
-                back = jnp.zeros_like(order).at[order].set(
-                    jnp.arange(chunk * k, dtype=order.dtype),
-                    unique_indices=True)
-                out = jnp.take(out, back, axis=0, unique_indices=True)
-                y = jnp.einsum("tkd,tk->td", out.reshape(chunk, k, d),
-                               jnp.where(mine, gates, 0.0).astype(out.dtype),
-                               preferred_element_type=jnp.float32)
-            return y.astype(x.dtype), sizes
+            y = _held_rows(rows, self.quant, xc, gates, weights, order, sizes)
+            return y.astype(x.dtype), sizes, _passes(sizes, rows)
 
-        y, sizes = jax.lax.map(one_chunk, rows)
+        y, sizes, ran = jax.lax.map(one_chunk, x.reshape(n // chunk, chunk, d))
         if self.is_mutable_collection("stats"):
             per_expert = sizes.sum(axis=0)
             self.sow("stats", "moe_rows_held", per_expert.sum())
             self.sow("stats", "moe_rows_max_expert", per_expert.max())
             self.sow("stats", "moe_groups_fed", (sizes > 0).sum())
+            self.sow("stats", "moe_passes_run", ran.sum())
         return y.reshape(b, t, d)
