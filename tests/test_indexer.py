@@ -31,7 +31,7 @@ def small_blocks():
     indexer a sequence, four chunks of the expert layer a batch."""
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(indexer, "ROW_BLOCK", 32)
-        patch.setattr(moe, "CHUNK", 32)
+        patch.setattr(moe, "chunk_tokens", lambda top_k, n_experts: 32)
         yield
 
 

@@ -18,6 +18,7 @@ from tony_tpu.models.moe import DroplessMoE
 
 D, F, E, K, T = 32, 16, 16, 4, 96
 CFG = {"top_k": K}
+CHUNK_RULE = moe.chunk_tokens       # the fixture below patches the name
 
 
 def _weights(seed, router_scale=1.0):
@@ -30,7 +31,7 @@ def _weights(seed, router_scale=1.0):
 
 @pytest.fixture(autouse=True)
 def three_chunks(monkeypatch):
-    monkeypatch.setattr(moe, "CHUNK", T // 3)
+    monkeypatch.setattr(moe, "chunk_tokens", lambda top_k, n_experts: T // 3)
 
 
 def _share(x, w, held, offset):
@@ -237,7 +238,7 @@ def _routed(per_expert, seed):
 
 @pytest.fixture
 def overflowing(request, monkeypatch):
-    monkeypatch.setattr(moe, "CHUNK", 96)
+    monkeypatch.setattr(moe, "chunk_tokens", lambda top_k, n_experts: 96)
     per_expert, passes = OVERFLOW[request.param]
     x = jnp.asarray(np.concatenate([_routed(per_expert, 7),
                                     _routed((20, 10, 5, 5), 8)]),
@@ -319,7 +320,7 @@ def test_an_overflowing_chunk_through_the_kernels(overflowing, monkeypatch):
 
 def test_every_expert_held_is_one_pass_over_every_row(layer_inputs):
     x, w = layer_inputs
-    chunk = moe.CHUNK
+    chunk = moe.chunk_tokens(K, E)
     assert moe.rows_buffer(chunk, K, E, E) == chunk * K
     assert moe.rows_buffer(1024, 8, 128, 128) == 8192
     _, stats = _share(x, w, 0, 0)
@@ -339,10 +340,11 @@ def _shapes_in(jaxpr):
 
 def test_no_worst_case_buffer_at_the_keye_shapes(monkeypatch):
     """Trace only, nothing computed: at 16384 tokens of width 2048, 16 of
-    128 experts held, 8 a token, no array of ``CHUNK * top_k`` rows by the
-    model or the expert width is in the layer's forward or backward, and a
-    pass's buffers are 2048 rows."""
-    monkeypatch.setattr(moe, "CHUNK", 1024)
+    128 experts held, 8 a token (a chunk of 1024 by ``moe.chunk_tokens``),
+    no array of ``chunk * top_k`` rows by the model or the expert width is
+    in the layer's forward or backward, and a pass's buffers are 2048
+    rows."""
+    monkeypatch.setattr(moe, "chunk_tokens", CHUNK_RULE)
     d, f, chunk_rows = 2048, 768, 1024 * 8
     layer = DroplessMoE(d, f, 128, top_k=8, experts_held=16)
     x = jax.ShapeDtypeStruct((1, 16384, d), jnp.bfloat16)

@@ -42,7 +42,7 @@ def _one(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _packed(grad, hkv, t=T):
+def _packed(grad, hkv, t=T, h=H):
     """The packed call with no blocks named: what the kernels' rule picks
     for the length must compile and fit VMEM — K/V resident up to 4096
     (blocks of 512), streamed at 8192 (1024); the batch shrinks as the
@@ -50,12 +50,12 @@ def _packed(grad, hkv, t=T):
     from tony_tpu.ops import flash_attention_packed
 
     def fwd(q, k, v):
-        return flash_attention_packed(q, k, v, H, causal=True,
+        return flash_attention_packed(q, k, v, h, causal=True,
                                       interpret=False)
 
     def build(topo):
-        sh, b = _one(topo), B * T // t
-        q = jax.ShapeDtypeStruct((b, t, H * D), jnp.bfloat16, sharding=sh)
+        sh, b = _one(topo), max(1, B * T // t)
+        q = jax.ShapeDtypeStruct((b, t, h * D), jnp.bfloat16, sharding=sh)
         kv = jax.ShapeDtypeStruct((b, t, hkv * D), jnp.bfloat16,
                                   sharding=sh)
         if not grad:
@@ -170,12 +170,14 @@ def _head_probs(topo):
         (q, k, lse, sel))
 
 
-def _grouped_experts(grad):
+def _grouped_experts(grad, rows=8192, experts=16, ffn=768):
     """The dropless layer's grouped matmul (``ops.gmm``) at one chunk's
     worst case, 8192 sorted rows over 16 experts: into the expert width
     (2048 x 768, as ``w_gate`` / ``w_up``) and back (768 x 2048, as
     ``w_down``); with ``grad`` the transposed and the weight-gradient
-    kernels too."""
+    kernels too. (4096 rows over 8 experts of 2048 x 2048: the zaya1-8b
+    cell's chunk, whose weight blocks are the widest any cell hands the
+    kernels.)"""
     from tony_tpu.ops.gmm import grouped_matmul
 
     def fwd(x, w_in, w_out, sizes):
@@ -184,12 +186,12 @@ def _grouped_experts(grad):
 
     def build(topo):
         sh = _one(topo)
-        x = jax.ShapeDtypeStruct((8192, 2048), jnp.bfloat16, sharding=sh)
-        w_in = jax.ShapeDtypeStruct((16, 2048, 768), jnp.bfloat16,
+        x = jax.ShapeDtypeStruct((rows, 2048), jnp.bfloat16, sharding=sh)
+        w_in = jax.ShapeDtypeStruct((experts, 2048, ffn), jnp.bfloat16,
                                     sharding=sh)
-        w_out = jax.ShapeDtypeStruct((16, 768, 2048), jnp.bfloat16,
+        w_out = jax.ShapeDtypeStruct((experts, ffn, 2048), jnp.bfloat16,
                                      sharding=sh)
-        sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=sh)
+        sizes = jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=sh)
         if not grad:
             return fwd, (x, w_in, w_out, sizes)
         return jax.grad(lambda x, a, b, s: fwd(x, a, b, s).astype(
@@ -204,6 +206,10 @@ CASES = {
     "head_probs_1024x16384": _head_probs,
     "grouped_experts_fwd_8192x16": _grouped_experts(grad=False),
     "grouped_experts_fwd_bwd_8192x16": _grouped_experts(grad=True),
+    "grouped_experts_fwd_bwd_4096x8_wide": _grouped_experts(
+        grad=True, rows=4096, experts=8, ffn=2048),
+    "flash_packed_fwd_bwd_latent8over2_t32768": _packed(
+        grad=True, hkv=2, t=32768, h=8),
     "flash_packed_fwd_mha": _packed(grad=False, hkv=H),
     "flash_packed_fwd_bwd_mha": _packed(grad=True, hkv=H),
     "flash_packed_fwd_bwd_gqa8": _packed(grad=True, hkv=HKV),

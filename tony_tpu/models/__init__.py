@@ -36,8 +36,8 @@ def register(name: str):
 def get_model(name: str, **kw):
     """Build a registered model by name (``resnet50``, ``llama2-7b``,
     ``llama-tiny``, ``mixtral-8x7b``, ``keye-vl-2.0-30b-a3b``,
-    ``keye-tiny``, ``hybrid-decoder``, ``hybrid-tiny``, ``mnist-mlp``,
-    ``mnist-cnn``)."""
+    ``keye-tiny``, ``zaya1-8b``, ``zaya-tiny``, ``hybrid-decoder``,
+    ``hybrid-tiny``, ``mnist-mlp``, ``mnist-cnn``)."""
     # Import for registration side effects.
     from tony_tpu.models import (hybrid, mnist, resnet,  # noqa: F401
                                  transformer)

@@ -20,10 +20,12 @@ spread over an ``expert`` mesh axis (``mixtral-8x7b``, ``llama-moe-tiny``):
 
 :class:`DroplessMoE` — **no token dropped**, for many fine-grained experts
 of which this chip holds a contiguous range (``keye-vl-2.0-30b-a3b``: 16 of
-128): the layer routes over all ``n_experts``, is told which it holds
-(``experts_held``, ``expert_offset``) and returns the part of the result
-its own experts give. The one-hot form is ``tokens x experts x capacity``
-and cannot be the path at 128 experts: rows are **sorted by expert**, the
+128; ``zaya1-8b``: 8 of 16 wide ones, one a token, chosen by
+:class:`MLPRouter`): the layer routes over all ``n_experts``, is told which
+it holds (``experts_held``, ``expert_offset``) and returns the part of the
+result its own experts give. The one-hot form is ``tokens x experts x
+capacity`` and cannot be the path at 128 experts: rows are **sorted by
+expert**, the
 experts run as one grouped matmul over ragged row groups
 (:func:`tony_tpu.ops.gmm.grouped_matmul`: on the TPU Pallas kernels that
 walk the groups and cost the rows that are there; XLA's own
@@ -200,12 +202,72 @@ def route_top_k(x: jax.Array, w_router: jax.Array, top_k: int):
     """``(experts [N, k] int32, gates [N, k] float32)`` of the rows
     ``x [N, D]``: softmax over every expert in float32 (the products too:
     an expert that flips on rounding changes a token's whole output),
-    the ``top_k`` largest, renormalised over those."""
+    the ``top_k`` largest, renormalised over those. Not for ``top_k`` 1:
+    a single gate renormalised is the constant 1 and the router would
+    learn nothing (:class:`MLPRouter` gates by the probability itself)."""
+    if top_k == 1:
+        raise ValueError("route_top_k renormalises the chosen gates: at "
+                         "top_k=1 every gate is 1.0 and the router gets no "
+                         "gradient; use a router that gates by the "
+                         "probability (router_hidden)")
     logits = jnp.dot(x.astype(jnp.float32), w_router,
                      precision=jax.lax.Precision.HIGHEST)
     gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
     gates = gates / gates.sum(axis=-1, keepdims=True)
     return experts.astype(jnp.int32), gates
+
+
+def route_mlp(p: dict, y: jax.Array, r_prev: jax.Array, top_k: int,
+              eps: float):
+    """``(experts [N, k] int32, gates [N, k] float32, r [N, hidden])`` of
+    the rows ``y [N, D]`` and the router state ``r_prev [N, hidden]`` of
+    the layer before, through the router ``p`` (:class:`MLPRouter`'s
+    parameters): ``r = y W_d + b_d + gamma * r_prev``, ``prob =
+    softmax(W_3 gelu(W_2 gelu(W_1 RMSNorm(r) + b_1) + b_2))`` over every
+    expert, the ``top_k`` most probable chosen (ties to the lower index)
+    and **gated by their probability, not renormalised** — so the one
+    expert of ``top_k`` 1 still gives the router a gradient. float32
+    throughout, the products too (as :func:`route_top_k`: an expert that
+    flips on rounding changes a token's whole output)."""
+    dot = lambda a, w: jnp.dot(a, w, precision=jax.lax.Precision.HIGHEST)
+    r = dot(y.astype(jnp.float32), p["w_down"]) + p["b_down"] \
+        + p["gamma"] * r_prev
+    z = r * jax.lax.rsqrt(jnp.mean(r * r, axis=-1, keepdims=True) + eps) \
+        * p["norm"]
+    for i in ("1", "2"):
+        z = jax.nn.gelu(dot(z, p["w" + i]) + p["b" + i], approximate=False)
+    gates, experts = jax.lax.top_k(
+        jax.nn.softmax(dot(z, p["w3"]), axis=-1), top_k)
+    return experts.astype(jnp.int32), gates, r
+
+
+class MLPRouter(nn.Module):
+    """The parameters of a router with a state that runs from layer to
+    layer (ZAYA1, arXiv:2511.17127; :func:`route_mlp` is its arithmetic,
+    which the expert layer runs a chunk at a time): a down-projection of
+    the model width to ``hidden`` with a bias, the decay ``gamma`` on the
+    state of the layer before (init 1), an RMSNorm scale, and a three-layer
+    MLP onto ``n_experts`` (biases on the two hidden layers)."""
+    n_experts: int
+    hidden: int
+
+    @nn.compact
+    def __call__(self, dim: int) -> dict:
+        h = self.hidden
+        mat = lambda name, shape: self.param(
+            name, nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), (None, None)), shape,
+            jnp.float32)
+        vec = lambda name, init: self.param(
+            name, nn.with_logical_partitioning(init, (None,)), (h,),
+            jnp.float32)
+        zeros, ones = nn.initializers.zeros, nn.initializers.ones
+        return {"w_down": mat("w_down", (dim, h)),
+                "b_down": vec("b_down", zeros), "gamma": vec("gamma", ones),
+                "norm": vec("norm", ones), "w1": mat("w1", (h, h)),
+                "b1": vec("b1", zeros), "w2": mat("w2", (h, h)),
+                "b2": vec("b2", zeros),
+                "w3": mat("w3", (h, self.n_experts))}
 
 
 def _int8_image(x: jax.Array, axis: int) -> jax.Array:
@@ -222,9 +284,34 @@ def _int8_image(x: jax.Array, axis: int) -> jax.Array:
     return x + jax.lax.stop_gradient(image - x)
 
 
-# Tokens a dropless layer routes at a time (fewer where a call has fewer):
-# the one value that has run on the chip (PERF.md §5).
+# Tokens a dropless layer routes at a time (fewer where a call has fewer)
+# where few experts a token are chosen of many: the value Keye's layer has
+# run at on the chip (PERF.md §5).
 CHUNK = 1024
+# Rows an expert should expect of a chunk, so that its three matrices, read
+# once a chunk, are amortised: the v5e's 240 FLOP a byte (197 TFLOP/s over
+# 819 GB/s; an expert's rows are its FLOPs a weight byte), in a power of
+# two. No more: everything a chunk's backward holds grows with the chunk
+# (0.55 GiB of the compiled step from 4096 to 8192 tokens of width 2048).
+ROWS_AMORTISED = 256
+# Routed rows (``chunk * top_k``) a chunk may sort, gather and scatter:
+# the 8192 of Keye's layer, the most that has run.
+ROUTED_MAX = 8192
+
+
+def chunk_tokens(top_k: int, n_experts: int) -> int:
+    """Tokens a dropless layer routes at a time, from the routing's shape:
+    :data:`CHUNK` doubled until an expert expects :data:`ROWS_AMORTISED`
+    rows of a chunk (``chunk * top_k / n_experts``), but never past
+    :data:`ROUTED_MAX` routed rows. 8 of 128 experts a token: 1024 (64 rows
+    an expert: the cap binds); 1 of 16: 4096 (256)."""
+    chunk = CHUNK
+    while chunk * top_k < ROWS_AMORTISED * n_experts \
+            and 2 * chunk * top_k <= ROUTED_MAX:
+        chunk *= 2
+    return chunk
+
+
 # A pass's row buffers hold this many times the rows a chunk's held experts
 # expect (``chunk * top_k * held / n_experts``). Seeded routing sends a
 # chunk 0.5-1.4 times its expectation, and one chunk of 1920 read 1.98
@@ -328,16 +415,18 @@ class DroplessMoE(nn.Module):
 
     Input ``[B, T, D]``; the output is ``sum_{e in top_k(t), e held}
     gate[t, e] * FFN_e(x_t)`` — with every expert held, the whole layer.
-    Tokens are taken :data:`CHUNK` at a time: a chunk's routed rows are
-    sorted by held expert, and everything of the model or expert width
-    works on :func:`rows_buffer` of them a pass (all of them where every
-    expert is held); the grouped matmul computes only the rows that are
-    there, a chunk sent more than a buffer takes further passes, and a
-    chunk is recomputed in the backward instead of kept.
+    The router is a ``[D, E]`` matrix (:func:`route_top_k`) or, with
+    ``router_hidden``, an :class:`MLPRouter` whose state the call takes
+    and returns. Tokens are taken :func:`chunk_tokens` at a time: a
+    chunk's routed rows are sorted by held expert, and everything of the
+    model or expert width works on :func:`rows_buffer` of them a pass (all
+    of them where every expert is held); the grouped matmul computes only
+    the rows that are there, a chunk sent more than a buffer takes further
+    passes, and a chunk is recomputed in the backward instead of kept.
 
-    Device scopes ``moe`` > ``moe_route``, ``moe_dispatch``,
-    ``moe_experts``, ``moe_combine``. With the ``stats`` collection
-    mutable (a train step's is), ``moe_rows_held``,
+    Device scopes ``moe`` > ``moe_router`` (an MLP router), ``moe_route``,
+    ``moe_dispatch``, ``moe_experts``, ``moe_combine``. With the ``stats``
+    collection mutable (a train step's is), ``moe_rows_held``,
     ``moe_rows_max_expert``, ``moe_groups_fed`` (the (chunk, held
     expert) pairs that got a row) and ``moe_passes_run`` (the chunk count
     when no chunk overflowed its buffer) of the call are sown (what a
@@ -352,19 +441,40 @@ class DroplessMoE(nn.Module):
     # The int8 lane (``TransformerConfig.quant``'s "mlp"): the operands of
     # the three grouped matmuls take their int8 image first.
     quant: bool = False
+    # >0: the router is an :class:`MLPRouter` of this width in place of the
+    # ``[D, E]`` matrix; the call then takes the router state of the layer
+    # before and returns ``(y, router_state)``.
+    router_hidden: int = 0
+    norm_eps: float = 1e-5
 
     @nn.compact
     @jax.named_scope("moe")
-    def __call__(self, x):
+    def __call__(self, x, router_state=None):
         b, t, d = x.shape
         e, f, k = self.n_experts, self.ffn_hidden, self.top_k
         held = self.experts_held or e
         if not 0 <= self.expert_offset <= e - held:
             raise ValueError(f"experts [{self.expert_offset}, "
                              f"{self.expert_offset + held}) of {e}")
-        wr = self.param("w_router", nn.with_logical_partitioning(
-            nn.initializers.lecun_normal(), ("embed", "expert_dim")),
-            (d, e), jnp.float32)
+        n = b * t
+        chunk = min(chunk_tokens(k, e), n)
+        if n % chunk:
+            raise ValueError(f"{n} tokens are not whole chunks of {chunk}")
+        if self.router_hidden:
+            router = MLPRouter(e, self.router_hidden, name="router")(d)
+            profiler.count_once("moe:router_hidden", self.router_hidden)
+
+            @jax.named_scope("moe_router")
+            def route(xc, rc):
+                return route_mlp(router, xc, rc, k, self.norm_eps)
+        else:
+            wr = self.param("w_router", nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), ("embed", "expert_dim")),
+                (d, e), jnp.float32)
+
+            @jax.named_scope("moe_route")
+            def route(xc, rc):
+                return (*route_top_k(xc, wr, k), rc)
         stacked = lambda name, shape, logical: self.param(
             name, nn.with_logical_partitioning(
                 nn.initializers.lecun_normal(batch_axis=(0,)), logical),
@@ -376,21 +486,21 @@ class DroplessMoE(nn.Module):
         if self.quant:
             weights = tuple(_int8_image(w, 1) for w in weights)
 
-        n = b * t
-        chunk = min(CHUNK, n)
-        if n % chunk:
-            raise ValueError(f"{n} tokens are not whole chunks of {chunk}")
         rows = rows_buffer(chunk, k, held, e)
         passes = -(-chunk * k // rows)
         for name, fact in (("experts_total", e), ("experts_held", held),
                            ("top_k", k), ("rows_buffer", rows),
-                           ("passes_max", passes), ("chunks", n // chunk)):
+                           ("passes_max", passes), ("chunks", n // chunk),
+                           ("chunk", chunk)):
             profiler.count_once("moe:" + name, fact)
 
         @jax.checkpoint
-        def one_chunk(xc):
+        def one_chunk(xc, rc):
+            # The router too works a chunk at a time, as everything of the
+            # model width in this layer: a float32 copy of the rows and its
+            # cotangent are a chunk's, not the call's.
+            experts, gates, rc = route(xc, rc)
             with jax.named_scope("moe_route"):
-                experts, gates = route_top_k(xc, wr, k)
                 local = experts - self.expert_offset
                 mine = (local >= 0) & (local < held)
                 # Rows of experts held elsewhere sort behind every group.
@@ -401,13 +511,20 @@ class DroplessMoE(nn.Module):
                 sizes = jnp.bincount(local, length=held + 1)[:held].astype(
                     jnp.int32)
             y = _held_rows(rows, self.quant, xc, gates, weights, order, sizes)
-            return y.astype(x.dtype), sizes, _passes(sizes, rows)
+            return y.astype(x.dtype), sizes, _passes(sizes, rows), rc
 
-        y, sizes, ran = jax.lax.map(one_chunk, x.reshape(n // chunk, chunk, d))
+        if router_state is not None:
+            router_state = router_state.reshape(n // chunk, chunk, -1)
+        y, sizes, ran, router_state = jax.lax.map(
+            lambda a: one_chunk(*a),
+            (x.reshape(n // chunk, chunk, d), router_state))
         if self.is_mutable_collection("stats"):
             per_expert = sizes.sum(axis=0)
             self.sow("stats", "moe_rows_held", per_expert.sum())
             self.sow("stats", "moe_rows_max_expert", per_expert.max())
             self.sow("stats", "moe_groups_fed", (sizes > 0).sum())
             self.sow("stats", "moe_passes_run", ran.sum())
-        return y.reshape(b, t, d)
+        y = y.reshape(b, t, d)
+        if router_state is None:
+            return y
+        return y, router_state.reshape(b, t, -1)

@@ -90,6 +90,24 @@ class TransformerConfig:
     moe_dropless: bool = False
     moe_experts_held: int = 0
     moe_expert_offset: int = 0
+    # Attention in a compressed latent with convolutional q/k mixing
+    # (tony_tpu.ops.cca; arXiv:2510.04476): q, k, v are n_heads /
+    # n_kv_heads x attn_head_dim wide (less than dim), the second half of
+    # v reads the token before, q and k pass a depthwise causal
+    # convolution of cca_taps[0] taps and a per-head one of cca_taps[1],
+    # take each other's mean, are L2-normalised (k times a learned
+    # temperature a head) and rotated.
+    attn_latent: bool = False
+    cca_taps: Tuple[int, int] = (2, 2)
+    # Share of each head's width that is rotated (the leading dims).
+    rope_fraction: float = 1.0
+    # >0: the dropless experts' router is an MLP of this width
+    # (models.moe.MLPRouter) whose state runs from layer to layer: the
+    # layer stack carries (x, router_state); the gate is the softmax
+    # probability itself.
+    router_hidden: int = 0
+    # One table: the head reads the embedding transposed.
+    tie_embeddings: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -121,7 +139,10 @@ class TransformerConfig:
         contributes zero matmul FLOPs, so only the unembed projection
         counts toward the vocab term. For MoE, only the top-k experts' FFN
         params are active per token — of a held range, the share of them
-        that an even routing sends here. With an indexer: its three
+        that an even routing sends here; a router MLP counts its four
+        matrices, latent attention its per-head convolution (the depthwise
+        one is no matmul). A tied head is still one multiplied table. With
+        an indexer: its three
         projections, its scores over the causal half (forward and
         backward), and attention over the selected pairs only (a token
         sees min(position + 1, index_topk) keys)."""
@@ -129,16 +150,20 @@ class TransformerConfig:
         if self.moe_experts > 0:
             share = (self.moe_experts_held or self.moe_experts) \
                 / self.moe_experts if self.moe_dropless else 1.0
-            ffn_active = int(self.moe_top_k * share * ffn_active
-                             + self.dim * self.moe_experts)  # + router
+            h = self.router_hidden
+            router = self.dim * h + 2 * h * h + h * self.moe_experts \
+                if h else self.dim * self.moe_experts
+            ffn_active = int(self.moe_top_k * share * ffn_active + router)
         qdim = self.n_heads * self.head_dim
+        mix = self.cca_taps[1] * self.head_dim ** 2 * (
+            self.n_heads + self.n_kv_heads) if self.attn_latent else 0
         n_params = (
             self.vocab * self.dim  # unembed only; embed gather = 0 matmul FLOPs
             + self.n_layers * (
                 self.dim * self.head_dim
                 * (self.n_heads + 2 * self.n_kv_heads)   # wq, wk, wv
                 + qdim * self.dim                          # wo
-                + ffn_active))
+                + mix + ffn_active))
         t = self.max_seq
         if not self.index_heads:
             return 6 * n_params + 12 * self.n_layers * qdim * t
@@ -152,11 +177,20 @@ class TransformerConfig:
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float,
-         seq_axis: int = 2) -> jax.Array:
+         seq_axis: int = 2, fraction: float = 1.0) -> jax.Array:
     """Rotary embedding with positions [T] (shared across the batch) or
     [B, T] (per-sequence absolute positions — the serving plane's decode
     rows sit at different depths per sequence); the sequence dim sits at
-    ``seq_axis`` (2 for [B, H, T, D], 1 for the packed [B, T, H, D])."""
+    ``seq_axis`` (2 for [B, H, T, D], 1 for the packed [B, T, H, D]).
+    ``fraction`` < 1 rotates only that leading share of the last dim (its
+    frequencies span the rotated width) and passes the rest through."""
+    if fraction < 1.0:
+        rot = int(x.shape[-1] * fraction)
+        # packsite: region-local — the rotated and the passed share of one
+        # head's own lanes; no sharded dim is joined.
+        return jnp.concatenate(
+            [rope(x[..., :rot], positions, theta, seq_axis), x[..., rot:]],
+            axis=-1)
     d = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     if positions.ndim == 2:
@@ -254,6 +288,11 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, positions, kv=None):
         cfg = self.cfg
+        if cfg.attn_latent:
+            if kv is not None or cfg.mesh is not None:
+                raise ValueError("latent attention has no serve mode and "
+                                 "no sharded path")
+            return self._latent(x, positions)
         b, t, _ = x.shape
         hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
         # Fused-head projections with rank-2 kernels: (embed, heads·hd)
@@ -378,6 +417,63 @@ class Attention(nn.Module):
         out = out.transpose(0, 2, 1, 3).reshape(b, t, nh * hd)
         return wo(out)
 
+    def _latent(self, x, positions):
+        """Training-mode attention in a compressed latent
+        (tony_tpu.ops.cca): the packed flash kernel over ``H·d`` query and
+        ``G·d`` key/value columns, fewer than ``dim``. Device scopes
+        ``cca_proj`` (the three projections and v's shifted half) and
+        ``cca_mix`` (both convolutions, the means, norms, rotation)."""
+        from tony_tpu import profiler
+        from tony_tpu.ops import cca, flash_attention_packed
+
+        cfg = self.cfg
+        b, t, _ = x.shape
+        hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+        dense = lambda feats, logical, name, lane: _proj_dense(
+            cfg, lane, feats, logical, name)
+        with jax.named_scope("cca_proj"):
+            q_raw = dense(nh * hd, ("embed", "heads"), "wq", "qkv")(x)
+            k_raw = dense(nkv * hd, ("embed", "kv_heads"), "wk", "qkv")(x)
+            v = dense(nkv * hd, ("embed", "kv_heads"), "wv", "qkv")(x)
+            # The second half of v's heads reads the token before:
+            # x_{t-1} W = (x W)_{t-1}.
+            late = jnp.arange(nkv * hd) >= (nkv - nkv // 2) * hd
+            v = remat.name(jnp.where(late, cca.shift1(v), v), "v")
+        taps0, taps1 = cfg.cca_taps
+        param = lambda name, init, shape: self.param(
+            name, nn.with_logical_partitioning(init, (None,) * len(shape)),
+            shape, jnp.float32)
+        normal = lambda fan_in: nn.initializers.normal(fan_in ** -0.5)
+        mix_params = (
+            param("conv0_w", normal(taps0), (taps0, (nh + nkv) * hd)),
+            param("conv0_b", nn.initializers.zeros, ((nh + nkv) * hd,)),
+            param("conv1_w", normal(taps1 * hd), (taps1, nh + nkv, hd, hd)),
+            param("conv1_b", nn.initializers.zeros, ((nh + nkv) * hd,)),
+            param("tau", nn.initializers.ones, (nkv,)))
+
+        # Recomputed in its own backward, whatever the block keeps: a
+        # dozen float32 [T, H d] links of an elementwise chain that XLA
+        # fuses away when it meets them together, and holds (1.3 GiB at
+        # 32k tokens) when the forward hands them to the backward.
+        @jax.checkpoint
+        @jax.named_scope("cca_mix")
+        def mixed(q_raw, k_raw, mix_params):
+            q4, k4 = cca.mix(q_raw, k_raw, *mix_params, n_heads=nh,
+                             n_kv_heads=nkv, eps=cfg.norm_eps)
+            return tuple(
+                rope(a, positions, cfg.rope_theta, seq_axis=1,
+                     fraction=cfg.rope_fraction).astype(cfg.dtype).reshape(
+                         b, t, -1) for a in (q4, k4))
+
+        q3, k3 = mixed(q_raw, k_raw, mix_params)
+        for name, n in (("heads", nh), ("kv_heads", nkv), ("taps", taps0)):
+            profiler.count_once("cca:" + name, n)
+        if hd % 128 == 0:
+            _count_blocks(t, hd, v.dtype.itemsize)
+        out = flash_attention_packed(remat.name(q3, "q"),
+                                     remat.name(k3, "k"), v, nh, causal=True)
+        return remat.name(
+            dense(cfg.dim, ("heads", "embed"), "wo", "o")(out), "wo")
 
     def _selected(self, x, q, k, v, positions):
         """Training-mode attention with q/k-norm and/or a learned
@@ -445,7 +541,15 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, kv=None):
+        """``x`` is the residual stream, or with ``cfg.router_hidden`` the
+        pair ``(x, router_state)`` the layer stack carries; the same comes
+        back."""
         cfg = self.cfg
+        if cfg.router_hidden:
+            if not (cfg.moe_experts > 0 and cfg.moe_dropless):
+                raise ValueError("router_hidden is the dropless experts' "
+                                 "router: set moe_experts and moe_dropless")
+            x, router_state = x
         attn_out = Attention(cfg, name="attn")(
             RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions, kv=kv)
         new_kv = None
@@ -460,6 +564,8 @@ class Block(nn.Module):
                               expert_offset=cfg.moe_expert_offset,
                               dtype=cfg.dtype,
                               quant="mlp" in cfg.quant_lanes(),
+                              router_hidden=cfg.router_hidden,
+                              norm_eps=cfg.norm_eps,
                               name="moe_mlp")
         elif cfg.moe_experts > 0:
             from tony_tpu.models.moe import MoEMLP
@@ -470,6 +576,10 @@ class Block(nn.Module):
                          name="moe_mlp")
         else:
             mlp = MLP(cfg, name="mlp")
+        if cfg.router_hidden:
+            y, router_state = mlp(
+                RMSNorm(cfg.norm_eps, name="mlp_norm")(x), router_state)
+            return x + y, router_state
         x = x + mlp(RMSNorm(cfg.norm_eps, name="mlp_norm")(x))
         if kv is not None:
             return x, new_kv
@@ -513,6 +623,9 @@ class Transformer(nn.Module):
                                  "[b, t] (per-sequence absolute)")
             if cfg.moe_experts > 0:
                 raise ValueError("serve mode does not support MoE blocks")
+        if cfg.tie_embeddings and not cfg.xent_chunk:
+            raise ValueError("tie_embeddings needs the fused head + loss "
+                             "(xent_chunk)")
         embed = self.param("embedding", nn.with_logical_partitioning(
             nn.initializers.normal(0.02), ("vocab", "embed")),
             (cfg.vocab, cfg.dim), jnp.float32)
@@ -554,6 +667,11 @@ class Transformer(nn.Module):
         if positions is None:
             positions = jnp.arange(t)
 
+        if cfg.router_hidden:
+            # The router's state of the layer before; none before the
+            # first: zeros, whatever the first layer's decay.
+            x = (x, jnp.zeros((*tokens.shape, cfg.router_hidden),
+                              jnp.float32))
         block_cls = remat.block(ScannedBlock) if cfg.remat else ScannedBlock
         new_kv = None
         if cfg.scan_layers:
@@ -594,6 +712,8 @@ class Transformer(nn.Module):
             else:
                 for i in range(cfg.n_layers):
                     x, _ = block_cls(cfg, name=f"layer_{i}")(x, positions)
+        if cfg.router_hidden:
+            x, _ = x
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
         if cfg.xent_chunk:
             # Fused head+loss: the kernel is hoisted to this scope (param
@@ -601,8 +721,11 @@ class Transformer(nn.Module):
             # row-chunked CE never materializes full logits. Without
             # targets (init / inference) it degrades to a plain head.
             from tony_tpu.train import chunked_next_token_xent
-            w = self.param("lm_head_kernel", nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), ("embed", "vocab")),
+            # Tied: the table the ids were looked up in, transposed; its
+            # gradient is the lookup's plus the head's.
+            w = embed.T if cfg.tie_embeddings else self.param(
+                "lm_head_kernel", nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(), ("embed", "vocab")),
                 (cfg.dim, cfg.vocab), jnp.float32)
             if targets is not None:
                 return chunked_next_token_xent(x, w, targets,
@@ -687,6 +810,39 @@ def keye_tiny(**kw) -> Transformer:
                     index_heads=2, index_dim=16, index_topk=16,
                     moe_experts=8, moe_top_k=2,
                     moe_dropless=True, attention="reference",
+                    remat=False, xent_chunk=32)
+    defaults.update(kw)
+    return Transformer(TransformerConfig(**defaults))
+
+
+@register("zaya1-8b")
+def zaya1_8b(**kw) -> Transformer:
+    """ZAYA1-8B: attention in a 1024-wide latent (8 query heads over 2
+    key/value heads of 128) with convolutional q/k mixing, a shifted value
+    half and half of each head rotated; 16 experts of width 2048, one a
+    token, chosen by an MLP router of width 256 whose state runs down the
+    stack; one tied 262,272-row table. ``moe_experts_held`` /
+    ``moe_expert_offset`` say which experts live here."""
+    defaults = dict(vocab=262272, dim=2048, n_layers=40, n_heads=8,
+                    n_kv_heads=2, attn_head_dim=128, ffn_hidden=2048,
+                    max_seq=32768, rope_theta=5e6, norm_eps=1e-5,
+                    attn_latent=True, cca_taps=(2, 2), rope_fraction=0.5,
+                    moe_experts=16, moe_top_k=1, moe_dropless=True,
+                    router_hidden=256, tie_embeddings=True, xent_chunk=1024)
+    defaults.update(kw)
+    defaults["cca_taps"] = tuple(defaults["cca_taps"])   # a JSON list
+    return Transformer(TransformerConfig(**defaults))
+
+
+@register("zaya-tiny")
+def zaya_tiny(**kw) -> Transformer:
+    """Test-scale twin of ``zaya1-8b``: the same code path at toy shapes."""
+    defaults = dict(vocab=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
+                    attn_head_dim=8, ffn_hidden=32, max_seq=64,
+                    rope_theta=5e6, norm_eps=1e-5, attn_latent=True,
+                    cca_taps=(2, 2), rope_fraction=0.5, moe_experts=8,
+                    moe_top_k=1, moe_dropless=True, router_hidden=16,
+                    tie_embeddings=True, attention="reference",
                     remat=False, xent_chunk=32)
     defaults.update(kw)
     return Transformer(TransformerConfig(**defaults))
