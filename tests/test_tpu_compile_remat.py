@@ -3,7 +3,8 @@
 attached, nothing runs): what the backward may keep is chosen from these
 very numbers (``tony_tpu.remat``, ISSUE 30), so they are pinned — the
 floor's 13.57 GiB, the rung the rule takes beside a 15.75 GiB limit, and
-that the step with no remat at all is refused."""
+that the step with no remat at all is refused. And the ZAYA1 cell's step
+at the ladder's floor, the fullest step any cell runs (ISSUE 34)."""
 
 import os
 
@@ -16,7 +17,7 @@ import pytest
 from flax.training.train_state import TrainState
 from jax.sharding import SingleDeviceSharding
 
-from benchmark import modelcfg
+from benchmark import modelcfg, modelcfg_zaya1
 from tony_tpu import profiler, remat, train
 from tony_tpu.models import get_model
 
@@ -26,9 +27,7 @@ LIMIT = int(15.75 * GiB)             # memory_stats()["bytes_limit"], v5e
 
 
 @pytest.fixture(scope="module")
-def cell(no_jax_compile_cache):
-    """``step_for(**model_kwargs)`` -> (the cell's step, abstract state and
-    batch on the described chip)."""
+def one_chip(no_jax_compile_cache):
     from jax.experimental import topologies
 
     try:
@@ -36,23 +35,32 @@ def cell(no_jax_compile_cache):
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — any failure = no compiler here
         pytest.skip(f"cannot describe a v5e topology here: {e}")
-    sh = SingleDeviceSharding(topo.devices[0])
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _abstract(model, step, batch, seq, sh):
+    """(the step, abstract state and batch on the described chip)."""
+    abstract = jax.eval_shape(
+        lambda rng: TrainState.create(
+            apply_fn=model.apply, tx=optax.adamw(3e-4),
+            params=model.init(rng, jnp.zeros((batch, seq), jnp.int32))[
+                "params"]), jax.random.PRNGKey(0))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
+    return (step, jax.tree.map(on_chip, abstract),
+            {"x": on_chip(jnp.zeros((batch, seq), jnp.int32))})
+
+
+@pytest.fixture(scope="module")
+def cell(one_chip):
+    """``step_for(**model_kwargs)`` -> the Mistral cell's step."""
     cfg = modelcfg.load("mistral-7b-v0.3")
 
     def step_for(**model_kwargs):
         model = get_model(cfg["program"]["model"], attention="flash",
                           **modelcfg.program_kwargs(cfg, S), **model_kwargs)
-        abstract = jax.eval_shape(
-            lambda rng: TrainState.create(
-                apply_fn=model.apply, tx=optax.adamw(3e-4),
-                params=model.init(rng, jnp.zeros((B, S), jnp.int32))[
-                    "params"]), jax.random.PRNGKey(0))
-        on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                                 sharding=sh)
         step = train.make_train_step(
             loss_of=lambda lg, b: train.next_token_loss(lg, b["x"]))
-        return (step, jax.tree.map(on_chip, abstract),
-                {"x": on_chip(jnp.zeros((B, S), jnp.int32))})
+        return _abstract(model, step, B, S, one_chip)
     return step_for
 
 
@@ -101,3 +109,36 @@ def test_step_with_no_remat_is_refused(cell, on_tpu):
     with pytest.raises(jax.errors.JaxRuntimeError,
                        match="RESOURCE_EXHAUSTED"):
         step.build(remat.Saved()).lower(state, batch).compile()
+
+
+ZAYA_SEQ = 32768                     # benchmark/workloads/zaya1.train-32k
+
+
+def test_zaya1_floor_step_holds_no_more_than_it_did(one_chip, on_tpu):
+    """The chunked head takes its gradient in the forward (ISSUE 34): two
+    more arrays leave the head's loop, none may raise what the step holds.
+    Pinned at the parent's (PR 33): arguments, the program's heap and the
+    outputs that alias nothing, 14,084,792,320 bytes, which is what the
+    compiler reports as the program's HBM (``peak_memory_in_bytes``).
+    ``remat.step_bytes`` reads more, 17,112,940,544 for the parent's
+    16,838,215,168: ``temp_size_in_bytes`` is that heap plus the holes in
+    it, which the heap already spans, and one loop where there were two
+    leaves 0.256 GiB more of them (PERF.md section 7). The floor is taken
+    whatever it reads."""
+    cfg = modelcfg_zaya1.load("zaya1-8b")
+    model = get_model(cfg["program"]["model"],
+                      **modelcfg_zaya1.program_kwargs(cfg, ZAYA_SEQ))
+    step = train.make_train_step(
+        loss_of=lambda loss, b: loss,
+        apply_kwargs_of=lambda b: {"targets": b["x"]})
+    profiler.reset_timeline()
+    step, state, batch = _abstract(model, step, 1, ZAYA_SEQ, one_chip)
+    compiled = step.build(remat.Saved()).lower(state, batch).compile()
+    c = profiler.counters()
+    profiler.reset_timeline()
+    assert (c["head:chunks"], c["head:grad_in_forward"]) == (32, 1)
+    m = compiled.memory_analysis()
+    assert m.peak_memory_in_bytes <= 14_084_792_320
+    # the heap's holes, counted a second time: what the ladder's reading
+    # is over by
+    assert remat.step_bytes(compiled) - m.peak_memory_in_bytes >= 2 << 30

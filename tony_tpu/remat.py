@@ -114,6 +114,13 @@ def name(x: jax.Array, tag: str) -> jax.Array:
     return checkpoint_name(x, tag)
 
 
+def kept() -> Tuple[str, ...]:
+    """The names the step being traced keeps, of those its model has met
+    so far; empty outside a step and at the floor."""
+    active = _ACTIVE.get()
+    return active.effective(active.names) if active is not None else FLOOR
+
+
 def block(layer_cls):
     """``layer_cls`` under ``nn.remat``: its backward recomputes the layer
     from its input, but for the names the step's :class:`Saved` keeps."""

@@ -17,6 +17,7 @@ loop a library so examples and benchmarks share one GSPMD path:
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import time
@@ -52,16 +53,118 @@ def next_token_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
         return cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
 
 
+def _recomputed_xent(rows, wb, labels, weights, r):
+    """Sum of the weighted row losses over ``r``; ``rows`` [n, chunk, d],
+    ``wb`` [d, V], ``labels`` and ``weights`` [n, chunk]. Differentiated by
+    autodiff, the checkpointed body multiplies a chunk's logits a second
+    time in the backward: four products a chunk."""
+    @jax.checkpoint
+    def body(acc, xs):
+        hc, lc, mc = xs
+        with jax.named_scope("lm_head"):
+            logits = (hc @ wb).astype(jnp.float32)      # [chunk, V]
+        with jax.named_scope("loss"):
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            lab = jnp.take_along_axis(logits, lc[:, None], axis=-1)[:, 0]
+            return acc + ((lse - lab) * mc).sum(), None
+
+    total, _ = jax.lax.scan(body, jnp.float32(0.0), (rows, labels, weights))
+    return total / r
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _grad_in_forward_xent(rows, wb, labels, weights, r):
+    """:func:`_recomputed_xent` whose differentiated pass takes both
+    gradients while a chunk's logits are in hand: three products a chunk.
+    Undifferentiated (evaluation, ``eval_shape``) no gradient is formed."""
+    return _recomputed_xent(rows, wb, labels, weights, r)
+
+
+def _grad_in_forward_xent_fwd(rows, wb, labels, weights, r):
+    profiler.count_once("head:grad_in_forward", 1)
+
+    def body(carry, xs):
+        acc, dw = carry
+        hc, lc, mc = xs
+        with jax.named_scope("lm_head"):
+            low, products = jax.vjp(jnp.matmul, hc, wb)     # [chunk, V]
+        with jax.named_scope("loss"):
+            logits = low.astype(jnp.float32)
+            # jax.nn.logsumexp with its parts kept, for d loss / d logits
+            # below to be autodiff's of _recomputed_xent's body operation
+            # for operation: the same float32 numbers, one cast to the
+            # products' dtype.
+            top = jnp.max(logits, axis=-1)
+            top = jnp.where(jnp.isfinite(top), top, 0.0)
+            e = jnp.exp(logits - top[:, None])
+            s = e.sum(axis=-1)
+            lse = jnp.log(s) + top
+            # The label logit is picked before the cast (the same number):
+            # a gather over the float32 logits makes XLA write them out for
+            # it alone, half a GiB a chunk at 1024 x 131,136.
+            lab = jnp.take_along_axis(low, lc[:, None], axis=-1)[:, 0]
+            term = ((lse - lab.astype(jnp.float32)) * mc).sum()
+            ct = mc / r                         # a row's cotangent
+            soft = e * (ct / s)[:, None]
+            hit = jax.lax.broadcasted_iota(
+                jnp.int32, logits.shape, 1) == lc[:, None]
+            dl = jnp.where(hit, soft - ct[:, None], soft).astype(low.dtype)
+        with jax.named_scope("lm_head"):
+            dh, dwc = products(dl)              # [chunk, d], [d, V]
+        return (acc + term, dw + dwc), dh
+
+    # Last chunk first, as autodiff's backward of the scan runs: the table's
+    # gradient is summed in ``dtype``, and the order is part of the sum.
+    (total, dw), dh = jax.lax.scan(
+        body, (jnp.float32(0.0), jnp.zeros_like(wb)),
+        (rows, labels, weights), reverse=True)
+    return total / r, (dh, dw)
+
+
+def _grad_in_forward_xent_bwd(r, res, g):
+    scale = lambda a: (g * a.astype(jnp.float32)).astype(a.dtype)
+    return (*map(scale, res), None, None)
+
+
+_grad_in_forward_xent.defvjp(_grad_in_forward_xent_fwd,
+                             _grad_in_forward_xent_bwd)
+
+
 def chunked_next_token_xent(hidden: jax.Array, lm_head: jax.Array,
                             tokens: jax.Array, chunk: int,
                             dtype=jnp.bfloat16) -> jax.Array:
-    """Fused LM-head + causal cross entropy without materializing the
-    [B, T, V] logits (f32: 4 GB at b64·s512·v32k — the tensor that capped
-    the bench batch at 32). Rows are processed in ``chunk``-sized scan
-    steps: per-chunk bf16 logits on the MXU, f32 logsumexp − label logit,
-    summed into a carry; ``jax.checkpoint`` on the body recomputes the
-    chunk logits in the backward instead of stacking them as residuals
-    (which would rebuild the full tensor)."""
+    """Fused LM-head + causal cross entropy that never holds the [B, T, V]
+    logits (float32: 16 GiB at 1 x 32768 over 131,136 columns). Rows go
+    through a ``lax.scan`` in ``chunk``-sized steps: a chunk's logits in
+    ``dtype`` on the MXU, float32 log-sum-exp minus the label logit, summed
+    into a carry.
+
+    Differentiated, the same pass takes the gradient while the chunk's
+    logits are in hand: ``(softmax - onehot) * weight / r`` as autodiff
+    forms it, cast to ``dtype``, times the head for the rows' gradient (the
+    scan's stacked output), times the rows for the head's (summed over
+    chunks in the carry, last chunk first as autodiff sums them). Those two
+    arrays are the only residuals and the backward scales them by the
+    cotangent: three products a chunk. At a cotangent of 1 (or any power of
+    two) they are the gradients autodiff takes of the checkpointed scan bit
+    for bit; another cotangent scales what is already rounded to ``dtype``
+    and rounds again, where autodiff rounds once: one ulp of ``dtype``.
+    The cast of ``lm_head``, the slice, the padding and a tied table's
+    transpose stay outside, for autodiff to transpose.
+
+    In a step whose backward keeps named residuals
+    (:func:`tony_tpu.remat.kept`) autodiff differentiates the checkpointed
+    scan instead, which rebuilds a chunk's logits in the backward (four
+    products). There the ladder's reading of the step decides what is
+    kept, and it reads the gradients held here at twice what the program
+    grows by (it counts the heap's holes a second time: PERF.md section
+    7), which costs the Keye cell's step its ``sel``; at the floor there
+    is nothing to lose. The body goes when the ladder reads what the
+    program holds (ROADMAP G22).
+
+    Trace-time counters: ``head:chunks`` (the scan's length),
+    ``head:grad_in_forward`` (1 once the three-product pass was traced).
+    """
     d = hidden.shape[-1]
     rows = hidden[:, :-1].reshape(-1, d)
     labels = tokens[:, 1:].reshape(-1)
@@ -76,22 +179,10 @@ def chunked_next_token_xent(hidden: jax.Array, lm_head: jax.Array,
     else:
         weights = jnp.ones((r,), jnp.float32)
     wb = lm_head.astype(dtype)
-
-    @jax.checkpoint
-    def body(acc, xs):
-        hc, lc, mc = xs
-        with jax.named_scope("lm_head"):
-            logits = (hc @ wb).astype(jnp.float32)      # [chunk, V]
-        with jax.named_scope("loss"):
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            lab = jnp.take_along_axis(logits, lc[:, None], axis=-1)[:, 0]
-            return acc + ((lse - lab) * mc).sum(), None
-
-    total, _ = jax.lax.scan(
-        body, jnp.float32(0.0),
-        (rows.reshape(n, chunk, d), labels.reshape(n, chunk),
-         weights.reshape(n, chunk)))
-    return total / r
+    profiler.count_once("head:chunks", n)
+    xent = _recomputed_xent if remat.kept() else _grad_in_forward_xent
+    return xent(rows.reshape(n, chunk, d), wb, labels.reshape(n, chunk),
+                weights.reshape(n, chunk), r)
 
 
 def param_shardings(model: nn.Module, sample_input: jax.Array, mesh: Mesh,
