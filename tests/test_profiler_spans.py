@@ -17,6 +17,8 @@ from pathlib import Path
 
 import pytest
 
+from test_remat import (BATCH, FULL, LIMIT, MISTRAL, NO_WO, STATE,  # noqa: F401
+                        FakeCompiler, FakeDevice, memo_dir)
 from tony_tpu import constants, events as ev, profiler
 from tony_tpu.minipod import MiniPod
 
@@ -90,12 +92,95 @@ def test_timeline_is_bounded_and_counters_never_stop():
     assert got["counters"]["programs_compiled"] == profiler.MAX_BUILDS + 10
 
 
-def test_counters_count_and_add_seconds():
+def test_counters_count_events_and_seconds():
     profiler.count("saves")
     profiler.count("saves", 2)
-    profiler.add_seconds("save_stall_s", 0.25)
-    profiler.add_seconds("save_stall_s", 0.5)
+    profiler.count("save_stall_s", 0.25)
+    profiler.count("save_stall_s", 0.5)
     assert profiler.counters() == {"saves": 3, "save_stall_s": 0.75}
+
+
+def test_importing_spans_only_the_statement_that_imports_first():
+    assert "json" in sys.modules
+    with profiler.importing("json"):
+        import json as _again  # noqa: F401
+    assert profiler.timeline()["spans"] == []
+    with profiler.span("tony:dist_initialize"):
+        with profiler.importing("tony_tpu_no_such_module"):
+            pass
+    first, _ = profiler.timeline()["spans"]
+    assert (first["name"], first["parent"], first["attrs"]) == (
+        "tony:import", "tony:dist_initialize",
+        {"module": "tony_tpu_no_such_module"})
+
+
+def _span(name, t0, t1, parent=None):
+    return {"name": name, "t0": t0, "t1": t1, "parent": parent, "attrs": {}}
+
+
+@pytest.mark.parametrize("tl,until,want", [
+    # every second of the start under some span: nothing is left
+    ({"t_launch": 10.0, "spans": [_span("tony:python_start", 10.0, 11.0),
+                                  _span("tony:dist_initialize", 11.0, 15.0),
+                                  _span("tony:first_step", 15.0, 18.0)]},
+     None, (0.0, 8.0)),
+    # a nested span and a build inside a span are not counted twice; a
+    # build outside one is covered; the gaps 12-13 and 16.5-17 are not
+    ({"t_launch": 10.0,
+      "spans": [_span("tony:dist_initialize", 10.0, 12.0),
+                _span("tony:import", 10.5, 11.5, "tony:dist_initialize"),
+                _span("tony:first_step", 17.0, 20.0)],
+      "builds": [{"t": 11.9, "kind": "compile", "s": 0.5},
+                 {"t": 16.5, "kind": "load", "s": 3.5}]},
+     None, (1.5, 10.0)),
+    # read up to a moment of the reader's choosing: spans are clipped
+    ({"t_launch": 10.0, "spans": [_span("tony:first_step", 11.0, 20.0)]},
+     14.0, (1.0, 4.0)),
+    ({"t_launch": 10.0, "spans": []}, 12.5, (2.5, 2.5)),
+    # no origin (a timeline written before there was one), nothing to end at
+    ({"spans": [_span("tony:restore", 1.0, 2.0)]}, None, None),
+    ({"t_launch": 10.0, "spans": []}, None, None),
+])
+def test_unspanned_is_the_start_less_the_union_of_spans_and_builds(
+        tl, until, want):
+    got = profiler.unspanned(tl, until)
+    assert got == (want if want is None else pytest.approx(want))
+
+
+@pytest.mark.parametrize("stamp,ago", [
+    ("stamped", 2.0), ("absent", None), ("garbage", None), ("future", None)])
+def test_the_timeline_starts_at_the_executors_launch_stamp(stamp, ago):
+    """``TONY_LAUNCH_TIME`` is the origin and ``tony:python_start`` runs
+    from it to the profiler's import; the first process to read the stamp
+    takes it out of the environment. No stamp, or one that is no time
+    before now: the origin is the import and there is no such span."""
+    now = time.time()
+    env = {**os.environ}
+    env.pop(constants.ENV_LAUNCH_TIME, None)
+    if stamp != "absent":
+        env[constants.ENV_LAUNCH_TIME] = {
+            "stamped": repr(now - 2.0), "garbage": "soon",
+            "future": repr(now + 3600)}[stamp]
+    code = ("import json, os\n"
+            "from tony_tpu import constants, profiler\n"
+            "assert constants.ENV_LAUNCH_TIME not in os.environ\n"
+            "with profiler.span('tony:restore'):\n"
+            "    pass\n"
+            "print(json.dumps(profiler.timeline()))\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    tl = json.loads(out.stdout)
+    names = [s["name"] for s in tl["spans"]]
+    if ago is None:
+        assert names == ["tony:restore"]
+        assert now <= tl["t_launch"] <= tl["spans"][0]["t0"]
+        return
+    assert names == ["tony:python_start", "tony:restore"]
+    start, restore = tl["spans"]
+    assert tl["t_launch"] == start["t0"] == pytest.approx(now - ago)
+    assert now <= start["t1"] <= restore["t0"]
+    assert (start["parent"], start["attrs"]) == (None, {})
 
 
 def test_a_cache_load_is_one_build_not_also_a_compile():
@@ -266,25 +351,38 @@ def _job_detail(job):
                        "path": str(jhist), "metadata": {}})
 
 
-def test_submitted_jobs_timeline_reaches_tony_history(tmp_path):
-    """timeline.json -> register_execution_result -> TASK_TIMELINE ->
-    history.job_detail["timelines"], through a real executor."""
-    from tony_tpu.history import render_show
-
-    job = MiniPod(tmp_path).run({
+@pytest.fixture(scope="module")
+def timeline_job(tmp_path_factory):
+    """One `tony submit` of tests/workloads/timeline_train.py — a
+    one-process job that never builds a mesh — through a real executor;
+    its history detail."""
+    job = MiniPod(tmp_path_factory.mktemp("timeline_job")).run({
         "tony.application.framework": "jax",
         "tony.worker.instances": "1",
         "tony.application.executes": "python timeline_train.py",
         "tony.task.max-missed-heartbeats": "200",
     }, src_dir=WORKLOADS, timeout=180)
     assert job.exit_code == 0, job.session.final_message
-    detail = _job_detail(job)
-    timeline = detail["timelines"]["worker:0"]
-    names = [s["name"] for s in timeline["spans"]]
-    assert names[:2] == ["tony:dist_initialize", "tony:create_train_state"]
-    assert "tony:restore" in names
-    restore = next(s for s in timeline["spans"]
-                   if s["name"] == "tony:restore")
+    return _job_detail(job)
+
+
+def _named(timeline, name):
+    return [s for s in timeline["spans"] if s["name"] == name]
+
+
+def test_submitted_jobs_timeline_reaches_tony_history(timeline_job):
+    """timeline.json -> register_execution_result -> TASK_TIMELINE ->
+    history.job_detail["timelines"]."""
+    from tony_tpu.history import render_show
+
+    timeline = timeline_job["timelines"]["worker:0"]
+    top = [s["name"] for s in sorted(timeline["spans"],
+                                     key=lambda s: s["t0"])
+           if s["parent"] is None and s["name"] != "tony:import"]
+    assert top == ["tony:python_start", "tony:dist_initialize",
+                   "tony:create_train_state", "tony:restore",
+                   "tony:first_step"]
+    [restore] = _named(timeline, "tony:restore")
     assert restore["attrs"] == {"step": None, "bytes": 0}
     c = timeline["counters"]
     # Compiled or, with jax's persistent cache warm, loaded.
@@ -297,10 +395,84 @@ def test_submitted_jobs_timeline_reaches_tony_history(tmp_path):
         **{f"attn:block_{side}.{kernel}.dense": 16 for side in "qk"
            for kernel in ("fwd", "dq", "dkv")}}
     assert any(b["kind"] in ("compile", "load") for b in timeline["builds"])
-    shown = render_show(detail)
+    shown = render_show(timeline_job)
     assert "task start timelines:" in shown
     assert "tony:create_train_state" in shown
     assert "program(s) built or loaded" in shown
+
+
+def test_the_backend_starts_under_its_span_in_a_job_without_a_mesh(
+        timeline_job):
+    """The script's own jax.devices() comes after dist.initialize(),
+    which has started the backend inside the framework."""
+    timeline = timeline_job["timelines"]["worker:0"]
+    [init] = _named(timeline, "tony:dist_initialize")
+    [backend] = _named(timeline, "tony:backend_init")
+    assert backend["parent"] == "tony:dist_initialize"
+    assert init["t0"] <= backend["t0"] <= backend["t1"] <= init["t1"]
+
+
+def test_imports_nest_under_their_importer_and_name_their_module(
+        timeline_job):
+    timeline = timeline_job["timelines"]["worker:0"]
+    imports = _named(timeline, "tony:import")
+    modules = [s["attrs"]["module"] for s in imports]
+    assert len(set(modules)) == len(modules)        # each imported once
+    # dist.initialize() was the first to need jax; the script's own
+    # `import jax` after it is no span.
+    [init] = _named(timeline, "tony:dist_initialize")
+    [jax_import] = [s for s in imports if s["attrs"]["module"] == "jax"]
+    assert jax_import["parent"] == "tony:dist_initialize"
+    assert init["t0"] <= jax_import["t0"] <= jax_import["t1"] <= init["t1"]
+    # what `from tony_tpu import train` and get_model() brought in
+    assert {"flax.linen", "tony_tpu.models.transformer",
+            "jax.experimental.pallas"} <= set(modules)
+    assert "optax" not in modules       # the script's own import came first
+
+
+def test_every_span_follows_the_launch_and_pythons_start(timeline_job):
+    timeline = timeline_job["timelines"]["worker:0"]
+    [start] = _named(timeline, "tony:python_start")
+    assert timeline["t_launch"] == start["t0"] <= start["t1"]
+    others = [s for s in timeline["spans"] if s is not start]
+    assert others and all(start["t1"] <= s["t0"] <= s["t1"] for s in others)
+    launched = next(r["timestamp"] for r in timeline_job["events"]
+                    if r["type"] == ev.TASK_STARTED)
+    assert 0 <= timeline["t_launch"] - launched < 60
+
+
+def test_the_jobs_first_step_carries_what_it_built(timeline_job):
+    timeline = timeline_job["timelines"]["worker:0"]
+    [first] = _named(timeline, "tony:first_step")
+    assert first["parent"] is None
+    assert set(first["attrs"]) == {"programs", "trace_s", "lower_s",
+                                   "compile_s", "load_s"}
+    assert first["attrs"]["programs"] >= 1 and first["attrs"]["trace_s"] > 0
+    inside = [b for b in timeline["builds"]
+              if first["t0"] <= b["t"] <= first["t1"]
+              and b["kind"] in ("compile", "load")]
+    assert len(inside) == first["attrs"]["programs"]
+    # The CPU reports no memory limit: no ladder, no rung, no memo.
+    assert not _named(timeline, "tony:remat_rung")
+
+
+def test_history_show_says_how_much_of_the_start_is_under_no_span(
+        timeline_job):
+    import re
+
+    from tony_tpu.history import render_show
+
+    timeline = timeline_job["timelines"]["worker:0"]
+    bare, whole = profiler.unspanned(timeline)
+    assert 0 <= bare < whole
+    found = re.search(r"under no span: ([0-9.]+)s of ([0-9.]+)s",
+                      render_show(timeline_job))
+    assert found, render_show(timeline_job)
+    assert (float(found[1]), float(found[2])) == (
+        pytest.approx(bare, abs=0.006), pytest.approx(whole, abs=0.006))
+    # nested spans are indented under what encloses them
+    assert re.search(r"\n        \+[0-9.]+s tony:backend_init ",
+                     render_show(timeline_job))
 
 
 @pytest.mark.parametrize("kw,t,side,visited,total", [
@@ -415,7 +587,7 @@ def test_tony_profile_captures_a_live_replicas_serve_spans(tmp_path,
     from tony_tpu.rpc import RpcClient
 
     if profiler._trace_fn() is None:
-        pytest.skip("no profiler client (xprof / tensorflow) importable")
+        pytest.skip("no profiler client (xprof) importable")
     job = MiniPod(tmp_path).submit(
         serve_props(tiny_ckpt, **{"tony.task.profiler.enabled": "true"}))
     stop = threading.Event()
@@ -488,8 +660,160 @@ def test_train_loop_puts_every_step_on_a_profiler_trace(tmp_path):
     # Three batches and the call that finds the iterator exhausted.
     assert names.count("train:next_batch") == 4
     assert "train:save" not in names and "train:drain_poll" not in names
+    # (and a tony:import each where this test is the process's first to
+    # import tony_tpu.train and the models)
+    assert [s["name"] for s in profiler.timeline()["spans"]
+            if s["name"] != "tony:import"] == \
+        ["tony:create_train_state", "tony:first_step"]
+
+
+@pytest.mark.parametrize("gang", [True, False])
+def test_initialize_starts_the_backend_after_the_rendezvous(monkeypatch,
+                                                             gang):
+    """On a gang the backend may only start once jax.distributed knows
+    the processes; alone there is no rendezvous and the backend starts
+    all the same, under the span, inside the framework."""
+    import jax
+
+    from tony_tpu import distributed, util
+
+    calls = []
+    monkeypatch.setattr(util, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(profiler, "watch_builds", lambda: None)
+    monkeypatch.setattr(
+        jax.distributed, "initialize",
+        lambda **kw: calls.append(("rendezvous", kw["num_processes"])))
+    monkeypatch.setattr(profiler, "backend_devices",
+                        lambda: calls.append(("backend",
+                                              list(profiler._TIMELINE
+                                                   .open_spans()))))
+    monkeypatch.delenv(constants.ENV_PROFILER_PORT, raising=False)
+    for name, value in ((constants.ENV_COORDINATOR_ADDRESS, "localhost:1"),
+                        (constants.ENV_NUM_PROCESSES, "2"),
+                        (constants.ENV_PROCESS_ID, "1")):
+        if gang:
+            monkeypatch.setenv(name, value)
+        else:
+            monkeypatch.delenv(name, raising=False)
+    assert distributed.initialize() is gang
+    backend = ("backend", ["tony:dist_initialize"])
+    assert calls == ([("rendezvous", 2), backend] if gang else [backend])
+
+
+def test_backend_devices_is_a_span_only_where_it_starts_the_backend(
+        monkeypatch):
+    import jax
+    from jax._src import xla_bridge
+
+    assert profiler.backend_devices() == jax.devices()   # started long ago
+    assert profiler.timeline()["spans"] == []
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized",
+                        lambda: False)
+    assert profiler.backend_devices() == jax.devices()
     assert [s["name"] for s in profiler.timeline()["spans"]] == \
-        ["tony:create_train_state"]
+        ["tony:backend_init"]
+
+
+def test_first_step_wraps_exactly_the_first_call_and_what_it_built():
+    from tony_tpu import train
+
+    compile_ev = "/jax/core/compile/backend_compile_duration"
+    trace_ev = "/jax/core/compile/jaxpr_trace_duration"
+    load_ev = "/jax/compilation_cache/cache_retrieval_time_sec"
+    profiler._on_build(compile_ev, 4.0)          # before the loop: not its
+    called = []
+
+    def step_fn(state, batch):
+        called.append(time.time())
+        if len(called) == 1:
+            profiler._on_build(trace_ev, 0.5)
+            profiler._on_build(compile_ev, 2.0)
+            profiler._on_build(load_ev, 0.25)
+            profiler._on_build(compile_ev, 0.26)        # the load, again
+        else:
+            profiler._on_build(compile_ev, 1.0)         # a later shape
+        time.sleep(0.01)
+        return state + 1, {"loss": batch}
+
+    state, metrics = train.train_loop(0, step_fn, batches=[10, 11, 12],
+                                      save_final=False)
+    assert (state, metrics) == (3, {"loss": 12})
+    [first] = profiler.timeline()["spans"]
+    assert (first["name"], first["parent"]) == ("tony:first_step", None)
+    assert first["attrs"] == {"programs": 2, "trace_s": 0.5, "lower_s": 0,
+                              "compile_s": 2.0, "load_s": 0.25}
+    assert first["t0"] <= called[0] < first["t1"] <= called[1]
+
+
+def test_a_first_step_that_fails_still_closes_its_span():
+    from tony_tpu import train
+
+    def step_fn(state, batch):
+        raise RuntimeError("no such step")
+
+    with pytest.raises(RuntimeError, match="no such step"):
+        train.train_loop(0, step_fn, batches=[1], save_final=False)
+    [first] = profiler.timeline()["spans"]
+    assert first["name"] == "tony:first_step"
+    assert first["attrs"]["programs"] == 0
+    assert profiler._TIMELINE.open_spans() == []
+
+
+@pytest.fixture
+def fake_limit(monkeypatch, memo_dir):
+    """tests/test_remat.py's fake device, 15.75 GiB, and an empty memo."""
+    from tony_tpu import remat
+
+    monkeypatch.setattr(remat, "_device_of", lambda _state: FakeDevice(LIMIT))
+
+
+def test_a_cold_first_step_holds_one_rung_span_for_each_rung_tried(
+        fake_limit):
+    """tests/test_remat.py's fake compiler and fake limit under the real
+    loop: a cold start tries rungs, each a child of tony:first_step; the
+    start after it reads the memo and tries none."""
+    from tony_tpu import remat, train
+
+    def start():
+        profiler.reset_timeline()
+        step = remat.ChosenStep(FakeCompiler({**MISTRAL, FULL: None}))
+        ran, names = train.train_loop(STATE, step, batches=[BATCH, BATCH],
+                                      save_final=False)
+        assert (ran, names) == ("ran", NO_WO)
+        spans = profiler.timeline()["spans"]
+        return spans[-1], spans[:-1]
+
+    first, rungs = start()
+    assert first["name"] == "tony:first_step"
+    assert first["attrs"]["from_memo"] is False
+    assert [(r["name"], r["parent"]) for r in rungs] == \
+        [("tony:remat_rung", "tony:first_step")] * 2
+    assert [r["attrs"] for r in rungs] == [
+        {"saved": ",".join(FULL), "bytes": None, "fits": False},
+        {"saved": ",".join(NO_WO), "bytes": MISTRAL[NO_WO], "fits": True}]
+    assert first["t0"] <= rungs[0]["t0"] <= rungs[0]["t1"] \
+        <= rungs[1]["t0"] <= rungs[1]["t1"] <= first["t1"]
+    first, rungs = start()
+    assert rungs == [] and first["attrs"]["from_memo"] is True
+
+
+def test_a_rung_whose_trace_fails_closes_its_span_where_it_failed(
+        fake_limit):
+    from tony_tpu import remat
+
+    class Untraceable(FakeCompiler):
+        def __call__(self, saved):
+            step = super().__call__(saved)
+            step.trace = lambda state, batch: 1 / 0
+            return step
+
+    with profiler.span("tony:first_step"):
+        with pytest.raises(ZeroDivisionError):
+            remat.ChosenStep(Untraceable(MISTRAL))(STATE, BATCH)
+        assert profiler._TIMELINE.open_spans() == ["tony:first_step"]
+    rung, first = profiler.timeline()["spans"]
+    assert (rung["name"], rung["parent"], rung["attrs"]) == (
+        "tony:remat_rung", "tony:first_step", {})
 
 
 def test_device_scopes_are_in_the_programs_the_step_and_kernels_lower_to():

@@ -56,6 +56,12 @@ ENV_DATA_SEED = "TONY_DATA_SEED"
 # there and the executor's heartbeat loop piggybacks it to the AM (both
 # sides jax-free), where the replica autoscaler reads it.
 ENV_SERVE_STATS = "TONY_SERVE_STATS"
+# The task's start timeline (tony_tpu.profiler): the executor stamps the
+# moment it launches the user process (epoch seconds); the first process
+# to import the profiler takes the stamp as its timeline's origin
+# (``t_launch``, the start of ``tony:python_start``) and out of the
+# environment. Plumbing between executor and task, not a switch.
+ENV_LAUNCH_TIME = "TONY_LAUNCH_TIME"
 # Elastic resize (tony_tpu.am.resize): the executor exports a drain-file
 # path; when the AM's heartbeat response carries the drain directive the
 # executor creates the file, and train_loop — polling it between steps —

@@ -41,32 +41,37 @@ def initialize(local_device_ids: Optional[Sequence[int]] = None) -> bool:
     (``tony.task.profiler.enabled`` — SURVEY.md §5.1). As the training
     entry it also turns on jax's persistent compile cache
     (:func:`tony_tpu.util.enable_compile_cache`) and the program's build
-    counters (:func:`tony_tpu.profiler.watch_builds`); the whole call —
-    the jax import included, where this is the first to need it — is the
-    set-up span ``tony:dist_initialize``."""
+    counters (:func:`tony_tpu.profiler.watch_builds`), and it ends by
+    starting the backend (:func:`tony_tpu.profiler.backend_devices`:
+    after the rendezvous on a gang, and in the single-process fallback
+    too), so configure jax — platform, device count — before this call,
+    not after it. The whole call is the set-up span
+    ``tony:dist_initialize``; inside it the jax import, where this is the
+    first to need it, is a ``tony:import`` and the backend's start is
+    ``tony:backend_init``."""
     from tony_tpu.util import enable_compile_cache
 
+    with profiler.importing("jax"):
+        import jax
     enable_compile_cache()
     profiler.watch_builds()
     _maybe_start_profiler()
     spec = env_spec()
-    if spec is None:
-        return False
-    addr, num_processes, process_id = spec
-    if num_processes <= 1:
-        return False
-    import jax
-    if local_device_ids is None:
-        raw = os.environ.get(constants.ENV_LOCAL_DEVICE_IDS)
-        if raw:
-            local_device_ids = [int(x) for x in raw.split(",")]
-    jax.distributed.initialize(
-        coordinator_address=addr,
-        num_processes=num_processes,
-        process_id=process_id,
-        local_device_ids=local_device_ids,
-    )
-    return True
+    gang = spec is not None and spec[1] > 1
+    if gang:
+        addr, num_processes, process_id = spec
+        if local_device_ids is None:
+            raw = os.environ.get(constants.ENV_LOCAL_DEVICE_IDS)
+            if raw:
+                local_device_ids = [int(x) for x in raw.split(",")]
+        jax.distributed.initialize(
+            coordinator_address=addr,
+            num_processes=num_processes,
+            process_id=process_id,
+            local_device_ids=local_device_ids,
+        )
+    profiler.backend_devices()
+    return gang
 
 
 def _maybe_start_profiler() -> None:

@@ -632,6 +632,7 @@ class TaskExecutor:
             # scheduler's teardown killpg must keep reaping executor +
             # user tree together; the executor's own kills walk the tree
             # (see _kill_user_proc).
+            env[constants.ENV_LAUNCH_TIME] = repr(time.time())
             self.user_proc = subprocess.Popen(
                 cmd, shell=True, env=env, cwd=cwd,
                 stdout=stdout, stderr=stderr)

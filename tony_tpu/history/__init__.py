@@ -404,10 +404,13 @@ def render_show(detail: Dict[str, Any]) -> str:
 
 def _render_timeline(tid: str, tl: Dict[str, Any]) -> List[str]:
     """One task's set-up spans in start order (nested ones indented
-    under their parent) and what it built, as text lines."""
-    from tony_tpu.profiler import build_totals
+    under what encloses them), what it built, and how much of its start
+    — launch to the end of the last span — no span or build covers, as
+    text lines."""
+    from tony_tpu.profiler import build_totals, unspanned
 
-    spans = sorted(tl.get("spans") or [], key=lambda s: s.get("t0", 0.0))
+    spans = sorted(tl.get("spans") or [],
+                   key=lambda s: (s.get("t0", 0.0), -s.get("t1", 0.0)))
     c = tl.get("counters") or {}
     totals = build_totals(c)
     out = [f"    {tid}: {len(spans)} span(s); "
@@ -416,14 +419,22 @@ def _render_timeline(tid: str, tl: Dict[str, Any]) -> List[str]:
            f"{c.get('programs_loaded', 0):.0f} from the cache) in "
            f"{totals['build_s']:.2f}s of tracing, lowering, compiling and "
            f"loading"]
-    t_first = spans[0]["t0"] if spans else 0.0
+    t_first = tl.get("t_launch") or (spans[0]["t0"] if spans else 0.0)
+    open_until: List[float] = []     # ends of the spans enclosing this one
     for sp in spans:
+        while open_until and sp["t0"] >= open_until[-1]:
+            open_until.pop()
+        depth = len(open_until) if sp.get("parent") else 0
+        open_until.append(sp["t1"])
         attrs = " ".join(f"{k}={v}" for k, v in sorted(
             (sp.get("attrs") or {}).items()))
-        out.append(f"      {'  ' if sp.get('parent') else ''}"
+        out.append(f"      {'  ' * depth}"
                    f"+{sp['t0'] - t_first:.2f}s {sp['name']} "
                    f"{sp['t1'] - sp['t0']:.2f}s"
                    + (f" ({attrs})" if attrs else ""))
+    bare = unspanned(tl)
+    if bare is not None:
+        out.append(f"      under no span: {bare[0]:.2f}s of {bare[1]:.2f}s")
     return out
 
 

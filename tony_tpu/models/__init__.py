@@ -38,9 +38,14 @@ def get_model(name: str, **kw):
     ``llama-tiny``, ``mixtral-8x7b``, ``keye-vl-2.0-30b-a3b``,
     ``keye-tiny``, ``zaya1-8b``, ``zaya-tiny``, ``hybrid-decoder``,
     ``hybrid-tiny``, ``mnist-mlp``, ``mnist-cnn``)."""
-    # Import for registration side effects.
-    from tony_tpu.models import (hybrid, mnist, resnet,  # noqa: F401
-                                 transformer)
+    # Import for registration side effects: the model files, and through
+    # them flax and tony_tpu.ops' kernels, under one set-up span the first
+    # time.
+    from tony_tpu import profiler
+
+    with profiler.importing("tony_tpu.models.transformer"):
+        from tony_tpu.models import (hybrid, mnist, resnet,  # noqa: F401
+                                     transformer)
     if name not in _REGISTRY:
         raise ValueError(f"unknown model {name!r}; known: {sorted(_REGISTRY)}")
     return _REGISTRY[name](**kw)

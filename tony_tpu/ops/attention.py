@@ -21,10 +21,16 @@ import functools
 import math
 from typing import NamedTuple, Optional
 
-import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from tony_tpu import profiler
+
+# The first module of tony_tpu.ops: pallas is imported here before any
+# other kernel file asks (set-up spans tony:import; its TPU half is 0.02 s).
+with profiler.importing("jax"):
+    import jax
+    import jax.numpy as jnp
+with profiler.importing("jax.experimental.pallas"):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
 from tony_tpu import remat
 

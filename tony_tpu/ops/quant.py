@@ -49,17 +49,18 @@ import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-import flax.linen as nn
+from tony_tpu import profiler
+
+with profiler.importing("flax.linen"):  # set-up span tony:import
+    import flax.linen as nn
+    from flax import struct
+    from flax.training.train_state import TrainState
 import jax
 import jax.numpy as jnp
 import numpy as np
-from flax import struct
-from flax.training.train_state import TrainState
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from tony_tpu import profiler
 
 # Trace-time side channel into the profiler's plan registry.
 _record = functools.partial(profiler.record, "quant")

@@ -24,11 +24,12 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional, Sequence, Tuple
 
-import jax
+from tony_tpu import profiler
+
+with profiler.importing("jax"):         # set-up span tony:import
+    import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from tony_tpu import profiler
 
 # Mesh axis names, outermost (most DCN-friendly) to innermost (most
 # ICI-bandwidth-hungry). The slice axis IS the DCN boundary: collectives

@@ -5,19 +5,24 @@ Three halves, one module (imports no jax at module level: the executor,
 the AM and the history plane import it too):
 
 * **Spans and counters** (:func:`span`, :func:`count`,
-  :func:`add_seconds`, :func:`watch_builds`). A span always enters a
+  :func:`watch_builds`). A span always enters a
   ``jax.profiler.TraceAnnotation`` when jax is imported, so with a
   profiler session active it lands on the host plane of the same
   ``.xplane.pb`` as the device operations, on one clock; with none it
-  costs a TraceMe enter/exit. The few *set-up* spans (:data:`SETUP_SPANS`)
-  and every program jax builds or loads (``watch_builds``) are also kept
-  on a bounded in-memory timeline with process-local counters, and
+  costs a TraceMe enter/exit. The *set-up* spans (:data:`SETUP_SPANS`:
+  the process's start, the heavy imports the package causes, the
+  rendezvous, the backend's start, the state, the restore, the warm-up,
+  the first step and the rungs it tries) and every program jax builds or
+  loads (``watch_builds``) are also kept on a bounded in-memory timeline
+  with process-local counters, from an origin the process did not choose
+  (``t_launch``: when the executor launched it), and
   :func:`write_timeline` puts that in ``timeline.json`` beside the stats
   file the executor names (``TONY_SERVE_STATS``). The executor hands the
   file to the AM, which logs one ``TASK_TIMELINE`` event per task:
-  ``tony history show`` then says where a task's start went, with no
-  profiler attached. Per-step and per-iteration spans go to the TraceMe
-  only — nothing is appended on the hot path.
+  ``tony history show`` then says where a task's start went and how much
+  of it lies under no span (:func:`unspanned`), with no profiler
+  attached. Per-step and per-iteration spans go to the TraceMe only —
+  nothing is appended on the hot path.
 * **The plan registry** (:func:`record`, :func:`report`,
   :func:`reset_records` over :data:`KINDS`): what the planners decided at
   jit-trace time, last plan per tag wins.
@@ -49,9 +54,21 @@ import sys
 import threading
 import time
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from tony_tpu import constants
+
+# The timeline's origin: the stamp the executor put into the environment
+# as it launched this process, taken out so that no process this one
+# starts inherits it. Where there is none (outside a tony task) or it is
+# no time before now, the origin is this import.
+_T_IMPORT = time.time()
+try:
+    _T_LAUNCH = float(os.environ.pop(constants.ENV_LAUNCH_TIME, ""))
+except ValueError:
+    _T_LAUNCH = _T_IMPORT
+if not 0 < _T_LAUNCH <= _T_IMPORT:
+    _T_LAUNCH = _T_IMPORT
 
 # Tracer levels: host TraceMe spans + python + device. Without these the
 # remote session returns "no trace data" (measured, not hypothetical).
@@ -67,12 +84,22 @@ _TRACE_OPTIONS = {
 # history` and the README's Observability section find them by name.
 
 # The set-up spans: kept on the timeline (and so in the job's event log),
-# because they happen before any profiler session can start.
+# because they happen before any profiler session can start. Each occurs
+# once in a task's start but ``tony:import`` (once for each heavy module
+# the package is the first to import: `importing`) and
+# ``tony:remat_rung`` (once for each rung a cold first step tries, six at
+# most); ``tony:python_start`` is not entered but made by `timeline` from
+# the launch stamp.
 SETUP_SPANS = frozenset({
-    "tony:dist_initialize", "tony:backend_init", "tony:create_train_state",
-    "tony:restore", "tony:warm"})
+    "tony:python_start", "tony:import", "tony:dist_initialize",
+    "tony:backend_init", "tony:create_train_state", "tony:restore",
+    "tony:warm", "tony:first_step", "tony:remat_rung"})
 TIMELINE_FILE = "timeline.json"
-MAX_SPANS = 256          # set-up spans kept (a task records a handful)
+# Set-up spans kept. A task's start records a dozen or two, not a handful
+# (five imports and six rungs at most, one of each other; a replica one
+# more for each restore and warm-up): a bound no start reaches, there for
+# the program that calls a spanned function in a loop.
+MAX_SPANS = 256
 MAX_BUILDS = 2048        # build records kept; the counters never stop
 MIN_BUILD_RECORD_S = 1e-3   # a shorter trace or lowering is only counted
 
@@ -159,6 +186,17 @@ class span(contextlib.ContextDecorator):
         return False
 
 
+def importing(module: str):
+    """``with importing("optax"): import optax`` — the set-up span
+    ``tony:import`` (attr ``module``) where this statement is the first
+    of the process to import ``module``, nothing where the module is
+    loaded already. For the third-party imports of 0.2 s or more that the
+    package causes (PERF.md §5); a user's own stay unspanned."""
+    if module in sys.modules:
+        return contextlib.nullcontext()
+    return span("tony:import", module=module)
+
+
 def backend_devices() -> list:
     """``jax.devices()``; where this call is the one that starts the
     backend (the first in the process), it is the set-up span
@@ -184,11 +222,6 @@ def count_once(name: str, n: float) -> None:
     trace, not again by init, remat or a re-trace."""
     with _TIMELINE.lock:
         _TIMELINE.counters.setdefault(name, n)
-
-
-def add_seconds(name: str, s: float) -> None:
-    """Add ``s`` seconds to the process-local sum ``name``."""
-    count(name, float(s))
 
 
 def counters() -> Dict[str, float]:
@@ -260,13 +293,40 @@ def build_totals(c: Optional[Dict[str, float]] = None) -> Dict[str, float]:
 
 
 def timeline() -> Dict[str, object]:
-    """A copy of what this process has recorded so far."""
+    """A copy of what this process has recorded so far. ``t_launch`` is
+    when the executor launched the process; from there to this module's
+    import is the span ``tony:python_start`` (none where nothing stamped
+    the launch: the origin is then the import)."""
+    start = [] if _T_LAUNCH == _T_IMPORT else [{
+        "name": "tony:python_start", "t0": _T_LAUNCH, "t1": _T_IMPORT,
+        "parent": None, "attrs": {}}]
     with _TIMELINE.lock:
         return {"pid": os.getpid(), "written": time.time(),
-                "spans": [dict(s) for s in _TIMELINE.spans],
+                "t_launch": _T_LAUNCH,
+                "spans": start + [dict(s) for s in _TIMELINE.spans],
                 "builds": [dict(b) for b in _TIMELINE.builds],
                 "builds_dropped": _TIMELINE.builds_dropped,
                 "counters": dict(_TIMELINE.counters)}
+
+
+def unspanned(tl: Dict[str, object], until: Optional[float] = None
+              ) -> Optional[Tuple[float, float]]:
+    """``(seconds under no set-up span and no build record, seconds in
+    all)`` of a timeline's start: from ``t_launch`` to ``until`` (the end
+    of its last span by default). None for a timeline with no origin or
+    nothing to end at."""
+    t0, spans = tl.get("t_launch"), tl.get("spans") or []
+    if t0 is None or not (spans or until is not None):
+        return None
+    t1 = max(s["t1"] for s in spans) if until is None else until
+    held = [(s["t0"], s["t1"]) for s in spans] + [
+        (b["t"] - b["s"], b["t"]) for b in tl.get("builds") or []]
+    covered, edge = 0.0, t0
+    for a, b in sorted(held):
+        a, b = max(a, edge), min(b, t1)
+        if b > a:
+            covered, edge = covered + b - a, b
+    return max(t1 - t0 - covered, 0.0), max(t1 - t0, 0.0)
 
 
 def reset_timeline() -> None:
@@ -404,29 +464,6 @@ def _trace_fn():
 
         return capture
     except ImportError:
-        pass
-    try:
-        from tensorflow.python.profiler import profiler_client
-
-        def capture(addr: str, logdir: str, duration_ms: int) -> None:
-            # TF >= 2.16 requires a ProfilerOptions namedtuple (it calls
-            # options._asdict()); a plain dict dies inside the client
-            # with "'dict' object has no attribute '_asdict'" — measured
-            # on this image's TF 2.20, where it broke every capture.
-            options: object = _TRACE_OPTIONS
-            try:
-                from tensorflow.python.profiler.profiler_v2 import (
-                    ProfilerOptions)
-                options = ProfilerOptions(**{
-                    k: v for k, v in _TRACE_OPTIONS.items()
-                    if k in ProfilerOptions._fields})
-            except ImportError:
-                pass
-            profiler_client.trace(f"grpc://{addr}", logdir, duration_ms,
-                                  options=options)
-
-        return capture
-    except ImportError:
         return None
 
 
@@ -479,8 +516,8 @@ def collect_traces(endpoints: Dict[str, str], history_dir: str | Path,
     session — a partial profile beats none."""
     capture = _trace_fn()
     if capture is None:
-        log("trace collection unavailable: no profiler client "
-            "(xprof / tensorflow) importable", file=sys.stderr)
+        log("trace collection unavailable: no profiler client (xprof) "
+            "importable", file=sys.stderr)
         return []
     live = {}
     for task_id, addr in sorted(endpoints.items()):

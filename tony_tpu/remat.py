@@ -276,7 +276,13 @@ class ChosenStep:
         with (jax.set_mesh(self._mesh) if self._mesh is not None
               else contextlib.nullcontext()):
             while True:
-                out = getattr(fn, "trace" if picking else how)(state, batch)
+                try:
+                    out = getattr(fn, "trace" if picking else how)(
+                        state, batch)
+                except BaseException:
+                    if picking is not None:
+                        picking.close()     # the rung's span ends here
+                    raise
                 if picking is None:
                     return out
                 fn, picking = self._advance(picking, out)
@@ -305,26 +311,37 @@ class ChosenStep:
         # has; rungs that differ only in names it lacks are one rung, and
         # the first candidate is the program of its own effective rung.
         first = Saved(LADDER[0])
-        fn = self.build(first)
-        trace = yield fn
-        readings = []
-        for rung in dict.fromkeys(map(first.effective, LADDER + (FLOOR,))):
-            if rung != first.effective(LADDER[0]):
-                fn = self.build(Saved(rung))
-                trace = yield fn
+        fn, reading = yield from self._rung(first, first, limit)
+        readings = [reading]
+        for rung in list(dict.fromkeys(
+                map(first.effective, LADDER + (FLOOR,))))[1:]:
+            if readings[-1]["fits"]:
+                break
+            fn, reading = yield from self._rung(Saved(rung), first, limit)
+            readings.append(reading)
+        choice = {"saved": reading["saved"], "step_bytes": reading["bytes"],
+                  "rungs": readings}
+        _write_memo(path, choice)
+        _publish(choice, limit, from_memo=False)
+        return fn
+
+    def _rung(self, saved: Saved, first: Saved, limit: int):
+        """Generator: one rung — its candidate yielded for tracing, the
+        trace compiled, its bytes read — under the set-up span
+        ``tony:remat_rung`` (attrs ``saved``, ``bytes``: None for a
+        refused compile, ``fits``). Returns the candidate and its
+        reading."""
+        with profiler.span("tony:remat_rung") as sp:
+            fn = self.build(saved)
+            trace = yield fn
+            rung = first.effective(saved.names)
             try:
                 total = step_bytes(trace.lower().compile())
             except jax.errors.JaxRuntimeError as e:
                 if "RESOURCE_EXHAUSTED" not in str(e) or rung == FLOOR:
                     raise
                 total = None
-            readings.append({"saved": list(rung), "bytes": total})
-            if rung == FLOOR or (total is not None
-                                 and total + MARGIN <= limit):
-                break
-        choice = {"saved": list(rung), "step_bytes": total,
-                  "rungs": readings}
-        _write_memo(path, choice)
-        _publish(choice, limit, from_memo=False)
-        return fn
-
+            fits = rung == FLOOR or (total is not None
+                                     and total + MARGIN <= limit)
+            sp.attrs.update(saved=",".join(rung), bytes=total, fits=fits)
+        return fn, {"saved": list(rung), "bytes": total, "fits": fits}

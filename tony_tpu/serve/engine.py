@@ -66,11 +66,12 @@ import uuid
 from collections import OrderedDict, deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
 from tony_tpu import profiler
+
+with profiler.importing("jax"):         # set-up span tony:import
+    import jax
+    import jax.numpy as jnp
+import numpy as np
 from tony_tpu.serve import prefix as prefix_mod
 from tony_tpu.serve.disagg import HandoffError, decode_f32, encode_f32
 from tony_tpu.serve.kvcache import AdmissionError, PagedKVCache

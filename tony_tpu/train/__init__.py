@@ -17,6 +17,7 @@ loop a library so examples and benchmarks share one GSPMD path:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import os
@@ -24,15 +25,22 @@ import time
 import weakref
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
-import flax.linen as nn
-import jax
-import jax.numpy as jnp
+from tony_tpu import profiler
+
+# Each the set-up span tony:import where this module is the first of the
+# process to import it (PERF.md §5 has what each costs on the chip's host).
+with profiler.importing("jax"):
+    import jax
+    import jax.numpy as jnp
+with profiler.importing("flax.linen"):
+    import flax.linen as nn
+    from flax.training.train_state import TrainState
+with profiler.importing("optax"):
+    import optax
 import numpy as np
-import optax
-from flax.training.train_state import TrainState
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tony_tpu import chaos, constants, profiler, remat
+from tony_tpu import chaos, constants, remat
 from tony_tpu import parallel as par
 from tony_tpu.parallel import overlap
 
@@ -670,6 +678,32 @@ def shared_aot_cache(path: Optional[str] = None):
     return AOTCache(path)
 
 
+@contextlib.contextmanager
+def _first_step():
+    """The set-up span ``tony:first_step`` around the loop's first
+    ``step_fn`` call, from the call to its return (no fence: what the
+    device still has to do is the second step's wait). Its attrs are
+    what the process built under it, by the counters' change — programs
+    compiled or loaded and the seconds of each kind — and whether the
+    step's residuals came from the memo (``from_memo``: absent where
+    no ladder was walked or read). A cold start's rungs are its
+    ``tony:remat_rung`` children."""
+    before = profiler.counters()
+    with profiler.span("tony:first_step") as sp:
+        try:
+            yield
+        finally:
+            after = profiler.counters()
+            grew = lambda k: after.get(k, 0) - before.get(k, 0)
+            sp.attrs.update(
+                programs=int(grew("programs_compiled")
+                             + grew("programs_loaded")),
+                **{k: round(grew(k), 3) for k in
+                   ("trace_s", "lower_s", "compile_s", "load_s")})
+            if "remat:from_memo" in after:
+                sp.attrs.update(from_memo=bool(after["remat:from_memo"]))
+
+
 def train_loop(state: TrainState, step_fn: Callable[[TrainState, Any],
                                                     Tuple[TrainState, Any]],
                batches: Optional[Iterable[Any]] = None, *,
@@ -740,8 +774,11 @@ def train_loop(state: TrainState, step_fn: Callable[[TrainState, Any],
     ``StepTraceAnnotation`` around each ``step_fn`` call),
     ``train:next_batch``, ``train:on_step``, ``train:save`` and, with a
     drain file, ``train:drain_poll``; the restore is the set-up span
-    ``tony:restore`` and the snapshot stalls add up in the counters
-    ``saves`` / ``save_stall_s`` (:mod:`tony_tpu.profiler`).
+    ``tony:restore``, the first ``step_fn`` call — the one that traces,
+    lowers and compiles or loads the step — the set-up span
+    ``tony:first_step`` with what it built as attrs, and the snapshot
+    stalls add up in the counters ``saves`` / ``save_stall_s``
+    (:mod:`tony_tpu.profiler`).
 
     Returns ``(state, last_metrics)``.
     """
@@ -850,7 +887,7 @@ def train_loop(state: TrainState, step_fn: Callable[[TrainState, Any],
         t0 = time.perf_counter()
         with profiler.span("train:save", step=step):
             mgr.save(payload(), step=step)
-        profiler.add_seconds("save_stall_s", time.perf_counter() - t0)
+        profiler.count("save_stall_s", time.perf_counter() - t0)
         profiler.count("saves")
 
     end = object()
@@ -861,8 +898,12 @@ def train_loop(state: TrainState, step_fn: Callable[[TrainState, Any],
                 batch = next(feed, end)
             if batch is end:
                 break
+            # One call site for every step: the call stack above a Pallas
+            # kernel is part of its compile-cache key.
             with jax.profiler.StepTraceAnnotation("train_step",
-                                                  step_num=done):
+                                                  step_num=done), \
+                    (_first_step() if done == 0
+                     else contextlib.nullcontext()):
                 state, metrics = step_fn(state, batch)
             done += 1
             if done == 1:
