@@ -4,7 +4,13 @@ weights: the system (``get_model`` -> ``create_train_state`` ->
 reference — both losses, every gradient leaf, two AdamW steps — the
 stop-gradients between the two objectives, the exact threshold against
 ``jax.lax.top_k``, and the selected-attention grids against the reference
-attention with the same membership (Pallas interpreter)."""
+attention with the same membership (Pallas interpreter). And that the
+indexer's loss runs once a layer (ISSUE 37): under ``remat.block`` its
+gradient, taken in the forward and kept as the three kernels', is the
+two-pass path's, and the backward holds none of its work."""
+
+import collections
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +21,7 @@ import pytest
 from benchmark import modelcfg_keyevl2 as mc
 from benchmark import reference, reference_keyevl2 as ref
 from benchmark import weights_keyevl2 as wk
-from tony_tpu import train
+from tony_tpu import profiler, remat, train
 from tony_tpu.models import get_model, moe
 from tony_tpu.ops import attention as att
 from tony_tpu.ops import indexer
@@ -35,9 +41,9 @@ def small_blocks():
         yield
 
 
-def _model():
+def _model(**over):
     kw = mc.program_kwargs(CFG, S)
-    kw.update(remat=False, dtype=jnp.float32)
+    kw.update({"remat": False, "dtype": jnp.float32, **over})
     return get_model(CFG["program"]["model"], **kw)
 
 
@@ -110,12 +116,14 @@ def test_two_adamw_steps_match_the_reference():
     assert len(stats) == 4 and all(s.shape == (CFG["layers"],) for s in stats)
 
 
+@pytest.mark.parametrize("blocks", [False, True], ids=["plain", "remat"])
 @pytest.mark.parametrize("objective, zero, live", [
     ("L_LM", wk.INDEX_LEAVES, ("wq", "w_gate", "embed")),
     ("L_I", tuple(n for n in LEAVES if n not in wk.INDEX_LEAVES),
      wk.INDEX_LEAVES)])
-def test_each_objective_reaches_its_own_leaves_only(objective, zero, live):
-    model, w0, (x,) = _model(), wk.make_weights(CFG, 5), _tokens(2)
+def test_each_objective_reaches_its_own_leaves_only(objective, zero, live,
+                                                    blocks):
+    model, w0, (x,) = _model(remat=blocks), wk.make_weights(CFG, 5), _tokens(2)
 
     def one(params):
         lm, sown = model.apply({"params": params}, x, targets=x,
@@ -128,6 +136,152 @@ def test_each_objective_reaches_its_own_leaves_only(objective, zero, live):
         assert float(jnp.abs(g[leaf]).max()) == 0.0, leaf
     for leaf in live:
         assert float(jnp.abs(g[leaf]).max()) > 0.0, leaf
+
+
+# ---------------------------------------------------- one pass a layer
+
+def _unrolled(params):
+    """The scanned model's stacked tree as the unrolled model's."""
+    rest = {k: v for k, v in params.items() if k != "layers"}
+    return {**rest, **{f"layer_{i}": jax.tree.map(lambda a: a[i],
+                                                  params["layers"])
+                       for i in range(CFG["layers"])}}
+
+
+def _restacked(grads):
+    layers = [grads.pop(f"layer_{i}") for i in range(CFG["layers"])]
+    return {**grads, "layers": jax.tree.map(lambda *a: jnp.stack(a), *layers)}
+
+
+def _both_losses(model, rung, x):
+    """``params -> (L_LM + L_I, L_I)`` of a step that keeps ``rung``
+    (None: no step around the trace)."""
+    def objective(params):
+        with remat.Saved(rung) if rung is not None \
+                else contextlib.nullcontext():
+            lm, sown = model.apply({"params": params}, x, targets=x,
+                                   mutable="losses")
+        kl = sum(leaf.sum() for leaf in jax.tree.leaves(sown))
+        return lm + kl, kl
+    return objective
+
+
+@pytest.fixture(scope="module")
+def two_pass():
+    """The path before ISSUE 37: no remat, the loss differentiated with
+    respect to ``qI, w, kI`` and the projections transposed by autodiff in
+    the backward."""
+    (x,) = _tokens(4)
+    params = wk.to_program_tree(wk.make_weights(CFG, 11))
+    real = indexer.index_loss
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(indexer, "index_loss", lambda *a, through, **kw: real(
+            *a, through=(lambda given: given, a[:3]), **kw))
+        (_, kl), g = jax.value_and_grad(
+            _both_losses(_model(), None, x), has_aux=True)(params)
+    return x, params, kl, g
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scanned", "unrolled"])
+@pytest.mark.parametrize("rung", [remat.FLOOR, ("sel",)], ids=["floor", "sel"])
+def test_one_pass_gives_the_two_pass_loss_and_gradients(two_pass, rung, scan):
+    x, params, want_kl, want = two_pass
+    model = _model(remat=True, scan_layers=scan)
+    (_, kl), g = jax.jit(jax.value_and_grad(
+        _both_losses(model, rung, x), has_aux=True))(
+            params if scan else _unrolled(params))
+    g = g if scan else _restacked(g)
+    assert float(kl) == pytest.approx(float(want_kl), rel=1e-6)
+    got, want = wk.from_program_tree(g), wk.from_program_tree(want)
+    for leaf in LEAVES:
+        a, c = np.asarray(got[leaf]), np.asarray(want[leaf])
+        tight = 1e-6 if leaf in wk.INDEX_LEAVES else 1e-5
+        assert np.linalg.norm(a - c) <= tight * np.linalg.norm(c), leaf
+
+
+def _walk(jaxpr, under=()):
+    for i, eqn in enumerate(jaxpr.eqns):
+        yield eqn, under
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub, under + ((eqn.primitive.name, i),))
+
+
+def _loss_work(model, params, x, rung=remat.FLOOR):
+    """Where the loss's work is in ``value_and_grad`` of a step: for each
+    enclosing equation (the layers' scans; () for an unrolled model), the
+    row blocks the loss walks (one barrier each, and nothing else in the
+    model has one) and the matmuls under its scope."""
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        _both_losses(model, rung, x), has_aux=True))(params)
+    blocks, dots = collections.Counter(), collections.Counter()
+    for eqn, under in _walk(jaxpr.jaxpr):
+        if eqn.primitive.name == "optimization_barrier":
+            blocks[under[:1]] += 1
+        elif eqn.primitive.name == "dot_general" and "attn_index_loss" in str(
+                eqn.source_info.name_stack):
+            dots[under[:1]] += 1
+    return blocks, dots
+
+
+@pytest.mark.parametrize("rung", [remat.FLOOR, ("sel",)], ids=["floor", "sel"])
+def test_the_loss_runs_once_a_layer_and_not_in_the_backward(rung, monkeypatch):
+    (x,) = _tokens(4)
+    params = wk.to_program_tree(wk.make_weights(CFG, 11))
+    layers, row_blocks = CFG["layers"], S // indexer.ROW_BLOCK
+    assert layers == 2 and row_blocks == 2
+    # Scanned: the forward's scan holds a layer's loss, the backward's none.
+    blocks, dots = _loss_work(_model(remat=True), params, x, rung)
+    (fwd, n), = blocks.items()
+    assert n == row_blocks and fwd[0][0] == "scan"
+    assert set(dots) == {fwd} and dots[fwd] > 0
+    # Unrolled: once for each layer, in the forward (under no equation);
+    # none in the backward's recomputation of a layer (a ``remat2``).
+    unrolled = _model(remat=True, scan_layers=False)
+    blocks, dots = _loss_work(unrolled, _unrolled(params), x, rung)
+    assert blocks == {(): layers * row_blocks} and set(dots) == {()}
+    # What the count sees: a block that does not keep the name runs the
+    # loss again where it recomputes the layer.
+    real = remat.block
+    monkeypatch.setattr(remat, "block", lambda cls, always=(): real(cls))
+    blocks, dots = _loss_work(unrolled, _unrolled(params), x, rung)
+    again = {under for under in blocks if under}
+    assert len(again) == layers and {u[0][0] for u in again} == {"remat2"}
+    assert all(blocks[under] == row_blocks and dots[under] for under in again)
+    assert blocks[()] == layers * row_blocks
+
+
+@pytest.mark.parametrize("rung, head_in_forward", [
+    (remat.FLOOR, True), (("sel",), False)], ids=["floor", "sel"])
+def test_the_kept_gradient_is_on_no_rung(rung, head_in_forward):
+    """``index_grad`` is kept by every step and is not of its set: at the
+    floor ``kept()`` is empty and the chunked head takes its gradient in
+    the forward, as in a model without an indexer."""
+    model, (x,) = _model(remat=True), _tokens(4)
+    state = train.create_train_state(
+        model, optax.adamw(LR), jnp.zeros((B, S), jnp.int32),
+        jax.random.PRNGKey(0))
+    step = train.make_train_step(
+        loss_of=lambda loss, b: loss,
+        apply_kwargs_of=lambda b: {"targets": b["x"]})
+    saved = remat.Saved(rung)
+    kept_at_the_head = []
+    real = train.chunked_next_token_xent
+    profiler.reset_timeline()
+    try:
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(train, "chunked_next_token_xent", lambda *a, **kw: (
+                kept_at_the_head.append(remat.kept()), real(*a, **kw))[1])
+            jax.eval_shape(step.build(saved), state, {"x": x})
+        c = profiler.counters()
+    finally:
+        profiler.reset_timeline()
+    assert kept_at_the_head and set(kept_at_the_head) == {rung}
+    assert saved.names == rung and "index_grad" in saved.met
+    assert c["remat:saved.index_grad"] == 1
+    assert saved.effective(remat.LADDER[0]) == ("sel", "q", "k", "v", "wo")
+    assert c["index:grad_in_forward"] == 1
+    assert ("head:grad_in_forward" in c) == head_in_forward
+    assert "index_grad" not in {n for r in remat.LADDER for n in r}
 
 
 def _top_k_membership(scores, row0, k):
@@ -259,8 +413,9 @@ def test_index_scores_kernel_and_loss_backward(monkeypatch):
         return jnp.sum(jnp.where(p > 0, p * (jnp.log(jnp.where(
             p > 0, p, 1.0)) - logq), 0.0)) / (b * t)
 
-    loss = lambda *a: indexer.index_loss(*a, sel, q, kk, lse, h,
-                                         interpret=True)
+    loss = lambda *a: indexer.index_loss(
+        *a, sel, q, kk, lse, h, through=(lambda given: given, a),
+        interpret=True)
     assert float(loss(qi, w, ki)) == pytest.approx(
         float(plain(qi, w, ki)), rel=1e-5)
     got = jax.grad(loss, (0, 1, 2))(qi, w, ki)

@@ -242,6 +242,42 @@ def test_a_learned_selection_is_the_last_residual_to_go(
     assert compiler.traced[:2] == list(table)[:2]
 
 
+@pytest.mark.parametrize("in_step", [True, False], ids=["step", "no-step"])
+def test_a_name_kept_always_is_on_the_steps_timeline_and_on_no_rung(in_step):
+    """The indexer's kept gradients (ISSUE 37): the model hands the name to
+    ``block``, whose policy keeps it beside the step's set; the set, what
+    ``kept()`` says and the ladder do not change; a step's timeline says
+    so, ``model.init``'s does not."""
+    import contextlib
+
+    import flax.linen as nn
+    from jax._src.ad_checkpoint import name_p
+
+    policies, real = [], nn.remat
+    profiler.reset_timeline()
+    try:
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(nn, "remat", lambda cls, **k: (
+                policies.append(k["policy"]), real(cls, **k))[1])
+            with remat.Saved(("sel",)) if in_step \
+                    else contextlib.nullcontext() as saved:
+                remat.block(nn.Dense, always=("index_grad",))
+                assert remat.kept() == ()                   # nothing met yet
+                remat.name(jnp.zeros(()), "index_grad")
+                assert remat.kept() == ()                   # not of the set
+        c = profiler.counters()
+    finally:
+        profiler.reset_timeline()
+    (policy,) = policies
+    assert {n for n in ("sel", "q", "index_grad") if policy(name_p, name=n)} \
+        == ({"sel", "index_grad"} if in_step else {"index_grad"})
+    assert ("remat:saved.index_grad" in c) == in_step
+    if in_step:
+        assert saved.names == ("sel",) and saved.blocks == 1
+        assert saved.effective(remat.LADDER[0]) == ()
+    assert not any("index_grad" in rung for rung in remat.LADDER)
+
+
 @pytest.mark.parametrize("fake", [
     {"model_names": {"flash_out", "flash_lse"}}, {"blocks": 0}],
     ids=["none-of-the-names", "remat-off"])
@@ -334,6 +370,36 @@ def test_a_name_the_model_lacks_leaves_its_program_alone(model_name, kw, has):
             params).as_text())
         assert saved.effective(remat.LADDER[0]) == has
     assert texts[0] == texts[1]
+
+
+@pytest.mark.parametrize("model_name, kw, always", [
+    ("llama-tiny", {}, ()), ("hybrid-tiny", {"xent_chunk": 8}, ()),
+    ("zaya-tiny", {"xent_chunk": 8}, ()),
+    ("keye-tiny", {"xent_chunk": 8}, ("index_grad",))])
+def test_only_a_model_with_an_indexer_gets_one_more_name(
+        model_name, kw, always, monkeypatch):
+    """What ``block`` hands ``nn.remat``: at the floor no policy at all for
+    a model without an indexer (the program it built before ISSUE 37) and
+    the one name for a model with one; on a rung the rung's names, and that
+    name beside them."""
+    import flax.linen as nn
+    from jax._src.ad_checkpoint import name_p
+
+    policies, real = [], nn.remat
+    monkeypatch.setattr(nn, "remat", lambda cls, **k: (
+        policies.append(k["policy"]), real(cls, **k))[1])
+    every = set(remat.LADDER[0]) | {"index_grad", "flash_out"}
+    for rung in (remat.FLOOR, ("sel", "q")):
+        loss, params, saved = _loss_fn(model_name, rung, **kw)
+        del policies[:]                 # model.init's: no step around it
+        jax.eval_shape(loss, params)
+        assert policies
+        for policy in policies:
+            if not rung and not always:
+                assert policy is None
+                continue
+            assert {n for n in every if policy(name_p, name=n)} == {
+                *rung, *always}
 
 
 def test_names_are_inert_outside_a_step():

@@ -478,7 +478,11 @@ class Attention(nn.Module):
     def _selected(self, x, q, k, v, positions):
         """Training-mode attention with q/k-norm and/or a learned
         selection, over the packed layout: returns the ``[B, T, H·D]``
-        output before ``wo`` and sows the indexer's loss."""
+        output before ``wo`` and sows the indexer's loss. The indexer's
+        ``qI, w, kI`` are made once, select, and are handed to the loss
+        with the three kernels they came through: the loss runs once a
+        layer and leaves the kernels' gradients as its residuals
+        (:func:`tony_tpu.ops.indexer.index_loss`)."""
         from tony_tpu.ops import attention as att
         from tony_tpu.ops import indexer
 
@@ -499,21 +503,39 @@ class Attention(nn.Module):
         # its three projections learn from their own loss alone.
         xi = jax.lax.stop_gradient(x)
         nj, ne = cfg.index_heads, cfg.index_dim
-        proj = lambda feats, name: _proj_dense(
-            cfg, "qkv", feats, ("embed", None), name)(xi)
-        with jax.named_scope("attn_index"):
-            qi = rope(proj(nj * ne, "index_wq").reshape(b, t, nj, ne),
-                      positions, cfg.rope_theta, seq_axis=1)
-            ki = rope(proj(ne, "index_wk").reshape(b, t, 1, ne), positions,
-                      cfg.rope_theta, seq_axis=1).reshape(b, t, ne)
-            wi = proj(nj, "index_w").astype(jnp.float32) * (nj * ne) ** -0.5
+        denses = {name: _proj_dense(cfg, "qkv", feats, ("embed", None), name)
+                  for name, feats in (("index_wq", nj * ne), ("index_wk", ne),
+                                      ("index_w", nj))}
+
+        def project(dense, positions):
+            """``qI, w, kI`` of the three projections ``dense(name)``."""
+            with jax.named_scope("attn_index"):
+                qi = rope(dense("index_wq").reshape(b, t, nj, ne), positions,
+                          cfg.rope_theta, seq_axis=1)
+                ki = rope(dense("index_wk").reshape(b, t, 1, ne), positions,
+                          cfg.rope_theta, seq_axis=1).reshape(b, t, ne)
+                wi = dense("index_w").astype(jnp.float32) * (nj * ne) ** -0.5
+            return qi, wi, ki
+
+        def of_kernels(kernels, xi, positions):
+            """:func:`project` as a function of the kernels: what the
+            loss transposes to hand them its gradient."""
+            return project(lambda name: denses[name].apply(
+                {"params": {"kernel": kernels[name]}}, xi), positions)
+
+        qi, wi, ki = project(lambda name: denses[name](xi), positions)
+        kernels = {name: nn.unbox(dense.variables["params"]["kernel"])
+                   for name, dense in denses.items()}
         # Kernels on a TPU, their jax.numpy twins elsewhere (the ops
         # decide by backend, as flash_attention does).
         sel = remat.name(indexer.select(qi, wi, ki, cfg.index_topk), "sel")
         if t % 128 == 0:
             _count_selection(t, cfg.index_topk, hd, v.dtype.itemsize)
         out, lse = att.flash_attention_selected(q3, k3, v, sel, nh)
-        loss = indexer.index_loss(qi, wi, ki, sel, q3, k3, lse, nh)
+        # One pass a layer: the loss takes its gradient with its value and
+        # keeps it as the three kernels' (remat.block keeps "index_grad").
+        loss = indexer.index_loss(qi, wi, ki, sel, q3, k3, lse, nh,
+                                  through=(of_kernels, kernels, xi, positions))
         self.sow("losses", "index_kl", loss,
                  reduce_fn=lambda a, c: a + c,
                  init_fn=lambda: jnp.float32(0.0))
@@ -672,7 +694,10 @@ class Transformer(nn.Module):
             # first: zeros, whatever the first layer's decay.
             x = (x, jnp.zeros((*tokens.shape, cfg.router_hidden),
                               jnp.float32))
-        block_cls = remat.block(ScannedBlock) if cfg.remat else ScannedBlock
+        # A model with an indexer keeps its loss's residuals on every rung.
+        block_cls = remat.block(
+            ScannedBlock, always=("index_grad",) if cfg.index_heads else ()
+        ) if cfg.remat else ScannedBlock
         new_kv = None
         if cfg.scan_layers:
             if kv is not None:

@@ -24,6 +24,19 @@ same row blocks: scores again, the probabilities pass
 (:func:`tony_tpu.ops.attention.selected_head_probs`), the KL and its
 backward into ``qI``, ``kI`` and ``w``.
 
+The loss runs **once a layer**. Its gradient is taken with its value, in
+the forward, and pulled back through whatever made ``qI``, ``w`` and ``kI``
+(``through``: the model's three projections, scale and rotation) to the
+parameters behind them; those gradients — three small matrices a layer, not
+three ``[T, ...]`` activations — are the loss's only residuals, named
+``index_grad`` (:func:`tony_tpu.remat.name`), and the backward scales them
+by the cotangent. A block under :func:`tony_tpu.remat.block` keeps that
+name whatever else its step keeps, so remat's second forward holds none of
+the loss's work. Everything else the loss reads (``sel``, the main
+attention's ``q``, ``k``, ``lse``, the projections' input) is a constant to
+it. Trace-time counter: ``index:grad_in_forward`` (1 once that pass was
+traced).
+
 Device scopes: ``attn_index`` (scores), ``attn_select`` (threshold, ties,
 packing), ``attn_index_loss`` (probabilities, KL and its backward).
 """
@@ -37,6 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from tony_tpu import profiler, remat
 from tony_tpu.ops import attention as att
 
 
@@ -278,40 +292,55 @@ def _loss_blocks(qi, w, ki, sel, q, k, lse, heads, scale, interpret,
                   dk)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def _index_loss(qi, w, ki, sel, q, k, lse, heads, scale, interpret):
-    return _loss_blocks(qi, w, ki, sel, q, k, lse, heads, scale, interpret,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 8, 9, 10))
+def _index_loss(project, params, inputs, projected, sel, q, k, lse, heads,
+                scale, interpret):
+    """``L_I`` of ``projected = project(params, *inputs)``, differentiated
+    with respect to ``params`` alone."""
+    return _loss_blocks(*projected, sel, q, k, lse, heads, scale, interpret,
                         False)[0]
 
 
-def _index_loss_fwd(qi, w, ki, sel, q, k, lse, heads, scale, interpret):
+def _index_loss_fwd(project, params, inputs, projected, sel, q, k, lse,
+                    heads, scale, interpret):
+    profiler.count_once("index:grad_in_forward", 1)
     # The gradient is made with the value: the probabilities and the
     # scores of a row block are then computed once for both.
-    loss, (dq, dw, dk) = _loss_blocks(qi, w, ki, sel, q, k, lse, heads,
-                                      scale, interpret, True)
-    return loss, (dq.astype(qi.dtype), dw.astype(w.dtype),
-                  dk.astype(ki.dtype))
+    loss, grads = _loss_blocks(*projected, sel, q, k, lse, heads, scale,
+                               interpret, True)
+    # Only the transpose of ``project`` is run: its value is ``projected``,
+    # which the caller made (and selected with), and is dead here.
+    _, pull = jax.vjp(lambda p: project(p, *inputs), params)
+    (grads,) = pull(tuple(g.astype(a.dtype)
+                          for g, a in zip(grads, projected)))
+    return loss, jax.tree.map(lambda g: remat.name(g, "index_grad"), grads)
 
 
-def _index_loss_bwd(heads, scale, interpret, grads, g):
-    dq, dw, dk = grads
-    return ((g * dq).astype(dq.dtype), (g * dw).astype(dw.dtype),
-            (g * dk).astype(dk.dtype), None, None, None, None)
+def _index_loss_bwd(project, heads, scale, interpret, grads, g):
+    return (jax.tree.map(lambda a: (g * a).astype(a.dtype), grads),
+            *[None] * 6)
 
 
 _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 
-def index_loss(qi, w, ki, sel, q, k, lse, heads: int,
+def index_loss(qi, w, ki, sel, q, k, lse, heads: int, through,
                scale: Optional[float] = None,
                interpret: Optional[bool] = None):
     """``L_I``: the mean over queries of ``KL(p_t || softmax_{S_t} I_t)``.
     ``q [B, T, H·D]``, ``k [B, T, Hkv·D]`` and ``lse [B, H, T]`` are the
-    main attention's (after rotary), read as constants, as is ``sel``;
-    the gradient reaches ``qi``, ``w`` and ``ki`` alone."""
+    main attention's (after rotary), read as constants, as is ``sel``.
+
+    ``through = (project, params, *inputs)`` says where ``qi, w, ki`` come
+    from: they are ``project(params, *inputs)``, made by the caller (who
+    selected with them), and a constant here too. The gradient reaches
+    ``params`` alone (a pytree; ``project`` closes over no array), and is
+    the loss's only residual (``index_grad``)."""
     d = q.shape[-1] // heads
     scale = d ** -0.5 if scale is None else scale
-    sel, q, k, lse = jax.lax.stop_gradient((sel, q, k, lse))
+    project, params, *inputs = through
+    constants = jax.lax.stop_gradient(
+        (tuple(inputs), (qi, w, ki), sel, q, k, lse))
     with jax.named_scope("attn_index_loss"):
-        return _index_loss(qi, w, ki, sel, q, k, lse, heads, scale,
+        return _index_loss(project, params, *constants, heads, scale,
                            interpret)

@@ -10,10 +10,14 @@ not add up: PERF.md §7), so the program asks the compiler:
 * the models **name** the candidates where they are made (:func:`name`, a
   ``checkpoint_name``): ``q``, ``k``, ``v``, ``wo``, ``gate``, ``up``,
   ``flash_out``, ``flash_lse``, ``sel``. A name costs nothing until a policy asks
-  for it;
+  for it. One more name is no candidate: ``index_grad``, the gradients an
+  indexer's loss takes in its forward (tony_tpu.ops.indexer: three small
+  matrices a layer against a second run of the whole loss), which a model
+  that has an indexer keeps in every step, the floor included;
 * :func:`block` is the one ``nn.remat`` both decoders wrap their layer in;
   it keeps the names of the :class:`Saved` the step entered around the
-  model's trace, and nothing outside one (``model.init``, serving);
+  model's trace and the names the model tells it to keep always, and
+  nothing else outside a step (``model.init``, serving);
 * :class:`ChosenStep` is what ``make_train_step`` returns: at its first
   call it walks :data:`LADDER`, richest set first, compiles each candidate
   step and takes the first whose ``memory_analysis()`` total leaves
@@ -55,7 +59,11 @@ _log = logging.getLogger(__name__)
 # causal pair against a second scoring and 32-pass threshold of the whole
 # triangle in the backward), is on every rung and is the last to go: a
 # model without an indexer never meets the name, and its rungs are these
-# without it (``Saved.effective``).
+# without it (``Saved.effective``). ``index_grad`` is on no rung and not
+# the ladder's to give up: a model with an indexer hands it to ``block``
+# itself, for every step (9 MB a layer; without it the indexer's loss
+# runs twice), and the floor stays the empty set — ``kept()`` does not
+# count it and the memo's key does not hold it.
 LADDER: Tuple[Tuple[str, ...], ...] = (
     ("sel", "q", "k", "v", "wo", "gate", "up"),
     ("sel", "q", "k", "v", "gate", "up"),
@@ -121,15 +129,21 @@ def kept() -> Tuple[str, ...]:
     return active.effective(active.names) if active is not None else FLOOR
 
 
-def block(layer_cls):
+def block(layer_cls, always: Tuple[str, ...] = ()):
     """``layer_cls`` under ``nn.remat``: its backward recomputes the layer
-    from its input, but for the names the step's :class:`Saved` keeps."""
+    from its input, but for the names the step's :class:`Saved` keeps and
+    the names the model says its layers ``always`` keep (on no rung: the
+    step's set, :func:`kept` and the ladder do not hear of them; a step's
+    timeline does, as ``remat:saved.<name>``). A model that has none gets
+    the policy it would get without the argument."""
     import flax.linen as nn
 
     active = _ACTIVE.get()
-    names = active.names if active is not None else FLOOR
+    names = (active.names if active is not None else FLOOR) + tuple(always)
     if active is not None:
         active.blocks += 1
+        for n in always:
+            profiler.count_once(f"remat:saved.{n}", 1)
     policy = jax.checkpoint_policies.save_only_these_names(*names) \
         if names else None
     return nn.remat(layer_cls, prevent_cse=False, policy=policy)
