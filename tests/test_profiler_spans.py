@@ -789,8 +789,10 @@ def test_a_cold_first_step_holds_one_rung_span_for_each_rung_tried(
     assert [(r["name"], r["parent"]) for r in rungs] == \
         [("tony:remat_rung", "tony:first_step")] * 2
     assert [r["attrs"] for r in rungs] == [
-        {"saved": ",".join(FULL), "bytes": None, "fits": False},
-        {"saved": ",".join(NO_WO), "bytes": MISTRAL[NO_WO], "fits": True}]
+        {"saved": ",".join(FULL), "bytes": None, "fits": False,
+         "prevent_cse": False},
+        {"saved": ",".join(NO_WO), "bytes": MISTRAL[NO_WO], "fits": True,
+         "prevent_cse": False}]
     assert first["t0"] <= rungs[0]["t0"] <= rungs[0]["t1"] \
         <= rungs[1]["t0"] <= rungs[1]["t1"] <= first["t1"]
     first, rungs = start()

@@ -8,6 +8,7 @@ real step keeps nothing (the backend reports no limit).
 """
 
 import collections
+import contextlib
 import re
 
 import jax
@@ -26,6 +27,7 @@ GiB = 1 << 30
 FULL, NO_WO, MLP, QKV, _ = (tuple(n for n in rung if n != "sel")
                             for rung in remat.LADDER)
 RUNGS = (FULL, NO_WO, MLP, QKV, remat.FLOOR)
+FENCED = ("prevent_cse",)    # the fake's key of the floor with prevent_cse
 ALL_NAMES = set(FULL) | {"flash_out", "flash_lse"}
 
 
@@ -45,6 +47,8 @@ class FakeCompiler:
         outer = self
         rung = tuple(n for n in saved.names
                      if self.blocks and n in self.names)
+        if saved.prevent_cse:
+            rung += FENCED
 
         class Traced:
             def lower(self):
@@ -180,6 +184,42 @@ def test_floor_is_taken_whatever_the_margin_says(chooser):
     assert names == remat.FLOOR
     assert compiler.compiled == list(RUNGS)
     assert profiler.counters()["remat:step_bytes"] == LIMIT - 1
+
+
+def test_a_refused_floor_is_tried_again_with_its_second_forward_fenced(
+        chooser):
+    """Unrolled layers whose second forward XLA merges with the first can
+    hold more than the chip has (the 32k Kimi Linear step): the floor is
+    then compiled once more under ``prevent_cse``; the memo remembers
+    which floor it was, and a warm start builds that one."""
+    table = {**{r: None for r in RUNGS}, FENCED: int(14.76 * GiB)}
+    names, compiler = chooser(table)
+    assert names == FENCED
+    assert compiler.compiled == list(RUNGS) + [FENCED]
+    c = profiler.counters()
+    assert (c["remat:rungs_tried"], c["remat:rungs_refused"],
+            c["remat:prevent_cse"]) == (6, 5, 1)
+    assert c["remat:step_bytes"] == table[FENCED]
+    assert [r["prevent_cse"] for r in profiler.report("remat")[
+        "train_step"]["rungs"]] == [False] * 5 + [True]
+    profiler.reset_timeline()
+    names, compiler = chooser(table)
+    assert names == FENCED and compiler.compiled == []
+    c = profiler.counters()
+    assert (c["remat:from_memo"], c["remat:prevent_cse"]) == (1, 1)
+
+
+def test_a_floor_that_compiles_is_never_fenced(chooser):
+    names, compiler = chooser({**{r: LIMIT - 1 for r in RUNGS},
+                               FENCED: GiB})
+    assert names == remat.FLOOR and FENCED not in compiler.compiled
+    assert "remat:prevent_cse" not in profiler.counters()
+
+
+def test_a_refused_fenced_floor_is_the_steps_error(chooser):
+    with pytest.raises(jax.errors.JaxRuntimeError,
+                       match="RESOURCE_EXHAUSTED"):
+        chooser({**{r: None for r in RUNGS}, FENCED: None})
 
 
 def test_no_limit_reported_keeps_nothing_and_compiles_nothing(chooser):
@@ -400,6 +440,34 @@ def test_only_a_model_with_an_indexer_gets_one_more_name(
                 continue
             assert {n for n in every if policy(name_p, name=n)} == {
                 *rung, *always}
+
+
+@pytest.mark.parametrize("model_name, kw", [
+    ("llama-tiny", {"remat": True}), ("kimi-linear-tiny", {"remat": True})],
+    ids=["scanned", "unrolled"])
+def test_only_a_fenced_step_puts_barriers_around_its_layers(model_name, kw):
+    """``Saved(prevent_cse=True)`` is heard by :func:`remat.block` in both
+    decoders; every other step, and a trace outside a step, is the program
+    it was (``prevent_cse=False`` on every layer's ``checkpoint``; the
+    barriers themselves are put in when the step is lowered)."""
+    tokens = jnp.zeros((1, 64), jnp.int32)
+    model = get_model(model_name, **kw)
+    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+
+    def barriers(saved):
+        def loss(p):
+            out = model.apply({"params": p}, tokens,
+                              mutable=["losses", "stats"])[0]
+            return jnp.sum(out.astype(jnp.float32))
+        with saved or contextlib.nullcontext():
+            text = str(jax.make_jaxpr(jax.grad(loss))(params))
+        return text.count("prevent_cse=True"), text.count("prevent_cse=False")
+
+    # (an expert layer's per-chunk ``jax.checkpoint`` is fenced always)
+    fenced, merged = barriers(None)
+    assert merged > 0
+    assert barriers(remat.Saved()) == (fenced, merged)
+    assert barriers(remat.Saved(prevent_cse=True)) == (fenced + merged, 0)
 
 
 def test_names_are_inert_outside_a_step():

@@ -170,14 +170,16 @@ def _head_probs(topo):
         (q, k, lse, sel))
 
 
-def _grouped_experts(grad, rows=8192, experts=16, ffn=768):
+def _grouped_experts(grad, rows=8192, experts=16, ffn=768, dim=2048):
     """The dropless layer's grouped matmul (``ops.gmm``) at one chunk's
     worst case, 8192 sorted rows over 16 experts: into the expert width
     (2048 x 768, as ``w_gate`` / ``w_up``) and back (768 x 2048, as
     ``w_down``); with ``grad`` the transposed and the weight-gradient
     kernels too. (4096 rows over 8 experts of 2048 x 2048: the zaya1-8b
     cell's chunk, whose weight blocks are the widest any cell hands the
-    kernels.)"""
+    kernels; 1024 rows over 8 experts of 2304 x 1024: a pass of the
+    kimi-linear-48b-a3b cell's chunk, ``moe.rows_buffer(1024, 8, 8, 256)``
+    rows at a model width that is no multiple of 512.)"""
     from tony_tpu.ops.gmm import grouped_matmul
 
     def fwd(x, w_in, w_out, sizes):
@@ -186,10 +188,10 @@ def _grouped_experts(grad, rows=8192, experts=16, ffn=768):
 
     def build(topo):
         sh = _one(topo)
-        x = jax.ShapeDtypeStruct((rows, 2048), jnp.bfloat16, sharding=sh)
-        w_in = jax.ShapeDtypeStruct((experts, 2048, ffn), jnp.bfloat16,
+        x = jax.ShapeDtypeStruct((rows, dim), jnp.bfloat16, sharding=sh)
+        w_in = jax.ShapeDtypeStruct((experts, dim, ffn), jnp.bfloat16,
                                     sharding=sh)
-        w_out = jax.ShapeDtypeStruct((experts, ffn, 2048), jnp.bfloat16,
+        w_out = jax.ShapeDtypeStruct((experts, ffn, dim), jnp.bfloat16,
                                      sharding=sh)
         sizes = jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=sh)
         if not grad:
@@ -208,6 +210,8 @@ CASES = {
     "grouped_experts_fwd_bwd_8192x16": _grouped_experts(grad=True),
     "grouped_experts_fwd_bwd_4096x8_wide": _grouped_experts(
         grad=True, rows=4096, experts=8, ffn=2048),
+    "grouped_experts_fwd_bwd_1024x8_dim2304": _grouped_experts(
+        grad=True, rows=1024, experts=8, ffn=1024, dim=2304),
     "flash_packed_fwd_bwd_latent8over2_t32768": _packed(
         grad=True, hkv=2, t=32768, h=8),
     "flash_packed_fwd_mha": _packed(grad=False, hkv=H),

@@ -1,7 +1,10 @@
 """Compile what the layer-kind decoder adds for a DESCRIBED v5e at the
 widths ``phi4flash.train-8k`` runs (1 x 8192 tokens; scan over E = 5120,
 N = 16; 40 zero-extended query heads of 128 over 10 K/V heads of 128,
-window 512): as ``tests/test_tpu_compile.py``, a pass is a COMPILE for a
+window 512) and ``kimilinear.train-32k`` runs (1 x 32768 tokens; the KDA
+chunk kernels over 32 heads of 128 x 128; the latent-attention grids at a
+192-wide q/k — 128 of a head's own and 64 shared — over 128-wide values,
+32 heads): as ``tests/test_tpu_compile.py``, a pass is a COMPILE for a
 chip that is not attached — what Mosaic refuses there (a block off the
 tiling, too much VMEM, an SMEM block it cannot place) is refused here."""
 
@@ -61,7 +64,41 @@ def _attention(window, block):
     return build
 
 
+T32K, KH = 32768, 32       # kimilinear.train-32k
+
+
+def _kda(grad):
+    from tony_tpu.ops.kda import kda
+
+    def fwd(*args):
+        return kda(*args, interpret=False)
+
+    def build(sh):
+        s = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                       sharding=sh)
+        args = (s(jnp.bfloat16, 1, T32K, KH, D),) * 3 + (
+            s(jnp.float32, 1, T32K, KH, D), s(jnp.float32, 1, T32K, KH))
+        if not grad:
+            return fwd, args
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        range(5)), args
+    return build
+
+
+def _mla(sh):
+    from tony_tpu.ops.attention import flash_attention_mla
+
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sh)
+    args = (s(1, T32K, KH * D), s(1, KH, T32K, 64), s(1, T32K, KH * D),
+            s(1, T32K, 64), s(1, T32K, KH * D))
+    return jax.grad(lambda *a: flash_attention_mla(
+        *a, KH, interpret=False).astype(jnp.float32).sum(), range(5)), args
+
+
 CASES = {
+    "kda_chunk_fwd_32x128_t32768": _kda(grad=False),
+    "kda_chunk_fwd_bwd_32x128_t32768": _kda(grad=True),
+    "flash_mla_fwd_bwd_32x192over128_t32768": _mla,
     "ssm_scan_fwd": _scan(grad=False),
     "ssm_scan_fwd_bwd": _scan(grad=True),
     "ssm_scan_fwd_bwd_bfloat16_state": _scan(True, jnp.bfloat16),
@@ -86,6 +123,21 @@ def test_kernel_compiles_for_v5e(topo, case):
         # the [T, E, N] state is never a buffer of the program
         assert f"{T},{E},{N}]" not in text and f"{T},{N},{E}]" not in text
         assert "ssm_scan_fwd" in text
+    elif case.startswith("kda"):
+        assert "kda_chunk_fwd" in text
+        assert ("kda_chunk_bwd" in text) == ("bwd" in case)
+        # the backward starts from kept states, a step of chunks apart:
+        # [1, heads, steps, 128, 128] float32, never one a chunk
+        from tony_tpu.ops import kda as kda_ops
+        kept, chunks = kda_ops.states_kept(T32K), kda_ops.n_chunks(T32K)
+        assert kept < chunks
+        assert f"f32[1,{KH},{kept},{D},{D}]" in text
+        assert f"f32[1,{KH},{chunks},{D},{D}]" not in text
+    elif case.startswith("flash_mla"):
+        for name in ("attn_fwd_mla", "attn_bwd_dq_mla", "attn_bwd_dkv_mla"):
+            assert name in text, name
+        # no operand padded to a 256-wide head
+        assert f"{T32K},{KH * 256}]" not in text
     else:
         # a windowed call's operations carry ``_win`` in their name
         assert "attn_fwd" in text

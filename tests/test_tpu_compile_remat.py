@@ -142,3 +142,36 @@ def test_zaya1_floor_step_holds_no_more_than_it_did(one_chip, on_tpu):
     # the heap's holes, counted a second time: what the ladder's reading
     # is over by
     assert remat.step_bytes(compiled) - m.peak_memory_in_bytes >= 2 << 30
+
+
+KIMI_SEQ = 32768                     # benchmark/workloads/kimilinear.train-32k
+
+
+@pytest.mark.parametrize("lane, held", [("bfloat16", 14.45), ("int8", 15.25)])
+def test_kimi_linear_step_fits_only_with_its_second_forward_fenced(
+        one_chip, on_tpu, lane, held):
+    """Five unrolled layers at 1 x 32768 (ISSUE 38): merged with its first
+    forward a layer's second keeps every layer's temporaries to the
+    backward and the floor is refused (32.9 GB of 15.75); the ladder's last
+    resort, the floor under ``prevent_cse``, holds 14.45 GiB. The cell's
+    control, the int8 lane over every projection, fits too (15.25 GiB)
+    since an int8 matmul stores its caller's dtype (``quant_dot``'s
+    ``out_dtype``): with float32 products it read 16.02 GB."""
+    from benchmark import modelcfg_kimilinear
+
+    cfg = modelcfg_kimilinear.load("kimi-linear-48b-a3b")
+    model = get_model(cfg["program"]["model"], quant=lane == "int8",
+                      **modelcfg_kimilinear.program_kwargs(cfg, KIMI_SEQ))
+    step = train.make_train_step(
+        loss_of=lambda loss, b: loss,
+        apply_kwargs_of=lambda b: {"targets": b["x"]})
+    step, state, batch = _abstract(model, step, 1, KIMI_SEQ, one_chip)
+    if lane == "bfloat16":
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="RESOURCE_EXHAUSTED"):
+            step.build(remat.Saved()).lower(state, batch).compile()
+    compiled = step.build(remat.Saved(prevent_cse=True)).lower(
+        state, batch).compile()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert held - 0.1 < peak / GiB < held + 0.05
+    assert peak < LIMIT

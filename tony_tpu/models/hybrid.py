@@ -1,7 +1,9 @@
 """A decoder built from a tuple of layer kinds — state-space, windowed and
 full differential attention, gated memory units, cross-attention to one
 shared K/V (the SambaY decoder-hybrid-decoder of arXiv:2507.06607, as
-Phi-4-mini-flash-reasoning runs it).
+Phi-4-mini-flash-reasoning runs it); gated delta-rule linear attention and
+latent attention without positions over dense or expert feed-forwards
+(Kimi Linear, arXiv:2510.26692).
 
 :class:`~tony_tpu.models.transformer.Transformer` folds ONE block kind with
 ``nn.scan``; here the kinds differ and two streams cross layers, so each
@@ -20,6 +22,10 @@ kind (scope)      mixer                      consumes             emits
 ``gmu`` (gmu)     gated memory unit          ``m``                —
 ``cross``         differential attention of  ``k``, ``v``         —
 (attn_cross)      its own q over k, v
+``kda`` (kda)     gated delta rule, a        —                    —
+                  128 x 128 state a head
+``mla``           latent attention expanded  —                    —
+(attn_mla)        for training, no rotation
 ================  =========================  ===================  =========
 
 A later emitter replaces an earlier one's stream (the last ``mamba``
@@ -28,7 +34,27 @@ before a ``gmu`` is the one it reads). Layer i, pre-norm:
 and ``MLP(u) = (up * silu(gate)) W2, [gate, up] = u W1``. No positional
 encoding. The embedding table is tied: one parameter, looked up at the
 bottom and multiplied at the top (``vocab`` may be a slice of the
-published table — ids, logits and loss are then over the slice).
+published table — ids, logits and loss are then over the slice). The
+configuration may change three of these: ``norm="rmsnorm"`` (scale only),
+``tie_embeddings=False`` (an untied head ``lm_head_kernel``), and ``ffns``,
+a feed-forward a layer — ``("dense", width)``, a SwiGLU with separate
+``w_gate`` / ``w_up`` / ``w_down`` under the scope ``mlp``, or
+``"experts"``, :class:`~tony_tpu.models.moe.DroplessMoE` with a sigmoid
+router and shared experts.
+
+``kda`` (:class:`KDA`; :mod:`tony_tpu.ops.kda` has the recurrence)::
+
+    q, k, v = silu(conv4(u W_{q,k,v}))      depthwise, causal, no bias
+    q, k <- q / |q|, k / |k| a head;  q <- q * head^-1/2
+    g = -exp(A_log_h) * softplus(W_f2 (W_f1 u) + dt_bias)     [heads x head]
+    beta = sigmoid(u W_b)                                     [heads]
+    y = W_o (RMSNorm_head(kda(q, k, v, g, beta)) * sigmoid(W_g2 (W_g1 u) + b_g))
+
+``mla`` (:class:`MLA`): ``q_h = u W_q`` (nope + rope wide), ``[c, k_s] = u
+W_kva``, ``[k_h, v_h] = RMSNorm(c) W_kvb``; head h's key is ``[k_h, k_s]``
+with ``k_s`` shared by the heads and **no rotation** on any part; causal
+softmax of ``q_h . key_h / sqrt(nope + rope)``; ``W_o`` over the values
+(:func:`tony_tpu.ops.attention.flash_attention_mla`: the expanded form).
 
 Differential attention (heads 2p, 2p+1 are pair p; two query pairs share
 one K/V pair; ``v = [v_1, v_2]`` is 128 wide)::
@@ -67,7 +93,10 @@ from tony_tpu.models.transformer import RMSNorm
 from tony_tpu.ops import attention as attn_ops
 from tony_tpu.ops import ssm
 
-KINDS = ("mamba", "swa", "full", "gmu", "cross")
+KINDS = ("mamba", "swa", "full", "gmu", "cross", "kda", "mla")
+DIFFERENTIAL = ("swa", "full", "cross")
+# Kimi Linear's layers 1-5: the leading dense layer, then one period.
+KIMI_LINEAR_CUT = ("kda", "kda", "kda", "mla", "kda")
 PHI4_FLASH_CUT = ("mamba", "swa", "mamba", "full", "gmu", "cross")
 
 
@@ -101,6 +130,28 @@ class HybridConfig:
     # bodies in the Pallas interpreter (CPU tests).
     interpret: Optional[bool] = None
     mesh: Optional[Any] = None      # not supported: one chip
+    norm: str = "layernorm"         # | "rmsnorm"
+    tie_embeddings: bool = True
+    # () = every layer the fused GatedMLP of ``ffn_hidden``; else one entry
+    # a layer: ("dense", width) or "experts".
+    ffns: Tuple[Any, ...] = ()
+    kda_heads: int = 32
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    kda_chunk: int = 64
+    kda_keep: int = 4
+    mla_heads: int = 32
+    mla_kv_rank: int = 512
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64          # the key part the heads share
+    mla_v_dim: int = 128
+    moe_experts: int = 256
+    moe_top_k: int = 8
+    moe_experts_held: int = 0       # 0 = all of them
+    moe_expert_offset: int = 0
+    moe_ffn: int = 1024
+    moe_shared: int = 1
+    moe_route_scale: float = 1.0
 
     def __post_init__(self):
         unknown = set(self.layers) - set(KINDS)
@@ -115,11 +166,26 @@ class HybridConfig:
                     f"layer {i} ({kind}) consumes {sorted(missing)}, which "
                     f"no earlier layer emits")
             have |= set(MIXERS[kind].emits)
-        if self.n_heads % 2 or self.n_kv_heads % 2 \
-                or (self.n_heads // 2) % (self.n_kv_heads // 2):
+        if set(self.layers) & set(DIFFERENTIAL) and (
+                self.n_heads % 2 or self.n_kv_heads % 2
+                or (self.n_heads // 2) % (self.n_kv_heads // 2)):
             raise ValueError("differential attention pairs heads: n_heads "
                              "and n_kv_heads even, query pairs a multiple "
                              "of K/V pairs")
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm {self.norm!r}: 'layernorm' or 'rmsnorm'")
+        if self.ffns and len(self.ffns) != len(self.layers):
+            raise ValueError(f"ffns names {len(self.ffns)} feed-forwards "
+                             f"for {len(self.layers)} layers")
+        for ffn in self.ffns:
+            if ffn != "experts" and not (
+                    isinstance(ffn, tuple) and len(ffn) == 2
+                    and ffn[0] == "dense"):
+                raise ValueError(f"feed-forward {ffn!r}: ('dense', width) "
+                                 f"or 'experts'")
+        if "mla" in self.layers and self.mla_nope_dim != self.mla_v_dim:
+            raise ValueError("mla: the keys' own part and the values share "
+                             "one packed layout (nope_dim == v_dim)")
         if self.mesh is not None:
             raise ValueError("the hybrid decoder runs on one chip; no "
                              "sharding rules are written for it yet")
@@ -135,6 +201,45 @@ class HybridConfig:
     @property
     def dt_rank(self) -> int:
         return self.ssm_dt_rank or math.ceil(self.dim / 16)
+
+    def flops_per_token(self, seq: int) -> float:
+        """~6 FLOPs a multiplied parameter (forward + backward) plus each
+        layer's sequence mixing, a trained token of a ``seq``-long row —
+        for a stack of ``kda`` / ``mla`` layers
+        (Transformer.flops_per_token's accounting: the embedding is a
+        gather, of a held range of experts the share an even routing sends
+        here, the shared experts whole; MLA over the causal half with q.k
+        over nope + rope and p.v over v, forward 2 and backward 4 products
+        (the scores a flash backward takes again are a recomputation); KDA
+        as the recurrence's own 7 multiply-adds a state element a step,
+        twice that backward)."""
+        if set(self.layers) - {"kda", "mla"}:
+            raise NotImplementedError(
+                "flops_per_token counts kda and mla mixers; the other "
+                "kinds' work is benchmark/roofline_ssm.py's")
+        d, hd = self.dim, self.kda_head_dim
+        e = self.kda_heads * hd
+        qk = self.mla_nope_dim + self.mla_rope_dim
+        per = {
+            "kda": (3 * d * e + 2 * (d * hd + hd * e) + d * self.kda_heads
+                    + e * d, 3 * 7 * self.kda_heads * hd * hd),
+            "mla": (d * self.mla_heads * qk
+                    + d * (self.mla_kv_rank + self.mla_rope_dim)
+                    + self.mla_kv_rank * self.mla_heads
+                    * (self.mla_nope_dim + self.mla_v_dim)
+                    + self.mla_heads * self.mla_v_dim * d,
+                    3 * self.mla_heads * seq * (qk + self.mla_v_dim)),
+        }
+        share = (self.moe_experts_held or self.moe_experts) / self.moe_experts
+        experts = d * self.moe_experts + 3 * d * self.moe_ffn * (
+            self.moe_shared + self.moe_top_k * share)
+        params, mixing = d * self.vocab, 0.0
+        for kind, ffn in zip(self.layers, self.ffns or (
+                ("dense", self.ffn_hidden),) * len(self.layers)):
+            params += per[kind][0] + (experts if ffn == "experts"
+                                      else 3 * d * ffn[1])
+            mixing += per[kind][1]
+        return 6.0 * params + mixing
 
 
 def _dense(cfg, feats, name, bias=False):
@@ -161,6 +266,27 @@ class LayerNorm(nn.Module):
         var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
         y = (x32 - mean) * jax.lax.rsqrt(var + self.eps)
         return (y * scale + bias).astype(x.dtype)
+
+
+def _norm(cfg, name):
+    """The configuration's norm over the model width."""
+    if cfg.norm == "rmsnorm":
+        return RMSNorm(cfg.norm_eps, name=name)
+    return LayerNorm(cfg.norm_eps, name=name)
+
+
+class SwiGLU(nn.Module):
+    """``(silu(u W_gate) * u W_up) W_down``: the dense decoder's MLP (its
+    parameter names, and ``gate`` / ``up`` named for the residual ladder)."""
+    cfg: HybridConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, u):
+        cfg = self.cfg
+        gate = remat.name(_dense(cfg, self.width, "w_gate")(u), "gate")
+        up = remat.name(_dense(cfg, self.width, "w_up")(u), "up")
+        return _dense(cfg, cfg.dim, "w_down")(nn.silu(gate) * up)
 
 
 class GatedMLP(nn.Module):
@@ -298,6 +424,101 @@ class DiffAttention(nn.Module):
         return y, ((k, v) if self.kind == "full" else ())
 
 
+def _a_log_init(key, shape, dtype):
+    """log of U(1, 16) a head (the decay's rate, as Mamba-2 draws it)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+class KDA(nn.Module):
+    """Gated delta-rule linear attention (module docstring): device scopes
+    ``kda`` > ``kda_proj``, ``kda_conv``, ``kda_gate``, ``kda_out`` and the
+    two chunk kernels. No positions enter."""
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, u):
+        from tony_tpu.ops import kda as kda_ops
+
+        cfg = self.cfg
+        b, t, _ = u.shape
+        h, d = cfg.kda_heads, cfg.kda_head_dim
+        e = h * d
+        with jax.named_scope("kda_proj"):
+            q, k, v = (remat.name(_dense(cfg, e, "w" + n)(u), n)
+                       for n in "qkv")
+        with jax.named_scope("kda_conv"):
+            taps = lambda n: self.param(f"conv_{n}", _conv_init,
+                                        (cfg.kda_conv, e), jnp.float32)
+            # float32 from the projections' outputs to the kernel's
+            # operands: four shifted products and a norm are one fused
+            # elementwise pass either way.
+            q, k, v = (nn.silu(ssm.causal_conv1d(
+                x.astype(jnp.float32), taps(n))).reshape(b, t, h, d)
+                for x, n in ((q, "q"), (k, "k"), (v, "v")))
+            unit = lambda x: x * jax.lax.rsqrt(
+                jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+            q, k, v = (unit(q) * d ** -0.5).astype(cfg.dtype), \
+                unit(k).astype(cfg.dtype), v.astype(cfg.dtype)
+        with jax.named_scope("kda_gate"):
+            a_log = self.param("a_log", _a_log_init, (h,), jnp.float32)
+            dt_bias = self.param("dt_bias", _dt_bias_init, (e,), jnp.float32)
+            f = _dense(cfg, e, "wf2")(_dense(cfg, d, "wf1")(u))
+            g = (-jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                f.astype(jnp.float32) + dt_bias).reshape(b, t, h, d))
+            beta = jax.nn.sigmoid(
+                _dense(cfg, h, "wb")(u).astype(jnp.float32))
+        for name, fact in (
+                ("heads", h), ("chunk", cfg.kda_chunk),
+                ("chunks", kda_ops.n_chunks(t, cfg.kda_chunk)),
+                ("states_kept", kda_ops.states_kept(t, cfg.kda_chunk,
+                                                    cfg.kda_keep))):
+            profiler.count_once("kda:" + name, fact)
+        o = kda_ops.kda(q, k, v, g, beta, chunk=cfg.kda_chunk,
+                        keep=cfg.kda_keep, interpret=cfg.interpret)
+        with jax.named_scope("kda_out"):
+            gate = jax.nn.sigmoid(_dense(cfg, e, "wg2", bias=True)(
+                _dense(cfg, d, "wg1")(u)).astype(jnp.float32))
+            o = RMSNorm(cfg.norm_eps, name="o_norm")(o).astype(jnp.float32)
+            y = (o.reshape(b, t, e) * gate).astype(cfg.dtype)
+            return remat.name(_dense(cfg, cfg.dim, "wo")(y), "wo"), ()
+
+
+class MLA(nn.Module):
+    """Latent attention expanded for training, without rotation (module
+    docstring): device scopes ``attn_mla`` > ``mla_proj`` and the three
+    ``*_mla`` flash calls."""
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, u):
+        cfg = self.cfg
+        b, t, _ = u.shape
+        h, r = cfg.mla_heads, cfg.mla_kv_rank
+        dn, ds, dv = cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim
+        with jax.named_scope("mla_proj"):
+            q = remat.name(_dense(cfg, h * (dn + ds), "wq")(u), "q")
+            q = q.reshape(b, t, h, dn + ds)
+            kva = _dense(cfg, r + ds, "wkv_a")(u)
+            c, ks = kva[..., :r], kva[..., r:]
+            kv = _dense(cfg, h * (dn + dv), "wkv_b")(
+                RMSNorm(cfg.norm_eps, name="kv_norm")(c))
+            kv = kv.reshape(b, t, h, dn + dv)
+            k = remat.name(kv[..., :dn].reshape(b, t, h * dn), "k")
+            v = remat.name(kv[..., dn:].reshape(b, t, h * dv), "v")
+            qn = q[..., :dn].reshape(b, t, h * dn)
+            qs = q[..., dn:].transpose(0, 2, 1, 3)
+        for name, n in attn_ops.block_facts(
+                t, t, causal=True, head_dim=dn,
+                itemsize=k.dtype.itemsize).items():
+            profiler.count_once(f"attn:{name}.mla", n)
+        for name, fact in (("kv_rank", r), ("qk_dim", dn + ds),
+                           ("v_dim", dv)):
+            profiler.count_once("mla:" + name, fact)
+        out = attn_ops.flash_attention_mla(qn, qs, k, ks, v, h,
+                                           interpret=cfg.interpret)
+        return remat.name(_dense(cfg, cfg.dim, "wo")(out), "wo"), ()
+
+
 class Mixer(NamedTuple):
     """A layer kind: the mixer module's name (its device scope), the
     streams it reads from earlier layers and writes for later ones, and
@@ -320,6 +541,9 @@ MIXERS = {
     "swa": Mixer("attn_swa", (), (), _attention("swa")),
     "full": Mixer("attn_full", (), ("k", "v"), _attention("full")),
     "cross": Mixer("attn_cross", ("k", "v"), (), _attention("cross")),
+    "kda": Mixer("kda", (), (), lambda cfg, index, name: KDA(cfg, name=name)),
+    "mla": Mixer("attn_mla", (), (),
+                 lambda cfg, index, name: MLA(cfg, name=name)),
 }
 
 
@@ -335,10 +559,24 @@ class HybridLayer(nn.Module):
         cfg = self.cfg
         mixer = MIXERS[self.kind]
         out, emitted = mixer.build(cfg, self.index, mixer.scope)(
-            LayerNorm(cfg.norm_eps, name="norm1")(x), *consumed)
+            _norm(cfg, "norm1")(x), *consumed)
         x = x + out
-        x = x + GatedMLP(cfg, name="mlp")(
-            LayerNorm(cfg.norm_eps, name="norm2")(x))
+        ffn = cfg.ffns[self.index] if cfg.ffns else None
+        if ffn is None:
+            mlp = GatedMLP(cfg, name="mlp")
+        elif ffn == "experts":
+            from tony_tpu.models.moe import DroplessMoE
+            mlp = DroplessMoE(cfg.dim, cfg.moe_ffn, cfg.moe_experts,
+                              top_k=cfg.moe_top_k,
+                              experts_held=cfg.moe_experts_held,
+                              expert_offset=cfg.moe_expert_offset,
+                              dtype=cfg.dtype, quant=cfg.quant,
+                              router="sigmoid",
+                              route_scale=cfg.moe_route_scale,
+                              shared=cfg.moe_shared, name="moe_mlp")
+        else:
+            mlp = SwiGLU(cfg, ffn[1], name="mlp")
+        x = x + mlp(_norm(cfg, "norm2")(x))
         return x, emitted
 
 
@@ -362,14 +600,19 @@ class HybridDecoder(nn.Module):
         for kind in KINDS:
             profiler.count_once(f"model:layers.{kind}",
                                 cfg.layers.count(kind))
-        x = LayerNorm(cfg.norm_eps, name="final_norm")(x)
+        profiler.count_once("model:layers.experts",
+                            cfg.ffns.count("experts"))
+        x = _norm(cfg, "final_norm")(x)
         # Tied head: the same table, transposed.
+        head = embed.T if cfg.tie_embeddings else self.param(
+            "lm_head_kernel", nn.initializers.lecun_normal(),
+            (cfg.dim, cfg.vocab), jnp.float32)
         if cfg.xent_chunk and targets is not None:
             from tony_tpu.train import chunked_next_token_xent
-            return chunked_next_token_xent(x, embed.T, targets,
+            return chunked_next_token_xent(x, head, targets,
                                            cfg.xent_chunk, cfg.dtype)
         with jax.named_scope("lm_head"):
-            return jnp.dot(x, embed.T.astype(cfg.dtype),
+            return jnp.dot(x, head.astype(cfg.dtype),
                            preferred_element_type=jnp.float32)
 
 
@@ -379,6 +622,9 @@ def hybrid_decoder(**kw) -> HybridDecoder:
     its six-layer cut by default; every size is a keyword."""
     if "layers" in kw:
         kw["layers"] = tuple(kw["layers"])
+    if "ffns" in kw:
+        kw["ffns"] = tuple(f if isinstance(f, str) else tuple(f)
+                           for f in kw["ffns"])
     if "scan_dtype" in kw:
         kw["scan_dtype"] = jnp.dtype(kw["scan_dtype"])
     return HybridDecoder(HybridConfig(**kw))
@@ -392,3 +638,37 @@ def hybrid_tiny(**kw) -> HybridDecoder:
                     remat=False)
     defaults.update(kw)
     return hybrid_decoder(**defaults)
+
+
+@register("kimi-linear-48b-a3b")
+def kimi_linear(**kw) -> HybridDecoder:
+    """Kimi-Linear-48B-A3B's widths over its layers 1-5 by default (the
+    leading dense KDA layer, then KDA, KDA, MLA, KDA over experts): hidden
+    2304, 32 x 128 KDA heads, MLA of 32 heads at 128 + 64 over a 512-wide
+    latent, a dense SwiGLU of 9216, 8 of 256 sigmoid-routed experts of 1024
+    and a shared one, gates scaled by 2.446; RMSNorm, an untied head. A
+    deployment holds a range of the experts (``moe_experts_held``) and a
+    slice of the vocabulary."""
+    defaults = dict(
+        vocab=163840, dim=2304, layers=KIMI_LINEAR_CUT,
+        ffns=(("dense", 9216),) + ("experts",) * 4, norm="rmsnorm",
+        tie_embeddings=False, moe_route_scale=2.446)
+    defaults.update(kw)
+    if "ffns" not in kw and "layers" in kw:
+        defaults["ffns"] = (("dense", 9216),) \
+            + ("experts",) * (len(tuple(kw["layers"])) - 1)
+    return hybrid_decoder(**defaults)
+
+
+@register("kimi-linear-tiny")
+def kimi_linear_tiny(**kw) -> HybridDecoder:
+    """Test scale: the same code path (both new mixers, the dense and the
+    expert feed-forward) at toy widths."""
+    defaults = dict(
+        vocab=256, dim=64, layers=("kda", "kda", "mla"),
+        ffns=(("dense", 128), "experts", "experts"), kda_heads=2,
+        kda_head_dim=16, kda_chunk=8, kda_keep=2, mla_heads=2,
+        mla_kv_rank=32, mla_nope_dim=16, mla_rope_dim=8, mla_v_dim=16,
+        moe_experts=8, moe_top_k=2, moe_ffn=32, remat=False)
+    defaults.update(kw)
+    return kimi_linear(**defaults)

@@ -250,12 +250,21 @@ _TN = ((0,), (0,))      # a.T @ b
 # runs in f32; ``scale`` multiplies the f32 scores, never a bf16 operand.
 
 
-def _fwd_tile(q, k_blk, v_blk, carry, mask, scale):
+def _scores(q, k_blk, shared):
+    """``q . k^T`` of one tile; with ``shared = (qs, ks_blk)`` plus the
+    part of the score every head takes against one shared key part (MLA:
+    ``[q_h, qs_h] . [k_h, ks]`` without a 192-wide operand)."""
+    if shared is None:
+        return _dot(q, k_blk, _NT)
+    return _dot(q, k_blk, _NT) + _dot(shared[0], shared[1], _NT)
+
+
+def _fwd_tile(q, k_blk, v_blk, carry, mask, scale, shared=None):
     """One online-softmax step: the ``[Bq, Bk]`` score tile folded into
     the running (max m, normalizer l, accumulator acc); p casts back to
     the storage dtype for p.v."""
     m, l, acc = carry
-    s = mask(_dot(q, k_blk, _NT) * scale)
+    s = mask(_scores(q, k_blk, shared) * scale)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m - m_new)
@@ -276,29 +285,36 @@ def _row_dot(do, o):
                    axis=-1, keepdims=True)
 
 
-def _dq_tile(q, do, lse, D, k_blk, v_blk, mask, scale):
+def _dq_tile(q, do, lse, D, k_blk, v_blk, mask, scale, shared=None):
     """dq's share of one tile: p recomputed exactly from (q, k, lse),
-    ds = p * (dp - D), returns ds . k * scale in f32."""
-    p = jnp.exp(mask(_dot(q, k_blk, _NT) * scale) - lse)
+    ds = p * (dp - D), returns ds . k * scale in f32 (with ``shared``:
+    and ds . ks * scale, the shared part's query gradient)."""
+    p = jnp.exp(mask(_scores(q, k_blk, shared) * scale) - lse)
     dp = _dot(do, v_blk, _NT)
     ds = (p * (dp - D)).astype(k_blk.dtype)
-    return _dot(ds, k_blk, _NN) * scale
+    if shared is None:
+        return _dot(ds, k_blk, _NN) * scale
+    return _dot(ds, k_blk, _NN) * scale, _dot(ds, shared[1], _NN) * scale
 
 
-def _dkv_tile(q, do, o, lse, k_blk, v_blk, mask, scale):
+def _dkv_tile(q, do, o, lse, k_blk, v_blk, mask, scale, shared=None):
     """(dk, dv) shares of one tile, f32: dv = p^T . do, dk = ds^T . q *
-    scale, with p recomputed exactly from (q, k, lse)."""
-    p = jnp.exp(mask(_dot(q, k_blk, _NT) * scale) - lse)
+    scale, with p recomputed exactly from (q, k, lse) (with ``shared``:
+    and ds^T . qs * scale, this head's share of the shared key part's
+    gradient)."""
+    p = jnp.exp(mask(_scores(q, k_blk, shared) * scale) - lse)
     dv = _dot(p.astype(do.dtype), do, _TN)
     dp = _dot(do, v_blk, _NT)
     ds = (p * (dp - _row_dot(do, o))).astype(q.dtype)
-    return _dot(ds, q, _TN) * scale, dv
+    if shared is None:
+        return _dot(ds, q, _TN) * scale, dv
+    return (_dot(ds, q, _TN) * scale, dv, _dot(ds, shared[0], _TN) * scale)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                   acc_scr, *, causal: bool, scale: float, qi_axis: int = 1,
                   kv_len: Optional[int] = None,
-                  window: Optional[int] = None, sel_ref=None):
+                  window: Optional[int] = None, sel_ref=None, shared=None):
     """Streamed-KV flash forward: grid ``(..., qi, kb)`` with the k-block
     axis INNERMOST, so K/V arrive one ``[Bk, D]`` block at a time (VMEM
     stays O(block), any context length fits) while the online-softmax
@@ -314,7 +330,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     ``window`` the k axis holds only the blocks a q-block can see (``_window_spans``): step 0 is
     the q-block's first visible block, and blocks left of the window are
     neither fetched nor computed. ``sel_ref`` (the ``_sel`` wrappers): the
-    word block of a learned selection, which then is the mask."""
+    word block of a learned selection, which then is the mask. ``shared``
+    (the ``_mla`` wrappers): ``(qs_ref, ks_ref)``, this head's query part
+    against the key part all heads share (:func:`_scores`)."""
     bq, d = q_ref.shape
     bk = k_ref.shape[0]
     qi = pl.program_id(qi_axis)
@@ -336,9 +354,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         mask = _mask_at(qi * bq, kb * bk, causal, kv_len, window) \
             if sel_ref is None else functools.partial(
                 _mask_sel, words=sel_ref[:], kb=kb)
-        m, l, acc = _fwd_tile(
-            q_ref[:], k_ref[:], v_ref[:],
-            (m_scr[:, 0:1], l_scr[:, 0:1], acc_scr[:]), mask, scale)
+        if shared is None:
+            m, l, acc = _fwd_tile(
+                q_ref[:], k_ref[:], v_ref[:],
+                (m_scr[:, 0:1], l_scr[:, 0:1], acc_scr[:]), mask, scale)
+        else:
+            m, l, acc = _fwd_tile(
+                q_ref[:], k_ref[:], v_ref[:],
+                (m_scr[:, 0:1], l_scr[:, 0:1], acc_scr[:]), mask, scale,
+                (shared[0][:], shared[1][:]))
         acc_scr[:] = acc
         m_scr[:] = jnp.broadcast_to(m, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l, l_scr.shape)
@@ -351,11 +375,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
                          dq_scr, *, causal: bool, scale: float,
                          qi_axis: int = 1, kv_len: Optional[int] = None,
-                         window: Optional[int] = None, sel_ref=None):
+                         window: Optional[int] = None, sel_ref=None,
+                         shared=None):
     """dq, streamed like the forward (grid ``(..., qi, kb)``, k innermost,
     dq accumulated in VMEM scratch): recompute p from (q, k, lse) per
     k-block — ds = p·(dpᵀ−D); dq += ds·k·scale. No T×T buffer and no
-    full-length K/V ever materialize."""
+    full-length K/V ever materialize. ``shared``: ``(qs_ref, ks_ref,
+    dqs_ref, dqs_scr)`` (MLA)."""
     bq, d = q_ref.shape
     bk = k_ref.shape[0]
     qi = pl.program_id(qi_axis)
@@ -367,6 +393,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        if shared is not None:
+            shared[3][:] = jnp.zeros_like(shared[3])
 
     contributes = (kb * bk < (qi + 1) * bq) if causal else (kb >= 0)
 
@@ -376,13 +404,23 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
             if sel_ref is None else functools.partial(
                 _mask_sel, words=sel_ref[:], kb=kb)
         do = do_ref[:]
-        dq_scr[:] = dq_scr[:] + _dq_tile(
-            q_ref[:], do, lse_ref[:, 0:1], _row_dot(do, o_ref[:]),
-            k_ref[:], v_ref[:], mask, scale)
+        if shared is None:
+            dq_scr[:] = dq_scr[:] + _dq_tile(
+                q_ref[:], do, lse_ref[:, 0:1], _row_dot(do, o_ref[:]),
+                k_ref[:], v_ref[:], mask, scale)
+        else:
+            dq, dqs = _dq_tile(
+                q_ref[:], do, lse_ref[:, 0:1], _row_dot(do, o_ref[:]),
+                k_ref[:], v_ref[:], mask, scale,
+                (shared[0][:], shared[1][:]))
+            dq_scr[:] = dq_scr[:] + dq
+            shared[3][:] = shared[3][:] + dqs
 
     @pl.when(step == nkb - 1)
     def _finalize():
         dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
+        if shared is not None:
+            shared[2][:] = shared[3][:].astype(shared[2].dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
@@ -390,7 +428,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                           scale: float, qi_axis: int = 1, nqb: int = 0,
                           kv_len: Optional[int] = None,
                           window: Optional[int] = None, nq: int = 0,
-                          sel_ref=None):
+                          sel_ref=None, shared=None):
     """dk/dv, streamed: grid ``(..., kj, qx)`` with the q-side axis
     INNERMOST — q/do/o/lse arrive one block at a time while this k-block's
     dk/dv accumulate in VMEM scratch (dv += pᵀ·do; dk += dsᵀ·q·scale).
@@ -403,7 +441,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     axis is plain q-blocks). Under a ``window`` the per-head sweep
     holds only the q-blocks that can see this k-block (``nqb`` is then
     that span, ``nq`` the real q-block count): it starts at the diagonal
-    and ends where the window does."""
+    and ends where the window does. ``shared``: ``(qs_ref, ks_ref,
+    dks_ref, dks_scr)`` (MLA; this head's share of the shared part's
+    gradient, summed over heads by the caller)."""
     bk, d = k_ref.shape
     bq = q_ref.shape[0]
     kj = pl.program_id(qi_axis)
@@ -417,6 +457,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
+        if shared is not None:
+            shared[3][:] = jnp.zeros_like(shared[3])
 
     contributes = ((qb + 1) * bq > kj * bk) if causal else (qb >= 0)
     if window is not None:
@@ -427,8 +469,16 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         mask = _mask_at(qb * bq, kj * bk, causal, kv_len, window) \
             if sel_ref is None else functools.partial(
                 _mask_sel, words=sel_ref[:], kb=kj)
-        dk, dv = _dkv_tile(q_ref[:], do_ref[:], o_ref[:], lse_ref[:, 0:1],
-                           k_ref[:], v_ref[:], mask, scale)
+        if shared is None:
+            dk, dv = _dkv_tile(q_ref[:], do_ref[:], o_ref[:],
+                               lse_ref[:, 0:1], k_ref[:], v_ref[:], mask,
+                               scale)
+        else:
+            dk, dv, dks = _dkv_tile(q_ref[:], do_ref[:], o_ref[:],
+                                    lse_ref[:, 0:1], k_ref[:], v_ref[:],
+                                    mask, scale,
+                                    (shared[0][:], shared[1][:]))
+            shared[3][:] = shared[3][:] + dks
         dv_scr[:] = dv_scr[:] + dv
         dk_scr[:] = dk_scr[:] + dk
 
@@ -436,6 +486,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     def _finalize():
         dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
+        if shared is not None:
+            shared[2][:] = shared[3][:].astype(shared[2].dtype)
 
 
 def _dkv_resident_nogroup(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
@@ -1396,6 +1448,203 @@ def flash_attention_selected(q: jax.Array, k: jax.Array, v: jax.Array,
                                selection_blocks(t, d, k.dtype.itemsize),
                                interpret)
     return out, lse[..., 0]
+
+
+# --------------------------------------------------------------------
+# Latent attention expanded for training (MLA without rotation): head h
+# scores ``[q_h, qs_h] . [k_h, ks]`` — a ``d``-wide part of its own and a
+# narrower part against ONE key part all heads share — and sums values of
+# width ``d``. The q/k width (192) is not the value width (128) and is no
+# multiple of the lane tile, so the score is taken as two products
+# (:func:`_scores`) in the three streamed packed grids: nothing is padded
+# to 256 and the shared part is read once a tile, not once a head in HBM.
+# ``qs`` travels as [B, H, T, ds] (a 64-wide head is no lane block of a
+# packed array), ``ks`` as [B, T, ds].
+# --------------------------------------------------------------------
+
+
+def _mla_fwd_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, o_ref, lse_ref,
+                    m_scr, l_scr, acc_scr, **kw):
+    _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                  acc_scr, shared=(qs_ref, ks_ref), **kw)
+
+
+def _mla_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, qs_ref,
+                   ks_ref, dq_ref, dqs_ref, dq_scr, dqs_scr, **kw):
+    _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                         dq_ref, dq_scr,
+                         shared=(qs_ref, ks_ref, dqs_ref, dqs_scr), **kw)
+
+
+def _mla_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, qs_ref,
+                    ks_ref, dk_ref, dv_ref, dks_ref, dk_scr, dv_scr,
+                    dks_scr, **kw):
+    _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                          dk_ref, dv_ref, dk_scr, dv_scr,
+                          shared=(qs_ref, ks_ref, dks_ref, dks_scr), **kw)
+
+
+def _mla_forward(q, qs, k, ks, v, heads, scale, blocks, interpret):
+    b, t, hd = q.shape
+    d, ds = hd // heads, ks.shape[2]
+    bq, bk = blocks.fwd
+    q_pin = pl.BlockSpec((None, bq, d), lambda bi, h, i, kb: (bi, i, h))
+    k_str = pl.BlockSpec((None, bk, d), lambda bi, h, i, kb: (bi, kb, h))
+    with jax.named_scope("attn_fwd_mla"):
+        return pl.pallas_call(
+            functools.partial(_mla_fwd_kernel, causal=True, scale=scale,
+                              qi_axis=2),
+            grid=(b, heads, t // bq, t // bk),
+            in_specs=[q_pin, k_str, k_str,
+                      pl.BlockSpec((None, None, bq, ds),
+                                   lambda bi, h, i, kb: (bi, h, i, 0)),
+                      pl.BlockSpec((None, bk, ds),
+                                   lambda bi, h, i, kb: (bi, kb, 0))],
+            out_specs=(q_pin,
+                       pl.BlockSpec((None, None, bq, _LSE_LANES),
+                                    lambda bi, h, i, kb: (bi, h, i, 0))),
+            out_shape=(
+                jax.ShapeDtypeStruct((b, t, hd), q.dtype),
+                jax.ShapeDtypeStruct((b, heads, t, _LSE_LANES), jnp.float32)),
+            scratch_shapes=_fwd_scratch(bq, d),
+            interpret=interpret,
+        )(q, k, v, qs, ks)
+
+
+def _mla_backward(q, qs, k, ks, v, do, o, lse, heads, scale, blocks,
+                  interpret):
+    b, t, hd = q.shape
+    d, ds = hd // heads, ks.shape[2]
+    bq, bk = blocks.dq
+    q_pin = pl.BlockSpec((None, bq, d), lambda bi, h, i, kb: (bi, i, h))
+    k_str = pl.BlockSpec((None, bk, d), lambda bi, h, i, kb: (bi, kb, h))
+    lse_pin = pl.BlockSpec((None, None, bq, _LSE_LANES),
+                           lambda bi, h, i, kb: (bi, h, i, 0))
+    qs_pin = pl.BlockSpec((None, None, bq, ds),
+                          lambda bi, h, i, kb: (bi, h, i, 0))
+    with jax.named_scope("attn_bwd_dq_mla"):
+        dq, dqs = pl.pallas_call(
+            functools.partial(_mla_dq_kernel, causal=True, scale=scale,
+                              qi_axis=2),
+            grid=(b, heads, t // bq, t // bk),
+            in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin, qs_pin,
+                      pl.BlockSpec((None, bk, ds),
+                                   lambda bi, h, i, kb: (bi, kb, 0))],
+            out_specs=(q_pin, qs_pin),
+            out_shape=(jax.ShapeDtypeStruct((b, t, hd), q.dtype),
+                       jax.ShapeDtypeStruct(qs.shape, qs.dtype)),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                            pltpu.VMEM((bq, ds), jnp.float32)],
+            interpret=interpret,
+        )(q, k, v, do, o, lse, qs, ks)
+    # dk/dv: (b, h, kj, qx); each head writes its share of the shared key
+    # part's gradient, summed over the heads below.
+    bq, bk = blocks.dkv
+    k_pin = pl.BlockSpec((None, bk, d), lambda bi, h, j, qx: (bi, j, h))
+    q_str = pl.BlockSpec((None, bq, d), lambda bi, h, j, qx: (bi, qx, h))
+    lse_str = pl.BlockSpec((None, None, bq, _LSE_LANES),
+                           lambda bi, h, j, qx: (bi, h, qx, 0))
+    ks_head = pl.BlockSpec((None, None, bk, ds),
+                           lambda bi, h, j, qx: (bi, h, j, 0))
+    with jax.named_scope("attn_bwd_dkv_mla"):
+        dk, dv, dks = pl.pallas_call(
+            functools.partial(_mla_dkv_kernel, causal=True, scale=scale,
+                              qi_axis=2),
+            grid=(b, heads, t // bk, t // bq),
+            in_specs=[q_str, k_pin, k_pin, q_str, q_str, lse_str,
+                      pl.BlockSpec((None, None, bq, ds),
+                                   lambda bi, h, j, qx: (bi, h, qx, 0)),
+                      pl.BlockSpec((None, bk, ds),
+                                   lambda bi, h, j, qx: (bi, j, 0))],
+            out_specs=(k_pin, k_pin, ks_head),
+            out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype),
+                       jax.ShapeDtypeStruct((b, heads, t, ds), jnp.float32)),
+            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                            pltpu.VMEM((bk, d), jnp.float32),
+                            pltpu.VMEM((bk, ds), jnp.float32)],
+            interpret=interpret,
+        )(q, k, v, do, o, lse, qs, ks)
+    return dq, dqs, dk, dks.sum(axis=1).astype(ks.dtype), dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_mla(q, qs, k, ks, v, heads, scale, blocks, interpret):
+    return _flash_mla_fwd(q, qs, k, ks, v, heads, scale, blocks,
+                          interpret)[0]
+
+
+def _flash_mla_fwd(q, qs, k, ks, v, heads, scale, blocks, interpret):
+    out, lse = _mla_forward(q, qs, k, ks, v, heads, scale, blocks, interpret)
+    out, lse = remat.name(out, "flash_out"), remat.name(lse, "flash_lse")
+    return out, (q, qs, k, ks, v, out, lse)
+
+
+def _flash_mla_bwd(heads, scale, blocks, interpret, residuals, g):
+    q, qs, k, ks, v, out, lse = residuals
+    return _mla_backward(q, qs, k, ks, v, g, out, lse, heads, scale, blocks,
+                         interpret)
+
+
+_flash_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
+
+
+def mla_attention_reference(q, qs, k, ks, v, heads,
+                            scale: Optional[float] = None):
+    """The semantic spec of :func:`flash_attention_mla`:
+    :func:`reference_attention` over the concatenated ``[q_h, qs_h]`` and
+    ``[k_h, ks]``."""
+    b, t, hd = q.shape
+    d, ds = hd // heads, ks.shape[2]
+    scale = (d + ds) ** -0.5 if scale is None else scale
+    to4 = lambda x: x.reshape(b, t, heads, -1).transpose(0, 2, 1, 3)
+    # packsite: region-local — each head's own part beside the shared part
+    # of one unsharded activation (the model refuses a mesh).
+    q4 = jnp.concatenate([to4(q), qs], axis=-1)
+    # packsite: region-local — as above.
+    k4 = jnp.concatenate(
+        [to4(k), jnp.broadcast_to(ks[:, None], (b, heads, t, ds))], axis=-1)
+    out = reference_attention(q4, k4, to4(v), True, scale)
+    return out.transpose(0, 2, 1, 3).reshape(b, t, -1)
+
+
+def flash_attention_mla(q: jax.Array, qs: jax.Array, k: jax.Array,
+                        ks: jax.Array, v: jax.Array, heads: int,
+                        scale: Optional[float] = None,
+                        interpret: Optional[bool] = None) -> jax.Array:
+    """Causal self-attention whose query/key width is not its value width
+    (latent attention expanded for training): ``q``, ``k`` ``[B, T, H·d]``
+    packed (each head's own part), ``qs`` ``[B, H, T, ds]`` (each head's
+    part against the shared key part), ``ks`` ``[B, T, ds]`` (the key part
+    all heads share), ``v`` ``[B, T, H·d]``; head h's scores are ``(q_h .
+    k_h + qs_h . ks) * scale`` (``scale`` defaults to ``(d + ds)^-1/2``).
+    Returns ``[B, T, H·d]``. Pallas kernels (the streamed packed grids; a
+    length off the blocks is zero-padded at the end, which the causal mask
+    hides) on a TPU or with ``interpret=True``,
+    :func:`mla_attention_reference` elsewhere."""
+    b, t, hd = q.shape
+    d, ds = hd // heads, ks.shape[2]
+    if not (k.shape == v.shape == q.shape and qs.shape == (b, heads, t, ds)
+            and ks.shape == (b, t, ds)):
+        raise ValueError(f"mla shapes: q {q.shape} qs {qs.shape} k {k.shape} "
+                         f"ks {ks.shape} v {v.shape}, heads={heads}")
+    scale = (d + ds) ** -0.5 if scale is None else scale
+    if interpret is None:
+        if jax.default_backend() != "tpu":
+            return mla_attention_reference(q, qs, k, ks, v, heads, scale)
+        interpret = False
+    if d % 128:
+        raise ValueError(f"mla attention reads heads as lane blocks: "
+                         f"head part {d} is not a multiple of 128")
+    plan, blocks, t_pad = _plan_dispatch(t, t, None, None, True, None, d,
+                                         k.dtype.itemsize)
+    if plan == "kernel":
+        return _flash_mla(q, qs, k, ks, v, heads, scale, blocks, interpret)
+    rows = lambda x, axis: jnp.pad(
+        x, [(0, t_pad - t if a == axis else 0) for a in range(x.ndim)])
+    out = _flash_mla(rows(q, 1), rows(qs, 2), rows(k, 1), rows(ks, 1),
+                     rows(v, 1), heads, scale, blocks, interpret)
+    return out[:, :t]
 
 
 # Query rows of a probabilities tile; its keys are twice as many.

@@ -132,7 +132,7 @@ def _dot_kernel(sx_ref, x_ref, w_ref, sw_ref, o_ref):
     acc = jax.lax.dot_general(
         x_ref[:], w_ref[:], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
-    o_ref[:] = _rescale(acc, sx_ref[0], sw_ref[0])
+    o_ref[:] = _rescale(acc, sx_ref[0], sw_ref[0]).astype(o_ref.dtype)
 
 
 # Output-tile targets: int8 operand tiles are (32, 128); 256×256 keeps
@@ -142,16 +142,18 @@ _BM, _BN = 256, 256
 
 def _int8_matmul(xq: jax.Array, wq: jax.Array, sx: jax.Array,
                  sw: jax.Array, *, impl: Optional[str],
-                 interpret: bool) -> jax.Array:
-    """``[M, K] int8 @ [K, N] int8 → [M, N] f32`` with f32 rescale —
-    the dispatch point of the two bit-identical paths. ``sw`` is the
-    [N] per-channel scale vector."""
+                 interpret: bool, out_dtype: Any = jnp.float32) -> jax.Array:
+    """``[M, K] int8 @ [K, N] int8 → [M, N]`` with f32 rescale — the
+    dispatch point of the two bit-identical paths. ``sw`` is the [N]
+    per-channel scale vector. ``out_dtype``: what the rescaled f32 tile
+    is stored as (a cast of the same f32 value on both paths; a bf16
+    caller then never holds the f32 ``[M, N]``)."""
     impl = _resolve_impl(impl, interpret)
     if impl == "xla":
         acc = jax.lax.dot_general(
             xq, wq, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
-        return _rescale(acc, sx, sw)
+        return _rescale(acc, sx, sw).astype(out_dtype)
     if impl != "pallas":
         raise ValueError(f"unknown impl {impl!r} (pallas|xla)")
     m, k = xq.shape
@@ -175,18 +177,19 @@ def _int8_matmul(xq: jax.Array, wq: jax.Array, sx: jax.Array,
             pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n,
-            bytes_accessed=m * k + k * n + 4 * m * n + 4 * n,
+            bytes_accessed=m * k + k * n
+            + jnp.dtype(out_dtype).itemsize * m * n + 4 * n,
             transcendentals=0),
     )(sx.reshape(1), xq, wq, sw2)
     return out[:m, :n]
 
 
 def _qdot_impl(x: jax.Array, w: jax.Array, per_channel: bool,
-               impl: Optional[str], interpret: bool):
+               impl: Optional[str], interpret: bool, out_dtype: Any):
     """Quantize + matmul, shared by the primal and fwd rules. Returns
     ``(y, (xq, sx, wq, sw))`` — the int8 residuals are what the STE
     backward dequantizes (4× smaller than f32 residuals)."""
@@ -201,24 +204,25 @@ def _qdot_impl(x: jax.Array, w: jax.Array, per_channel: bool,
     sw = jnp.broadcast_to(scale_of(aw), (n,))
     xq = quantize(x2, sx)
     wq = quantize(w, sw)
-    y = _int8_matmul(xq, wq, sx, sw, impl=impl, interpret=interpret)
+    y = _int8_matmul(xq, wq, sx, sw, impl=impl, interpret=interpret,
+                     out_dtype=out_dtype)
     return y.reshape(x.shape[:-1] + (n,)), (xq, sx, wq, sw)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _qdot(x, w, per_channel, impl, interpret):
-    return _qdot_impl(x, w, per_channel, impl, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _qdot(x, w, per_channel, impl, interpret, out_dtype):
+    return _qdot_impl(x, w, per_channel, impl, interpret, out_dtype)[0]
 
 
-def _qdot_fwd(x, w, per_channel, impl, interpret):
-    y, res = _qdot_impl(x, w, per_channel, impl, interpret)
+def _qdot_fwd(x, w, per_channel, impl, interpret, out_dtype):
+    y, res = _qdot_impl(x, w, per_channel, impl, interpret, out_dtype)
     # Dtype sentinels: residuals must be jax types, and the cotangents
     # must come back in the PRIMAL dtypes (x may be bf16 while y/g are
     # f32 — the rescale owns the output precision).
     return y, (res, jnp.zeros((), x.dtype), jnp.zeros((), w.dtype))
 
 
-def _qdot_bwd(per_channel, impl, interpret, residuals, g):
+def _qdot_bwd(per_channel, impl, interpret, out_dtype, residuals, g):
     # Straight-through estimator: quantize∘dequantize ≈ identity for the
     # gradient, so the backward is the plain matmul transpose pair over
     # the DEQUANTIZED (fake-quant) operands, run in f32 — standard QAT.
@@ -236,12 +240,15 @@ _qdot.defvjp(_qdot_fwd, _qdot_bwd)
 
 def quant_dot(x: jax.Array, w: jax.Array, *, per_channel: bool = True,
               impl: Optional[str] = None, interpret: bool = False,
-              tag: Optional[str] = None) -> jax.Array:
+              tag: Optional[str] = None,
+              out_dtype: Any = jnp.float32) -> jax.Array:
     """Quantized ``x @ w``: symmetric int8 (per-tensor ``x``, per-channel
     ``w`` by default), int8×int8→int32 matmul, f32 rescale, straight-
     through gradients. ``x`` is ``[..., K]``, ``w`` is ``[K, N]``; the
-    result is f32 (cast at the call site — the f32 rescale IS the
-    accumulation story, callers choose the storage dtype)."""
+    result is f32 (the f32 rescale IS the accumulation story) unless the
+    caller names its storage dtype: ``out_dtype`` is the same f32 value
+    cast where it is made, so a ``[32768, 9216]`` product is never held
+    in f32 beside its bf16 copy."""
     if w.ndim != 2:
         raise ValueError(f"quant_dot expects a rank-2 rhs [K, N], got "
                          f"shape {w.shape}")
@@ -253,7 +260,7 @@ def quant_dot(x: jax.Array, w: jax.Array, *, per_channel: bool = True,
             impl=_resolve_impl(impl, interpret), per_channel=per_channel,
             int8_bytes=m * x.shape[-1] + x.shape[-1] * w.shape[1],
             bf16_bytes=2 * (m * x.shape[-1] + x.shape[-1] * w.shape[1]))
-    return _qdot(x, w, per_channel, impl, interpret)
+    return _qdot(x, w, per_channel, impl, interpret, jnp.dtype(out_dtype))
 
 
 def quant_dot_general(lhs: jax.Array, rhs: jax.Array,
@@ -295,9 +302,11 @@ class QuantDense(nn.Module):
     def __call__(self, x):
         kernel = self.param("kernel", self.kernel_init,
                             (x.shape[-1], self.features), self.param_dtype)
+        # Without a bias the f32 product is only cast: cast where it is made.
         y = quant_dot(x, kernel, per_channel=self.per_channel,
                       impl=self.impl, interpret=self.interpret,
-                      tag=f"dense.{self.name}")
+                      tag=f"dense.{self.name}",
+                      out_dtype=jnp.float32 if self.use_bias else self.dtype)
         if self.use_bias:
             bias = self.param("bias", self.bias_init, (self.features,),
                               self.param_dtype)

@@ -23,9 +23,13 @@ not add up: PERF.md §7), so the program asks the compiler:
   step and takes the first whose ``memory_analysis()`` total leaves
   :data:`MARGIN` under the device's ``bytes_limit``. A refused compile
   (``RESOURCE_EXHAUSTED``) is a step down; the empty set is the floor and
-  is today's program. The answer is remembered beside the persistent
-  compile cache, so a warm start builds one program and no refused compile
-  is repeated. A backend that reports no limit (the CPU) gets the floor.
+  is today's program. Where the compiler refuses the floor too, one more
+  program is tried: the floor with ``prevent_cse`` (XLA may merge an
+  unrolled layer's second forward with its first, and then holds every
+  layer's temporaries to the backward; barriers keep them two). The
+  answer is remembered beside the persistent compile cache, so a warm
+  start builds one program and no refused compile is repeated. A backend
+  that reports no limit (the CPU) gets the floor.
 
 Processes of one job must agree on the set (they run one SPMD program):
 they do, because each reads the same compiler and the same kind of device.
@@ -91,12 +95,16 @@ _ACTIVE: contextvars.ContextVar = contextvars.ContextVar(
 
 
 class Saved:
-    """The names one step's backward keeps; entered around the model's
-    trace. Also the trace's witness: which names the model met under it
-    and how many layers :func:`block` wrapped."""
+    """The names one step's backward keeps, and whether its layers'
+    second forward is fenced off from the first (``prevent_cse``: the
+    step below the floor); entered around the model's trace. Also the
+    trace's witness: which names the model met under it and how many
+    layers :func:`block` wrapped."""
 
-    def __init__(self, names: Iterable[str] = FLOOR):
+    def __init__(self, names: Iterable[str] = FLOOR,
+                 prevent_cse: bool = False):
         self.names = tuple(names)
+        self.prevent_cse = bool(prevent_cse)
         self.met: set = set()
         self.blocks = 0
 
@@ -135,10 +143,17 @@ def block(layer_cls, always: Tuple[str, ...] = ()):
     the names the model says its layers ``always`` keep (on no rung: the
     step's set, :func:`kept` and the ladder do not hear of them; a step's
     timeline does, as ``remat:saved.<name>``). A model that has none gets
-    the policy it would get without the argument."""
+    the policy it would get without the argument. The second forward may
+    be merged with the first (``prevent_cse=False``: under ``nn.scan``
+    nothing can be, and unrolled layers whose step fits gain what XLA
+    merges) unless the step's :class:`Saved` says otherwise: the step
+    :class:`ChosenStep` falls back to when the floor itself is refused
+    (the 32k Kimi Linear step's five unrolled layers read 32.9 GB merged,
+    14.76 GiB fenced: PERF.md section 6, PR 38)."""
     import flax.linen as nn
 
     active = _ACTIVE.get()
+    prevent_cse = active is not None and active.prevent_cse
     names = (active.names if active is not None else FLOOR) + tuple(always)
     if active is not None:
         active.blocks += 1
@@ -146,7 +161,7 @@ def block(layer_cls, always: Tuple[str, ...] = ()):
             profiler.count_once(f"remat:saved.{n}", 1)
     policy = jax.checkpoint_policies.save_only_these_names(*names) \
         if names else None
-    return nn.remat(layer_cls, prevent_cse=False, policy=policy)
+    return nn.remat(layer_cls, prevent_cse=prevent_cse, policy=policy)
 
 
 def step_bytes(compiled) -> int:
@@ -246,6 +261,8 @@ def _publish(choice: dict, limit: int, from_memo: bool) -> None:
     profiler.count_once("remat:rungs_refused", 0 if from_memo else sum(
         r["bytes"] is None for r in choice["rungs"]))
     profiler.count_once("remat:from_memo", int(from_memo))
+    if choice.get("prevent_cse"):
+        profiler.count_once("remat:prevent_cse", 1)
     profiler.record("remat", "train_step", bytes_limit=limit, margin=MARGIN,
                     from_memo=from_memo, **choice)
     _log.info("remat: backward keeps %s; step %.3f of %.3f GiB%s",
@@ -320,7 +337,8 @@ class ChosenStep:
         found = _read_memo(path)
         if found is not None:
             _publish(found, limit, from_memo=True)
-            return self.build(Saved(found["saved"]))
+            return self.build(Saved(found["saved"],
+                                    found.get("prevent_cse", False)))
         # The first candidate's trace also tells which names this model
         # has; rungs that differ only in names it lacks are one rung, and
         # the first candidate is the program of its own effective rung.
@@ -333,8 +351,14 @@ class ChosenStep:
                 break
             fn, reading = yield from self._rung(Saved(rung), first, limit)
             readings.append(reading)
+        if not readings[-1]["fits"]:
+            # The floor was refused: the floor again, its layers' second
+            # forward fenced off from the first.
+            fn, reading = yield from self._rung(
+                Saved(FLOOR, prevent_cse=True), first, limit)
+            readings.append(reading)
         choice = {"saved": reading["saved"], "step_bytes": reading["bytes"],
-                  "rungs": readings}
+                  "prevent_cse": reading["prevent_cse"], "rungs": readings}
         _write_memo(path, choice)
         _publish(choice, limit, from_memo=False)
         return fn
@@ -343,8 +367,10 @@ class ChosenStep:
         """Generator: one rung — its candidate yielded for tracing, the
         trace compiled, its bytes read — under the set-up span
         ``tony:remat_rung`` (attrs ``saved``, ``bytes``: None for a
-        refused compile, ``fits``). Returns the candidate and its
-        reading."""
+        refused compile, ``fits``, ``prevent_cse``). A floor that compiles
+        fits, whatever the margin says; the fenced floor is the last
+        program there is, so its refusal is the step's error. Returns the
+        candidate and its reading."""
         with profiler.span("tony:remat_rung") as sp:
             fn = self.build(saved)
             trace = yield fn
@@ -352,10 +378,12 @@ class ChosenStep:
             try:
                 total = step_bytes(trace.lower().compile())
             except jax.errors.JaxRuntimeError as e:
-                if "RESOURCE_EXHAUSTED" not in str(e) or rung == FLOOR:
+                if "RESOURCE_EXHAUSTED" not in str(e) or saved.prevent_cse:
                     raise
                 total = None
-            fits = rung == FLOOR or (total is not None
-                                     and total + MARGIN <= limit)
-            sp.attrs.update(saved=",".join(rung), bytes=total, fits=fits)
-        return fn, {"saved": list(rung), "bytes": total, "fits": fits}
+            fits = total is not None and (rung == FLOOR
+                                          or total + MARGIN <= limit)
+            sp.attrs.update(saved=",".join(rung), bytes=total, fits=fits,
+                            prevent_cse=saved.prevent_cse)
+        return fn, {"saved": list(rung), "bytes": total, "fits": fits,
+                    "prevent_cse": saved.prevent_cse}
