@@ -4,7 +4,9 @@ gradient of each of ``q, k, v, g, beta`` — over several chunk counts, kept
 states and a padded length, under a decay strong enough to overflow
 ``exp(-cumsum g)``; the kernel bodies under the Pallas interpreter against
 the XLA twin; and the pieces: the level tables, the inverse, the exact 0/1
-product, the hand-written chunk backward against autodiff."""
+product and the exponent blocks it makes, the hand-written chunk backward
+(from a chunk's state-free half, made once) against autodiff of the two
+halves composed, and the halves a kernel call builds."""
 
 import jax
 import jax.numpy as jnp
@@ -110,14 +112,25 @@ def test_bfloat16_operands_stay_close():
                 K.kda_reference(*exact)) < 1e-2
 
 
+def _exponents64(g, chunk):
+    """Every level's (row, column) exponent blocks, then ``G`` and ``G_last
+    - G``, float64: level 1's row block is ``g`` itself and its column
+    block nothing; the rest is ``tables()`` x ``g``."""
+    g = np.asarray(g, np.float64)
+    e = (K.tables(chunk)[0].astype(np.float64) @ g).reshape(-1, *g.shape)
+    return np.concatenate([g[None], np.zeros_like(g)[None], e])
+
+
 @pytest.mark.parametrize("chunk", [2, 8, 64])
 def test_levels_cover_every_pair_once(chunk):
     sums, masks = K.tables(chunk)
+    assert sums.shape == (K.table_rows(chunk), chunk)
+    assert K.table_rows(chunk) == 2 * (chunk.bit_length() - 1) * chunk
     levels = masks.reshape(-1, chunk, chunk)
     t, i = np.indices((chunk, chunk))
     assert (levels.sum(0) == (i < t)).all()
     g = -np.random.default_rng(chunk).random((chunk, 3))
-    e = (sums @ g).reshape(-1, chunk, 3)
+    e = _exponents64(g, chunk)
     cum = np.cumsum(g, axis=0)
     for l, m in enumerate(levels):
         for a, b in zip(*np.nonzero(m)):
@@ -141,31 +154,131 @@ def test_inverse_of_a_strictly_lower_matrix(c):
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("cd, tol", [(jnp.bfloat16, 3e-5), (jnp.float32, 2e-7)])
-def test_split_product_is_exact_to_float32(cd, tol):
-    """The TPU's path for the 0/1 tables: bfloat16 passes over a split
-    operand (two parts in a bfloat16 model, three in float32)."""
+SPLIT = [(jnp.bfloat16, 3e-5), (jnp.float32, 2e-7)]
+
+
+@pytest.mark.parametrize("dims", [K._NN, K._TN], ids=["table_x", "tableT_x"])
+@pytest.mark.parametrize("cd, tol", SPLIT)
+def test_split_product_is_exact_to_float32(cd, tol, dims):
+    """The compiled kernels' path for the 0/1 tables: a float32 operand
+    split into bfloat16 parts (two in a bfloat16 model, three in float32)
+    against as many copies of the table side by side — one product whose
+    contraction the copies fill, or (transposed) a product a part."""
     sums, _ = K.tables(16)
-    x = jax.random.normal(jax.random.PRNGKey(1), (16, 128)) * 7
-    got = K._mm_split(jnp.asarray(sums, jnp.bfloat16), x, K._NN, cd)
-    want = sums.astype(np.float64) @ np.asarray(x, np.float64)
+    wide, _ = K._consts(16, cd)
+    assert wide.dtype == jnp.bfloat16
+    assert wide.shape == (sums.shape[0], 16 * K._parts(cd))
+    rows = 16 if dims == K._NN else sums.shape[0]
+    x = jax.random.normal(jax.random.PRNGKey(1), (rows, 128)) * 7
+    got = K._mm_table(wide, x, dims, cd)
+    table = sums.astype(np.float64)
+    want = (table if dims == K._NN else table.T) @ np.asarray(x, np.float64)
     assert np.abs(np.asarray(got) - want).max() < tol * np.abs(want).max()
 
 
-def test_chunk_backward_is_the_forward_s_vjp():
-    c, d = 16, 8
-    q, k, v, g, beta = (a[0, :, 0] for a in _data(c, h=1, d=d, seed=5))
+@pytest.mark.parametrize("decay", [0.3, 4.0, 12.0])
+@pytest.mark.parametrize("cd, tol", SPLIT)
+def test_exponent_blocks_are_exact_to_float32(cd, tol, decay):
+    """The exponent blocks by the compiled kernels' route (level 1's from
+    ``g`` itself, the rest one split product) against ``tables()`` x ``g``
+    in float64, under the strong decays too: sums of ``g``, never
+    differences of prefix sums, so nothing cancels."""
+    chunk = 64
+    g = np.asarray(_data(chunk, h=1, d=128, decay=decay, seed=7)[3][0, :, 0])
+    got = np.asarray(K._exponents(jnp.asarray(g), K._consts(chunk, cd)[0],
+                                  cd)).reshape(-1, *g.shape)
+    want = np.delete(_exponents64(g, chunk), 1, axis=0)
+    assert got.shape == want.shape and (got <= 0).all()
+    assert np.abs(got - want).max() < tol * np.abs(want).max()
+    # the float32 table (twin, interpreter): one float32 sum of 64 terms
+    twin = np.asarray(K._exponents(jnp.asarray(g), K._consts(chunk)[0], cd))
+    assert np.abs(twin.reshape(want.shape) - want).max() \
+        < 1e-6 * np.abs(want).max()
+
+
+@pytest.fixture(scope="module", params=[
+    (16, jnp.float32, 0.3), (64, jnp.float32, 0.3), (16, jnp.bfloat16, 0.3),
+    (64, jnp.bfloat16, 0.3), (64, jnp.float32, 6.0)],
+    ids=lambda p: f"c{p[0]}_{jnp.dtype(p[1]).name}_decay{p[2]}")
+def chunk_vjp(request):
+    """One chunk's adjoints by hand (``chunk_half`` once, handed to
+    ``chunk_bwd``) and by autodiff of ``chunk_fwd`` (the two halves
+    composed), under a strong decay too, with the tolerance the compute
+    dtype allows: autodiff rounds a bfloat16 operand's cotangent to
+    bfloat16, the hand-written backward keeps every product's float32."""
+    c, cd, decay = request.param
+    d = 32
+    q, k, v, g, beta = (a[0, :, 0] for a in
+                        _data(c, h=1, d=d, decay=decay, seed=c, dtype=cd))
+    beta = beta[:, None]
     st = jax.random.normal(jax.random.PRNGKey(2), (d, d))
+    do = jax.random.normal(jax.random.PRNGKey(4), (c, d)) * 0.3
+    dst1 = jax.random.normal(jax.random.PRNGKey(5), (d, d)) * 0.1
     sums, masks = K._consts(c)
-    kw = dict(sums=sums, masks=masks, cd=jnp.float32)
+    kw = dict(sums=sums, masks=masks, cd=cd)
     fwd = lambda st, q, k, v, g, b: K.chunk_fwd(st, q, k, v, g, b, **kw)
-    (o, st1), vjp = jax.vjp(fwd, st, q, k, v, g, beta[:, None])
-    do, dst1 = jnp.ones_like(o) * 0.3, jnp.ones_like(st1) * 0.1
-    want = vjp((do, dst1))
-    dq, dk, dv, dg, db, dst = K.chunk_bwd(st, q, k, v, g, beta[:, None], do,
-                                          dst1, **kw)
-    for a, b in zip((dst, dq, dk, dv, dg, db), want):
-        assert _rel(a, b) < 1e-5
+    _, vjp = jax.vjp(fwd, st, q, k, v, g, beta)
+    half = K.chunk_half(q, k, g, beta, **kw)
+    *grads, dst = K.chunk_bwd(st, half, q, k, v, beta, do, dst1, **kw)
+    # reads at most 1.6e-7 and 4.5e-3
+    tol = 1e-6 if cd == jnp.float32 else 1e-2
+    return (dst, *grads), vjp((do, dst1)), tol
+
+
+@pytest.mark.parametrize("out", range(6), ids=("state",) + ARGS)
+def test_chunk_backward_is_the_forward_s_vjp(chunk_vjp, out):
+    got, want, tol = chunk_vjp
+    assert got[out].shape == want[out].shape
+    assert _rel(got[out], want[out]) < tol
+
+
+@pytest.mark.parametrize("arg", range(5), ids=ARGS)
+def test_bfloat16_gradients_stay_close(arg):
+    """``g``'s and ``beta``'s too: they reach the parameters (a decay rate
+    a head, ``a_log``) only through the chunk backward's ``dg``."""
+    args = _data(128, d=32, seed=3, dtype=jnp.bfloat16)
+    exact = tuple(a.astype(jnp.float32) for a in args)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    grad = lambda f, a: jax.grad(
+        lambda *a: (f(*a).astype(jnp.float32) * w).sum(), arg)(*a)
+    got = grad(lambda *a: K.kda(*a, chunk=32, keep=2), args)
+    # reads 2.8e-3 to 4.0e-3
+    assert _rel(got, grad(K.kda_reference, exact)) < 1e-2, ARGS[arg]
+
+
+@pytest.mark.parametrize("keep", [2, 4, 8])
+def test_a_kernel_call_builds_one_half_a_chunk(monkeypatch, keep):
+    """Each kernel body has ONE loop that calls ``chunk_half``, traced once
+    and run ``keep`` times: the backward builds no half in its reverse
+    loop and none twice. ``halves_built`` reads the same off the kernels'
+    jaxprs — and hears a second half, where a kernel builds one."""
+    calls = []
+    real = K.chunk_half
+
+    def counted(*args, **kw):
+        calls.append(1)
+        return real(*args, **kw)
+    monkeypatch.setattr(K, "chunk_half", counted)
+    q, k, v, g, beta = _data(64 * keep, h=1, d=128, seed=keep)
+    o, res = jax.eval_shape(lambda *a: K._kda_fwd(*a, 64, keep, True),
+                            q, k, v, g, beta)
+    assert len(calls) == 1
+    del calls[:]
+    jax.eval_shape(lambda res, do: K._kda_bwd(64, keep, True, res, do),
+                   res, o)
+    assert len(calls) == 1
+    assert K.halves_built("fwd", 64, keep) == keep
+    assert K.halves_built("bwd", 64, keep) == keep
+    # PR 38's backward: every chunk's half again where the state's adjoint
+    # comes back (g is not handed down there: k stands in)
+    bwd = K.chunk_bwd
+    monkeypatch.setattr(K, "chunk_bwd", lambda st, half, q, k, v, beta, *a: bwd(
+        st, real(q, k, k, beta, *a[2:]), q, k, v, beta, *a))
+    K.halves_built.cache_clear()
+    try:
+        assert K.halves_built("bwd", 64, keep) == 2 * keep
+    finally:
+        K.halves_built.cache_clear()
 
 
 def test_shapes_are_checked_and_facts_counted():
@@ -174,3 +287,7 @@ def test_shapes_are_checked_and_facts_counted():
         K.kda(q, k, v, g, beta[:, :8])
     assert K.n_chunks(32768, 64) == 512
     assert K.states_kept(32768, 64, 4) == 128
+    # PR 38's kernels: 4 (forward) and 7 (backward) halves a step of four
+    # chunks, 896 rows
+    assert K.halves_built("fwd") == 4 == K.halves_built("bwd")
+    assert K.table_rows(64) == 768

@@ -12,7 +12,20 @@ compiles (``tests/workloads/flash_schedule_dump.py``); libtpu's dumper
 aborts that process after the compile, which is tolerated: each kernel's
 ``*-final_hlo-static-per-bundle-utilization.txt`` is on disk by then, one
 line a bundle. Skipped where the topology cannot be described or no file
-appears."""
+appears.
+
+The KDA chunk kernels (``tests/workloads/kda_schedule_dump.py``, PR 39) are
+pinned the same way at the Kimi Linear cell's head (128 x 128, chunk 64,
+keep 4, bfloat16), by the bundles a (head, step) cell of four chunks RUNS:
+the loops that build the chunks' state-free halves are unrolled (the
+forward's only loop; the backward's first), so their bundles are counted
+as they stand, and the backward's loop over the chunks' backward — read
+from ``*-final_bundles.txt``, where a bundle inside it is marked ``>>`` —
+counts ``keep`` times. Two dumps at once collide on ``/tmp/libtpu_lockfile``
+(the second child aborts before it compiles) unless
+``ALLOW_MULTIPLE_LIBTPU_LOAD`` is set: under ``-n`` with ``--dist loadfile``
+this file's tests share a worker and the module fixtures run one after the
+other; do not run a dump by hand beside them."""
 
 import re
 import subprocess
@@ -64,3 +77,73 @@ def test_bundles_per_score_tile(dump, kernel):
     assert per_tile <= ceiling, (
         f"{name}: {per_tile:.0f} bundles a 256 x 256 tile at blocks "
         f"{bq} x {bk}, ceiling {ceiling}")
+
+
+# ------------------------------------------------------------------ KDA
+
+KDA = dict(tokens=2048, heads=4, head=128, chunk=64, keep=4)
+# kernel -> bundles a (head, step) cell of `keep` chunks runs, at most:
+# ~5% over what PR 39 reads (8,686 / 14,268; PR 38's kernels 10,067 /
+# 20,976 — 46.8 / 96.4 ms a call on the chip over 4096 cells, 1.13 ns a
+# bundle; the chip gains more than the count: PERF.md §6 PR 39)
+KDA_CEILING = {"kda_chunk_fwd": 9120, "kda_chunk_bwd": 14980}
+IN_LOOP = re.compile(r"^\s*(?:0x[0-9a-f]+|\d+)\s+([A-Z]{2})?:\s*(>*)\s*\{")
+
+
+@pytest.fixture(scope="module")
+def kda_dump(tmp_path_factory):
+    out = tmp_path_factory.mktemp("llo_kda")
+    child = Path(__file__).parent / "workloads" / "kda_schedule_dump.py"
+    proc = subprocess.run(
+        [sys.executable, str(child), str(out), *map(str, KDA.values())],
+        capture_output=True, text=True, timeout=600,
+        cwd=Path(__file__).resolve().parent.parent)
+    assert "Traceback" not in proc.stderr, proc.stderr[-2000:]
+    if "NO_TOPOLOGY" in proc.stdout:
+        pytest.skip(f"cannot describe a v5e topology here: {proc.stdout}")
+    return out
+
+
+def kda_bundles(dump: Path, name: str):
+    """``(bundles outside the chunk loops, [bundles of each loop's body])``
+    of a kernel's final schedule."""
+    files = [f for f in dump.glob("*-final_bundles.txt")
+             if re.search(rf"{name}_*\.\d+-\d+-final_bundles", f.name)]
+    if not files:
+        pytest.skip(f"the compiler wrote no schedule for {name} here")
+    outside, loops = 0, []
+    for line in files[0].read_text().splitlines():
+        m = IN_LOOP.match(line)
+        if not m:
+            continue
+        depth = len(m.group(2))
+        if depth < 2:
+            outside += 1
+            continue
+        if m.group(1) == "LB" and depth == 2:
+            loops.append(0)
+        loops[-1] += 1
+    return outside, loops
+
+
+@pytest.mark.parametrize("kernel", sorted(KDA_CEILING))
+def test_kda_bundles_a_cell(kda_dump, kernel):
+    keep = KDA["keep"]
+    outside, loops = kda_bundles(kda_dump, kernel)
+    # the halves' loops are unrolled; the backward keeps one loop, which
+    # builds no half: the chunks' backward, last to first
+    assert len(loops) == kernel.endswith("bwd"), loops
+    cell = outside + keep * sum(loops)
+    assert cell <= KDA_CEILING[kernel], (
+        f"{kernel}: {cell} bundles a cell ({outside} outside the loop, "
+        f"{keep} x {loops}), ceiling {KDA_CEILING[kernel]}")
+
+
+def test_kda_backward_rebuilds_with_the_state_s_products_alone(kda_dump):
+    """The backward's first part — every chunk's half and the states — is
+    a forward cell without its output: no larger than the forward kernel
+    plus the scratch's stores (PR 38's ran three whole forward chunks here
+    and built all four halves again in the loop after)."""
+    fwd, _ = kda_bundles(kda_dump, "kda_chunk_fwd")
+    rebuild, _ = kda_bundles(kda_dump, "kda_chunk_bwd")
+    assert rebuild <= fwd + 600, (rebuild, fwd)

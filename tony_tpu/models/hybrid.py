@@ -471,7 +471,11 @@ class KDA(nn.Module):
                 ("heads", h), ("chunk", cfg.kda_chunk),
                 ("chunks", kda_ops.n_chunks(t, cfg.kda_chunk)),
                 ("states_kept", kda_ops.states_kept(t, cfg.kda_chunk,
-                                                    cfg.kda_keep))):
+                                                    cfg.kda_keep)),
+                *((f"halves_built.{kernel}", kda_ops.halves_built(
+                    kernel, cfg.kda_chunk, cfg.kda_keep))
+                  for kernel in ("fwd", "bwd")),
+                ("table_rows", kda_ops.table_rows(cfg.kda_chunk))):
             profiler.count_once("kda:" + name, fact)
         o = kda_ops.kda(q, k, v, g, beta, chunk=cfg.kda_chunk,
                         keep=cfg.kda_keep, interpret=cfg.interpret)

@@ -26,23 +26,44 @@ belongs to the level where ``t`` and ``i`` fall in sibling blocks, and the
 reference is the first token of ``t``'s block: both exponents are sums of
 ``g`` over tokens between the two and never positive, every level is one
 ``[C, dk] x [dk, C]`` matmul under a 0/1 mask, and nothing can overflow.
-The partial sums of ``g`` are one matmul with a constant 0/1 table
-(:func:`tables`), ``g`` split in bfloat16 parts so that the product is
-exact to float32. ``(I + A)^-1`` is Neumann products: exact for a
-nilpotent matrix, 16 x 16 diagonal blocks first so that no power grows.
+Level 1 pairs ``t`` with ``t - 1``: its one exponent is ``g_t`` itself. The
+other levels' partial sums of ``g`` are ONE matmul with a constant 0/1
+table (:func:`tables`, 768 rows at C = 64), exact to float32
+(:func:`_mm_table`): ``g`` is split in bfloat16 parts (two in a bfloat16
+model: 2^-16; three in float32), the parts are stacked under each other
+and meet as many copies of the table side by side, so one native bfloat16
+product with a full contraction (K = 128) makes every block and the MXU's
+float32 accumulator adds the parts. Each block is a sum of at most 64
+nonpositive numbers, never a difference of prefix sums: nothing cancels.
+``(I + A)^-1`` is Neumann products: exact for a nilpotent matrix, 16 x 16
+diagonal blocks first so that no power grows.
 
-The forward keeps the state at the start of every ``keep``-th chunk
-(``[T / (keep C), dv, dk]`` float32 a head); the backward walks those steps
-in reverse, rebuilds the ``keep`` states of one step, and runs each
-chunk's hand-written backward (:func:`chunk_bwd`) with the state's adjoint
-carried across. Decays, state, the inverse and every accumulation are
-float32; the large matmuls take operands in the inputs' dtype.
+A chunk has two halves. What the incoming state does not touch — the decay
+factors, the pair matrices ``A`` / ``Aqk``, the inverse — is the
+**state-free half** (:func:`chunk_half`); ``U``, ``O`` and ``S'`` start
+from the state (:func:`chunk_state`). Each kernel call builds a chunk's
+state-free half ONCE. The forward keeps the state at the start of every
+``keep``-th chunk (``[T / (keep C), dv, dk]`` float32 a head); the backward
+walks those steps in reverse: one loop forward over a step's ``keep`` chunks
+builds each chunk's half, leaves it in VMEM scratch beside the state the
+chunk starts from (the factors ``[keep, 13 C, dk]`` and ``A``, ``Aqk``,
+the inverse ``[keep, 3, C, C]``, float32: 1.9 MB at 4 x 64 x 128, inside
+the default 16 MiB) and advances the state with the state's products
+alone; then each chunk's hand-written backward (:func:`chunk_bwd`) reads
+the scratch, the state's adjoint carried across. Decays, state, the
+inverse and every accumulation are float32; the large matmuls take
+operands in the inputs' dtype.
 
 Dispatch follows :mod:`tony_tpu.ops.ssm`: Pallas kernels
 (``kda_chunk_fwd``, ``kda_chunk_bwd``; grid (batch, head, step) with the
 step axis sequential and the state in VMEM scratch) on a TPU, the same
 chunk functions under ``interpret=True`` for CPU tests, and an XLA twin
-(``lax.scan`` over steps of the same functions) elsewhere.
+(``lax.scan`` over steps of the same functions) elsewhere. Only the
+kernels compiled for the chip take the split product: the twin and the
+interpreter multiply a float32 table at the highest precision — chosen by
+the table :func:`_consts` hands over, not by the backend's name, so a
+compile for a DESCRIBED chip (``tests/test_tpu_compile_hybrid.py``,
+``tests/test_kernel_schedule.py``) builds the program the chip runs.
 """
 
 from __future__ import annotations
@@ -79,15 +100,54 @@ def states_kept(t: int, chunk: int = CHUNK, keep: int = KEEP) -> int:
 
 
 @functools.lru_cache(maxsize=None)
+def halves_built(kernel: str, chunk: int = CHUNK, keep: int = KEEP) -> int:
+    """State-free halves (:func:`chunk_half`) a (head, step) cell of the
+    kernel ``"fwd"`` / ``"bwd"`` builds for its ``keep`` chunks, read off
+    the kernel's own jaxpr (the counters ``kda:halves_built.fwd`` /
+    ``.bwd``): a half takes the one ``exp`` of a chunk's stacked exponents
+    (``[(2 L + 1) C, dk]``, no other value has those rows), counted once
+    for every trip of the loops around it."""
+    d = 128
+    x = jax.ShapeDtypeStruct((1, chunk * keep, 1, d), jnp.bfloat16)
+    g = jax.ShapeDtypeStruct(x.shape, jnp.float32)
+    args = (x, x, x, g, jax.ShapeDtypeStruct(x.shape[:3], jnp.float32))
+    if kernel == "fwd":
+        call = _fwd_pallas
+    else:
+        call = _bwd_pallas
+        args += (jax.ShapeDtypeStruct((1, 1, 1, d, d), jnp.float32), x)
+    rows = chunk + table_rows(chunk)
+
+    def count(jaxpr):
+        n = 0
+        for eqn in jaxpr.eqns:
+            n += (eqn.primitive.name == "exp"
+                  and eqn.outvars[0].aval.shape[:1] == (rows,))
+            n += eqn.params.get("length", 1) * sum(
+                map(count, jax.core.jaxprs_in_params(eqn.params)))
+        return n
+    return count(jax.make_jaxpr(functools.partial(
+        call, chunk=chunk, keep=keep, interpret=False))(*args).jaxpr)
+
+
+def table_rows(chunk: int = CHUNK) -> int:
+    """Rows of 0/1 table a chunk's exponents are multiplied by (the
+    counter ``kda:table_rows``)."""
+    return tables(chunk)[0].shape[0]
+
+
+@functools.lru_cache(maxsize=None)
 def tables(chunk: int):
     """``(sums, masks)`` of a chunk length (a power of two), as numpy.
 
-    ``sums`` ``[(2 L + 2) C, C]`` 0/1: stacked blocks that, times ``g
-    [C, dk]``, give for each level ``n = 2^l`` the row exponent ``sum of g
-    over (start of t's block, t]`` and the column exponent ``sum over (i,
-    start of the block after i's]``, then ``G`` (sum over ``[0, t]``) and
-    ``G_last - G`` (sum over ``(t, C)``). ``masks`` ``[L C, C]`` 0/1: the
-    pairs ``(t, i)`` of each level."""
+    ``sums`` ``[2 L C, C]`` 0/1: stacked blocks that, times ``g [C, dk]``,
+    give for each level ``n = 2^l`` ABOVE THE FIRST the row exponent ``sum
+    of g over (start of t's block, t]`` and the column exponent ``sum over
+    (i, start of the block after i's]``, then ``G`` (sum over ``[0, t]``)
+    and ``G_last - G`` (sum over ``(t, C)``). Level 1 pairs ``t`` with ``t
+    - 1``: its row exponent is ``g_t`` itself and its column has none, so
+    it takes no rows. ``masks`` ``[L C, C]`` 0/1: the pairs ``(t, i)`` of
+    each level, the first included."""
     levels = [1 << i for i in range(chunk.bit_length() - 1)]
     if not levels or levels[-1] * 2 != chunk:
         raise ValueError(f"kda chunk {chunk}: a power of two, at least 2")
@@ -95,8 +155,9 @@ def tables(chunk: int):
     j = np.arange(chunk)[None, :]
     sums, masks = [], []
     for n in levels:
-        sums.append((j > t // n * n) & (j <= t))
-        sums.append((j > t) & (j <= (t // n + 1) * n))
+        if n > 1:
+            sums.append((j > t // n * n) & (j <= t))
+            sums.append((j > t) & (j <= (t // n + 1) * n))
         masks.append((t // n == j // n + 1) & (t // n % 2 == 1))
     sums += [j <= t, j > t]
     return (np.concatenate(sums).astype(np.float32),
@@ -115,30 +176,40 @@ def _mm(a, b, dims, cd):
                                preferred_element_type=jnp.float32)
 
 
-def _mm_table(table, x, dims, cd):
-    """A 0/1 ``table`` (bfloat16, exact) x float32 ``x``, exact to float32
-    in bfloat16 passes: ``x`` is split into parts that each fit bfloat16
-    (two where the model computes in bfloat16: 2^-16, three in float32).
-    Off the TPU one float32 product (XLA's CPU runtime has no bfloat16
-    product inside a loop)."""
-    if jax.default_backend() != "tpu":
-        return _mm32(table.astype(jnp.float32), x, dims)
-    return _mm_split(table, x, dims, cd)
-
-
-def _mm_split(table, x, dims, cd):
-    out, rest = None, x
-    for _ in range(3 if jnp.dtype(cd) == jnp.float32 else 2):
-        part = rest.astype(jnp.bfloat16)
-        rest = rest - part.astype(jnp.float32)
-        term = jax.lax.dot_general(table, part, (dims, ((), ())),
-                                   preferred_element_type=jnp.float32)
-        out = term if out is None else out + term
-    return out
-
-
 def _mm32(a, b, dims=_NN):
     return _mm(a, b, dims, jnp.float32)
+
+
+def _parts(cd) -> int:
+    """bfloat16 parts a float32 operand of a 0/1 table is split into: two
+    where the model computes in bfloat16 (2^-16), three in float32."""
+    return 3 if jnp.dtype(cd) == jnp.float32 else 2
+
+
+def _mm_table(table, x, dims, cd):
+    """A 0/1 ``table`` x float32 ``x``, exact to float32. A float32 table
+    (the twin's, the interpreter's: XLA's CPU runtime has no bfloat16
+    product inside a loop) is one float32 product. A bfloat16 table (the
+    compiled kernels': :func:`_consts`) is ``_parts(cd)`` copies of the
+    table side by side, and ``x`` is split into as many parts that each fit
+    bfloat16: table x ``x`` (``_NN``) meets the parts stacked under each
+    other in ONE native product whose contraction the copies fill (C = 64
+    alone half-fills a bfloat16 tile's lanes) and whose float32
+    accumulator adds the parts; table^T x ``x`` (``_TN``) contracts over
+    the rows and runs a product a part over the first copy."""
+    if table.dtype == jnp.float32:
+        return _mm32(table[...], x, dims)
+    parts, rest = [], x
+    for _ in range(_parts(cd)):
+        parts.append(rest.astype(jnp.bfloat16))
+        rest = rest - parts[-1].astype(jnp.float32)
+    dot = lambda a, b: jax.lax.dot_general(
+        a, b, (dims, ((), ())), preferred_element_type=jnp.float32)
+    if dims == _NN:
+        # packsite: region-local — one head's chunk (VMEM values).
+        return dot(table[...], jnp.concatenate(parts, axis=0))
+    first = table[:, :table.shape[1] // len(parts)]
+    return functools.reduce(jnp.add, (dot(first, p) for p in parts))
 
 
 def _eye(c):
@@ -173,52 +244,80 @@ def _inverse(a):
     return _mm32(_neumann(_mm32(td, a - d), c // sub, eye), td)
 
 
-def _levels(q, k, g, sums, masks, cd):
-    """What a chunk's forward and backward both start from: the exponent
-    blocks, the two pair matrices and each level's decayed operands."""
+def _f32(*xs):
+    return tuple(x.astype(jnp.float32) for x in xs)
+
+
+def _exponents(g, sums, cd):
+    """The exponent blocks of a chunk stacked ``[(2 L + 1) C, dk]``: level
+    1's row exponent, ``g`` itself, then the table's blocks
+    (:func:`tables`) — each further level's row and column exponents,
+    ``G``, ``G_last - G``. Sums of ``g``, each exact to float32; none is
+    positive."""
+    # packsite: region-local — one head's chunk (VMEM values).
+    return jnp.concatenate([g, _mm_table(sums, g, _NN, cd)], axis=0)
+
+
+def _operands(q, k, ex, masks):
+    """Each level's decay factors (blocks of ``ex``, the ``exp`` of
+    :func:`_exponents`), decayed operands and pair mask, ``(er, ec, kr, qr,
+    kc, m)``; ``ec`` is None at level 1, whose column operand is ``k``."""
     c = q.shape[0]
-    e = _mm_table(sums, g, _NN, cd)
-    block = lambda i: e[i * c:(i + 1) * c]
-    n_levels = masks.shape[0] // c
+    block = lambda i: ex[i * c:(i + 1) * c]
+    for l in range(masks.shape[0] // c):
+        er, ec = block(max(2 * l - 1, 0)), block(2 * l) if l else None
+        yield (er, ec, k * er, q * er, k * ec if l else k,
+               masks[l * c:(l + 1) * c])
+
+
+def chunk_half(q, k, g, beta, sums, masks, cd):
+    """The half of a chunk that its incoming state does not touch, made
+    once a chunk by each kernel call: ``q, k, g [C, dk]``, ``beta [C, 1]``
+    -> ``(ex, akk, aqk, t)`` float32 — the decay factors (``exp`` of
+    :func:`_exponents`), the two pair matrices ``[C, C]`` and ``t = (I +
+    beta akk)^-1``."""
+    q, k, g, beta = _f32(q, k, g, beta)
+    ex = jnp.exp(_exponents(g, sums, cd))
     akk = aqk = None
-    per_level = []
-    for l in range(n_levels):
-        er, ec = jnp.exp(block(2 * l)), jnp.exp(block(2 * l + 1))
-        kr, qr, kc = k * er, q * er, k * ec
-        m = masks[l * c:(l + 1) * c]
+    for _, _, kr, qr, kc, m in _operands(q, k, ex, masks):
         pk, pq = m * _mm(kr, kc, _NT, cd), m * _mm(qr, kc, _NT, cd)
         akk = pk if akk is None else akk + pk
         aqk = pq if aqk is None else aqk + pq
-        per_level.append((er, ec, kr, qr, kc, m))
-    eg, el = jnp.exp(block(2 * n_levels)), jnp.exp(block(2 * n_levels + 1))
-    aqk = aqk + jnp.where(_eye(c), jnp.sum(q * k, axis=1, keepdims=True), 0.0)
-    return akk, aqk, eg, el, per_level
+    aqk = aqk + jnp.where(_eye(q.shape[0]),
+                          jnp.sum(q * k, axis=1, keepdims=True), 0.0)
+    return ex, akk, aqk, _inverse(beta * akk)
 
 
-def chunk_fwd(st, q, k, v, g, beta, sums, masks, cd):
-    """One chunk of one head: ``st [dv, dk]`` float32 (the state,
-    transposed: a decay scales its lanes), ``q, k, g [C, dk]``, ``v
-    [C, dv]``, ``beta [C, 1]`` -> ``(o [C, dv], state after)``, float32."""
-    f32 = lambda x: x.astype(jnp.float32)
-    q, k, v, g, beta = f32(q), f32(k), f32(v), f32(g), f32(beta)
+def chunk_state(st, half, q, k, v, beta, cd):
+    """The half of a chunk's forward that starts from the state: ``st
+    [dv, dk]`` float32 (the state, transposed: a decay scales its lanes),
+    ``half`` from :func:`chunk_half`, ``v [C, dv]`` -> ``(o [C, dv], state
+    after)``, float32."""
+    q, k, v, beta = _f32(q, k, v, beta)
     c = q.shape[0]
-    akk, aqk, eg, el, _ = _levels(q, k, g, sums, masks, cd)
-    t = _inverse(beta * akk)
+    ex, _, aqk, t = half
+    eg, el = ex[-2 * c:-c], ex[-c:]
     u = _mm(t, beta * (v - _mm(k * eg, st, _NT, cd)), _NN, cd)
     o = _mm(q * eg, st, _NT, cd) + _mm(aqk, u, _NN, cd)
     return o, st * eg[c - 1:c] + _mm(u, k * el, _TN, cd)
 
 
-def chunk_bwd(st, q, k, v, g, beta, do, dst1, sums, masks, cd):
-    """The chunk's backward, by hand: ``(dq, dk, dv, dg, dbeta, dst)`` from
-    the output's and the outgoing state's adjoints. ``d(I + A)^-1`` is
-    closed (``dA = -(T^T dU) U^T``), so the inverse's products are not
-    walked back."""
-    f32 = lambda x: x.astype(jnp.float32)
-    q, k, v, g, beta, do = f32(q), f32(k), f32(v), f32(g), f32(beta), f32(do)
+def chunk_fwd(st, q, k, v, g, beta, sums, masks, cd):
+    """One chunk of one head, both halves: ``(o, state after)``."""
+    half = chunk_half(q, k, g, beta, sums, masks, cd)
+    return chunk_state(st, half, q, k, v, beta, cd)
+
+
+def chunk_bwd(st, half, q, k, v, beta, do, dst1, sums, masks, cd):
+    """The chunk's backward, by hand, from the state it started from and
+    its state-free half: ``(dq, dk, dv, dg, dbeta, dst)`` from the
+    output's and the outgoing state's adjoints. ``d(I + A)^-1`` is closed
+    (``dA = -(T^T dU) U^T``), so the inverse's products are not walked
+    back."""
+    q, k, v, beta, do = _f32(q, k, v, beta, do)
     c = q.shape[0]
-    akk, aqk, eg, el, per_level = _levels(q, k, g, sums, masks, cd)
-    t = _inverse(beta * akk)
+    ex, akk, aqk, t = half
+    eg, el = ex[-2 * c:-c], ex[-c:]
     kg, qg, kl = k * eg, q * eg, k * el
     resid = v - _mm(kg, st, _NT, cd)
     u = _mm(t, beta * resid, _NN, cd)
@@ -248,18 +347,19 @@ def chunk_bwd(st, q, k, v, g, beta, do, dst1, sums, masks, cd):
     dk = dkg * eg + dkl * el + diag * q
     row = jax.lax.broadcasted_iota(jnp.int32, eg.shape, 0)
     de = []
-    for er, ec, kr, qr, kc, m in per_level:
+    for er, ec, kr, qr, kc, m in _operands(q, k, ex, masks):
         dpk, dpq = m * dakk, m * daqk
         dkr, dqr = _mm(dpk, kc, _NN, cd), _mm(dpq, kc, _NN, cd)
         dkc = _mm(dpk, kr, _TN, cd) + _mm(dpq, qr, _TN, cd)
         dq = dq + dqr * er
-        dk = dk + dkr * er + dkc * ec
-        de += [dkr * kr + dqr * qr, dkc * kc]
+        dk = dk + dkr * er + (dkc if ec is None else dkc * ec)
+        de += [dkr * kr + dqr * qr] + ([] if ec is None else [dkc * kc])
     de += [dkg * kg + dqg * qg + jnp.where(row == c - 1, d_last, 0.0),
            dkl * kl]
     # packsite: region-local — the exponent blocks' adjoints stacked as
-    # the table stacks the blocks, one head's chunk (VMEM values).
-    dg = _mm_table(sums, jnp.concatenate(de, axis=0), _TN, cd)
+    # the table stacks the blocks, one head's chunk (VMEM values); level
+    # 1's exponent is g itself.
+    dg = de[0] + _mm_table(sums, jnp.concatenate(de[1:], axis=0), _TN, cd)
     return dq, dk, dv, dg, dbeta, dst
 
 
@@ -281,9 +381,16 @@ def _unsteps(x):
     return x.transpose(2, 0, 1, 4, 3, 5).reshape(b, s * keep * chunk, h, d)
 
 
-def _consts(chunk):
+def _consts(chunk, cd=None):
+    """The tables as the chunk functions take them: float32 for the twin
+    and the interpreter; for the kernels compiled for the chip (``cd``
+    their compute dtype) the sums in bfloat16 (0/1: exact), ``_parts(cd)``
+    copies side by side (:func:`_mm_table`)."""
     sums, masks = tables(chunk)
-    return jnp.asarray(sums, jnp.bfloat16), jnp.asarray(masks)
+    if cd is None:
+        return jnp.asarray(sums), jnp.asarray(masks)
+    return (jnp.asarray(np.tile(sums, (1, _parts(cd))), jnp.bfloat16),
+            jnp.asarray(masks))
 
 
 def _fwd_xla(q, k, v, g, beta, chunk, keep):
@@ -307,21 +414,26 @@ def _fwd_xla(q, k, v, g, beta, chunk, keep):
 def _bwd_xla(q, k, v, g, beta, kept, do, chunk, keep):
     sums, masks = _consts(chunk)
     kw = dict(sums=sums, masks=masks, cd=q.dtype)
-    fwd = jax.vmap(jax.vmap(functools.partial(chunk_fwd, **kw)))
-    bwd = jax.vmap(jax.vmap(functools.partial(chunk_bwd, **kw)))
+    over = lambda f, **consts: jax.vmap(jax.vmap(
+        functools.partial(f, **consts)))
+    half_of = over(chunk_half, **kw)
+    state, bwd = over(chunk_state, cd=q.dtype), over(chunk_bwd, **kw)
 
     def step(dst, xs):
         st, x, do = xs
 
         def rebuild(st, x):
-            return fwd(st, *x)[1], st
-        _, states = jax.lax.scan(rebuild, st, x)
+            q, k, v, g, beta = x
+            half = half_of(q, k, g, beta)
+            return state(st, half, q, k, v, beta)[1], (st, half)
+        _, (states, halves) = jax.lax.scan(rebuild, st, x)
 
         def chunk_of(dst, args):
-            st, x, do = args
-            *grads, dst0 = bwd(st, *x, do, dst)
+            st, half, (q, k, v, _, beta), do = args
+            *grads, dst0 = bwd(st, half, q, k, v, beta, do, dst)
             return dst0, tuple(grads)
-        return jax.lax.scan(chunk_of, dst, (states, x, do), reverse=True)
+        return jax.lax.scan(chunk_of, dst, (states, halves, x, do),
+                            reverse=True)
     x = tuple(_steps(a, chunk, keep) for a in (q, k, v, g, beta[..., None]))
     dst = jnp.zeros_like(kept[:, :, 0])
     _, grads = jax.lax.scan(
@@ -340,56 +452,71 @@ def _bwd_xla(q, k, v, g, beta, kept, do, chunk, keep):
 def kda_chunk_fwd(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, masks_ref,
                   o_ref, kept_ref, st_scr, *, chunk: int, keep: int):
     """One (batch, head, step) cell: ``keep`` chunks. Writes o and the
-    state the step STARTED from."""
+    state the step STARTED from. The loop runs unrolled: a chunk's
+    state-free half does not wait for the chunk before it, and in one block
+    the scheduler starts it under that chunk's tail (on the chip 45.2 ms a
+    call for 50.3 at the Kimi Linear cell's size: PERF.md §6 PR 39). The
+    tables go down as refs (:func:`kda_chunk_bwd` says why)."""
     @pl.when(pl.program_id(2) == 0)
     def _init():
         st_scr[...] = jnp.zeros_like(st_scr)
 
     kept_ref[...] = st_scr[...]
-    sums, masks = sums_ref[...], masks_ref[...]
 
     def body(j, st):
         at = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
         o, st1 = chunk_fwd(st, q_ref[at, :], k_ref[at, :], v_ref[at, :],
                            g_ref[at, :], _column(b_ref[pl.ds(j, 1), :]),
-                           sums, masks, q_ref.dtype)
+                           sums_ref, masks_ref, q_ref.dtype)
         o_ref[at, :] = o.astype(o_ref.dtype)
         return st1
 
-    st_scr[...] = jax.lax.fori_loop(0, keep, body, st_scr[...])
+    st_scr[...] = jax.lax.fori_loop(0, keep, body, st_scr[...], unroll=True)
 
 
 def kda_chunk_bwd(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, masks_ref,
                   kept_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
-                  states_scr, dst_scr, *, chunk: int, keep: int):
+                  states_scr, dst_scr, ex_scr, pairs_scr, *, chunk: int,
+                  keep: int):
     """The same cell in reverse (the index maps walk the steps last to
-    first): rebuild the step's ``keep`` incoming states, then each chunk's
-    backward from the last to the first, the state's adjoint in scratch
-    across steps."""
+    first). One loop forward over the step's chunks builds each chunk's
+    state-free half, leaves it in scratch (``ex_scr``: the decay factors;
+    ``pairs_scr``: akk, aqk, t) beside the state the chunk starts from,
+    and advances the state with the state's products alone (unrolled as
+    the forward's loop); then each chunk's backward from the last to the
+    first reads the scratch, the state's adjoint in scratch across steps
+    (a loop: each chunk waits for the adjoint of the one after it, and
+    unrolled it ran no faster)."""
     @pl.when(pl.program_id(2) == 0)
     def _init():
         dst_scr[...] = jnp.zeros_like(dst_scr)
 
-    sums, masks = sums_ref[...], masks_ref[...]
-    cd = q_ref.dtype
+    # the tables go down as refs, read where a product takes them: read
+    # whole up here they spill, ~100 vregs, before the loops start
+    sums, masks, cd = sums_ref, masks_ref, q_ref.dtype
 
     def inputs(j):
         at = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
-        return at, (q_ref[at, :], k_ref[at, :], v_ref[at, :], g_ref[at, :],
+        return at, (q_ref[at, :], k_ref[at, :], v_ref[at, :],
                     _column(b_ref[pl.ds(j, 1), :]))
 
     def rebuild(j, st):
+        at, (q, k, v, beta) = inputs(j)
+        half = chunk_half(q, k, g_ref[at, :], beta, sums, masks, cd)
         states_scr[j] = st
-        return chunk_fwd(st, *inputs(j)[1], sums, masks, cd)[1]
+        ex_scr[j] = half[0]
+        for n, pair in enumerate(half[1:]):
+            pairs_scr[j, n] = pair
+        return chunk_state(st, half, q, k, v, beta, cd)[1]
 
-    states_scr[keep - 1] = jax.lax.fori_loop(0, keep - 1, rebuild,
-                                             kept_ref[...])
+    jax.lax.fori_loop(0, keep, rebuild, kept_ref[...], unroll=True)
 
     def body(i, dst):
         j = keep - 1 - i
         at, x = inputs(j)
+        half = (ex_scr[j], *(pairs_scr[j, n] for n in range(3)))
         dq, dk, dv, dg, db, dst0 = chunk_bwd(
-            states_scr[j], *x, do_ref[at, :], dst, sums, masks, cd)
+            states_scr[j], half, *x, do_ref[at, :], dst, sums, masks, cd)
         dq_ref[at, :] = dq.astype(dq_ref.dtype)
         dk_ref[at, :] = dk.astype(dk_ref.dtype)
         dv_ref[at, :] = dv.astype(dv_ref.dtype)
@@ -436,7 +563,7 @@ def _fwd_pallas(q, k, v, g, beta, chunk, keep, interpret):
     dv = v.shape[3]
     rows = chunk * keep
     steps = t // rows
-    sums, masks = _consts(chunk)
+    sums, masks = _consts(chunk, None if interpret else q.dtype)
     seq, beta_spec, kept, whole = _specs(rows, keep, dk, dv, lambda si: si)
     o, states = pl.pallas_call(
         functools.partial(kda_chunk_fwd, chunk=chunk, keep=keep),
@@ -459,7 +586,7 @@ def _bwd_pallas(q, k, v, g, beta, states, do, chunk, keep, interpret):
     dv = v.shape[3]
     rows = chunk * keep
     steps = t // rows
-    sums, masks = _consts(chunk)
+    sums, masks = _consts(chunk, None if interpret else q.dtype)
     seq, beta_spec, kept, whole = _specs(rows, keep, dk, dv,
                                          lambda si: steps - 1 - si)
     packed = lambda d, dtype: jax.ShapeDtypeStruct((b, t, h * d), dtype)
@@ -473,8 +600,14 @@ def _bwd_pallas(q, k, v, g, beta, states, do, chunk, keep, interpret):
                    packed(dv, v.dtype), packed(dk, jnp.float32),
                    jax.ShapeDtypeStruct((b, h, steps, keep, chunk),
                                         jnp.float32)),
-        scratch_shapes=[pltpu.VMEM((keep, dv, dk), jnp.float32),
-                        pltpu.VMEM((dv, dk), jnp.float32)],
+        # the step's incoming states, the state's adjoint, and each
+        # chunk's state-free half: (keep, 13 C, dk) + (keep, 3, C, C)
+        # float32 = 1.9 MB at the cell's 4 x 64 x 128
+        scratch_shapes=[
+            pltpu.VMEM((keep, dv, dk), jnp.float32),
+            pltpu.VMEM((dv, dk), jnp.float32),
+            pltpu.VMEM((keep, chunk + table_rows(chunk), dk), jnp.float32),
+            pltpu.VMEM((keep, 3, chunk, chunk), jnp.float32)],
         compiler_params=_SEQ, interpret=interpret, name="kda_chunk_bwd",
     )(q.reshape(b, t, h * dk), k.reshape(b, t, h * dk),
       v.reshape(b, t, h * dv), g.reshape(b, t, h * dk),
