@@ -6,7 +6,10 @@ states and a padded length, under a decay strong enough to overflow
 the XLA twin; and the pieces: the level tables, the inverse, the exact 0/1
 product and the exponent blocks it makes, the hand-written chunk backward
 (from a chunk's state-free half, made once) against autodiff of the two
-halves composed, and the halves a kernel call builds."""
+halves composed, and the halves a kernel call builds. The same under ONE
+decay a head (Gated DeltaNet: ``g [B, T, H]``), key and value sizes that
+differ and lie off the lane width (the kernels' zero lanes), beta up to
+2."""
 
 import jax
 import jax.numpy as jnp
@@ -291,3 +294,181 @@ def test_shapes_are_checked_and_facts_counted():
     # chunks, 896 rows
     assert K.halves_built("fwd") == 4 == K.halves_built("bwd")
     assert K.table_rows(64) == 768
+
+
+# -- one decay a head (Gated DeltaNet) ---------------------------------------
+
+def _scalar_data(t, h=2, dk=24, dv=40, decay=0.3, seed=0, dtype=jnp.float32,
+                 alike=0.0):
+    """``dk != dv``, both off the lane width; ``g [1, t, h]``; beta in (0,
+    2). ``alike`` mixes one direction into every key (neighbouring tokens'
+    keys of a trained model point alike)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (1, t, h, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (1, t, h, dk))
+             + alike * dk ** 0.5 * unit(jax.random.normal(ks[5], (1, 1, h, dk))))
+    v = jax.random.normal(ks[2], (1, t, h, dv))
+    g = -jax.nn.softplus(jax.random.normal(ks[3], (1, t, h))) * decay
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (1, t, h)))
+    return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta)
+
+
+def _plain(q, k, v, g, beta):
+    """The token-by-token recurrence with the scalar decay on every key
+    channel."""
+    return K.kda_reference(q, k, v, jnp.broadcast_to(g[..., None], q.shape),
+                           beta)
+
+
+def _both_scalar(t, chunk, keep, decay=0.3, interpret=None, **sizes):
+    args = _scalar_data(t, decay=decay, seed=t, **sizes)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    mine = lambda *a: (K.kda(*a, chunk=chunk, keep=keep,
+                             interpret=interpret).astype(jnp.float32)
+                       * w).sum()
+    plain = lambda *a: (_plain(*a) * w).sum()
+    return (K.kda(*args, chunk=chunk, keep=keep, interpret=interpret),
+            _plain(*args), jax.grad(mine, (0, 1, 2, 3, 4))(*args),
+            jax.grad(plain, (0, 1, 2, 3, 4))(*args))
+
+
+@pytest.fixture(scope="module", params=SHAPES, ids=lambda s: "t%d_c%d_k%d" % s)
+def scalar(request):
+    return _both_scalar(*request.param)
+
+
+def test_scalar_decay_output_matches_the_recurrence(scalar):
+    o, want, g, _ = scalar
+    assert o.shape == want.shape and o.shape[-1] == 40
+    assert g[3].shape == g[4].shape == o.shape[:3]     # dg leaves [B, T, H]
+    assert _rel(o, want) < 2e-5
+
+
+@pytest.mark.parametrize("arg", range(5), ids=ARGS)
+def test_scalar_decay_gradient_matches_the_recurrence(scalar, arg):
+    _, _, g, want = scalar
+    assert _rel(g[arg], want[arg]) < 5e-5, ARGS[arg]
+
+
+@pytest.fixture(scope="module", params=[4.0, 12.0], ids=["decay4", "decay12"])
+def scalar_strong(request):
+    """``exp(G_t - G_i)`` is taken under the causal mask: above the
+    diagonal the difference is positive and e^256 is beyond float32."""
+    return _both_scalar(96, 32, 2, decay=request.param)
+
+
+@pytest.mark.parametrize("arg", range(6), ids=("o",) + ARGS)
+def test_scalar_decay_strong_overflows_nothing(scalar_strong, arg):
+    o, want, g, gw = scalar_strong
+    got, want = ((o,) + tuple(g))[arg], ((want,) + tuple(gw))[arg]
+    assert np.isfinite(np.asarray(got)).all()
+    assert _rel(got, want) < 5e-5
+
+
+@pytest.fixture(scope="module", params=[(256, 64, 2, 0.3), (128, 64, 1, 6.0)],
+                ids=["steps2", "strong"])
+def scalar_interpreted(request):
+    """The Pallas bodies at the Olmo Hybrid cell's head sizes, 96 and 192,
+    behind zero lanes (128 and 256), and the XLA twin at 96 and 192."""
+    t, chunk, keep, decay = request.param
+    sizes = dict(dk=96, dv=192)
+    return (_both_scalar(t, chunk, keep, decay, interpret=True, **sizes),
+            _both_scalar(t, chunk, keep, decay, interpret=None, **sizes))
+
+
+@pytest.mark.parametrize("arg", range(6), ids=("o",) + ARGS)
+def test_scalar_decay_kernel_bodies_equal_the_twin(scalar_interpreted, arg):
+    (o, want, g, gw), (o2, _, g2, _) = scalar_interpreted
+    a, b, c = (((x,) + tuple(y))[arg] for x, y in ((o, g), (o2, g2),
+                                                   (want, gw)))
+    assert a.shape == b.shape == c.shape
+    assert _rel(a, b) < 2e-6 and _rel(a, c) < 5e-5
+
+
+def test_zero_lanes_serve_the_per_channel_route_too():
+    """Head size 24 through the kernel bodies: q, k, g padded to 128 key
+    lanes, v to 128 value lanes, o cut back."""
+    args = _data(64, d=24, seed=5)
+    o = K.kda(*args, chunk=16, keep=2, interpret=True)
+    assert o.shape == args[2].shape
+    assert _rel(o, K.kda_reference(*args)) < 2e-5
+    assert _rel(o, K.kda(*args, chunk=16, keep=2)) < 1e-6
+
+
+@pytest.mark.parametrize("arg", range(6), ids=("o",) + ARGS)
+def test_scalar_decay_bfloat16_stays_close(arg):
+    args = _scalar_data(128, seed=3, dtype=jnp.bfloat16)
+    exact = tuple(a.astype(jnp.float32) for a in args)
+    if arg == 0:
+        assert _rel(K.kda(*args, chunk=32, keep=2), _plain(*exact)) < 1e-2
+        return
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    grad = lambda f, a: jax.grad(
+        lambda *a: (f(*a).astype(jnp.float32) * w).sum(), arg - 1)(*a)
+    got = grad(lambda *a: K.kda(*a, chunk=32, keep=2), args)
+    # reads 2.5e-3 to 4.5e-3
+    assert _rel(got, grad(_plain, exact)) < 1e-2, ARGS[arg - 1]
+
+
+@pytest.mark.parametrize("alike, tol", [(0.0, 2e-5), (0.5, 2e-5), (2.0, 5e-4)])
+def test_beta_up_to_two_over_keys_that_point_alike(alike, tol):
+    """``(I + A)^-1`` by Neumann products is exact for any nilpotent ``A``,
+    but its powers grow with ``beta |k_t . k_i|`` before they cancel: with
+    beta in (0, 2) and keys sharing a direction (cosine ~0.2 at 0.5, ~0.8
+    at 2) float32 still holds the recurrence, the looser the more alike."""
+    args = _scalar_data(128, seed=11, alike=alike)
+    cos = jnp.einsum("bthd,bshd->bhts", args[1], args[1])
+    assert (alike == 0) or float(jnp.mean(cos)) > 0.15 * alike
+    assert float(args[4].max()) > 1.8
+    assert _rel(K.kda(*args, chunk=64, keep=2), _plain(*args)) < tol
+
+
+@pytest.fixture(scope="module", params=[
+    (16, jnp.float32, 0.3), (64, jnp.float32, 0.3), (16, jnp.bfloat16, 0.3),
+    (64, jnp.bfloat16, 0.3), (64, jnp.float32, 6.0)],
+    ids=lambda p: f"c{p[0]}_{jnp.dtype(p[1]).name}_decay{p[2]}")
+def scalar_chunk_vjp(request):
+    """``chunk_vjp`` without tables: one decay a head, ``g [C, 1]``, a
+    ``dk x dv`` state."""
+    c, cd, decay = request.param
+    dk, dv = 24, 40
+    q, k, v, g, beta = (a[0, :, 0] for a in _scalar_data(
+        c, h=1, dk=dk, dv=dv, decay=decay, seed=c, dtype=cd))
+    g, beta = g[:, None], beta[:, None]
+    st = jax.random.normal(jax.random.PRNGKey(2), (dv, dk))
+    do = jax.random.normal(jax.random.PRNGKey(4), (c, dv)) * 0.3
+    dst1 = jax.random.normal(jax.random.PRNGKey(5), (dv, dk)) * 0.1
+    kw = dict(sums=None, masks=None, cd=cd)
+    fwd = lambda st, q, k, v, g, b: K.chunk_fwd(st, q, k, v, g, b, **kw)
+    _, vjp = jax.vjp(fwd, st, q, k, v, g, beta)
+    half = K.chunk_half(q, k, g, beta, **kw)
+    assert len(half) == 5 and half[0].shape == (2 * c, dk)
+    *grads, dst = K.chunk_bwd(st, half, q, k, v, beta, do, dst1, **kw)
+    # reads at most 1.3e-6 (dg under decay 6: row and column sums of both
+    # signs) and 4.7e-3
+    tol = 3e-6 if cd == jnp.float32 else 1e-2
+    return (dst, *grads), vjp((do, dst1)), tol
+
+
+@pytest.mark.parametrize("out", range(6), ids=("state",) + ARGS)
+def test_scalar_chunk_backward_is_the_forward_s_vjp(scalar_chunk_vjp, out):
+    got, want, tol = scalar_chunk_vjp
+    assert got[out].shape == want[out].shape
+    assert _rel(got[out], want[out]) < tol
+
+
+def test_scalar_decay_takes_no_table_and_names_its_kernels():
+    """The scalar route's kernels get five operands (no 0/1 tables) and
+    their own names; the per-channel route's keep theirs."""
+    def calls(args):
+        text = str(jax.make_jaxpr(lambda *a: K.kda(
+            *a, chunk=64, keep=2, interpret=False))(*args))
+        return [n for n in ("kda_chunk_fwd", "gdn_chunk_fwd")
+                if f"name={n}" in text or f"{n} for" in text]
+    assert calls(_scalar_data(128, dk=96, dv=192)) == ["gdn_chunk_fwd"]
+    assert calls(_data(128, d=128)) == ["kda_chunk_fwd"]
+    assert K._tables(_scalar_data(16)[3], 64) == ()
+    with pytest.raises(ValueError, match="kda shapes"):
+        q, k, v, g, beta = _scalar_data(16)
+        K.kda(q, k, v, g[..., :1], beta)

@@ -25,7 +25,12 @@ counts ``keep`` times. Two dumps at once collide on ``/tmp/libtpu_lockfile``
 (the second child aborts before it compiles) unless
 ``ALLOW_MULTIPLE_LIBTPU_LOAD`` is set: under ``-n`` with ``--dist loadfile``
 this file's tests share a worker and the module fixtures run one after the
-other; do not run a dump by hand beside them."""
+other; do not run a dump by hand beside them.
+
+The same kernels under ONE decay a head (``gdn_chunk_fwd`` / ``_bwd``, PR
+40) at the Olmo Hybrid cell's head behind its zero lanes (96 x 192 as 128
+x 256): no levels and no table, so a cell is smaller than the per-channel
+route's though its state is twice as wide."""
 
 import re
 import subprocess
@@ -92,10 +97,13 @@ IN_LOOP = re.compile(r"^\s*(?:0x[0-9a-f]+|\d+)\s+([A-Z]{2})?:\s*(>*)\s*\{")
 
 @pytest.fixture(scope="module")
 def kda_dump(tmp_path_factory):
-    out = tmp_path_factory.mktemp("llo_kda")
+    return _delta_dump(tmp_path_factory.mktemp("llo_kda"), KDA)
+
+
+def _delta_dump(out, sizes):
     child = Path(__file__).parent / "workloads" / "kda_schedule_dump.py"
     proc = subprocess.run(
-        [sys.executable, str(child), str(out), *map(str, KDA.values())],
+        [sys.executable, str(child), str(out), *map(str, sizes.values())],
         capture_output=True, text=True, timeout=600,
         cwd=Path(__file__).resolve().parent.parent)
     assert "Traceback" not in proc.stderr, proc.stderr[-2000:]
@@ -147,3 +155,32 @@ def test_kda_backward_rebuilds_with_the_state_s_products_alone(kda_dump):
     fwd, _ = kda_bundles(kda_dump, "kda_chunk_fwd")
     rebuild, _ = kda_bundles(kda_dump, "kda_chunk_bwd")
     assert rebuild <= fwd + 600, (rebuild, fwd)
+
+
+# ------------------------------------------------- one decay a head (GDN)
+
+GDN = dict(KDA, value=256)          # 96 x 192 behind zero lanes: 128 x 256
+# ~5% over what PR 40 reads (7,348 / 11,272 = 6,808 + 4 x 1,116): the pair
+# matrices are one product and a [C, C] factor, no level and no table, but
+# the inverse (ten dependent 64^3 float32 products a chunk: PERF.md §5, the
+# Kimi row) is the per-channel route's, and the state's products run over
+# 256 value lanes
+GDN_CEILING = {"gdn_chunk_fwd": 7720, "gdn_chunk_bwd": 11840}
+
+
+@pytest.fixture(scope="module")
+def gdn_dump(tmp_path_factory):
+    return _delta_dump(tmp_path_factory.mktemp("llo_gdn"), GDN)
+
+
+@pytest.mark.parametrize("kernel", sorted(GDN_CEILING))
+def test_gdn_bundles_a_cell(gdn_dump, kernel):
+    keep = GDN["keep"]
+    outside, loops = kda_bundles(gdn_dump, kernel)
+    assert len(loops) == kernel.endswith("bwd"), loops
+    cell = outside + keep * sum(loops)
+    assert cell <= GDN_CEILING[kernel], (
+        f"{kernel}: {cell} bundles a cell ({outside} outside the loop, "
+        f"{keep} x {loops}), ceiling {GDN_CEILING[kernel]}")
+    # a scalar decay's cell is the smaller, at twice the value lanes
+    assert GDN_CEILING[kernel] < KDA_CEILING[kernel.replace("gdn", "kda")]

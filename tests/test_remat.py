@@ -470,6 +470,24 @@ def test_only_a_fenced_step_puts_barriers_around_its_layers(model_name, kw):
     assert barriers(remat.Saved(prevent_cse=True)) == (fenced + merged, 0)
 
 
+@pytest.mark.parametrize("rung", [("q", "k", "v", "wo", "gate", "up"),
+                                  ("gate", "up"), ()], ids="+".join)
+def test_delta_rule_and_plain_attention_mixers_name_the_ladders_residuals(
+        rung):
+    """The layer-kind decoder's ``gdn`` and ``attn`` mixers name ``q, k, v,
+    wo`` and its dense feed-forward ``gate, up`` (ISSUE 40): a rung meets
+    every name, wraps every layer, and changes no number of the gradient."""
+    loss, params, saved = _loss_fn("olmo-hybrid-tiny", rung, xent_chunk=8,
+                                   layers=("gdn", "attn"))
+    grads = jax.grad(loss)(params)
+    assert saved.met == set(FULL) and saved.blocks
+    floor, params, _ = _loss_fn("olmo-hybrid-tiny", (), xent_chunk=8,
+                                layers=("gdn", "attn"))
+    want = jax.grad(floor)(params)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        assert jnp.allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
 def test_names_are_inert_outside_a_step():
     """``model.init``, the serve forward and a step on the CPU trace the
     same modules with no set active: nothing is met, nothing is kept."""

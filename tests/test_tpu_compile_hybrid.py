@@ -4,7 +4,9 @@ N = 16; 40 zero-extended query heads of 128 over 10 K/V heads of 128,
 window 512) and ``kimilinear.train-32k`` runs (1 x 32768 tokens; the KDA
 chunk kernels over 32 heads of 128 x 128; the latent-attention grids at a
 192-wide q/k — 128 of a head's own and 64 shared — over 128-wide values,
-32 heads): as ``tests/test_tpu_compile.py``, a pass is a COMPILE for a
+32 heads) and ``olmohybrid.train-16k`` runs (1 x 16384 tokens; the same
+chunk kernels under one decay a head over 15 heads of 96 x 192, behind zero
+lanes; the packed flash kernels at 15 heads of 128): as ``tests/test_tpu_compile.py``, a pass is a COMPILE for a
 chip that is not attached — what Mosaic refuses there (a block off the
 tiling, too much VMEM, an SMEM block it cannot place) is refused here."""
 
@@ -85,6 +87,37 @@ def _kda(grad):
     return build
 
 
+T16K, OH, DK, DV = 16384, 15, 96, 192     # olmohybrid.train-16k
+
+
+def _gdn(grad):
+    from tony_tpu.ops.kda import kda
+
+    def fwd(*args):
+        return kda(*args, interpret=False)
+
+    def build(sh):
+        s = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                       sharding=sh)
+        args = (s(jnp.bfloat16, 1, T16K, OH, DK),) * 2 + (
+            s(jnp.bfloat16, 1, T16K, OH, DV),) + (
+            s(jnp.float32, 1, T16K, OH),) * 2
+        if not grad:
+            return fwd, args
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        range(5)), args
+    return build
+
+
+def _flash15(sh):
+    from tony_tpu.ops import flash_attention_packed
+
+    s = jax.ShapeDtypeStruct((1, T16K, OH * D), jnp.bfloat16, sharding=sh)
+    return jax.grad(lambda q, k, v: flash_attention_packed(
+        q, k, v, OH, causal=True, scale=D ** -0.5, interpret=False).astype(
+            jnp.float32).sum(), (0, 1, 2)), (s, s, s)
+
+
 def _mla(sh):
     from tony_tpu.ops.attention import flash_attention_mla
 
@@ -96,6 +129,9 @@ def _mla(sh):
 
 
 CASES = {
+    "gdn_chunk_fwd_15x96x192_t16384": _gdn(grad=False),
+    "gdn_chunk_fwd_bwd_15x96x192_t16384": _gdn(grad=True),
+    "flash_packed_causal_15x128_t16384_fwd_bwd": _flash15,
     "kda_chunk_fwd_32x128_t32768": _kda(grad=False),
     "kda_chunk_fwd_bwd_32x128_t32768": _kda(grad=True),
     "flash_mla_fwd_bwd_32x192over128_t32768": _mla,
@@ -133,6 +169,19 @@ def test_kernel_compiles_for_v5e(topo, case):
         assert kept < chunks
         assert f"f32[1,{KH},{kept},{D},{D}]" in text
         assert f"f32[1,{KH},{chunks},{D},{D}]" not in text
+    elif case.startswith("gdn"):
+        # head sizes 96 and 192 reach the Pallas kernels (no fallback
+        # warning above) behind zero lanes: q, k 128 and v, o 256 a head
+        assert "gdn_chunk_fwd" in text and "kda_chunk" not in text
+        assert ("gdn_chunk_bwd" in text) == ("bwd" in case)
+        assert f"bf16[1,{T16K},{OH * 128}]" in text
+        assert f"bf16[1,{T16K},{OH * 256}]" in text
+        from tony_tpu.ops import kda as kda_ops
+        kept = kda_ops.states_kept(T16K)
+        assert f"f32[1,{OH},{kept},256,128]" in text
+        # the decay and its gradient travel as rows, one float a head and
+        # token: no [T, H x dk] float32 gate
+        assert f"f32[1,{T16K},{OH * 128}]" not in text
     elif case.startswith("flash_mla"):
         for name in ("attn_fwd_mla", "attn_bwd_dq_mla", "attn_bwd_dkv_mla"):
             assert name in text, name
@@ -142,3 +191,47 @@ def test_kernel_compiles_for_v5e(topo, case):
         # a windowed call's operations carry ``_win`` in their name
         assert "attn_fwd" in text
         assert ("attn_fwd_win" in text) == ("window" in case)
+
+
+def test_olmo_hybrid_step_fits_with_its_second_forward_fenced(topo,
+                                                              monkeypatch):
+    """``olmohybrid.train-16k``'s train step (766 M parameters: 8.56 GiB
+    of arguments) at the residual ladder's floor. Merged with its first
+    forward a layer's second keeps every layer's temporaries (16.15 GB of
+    15.75, refused, whatever the rung keeps), so the ladder ends at its
+    last resort, the floor under ``prevent_cse``: 12.66 GiB held, 12.78 by
+    ``remat.step_bytes`` (pinned loosely: it leaves the margin). Thirteen
+    kernel calls: a chunk forward, its remat's and a chunk backward a gdn
+    layer, the flash forward twice and its two backward kernels."""
+    import optax
+    from flax.training.train_state import TrainState
+
+    from benchmark import modelcfg_olmohybrid as mc
+    from tony_tpu import remat, train
+    from tony_tpu.models import get_model
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sh = SingleDeviceSharding(topo.devices[0])
+    cfg = mc.load("olmo-hybrid-7b")
+    model = get_model(cfg["program"]["model"], **mc.program_kwargs(cfg))
+    step = train.make_train_step(
+        loss_of=lambda loss, b: loss,
+        apply_kwargs_of=lambda b: {"targets": b["x"]})
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
+    x = jnp.zeros((1, T16K), jnp.int32)
+    state = jax.tree.map(on_chip, jax.eval_shape(
+        lambda rng: TrainState.create(
+            apply_fn=model.apply, tx=optax.adamw(3e-4),
+            params=model.init(rng, x)["params"]), jax.random.PRNGKey(0)))
+    batch = {"x": on_chip(x)}
+    with pytest.raises(jax.errors.JaxRuntimeError,
+                       match="RESOURCE_EXHAUSTED"):
+        step.build(remat.Saved()).lower(state, batch).compile()
+    compiled = step.build(remat.Saved(prevent_cse=True)).lower(
+        state, batch).compile()
+    gib = 1 << 30
+    assert 12.4 < compiled.memory_analysis().peak_memory_in_bytes / gib < 12.9
+    assert remat.step_bytes(compiled) + remat.MARGIN < 15.748 * gib
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 13
+    assert "gdn_chunk_fwd" in text and "gdn_chunk_bwd" in text

@@ -3,7 +3,9 @@ full differential attention, gated memory units, cross-attention to one
 shared K/V (the SambaY decoder-hybrid-decoder of arXiv:2507.06607, as
 Phi-4-mini-flash-reasoning runs it); gated delta-rule linear attention and
 latent attention without positions over dense or expert feed-forwards
-(Kimi Linear, arXiv:2510.26692).
+(Kimi Linear, arXiv:2510.26692); Gated DeltaNet (arXiv:2412.06464: the
+delta rule under one decay a head) beside plain causal attention without
+rotation, the norms after the sublayers (Olmo-Hybrid-7B).
 
 :class:`~tony_tpu.models.transformer.Transformer` folds ONE block kind with
 ``nn.scan``; here the kinds differ and two streams cross layers, so each
@@ -26,6 +28,12 @@ kind (scope)      mixer                      consumes             emits
                   128 x 128 state a head
 ``mla``           latent attention expanded  —                    —
 (attn_mla)        for training, no rotation
+``gdn`` (gdn)     gated delta rule, one      —                    —
+                  decay a head, a key x
+                  value state a head
+``attn`` (attn)   causal softmax attention,  —                    —
+                  no rotation, optional
+                  q/k-norm
 ================  =========================  ===================  =========
 
 A later emitter replaces an earlier one's stream (the last ``mamba``
@@ -36,7 +44,9 @@ encoding. The embedding table is tied: one parameter, looked up at the
 bottom and multiplied at the top (``vocab`` may be a slice of the
 published table — ids, logits and loss are then over the slice). The
 configuration may change three of these: ``norm="rmsnorm"`` (scale only),
-``tie_embeddings=False`` (an untied head ``lm_head_kernel``), and ``ffns``,
+``tie_embeddings=False`` (an untied head ``lm_head_kernel``),
+``norm_placement="post"`` (the Olmo family's reordered norm: ``x +=
+LN(mixer_i(x)); x += LN(MLP(x))``), and ``ffns``,
 a feed-forward a layer — ``("dense", width)``, a SwiGLU with separate
 ``w_gate`` / ``w_up`` / ``w_down`` under the scope ``mlp``, or
 ``"experts"``, :class:`~tony_tpu.models.moe.DroplessMoE` with a sigmoid
@@ -49,6 +59,26 @@ router and shared experts.
     g = -exp(A_log_h) * softplus(W_f2 (W_f1 u) + dt_bias)     [heads x head]
     beta = sigmoid(u W_b)                                     [heads]
     y = W_o (RMSNorm_head(kda(q, k, v, g, beta)) * sigmoid(W_g2 (W_g1 u) + b_g))
+
+``gdn`` (:class:`GDN`; the same op under a scalar decay, ``dk`` key and
+``dv`` value channels a head)::
+
+    q, k, v = silu(conv4(u W_{q,k,v}))      as ``kda``; q, k normalised alike
+    g = -exp(A_log_h) * softplus(u w_a + dt_bias)             [heads]
+    beta = 2 sigmoid(u w_b)        (1 sigmoid without ``gdn_neg_eigval``)
+    y = W_o (RMSNorm_dv(kda(q, k, v, g, beta)) * silu(u W_z))
+
+``attn`` (:class:`Attn`): ``q, k, v = u W_{q,k,v}`` over heads of
+``head_dim``; with ``attn_qk_norm`` an RMSNorm over q's and k's whole
+width; no rotation; causal softmax of ``q . k / sqrt(head_dim)``; ``W_o``.
+
+**A share of the heads** (``heads_held``): ``gdn`` and ``attn`` build only
+the held heads' columns of ``W_q .. W_z`` and rows of ``W_o`` — what one of
+the chips that divide a layer's mixers by heads holds; the feed-forward and
+the norms are whole on each. A mixer's shares add up to the uncut mixer
+(the all-reduce that adds them on real chips is not run on one, and nothing
+stands in for it); the q/k-norm's mean square is over the held width, what
+a chip has without exchanging one scalar a token.
 
 ``mla`` (:class:`MLA`): ``q_h = u W_q`` (nope + rope wide), ``[c, k_s] = u
 W_kva``, ``[k_h, v_h] = RMSNorm(c) W_kvb``; head h's key is ``[k_h, k_s]``
@@ -93,11 +123,13 @@ from tony_tpu.models.transformer import RMSNorm
 from tony_tpu.ops import attention as attn_ops
 from tony_tpu.ops import ssm
 
-KINDS = ("mamba", "swa", "full", "gmu", "cross", "kda", "mla")
+KINDS = ("mamba", "swa", "full", "gmu", "cross", "kda", "mla", "gdn", "attn")
 DIFFERENTIAL = ("swa", "full", "cross")
 # Kimi Linear's layers 1-5: the leading dense layer, then one period.
 KIMI_LINEAR_CUT = ("kda", "kda", "kda", "mla", "kda")
 PHI4_FLASH_CUT = ("mamba", "swa", "mamba", "full", "gmu", "cross")
+# Olmo-Hybrid's period: three linear-attention layers, then a full one.
+OLMO_HYBRID_CUT = ("gdn", "gdn", "gdn", "attn")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,8 +170,18 @@ class HybridConfig:
     kda_heads: int = 32
     kda_head_dim: int = 128
     kda_conv: int = 4
-    kda_chunk: int = 64
+    kda_chunk: int = 64             # the delta rule's, ``gdn`` too
     kda_keep: int = 4
+    gdn_heads: int = 30
+    gdn_key_dim: int = 96
+    gdn_value_dim: int = 192
+    gdn_conv: int = 4
+    gdn_neg_eigval: bool = True     # beta in (0, 2)
+    attn_qk_norm: bool = False      # ``attn``: RMSNorm over q's, k's width
+    # >0: ``gdn`` and ``attn`` build this many of their ``gdn_heads`` /
+    # ``n_heads`` heads (module docstring, a share of the heads).
+    heads_held: int = 0
+    norm_placement: str = "pre"     # | "post": x += LN(sublayer(x))
     mla_heads: int = 32
     mla_kv_rank: int = 512
     mla_nope_dim: int = 128
@@ -174,6 +216,16 @@ class HybridConfig:
                              "of K/V pairs")
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"norm {self.norm!r}: 'layernorm' or 'rmsnorm'")
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(f"norm_placement {self.norm_placement!r}: "
+                             f"'pre' or 'post'")
+        if self.heads_held and (
+                set(self.layers) - {"gdn", "attn"} or not 0 < self.heads_held
+                <= min(self.gdn_heads, self.n_heads)):
+            raise ValueError(
+                f"heads_held={self.heads_held}: only gdn and attn mixers "
+                f"build a share of their {self.gdn_heads} / {self.n_heads} "
+                f"heads")
         if self.ffns and len(self.ffns) != len(self.layers):
             raise ValueError(f"ffns names {len(self.ffns)} feed-forwards "
                              f"for {len(self.layers)} layers")
@@ -202,25 +254,37 @@ class HybridConfig:
     def dt_rank(self) -> int:
         return self.ssm_dt_rank or math.ceil(self.dim / 16)
 
+    def held(self, heads: int) -> int:
+        """Of a ``gdn`` / ``attn`` mixer's ``heads``, those built here."""
+        return self.heads_held or heads
+
     def flops_per_token(self, seq: int) -> float:
         """~6 FLOPs a multiplied parameter (forward + backward) plus each
         layer's sequence mixing, a trained token of a ``seq``-long row —
-        for a stack of ``kda`` / ``mla`` layers
+        for a stack of ``kda`` / ``mla`` / ``gdn`` / ``attn`` layers, the
+        last two at the heads held
         (Transformer.flops_per_token's accounting: the embedding is a
         gather, of a held range of experts the share an even routing sends
         here, the shared experts whole; MLA over the causal half with q.k
         over nope + rope and p.v over v, forward 2 and backward 4 products
-        (the scores a flash backward takes again are a recomputation); KDA
-        as the recurrence's own 7 multiply-adds a state element a step,
-        twice that backward)."""
-        if set(self.layers) - {"kda", "mla"}:
+        (the scores a flash backward takes again are a recomputation),
+        plain attention alike over ``head_dim``; the delta rule as the
+        recurrence's own 7 multiply-adds a state element a step, twice that
+        backward)."""
+        if set(self.layers) - {"kda", "mla", "gdn", "attn"}:
             raise NotImplementedError(
-                "flops_per_token counts kda and mla mixers; the other "
-                "kinds' work is benchmark/roofline_ssm.py's")
+                "flops_per_token counts kda, mla, gdn and attn mixers; the "
+                "other kinds' work is benchmark/roofline_ssm.py's")
         d, hd = self.dim, self.kda_head_dim
         e = self.kda_heads * hd
         qk = self.mla_nope_dim + self.mla_rope_dim
+        gh, ah = self.held(self.gdn_heads), self.held(self.n_heads)
+        gk, gv = gh * self.gdn_key_dim, gh * self.gdn_value_dim
         per = {
+            "gdn": (2 * d * gk + 2 * d * gv + 2 * d * gh + gv * d,
+                    3 * 7 * gh * self.gdn_key_dim * self.gdn_value_dim),
+            "attn": (4 * d * ah * self.head_dim,
+                     3 * ah * (seq + 1) * 2 * self.head_dim),
             "kda": (3 * d * e + 2 * (d * hd + hd * e) + d * self.kda_heads
                     + e * d, 3 * 7 * self.kda_heads * hd * hd),
             "mla": (d * self.mla_heads * qk
@@ -429,6 +493,40 @@ def _a_log_init(key, shape, dtype):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
+def _delta_qkv(mod, cfg, u, scope, heads, dk, dv, taps):
+    """A delta-rule mixer's operands, under the device scopes ``<scope>_
+    proj`` and ``<scope>_conv``: ``q, k, v = silu(conv(u W))`` over
+    ``heads`` of ``dk`` / ``dk`` / ``dv`` channels (the projections named
+    for the residual ladder), ``q`` and ``k`` normalised a head, ``q``
+    times ``dk^-1/2``; ``[B, T, heads, d]`` in the compute dtype."""
+    b, t, _ = u.shape
+    widths = (("q", dk), ("k", dk), ("v", dv))
+    with jax.named_scope(scope + "_proj"):
+        q, k, v = (remat.name(_dense(cfg, heads * d, "w" + n)(u), n)
+                   for n, d in widths)
+    with jax.named_scope(scope + "_conv"):
+        # float32 from the projections' outputs to the kernel's
+        # operands: four shifted products and a norm are one fused
+        # elementwise pass either way.
+        q, k, v = (nn.silu(ssm.causal_conv1d(
+            x.astype(jnp.float32), mod.param(
+                f"conv_{n}", _conv_init, (taps, heads * d), jnp.float32))
+            ).reshape(b, t, heads, d) for x, (n, d) in zip((q, k, v), widths))
+        unit = lambda x: x * jax.lax.rsqrt(
+            jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+        return (unit(q) * dk ** -0.5).astype(cfg.dtype), \
+            unit(k).astype(cfg.dtype), v.astype(cfg.dtype)
+
+
+def _delta_out(cfg, o, gate):
+    """``W_o (RMSNorm_head(o) * gate)``: ``o [B, T, heads, dv]``, ``gate``
+    ``[B, T, heads dv]`` float32; ``wo`` named for the residual ladder."""
+    b, t, h, dv = o.shape
+    o = RMSNorm(cfg.norm_eps, name="o_norm")(o).astype(jnp.float32)
+    y = (o.reshape(b, t, h * dv) * gate).astype(cfg.dtype)
+    return remat.name(_dense(cfg, cfg.dim, "wo")(y), "wo")
+
+
 class KDA(nn.Module):
     """Gated delta-rule linear attention (module docstring): device scopes
     ``kda`` > ``kda_proj``, ``kda_conv``, ``kda_gate``, ``kda_out`` and the
@@ -443,22 +541,7 @@ class KDA(nn.Module):
         b, t, _ = u.shape
         h, d = cfg.kda_heads, cfg.kda_head_dim
         e = h * d
-        with jax.named_scope("kda_proj"):
-            q, k, v = (remat.name(_dense(cfg, e, "w" + n)(u), n)
-                       for n in "qkv")
-        with jax.named_scope("kda_conv"):
-            taps = lambda n: self.param(f"conv_{n}", _conv_init,
-                                        (cfg.kda_conv, e), jnp.float32)
-            # float32 from the projections' outputs to the kernel's
-            # operands: four shifted products and a norm are one fused
-            # elementwise pass either way.
-            q, k, v = (nn.silu(ssm.causal_conv1d(
-                x.astype(jnp.float32), taps(n))).reshape(b, t, h, d)
-                for x, n in ((q, "q"), (k, "k"), (v, "v")))
-            unit = lambda x: x * jax.lax.rsqrt(
-                jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
-            q, k, v = (unit(q) * d ** -0.5).astype(cfg.dtype), \
-                unit(k).astype(cfg.dtype), v.astype(cfg.dtype)
+        q, k, v = _delta_qkv(self, cfg, u, "kda", h, d, d, cfg.kda_conv)
         with jax.named_scope("kda_gate"):
             a_log = self.param("a_log", _a_log_init, (h,), jnp.float32)
             dt_bias = self.param("dt_bias", _dt_bias_init, (e,), jnp.float32)
@@ -482,9 +565,74 @@ class KDA(nn.Module):
         with jax.named_scope("kda_out"):
             gate = jax.nn.sigmoid(_dense(cfg, e, "wg2", bias=True)(
                 _dense(cfg, d, "wg1")(u)).astype(jnp.float32))
-            o = RMSNorm(cfg.norm_eps, name="o_norm")(o).astype(jnp.float32)
-            y = (o.reshape(b, t, e) * gate).astype(cfg.dtype)
-            return remat.name(_dense(cfg, cfg.dim, "wo")(y), "wo"), ()
+            return _delta_out(cfg, o, gate), ()
+
+
+class GDN(nn.Module):
+    """Gated DeltaNet (module docstring): :class:`KDA`'s operands and
+    output form at ``gdn_key_dim`` / ``gdn_value_dim`` a head, ONE decay a
+    head, beta up to 2, a full-rank SiLU output gate; the held heads only.
+    Device scopes ``gdn`` > ``gdn_proj``, ``gdn_conv``, ``gdn_gate``,
+    ``gdn_out`` and the chunk kernels ``gdn_chunk_fwd`` /
+    ``gdn_chunk_bwd``."""
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, u):
+        from tony_tpu.ops import kda as kda_ops
+
+        cfg = self.cfg
+        t = u.shape[1]
+        h, dk, dv = cfg.held(cfg.gdn_heads), cfg.gdn_key_dim, \
+            cfg.gdn_value_dim
+        q, k, v = _delta_qkv(self, cfg, u, "gdn", h, dk, dv, cfg.gdn_conv)
+        with jax.named_scope("gdn_gate"):
+            a_log = self.param("a_log", _a_log_init, (h,), jnp.float32)
+            dt_bias = self.param("dt_bias", _dt_bias_init, (h,), jnp.float32)
+            g = -jnp.exp(a_log) * jax.nn.softplus(
+                _dense(cfg, h, "wa")(u).astype(jnp.float32) + dt_bias)
+            beta = (2.0 if cfg.gdn_neg_eigval else 1.0) * jax.nn.sigmoid(
+                _dense(cfg, h, "wb")(u).astype(jnp.float32))
+        for name, fact in (
+                ("heads", h), ("key_dim", dk), ("value_dim", dv),
+                ("chunk", cfg.kda_chunk),
+                ("chunks", kda_ops.n_chunks(t, cfg.kda_chunk)),
+                ("states_kept", kda_ops.states_kept(t, cfg.kda_chunk,
+                                                    cfg.kda_keep)),
+                ("decay", 1)):                  # decays a head: scalar
+            profiler.count_once("gdn:" + name, fact)
+        o = kda_ops.kda(q, k, v, g, beta, chunk=cfg.kda_chunk,
+                        keep=cfg.kda_keep, interpret=cfg.interpret)
+        with jax.named_scope("gdn_out"):
+            gate = nn.silu(_dense(cfg, h * dv, "wz")(u).astype(jnp.float32))
+            return _delta_out(cfg, o, gate), ()
+
+
+class Attn(nn.Module):
+    """Plain causal softmax attention over the held heads of ``head_dim``
+    (module docstring): no rotation, an optional RMSNorm over q's and k's
+    whole held width; the packed flash kernels the dense decoder runs.
+    Device scope ``attn`` and the three ``attn_*`` flash calls."""
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, u):
+        cfg = self.cfg
+        t = u.shape[1]
+        h, hd = cfg.held(cfg.n_heads), cfg.head_dim
+        q, k, v = (remat.name(_dense(cfg, h * hd, "w" + n)(u), n)
+                   for n in "qkv")
+        if cfg.attn_qk_norm:
+            q = RMSNorm(cfg.norm_eps, name="q_norm")(q)
+            k = RMSNorm(cfg.norm_eps, name="k_norm")(k)
+        for name, n in attn_ops.block_facts(
+                t, t, causal=True, head_dim=hd,
+                itemsize=k.dtype.itemsize).items():
+            profiler.count_once(f"attn:{name}.attn", n)
+        out = attn_ops.flash_attention_packed(
+            q, k, v, h, causal=True, scale=hd ** -0.5,
+            interpret=cfg.interpret)
+        return remat.name(_dense(cfg, cfg.dim, "wo")(out), "wo"), ()
 
 
 class MLA(nn.Module):
@@ -548,12 +696,17 @@ MIXERS = {
     "kda": Mixer("kda", (), (), lambda cfg, index, name: KDA(cfg, name=name)),
     "mla": Mixer("attn_mla", (), (),
                  lambda cfg, index, name: MLA(cfg, name=name)),
+    "gdn": Mixer("gdn", (), (), lambda cfg, index, name: GDN(cfg, name=name)),
+    "attn": Mixer("attn", (), (),
+                  lambda cfg, index, name: Attn(cfg, name=name)),
 }
 
 
 class HybridLayer(nn.Module):
-    """``x += mixer(LN(x)); x += MLP(LN(x))``; returns the new ``x`` and
-    what the mixer emits. The mixer's module name is its device scope."""
+    """``x += mixer(LN(x)); x += MLP(LN(x))`` — under ``norm_placement=
+    "post"`` ``x += LN(mixer(x)); x += LN(MLP(x))``; returns the new ``x``
+    and what the mixer emits. The mixer's module name is its device
+    scope."""
     cfg: HybridConfig
     kind: str
     index: int
@@ -562,9 +715,11 @@ class HybridLayer(nn.Module):
     def __call__(self, x, *consumed):
         cfg = self.cfg
         mixer = MIXERS[self.kind]
+        norm1, norm2 = _norm(cfg, "norm1"), _norm(cfg, "norm2")
+        post = cfg.norm_placement == "post"
         out, emitted = mixer.build(cfg, self.index, mixer.scope)(
-            _norm(cfg, "norm1")(x), *consumed)
-        x = x + out
+            x if post else norm1(x), *consumed)
+        x = x + (norm1(out) if post else out)
         ffn = cfg.ffns[self.index] if cfg.ffns else None
         if ffn is None:
             mlp = GatedMLP(cfg, name="mlp")
@@ -580,8 +735,8 @@ class HybridLayer(nn.Module):
                               shared=cfg.moe_shared, name="moe_mlp")
         else:
             mlp = SwiGLU(cfg, ffn[1], name="mlp")
-        x = x + mlp(_norm(cfg, "norm2")(x))
-        return x, emitted
+        out = mlp(x if post else norm2(x))
+        return x + (norm2(out) if post else out), emitted
 
 
 class HybridDecoder(nn.Module):
@@ -606,6 +761,9 @@ class HybridDecoder(nn.Module):
                                 cfg.layers.count(kind))
         profiler.count_once("model:layers.experts",
                             cfg.ffns.count("experts"))
+        if cfg.heads_held:
+            profiler.count_once("model:heads_held", cfg.heads_held)
+            profiler.count_once("model:heads_total", cfg.n_heads)
         x = _norm(cfg, "final_norm")(x)
         # Tied head: the same table, transposed.
         head = embed.T if cfg.tie_embeddings else self.param(
@@ -676,3 +834,33 @@ def kimi_linear_tiny(**kw) -> HybridDecoder:
         moe_experts=8, moe_top_k=2, moe_ffn=32, remat=False)
     defaults.update(kw)
     return kimi_linear(**defaults)
+
+
+@register("olmo-hybrid-7b")
+def olmo_hybrid(**kw) -> HybridDecoder:
+    """Olmo-Hybrid-7B's widths over one period of its layers by default
+    (Gated DeltaNet x 3, then full attention): hidden 3840, 30 heads — of
+    96 key and 192 value channels in the linear layers, of 128 in the full
+    one, q/k-normed, no rotation — a dense SwiGLU of 11008 a layer, RMSNorm
+    after each sublayer, an untied head. A deployment holds a share of the
+    heads (``heads_held``) and a slice of the vocabulary."""
+    defaults = dict(
+        vocab=100352, dim=3840, ffn_hidden=11008, n_heads=30, n_kv_heads=30,
+        layers=OLMO_HYBRID_CUT, norm="rmsnorm", norm_eps=1e-6,
+        norm_placement="post", tie_embeddings=False, attn_qk_norm=True)
+    defaults.update(kw)
+    defaults.setdefault("ffns", (("dense", defaults["ffn_hidden"]),)
+                        * len(tuple(defaults["layers"])))
+    return hybrid_decoder(**defaults)
+
+
+@register("olmo-hybrid-tiny")
+def olmo_hybrid_tiny(**kw) -> HybridDecoder:
+    """Test scale: the same code path (both mixers, the reordered norm, a
+    share of the heads) at toy widths off the lane width, ``dk != dv``."""
+    defaults = dict(
+        vocab=256, dim=64, ffn_hidden=128, n_heads=4, n_kv_heads=4,
+        layers=("gdn", "attn"), gdn_heads=4, gdn_key_dim=12,
+        gdn_value_dim=24, kda_chunk=8, kda_keep=2, remat=False)
+    defaults.update(kw)
+    return olmo_hybrid(**defaults)

@@ -1,8 +1,11 @@
-"""Gated delta-rule linear attention with a per-channel decay (KDA, the
-Kimi Linear report, arXiv:2510.26692): a matrix state a head.
+"""Gated delta-rule linear attention: a matrix state a head, under a
+per-channel decay (KDA, the Kimi Linear report, arXiv:2510.26692) or ONE
+decay a head (Gated DeltaNet, arXiv:2412.06464).
 
 The recurrence, per head, ``k_t, q_t, g_t`` in ``R^dk`` (``g_t <= 0`` the
-log-decay of each key channel), ``v_t`` in ``R^dv``, ``beta_t`` in (0, 1)::
+log-decay of each key channel; a scalar decay is the same number on every
+channel), ``v_t`` in ``R^dv`` (``dv`` need not be ``dk``), ``beta_t`` in
+(0, 2) (above 1 a step's ``I - beta k k^T`` has a negative eigenvalue)::
 
     S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t                                            S_{-1} = 0
@@ -36,7 +39,18 @@ product with a full contraction (K = 128) makes every block and the MXU's
 float32 accumulator adds the parts. Each block is a sum of at most 64
 nonpositive numbers, never a difference of prefix sums: nothing cancels.
 ``(I + A)^-1`` is Neumann products: exact for a nilpotent matrix, 16 x 16
-diagonal blocks first so that no power grows.
+diagonal blocks first so that no power grows (they grow with ``beta |k_t .
+k_i|`` before they cancel: float32 holds beta up to 2 over keys that point
+alike, the looser the more alike — ``tests/test_kda.py``).
+
+**One decay a head** (``g [B, T, H]``): ``exp(G_t - G_i)`` is a ``[C, C]``
+factor, a sum of ``g`` over ``(i, t]`` that is never positive under the
+causal mask (above the diagonal the exponent is ``-inf``: nothing
+overflows), so ``A`` and ``Aqk`` are ONE product ``[k; q] k^T`` times it —
+no levels, no table (:func:`_half_scalar`, :func:`_bwd_scalar`). Everything
+after the pair matrices — the inverse, the state's half, the head of the
+backward — is the per-channel route's code; the decay and its gradient
+travel as rows beside beta, one float a head and token.
 
 A chunk has two halves. What the incoming state does not touch — the decay
 factors, the pair matrices ``A`` / ``Aqk``, the inverse — is the
@@ -55,8 +69,11 @@ inverse and every accumulation are float32; the large matmuls take
 operands in the inputs' dtype.
 
 Dispatch follows :mod:`tony_tpu.ops.ssm`: Pallas kernels
-(``kda_chunk_fwd``, ``kda_chunk_bwd``; grid (batch, head, step) with the
-step axis sequential and the state in VMEM scratch) on a TPU, the same
+(``kda_chunk_fwd``, ``kda_chunk_bwd`` — called ``gdn_chunk_fwd`` /
+``gdn_chunk_bwd`` under a scalar decay, the same two bodies without their
+table operands; grid (batch, head, step) with the step axis sequential and
+the state in VMEM scratch; a head a lane block, head sizes off 128 behind
+zero lanes: :func:`kda`) on a TPU, the same
 chunk functions under ``interpret=True`` for CPU tests, and an XLA twin
 (``lax.scan`` over steps of the same functions) elsewhere. Only the
 kernels compiled for the chip take the split product: the twin and the
@@ -76,8 +93,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from tony_tpu.ops.attention import _warn_fallback
 
 CHUNK = 64      # tokens a chunk
 KEEP = 4        # chunks between two kept states
@@ -270,13 +285,53 @@ def _operands(q, k, ex, masks):
                masks[l * c:(l + 1) * c])
 
 
+def _grid(c):
+    """Row and column indices of a ``[c, c]`` matrix."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (c, c), a) for a in (0, 1))
+
+
+def _row(column):
+    """``[C, 1]`` -> ``[1, C]`` through the diagonal."""
+    return jnp.sum(jnp.where(_eye(column.shape[0]), column, 0.0), axis=0,
+                   keepdims=True)
+
+
+def _half_scalar(q, k, g, beta, cd):
+    """:func:`chunk_half` under ONE decay a head, ``g [C, 1]``: ``exp(G_t -
+    G_i)`` is a ``[C, C]`` factor, so both pair matrices are one product
+    (``[k; q] k^T``) times it under the causal mask — no levels, no table.
+    ``G_t - G_i`` is a sum of ``g`` over ``(i, t]``, never positive where
+    the mask keeps it; above the diagonal the exponent is ``-inf``. Returns
+    ``(ex, akk, aqk, t, decay)``: ``ex [2 C, dk]`` stacks ``exp(G)`` and
+    ``exp(G_last - G)`` over the key lanes, as the per-channel route's last
+    two blocks, so :func:`chunk_state` and the head of :func:`chunk_bwd`
+    read both routes alike (a ``[C, 1]`` value fills as many vector
+    registers, and Mosaic broadcasts along one axis at a time: the state's
+    ``[1, 1]`` decay would not lower); ``decay`` is the factor, kept for
+    the backward."""
+    c = q.shape[0]
+    row, col = _grid(c)
+    cum = jnp.sum(jnp.where(row >= col, _row(g), 0.0), axis=1, keepdims=True)
+    decay = jnp.exp(jnp.where(row >= col, cum - _row(cum), -jnp.inf))
+    # packsite: region-local — one head's chunk (VMEM values).
+    ex = jnp.broadcast_to(jnp.exp(jnp.concatenate(
+        [cum, cum[c - 1:c] - cum], axis=0)), (2 * c, q.shape[1]))
+    # packsite: region-local — as above: k and q share the product with k^T.
+    pairs = _mm(jnp.concatenate([k, q], axis=0), k, _NT, cd)
+    akk = jnp.where(row > col, pairs[:c] * decay, 0.0)
+    return ex, akk, pairs[c:] * decay, _inverse(beta * akk), decay
+
+
 def chunk_half(q, k, g, beta, sums, masks, cd):
     """The half of a chunk that its incoming state does not touch, made
     once a chunk by each kernel call: ``q, k, g [C, dk]``, ``beta [C, 1]``
     -> ``(ex, akk, aqk, t)`` float32 — the decay factors (``exp`` of
     :func:`_exponents`), the two pair matrices ``[C, C]`` and ``t = (I +
-    beta akk)^-1``."""
+    beta akk)^-1``. Without tables (``sums`` None) the decay is one a head,
+    ``g [C, 1]``: :func:`_half_scalar`."""
     q, k, g, beta = _f32(q, k, g, beta)
+    if sums is None:
+        return _half_scalar(q, k, g, beta, cd)
     ex = jnp.exp(_exponents(g, sums, cd))
     akk = aqk = None
     for _, _, kr, qr, kc, m in _operands(q, k, ex, masks):
@@ -295,7 +350,7 @@ def chunk_state(st, half, q, k, v, beta, cd):
     after)``, float32."""
     q, k, v, beta = _f32(q, k, v, beta)
     c = q.shape[0]
-    ex, _, aqk, t = half
+    ex, _, aqk, t = half[:4]
     eg, el = ex[-2 * c:-c], ex[-c:]
     u = _mm(t, beta * (v - _mm(k * eg, st, _NT, cd)), _NN, cd)
     o = _mm(q * eg, st, _NT, cd) + _mm(aqk, u, _NN, cd)
@@ -316,7 +371,7 @@ def chunk_bwd(st, half, q, k, v, beta, do, dst1, sums, masks, cd):
     back."""
     q, k, v, beta, do = _f32(q, k, v, beta, do)
     c = q.shape[0]
-    ex, akk, aqk, t = half
+    ex, akk, aqk, t = half[:4]
     eg, el = ex[-2 * c:-c], ex[-c:]
     kg, qg, kl = k * eg, q * eg, k * el
     resid = v - _mm(kg, st, _NT, cd)
@@ -342,6 +397,11 @@ def chunk_bwd(st, half, q, k, v, beta, do, dst1, sums, masks, cd):
     dkg = -_mm(bd, st, _NN, cd)
     dst = dst - _mm(bd, kg, _TN, cd)
     dakk = beta * da
+    if sums is None:
+        dq, dk, dg = _bwd_scalar(q, k, half[4], akk, aqk, dakk, daqk,
+                                 dqg * eg, dkg * eg + dkl * el,
+                                 dkg * kg + dqg * qg, dkl * kl, d_last, cd)
+        return dq, dk, dv, dg, dbeta, dst
     diag = jnp.sum(jnp.where(_eye(c), daqk, 0.0), axis=1, keepdims=True)
     dq = dqg * eg + diag * k
     dk = dkg * eg + dkl * el + diag * q
@@ -361,6 +421,39 @@ def chunk_bwd(st, half, q, k, v, beta, do, dst1, sums, masks, cd):
     # 1's exponent is g itself.
     dg = de[0] + _mm_table(sums, jnp.concatenate(de[1:], axis=0), _TN, cd)
     return dq, dk, dv, dg, dbeta, dst
+
+
+def _bwd_scalar(q, k, decay, akk, aqk, dakk, daqk, dq, dk, deg, del_, d_last,
+                cd):
+    """The tail of :func:`chunk_bwd` under one decay a head: the pair
+    matrices' adjoints back to ``q``, ``k`` (two products: ``[dQK; dKK] k``
+    and ``[dKK; dQK]^T [k; q]``, the second with a full contraction) and to
+    the decay ``g [C, 1]``. ``dq``, ``dk`` arrive holding the state's part;
+    ``deg`` / ``del_`` ``[C, dk]`` are the adjoints of ``exp(G)`` / ``exp(
+    G_last - G)`` times their values, channel by channel, ``d_last`` ``[1,
+    dk]`` the state's decay's."""
+    c = q.shape[0]
+    row, col = _grid(c)
+    dkk, dqk = jnp.where(row > col, dakk, 0.0) * decay, daqk * decay
+    # packsite: region-local — one head's chunk (VMEM values), as below.
+    left = _mm(jnp.concatenate([dqk, dkk], axis=0), k, _NN, cd)
+    # packsite: region-local
+    pairs = jnp.concatenate([dkk, dqk], axis=0)
+    # packsite: region-local
+    dk = dk + left[c:] + _mm(pairs, jnp.concatenate([k, q], axis=0), _TN, cd)
+    # G_t - G_i: + to G_t along a row, - to G_i along a column
+    dd = dakk * akk + daqk * aqk
+    dcum = jnp.sum(dd, axis=1, keepdims=True) \
+        - _column(jnp.sum(dd, axis=0, keepdims=True))
+    deg, del_ = (jnp.sum(x, axis=1, keepdims=True) for x in (deg, del_))
+    last = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+    dcum = dcum + deg - del_ + jnp.where(
+        last, jnp.sum(d_last, axis=1, keepdims=True)
+        + jnp.sum(del_, axis=0, keepdims=True), 0.0)
+    # G = cumsum g: g_j reaches every G_t with t >= j
+    dg = jnp.sum(jnp.where(col >= row, _row(dcum), 0.0), axis=1,
+                 keepdims=True)
+    return dq + left[:c], dk, dg
 
 
 # --------------------------------------------------------------------
@@ -393,9 +486,21 @@ def _consts(chunk, cd=None):
             jnp.asarray(masks))
 
 
+def _tables(g, chunk, cd=None):
+    """The tables a call over the decay ``g`` hands down: :func:`_consts`
+    for a per-channel decay, none for a scalar one."""
+    return () if g.ndim == 3 else _consts(chunk, cd)
+
+
+def _columns(g):
+    """A scalar decay ``[B, T, H]`` as ``[B, T, H, 1]`` (as beta travels
+    through the twin); a per-channel one as it is."""
+    return g[..., None] if g.ndim == 3 else g
+
+
 def _fwd_xla(q, k, v, g, beta, chunk, keep):
     b, t, h, dk = q.shape
-    sums, masks = _consts(chunk)
+    sums, masks = _tables(g, chunk) or (None, None)
     one = jax.vmap(jax.vmap(functools.partial(
         chunk_fwd, sums=sums, masks=masks, cd=q.dtype)))
 
@@ -406,13 +511,14 @@ def _fwd_xla(q, k, v, g, beta, chunk, keep):
         st1, o = jax.lax.scan(chunk_of, st, xs)
         return st1, (o, st)
     st0 = jnp.zeros((b, h, v.shape[3], dk), jnp.float32)
-    xs = tuple(_steps(x, chunk, keep) for x in (q, k, v, g, beta[..., None]))
+    xs = tuple(_steps(x, chunk, keep)
+               for x in (q, k, v, _columns(g), beta[..., None]))
     _, (o, kept) = jax.lax.scan(step, st0, xs)
     return _unsteps(o).astype(q.dtype), jnp.moveaxis(kept, 0, 2)
 
 
 def _bwd_xla(q, k, v, g, beta, kept, do, chunk, keep):
-    sums, masks = _consts(chunk)
+    sums, masks = _tables(g, chunk) or (None, None)
     kw = dict(sums=sums, masks=masks, cd=q.dtype)
     over = lambda f, **consts: jax.vmap(jax.vmap(
         functools.partial(f, **consts)))
@@ -434,29 +540,41 @@ def _bwd_xla(q, k, v, g, beta, kept, do, chunk, keep):
             return dst0, tuple(grads)
         return jax.lax.scan(chunk_of, dst, (states, halves, x, do),
                             reverse=True)
-    x = tuple(_steps(a, chunk, keep) for a in (q, k, v, g, beta[..., None]))
+    x = tuple(_steps(a, chunk, keep)
+              for a in (q, k, v, _columns(g), beta[..., None]))
     dst = jnp.zeros_like(kept[:, :, 0])
     _, grads = jax.lax.scan(
         step, dst, (jnp.moveaxis(kept, 2, 0), x, _steps(do, chunk, keep)),
         reverse=True)
     dq, dk, dv, dg, dbeta = (_unsteps(a) for a in grads)
-    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), dg,
-            dbeta[..., 0])
+    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+            dg[..., 0] if g.ndim == 3 else dg, dbeta[..., 0])
 
 
 # --------------------------------------------------------------------
 # Pallas kernels. Packed layout: q, k, g [B, T, H dk], v, o [B, T, H dv],
-# a head a lane block; beta [B, H, steps, keep, chunk] (rows).
+# a head a lane block; beta — and a scalar decay g, and its dg —
+# [B, H, steps, keep, chunk] (rows). One body a direction serves both
+# routes: the per-channel decay's call hands it the two tables
+# (``tables``), the scalar decay's none.
 # --------------------------------------------------------------------
 
-def kda_chunk_fwd(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, masks_ref,
-                  o_ref, kept_ref, st_scr, *, chunk: int, keep: int):
+def _decay(g_ref, tables, j, at):
+    """Chunk ``j``'s decay: its ``[C, dk]`` block, or a scalar decay's row
+    stood up as ``[C, 1]``."""
+    return g_ref[at, :] if tables else _column(g_ref[pl.ds(j, 1), :])
+
+
+def kda_chunk_fwd(q_ref, k_ref, v_ref, g_ref, b_ref, *rest, chunk: int,
+                  keep: int):
     """One (batch, head, step) cell: ``keep`` chunks. Writes o and the
     state the step STARTED from. The loop runs unrolled: a chunk's
     state-free half does not wait for the chunk before it, and in one block
     the scheduler starts it under that chunk's tail (on the chip 45.2 ms a
     call for 50.3 at the Kimi Linear cell's size: PERF.md §6 PR 39). The
     tables go down as refs (:func:`kda_chunk_bwd` says why)."""
+    *tables, o_ref, kept_ref, st_scr = rest
+
     @pl.when(pl.program_id(2) == 0)
     def _init():
         st_scr[...] = jnp.zeros_like(st_scr)
@@ -466,17 +584,16 @@ def kda_chunk_fwd(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, masks_ref,
     def body(j, st):
         at = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
         o, st1 = chunk_fwd(st, q_ref[at, :], k_ref[at, :], v_ref[at, :],
-                           g_ref[at, :], _column(b_ref[pl.ds(j, 1), :]),
-                           sums_ref, masks_ref, q_ref.dtype)
+                           _decay(g_ref, tables, j, at),
+                           _column(b_ref[pl.ds(j, 1), :]),
+                           *(tables or (None, None)), q_ref.dtype)
         o_ref[at, :] = o.astype(o_ref.dtype)
         return st1
 
     st_scr[...] = jax.lax.fori_loop(0, keep, body, st_scr[...], unroll=True)
 
 
-def kda_chunk_bwd(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, masks_ref,
-                  kept_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
-                  states_scr, dst_scr, ex_scr, pairs_scr, *, chunk: int,
+def kda_chunk_bwd(q_ref, k_ref, v_ref, g_ref, b_ref, *rest, chunk: int,
                   keep: int):
     """The same cell in reverse (the index maps walk the steps last to
     first). One loop forward over the step's chunks builds each chunk's
@@ -487,13 +604,16 @@ def kda_chunk_bwd(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, masks_ref,
     first reads the scratch, the state's adjoint in scratch across steps
     (a loop: each chunk waits for the adjoint of the one after it, and
     unrolled it ran no faster)."""
+    (*tables, kept_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
+     states_scr, dst_scr, ex_scr, pairs_scr) = rest
+
     @pl.when(pl.program_id(2) == 0)
     def _init():
         dst_scr[...] = jnp.zeros_like(dst_scr)
 
     # the tables go down as refs, read where a product takes them: read
     # whole up here they spill, ~100 vregs, before the loops start
-    sums, masks, cd = sums_ref, masks_ref, q_ref.dtype
+    (sums, masks), cd = tables or (None, None), q_ref.dtype
 
     def inputs(j):
         at = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
@@ -502,7 +622,8 @@ def kda_chunk_bwd(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, masks_ref,
 
     def rebuild(j, st):
         at, (q, k, v, beta) = inputs(j)
-        half = chunk_half(q, k, g_ref[at, :], beta, sums, masks, cd)
+        half = chunk_half(q, k, _decay(g_ref, tables, j, at), beta, sums,
+                          masks, cd)
         states_scr[j] = st
         ex_scr[j] = half[0]
         for n, pair in enumerate(half[1:]):
@@ -514,15 +635,18 @@ def kda_chunk_bwd(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, masks_ref,
     def body(i, dst):
         j = keep - 1 - i
         at, x = inputs(j)
-        half = (ex_scr[j], *(pairs_scr[j, n] for n in range(3)))
+        half = (ex_scr[j],
+                *(pairs_scr[j, n] for n in range(pairs_scr.shape[1])))
         dq, dk, dv, dg, db, dst0 = chunk_bwd(
             states_scr[j], half, *x, do_ref[at, :], dst, sums, masks, cd)
         dq_ref[at, :] = dq.astype(dq_ref.dtype)
         dk_ref[at, :] = dk.astype(dk_ref.dtype)
         dv_ref[at, :] = dv.astype(dv_ref.dtype)
-        dg_ref[at, :] = dg
-        db_ref[pl.ds(j, 1), :] = jnp.sum(
-            jnp.where(_eye(chunk), db, 0.0), axis=0, keepdims=True)
+        if tables:
+            dg_ref[at, :] = dg
+        else:
+            dg_ref[pl.ds(j, 1), :] = _row(dg)
+        db_ref[pl.ds(j, 1), :] = _row(db)
         return dst0
 
     dst_scr[...] = jax.lax.fori_loop(0, keep, body, dst_scr[...])
@@ -558,26 +682,43 @@ def _specs(rows, keep, dk, dv, order):
     return seq, beta, kept, whole
 
 
+def _packed_decay(g, chunk, keep):
+    """The decay as the kernels read it: a per-channel one beside q and k
+    (a head a lane block), a scalar one as beta's rows."""
+    if g.ndim == 3:
+        return _beta_rows(g, chunk, keep)
+    b, t, h, dk = g.shape
+    return g.reshape(b, t, h * dk)
+
+
+def _name(g, kernel):
+    """The kernel call's name (the custom call's in a trace, the device
+    scope around it): ``kda_chunk_*`` under a per-channel decay,
+    ``gdn_chunk_*`` under a scalar one."""
+    return f"{'gdn' if g.ndim == 3 else 'kda'}_chunk_{kernel}"
+
+
 def _fwd_pallas(q, k, v, g, beta, chunk, keep, interpret):
     b, t, h, dk = q.shape
     dv = v.shape[3]
     rows = chunk * keep
     steps = t // rows
-    sums, masks = _consts(chunk, None if interpret else q.dtype)
     seq, beta_spec, kept, whole = _specs(rows, keep, dk, dv, lambda si: si)
+    tables = _tables(g, chunk, None if interpret else q.dtype)
+    g_spec = seq(dk) if tables else beta_spec
     o, states = pl.pallas_call(
         functools.partial(kda_chunk_fwd, chunk=chunk, keep=keep),
         grid=(b, h, steps),
-        in_specs=[seq(dk), seq(dk), seq(dv), seq(dk), beta_spec,
-                  whole(sums), whole(masks)],
+        in_specs=[seq(dk), seq(dk), seq(dv), g_spec, beta_spec,
+                  *map(whole, tables)],
         out_specs=(seq(dv), kept),
         out_shape=(jax.ShapeDtypeStruct((b, t, h * dv), q.dtype),
                    jax.ShapeDtypeStruct((b, h, steps, dv, dk), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
-        compiler_params=_SEQ, interpret=interpret, name="kda_chunk_fwd",
+        compiler_params=_SEQ, interpret=interpret, name=_name(g, "fwd"),
     )(q.reshape(b, t, h * dk), k.reshape(b, t, h * dk),
-      v.reshape(b, t, h * dv), g.reshape(b, t, h * dk),
-      _beta_rows(beta, chunk, keep), sums, masks)
+      v.reshape(b, t, h * dv), _packed_decay(g, chunk, keep),
+      _beta_rows(beta, chunk, keep), *tables)
     return o.reshape(b, t, h, dv), states
 
 
@@ -586,35 +727,42 @@ def _bwd_pallas(q, k, v, g, beta, states, do, chunk, keep, interpret):
     dv = v.shape[3]
     rows = chunk * keep
     steps = t // rows
-    sums, masks = _consts(chunk, None if interpret else q.dtype)
     seq, beta_spec, kept, whole = _specs(rows, keep, dk, dv,
                                          lambda si: steps - 1 - si)
     packed = lambda d, dtype: jax.ShapeDtypeStruct((b, t, h * d), dtype)
+    row_shape = jax.ShapeDtypeStruct((b, h, steps, keep, chunk), jnp.float32)
+    tables = _tables(g, chunk, None if interpret else q.dtype)
+    if tables:
+        g_spec, dg_shape = seq(dk), packed(dk, jnp.float32)
+        half_rows, pairs = chunk + table_rows(chunk), 3
+    else:       # the factors over the key lanes; akk, aqk, t and the decay
+        g_spec, dg_shape, half_rows, pairs = beta_spec, row_shape, \
+            2 * chunk, 4
     dq, dk_, dv_, dg, db = pl.pallas_call(
         functools.partial(kda_chunk_bwd, chunk=chunk, keep=keep),
         grid=(b, h, steps),
-        in_specs=[seq(dk), seq(dk), seq(dv), seq(dk), beta_spec,
-                  whole(sums), whole(masks), kept, seq(dv)],
-        out_specs=(seq(dk), seq(dk), seq(dv), seq(dk), beta_spec),
+        in_specs=[seq(dk), seq(dk), seq(dv), g_spec, beta_spec,
+                  *map(whole, tables), kept, seq(dv)],
+        out_specs=(seq(dk), seq(dk), seq(dv), g_spec, beta_spec),
         out_shape=(packed(dk, q.dtype), packed(dk, k.dtype),
-                   packed(dv, v.dtype), packed(dk, jnp.float32),
-                   jax.ShapeDtypeStruct((b, h, steps, keep, chunk),
-                                        jnp.float32)),
+                   packed(dv, v.dtype), dg_shape, row_shape),
         # the step's incoming states, the state's adjoint, and each
         # chunk's state-free half: (keep, 13 C, dk) + (keep, 3, C, C)
-        # float32 = 1.9 MB at the cell's 4 x 64 x 128
+        # float32 = 1.9 MB at the cell's 4 x 64 x 128 (a scalar decay's:
+        # (keep, 2 C, dk) and four pair matrices)
         scratch_shapes=[
             pltpu.VMEM((keep, dv, dk), jnp.float32),
             pltpu.VMEM((dv, dk), jnp.float32),
-            pltpu.VMEM((keep, chunk + table_rows(chunk), dk), jnp.float32),
-            pltpu.VMEM((keep, 3, chunk, chunk), jnp.float32)],
-        compiler_params=_SEQ, interpret=interpret, name="kda_chunk_bwd",
+            pltpu.VMEM((keep, half_rows, dk), jnp.float32),
+            pltpu.VMEM((keep, pairs, chunk, chunk), jnp.float32)],
+        compiler_params=_SEQ, interpret=interpret, name=_name(g, "bwd"),
     )(q.reshape(b, t, h * dk), k.reshape(b, t, h * dk),
-      v.reshape(b, t, h * dv), g.reshape(b, t, h * dk),
-      _beta_rows(beta, chunk, keep), sums, masks, states,
+      v.reshape(b, t, h * dv), _packed_decay(g, chunk, keep),
+      _beta_rows(beta, chunk, keep), *tables, states,
       do.reshape(b, t, h * dv))
+    unrow = lambda x: x.reshape(b, h, t).transpose(0, 2, 1)
     return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
-            dg.reshape(g.shape), db.reshape(b, h, t).transpose(0, 2, 1))
+            dg.reshape(g.shape) if tables else unrow(dg), unrow(db))
 
 
 # --------------------------------------------------------------------
@@ -627,7 +775,7 @@ def _kda(q, k, v, g, beta, chunk, keep, interpret):
 
 
 def _kda_fwd(q, k, v, g, beta, chunk, keep, interpret):
-    with jax.named_scope("kda_chunk_fwd"):
+    with jax.named_scope(_name(g, "fwd")):
         if interpret is None:
             o, states = _fwd_xla(q, k, v, g, beta, chunk, keep)
         else:
@@ -637,7 +785,7 @@ def _kda_fwd(q, k, v, g, beta, chunk, keep, interpret):
 
 def _kda_bwd(chunk, keep, interpret, res, do):
     q, k, v, g, beta, states = res
-    with jax.named_scope("kda_chunk_bwd"):
+    with jax.named_scope(_name(g, "bwd")):
         if interpret is None:
             return _bwd_xla(q, k, v, g, beta, states, do, chunk, keep)
         return _bwd_pallas(q, k, v, g, beta, states, do, chunk, keep,
@@ -665,40 +813,56 @@ def kda_reference(q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 1)
 
 
+def _lanes(x):
+    """The last axis zero-padded to a multiple of 128: the kernels read a
+    head as a lane block."""
+    pad = (-x.shape[-1]) % 128
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),)) if pad else x
+
+
 def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         beta: jax.Array, *, chunk: int = CHUNK, keep: int = KEEP,
         interpret: Optional[bool] = None) -> jax.Array:
-    """The recurrence of the module docstring over ``q, k, g``
-    ``[B, T, H, dk]`` (``g`` the log-decay, ``<= 0``), ``v``
-    ``[B, T, H, dv]``, ``beta`` ``[B, T, H]``; returns ``o``
-    ``[B, T, H, dv]`` in ``q``'s dtype. ``g`` and ``beta`` are taken in
-    float32 whatever their dtype; ``q``'s dtype is the large matmuls'.
+    """The recurrence of the module docstring over ``q, k`` ``[B, T, H,
+    dk]``, ``v`` ``[B, T, H, dv]``, ``beta`` ``[B, T, H]`` and the
+    log-decay ``g <= 0``: ``[B, T, H, dk]``, a decay a key channel (KDA),
+    or ``[B, T, H]``, ONE a head (Gated DeltaNet: its ``dg`` leaves at that
+    shape too). Returns ``o`` ``[B, T, H, dv]`` in ``q``'s dtype. ``g`` and
+    ``beta`` are taken in float32 whatever their dtype; ``q``'s dtype is the
+    large matmuls'.
 
     ``chunk`` tokens are solved together and every ``keep``-th chunk's
     incoming state is kept for the backward. A ``T`` off ``chunk * keep``
     is zero-padded at the end (``k = 0``, ``beta = 0``, ``g = 0`` leave the
     state as it is). ``interpret=None`` picks the Pallas kernels on a TPU
     and the XLA twin elsewhere; ``True`` runs the kernel bodies in the
-    Pallas interpreter."""
-    if not (q.shape == k.shape == g.shape and v.shape[:3] == q.shape[:3]
-            and beta.shape == q.shape[:3]):
+    Pallas interpreter. The kernels read a head as a lane block: head sizes
+    off 128 (96 and 192, say) go through them behind **zero lanes** — zero
+    key channels and zero value columns change nothing, their state rows
+    and output columns stay zero — at the cost of q, k, v, o and their
+    gradients ``ceil(d / 128) 128 / d`` times as wide in HBM (4/3 at 96 and
+    192); the MXU's passes and the vector registers are 128 lanes wide
+    either way."""
+    scalar = g.ndim == 3
+    if not (q.shape == k.shape and v.shape[:3] == q.shape[:3]
+            and beta.shape == q.shape[:3]
+            and g.shape == (beta.shape if scalar else q.shape)):
         raise ValueError(f"kda shapes: q {q.shape} k {k.shape} v {v.shape} "
                          f"g {g.shape} beta {beta.shape}")
-    t = q.shape[1]
+    t, dv = q.shape[1], v.shape[3]
     k, v = k.astype(q.dtype), v.astype(q.dtype)
     g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
     while keep > 1 and chunk * (keep // 2) >= t:
         keep //= 2
     pad = (-t) % (chunk * keep)
     if pad:
-        q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                      for x in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+        steps = lambda x: jnp.pad(
+            x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        q, k, v, g, beta = map(steps, (q, k, v, g, beta))
     if interpret is None and jax.default_backend() == "tpu":
         interpret = False
-    if interpret is not None and (q.shape[3] % 128 or v.shape[3] % 128):
-        _warn_fallback(f"kda reads heads as lane blocks: head sizes "
-                       f"{q.shape[3]}, {v.shape[3]} off 128")
-        interpret = None
+    if interpret is not None:
+        q, k, v = map(_lanes, (q, k, v))
+        g = g if scalar else _lanes(g)
     o = _kda(q, k, v, g, beta, chunk, keep, interpret)
-    return o[:, :t] if pad else o
+    return o[:, :t, :, :dv] if pad or o.shape[3] != dv else o
