@@ -27,7 +27,8 @@ GiB = 1 << 30
 FULL, NO_WO, MLP, QKV, _ = (tuple(n for n in rung if n != "sel")
                             for rung in remat.LADDER)
 RUNGS = (FULL, NO_WO, MLP, QKV, remat.FLOOR)
-FENCED = ("prevent_cse",)    # the fake's key of the floor with prevent_cse
+FENCED = ("prevent_cse",)    # the fake's key of the floor with prevent_cse;
+#                              a fenced rung's is ``rung + FENCED``
 ALL_NAMES = set(FULL) | {"flash_out", "flash_lse"}
 
 
@@ -112,6 +113,13 @@ LIMIT = int(15.75 * GiB)
 MISTRAL = {FULL: int(15.321 * GiB), NO_WO: int(14.695 * GiB),
            MLP: int(14.445 * GiB), QKV: int(13.821 * GiB),
            remat.FLOOR: int(13.570 * GiB)}
+# The Olmo Hybrid cell's (ISSUE 41's): four unrolled layers whose second
+# forwards XLA merges with the first, every rung refused alike; fenced,
+# the rungs read these, but the top one, which each test sets.
+FENCED_WALK = tuple(r + FENCED for r in RUNGS[::-1])   # the floor upwards
+OLMO = {**{r: None for r in RUNGS},
+        FENCED: int(12.78 * GiB), QKV + FENCED: int(13.31 * GiB),
+        MLP + FENCED: int(14.11 * GiB), NO_WO + FENCED: int(14.63 * GiB)}
 
 
 @pytest.fixture
@@ -186,40 +194,135 @@ def test_floor_is_taken_whatever_the_margin_says(chooser):
     assert profiler.counters()["remat:step_bytes"] == LIMIT - 1
 
 
-def test_a_refused_floor_is_tried_again_with_its_second_forward_fenced(
-        chooser):
+def _rung_spans():
+    return [sp["attrs"] for sp in profiler.timeline()["spans"]
+            if sp["name"] == "tony:remat_rung"]
+
+
+@pytest.mark.parametrize("top, want", [
+    (None, NO_WO), (15.2, NO_WO), (14.9, FULL)],
+    ids=["top-refused", "top-over-the-margin", "top-fits"])
+def test_a_refused_floor_walks_the_rungs_again_under_the_fence(
+        chooser, top, want):
     """Unrolled layers whose second forward XLA merges with the first can
-    hold more than the chip has (the 32k Kimi Linear step): the floor is
-    then compiled once more under ``prevent_cse``; the memo remembers
-    which floor it was, and a warm start builds that one."""
-    table = {**{r: None for r in RUNGS}, FENCED: int(14.76 * GiB)}
+    hold more than the chip has, whatever the rung keeps (the 16k Olmo
+    Hybrid step): once the unfenced floor is refused the rungs are
+    candidates under ``prevent_cse`` by the unfenced walk's rule — the
+    richest that compiles and leaves the margin — walked from the fenced
+    floor upwards. The memo remembers set and fence; a warm start builds
+    that one program and tries no rung."""
+    table = {**OLMO, FULL + FENCED: top and int(top * GiB)}
     names, compiler = chooser(table)
-    assert names == FENCED
-    assert compiler.compiled == list(RUNGS) + [FENCED]
+    assert names == want + FENCED
+    assert compiler.compiled == list(RUNGS) + list(FENCED_WALK)
+    assert compiler.traced == compiler.compiled     # one trace a rung
     c = profiler.counters()
     assert (c["remat:rungs_tried"], c["remat:rungs_refused"],
-            c["remat:prevent_cse"]) == (6, 5, 1)
-    assert c["remat:step_bytes"] == table[FENCED]
-    assert [r["prevent_cse"] for r in profiler.report("remat")[
-        "train_step"]["rungs"]] == [False] * 5 + [True]
+            c["remat:rungs_fenced"], c["remat:prevent_cse"]) == (
+                10, 5 + (top is None), 5, 1)
+    assert {n for n in ALL_NAMES if f"remat:saved.{n}" in c} == set(want)
+    assert c["remat:step_bytes"] == table[want + FENCED]
+    assert c["remat:step_bytes"] + remat.MARGIN <= c["remat:bytes_limit"]
+    rungs = profiler.report("remat")["train_step"]["rungs"]
+    assert [r["prevent_cse"] for r in rungs] == [False] * 5 + [True] * 5
+    assert _rung_spans() == [{**r, "saved": ",".join(r["saved"])}
+                             for r in rungs]     # a span a rung, in order
     profiler.reset_timeline()
     names, compiler = chooser(table)
-    assert names == FENCED and compiler.compiled == []
+    assert names == want + FENCED
+    assert compiler.traced == [] and compiler.compiled == []
+    assert _rung_spans() == []
+    c = profiler.counters()
+    assert (c["remat:from_memo"], c["remat:prevent_cse"],
+            c["remat:rungs_tried"], c["remat:rungs_refused"],
+            c["remat:rungs_fenced"]) == (1, 1, 0, 0, 0)
+    assert {n for n in ALL_NAMES if f"remat:saved.{n}" in c} == set(want)
+    assert c["remat:step_bytes"] == table[want + FENCED]
+
+
+@pytest.mark.parametrize("fenced, want, walked", [
+    ({QKV + FENCED: None}, remat.FLOOR, 2),
+    ({QKV + FENCED: int(15.2 * GiB)}, remat.FLOOR, 2),
+    ({QKV + FENCED: int(14.9 * GiB), MLP + FENCED: None}, QKV, 3),
+    ({QKV + FENCED: None, MLP + FENCED: int(14.0 * GiB)}, remat.FLOOR, 2)],
+    ids=["refused", "over-the-margin", "the-next-refused",
+         "a-richer-one-would-fit"])
+def test_a_refused_floor_is_tried_again_with_its_second_forward_fenced(
+        chooser, fenced, want, walked):
+    """The fenced floor is compiled first and the walk above it stops at
+    the first rung that does not fit: ``RESOURCE_EXHAUSTED`` on a fenced
+    rung is a step down, not the step's error, so a step with no room
+    above its fenced floor (the 32k Kimi Linear step, 14.76 GiB) pays one
+    more compile, not one a rung. The rung before is the step — the
+    fenced floor where the poorest rung already fails, whatever a richer
+    one would read; the memo remembers which, and a warm start builds
+    that one."""
+    table = {**{r: None for r in RUNGS}, FENCED: int(14.76 * GiB), **fenced}
+    names, compiler = chooser(table)
+    assert names == want + FENCED
+    assert compiler.compiled == list(RUNGS) + list(FENCED_WALK[:walked])
+    c = profiler.counters()
+    assert (c["remat:rungs_tried"], c["remat:rungs_fenced"],
+            c["remat:prevent_cse"]) == (5 + walked, walked, 1)
+    assert c["remat:rungs_refused"] == sum(
+        table[r] is None for r in compiler.compiled)
+    assert c["remat:step_bytes"] == table[want + FENCED]
+    assert not [n for n in ALL_NAMES if f"remat:saved.{n}" in c
+                and n not in want]
+    profiler.reset_timeline()
+    names, compiler = chooser(table)
+    assert names == want + FENCED and compiler.compiled == []
     c = profiler.counters()
     assert (c["remat:from_memo"], c["remat:prevent_cse"]) == (1, 1)
 
 
-def test_a_floor_that_compiles_is_never_fenced(chooser):
-    names, compiler = chooser({**{r: LIMIT - 1 for r in RUNGS},
-                               FENCED: GiB})
-    assert names == remat.FLOOR and FENCED not in compiler.compiled
-    assert "remat:prevent_cse" not in profiler.counters()
+def test_a_model_with_nothing_to_keep_has_no_fenced_rung_to_try(chooser):
+    """The fenced floor is then the whole second walk (the layer-kind
+    decoder's mixers before ISSUE 38 named their values)."""
+    table = {remat.FLOOR: None, FENCED: int(14.76 * GiB)}
+    names, compiler = chooser(table, model_names={"flash_out"})
+    assert names == FENCED
+    assert compiler.compiled == [remat.FLOOR, FENCED]
+    c = profiler.counters()
+    assert (c["remat:rungs_tried"], c["remat:rungs_refused"],
+            c["remat:rungs_fenced"]) == (2, 1, 1)
+
+
+@pytest.mark.parametrize("table", [
+    {**{r: LIMIT - 1 for r in RUNGS}, **{r: GiB for r in FENCED_WALK}},
+    {**MISTRAL, **{r: GiB for r in FENCED_WALK}},
+    {**{r: None for r in RUNGS[:-1]}, remat.FLOOR: LIMIT - 1,
+     **{r: GiB for r in FENCED_WALK}}],
+    ids=["floor-over-the-margin", "a-rung-fits", "rungs-refused-floor-not"])
+def test_a_floor_that_compiles_is_never_fenced(chooser, table):
+    """The second walk is entered on one observation, the unfenced floor's
+    refusal: a step whose unfenced walk ends on a rung or on its floor
+    (the Mistral, phi, Keye and ZAYA1 cells) tries nothing under the
+    fence, however little the fenced programs would hold."""
+    names, compiler = chooser(table)
+    assert FENCED[0] not in names
+    assert not [r for r in compiler.traced + compiler.compiled
+                if FENCED[0] in r]
+    c = profiler.counters()
+    assert "remat:prevent_cse" not in c and c["remat:rungs_fenced"] == 0
+    assert not any(r["prevent_cse"] for r in profiler.report("remat")[
+        "train_step"]["rungs"])
 
 
 def test_a_refused_fenced_floor_is_the_steps_error(chooser):
+    """Fenced rungs that would fit do not save it: the walk starts from
+    the floor, and no program is poorer."""
+    table = {**{r: None for r in RUNGS}, **{r: GiB for r in FENCED_WALK},
+             FENCED: None}
     with pytest.raises(jax.errors.JaxRuntimeError,
                        match="RESOURCE_EXHAUSTED"):
-        chooser({**{r: None for r in RUNGS}, FENCED: None})
+        chooser(table)
+
+
+def test_another_compile_error_under_the_fence_is_not_a_step_down(chooser):
+    broken = jax.errors.JaxRuntimeError("INTERNAL: Mosaic failed to compile")
+    with pytest.raises(jax.errors.JaxRuntimeError, match="Mosaic"):
+        chooser({**OLMO, MLP + FENCED: broken})
 
 
 def test_no_limit_reported_keeps_nothing_and_compiles_nothing(chooser):
@@ -336,12 +439,12 @@ def test_a_model_with_nothing_to_keep_gets_the_floor(chooser, fake):
 # --------------------------------------------------------------------------
 # The real models: names, policy, numerics.
 
-def _loss_fn(model_name, rung, **kw):
+def _loss_fn(model_name, rung, prevent_cse=False, **kw):
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 16), 0, 256)
     model = get_model(model_name, remat=True, **kw)
     state = train.create_train_state(model, optax.adam(1e-3), tokens,
                                      jax.random.PRNGKey(1))
-    saved = remat.Saved(rung)
+    saved = remat.Saved(rung, prevent_cse)
 
     def loss(params):
         with saved:
@@ -352,24 +455,31 @@ def _loss_fn(model_name, rung, **kw):
     return loss, state.params, saved
 
 
-def _kept_shapes(rung):
-    loss, params, saved = _loss_fn("llama-tiny", rung,
-                                          scan_layers=False)
+def _kept_shapes(rung, fenced=False):
+    loss, params, saved = _loss_fn("llama-tiny", rung, fenced,
+                                   scan_layers=False)
     kept = saved_residuals(loss, params)
     assert saved.met == set(FULL) and saved.blocks
+    text = str(jax.make_jaxpr(jax.grad(loss))(params))
+    assert ("prevent_cse=True" in text, "prevent_cse=False" in text) == (
+        fenced, not fenced)
     named = sum(bool(re.search(r"remat\.py:\d+:\d+ \(name\)", why))
                 for _, why in kept)
     return collections.Counter(aval.shape for aval, _ in kept), named
 
 
-@pytest.mark.parametrize("rung", RUNGS[:-1], ids="+".join)
-def test_saved_residuals_are_exactly_the_named_values(rung):
+@pytest.mark.parametrize("rung, fenced", [
+    *((rung, False) for rung in RUNGS[:-1]), (NO_WO, True)],
+    ids=lambda v: "+".join(v) if isinstance(v, tuple) else
+    "fenced" if v else "merged")
+def test_saved_residuals_are_exactly_the_named_values(rung, fenced):
     """What survives the forward beside what the floor keeps (each block's
     input): one value of each named width a layer, nothing else. (jax
-    keeps ``silu(gate)`` in ``gate``'s place: the same bytes.)"""
+    keeps ``silu(gate)`` in ``gate``'s place: the same bytes.) A fenced
+    rung keeps what the rung keeps, behind its layers' barriers."""
     widths = {"q": 64, "k": 32, "v": 32, "wo": 64, "gate": 128, "up": 128}
-    floor, floor_named = _kept_shapes(remat.FLOOR)
-    kept, named = _kept_shapes(rung)
+    floor, floor_named = _kept_shapes(remat.FLOOR, fenced)
+    kept, named = _kept_shapes(rung, fenced)
     assert floor_named == 0 and (2, 16, 128) not in floor
     assert kept - floor == collections.Counter(
         2 * [(2, 16, widths[n]) for n in rung])            # two layers
@@ -447,9 +557,11 @@ def test_only_a_model_with_an_indexer_gets_one_more_name(
     ids=["scanned", "unrolled"])
 def test_only_a_fenced_step_puts_barriers_around_its_layers(model_name, kw):
     """``Saved(prevent_cse=True)`` is heard by :func:`remat.block` in both
-    decoders; every other step, and a trace outside a step, is the program
-    it was (``prevent_cse=False`` on every layer's ``checkpoint``; the
-    barriers themselves are put in when the step is lowered)."""
+    decoders, at the floor and on a rung, whose layers keep the rung's
+    names behind the barriers; every other step, and a trace outside a
+    step, is the program it was (``prevent_cse=False`` on every layer's
+    ``checkpoint``; the barriers themselves are put in when the step is
+    lowered)."""
     tokens = jnp.zeros((1, 64), jnp.int32)
     model = get_model(model_name, **kw)
     params = model.init(jax.random.PRNGKey(0), tokens)["params"]
@@ -461,13 +573,19 @@ def test_only_a_fenced_step_puts_barriers_around_its_layers(model_name, kw):
             return jnp.sum(out.astype(jnp.float32))
         with saved or contextlib.nullcontext():
             text = str(jax.make_jaxpr(jax.grad(loss))(params))
-        return text.count("prevent_cse=True"), text.count("prevent_cse=False")
+        return (text.count("prevent_cse=True"),
+                text.count("prevent_cse=False"),
+                text.count("policy=<function save_only_these_names"))
 
     # (an expert layer's per-chunk ``jax.checkpoint`` is fenced always)
-    fenced, merged = barriers(None)
-    assert merged > 0
-    assert barriers(remat.Saved()) == (fenced, merged)
-    assert barriers(remat.Saved(prevent_cse=True)) == (fenced + merged, 0)
+    fenced, merged, policies = barriers(None)
+    assert merged > 0 and policies == 0
+    assert barriers(remat.Saved()) == (fenced, merged, 0)
+    assert barriers(remat.Saved(prevent_cse=True)) == (fenced + merged, 0, 0)
+    assert barriers(remat.Saved(NO_WO)) == (fenced, merged, merged)
+    on_a_rung = remat.Saved(NO_WO, prevent_cse=True)
+    assert barriers(on_a_rung) == (fenced + merged, 0, merged)
+    assert on_a_rung.effective(NO_WO) == NO_WO
 
 
 @pytest.mark.parametrize("rung", [("q", "k", "v", "wo", "gate", "up"),
@@ -538,6 +656,65 @@ def test_real_step_on_a_device_with_room_builds_one_program_a_start(
             assert 0 < c["remat:step_bytes"] < c["remat:bytes_limit"]
     assert losses["cold"] == losses["warm"] == losses["floor"]
     assert len(list((memo_dir / "tony_remat").glob("*.json"))) == 1
+
+
+def test_real_fenced_rung_is_the_program_a_warm_start_builds(
+        memo_dir, monkeypatch):
+    """The second walk over the real compiler (the CPU's) and real
+    unrolled layers; only the compiler's refusals and the bytes are made
+    up: every unfenced step refused, the top fenced rung over the margin.
+    The step is then not the last candidate traced; it runs, a warm start
+    builds it alone from the memo, and its loss is the floor's (in
+    float32: a second forward that is not merged with the first rounds
+    its bfloat16 intermediates where the compiler's fusions put them)."""
+    tried = []
+
+    def bytes_of(_compiled):
+        saved = tried[-1]
+        if not saved.prevent_cse:
+            raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: faked")
+        return LIMIT - remat.MARGIN + (saved.effective(saved.names) == FULL)
+
+    monkeypatch.setattr(remat, "_device_of", lambda _s: FakeDevice(LIMIT))
+    monkeypatch.setattr(remat, "step_bytes", bytes_of)
+    profiler.watch_builds()
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 16), 0, 256)
+    model = get_model("llama-tiny", remat=True, scan_layers=False,
+                      dtype=jnp.float32)
+    losses = {}
+    for start in ("cold", "warm", "floor"):
+        state = train.create_train_state(model, optax.adam(1e-3), tokens,
+                                         jax.random.PRNGKey(1))
+        real = train.make_train_step(
+            loss_of=lambda lg, b: train.next_token_loss(lg, b["x"]))
+        step = remat.ChosenStep(
+            lambda saved: (tried.append(saved), real.build(saved))[1])
+        if start == "floor":
+            step = real.build(remat.Saved())
+        del tried[:]
+        profiler.reset_timeline()
+        state, metrics = step(state, {"x": tokens})
+        state, metrics = step(state, {"x": tokens})
+        losses[start] = float(metrics["loss"])
+        if start == "floor":
+            continue
+        c = profiler.counters()
+        assert {n for n in FULL if f"remat:saved.{n}" in c} == set(NO_WO)
+        assert (c["remat:prevent_cse"], c["remat:from_memo"]) == (
+            1, start == "warm")
+        assert c["remat:step_bytes"] + remat.MARGIN == c["remat:bytes_limit"]
+        if start == "cold":
+            assert [(s.prevent_cse, s.effective(s.names)) for s in tried] \
+                == [(False, r) for r in RUNGS] + [
+                    (True, r) for r in RUNGS[::-1]]
+            assert (c["remat:rungs_tried"], c["remat:rungs_refused"],
+                    c["remat:rungs_fenced"]) == (10, 5, 5)
+        else:
+            assert [(s.prevent_cse, s.names) for s in tried] == [(True, NO_WO)]
+            assert c.get("programs_compiled", 0) \
+                + c.get("programs_loaded", 0) == 1, c
+            assert _rung_spans() == []
+    assert losses["cold"] == losses["warm"] == losses["floor"]
 
 
 def test_both_decoders_wrap_their_layers_in_the_one_helper():

@@ -198,9 +198,10 @@ def test_olmo_hybrid_step_fits_with_its_second_forward_fenced(topo,
     """``olmohybrid.train-16k``'s train step (766 M parameters: 8.56 GiB
     of arguments) at the residual ladder's floor. Merged with its first
     forward a layer's second keeps every layer's temporaries (16.15 GB of
-    15.75, refused, whatever the rung keeps), so the ladder ends at its
-    last resort, the floor under ``prevent_cse``: 12.66 GiB held, 12.78 by
-    ``remat.step_bytes`` (pinned loosely: it leaves the margin). Thirteen
+    15.75, refused, whatever the rung keeps), so the ladder's second walk
+    starts here, at the floor under ``prevent_cse``: 12.66 GiB held, 12.78
+    by ``remat.step_bytes`` (pinned loosely: it leaves the margin; the
+    rungs above it are ``tests/test_tpu_compile_remat.py``'s). Thirteen
     kernel calls: a chunk forward, its remat's and a chunk backward a gdn
     layer, the flash forward twice and its two backward kernels."""
     import optax

@@ -4,7 +4,9 @@ attached, nothing runs): what the backward may keep is chosen from these
 very numbers (``tony_tpu.remat``, ISSUE 30), so they are pinned — the
 floor's 13.57 GiB, the rung the rule takes beside a 15.75 GiB limit, and
 that the step with no remat at all is refused. And the ZAYA1 cell's step
-at the ladder's floor, the fullest step any cell runs (ISSUE 34)."""
+at the ladder's floor, the fullest step any cell runs (ISSUE 34), and the
+two steps whose unfenced floor is refused, under the fence (ISSUEs 38,
+41): the Olmo Hybrid cell's rungs, the Kimi Linear cell's floor."""
 
 import os
 
@@ -152,8 +154,9 @@ def test_kimi_linear_step_fits_only_with_its_second_forward_fenced(
         one_chip, on_tpu, lane, held):
     """Five unrolled layers at 1 x 32768 (ISSUE 38): merged with its first
     forward a layer's second keeps every layer's temporaries to the
-    backward and the floor is refused (32.9 GB of 15.75); the ladder's last
-    resort, the floor under ``prevent_cse``, holds 14.45 GiB. The cell's
+    backward and the floor is refused (32.9 GB of 15.75); the floor under
+    ``prevent_cse``, where the ladder's second walk starts and, in this
+    cell, ends, holds 14.45 GiB. The cell's
     control, the int8 lane over every projection, fits too (15.25 GiB)
     since an int8 matmul stores its caller's dtype (``quant_dot``'s
     ``out_dtype``): with float32 products it read 16.02 GB."""
@@ -175,3 +178,46 @@ def test_kimi_linear_step_fits_only_with_its_second_forward_fenced(
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert held - 0.1 < peak / GiB < held + 0.05
     assert peak < LIMIT
+    if lane == "bfloat16":
+        # 14.75 GiB by the ladder's reading, 0.25 under its line, and the
+        # poorest fenced rung is refused outright (18.7 GB): the second
+        # walk (ISSUE 41) ends here after one more compile.
+        assert round(remat.step_bytes(compiled) / GiB, 2) == 14.75
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="RESOURCE_EXHAUSTED"):
+            step.build(remat.Saved(("q", "k", "v"), prevent_cse=True)).lower(
+                state, batch).compile()
+
+
+OLMO_SEQ = 16384                     # benchmark/workloads/olmohybrid.train-16k
+LINE = 16_909_336_064 - remat.MARGIN     # the chip's bytes_limit, less MARGIN
+
+
+def test_olmo_hybrid_step_keeps_a_rung_behind_the_fence(one_chip, on_tpu):
+    """Four unrolled layers at 1 x 16384 (ISSUE 41): unfenced, the floor
+    is refused as every rung is (16.15 GB of 15.75: merged second
+    forwards); fenced, the floor reads 12.78 GiB and the ladder's second
+    walk goes on upwards — ``q,k,v`` 13.31, ``gate,up`` 14.11, ``q,k,v,
+    gate,up`` 14.63 and, ``wo`` too, 14.85, each under the 14.998 GiB the
+    rule holds a rung to: the richest is the step. Thirteen kernel calls
+    on every rung: the kernels' second forward stays."""
+    from benchmark import modelcfg_olmohybrid
+
+    cfg = modelcfg_olmohybrid.load("olmo-hybrid-7b")
+    model = get_model(cfg["program"]["model"],
+                      **modelcfg_olmohybrid.program_kwargs(cfg))
+    step = train.make_train_step(
+        loss_of=lambda loss, b: loss,
+        apply_kwargs_of=lambda b: {"targets": b["x"]})
+    step, state, batch = _abstract(model, step, 1, OLMO_SEQ, one_chip)
+    with pytest.raises(jax.errors.JaxRuntimeError,
+                       match="RESOURCE_EXHAUSTED"):
+        step.build(remat.Saved()).lower(state, batch).compile()
+    for rung, reads in ((("q", "k", "v", "gate", "up"), 14.63),
+                        (("q", "k", "v", "wo", "gate", "up"), 14.85)):
+        compiled = step.build(remat.Saved(rung, prevent_cse=True)).lower(
+            state, batch).compile()
+        total = remat.step_bytes(compiled)
+        assert round(total / GiB, 2) == reads, rung
+        assert total <= LINE
+        assert compiled.as_text().count("tpu_custom_call") == 13

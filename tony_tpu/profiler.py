@@ -87,16 +87,17 @@ _TRACE_OPTIONS = {
 # because they happen before any profiler session can start. Each occurs
 # once in a task's start but ``tony:import`` (once for each heavy module
 # the package is the first to import: `importing`) and
-# ``tony:remat_rung`` (once for each rung a cold first step tries, six at
-# most); ``tony:python_start`` is not entered but made by `timeline` from
-# the launch stamp.
+# ``tony:remat_rung`` (once for each rung a cold first step tries, twelve
+# at most: the ladder and its floor, merged and fenced);
+# ``tony:python_start`` is not entered but made by `timeline` from the
+# launch stamp.
 SETUP_SPANS = frozenset({
     "tony:python_start", "tony:import", "tony:dist_initialize",
     "tony:backend_init", "tony:create_train_state", "tony:restore",
     "tony:warm", "tony:first_step", "tony:remat_rung"})
 TIMELINE_FILE = "timeline.json"
 # Set-up spans kept. A task's start records a dozen or two, not a handful
-# (five imports and six rungs at most, one of each other; a replica one
+# (five imports and twelve rungs at most, one of each other; a replica one
 # more for each restore and warm-up): a bound no start reaches, there for
 # the program that calls a spanned function in a loop.
 MAX_SPANS = 256

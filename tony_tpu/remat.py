@@ -23,13 +23,17 @@ not add up: PERF.md §7), so the program asks the compiler:
   step and takes the first whose ``memory_analysis()`` total leaves
   :data:`MARGIN` under the device's ``bytes_limit``. A refused compile
   (``RESOURCE_EXHAUSTED``) is a step down; the empty set is the floor and
-  is today's program. Where the compiler refuses the floor too, one more
-  program is tried: the floor with ``prevent_cse`` (XLA may merge an
+  is today's program. Where the compiler refuses the floor too, the
+  ladder is walked a second time under ``prevent_cse`` (XLA may merge an
   unrolled layer's second forward with its first, and then holds every
-  layer's temporaries to the backward; barriers keep them two). The
-  answer is remembered beside the persistent compile cache, so a warm
-  start builds one program and no refused compile is repeated. A backend
-  that reports no limit (the CPU) gets the floor.
+  layer's temporaries to the backward, whatever the rung keeps; barriers
+  keep them two): the fenced floor first, the last program there is,
+  then the rungs from the poorest upwards while each compiles and leaves
+  the margin. The last that did is the step, so a step with no room
+  above its fenced floor learns that from one more compile. The answer
+  is remembered beside the persistent compile cache, so a warm start
+  builds one program and no refused compile is repeated. A backend that
+  reports no limit (the CPU) gets the floor.
 
 Processes of one job must agree on the set (they run one SPMD program):
 they do, because each reads the same compiler and the same kind of device.
@@ -97,7 +101,8 @@ _ACTIVE: contextvars.ContextVar = contextvars.ContextVar(
 class Saved:
     """The names one step's backward keeps, and whether its layers'
     second forward is fenced off from the first (``prevent_cse``: the
-    step below the floor); entered around the model's trace. Also the
+    ladder's second walk, for a step whose unfenced floor the compiler
+    refused); entered around the model's trace. Also the
     trace's witness: which names the model met under it and how many
     layers :func:`block` wrapped."""
 
@@ -146,10 +151,12 @@ def block(layer_cls, always: Tuple[str, ...] = ()):
     the policy it would get without the argument. The second forward may
     be merged with the first (``prevent_cse=False``: under ``nn.scan``
     nothing can be, and unrolled layers whose step fits gain what XLA
-    merges) unless the step's :class:`Saved` says otherwise: the step
-    :class:`ChosenStep` falls back to when the floor itself is refused
-    (the 32k Kimi Linear step's five unrolled layers read 32.9 GB merged,
-    14.76 GiB fenced: PERF.md section 6, PR 38)."""
+    merges) unless the step's :class:`Saved` says otherwise: the steps
+    of :class:`ChosenStep`'s second walk, floor and rungs alike, taken
+    when the unfenced floor itself is refused (the 32k Kimi Linear step's
+    five unrolled layers read 32.9 GB merged, 14.76 GiB fenced: PERF.md
+    section 6, PR 38; the 16k Olmo Hybrid step keeps ``q, k, v, wo,
+    gate, up`` under the fence: PR 41)."""
     import flax.linen as nn
 
     active = _ACTIVE.get()
@@ -260,6 +267,8 @@ def _publish(choice: dict, limit: int, from_memo: bool) -> None:
                         0 if from_memo else len(choice["rungs"]))
     profiler.count_once("remat:rungs_refused", 0 if from_memo else sum(
         r["bytes"] is None for r in choice["rungs"]))
+    profiler.count_once("remat:rungs_fenced", 0 if from_memo else sum(
+        r["prevent_cse"] for r in choice["rungs"]))
     profiler.count_once("remat:from_memo", int(from_memo))
     if choice.get("prevent_cse"):
         profiler.count_once("remat:prevent_cse", 1)
@@ -345,18 +354,27 @@ class ChosenStep:
         first = Saved(LADDER[0])
         fn, reading = yield from self._rung(first, first, limit)
         readings = [reading]
-        for rung in list(dict.fromkeys(
-                map(first.effective, LADDER + (FLOOR,))))[1:]:
+        rungs = list(dict.fromkeys(map(first.effective, LADDER + (FLOOR,))))
+        for rung in rungs[1:]:
             if readings[-1]["fits"]:
                 break
             fn, reading = yield from self._rung(Saved(rung), first, limit)
             readings.append(reading)
         if not readings[-1]["fits"]:
-            # The floor was refused: the floor again, its layers' second
-            # forward fenced off from the first.
+            # The floor was refused: the rungs again, each layer's second
+            # forward fenced off from its first. From the floor upwards,
+            # so that a step with no room above its floor finds out in
+            # one compile; the last rung that fitted is the step.
             fn, reading = yield from self._rung(
                 Saved(FLOOR, prevent_cse=True), first, limit)
             readings.append(reading)
+            for rung in reversed([r for r in rungs if r != FLOOR]):
+                candidate, richer = yield from self._rung(
+                    Saved(rung, prevent_cse=True), first, limit)
+                readings.append(richer)
+                if not richer["fits"]:
+                    break
+                fn, reading = candidate, richer
         choice = {"saved": reading["saved"], "step_bytes": reading["bytes"],
                   "prevent_cse": reading["prevent_cse"], "rungs": readings}
         _write_memo(path, choice)
@@ -367,10 +385,11 @@ class ChosenStep:
         """Generator: one rung — its candidate yielded for tracing, the
         trace compiled, its bytes read — under the set-up span
         ``tony:remat_rung`` (attrs ``saved``, ``bytes``: None for a
-        refused compile, ``fits``, ``prevent_cse``). A floor that compiles
-        fits, whatever the margin says; the fenced floor is the last
-        program there is, so its refusal is the step's error. Returns the
-        candidate and its reading."""
+        refused compile, ``fits``, ``prevent_cse``). A refused compile is
+        a step down; a floor that compiles fits, whatever the margin
+        says; the fenced floor is the last program there is, so its
+        refusal is the step's error. Returns the candidate and its
+        reading."""
         with profiler.span("tony:remat_rung") as sp:
             fn = self.build(saved)
             trace = yield fn
@@ -378,7 +397,8 @@ class ChosenStep:
             try:
                 total = step_bytes(trace.lower().compile())
             except jax.errors.JaxRuntimeError as e:
-                if "RESOURCE_EXHAUSTED" not in str(e) or saved.prevent_cse:
+                if "RESOURCE_EXHAUSTED" not in str(e) or (
+                        saved.prevent_cse and rung == FLOOR):
                     raise
                 total = None
             fits = total is not None and (rung == FLOOR
