@@ -606,6 +606,133 @@ def test_delta_rule_and_plain_attention_mixers_name_the_ladders_residuals(
         assert jnp.allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
+def _sublayer(what, dtype):
+    """(loss over a fenced sublayer's parameters and input, its arguments):
+    a ``SwiGLU`` alone, a ``gdn`` mixer alone (its ``_delta_out``), or a
+    whole ``gdn`` layer (the mixer and, behind the post-sublayer norm, the
+    ``SwiGLU``)."""
+    from tony_tpu.models import hybrid
+
+    cfg = get_model("olmo-hybrid-tiny", dtype=dtype, layers=("gdn",)).cfg
+    module = {"swiglu": lambda: hybrid.SwiGLU(cfg, 128),
+              "gdn": lambda: hybrid.GDN(cfg),
+              "gdn-layer": lambda: hybrid.HybridLayer(cfg, "gdn", 0)}[what]()
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, cfg.dim), dtype)
+    params = module.init(jax.random.PRNGKey(1), x)["params"]
+
+    def loss(params, x):
+        out = module.apply({"params": params}, x)
+        out = out if what == "swiglu" else out[0]
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+    return loss, (params, x)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("what", ["swiglu", "gdn", "gdn-layer"])
+def test_the_fence_is_the_identity_in_value_and_every_gradient(
+        what, dtype, monkeypatch):
+    """``remat.fence`` (ISSUE 42) only says where an array is made: the
+    loss and the gradient of every parameter and of the input are those of
+    the sublayer without it, bit for bit in both dtypes for a ``SwiGLU``
+    and for a ``gdn`` mixer. In a whole layer the loss is still bit for
+    bit and the gradients are the same sums in another order: the layer's
+    ``x + norm1(mixer)`` is read by the residual and by ``w_gate`` and
+    ``w_up``, and the fence on the FFN's input adds those two products'
+    cotangents to each other before the residual's (float addition does
+    not associate: 2e-6 of a gradient's norm in float32, 0.7% in bfloat16,
+    where PR 41's rungs differ from their floor by 0.6-1%)."""
+    loss, args = _sublayer(what, dtype)
+    got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(*args)
+    monkeypatch.setattr(remat, "fence", lambda x: x)
+    loss, args = _sublayer(what, dtype)
+    text = str(jax.make_jaxpr(jax.grad(loss))(*args))
+    assert "optimization_barrier" not in text
+    want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(*args)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if what != "gdn-layer":
+            np.testing.assert_array_equal(a, b)
+        else:
+            assert np.linalg.norm(a - b) <= (
+                1e-5 if dtype == jnp.float32 else 2e-2) * np.linalg.norm(b)
+
+
+# Fence sites a layer: ``SwiGLU`` (its input, ``gate``, ``up``, ``silu(gate)
+# * up``, its output) and ``_delta_out`` (``y``, ``wo``'s output).
+SWIGLU_FENCES, DELTA_OUT_FENCES = 5, 2
+
+
+@pytest.mark.parametrize("model_name, kw, fences, dead", [
+    ("olmo-hybrid-tiny", {}, 2 * SWIGLU_FENCES + DELTA_OUT_FENCES, 0),
+    ("kimi-linear-tiny", {}, SWIGLU_FENCES + 2 * DELTA_OUT_FENCES, 1),
+    ("hybrid-tiny", {}, 0, 0), ("llama-tiny", {"scan_layers": False}, 0, 0)])
+def test_a_steps_trace_counts_the_operands_it_makes_once(
+        chooser, monkeypatch, memo_dir, model_name, kw, fences, dead):
+    """Each fence is three barriers in the jaxpr of the step's gradient:
+    on its value in the forward, on its value again in the layer's second
+    forward, on its cotangent (the layers' own barriers, under
+    ``prevent_cse``, are put in when the step is lowered: none in the
+    jaxpr). One is ``dead``: no backward reads the output of a pre-norm
+    layer's feed-forward (Kimi Linear's dense one), so its second forward
+    is not traced; a post-norm layer's (Olmo Hybrid) is read by the norm's
+    backward. The step's ``Saved`` counts the sites, and the chosen step's
+    timeline says so — cold and from the memo — where there are any. A
+    model that never enters ``SwiGLU`` or ``_delta_out`` reads none."""
+    loss, params, saved = _loss_fn(model_name, remat.LADDER[0],
+                                   prevent_cse=True, xent_chunk=8, **kw)
+    text = str(jax.make_jaxpr(jax.grad(loss))(params))
+    assert text.count("optimization_barrier") == 3 * fences - dead
+    assert saved.fences == fences
+
+    monkeypatch.setattr(remat, "_device_of", lambda _s: FakeDevice(1 << 40))
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 16), 0, 256)
+    model = get_model(model_name, remat=True, xent_chunk=8, **kw)
+    for start in ("cold", "warm"):
+        state = train.create_train_state(model, optax.adam(1e-3), tokens,
+                                         jax.random.PRNGKey(1))
+        step = train.make_train_step(
+            loss_of=lambda loss, b: loss,
+            apply_kwargs_of=lambda b: {"targets": b["x"]})
+        profiler.reset_timeline()
+        step.lower(state, {"x": tokens})
+        c = profiler.counters()
+        assert c["remat:from_memo"] == (start == "warm")
+        assert c.get("remat:operands_made_once", 0) == fences, start
+        assert ("remat:operands_made_once" in c) == bool(fences)
+
+
+@pytest.mark.parametrize("model_name, digest", [
+    ("llama-tiny", "5beb4b71c9a3e9f4"), ("hybrid-tiny", "ec0ac400995f3d4c"),
+    ("zaya-tiny", "25ee96bc7f638dc6"), ("keye-tiny", "d5ee50706b6b6322")])
+def test_a_model_outside_the_fenced_sublayers_traces_the_parents_gradient(
+        model_name, digest):
+    """``Transformer``'s MLP and experts and the layer-kind decoder's
+    ``GatedMLP`` pass no fence: the jaxpr of the step's gradient on the
+    ladder's top rung is, letter for letter, the one PR 41's tree traced
+    (its sha256, function addresses left out; a PR that changes these
+    models' trace on purpose re-pins it from its own parent;
+    ``tests/test_kimi_linear.py`` pins the four cells' steps at their real
+    sizes, outside a step's ``Saved``)."""
+    import hashlib
+
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    model = get_model(model_name, remat=True)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens))["params"]
+    saved = remat.Saved(remat.LADDER[0])
+
+    def loss(p):
+        with saved:
+            return train.next_token_loss(
+                model.apply({"params": p}, tokens), tokens)
+    text = str(jax.make_jaxpr(jax.grad(loss))(params))
+    assert saved.fences == 0 and "optimization_barrier" not in text
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
 def test_names_are_inert_outside_a_step():
     """``model.init``, the serve forward and a step on the CPU trace the
     same modules with no set active: nothing is met, nothing is kept."""

@@ -341,16 +341,25 @@ def _norm(cfg, name):
 
 class SwiGLU(nn.Module):
     """``(silu(u W_gate) * u W_up) W_down``: the dense decoder's MLP (its
-    parameter names, and ``gate`` / ``up`` named for the residual ladder)."""
+    parameter names, and ``gate`` / ``up`` named for the residual ladder).
+    Every operand of its three weight-gradient products is fenced
+    (:func:`remat.fence`): the input, ``silu(gate) * up`` and, through
+    their cotangents, ``dgate``, ``dup`` and the output's are arrays made
+    once, where an unrolled layer's backward would make each again inside
+    every product that reads it (25.6 ms a product for 11.0 at 16384 x
+    3840 x 11008: PERF.md section 6, PR 42)."""
     cfg: HybridConfig
     width: int
 
     @nn.compact
     def __call__(self, u):
         cfg = self.cfg
-        gate = remat.name(_dense(cfg, self.width, "w_gate")(u), "gate")
-        up = remat.name(_dense(cfg, self.width, "w_up")(u), "up")
-        return _dense(cfg, cfg.dim, "w_down")(nn.silu(gate) * up)
+        u = remat.fence(u)
+        gate = remat.fence(
+            remat.name(_dense(cfg, self.width, "w_gate")(u), "gate"))
+        up = remat.fence(remat.name(_dense(cfg, self.width, "w_up")(u), "up"))
+        return remat.fence(_dense(cfg, cfg.dim, "w_down")(
+            remat.fence(nn.silu(gate) * up)))
 
 
 class GatedMLP(nn.Module):
@@ -520,11 +529,13 @@ def _delta_qkv(mod, cfg, u, scope, heads, dk, dv, taps):
 
 def _delta_out(cfg, o, gate):
     """``W_o (RMSNorm_head(o) * gate)``: ``o [B, T, heads, dv]``, ``gate``
-    ``[B, T, heads dv]`` float32; ``wo`` named for the residual ladder."""
+    ``[B, T, heads dv]`` float32; ``wo`` named for the residual ladder,
+    its two operands in the backward (the gated norm's output and the
+    cotangent of its own) fenced as :class:`SwiGLU`'s are."""
     b, t, h, dv = o.shape
     o = RMSNorm(cfg.norm_eps, name="o_norm")(o).astype(jnp.float32)
-    y = (o.reshape(b, t, h * dv) * gate).astype(cfg.dtype)
-    return remat.name(_dense(cfg, cfg.dim, "wo")(y), "wo")
+    y = remat.fence((o.reshape(b, t, h * dv) * gate).astype(cfg.dtype))
+    return remat.fence(remat.name(_dense(cfg, cfg.dim, "wo")(y), "wo"))
 
 
 class KDA(nn.Module):

@@ -33,7 +33,12 @@ not add up: PERF.md §7), so the program asks the compiler:
   above its fenced floor learns that from one more compile. The answer
   is remembered beside the persistent compile cache, so a warm start
   builds one program and no refused compile is repeated. A backend that
-  reports no limit (the CPU) gets the floor.
+  reports no limit (the CPU) gets the floor;
+* :func:`fence` says where an array is made: a sublayer of an unrolled
+  layer puts it on the operands of its weight-gradient products, which
+  XLA would otherwise make again inside each product, for every tile of a
+  matrix-shaped result (ISSUE 42). It is on every step of a model that
+  uses it, whatever the ladder keeps.
 
 Processes of one job must agree on the set (they run one SPMD program):
 they do, because each reads the same compiler and the same kind of device.
@@ -104,7 +109,8 @@ class Saved:
     ladder's second walk, for a step whose unfenced floor the compiler
     refused); entered around the model's trace. Also the
     trace's witness: which names the model met under it and how many
-    layers :func:`block` wrapped."""
+    layers :func:`block` wrapped, how many :func:`fence` sites it
+    passed."""
 
     def __init__(self, names: Iterable[str] = FLOOR,
                  prevent_cse: bool = False):
@@ -112,6 +118,7 @@ class Saved:
         self.prevent_cse = bool(prevent_cse)
         self.met: set = set()
         self.blocks = 0
+        self.fences = 0
 
     def __enter__(self) -> "Saved":
         self._token = _ACTIVE.set(self)
@@ -133,6 +140,31 @@ def name(x: jax.Array, tag: str) -> jax.Array:
     if active is not None:
         active.met.add(tag)
     return checkpoint_name(x, tag)
+
+
+@jax.custom_vjp
+def _fenced(x):
+    return jax.lax.optimization_barrier(x)
+
+
+_fenced.defvjp(lambda x: (jax.lax.optimization_barrier(x), None),
+               lambda _, ct: (jax.lax.optimization_barrier(ct),))
+
+
+def fence(x: jax.Array) -> jax.Array:
+    """``x``, made once: an identity whose value and whose cotangent each
+    pass ``optimization_barrier``. XLA fuses nothing across a barrier, so
+    the product that reads ``x`` (or, in the backward, the products that
+    read its cotangent) reads an array in HBM and not the elementwise
+    recipe that makes it. That is what a weight-gradient product of an
+    unrolled layer needs: its result is a matrix's shape, it walks many
+    result tiles, and a fused producer over a ``[tokens, .]`` operand is
+    run again for each (PERF.md section 6, PR 42). Counted on the step's
+    :class:`Saved` (``remat:operands_made_once`` on its timeline)."""
+    active = _ACTIVE.get()
+    if active is not None:
+        active.fences += 1
+    return _fenced(x)
 
 
 def kept() -> Tuple[str, ...]:
@@ -272,6 +304,9 @@ def _publish(choice: dict, limit: int, from_memo: bool) -> None:
     profiler.count_once("remat:from_memo", int(from_memo))
     if choice.get("prevent_cse"):
         profiler.count_once("remat:prevent_cse", 1)
+    if choice.get("operands_made_once"):
+        profiler.count_once("remat:operands_made_once",
+                            choice["operands_made_once"])
     profiler.record("remat", "train_step", bytes_limit=limit, margin=MARGIN,
                     from_memo=from_memo, **choice)
     _log.info("remat: backward keeps %s; step %.3f of %.3f GiB%s",
@@ -376,7 +411,8 @@ class ChosenStep:
                     break
                 fn, reading = candidate, richer
         choice = {"saved": reading["saved"], "step_bytes": reading["bytes"],
-                  "prevent_cse": reading["prevent_cse"], "rungs": readings}
+                  "prevent_cse": reading["prevent_cse"],
+                  "operands_made_once": first.fences, "rungs": readings}
         _write_memo(path, choice)
         _publish(choice, limit, from_memo=False)
         return fn
