@@ -5,9 +5,9 @@ every gradient leaf, two AdamW steps, in float32 and bfloat16 compute; the
 flash grids at unequal q/k and v widths against ``reference_attention``;
 ``route_sigmoid`` against a plain form; the **share test** (the routed parts
 of all the shares plus the shared expert counted once add up to the uncut
-layer); the FLOPs a token is charged; and the other four cells' train
-steps, traced at their real sizes with the kernels' branches taken, against
-the jaxprs the parent commit traced."""
+layer); the FLOPs a token is charged; and all six cells' train steps,
+traced at their real sizes with the kernels' branches taken, against the
+jaxprs a parent commit traced."""
 
 import hashlib
 import re
@@ -18,8 +18,8 @@ import numpy as np
 import optax
 import pytest
 
-from benchmark import modelcfg, modelcfg_keyevl2, modelcfg_phi4flash, \
-    modelcfg_zaya1
+from benchmark import modelcfg, modelcfg_keyevl2, modelcfg_olmohybrid, \
+    modelcfg_phi4flash, modelcfg_zaya1
 from benchmark import modelcfg_kimilinear as mc
 from benchmark import reference, reference_kimilinear as ref
 from benchmark import roofline_kimilinear, weights_kimilinear as wk
@@ -411,7 +411,7 @@ def test_param_count_is_issue_38s_table():
     assert sum(int(np.prod(s)) for s, _ in specs.values()) == count["total"]
 
 
-# -- the other four cells keep their programs --------------------------------
+# -- the cells keep their programs -------------------------------------------
 
 def _clean(text):
     text = re.sub(r"0x[0-9a-f]+", "0x", text)
@@ -437,7 +437,8 @@ def _step_digest(model, batch, seq, monkeypatch):
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-# Read on the parent commit fd57d29 (PR 37) with this very function.
+# Read on a parent commit with this very function: the first four on
+# fd57d29 (PR 37), before the Kimi Linear cell existed.
 PARENT_STEPS = {
     "mistral7b.train": ("ca491cfea9d04c94", lambda: (get_model(
         "llama2-7b", attention="flash", **modelcfg.program_kwargs(
@@ -451,6 +452,13 @@ PARENT_STEPS = {
     "zaya1.train-32k": ("c39e9c049f0ce4c8", lambda: (get_model(
         "zaya1-8b", **modelcfg_zaya1.program_kwargs(
             modelcfg_zaya1.load("zaya1-8b"), 32768)), 1, 32768)),
+    # These two read on d43341c (PR 42), before PR 43 touched the flash grids.
+    "kimilinear.train-32k": ("bf061bcfb035153c", lambda: (get_model(
+        "kimi-linear-48b-a3b", **mc.program_kwargs(
+            mc.load("kimi-linear-48b-a3b"), 32768)), 1, 32768)),
+    "olmohybrid.train-16k": ("c193f4a060c51298", lambda: (get_model(
+        "olmo-hybrid-7b", **modelcfg_olmohybrid.program_kwargs(
+            modelcfg_olmohybrid.load("olmo-hybrid-7b"))), 1, 16384)),
 }
 
 

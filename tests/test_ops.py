@@ -1,11 +1,14 @@
 """Pallas-kernel tests (interpret mode on CPU) against the pure-JAX
 reference — the kernel-correctness tier of the compute plane."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tony_tpu.ops import attention as att
 from tony_tpu.ops import flash_attention, reference_attention
 
 
@@ -477,3 +480,114 @@ def test_flash_gqa_rejects_ragged_heads():
     q, k, v = rand_gqa(h=4, hkv=3)
     with pytest.raises(ValueError, match="multiple"):
         flash_attention(q, k, v, interpret=True)
+
+
+# -- what the benchmark's readers and remat stand on, in every flash grid ----
+
+def _spec(*shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _classic_case(t, **kw):
+    q, kv = _spec(1, 4, t, 128), _spec(1, 2, t, 128)
+    return (lambda q, k, v: att.flash_attention(q, k, v, **kw)), (q, kv, kv)
+
+
+def _packed_case(t, **kw):
+    q, kv = _spec(1, t, 4 * 128), _spec(1, t, 2 * 128)
+    return (lambda q, k, v: att.flash_attention_packed(q, k, v, 4, **kw),
+            (q, kv, kv))
+
+
+def _selected_case(t):
+    q, kv = _spec(1, t, 4 * 128), _spec(1, t, 2 * 128)
+    sel = _spec(1, -(-t // att.SEL_SPAN), t, att.SEL_LANES, dtype=jnp.int32)
+    return (lambda q, k, v, sel: att.flash_attention_selected(
+        q, k, v, sel, 4)[0], (q, kv, kv, sel))
+
+
+def _mla_case(t):
+    q = _spec(1, t, 4 * 128)
+    return (lambda q, k, v, qs, ks: att.flash_attention_mla(
+        q, qs, k, ks, v, 4), (q, q, q, _spec(1, 4, t, 64), _spec(1, t, 64)))
+
+
+# (layout, residency, variant) with a public entry -> (the case at a length
+# on that side of ``_resident_fits`` — a function and its arguments, q, k, v
+# first — the scopes' ending, a window that length takes). K/V of 1024 x
+# 128 x bf16 stay in VMEM, 8192 stream.
+GRIDS = {
+    "classic-resident-plain": (_classic_case, 1024, "", 256),
+    "classic-streamed-plain": (_classic_case, 8192, "", 512),
+    "packed-resident-plain": (_packed_case, 1024, "", 256),
+    "packed-streamed-plain": (_packed_case, 8192, "", 512),
+    "packed-streamed-sel": (_selected_case, 8192, "_sel", None),
+    "packed-streamed-mla": (_mla_case, 8192, "_mla", None),
+}
+PASSES = {"fwd": "attn_fwd", "dq": "attn_bwd_dq", "dkv": "attn_bwd_dkv"}
+
+
+def _trace(case, monkeypatch):
+    """The jaxpr of the case's gradient with the kernels' branch taken, its
+    flash calls by scope, and what ``remat.name`` named."""
+    fn, args = case
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wrt = tuple(i for i, a in enumerate(args) if a.dtype == jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: fn(*a).astype(jnp.float32).sum(), wrt))(*args)
+    calls, names = {}, {}
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                scope = re.findall(
+                    r"attn_\w+", str(eqn.source_info.name_stack))[-1]
+                calls.setdefault(scope, []).append(
+                    [(v.aval.shape, v.aval.dtype) for v in eqn.outvars])
+            if eqn.primitive.name == "name":
+                names[eqn.params["name"]] = eqn.outvars[0].aval
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return str(jaxpr), calls, names, args
+
+
+@pytest.mark.parametrize("grid,which,windowed", [
+    pytest.param(grid, which, windowed,
+                 id=f"{grid}-{which}" + "-window" * windowed)
+    for grid in sorted(GRIDS) for which in sorted(PASSES)
+    for windowed in (False, True) if GRIDS[grid][3] or not windowed])
+def test_every_flash_grid_keeps_the_readers_contract(grid, which, windowed,
+                                                     monkeypatch):
+    """Scope names, the number, order, shapes and dtypes of each call's
+    results and the two named residuals are what ``benchmark/readers`` and
+    ``remat`` find the flash calls by: one case a (layout, residency,
+    variant, pass), with and without a window where the entry takes one."""
+    make, t, tag, window = GRIDS[grid]
+    kw = {"window": window} if windowed else {}
+    text, calls, names, args = _trace(make(t, **kw), monkeypatch)
+    ending = ("_win" if windowed else "") + tag
+    assert sorted(calls) == sorted(p + ending for p in PASSES.values())
+    q, k, v = args[:3]
+    classic = grid.startswith("classic")
+    rows = lambda a: ((a.shape[0] * a.shape[1], *a.shape[2:]) if classic
+                      else a.shape, a.dtype)
+    lse = (4, t, 8) if classic else (1, 4, t, 8)
+    want = {"fwd": [rows(q), (lse, jnp.float32)], "dq": [rows(q)],
+            "dkv": [rows(k), rows(v)]}[which]
+    if tag == "_mla":
+        qs, ks = args[3:]
+        want += {"fwd": [], "dq": [(qs.shape, qs.dtype)],
+                 "dkv": [((1, 4, t, ks.shape[2]), jnp.float32)]}[which]
+    assert calls[PASSES[which] + ending] == [want]
+    assert (names["flash_out"].shape, names["flash_out"].dtype) == (
+        q.shape, q.dtype)
+    assert (names["flash_lse"].shape, names["flash_lse"].dtype) == (
+        lse, jnp.float32)
+    if which == "fwd" and not tag and not windowed:
+        # a window of None is the program without the argument, a window
+        # reaching the first key is no window, a real one another program
+        assert text == _trace(make(t, window=None), monkeypatch)[0]
+        assert text == _trace(make(t, window=t), monkeypatch)[0]
+        assert text != _trace(make(t, window=window), monkeypatch)[0]

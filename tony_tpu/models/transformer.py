@@ -10,9 +10,16 @@ TPU-first design:
   (``nn.with_logical_partitioning``); :data:`tony_tpu.parallel.RULES` maps
   them to the dp/fsdp/tp mesh so GSPMD inserts the tensor-parallel
   collectives — no hand-written allreduce;
-* attention dispatches through :func:`tony_tpu.ops.flash_attention` (fused
-  pallas kernel on TPU) or :func:`tony_tpu.parallel.ring_attention_sharded`
-  when the sequence axis is sharded (long context, SURVEY.md §5.7);
+* attention dispatches by what the layer is given: without a mesh and at a
+  head size that is whole lane tiles,
+  :func:`tony_tpu.ops.flash_attention_packed` over the projections' own
+  ``[B, T, H·D]`` (no transpose); with q/k-norm
+  or an indexer, ``flash_attention_selected``; in a latent, the packed
+  kernels over its narrower heads; under a mesh,
+  ``flash_attention_sharded`` (``[B, H, T, D]``, heads on the tp axis), or
+  :func:`tony_tpu.parallel.ring_attention_sharded` when the sequence axis
+  is sharded (long context, SURVEY.md §5.7); serving, ``flash_decode``
+  over the cache; off the TPU, the pure-JAX reference;
 * ``scan_layers`` folds the layer stack into one ``nn.scan`` (one trace +
   one compile of a single block) and ``remat`` wraps blocks in
   ``jax.checkpoint`` to trade FLOPs for HBM (:func:`tony_tpu.remat.block`:
@@ -375,8 +382,8 @@ class Attention(nn.Module):
                       seq_axis=1)
             # GQA is zero-copy through the packed kernels: K/V stay at
             # [B, T, nkv·hd]; the kernel's index maps route query head h
-            # to kv lane-block h·nkv/nh (VERDICT r4 next-step #5 — no
-            # jnp.repeat, no phantom-head HBM).
+            # to kv lane-block h·nkv/nh: no jnp.repeat, and no HBM spent
+            # on repeated heads.
             _count_blocks(t, hd, v.dtype.itemsize)
             out = flash_attention_packed(
                 q4.reshape(b, t, nh * hd), k4.reshape(b, t, nkv * hd), v,
@@ -389,7 +396,7 @@ class Attention(nn.Module):
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
         # No GQA repeat on ANY path: the flash kernels, ring attention, and
-        # reference_attention are all GQA-native (r5) — ring even ships
+        # reference_attention are all GQA-native — ring even ships
         # the narrow K/V around the ICI ring, dividing rotate traffic by
         # the group size.
         if cfg.attention == "ring":
@@ -677,8 +684,7 @@ class Transformer(nn.Module):
                 # (psum over the contracted vocab axis + reduce-scatter)
                 # but a gather's embed-fsdp→batch-fsdp transition is an
                 # "involuntary full rematerialization": replicate-then-
-                # slice EVERY step, fwd and transpose (VERDICT r4
-                # next-step #3). The one-hot term is
+                # slice EVERY step, fwd and transpose. The one-hot term is
                 # 2·vocab·dim FLOPs/token ≈ 0.6% of a 7B step, and it
                 # rides the MXU.
                 x = jax.nn.one_hot(tokens, cfg.vocab, dtype=cfg.dtype) \

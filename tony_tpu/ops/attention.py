@@ -1,25 +1,41 @@
-"""Flash attention: fused online-softmax attention as a pallas TPU kernel.
+"""Flash attention: fused online-softmax attention as Pallas TPU kernels.
 
 The score matrix never leaves VMEM: each (batch·head, q-block) grid cell
 streams K/V blocks through the online-softmax recurrence (running max m,
 normalizer l, accumulator acc — same math as
 :mod:`tony_tpu.parallel.ring_attention`, which runs the recurrence *across
-chips* while this kernel runs it *within* one), so HBM traffic is O(T·D)
+chips* while these kernels run it *within* one), so HBM traffic is O(T·D)
 instead of O(T²) and the matmuls hit the MXU in bf16/f32 with f32
 accumulation. Causal runs skip entire k-blocks above the diagonal — the
-dominant win for long sequences.
+dominant win for long sequences — and a window those left of it. The
+backward is two more kernels (dq; dk/dv) that recompute p from the saved
+log-sum-exp rows (no saved T×T residuals).
 
-Public entry :func:`flash_attention` dispatches: pallas kernel on TPU (or
-``interpret=True`` for CPU tests), pure-JAX :func:`reference_attention`
-elsewhere; the backward is two more kernels (dq; dk/dv) that recompute p
-from the saved log-sum-exp rows (no saved T×T residuals).
+Entries (Pallas on a TPU or under ``interpret=True``, a pure-JAX reference
+elsewhere): :func:`flash_attention` over ``[B, H, T, D]`` (any head size,
+ragged and cross lengths; :func:`flash_attention_sharded` maps it over a
+mesh) and :func:`flash_attention_packed` over the projections' own ``[B,
+T, H·D]`` (heads as lane blocks, no transpose), both with zero-copy GQA
+and an optional causal window; :func:`flash_attention_selected` (packed,
+over the keys a learned selection names); :func:`flash_attention_mla`
+(packed, query/key wider than the values, one key part shared by the
+heads); :func:`flash_decode`, the serving plane's forward over a cache.
+
+The training entries share what lies below them: three tile expressions,
+six kernel bodies (forward, dq, dk/dv; K/V streamed by the grid or
+resident in VMEM) and ONE description of a grid — a layout, a variant,
+the call's statics — from which a streamed and a resident builder make
+every ``pallas_call``. What the entries decide they decide from the
+shapes: blocks (:func:`_pick_blocks`), padding (:func:`_plan_dispatch`),
+residency (:func:`_resident_fits`).
 """
 
 from __future__ import annotations
 
 import functools
+import itertools
 import math
-from typing import NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional
 
 from tony_tpu import profiler
 
@@ -325,13 +341,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     k-blocks above the diagonal via predication. Also writes the
     log-sum-exp rows the backward kernels reconstruct p from.
     ``qi_axis`` is which grid axis carries the q-block index (the k axis
-    is ``qi_axis + 1``): 1 for the [B·H, T, D] layout's (bh, i, kb) grid,
-    2 for the packed [B, T, H·D] layout's (b, h, i, kb) grid. Under a
-    ``window`` the k axis holds only the blocks a q-block can see (``_window_spans``): step 0 is
-    the q-block's first visible block, and blocks left of the window are
-    neither fetched nor computed. ``sel_ref`` (the ``_sel`` wrappers): the
+    is ``qi_axis + 1``): the layout's ``axis``. Under a ``window`` the k
+    axis holds only the blocks a q-block can see (``_window_spans``): step
+    0 is the q-block's first visible block, and blocks left of the window
+    are neither fetched nor computed. ``sel_ref`` (the ``_SEL`` variant): the
     word block of a learned selection, which then is the mask. ``shared``
-    (the ``_mla`` wrappers): ``(qs_ref, ks_ref)``, this head's query part
+    (the ``_MLA`` variant): ``(qs_ref, ks_ref)``, this head's query part
     against the key part all heads share (:func:`_scores`)."""
     bq, d = q_ref.shape
     bk = k_ref.shape[0]
@@ -490,48 +505,6 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
             shared[2][:] = shared[3][:].astype(shared[2].dtype)
 
 
-def _dkv_resident_nogroup(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                          dk_ref, dv_ref, **kw):
-    """reps==1 wrapper: no scratch operands, so the pallas_call allocates
-    zero dead VMEM on exactly the variant whose dispatch is gated on VMEM
-    fit (the kernel's nreps==1 fast path never touches scratch)."""
-    _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref,
-                                   lse_ref, dk_ref, dv_ref, None, None,
-                                   **kw)
-
-
-def _dkv_resident_scratch(reps: int, block_k: int, d: int):
-    """(kernel_fn, scratch_shapes) for the resident dkv dispatch."""
-    if reps == 1:
-        return _dkv_resident_nogroup, []
-    return _flash_bwd_dkv_kernel_resident, [
-        pltpu.VMEM((block_k, d), jnp.float32),
-        pltpu.VMEM((block_k, d), jnp.float32)]
-
-
-def _fwd_scratch(block_q, d):
-    return [pltpu.VMEM((block_q, _LSE_LANES), jnp.float32),   # m
-            pltpu.VMEM((block_q, _LSE_LANES), jnp.float32),   # l
-            pltpu.VMEM((block_q, d), jnp.float32)]            # acc
-
-
-def _kv_head_of(h: int, hkv: int):
-    """Zero-copy GQA (VERDICT r4 next-step #5): map the flattened (batch,
-    query-head) grid index onto the (batch, kv-head) K/V array — query head
-    hq reads kv head hq·hkv//h. No repeated K/V ever materializes; with
-    h == hkv this is the identity."""
-    reps = h // hkv
-    if reps == 1:
-        return lambda g: g
-    return lambda g: (g // h) * hkv + (g % h) // reps
-
-
-def _lane_of(reps: int):
-    """Packed-layout head→kv-lane-block map; identity when reps == 1 so
-    the MHA path keeps div-free index maps."""
-    if reps == 1:
-        return lambda h: h
-    return lambda h: h // reps
 
 
 def _windowed_k(nq, nk, bq, bk, window):
@@ -549,137 +522,21 @@ def _windowed_k(nq, nk, bq, bk, window):
 
 def _windowed_q(nq, nk, bq, bk, window):
     """(per-head extent of the q sweep, (k-block, position) -> q-block)
-    of the k-major dk/dv grid under a window: from the diagonal to the
-    last q-block that sees the k-block."""
+    of the k-major dk/dv grid. Without a window every q-block and the
+    identity; with one, from the diagonal to the last q-block that sees
+    the k-block."""
+    if window is None:
+        return nq, lambda j, x: x
     _, qspan = _window_spans(nq, nk, bq, bk, window)
     return qspan, lambda j, x: jnp.minimum((j * bk) // bq + x, nq - 1)
 
 
-def _flash_forward_streamed(q, k, v, causal, scale, block_q, block_k, interpret,
-                            kv_len=None, window=None):
-    b, h, t, d = q.shape
-    hkv, tk = k.shape[1], k.shape[2]
-    kv_of = _kv_head_of(h, hkv)
-    nkw, kblk = _windowed_k(pl.cdiv(t, block_q), pl.cdiv(tk, block_k),
-                            block_q, block_k, window)
-    grid = (b * h, pl.cdiv(t, block_q), nkw)
-    qr = q.reshape(b * h, t, d)
-    kr = k.reshape(b * hkv, tk, d)
-    vr = v.reshape(b * hkv, tk, d)
-    kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
-                               kv_len=kv_len, window=window)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda g, i, kb: (g, i, 0)),
-            pl.BlockSpec((None, block_k, d),
-                         lambda g, i, kb: (kv_of(g), kblk(i, kb), 0)),
-            pl.BlockSpec((None, block_k, d),
-                         lambda g, i, kb: (kv_of(g), kblk(i, kb), 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((None, block_q, d), lambda g, i, kb: (g, i, 0)),
-            pl.BlockSpec((None, block_q, _LSE_LANES),
-                         lambda g, i, kb: (g, i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, t, _LSE_LANES), jnp.float32),
-        ),
-        scratch_shapes=_fwd_scratch(block_q, d),
-        interpret=interpret,
-        cost_estimate=pl.CostEstimate(
-            flops=4 * b * h * t * tk * d // (2 if causal else 1),
-            bytes_accessed=(qr.size + kr.size + vr.size) * q.dtype.itemsize,
-            transcendentals=b * h * t * tk),
-    )(qr, kr, vr)
-    return out.reshape(b, h, t, d), lse   # lse: [b·h, t, _LSE_LANES]
-
-
-def _flash_backward_streamed(q, k, v, do, o, lse, causal, scale, blocks,
-                             interpret, kv_len=None, window=None):
-    b, h, t, d = q.shape
-    hkv, tk = k.shape[1], k.shape[2]
-    reps = h // hkv
-    kv_of = _kv_head_of(h, hkv)
-    bh = b * h
-    block_q, block_k = blocks.dq
-    nkw, kblk = _windowed_k(pl.cdiv(t, block_q), pl.cdiv(tk, block_k),
-                            block_q, block_k, window)
-    qr = q.reshape(bh, t, d)
-    kr, vr = k.reshape(b * hkv, tk, d), v.reshape(b * hkv, tk, d)
-    dor, outr = do.reshape(bh, t, d), o.reshape(bh, t, d)
-    lser = lse                                    # [bh, t, _LSE_LANES]
-    # dq grid: (bh, qi, kb) — k streamed innermost (q-side blocks pinned).
-    q_pin = pl.BlockSpec((None, block_q, d), lambda g, i, kb: (g, i, 0))
-    k_str = pl.BlockSpec((None, block_k, d),
-                         lambda g, i, kb: (kv_of(g), kblk(i, kb), 0))
-    lse_pin = pl.BlockSpec((None, block_q, _LSE_LANES),
-                           lambda g, i, kb: (g, i, 0))
-
-    with jax.named_scope(_scope("attn_bwd_dq", window)):
-        dq = pl.pallas_call(
-            functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
-                              kv_len=kv_len, window=window),
-            grid=(bh, pl.cdiv(t, block_q), nkw),
-            in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin],
-            out_specs=q_pin,
-            out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            interpret=interpret,
-        )(qr, kr, vr, dor, outr, lser)
-
-    # dkv grid: (b·hkv, kj, qx) — qx is the flattened (rep, q-block) sweep
-    # (k-blocks pinned; dk/dv accumulate across ALL query heads this kv
-    # head serves). reps==1 keeps the original identity maps (no per-step
-    # div/mod in the index computation).
-    block_q, block_k = blocks.dkv
-    nqb = pl.cdiv(t, block_q)
-    nq_all = nqb
-    if window is not None:
-        # per-head sweep = the q-blocks that can see a k-block
-        nqb, qblk = _windowed_q(nq_all, pl.cdiv(tk, block_k), block_q,
-                                block_k, window)
-
-    def q_head(g, qx):
-        return (g // hkv) * h + (g % hkv) * reps + qx // nqb
-
-    k_pin = pl.BlockSpec((None, block_k, d), lambda g, j, qx: (g, j, 0))
-    if window is not None:
-        q_str = pl.BlockSpec(
-            (None, block_q, d),
-            lambda g, j, qx: (q_head(g, qx), qblk(j, qx % nqb), 0))
-        lse_str = pl.BlockSpec(
-            (None, block_q, _LSE_LANES),
-            lambda g, j, qx: (q_head(g, qx), qblk(j, qx % nqb), 0))
-    elif reps == 1:
-        q_str = pl.BlockSpec((None, block_q, d),
-                             lambda g, j, qx: (g, qx, 0))
-        lse_str = pl.BlockSpec((None, block_q, _LSE_LANES),
-                               lambda g, j, qx: (g, qx, 0))
-    else:
-        q_str = pl.BlockSpec((None, block_q, d),
-                             lambda g, j, qx: (q_head(g, qx), qx % nqb, 0))
-        lse_str = pl.BlockSpec((None, block_q, _LSE_LANES),
-                               lambda g, j, qx: (q_head(g, qx), qx % nqb, 0))
-
-    with jax.named_scope(_scope("attn_bwd_dkv", window)):
-        dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
-                              nqb=nqb if reps > 1 or window else 0,
-                              kv_len=kv_len, window=window, nq=nq_all),
-            grid=(b * hkv, pl.cdiv(tk, block_k), reps * nqb),
-            in_specs=[q_str, k_pin, k_pin, q_str, q_str, lse_str],
-            out_specs=(k_pin, k_pin),
-            out_shape=(jax.ShapeDtypeStruct((b * hkv, tk, d), k.dtype),
-                       jax.ShapeDtypeStruct((b * hkv, tk, d), v.dtype)),
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
-            interpret=interpret,
-        )(qr, kr, vr, dor, outr, lser)
-    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
-
+def _lane_of(reps: int):
+    """Packed-layout head -> kv-lane-block map; identity when reps == 1 so
+    the MHA path keeps div-free index maps."""
+    if reps == 1:
+        return lambda h: h
+    return lambda h: h // reps
 
 
 # --------------------------------------------------------------------
@@ -687,8 +544,7 @@ def _flash_backward_streamed(q, k, v, do, o, lse, causal, scale, blocks,
 # VMEM and the kernel loops k-blocks internally, letting causal grids
 # skip above-diagonal blocks from the SCHEDULE (not just the compute)
 # — measured ~7% faster than the streamed kernels at bench shapes.
-# Only legal while K/V fit VMEM; _RESIDENT_MAX_T gates the dispatch
-# (t=8192 OOMs v5e VMEM, t=4096 fits with headroom).
+# Only legal while K/V fit VMEM: ``_resident_fits`` gates the dispatch.
 # --------------------------------------------------------------------
 
 def _flash_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
@@ -698,9 +554,7 @@ def _flash_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     """One grid cell: q-block [Bq, D] against the full K/V [T, D] in VMEM,
     streamed in block_k chunks through the online-softmax recurrence. Also
     writes the log-sum-exp rows the backward kernels reconstruct p from.
-    ``qi_axis`` is which grid axis carries the q-block index (1 for the
-    [B·H, T, D] layout's (bh, i) grid, 2 for the packed [B, T, H·D]
-    layout's (b, h, i) grid)."""
+    ``qi_axis``: the grid axis of the q-block index, the layout's ``axis``."""
     bq, d = q_ref.shape
     t = k_ref.shape[0]
     qi = pl.program_id(qi_axis)
@@ -750,7 +604,7 @@ def _flash_bwd_dq_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, d
 
 
 def _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                          dk_ref, dv_ref, dk_scr, dv_scr, *, block_q: int,
+                          dk_ref, dv_ref, *scratch, block_q: int,
                           causal: bool, scale: float, qi_axis: int = 1,
                           kv_len: Optional[int] = None,
                           window: Optional[int] = None):
@@ -759,8 +613,10 @@ def _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
     GQA: the grid carries a ``rep`` axis INSIDE the k-block axis (size 1
     without grouping); each rep step streams in one of the query heads this
-    kv head serves, and dk/dv accumulate in VMEM scratch across the sweep,
-    flushing on the last rep."""
+    kv head serves, and dk/dv accumulate across the sweep in ``scratch``
+    (two float32 k-blocks), flushing on the last rep. Without grouping the
+    call passes no scratch: the one-rep path never touches it, and this is
+    the variant whose dispatch is gated on VMEM fit."""
     bk, d = k_ref.shape
     t = q_ref.shape[0]
     kj = pl.program_id(qi_axis)
@@ -768,6 +624,8 @@ def _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     nreps = pl.num_programs(qi_axis + 1)   # static (grid is static)
 
     if nreps > 1:
+        dk_scr, dv_scr = scratch
+
         @pl.when(rep == 0)
         def _init():
             dk_scr[:] = jnp.zeros_like(dk_scr)
@@ -788,8 +646,8 @@ def _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
     if nreps == 1:
         # MHA / reps==1 fast path: register accumulation, one flush — no
-        # scratch round-trips (measured ~4 MFU pts on the r5 LLM bench
-        # when the grouped path ran unconditionally).
+        # scratch round-trips (measured ~4 MFU points of a Llama train
+        # step when the grouped path ran unconditionally).
         zeros = jnp.zeros((bk, d), jnp.float32)
         dk, dv = jax.lax.fori_loop(qb0, num_qb, body, (zeros, zeros))
         dk_ref[:] = dk.astype(dk_ref.dtype)
@@ -807,104 +665,9 @@ def _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _flash_forward_resident(q, k, v, causal, scale, block_q, block_k, interpret,
-                            kv_len=None, window=None):
-    b, h, t, d = q.shape
-    hkv, tk = k.shape[1], k.shape[2]
-    kv_of = _kv_head_of(h, hkv)
-    grid = (b * h, pl.cdiv(t, block_q))
-    qr = q.reshape(b * h, t, d)
-    kr = k.reshape(b * hkv, tk, d)
-    vr = v.reshape(b * hkv, tk, d)
-    kernel = functools.partial(_flash_kernel_resident, block_k=block_k,
-                               causal=causal, scale=scale, kv_len=kv_len,
-                               window=window)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((None, tk, d), lambda bh, i: (kv_of(bh), 0, 0)),
-            pl.BlockSpec((None, tk, d), lambda bh, i: (kv_of(bh), 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((None, block_q, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((None, block_q, _LSE_LANES), lambda bh, i: (bh, i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, t, _LSE_LANES), jnp.float32),
-        ),
-        interpret=interpret,
-        cost_estimate=pl.CostEstimate(
-            flops=4 * b * h * t * tk * d // (2 if causal else 1),
-            bytes_accessed=(qr.size + kr.size + vr.size) * q.dtype.itemsize,
-            transcendentals=b * h * t * tk),
-    )(qr, kr, vr)
-    return out.reshape(b, h, t, d), lse   # lse: [b·h, t, _LSE_LANES]
-
-
-def _flash_backward_resident(q, k, v, do, o, lse, causal, scale, blocks,
-                             interpret, kv_len=None, window=None):
-    b, h, t, d = q.shape
-    hkv, tk = k.shape[1], k.shape[2]
-    reps = h // hkv
-    kv_of = _kv_head_of(h, hkv)
-    bh = b * h
-    block_q, block_k = blocks.dq
-    qr = q.reshape(bh, t, d)
-    kr, vr = k.reshape(b * hkv, tk, d), v.reshape(b * hkv, tk, d)
-    dor, outr = do.reshape(bh, t, d), o.reshape(bh, t, d)
-    lser = lse                                    # [bh, t, _LSE_LANES]
-    q_spec = pl.BlockSpec((None, block_q, d), lambda g, i: (g, i, 0))
-    kv_full = pl.BlockSpec((None, tk, d), lambda g, i: (kv_of(g), 0, 0))
-    lse_blk = pl.BlockSpec((None, block_q, _LSE_LANES), lambda g, i: (g, i, 0))
-
-    with jax.named_scope(_scope("attn_bwd_dq", window)):
-        dq = pl.pallas_call(
-            functools.partial(_flash_bwd_dq_kernel_resident, block_k=block_k,
-                              causal=causal, scale=scale, kv_len=kv_len,
-                              window=window),
-            grid=(bh, pl.cdiv(t, block_q)),
-            in_specs=[q_spec, kv_full, kv_full, q_spec, q_spec, lse_blk],
-            out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            interpret=interpret,
-        )(qr, kr, vr, dor, outr, lser)
-
-    # dkv grid: (b·hkv, kj, rep) — rep streams in, one at a time, the query
-    # heads this kv head serves; dk/dv accumulate in scratch across them.
-    block_q, block_k = blocks.dkv
-
-    def q_head(g, r):
-        return (g // hkv) * h + (g % hkv) * reps + r
-
-    q_full = pl.BlockSpec((None, t, d), lambda g, j, r: (q_head(g, r), 0, 0))
-    lse_full = pl.BlockSpec((None, t, _LSE_LANES),
-                            lambda g, j, r: (q_head(g, r), 0, 0))
-    k_spec = pl.BlockSpec((None, block_k, d), lambda g, j, r: (g, j, 0))
-
-    dkv_kernel, dkv_scratch = _dkv_resident_scratch(reps, block_k, d)
-    with jax.named_scope(_scope("attn_bwd_dkv", window)):
-        dk, dv = pl.pallas_call(
-            functools.partial(dkv_kernel, block_q=block_q,
-                              causal=causal, scale=scale, kv_len=kv_len,
-                              window=window),
-            grid=(b * hkv, pl.cdiv(tk, block_k), reps),
-            in_specs=[q_full, k_spec, k_spec, q_full, q_full, lse_full],
-            out_specs=(k_spec, k_spec),
-            out_shape=(jax.ShapeDtypeStruct((b * hkv, tk, d), k.dtype),
-                       jax.ShapeDtypeStruct((b * hkv, tk, d), v.dtype)),
-            scratch_shapes=dkv_scratch,
-            interpret=interpret,
-        )(qr, kr, vr, dor, outr, lser)
-    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
-
-
-
 # One (batch, head)'s K/V must fit VMEM for the resident variants. The
 # budget is in BYTES, not sequence length: VMEM use scales with
-# tk·d·itemsize, so a fixed max-T gate (round 3) would OOM below it for
+# tk·d·itemsize, so a gate on the length alone would OOM below it for
 # head_dim>128 or f32 inputs. Calibrated on v5e at the measured boundary —
 # t=4096·d=128·bf16 (1 MiB per tensor) fits with headroom, t=8192 OOMs.
 _RESIDENT_KV_BYTES = 4096 * 128 * 2
@@ -914,139 +677,388 @@ def _resident_fits(tk: int, d: int, itemsize: int) -> bool:
     return tk * d * itemsize <= _RESIDENT_KV_BYTES
 
 
-def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   kv_len=None, window=None):
-    if _resident_fits(k.shape[2], k.shape[3], k.dtype.itemsize):
-        return _flash_forward_resident(q, k, v, causal, scale, block_q,
-                                       block_k, interpret, kv_len, window)
-    return _flash_forward_streamed(q, k, v, causal, scale, block_q,
-                                   block_k, interpret, kv_len, window)
+# --------------------------------------------------------------------
+# One description of a flash grid, two builders. Everything between an
+# entry and ``pallas_call`` — block specs, index maps, grids, scratch, out
+# shapes, the GQA sweep of dk/dv, a window's spans — is written once,
+# against a LAYOUT, a VARIANT and the call's statics (``_Grid``). The
+# STREAMED builder walks the k-side (forward, dq) or the q-side (dk/dv)
+# with the grid's last axis; the RESIDENT one holds it whole in VMEM.
+# --------------------------------------------------------------------
 
 
-def _flash_backward(q, k, v, do, o, lse, causal, scale, blocks,
-                    interpret, kv_len=None, window=None):
-    if _resident_fits(k.shape[2], k.shape[3], k.dtype.itemsize):
-        return _flash_backward_resident(q, k, v, do, o, lse, causal, scale,
-                                        blocks, interpret, kv_len, window)
-    return _flash_backward_streamed(q, k, v, do, o, lse, causal, scale,
-                                    blocks, interpret, kv_len, window)
+def _zero(*ids):
+    return 0
 
 
-def _flash_forward_packed(q, k, v, heads, causal, scale, block_q, block_k,
-                          interpret, window=None):
-    if _resident_fits(k.shape[1], q.shape[2] // heads, k.dtype.itemsize):
-        return _flash_forward_packed_resident(q, k, v, heads, causal, scale,
-                                              block_q, block_k, interpret,
-                                              window)
-    return _flash_forward_packed_streamed(q, k, v, heads, causal, scale,
-                                          block_q, block_k, interpret,
-                                          window)
+class _Layout(NamedTuple):
+    """How the grids address a ``(rows, head)`` block of their operands.
+
+    classic — ``[B, H, T, D]`` viewed ``[B·H, T, D]``: a head is a leading
+    index, and a grid has ONE axis of ``B·heads`` cells before its block
+    axes. packed — ``[B, T, H·D]``: a head is a lane block (its number the
+    block index of the minor dimension, so ``D % 128 == 0``) and a grid
+    starts ``(b, head)``; no ``[B, H, T, D]`` transpose ever materializes.
+    A rows-per-head operand (the log-sum-exp, MLA's ``qs``) is ``[B·H, T,
+    lanes]`` / ``[B, H, T, lanes]``.
+
+    GQA is zero-copy in both: K/V carry ``hkv = h / reps`` heads; a q-major
+    cell stands on a query head and reads :meth:`kv_head` of it, a k-major
+    cell stands on a kv head and sweeps the query heads :meth:`q_head`
+    names, so no repeated K/V ever materializes in HBM. The spec makers
+    take ``head`` and ``row`` as functions of a cell's grid ids and call
+    them in the order the block index lists them."""
+    packed: bool
+    b: int
+    h: int
+    hkv: int
+    t: int
+    tk: int
+    d: int
+
+    @property
+    def reps(self) -> int:
+        return self.h // self.hkv
+
+    @property
+    def axis(self) -> int:
+        """The first block axis of a grid, after the (batch, head) cells:
+        the kernels' ``qi_axis``."""
+        return 2 if self.packed else 1
+
+    def cells(self, heads: int) -> tuple:
+        """Leading grid extents: one cell a (batch, head)."""
+        return (self.b, heads) if self.packed else (self.b * heads,)
+
+    def own(self, *ids):
+        """The head a grid cell stands on (classic: over the batch)."""
+        return ids[self.axis - 1]
+
+    def kv_head(self, *ids):
+        """The kv head that serves a q-major cell's query head; without
+        grouping the identity, so MHA keeps div-free index maps."""
+        g = self.own(*ids)
+        if self.packed or self.reps == 1:
+            return _lane_of(self.reps)(g)
+        return (g // self.h) * self.hkv + (g % self.h) // self.reps
+
+    def q_head(self, rep):
+        """``ids -> `` the query head number ``rep(*ids)`` of those a
+        k-major cell's kv head serves."""
+        def head(*ids):
+            g = self.own(*ids)
+            if self.packed:
+                return g * self.reps + rep(*ids)
+            return ((g // self.hkv) * self.h + (g % self.hkv) * self.reps
+                    + rep(*ids))
+        return head
+
+    def rows(self, n: int, head, row, width: Optional[int] = None):
+        """Spec of ``n`` rows of one head of a q- or kv-like operand
+        (``width`` lanes a head, ``d`` unless named; ``head=_zero`` with a
+        ``width``: an operand all heads share, ``[B, T, width]``)."""
+        block = (None, n, self.d if width is None else width)
+        if self.packed:
+            return pl.BlockSpec(
+                block, lambda *ids: (ids[0], row(*ids), head(*ids)))
+        return pl.BlockSpec(block, lambda *ids: (head(*ids), row(*ids), 0))
+
+    def per_head(self, n: int, head, row, lanes: int = _LSE_LANES):
+        """Spec of ``n`` rows of one head of a rows-per-head operand. The
+        log-sum-exp is the default: ``_LSE_LANES`` equal lanes a row."""
+        if self.packed:
+            return pl.BlockSpec(
+                (None, None, n, lanes),
+                lambda *ids: (ids[0], head(*ids), row(*ids), 0))
+        return pl.BlockSpec((None, n, lanes),
+                            lambda *ids: (head(*ids), row(*ids), 0))
+
+    def per_head_f32(self, lanes: int = _LSE_LANES):
+        """A float32 rows-per-head result over the queries (the lse)."""
+        shape = (self.b, self.h) if self.packed else (self.b * self.h,)
+        return jax.ShapeDtypeStruct((*shape, self.t, lanes), jnp.float32)
 
 
-def _flash_backward_packed(q, k, v, do, o, lse, heads, causal, scale,
-                           blocks, interpret, window=None):
-    if _resident_fits(k.shape[1], q.shape[2] // heads, k.dtype.itemsize):
-        return _flash_backward_packed_resident(
-            q, k, v, do, o, lse, heads, causal, scale, blocks, interpret,
-            window)
-    return _flash_backward_packed_streamed(
-        q, k, v, do, o, lse, heads, causal, scale, blocks, interpret,
-        window)
+class _Sides(NamedTuple):
+    """A streamed grid as a variant's extra operands see it: the blocks,
+    and the query head, q-block and k-block a grid cell works on (functions
+    of its ids)."""
+    bq: int
+    bk: int
+    qhead: Callable
+    qrow: Callable
+    krow: Callable
 
 
-def _flash_forward_packed_resident(q, k, v, heads, causal, scale, block_q, block_k,
-                          interpret, window=None):
-    """Forward over the packed [B, T, H·D] layout: grid (b, h, i) with the
-    head carried as a lane offset (block index h on the last dim) — no
-    [B, H, T, D] transpose ever materializes. Same kernel body. GQA: K/V
-    are packed [B, T, Hkv·D]; query head h reads kv lane-block h·hkv//h."""
-    b, t, hd = q.shape
-    tk = k.shape[1]
-    d = hd // heads
-    reps = hd // k.shape[2]
-    lane = _lane_of(reps)
-    grid = (b, heads, pl.cdiv(t, block_q))
-    kernel = functools.partial(_flash_kernel_resident, block_k=block_k,
-                               causal=causal, scale=scale, qi_axis=2,
-                               window=window)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bi, h, i: (bi, i, h)),
-            pl.BlockSpec((None, tk, d), lambda bi, h, i: (bi, 0, lane(h))),
-            pl.BlockSpec((None, tk, d), lambda bi, h, i: (bi, 0, lane(h))),
-        ],
-        out_specs=(
-            pl.BlockSpec((None, block_q, d), lambda bi, h, i: (bi, i, h)),
-            pl.BlockSpec((None, None, block_q, _LSE_LANES),
-                         lambda bi, h, i: (bi, h, i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, heads, t, _LSE_LANES), jnp.float32),
-        ),
-        interpret=interpret,
-        cost_estimate=pl.CostEstimate(
-            flops=4 * b * heads * t * tk * d // (2 if causal else 1),
+_NONE = ([], [], [])
+
+
+class _Variant(NamedTuple):
+    """What a family adds to the three streamed calls, as data. Its extra
+    operands follow the base ones into every call, an extra result of dq
+    or dk/dv follows the base results and brings one float32 scratch.
+
+    ``tag`` ends the scope names; ``take`` turns a call's extra refs (ins,
+    outs, scratch) into the keywords the kernel bodies take them by;
+    ``specs(lay, sides, *extras)`` gives, under either grid, the extra
+    operands' specs and the extra results of dq and of dk/dv, each as
+    ``(specs, shapes, scratch)``. ``cost``: whether the forward carries a
+    cost estimate — the selected and MLA calls never did (ROADMAP D18),
+    and the estimate is part of the program a cell traces."""
+    tag: str = ""
+    take: Optional[Callable] = None
+    specs: Callable = lambda lay, sides: ([], _NONE, _NONE)
+    cost: bool = False
+
+
+_PLAIN = _Variant(cost=True)
+
+
+def _extras_kernel(body, take, n_in, n_x, n_out, n_dx, *refs, **kw):
+    """The one adapter between a variant's call and a kernel body. The call
+    lists base ins, extra ins, base outs, extra outs, base scratch, one
+    scratch an extra out; the body takes the base refs by position and the
+    extras by ``take``'s keywords."""
+    cuts = [0, *itertools.accumulate((n_in, n_x, n_out, n_dx)),
+            len(refs) - n_dx, len(refs)]
+    ins, xs, outs, dxs, scr, xscr = (
+        refs[a:b] for a, b in zip(cuts, cuts[1:]))
+    body(*ins, *outs, *scr, **take(*xs, *dxs, *xscr), **kw)
+
+
+class _Grid(NamedTuple):
+    """One attention as the builders see it: layout, variant, and the
+    statics of the call."""
+    lay: _Layout
+    var: _Variant
+    causal: bool
+    scale: float
+    blocks: Blocks
+    interpret: bool
+    kv_len: Optional[int] = None
+    window: Optional[int] = None
+
+    def scope(self, name: str) -> str:
+        return _scope(name, self.window) + self.var.tag
+
+    def kernel(self, body, n_in: int = 0, n_out: int = 0, extras=(),
+               n_dx: int = 0, **kw):
+        """``body`` with the call's statics bound — where the variant brings
+        extra refs, behind the adapter, told how many refs of each kind."""
+        kw.update(causal=self.causal, scale=self.scale,
+                  qi_axis=self.lay.axis, kv_len=self.kv_len,
+                  window=self.window)
+        if self.var.take is None:
+            return functools.partial(body, **kw)
+        return functools.partial(_extras_kernel, body, self.var.take, n_in,
+                                 len(extras), n_out, n_dx, **kw)
+
+    def cost(self, q, k, v):
+        if not self.var.cost:
+            return None
+        pairs = self.lay.b * self.lay.h * self.lay.t * self.lay.tk
+        return pl.CostEstimate(
+            flops=4 * pairs * self.lay.d // (2 if self.causal else 1),
             bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
-            transcendentals=b * heads * t * tk),
-    )(q, k, v)
-    return out, lse
+            transcendentals=pairs)
 
 
-def _flash_backward_packed_resident(q, k, v, do, o, lse, heads, causal, scale,
-                                    blocks, interpret, window=None):
-    b, t, hd = q.shape
-    tk = k.shape[1]
-    d = hd // heads
-    hkv = k.shape[2] // d
-    reps = heads // hkv
-    lane = _lane_of(reps)
-    block_q, block_k = blocks.dq
-    q_spec = pl.BlockSpec((None, block_q, d), lambda bi, h, i: (bi, i, h))
-    kv_full = pl.BlockSpec((None, tk, d),
-                           lambda bi, h, i: (bi, 0, lane(h)))
-    lse_blk = pl.BlockSpec((None, None, block_q, _LSE_LANES),
-                           lambda bi, h, i: (bi, h, i, 0))
+def _like(x):
+    return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
-    with jax.named_scope(_scope("attn_bwd_dq", window)):
+
+def _q_major(g: _Grid, bq: int, bk: Optional[int] = None):
+    """The q-major grid (forward, dq), each cell a pinned q-block of one
+    query head: ``(grid, q-side spec, k-side spec, lse spec, sides)``.
+    Streamed, a last axis walks the k-blocks — under a window only the
+    span a q-block sees (``_windowed_k``). Resident (``bk=None``) there is
+    no such axis: the kv head's whole K/V is one block."""
+    lay = g.lay
+    nq = pl.cdiv(lay.t, bq)
+
+    def qrow(*ids):
+        return ids[lay.axis]
+
+    if bk is None:
+        inner, krows, krow = (), lay.tk, _zero
+    else:
+        nkw, kblk = _windowed_k(nq, pl.cdiv(lay.tk, bk), bq, bk, g.window)
+        inner, krows = (nkw,), bk
+
+        def krow(*ids):
+            return kblk(ids[lay.axis], ids[lay.axis + 1])
+
+    return ((*lay.cells(lay.h), nq, *inner), lay.rows(bq, lay.own, qrow),
+            lay.rows(krows, lay.kv_head, krow),
+            lay.per_head(bq, lay.own, qrow),
+            _Sides(bq, bk, lay.own, qrow, krow))
+
+
+def _streamed_forward(g: _Grid, q, k, v, *extras):
+    """``(out, lse)`` of the streamed forward."""
+    lay = g.lay
+    bq, bk = g.blocks.fwd
+    grid, q_pin, k_str, lse_pin, sides = _q_major(g, bq, bk)
+    with jax.named_scope(g.scope("attn_fwd")):
+        return pl.pallas_call(
+            g.kernel(_flash_kernel, 3, 2, extras),
+            grid=grid,
+            in_specs=[q_pin, k_str, k_str,
+                      *g.var.specs(lay, sides, *extras)[0]],
+            out_specs=(q_pin, lse_pin),
+            out_shape=(_like(q), lay.per_head_f32()),
+            scratch_shapes=[
+                pltpu.VMEM((bq, _LSE_LANES), jnp.float32),   # m
+                pltpu.VMEM((bq, _LSE_LANES), jnp.float32),   # l
+                pltpu.VMEM((bq, lay.d), jnp.float32)],       # acc
+            interpret=g.interpret,
+            cost_estimate=g.cost(q, k, v),
+        )(q, k, v, *extras)
+
+
+def _streamed_backward(g: _Grid, q, k, v, do, o, lse, *extras):
+    """``(dq, dk, dv, extra results of dq, extra results of dk/dv)``,
+    streamed."""
+    lay, var = g.lay, g.var
+    operands = (q, k, v, do, o, lse, *extras)
+    bq, bk = g.blocks.dq
+    grid, q_pin, k_str, lse_pin, sides = _q_major(g, bq, bk)
+    xins, (xspecs, xshapes, xscratch), _ = var.specs(lay, sides, *extras)
+    with jax.named_scope(g.scope("attn_bwd_dq")):
+        dq, *dxq = pl.pallas_call(
+            g.kernel(_flash_bwd_dq_kernel, 6, 1, extras, len(xspecs)),
+            grid=grid,
+            in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin, *xins],
+            out_specs=[q_pin, *xspecs],
+            out_shape=[_like(q), *xshapes],
+            scratch_shapes=[pltpu.VMEM((bq, lay.d), jnp.float32), *xscratch],
+            interpret=g.interpret,
+        )(*operands)
+
+    # dk/dv grid: (cells of kv heads, kj, qx) — the q-side walked
+    # innermost. qx is the flattened (rep, q-block) sweep over every query
+    # head the kv head serves, under a window only the q-blocks that see
+    # the k-block; dk/dv accumulate across all of it. Without grouping or
+    # window the sweep is the q-blocks themselves and the maps stay free
+    # of div and mod.
+    bq, bk = g.blocks.dkv
+    nq, nk = pl.cdiv(lay.t, bq), pl.cdiv(lay.tk, bk)
+
+    def krow(*ids):
+        return ids[lay.axis]
+
+    def qx(*ids):
+        return ids[lay.axis + 1]
+
+    plain_sweep = lay.reps == 1 and g.window is None
+    if plain_sweep:
+        nqb, qhead, qrow = nq, lay.own, qx
+    else:
+        nqb, qblk = _windowed_q(nq, nk, bq, bk, g.window)
+        qhead = lay.q_head(lambda *ids: qx(*ids) // nqb)
+
+        def qrow(*ids):
+            return qblk(krow(*ids), qx(*ids) % nqb)
+
+    sides = _Sides(bq, bk, qhead, qrow, krow)
+    q_str = lay.rows(bq, qhead, qrow)
+    k_pin = lay.rows(bk, lay.own, krow)
+    xins, _, (xspecs, xshapes, xscratch) = var.specs(lay, sides, *extras)
+    with jax.named_scope(g.scope("attn_bwd_dkv")):
+        dk, dv, *dxk = pl.pallas_call(
+            g.kernel(_flash_bwd_dkv_kernel, 6, 2, extras, len(xspecs),
+                     nqb=0 if plain_sweep else nqb, nq=nq),
+            grid=(*lay.cells(lay.hkv), nk, lay.reps * nqb),
+            in_specs=[q_str, k_pin, k_pin, q_str, q_str,
+                      lay.per_head(bq, qhead, qrow), *xins],
+            out_specs=[k_pin, k_pin, *xspecs],
+            out_shape=[_like(k), _like(v), *xshapes],
+            scratch_shapes=[pltpu.VMEM((bk, lay.d), jnp.float32),
+                            pltpu.VMEM((bk, lay.d), jnp.float32), *xscratch],
+            interpret=g.interpret,
+        )(*operands)
+    return dq, dk, dv, dxq, dxk
+
+
+def _resident_forward(g: _Grid, q, k, v):
+    """``(out, lse)`` of the resident forward."""
+    bq, bk = g.blocks.fwd
+    grid, q_blk, kv_all, lse_blk, _ = _q_major(g, bq)
+    with jax.named_scope(g.scope("attn_fwd")):
+        return pl.pallas_call(
+            g.kernel(_flash_kernel_resident, block_k=bk),
+            grid=grid,
+            in_specs=[q_blk, kv_all, kv_all],
+            out_specs=(q_blk, lse_blk),
+            out_shape=(_like(q), g.lay.per_head_f32()),
+            interpret=g.interpret,
+            cost_estimate=g.cost(q, k, v),
+        )(q, k, v)
+
+
+def _resident_backward(g: _Grid, q, k, v, do, o, lse):
+    """``(dq, dk, dv)``, resident."""
+    lay = g.lay
+    bq, bk = g.blocks.dq
+    grid, q_blk, kv_all, lse_blk, _ = _q_major(g, bq)
+    with jax.named_scope(g.scope("attn_bwd_dq")):
         dq = pl.pallas_call(
-            functools.partial(_flash_bwd_dq_kernel_resident, block_k=block_k,
-                              causal=causal, scale=scale, qi_axis=2,
-                              window=window),
-            grid=(b, heads, pl.cdiv(t, block_q)),
-            in_specs=[q_spec, kv_full, kv_full, q_spec, q_spec, lse_blk],
-            out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-            interpret=interpret,
+            g.kernel(_flash_bwd_dq_kernel_resident, block_k=bk),
+            grid=grid,
+            in_specs=[q_blk, kv_all, kv_all, q_blk, q_blk, lse_blk],
+            out_specs=q_blk,
+            out_shape=_like(q),
+            interpret=g.interpret,
         )(q, k, v, do, o, lse)
 
-    # dkv grid: (b, hkv, kj, rep) — rep streams the query heads this kv
-    # head serves; dk/dv accumulate in scratch (see the kernel docstring).
-    block_q, block_k = blocks.dkv
-    q_full = pl.BlockSpec((None, t, d),
-                          lambda bi, hk, j, r: (bi, 0, hk * reps + r))
-    lse_full = pl.BlockSpec((None, None, t, _LSE_LANES),
-                            lambda bi, hk, j, r: (bi, hk * reps + r, 0, 0))
-    k_spec = pl.BlockSpec((None, block_k, d),
-                          lambda bi, hk, j, r: (bi, j, hk))
-
-    dkv_kernel, dkv_scratch = _dkv_resident_scratch(reps, block_k, d)
-    with jax.named_scope(_scope("attn_bwd_dkv", window)):
+    # dk/dv grid: (cells of kv heads, kj, rep) — rep brings in, one at a
+    # time and whole, the query heads the kv head serves; dk/dv accumulate
+    # in scratch across them (none without grouping: see the kernel).
+    bq, bk = g.blocks.dkv
+    qhead = lay.q_head(lambda *ids: ids[lay.axis + 1])
+    q_all = lay.rows(lay.t, qhead, _zero)
+    k_blk = lay.rows(bk, lay.own, lambda *ids: ids[lay.axis])
+    with jax.named_scope(g.scope("attn_bwd_dkv")):
         dk, dv = pl.pallas_call(
-            functools.partial(dkv_kernel, block_q=block_q,
-                              causal=causal, scale=scale, qi_axis=2,
-                              window=window),
-            grid=(b, hkv, pl.cdiv(tk, block_k), reps),
-            in_specs=[q_full, k_spec, k_spec, q_full, q_full, lse_full],
-            out_specs=(k_spec, k_spec),
-            out_shape=(jax.ShapeDtypeStruct((b, tk, hkv * d), k.dtype),
-                       jax.ShapeDtypeStruct((b, tk, hkv * d), v.dtype)),
-            scratch_shapes=dkv_scratch,
-            interpret=interpret,
+            g.kernel(_flash_bwd_dkv_kernel_resident, block_q=bq),
+            grid=(*lay.cells(lay.hkv), pl.cdiv(lay.tk, bk), lay.reps),
+            in_specs=[q_all, k_blk, k_blk, q_all, q_all,
+                      lay.per_head(lay.t, qhead, _zero)],
+            out_specs=(k_blk, k_blk),
+            out_shape=(_like(k), _like(v)),
+            scratch_shapes=[pltpu.VMEM((bk, lay.d), jnp.float32)] * 2
+            if lay.reps > 1 else [],
+            interpret=g.interpret,
         )(q, k, v, do, o, lse)
     return dq, dk, dv
+
+
+def _forward(g: _Grid, q, k, v):
+    if _resident_fits(g.lay.tk, g.lay.d, k.dtype.itemsize):
+        return _resident_forward(g, q, k, v)
+    return _streamed_forward(g, q, k, v)
+
+
+def _backward(g: _Grid, q, k, v, do, o, lse):
+    if _resident_fits(g.lay.tk, g.lay.d, k.dtype.itemsize):
+        return _resident_backward(g, q, k, v, do, o, lse)
+    return _streamed_backward(g, q, k, v, do, o, lse)[:3]
+
+
+def _named(out, lse):
+    """The two residuals of every flash forward under the names the
+    backward's keep-rule knows (:mod:`tony_tpu.remat`)."""
+    return remat.name(out, "flash_out"), remat.name(lse, "flash_lse")
+
+
+def _classic(q, k) -> _Layout:
+    b, h, t, d = q.shape
+    return _Layout(False, b, h, k.shape[1], t, k.shape[2], d)
+
+
+def _rows_of(x):
+    """``[B, H, T, D]`` as the classic grids read it: ``[B·H, T, D]``."""
+    return x.reshape(-1, *x.shape[2:])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -1058,147 +1070,29 @@ def _flash(q, k, v, causal, scale, blocks, interpret, kv_len=None,
 
 def _flash_fwd(q, k, v, causal, scale, blocks, interpret, kv_len=None,
                window=None):
-    with jax.named_scope(_scope("attn_fwd", window)):
-        out, lse = _flash_forward(q, k, v, causal, scale, *blocks.fwd,
-                                  interpret, kv_len, window)
-    out, lse = remat.name(out, "flash_out"), remat.name(lse, "flash_lse")
+    g = _Grid(_classic(q, k), _PLAIN, causal, scale, blocks, interpret,
+              kv_len, window)
+    out, lse = _forward(g, *map(_rows_of, (q, k, v)))
+    out, lse = _named(out.reshape(q.shape), lse)   # lse: [b·h, t, lanes]
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, scale, blocks, interpret, kv_len, window, residuals,
-               g):
+               do):
     q, k, v, out, lse = residuals
-    return _flash_backward(q, k, v, g, out, lse, causal, scale, blocks,
-                           interpret, kv_len, window)
+    g = _Grid(_classic(q, k), _PLAIN, causal, scale, blocks, interpret,
+              kv_len, window)
+    grads = _backward(g, *map(_rows_of, (q, k, v, do, out)), lse)
+    return tuple(dx.reshape(x.shape) for dx, x in zip(grads, (q, k, v)))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _flash_forward_packed_streamed(q, k, v, heads, causal, scale, block_q, block_k,
-                          interpret, window=None):
-    """Forward over the packed [B, T, H·D] layout: grid (b, h, i, kb) with
-    the head carried as a lane offset (block index h on the last dim) — no
-    [B, H, T, D] transpose ever materializes. Same streamed kernel body."""
+def _packed(q, k, heads: int) -> _Layout:
     b, t, hd = q.shape
-    tk = k.shape[1]
     d = hd // heads
-    reps = hd // k.shape[2]
-    lane = _lane_of(reps)
-    nkw, kblk = _windowed_k(pl.cdiv(t, block_q), pl.cdiv(tk, block_k),
-                            block_q, block_k, window)
-    grid = (b, heads, pl.cdiv(t, block_q), nkw)
-    kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
-                               qi_axis=2, window=window)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, d),
-                         lambda bi, h, i, kb: (bi, i, h)),
-            pl.BlockSpec((None, block_k, d),
-                         lambda bi, h, i, kb: (bi, kblk(i, kb), lane(h))),
-            pl.BlockSpec((None, block_k, d),
-                         lambda bi, h, i, kb: (bi, kblk(i, kb), lane(h))),
-        ],
-        out_specs=(
-            pl.BlockSpec((None, block_q, d),
-                         lambda bi, h, i, kb: (bi, i, h)),
-            pl.BlockSpec((None, None, block_q, _LSE_LANES),
-                         lambda bi, h, i, kb: (bi, h, i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, heads, t, _LSE_LANES), jnp.float32),
-        ),
-        scratch_shapes=_fwd_scratch(block_q, d),
-        interpret=interpret,
-        cost_estimate=pl.CostEstimate(
-            flops=4 * b * heads * t * tk * d // (2 if causal else 1),
-            bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
-            transcendentals=b * heads * t * tk),
-    )(q, k, v)
-    return out, lse
-
-
-def _flash_backward_packed_streamed(q, k, v, do, o, lse, heads, causal, scale,
-                                    blocks, interpret, window=None):
-    b, t, hd = q.shape
-    tk = k.shape[1]
-    d = hd // heads
-    hkv = k.shape[2] // d
-    reps = heads // hkv
-    block_q, block_k = blocks.dq
-    # dq grid: (b, h, qi, kb) — k streamed innermost.
-    q_pin = pl.BlockSpec((None, block_q, d),
-                         lambda bi, h, i, kb: (bi, i, h))
-    lane = _lane_of(reps)
-    nkw, kblk = _windowed_k(pl.cdiv(t, block_q), pl.cdiv(tk, block_k),
-                            block_q, block_k, window)
-    k_str = pl.BlockSpec((None, block_k, d),
-                         lambda bi, h, i, kb: (bi, kblk(i, kb), lane(h)))
-    lse_pin = pl.BlockSpec((None, None, block_q, _LSE_LANES),
-                           lambda bi, h, i, kb: (bi, h, i, 0))
-
-    with jax.named_scope(_scope("attn_bwd_dq", window)):
-        dq = pl.pallas_call(
-            functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
-                              qi_axis=2, window=window),
-            grid=(b, heads, pl.cdiv(t, block_q), nkw),
-            in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin],
-            out_specs=q_pin,
-            out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            interpret=interpret,
-        )(q, k, v, do, o, lse)
-
-    # dkv grid: (b, hkv, kj, qx) — qx flattens (rep, q-block), q-side
-    # streamed innermost; dk/dv accumulate across every query head this
-    # kv head serves. reps==1 keeps identity (div/mod-free) index maps.
-    block_q, block_k = blocks.dkv
-    nqb = pl.cdiv(t, block_q)
-    nq_all = nqb
-    if window is not None:
-        nqb, qblk = _windowed_q(nq_all, pl.cdiv(tk, block_k), block_q,
-                                block_k, window)
-    k_pin = pl.BlockSpec((None, block_k, d),
-                         lambda bi, hk, j, qx: (bi, j, hk))
-    if window is not None:
-        q_str = pl.BlockSpec(
-            (None, block_q, d), lambda bi, hk, j, qx:
-            (bi, qblk(j, qx % nqb), hk * reps + qx // nqb))
-        lse_str = pl.BlockSpec(
-            (None, None, block_q, _LSE_LANES), lambda bi, hk, j, qx:
-            (bi, hk * reps + qx // nqb, qblk(j, qx % nqb), 0))
-    elif reps == 1:
-        q_str = pl.BlockSpec((None, block_q, d),
-                             lambda bi, hk, j, qx: (bi, qx, hk))
-        lse_str = pl.BlockSpec((None, None, block_q, _LSE_LANES),
-                               lambda bi, hk, j, qx: (bi, hk, qx, 0))
-    else:
-        q_str = pl.BlockSpec((None, block_q, d),
-                             lambda bi, hk, j, qx:
-                             (bi, qx % nqb, hk * reps + qx // nqb))
-        lse_str = pl.BlockSpec((None, None, block_q, _LSE_LANES),
-                               lambda bi, hk, j, qx:
-                               (bi, hk * reps + qx // nqb, qx % nqb, 0))
-
-    with jax.named_scope(_scope("attn_bwd_dkv", window)):
-        dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
-                              qi_axis=2,
-                              nqb=nqb if reps > 1 or window else 0,
-                              window=window, nq=nq_all),
-            grid=(b, hkv, pl.cdiv(tk, block_k), reps * nqb),
-            in_specs=[q_str, k_pin, k_pin, q_str, q_str, lse_str],
-            out_specs=(k_pin, k_pin),
-            out_shape=(jax.ShapeDtypeStruct((b, tk, hkv * d), k.dtype),
-                       jax.ShapeDtypeStruct((b, tk, hkv * d), v.dtype)),
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
-            interpret=interpret,
-        )(q, k, v, do, o, lse)
-    return dq, dk, dv
+    return _Layout(True, b, heads, k.shape[2] // d, t, k.shape[1], d)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -1210,18 +1104,18 @@ def _flash_packed(q, k, v, heads, causal, scale, blocks, interpret,
 
 def _flash_packed_fwd(q, k, v, heads, causal, scale, blocks, interpret,
                       window=None):
-    with jax.named_scope(_scope("attn_fwd", window)):
-        out, lse = _flash_forward_packed(q, k, v, heads, causal, scale,
-                                         *blocks.fwd, interpret, window)
-    out, lse = remat.name(out, "flash_out"), remat.name(lse, "flash_lse")
+    g = _Grid(_packed(q, k, heads), _PLAIN, causal, scale, blocks,
+              interpret, None, window)
+    out, lse = _named(*_forward(g, q, k, v))
     return out, (q, k, v, out, lse)
 
 
 def _flash_packed_bwd(heads, causal, scale, blocks, interpret, window,
-                      residuals, g):
+                      residuals, do):
     q, k, v, out, lse = residuals
-    return _flash_backward_packed(q, k, v, g, out, lse, heads, causal,
-                                  scale, blocks, interpret, window)
+    g = _Grid(_packed(q, k, heads), _PLAIN, causal, scale, blocks,
+              interpret, None, window)
+    return _backward(g, q, k, v, do, out, lse)
 
 
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
@@ -1273,107 +1167,15 @@ def selection_blocks(t: int, d: int = 128, itemsize: int = 2) -> Blocks:
     return Blocks(*[(side, side)] * 3)
 
 
-def _sel_fwd_kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref, m_scr,
-                    l_scr, acc_scr, **kw):
-    _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                  acc_scr, sel_ref=sel_ref, **kw)
+def _sel_specs(lay, s, sel):
+    """The word block holding a tile's selection: the q-block's rows of
+    the word that covers the k-block. No gradient."""
+    word = _sel_word(s.bk)
+    return ([lay.per_head(s.bq, lambda *ids: word(s.krow(*ids)), s.qrow,
+                          SEL_LANES)], _NONE, _NONE)
 
 
-def _sel_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, sel_ref,
-                   dq_ref, dq_scr, **kw):
-    _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                         dq_ref, dq_scr, sel_ref=sel_ref, **kw)
-
-
-def _sel_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, sel_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, **kw):
-    _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                          dk_ref, dv_ref, dk_scr, dv_scr, sel_ref=sel_ref,
-                          **kw)
-
-
-def _selected_forward(q, k, v, sel, heads, scale, blocks, interpret):
-    b, t, hd = q.shape
-    d = hd // heads
-    lane = _lane_of(hd // k.shape[2])
-    bq, bk = blocks.fwd
-    word = _sel_word(bk)
-    q_pin = pl.BlockSpec((None, bq, d), lambda bi, h, i, kb: (bi, i, h))
-    k_str = pl.BlockSpec((None, bk, d),
-                         lambda bi, h, i, kb: (bi, kb, lane(h)))
-    with jax.named_scope("attn_fwd_sel"):
-        return pl.pallas_call(
-            functools.partial(_sel_fwd_kernel, causal=True, scale=scale,
-                              qi_axis=2),
-            grid=(b, heads, t // bq, t // bk),
-            in_specs=[q_pin, k_str, k_str,
-                      pl.BlockSpec((None, None, bq, SEL_LANES),
-                                   lambda bi, h, i, kb: (bi, word(kb), i, 0))],
-            out_specs=(q_pin,
-                       pl.BlockSpec((None, None, bq, _LSE_LANES),
-                                    lambda bi, h, i, kb: (bi, h, i, 0))),
-            out_shape=(
-                jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-                jax.ShapeDtypeStruct((b, heads, t, _LSE_LANES), jnp.float32)),
-            scratch_shapes=_fwd_scratch(bq, d),
-            interpret=interpret,
-        )(q, k, v, sel)
-
-
-def _selected_backward(q, k, v, sel, do, o, lse, heads, scale, blocks,
-                       interpret):
-    b, t, hd = q.shape
-    d = hd // heads
-    hkv = k.shape[2] // d
-    reps = heads // hkv
-    lane = _lane_of(reps)
-    bq, bk = blocks.dq
-    word = _sel_word(bk)
-    q_pin = pl.BlockSpec((None, bq, d), lambda bi, h, i, kb: (bi, i, h))
-    k_str = pl.BlockSpec((None, bk, d),
-                         lambda bi, h, i, kb: (bi, kb, lane(h)))
-    lse_pin = pl.BlockSpec((None, None, bq, _LSE_LANES),
-                           lambda bi, h, i, kb: (bi, h, i, 0))
-    with jax.named_scope("attn_bwd_dq_sel"):
-        dq = pl.pallas_call(
-            functools.partial(_sel_dq_kernel, causal=True, scale=scale,
-                              qi_axis=2),
-            grid=(b, heads, t // bq, t // bk),
-            in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin,
-                      pl.BlockSpec((None, None, bq, SEL_LANES),
-                                   lambda bi, h, i, kb: (bi, word(kb), i, 0))],
-            out_specs=q_pin,
-            out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-            interpret=interpret,
-        )(q, k, v, do, o, lse, sel)
-    # dk/dv: (b, hkv, kj, qx), qx the flattened (rep, q-block) sweep.
-    bq, bk = blocks.dkv
-    word = _sel_word(bk)
-    nqb = t // bq
-    k_pin = pl.BlockSpec((None, bk, d), lambda bi, hk, j, qx: (bi, j, hk))
-    q_str = pl.BlockSpec((None, bq, d), lambda bi, hk, j, qx:
-                         (bi, qx % nqb, hk * reps + qx // nqb))
-    lse_str = pl.BlockSpec((None, None, bq, _LSE_LANES),
-                           lambda bi, hk, j, qx:
-                           (bi, hk * reps + qx // nqb, qx % nqb, 0))
-    with jax.named_scope("attn_bwd_dkv_sel"):
-        dk, dv = pl.pallas_call(
-            functools.partial(_sel_dkv_kernel, causal=True, scale=scale,
-                              qi_axis=2, nqb=nqb),
-            grid=(b, hkv, t // bk, reps * nqb),
-            in_specs=[q_str, k_pin, k_pin, q_str, q_str, lse_str,
-                      pl.BlockSpec((None, None, bq, SEL_LANES),
-                                   lambda bi, hk, j, qx:
-                                   (bi, word(j), qx % nqb, 0))],
-            out_specs=(k_pin, k_pin),
-            out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
-                       jax.ShapeDtypeStruct(v.shape, v.dtype)),
-            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                            pltpu.VMEM((bk, d), jnp.float32)],
-            interpret=interpret,
-        )(q, k, v, do, o, lse, sel)
-    return dq, dk, dv
+_SEL = _Variant("_sel", lambda sel_ref: dict(sel_ref=sel_ref), _sel_specs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -1383,16 +1185,15 @@ def _flash_selected(q, k, v, sel, heads, scale, blocks, interpret):
 
 
 def _flash_selected_fwd(q, k, v, sel, heads, scale, blocks, interpret):
-    out, lse = _selected_forward(q, k, v, sel, heads, scale, blocks,
-                                 interpret)
-    out, lse = remat.name(out, "flash_out"), remat.name(lse, "flash_lse")
+    g = _Grid(_packed(q, k, heads), _SEL, True, scale, blocks, interpret)
+    out, lse = _named(*_streamed_forward(g, q, k, v, sel))
     return (out, lse), (q, k, v, sel, out, lse)
 
 
-def _flash_selected_bwd(heads, scale, blocks, interpret, residuals, g):
+def _flash_selected_bwd(heads, scale, blocks, interpret, residuals, do):
     q, k, v, sel, out, lse = residuals
-    dq, dk, dv = _selected_backward(q, k, v, sel, g[0], out, lse, heads,
-                                    scale, blocks, interpret)
+    g = _Grid(_packed(q, k, heads), _SEL, True, scale, blocks, interpret)
+    dq, dk, dv, _, _ = _streamed_backward(g, q, k, v, do[0], out, lse, sel)
     return dq, dk, dv, None
 
 
@@ -1463,109 +1264,20 @@ def flash_attention_selected(q: jax.Array, k: jax.Array, v: jax.Array,
 # --------------------------------------------------------------------
 
 
-def _mla_fwd_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, o_ref, lse_ref,
-                    m_scr, l_scr, acc_scr, **kw):
-    _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                  acc_scr, shared=(qs_ref, ks_ref), **kw)
+def _mla_specs(lay, s, qs, ks):
+    """``qs`` a head's rows beside q, ``ks`` the shared rows beside k. dq
+    also returns dqs, blocked as ``qs``; dk/dv each head's share of the
+    shared part's gradient, float32 ``[B, H, T, ds]``: the caller sums."""
+    width = ks.shape[2]
+    qs_blk = lay.per_head(s.bq, s.qhead, s.qrow, width)
+    scratch = lambda rows: [pltpu.VMEM((rows, width), jnp.float32)]
+    return ([qs_blk, lay.rows(s.bk, _zero, s.krow, width)],
+            ([qs_blk], [_like(qs)], scratch(s.bq)),
+            ([lay.per_head(s.bk, lay.own, s.krow, width)],
+             [lay.per_head_f32(width)], scratch(s.bk)))
 
 
-def _mla_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, qs_ref,
-                   ks_ref, dq_ref, dqs_ref, dq_scr, dqs_scr, **kw):
-    _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                         dq_ref, dq_scr,
-                         shared=(qs_ref, ks_ref, dqs_ref, dqs_scr), **kw)
-
-
-def _mla_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, qs_ref,
-                    ks_ref, dk_ref, dv_ref, dks_ref, dk_scr, dv_scr,
-                    dks_scr, **kw):
-    _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                          dk_ref, dv_ref, dk_scr, dv_scr,
-                          shared=(qs_ref, ks_ref, dks_ref, dks_scr), **kw)
-
-
-def _mla_forward(q, qs, k, ks, v, heads, scale, blocks, interpret):
-    b, t, hd = q.shape
-    d, ds = hd // heads, ks.shape[2]
-    bq, bk = blocks.fwd
-    q_pin = pl.BlockSpec((None, bq, d), lambda bi, h, i, kb: (bi, i, h))
-    k_str = pl.BlockSpec((None, bk, d), lambda bi, h, i, kb: (bi, kb, h))
-    with jax.named_scope("attn_fwd_mla"):
-        return pl.pallas_call(
-            functools.partial(_mla_fwd_kernel, causal=True, scale=scale,
-                              qi_axis=2),
-            grid=(b, heads, t // bq, t // bk),
-            in_specs=[q_pin, k_str, k_str,
-                      pl.BlockSpec((None, None, bq, ds),
-                                   lambda bi, h, i, kb: (bi, h, i, 0)),
-                      pl.BlockSpec((None, bk, ds),
-                                   lambda bi, h, i, kb: (bi, kb, 0))],
-            out_specs=(q_pin,
-                       pl.BlockSpec((None, None, bq, _LSE_LANES),
-                                    lambda bi, h, i, kb: (bi, h, i, 0))),
-            out_shape=(
-                jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-                jax.ShapeDtypeStruct((b, heads, t, _LSE_LANES), jnp.float32)),
-            scratch_shapes=_fwd_scratch(bq, d),
-            interpret=interpret,
-        )(q, k, v, qs, ks)
-
-
-def _mla_backward(q, qs, k, ks, v, do, o, lse, heads, scale, blocks,
-                  interpret):
-    b, t, hd = q.shape
-    d, ds = hd // heads, ks.shape[2]
-    bq, bk = blocks.dq
-    q_pin = pl.BlockSpec((None, bq, d), lambda bi, h, i, kb: (bi, i, h))
-    k_str = pl.BlockSpec((None, bk, d), lambda bi, h, i, kb: (bi, kb, h))
-    lse_pin = pl.BlockSpec((None, None, bq, _LSE_LANES),
-                           lambda bi, h, i, kb: (bi, h, i, 0))
-    qs_pin = pl.BlockSpec((None, None, bq, ds),
-                          lambda bi, h, i, kb: (bi, h, i, 0))
-    with jax.named_scope("attn_bwd_dq_mla"):
-        dq, dqs = pl.pallas_call(
-            functools.partial(_mla_dq_kernel, causal=True, scale=scale,
-                              qi_axis=2),
-            grid=(b, heads, t // bq, t // bk),
-            in_specs=[q_pin, k_str, k_str, q_pin, q_pin, lse_pin, qs_pin,
-                      pl.BlockSpec((None, bk, ds),
-                                   lambda bi, h, i, kb: (bi, kb, 0))],
-            out_specs=(q_pin, qs_pin),
-            out_shape=(jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-                       jax.ShapeDtypeStruct(qs.shape, qs.dtype)),
-            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
-                            pltpu.VMEM((bq, ds), jnp.float32)],
-            interpret=interpret,
-        )(q, k, v, do, o, lse, qs, ks)
-    # dk/dv: (b, h, kj, qx); each head writes its share of the shared key
-    # part's gradient, summed over the heads below.
-    bq, bk = blocks.dkv
-    k_pin = pl.BlockSpec((None, bk, d), lambda bi, h, j, qx: (bi, j, h))
-    q_str = pl.BlockSpec((None, bq, d), lambda bi, h, j, qx: (bi, qx, h))
-    lse_str = pl.BlockSpec((None, None, bq, _LSE_LANES),
-                           lambda bi, h, j, qx: (bi, h, qx, 0))
-    ks_head = pl.BlockSpec((None, None, bk, ds),
-                           lambda bi, h, j, qx: (bi, h, j, 0))
-    with jax.named_scope("attn_bwd_dkv_mla"):
-        dk, dv, dks = pl.pallas_call(
-            functools.partial(_mla_dkv_kernel, causal=True, scale=scale,
-                              qi_axis=2),
-            grid=(b, heads, t // bk, t // bq),
-            in_specs=[q_str, k_pin, k_pin, q_str, q_str, lse_str,
-                      pl.BlockSpec((None, None, bq, ds),
-                                   lambda bi, h, j, qx: (bi, h, qx, 0)),
-                      pl.BlockSpec((None, bk, ds),
-                                   lambda bi, h, j, qx: (bi, j, 0))],
-            out_specs=(k_pin, k_pin, ks_head),
-            out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
-                       jax.ShapeDtypeStruct(v.shape, v.dtype),
-                       jax.ShapeDtypeStruct((b, heads, t, ds), jnp.float32)),
-            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                            pltpu.VMEM((bk, d), jnp.float32),
-                            pltpu.VMEM((bk, ds), jnp.float32)],
-            interpret=interpret,
-        )(q, k, v, do, o, lse, qs, ks)
-    return dq, dqs, dk, dks.sum(axis=1).astype(ks.dtype), dv
+_MLA = _Variant("_mla", lambda *refs: dict(shared=refs), _mla_specs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
@@ -1575,15 +1287,17 @@ def _flash_mla(q, qs, k, ks, v, heads, scale, blocks, interpret):
 
 
 def _flash_mla_fwd(q, qs, k, ks, v, heads, scale, blocks, interpret):
-    out, lse = _mla_forward(q, qs, k, ks, v, heads, scale, blocks, interpret)
-    out, lse = remat.name(out, "flash_out"), remat.name(lse, "flash_lse")
+    g = _Grid(_packed(q, k, heads), _MLA, True, scale, blocks, interpret)
+    out, lse = _named(*_streamed_forward(g, q, k, v, qs, ks))
     return out, (q, qs, k, ks, v, out, lse)
 
 
-def _flash_mla_bwd(heads, scale, blocks, interpret, residuals, g):
+def _flash_mla_bwd(heads, scale, blocks, interpret, residuals, do):
     q, qs, k, ks, v, out, lse = residuals
-    return _mla_backward(q, qs, k, ks, v, g, out, lse, heads, scale, blocks,
-                         interpret)
+    g = _Grid(_packed(q, k, heads), _MLA, True, scale, blocks, interpret)
+    dq, dk, dv, (dqs,), (dks,) = _streamed_backward(
+        g, q, k, v, do, out, lse, qs, ks)
+    return dq, dqs, dk, dks.sum(axis=1).astype(ks.dtype), dv
 
 
 _flash_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
