@@ -6,7 +6,9 @@ chunk kernels over 32 heads of 128 x 128; the latent-attention grids at a
 192-wide q/k — 128 of a head's own and 64 shared — over 128-wide values,
 32 heads) and ``olmohybrid.train-16k`` runs (1 x 16384 tokens; the same
 chunk kernels under one decay a head over 15 heads of 96 x 192, behind zero
-lanes; the packed flash kernels at 15 heads of 128): as ``tests/test_tpu_compile.py``, a pass is a COMPILE for a
+lanes; the packed flash kernels at 15 heads of 128) and
+``glm47flash.train-16k`` runs (the packed flash kernels at 20 heads of
+256): as ``tests/test_tpu_compile.py``, a pass is a COMPILE for a
 chip that is not attached — what Mosaic refuses there (a block off the
 tiling, too much VMEM, an SMEM block it cannot place) is refused here."""
 
@@ -128,7 +130,19 @@ def _mla(sh):
         *a, KH, interpret=False).astype(jnp.float32).sum(), range(5)), args
 
 
+def _flash20x256(sh):
+    """``glm47flash.train-16k``'s attention: 20 heads, each head's joined
+    192 + 64 key beside a 256-wide value, two lane blocks a head."""
+    from tony_tpu.ops import flash_attention_packed
+
+    s = jax.ShapeDtypeStruct((1, T16K, 20 * 256), jnp.bfloat16, sharding=sh)
+    return jax.grad(lambda q, k, v: flash_attention_packed(
+        q, k, v, 20, causal=True, scale=256 ** -0.5, interpret=False).astype(
+            jnp.float32).sum(), (0, 1, 2)), (s, s, s)
+
+
 CASES = {
+    "flash_packed_causal_20x256_t16384_fwd_bwd": _flash20x256,
     "gdn_chunk_fwd_15x96x192_t16384": _gdn(grad=False),
     "gdn_chunk_fwd_bwd_15x96x192_t16384": _gdn(grad=True),
     "flash_packed_causal_15x128_t16384_fwd_bwd": _flash15,

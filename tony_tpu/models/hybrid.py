@@ -5,7 +5,10 @@ Phi-4-mini-flash-reasoning runs it); gated delta-rule linear attention and
 latent attention without positions over dense or expert feed-forwards
 (Kimi Linear, arXiv:2510.26692); Gated DeltaNet (arXiv:2412.06464: the
 delta rule under one decay a head) beside plain causal attention without
-rotation, the norms after the sublayers (Olmo-Hybrid-7B).
+rotation, the norms after the sublayers (Olmo-Hybrid-7B); rotated latent
+attention under a low-rank query in every layer and a multi-token-
+prediction module behind the stack (GLM-4.7-Flash; DeepSeek-V3,
+arXiv:2412.19437).
 
 :class:`~tony_tpu.models.transformer.Transformer` folds ONE block kind with
 ``nn.scan``; here the kinds differ and two streams cross layers, so each
@@ -27,7 +30,8 @@ kind (scope)      mixer                      consumes             emits
 ``kda`` (kda)     gated delta rule, a        —                    —
                   128 x 128 state a head
 ``mla``           latent attention expanded  —                    —
-(attn_mla)        for training, no rotation
+(attn_mla)        for training, rotated or
+                  not
 ``gdn`` (gdn)     gated delta rule, one      —                    —
                   decay a head, a key x
                   value state a head
@@ -80,11 +84,34 @@ the norms are whole on each. A mixer's shares add up to the uncut mixer
 stands in for it); the q/k-norm's mean square is over the held width, what
 a chip has without exchanging one scalar a token.
 
-``mla`` (:class:`MLA`): ``q_h = u W_q`` (nope + rope wide), ``[c, k_s] = u
-W_kva``, ``[k_h, v_h] = RMSNorm(c) W_kvb``; head h's key is ``[k_h, k_s]``
-with ``k_s`` shared by the heads and **no rotation** on any part; causal
-softmax of ``q_h . key_h / sqrt(nope + rope)``; ``W_o`` over the values
-(:func:`tony_tpu.ops.attention.flash_attention_mla`: the expanded form).
+``mla`` (:class:`MLA`): ``q_h = u W_q`` (nope + rope wide) — with
+``mla_q_rank`` ``RMSNorm(u W_qa) W_qb``, the query through a latent of its
+own —, ``[c, k_s] = u W_kva``, ``[k_h, v_h] = RMSNorm(c) W_kvb``; head h's
+key is ``[k_h, k_s]`` with ``k_s`` shared by the heads; with
+``mla_rope_theta`` (values as wide as the whole key only) the last
+``rope`` columns of every ``q_h`` and ``k_s`` are rotated by their position
+(:func:`tony_tpu.models.transformer.rope`, neighbouring pairs), without it
+**no rotation** on any part; causal
+softmax of ``q_h . key_h / sqrt(nope + rope)``; ``W_o`` over the values.
+Expanded for training, two shapes: values as wide as a key's own part
+(128 + 64 over 128) go through
+:func:`tony_tpu.ops.attention.flash_attention_mla`, which reads the shared
+part beside each head's; values as wide as the whole key (192 + 64 over
+256: a head's own part is no lane block) have each head's key joined and
+go through the packed flash kernels at that head size.
+
+**A second token** (``mtp_layers`` 1; DeepSeek-V3 section 2.2): behind the
+stack, ``h' = [RMSNorm(Emb(t_{i+1})); RMSNorm(h_i)] W_eh`` over the last
+layer's output ``h`` **before** the final norm, one more layer of the last
+layer's kind and feed-forward (its own weights, the stack's positions), a
+norm of its own, and the model's head a second time against ``t_{i+2}``:
+one table and one head, two uses and two gradients each. With ``targets``
+the model returns ``L_LM`` and sows ``mtp_weight * L_MTP`` into ``losses``
+(a train step adds it: its ``aux_loss``), each a mean over its own rows;
+without, it returns the first logits and sows the second into
+``intermediates``. The module runs over all ``T`` rows — the last embeds
+the row's first token in place of the one it lacks, sees no later row and
+carries no loss.
 
 Differential attention (heads 2p, 2p+1 are pair p; two query pairs share
 one K/V pair; ``v = [v_1, v_2]`` is 128 wide)::
@@ -119,7 +146,7 @@ import jax.numpy as jnp
 
 from tony_tpu import profiler, remat
 from tony_tpu.models import register
-from tony_tpu.models.transformer import RMSNorm
+from tony_tpu.models.transformer import RMSNorm, rope
 from tony_tpu.ops import attention as attn_ops
 from tony_tpu.ops import ssm
 
@@ -187,6 +214,12 @@ class HybridConfig:
     mla_nope_dim: int = 128
     mla_rope_dim: int = 64          # the key part the heads share
     mla_v_dim: int = 128
+    mla_q_rank: int = 0             # >0: the query through a latent
+    mla_rope_theta: float = 0.0     # >0: the rope parts are rotated
+    # 1: a multi-token-prediction module behind the stack (module
+    # docstring), its loss weighed ``mtp_weight`` beside L_LM.
+    mtp_layers: int = 0
+    mtp_weight: float = 0.3
     moe_experts: int = 256
     moe_top_k: int = 8
     moe_experts_held: int = 0       # 0 = all of them
@@ -235,9 +268,18 @@ class HybridConfig:
                     and ffn[0] == "dense"):
                 raise ValueError(f"feed-forward {ffn!r}: ('dense', width) "
                                  f"or 'experts'")
-        if "mla" in self.layers and self.mla_nope_dim != self.mla_v_dim:
-            raise ValueError("mla: the keys' own part and the values share "
-                             "one packed layout (nope_dim == v_dim)")
+        if "mla" in self.layers and self.mla_v_dim not in (
+                self.mla_nope_dim, self.mla_nope_dim + self.mla_rope_dim):
+            raise ValueError("mla: values as wide as a key's own part "
+                             "(nope_dim == v_dim) or as the whole key "
+                             "(nope_dim + rope_dim == v_dim)")
+        if "mla" in self.layers and self.mla_rope_theta \
+                and self.mla_v_dim == self.mla_nope_dim:
+            raise ValueError("mla: the rotation is written for values as "
+                             "wide as the whole key (joined keys)")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(f"mtp_layers={self.mtp_layers}: one module "
+                             f"predicts one more token")
         if self.mesh is not None:
             raise ValueError("the hybrid decoder runs on one chip; no "
                              "sharding rules are written for it yet")
@@ -267,7 +309,9 @@ class HybridConfig:
         gather, of a held range of experts the share an even routing sends
         here, the shared experts whole; MLA over the causal half with q.k
         over nope + rope and p.v over v, forward 2 and backward 4 products
-        (the scores a flash backward takes again are a recomputation),
+        (the scores a flash backward takes again are a recomputation), a
+        low-rank query as its two products; a multi-token-prediction
+        module as its join, one more layer and the head a second time;
         plain attention alike over ``head_dim``; the delta rule as the
         recurrence's own 7 multiply-adds a state element a step, twice that
         backward)."""
@@ -287,22 +331,26 @@ class HybridConfig:
                      3 * ah * (seq + 1) * 2 * self.head_dim),
             "kda": (3 * d * e + 2 * (d * hd + hd * e) + d * self.kda_heads
                     + e * d, 3 * 7 * self.kda_heads * hd * hd),
-            "mla": (d * self.mla_heads * qk
-                    + d * (self.mla_kv_rank + self.mla_rope_dim)
-                    + self.mla_kv_rank * self.mla_heads
-                    * (self.mla_nope_dim + self.mla_v_dim)
-                    + self.mla_heads * self.mla_v_dim * d,
-                    3 * self.mla_heads * seq * (qk + self.mla_v_dim)),
+            "mla": (
+                ((d + self.mla_heads * qk) * self.mla_q_rank
+                 if self.mla_q_rank else d * self.mla_heads * qk)
+                + d * (self.mla_kv_rank + self.mla_rope_dim)
+                + self.mla_kv_rank * self.mla_heads
+                * (self.mla_nope_dim + self.mla_v_dim)
+                + self.mla_heads * self.mla_v_dim * d,
+                3 * self.mla_heads * seq * (qk + self.mla_v_dim)),
         }
         share = (self.moe_experts_held or self.moe_experts) / self.moe_experts
         experts = d * self.moe_experts + 3 * d * self.moe_ffn * (
             self.moe_shared + self.moe_top_k * share)
         params, mixing = d * self.vocab, 0.0
-        for kind, ffn in zip(self.layers, self.ffns or (
-                ("dense", self.ffn_hidden),) * len(self.layers)):
+        stack = list(zip(self.layers, self.ffns or (
+            ("dense", self.ffn_hidden),) * len(self.layers)))
+        for kind, ffn in stack + stack[-1:] * self.mtp_layers:
             params += per[kind][0] + (experts if ffn == "experts"
                                       else 3 * d * ffn[1])
             mixing += per[kind][1]
+        params += self.mtp_layers * (2 * d * d + d * self.vocab)
         return 6.0 * params + mixing
 
 
@@ -647,9 +695,12 @@ class Attn(nn.Module):
 
 
 class MLA(nn.Module):
-    """Latent attention expanded for training, without rotation (module
-    docstring): device scopes ``attn_mla`` > ``mla_proj`` and the three
-    ``*_mla`` flash calls."""
+    """Latent attention expanded for training (module docstring): device
+    scopes ``attn_mla`` > ``mla_proj`` (the projections and their norms),
+    ``mla_rope`` (the rotation and, where the values are as wide as the
+    whole key, each head's joined key) and the three flash calls —
+    ``*_mla`` where the shared key part is read beside each head's own,
+    the packed kernels' own names where the keys are joined."""
     cfg: HybridConfig
 
     @nn.compact
@@ -658,27 +709,59 @@ class MLA(nn.Module):
         b, t, _ = u.shape
         h, r = cfg.mla_heads, cfg.mla_kv_rank
         dn, ds, dv = cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim
+        joined = dv != dn           # values as wide as the whole key
+        theta = cfg.mla_rope_theta
         with jax.named_scope("mla_proj"):
-            q = remat.name(_dense(cfg, h * (dn + ds), "wq")(u), "q")
+            if cfg.mla_q_rank:
+                q = _dense(cfg, h * (dn + ds), "wq_b")(
+                    RMSNorm(cfg.norm_eps, name="q_norm")(
+                        _dense(cfg, cfg.mla_q_rank, "wq_a")(u)))
+            else:
+                q = _dense(cfg, h * (dn + ds), "wq")(u)
+            if not joined:
+                q = remat.name(q, "q")
             q = q.reshape(b, t, h, dn + ds)
             kva = _dense(cfg, r + ds, "wkv_a")(u)
             c, ks = kva[..., :r], kva[..., r:]
             kv = _dense(cfg, h * (dn + dv), "wkv_b")(
                 RMSNorm(cfg.norm_eps, name="kv_norm")(c))
             kv = kv.reshape(b, t, h, dn + dv)
-            k = remat.name(kv[..., :dn].reshape(b, t, h * dn), "k")
+            if not joined:
+                k = remat.name(kv[..., :dn].reshape(b, t, h * dn), "k")
             v = remat.name(kv[..., dn:].reshape(b, t, h * dv), "v")
-            qn = q[..., :dn].reshape(b, t, h * dn)
-            qs = q[..., dn:].transpose(0, 2, 1, 3)
+            if not joined:
+                qn = q[..., :dn].reshape(b, t, h * dn)
+                qs = q[..., dn:].transpose(0, 2, 1, 3)
         for name, n in attn_ops.block_facts(
-                t, t, causal=True, head_dim=dn,
-                itemsize=k.dtype.itemsize).items():
+                t, t, causal=True, head_dim=dv,
+                itemsize=v.dtype.itemsize).items():
             profiler.count_once(f"attn:{name}.mla", n)
         for name, fact in (("kv_rank", r), ("qk_dim", dn + ds),
-                           ("v_dim", dv)):
-            profiler.count_once("mla:" + name, fact)
-        out = attn_ops.flash_attention_mla(qn, qs, k, ks, v, h,
-                                           interpret=cfg.interpret)
+                           ("v_dim", dv), ("q_rank", cfg.mla_q_rank),
+                           ("rope_dim", ds if theta else 0)):
+            if fact:
+                profiler.count_once("mla:" + name, fact)
+        if not joined:
+            out = attn_ops.flash_attention_mla(qn, qs, k, ks, v, h,
+                                               interpret=cfg.interpret)
+        else:
+            with jax.named_scope("mla_rope"):
+                qs, ks = q[..., dn:], ks[:, :, None]
+                if theta:
+                    qs = rope(qs, jnp.arange(t), theta, seq_axis=1)
+                    ks = rope(ks, jnp.arange(t), theta, seq_axis=1)
+                # packsite: region-local — each head's own part beside its
+                # rotated part, one unsharded activation (no mesh).
+                q = remat.name(jnp.concatenate(
+                    [q[..., :dn], qs], -1).reshape(b, t, -1), "q")
+                # packsite: region-local — each head's own part beside the
+                # part all heads share.
+                k = remat.name(jnp.concatenate(
+                    [kv[..., :dn], jnp.broadcast_to(ks, (b, t, h, ds))],
+                    -1).reshape(b, t, -1), "k")
+            out = attn_ops.flash_attention_packed(
+                q, k, v, h, causal=True, scale=(dn + ds) ** -0.5,
+                interpret=cfg.interpret)
         return remat.name(_dense(cfg, cfg.dim, "wo")(out), "wo"), ()
 
 
@@ -750,6 +833,29 @@ class HybridLayer(nn.Module):
         return x + (norm2(out) if post else out), emitted
 
 
+class MTP(nn.Module):
+    """The multi-token-prediction module (module docstring): ``h`` the
+    stack's output before the final norm, ``e`` the next token's embedding
+    -> what the head reads to predict the token after it. Device scopes
+    ``mtp`` > ``mtp_proj`` (the two norms, the join, ``W_eh``), the layer's
+    own scopes, and the head's second pass (the decoder's)."""
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, h, e):
+        cfg = self.cfg
+        with jax.named_scope("mtp_proj"):
+            # packsite: region-local — the embedding's half beside the
+            # stack's, one unsharded activation (no mesh).
+            x = _dense(cfg, cfg.dim, "w_eh")(jnp.concatenate(
+                [_norm(cfg, "e_norm")(e), _norm(cfg, "h_norm")(h)], -1))
+        layer_cls = remat.block(HybridLayer) if cfg.remat else HybridLayer
+        # One more of the last layer: its kind, its feed-forward.
+        x, _ = layer_cls(cfg, cfg.layers[-1], len(cfg.layers) - 1,
+                         name="layer")(x)
+        return _norm(cfg, "final_norm")(x)
+
+
 class HybridDecoder(nn.Module):
     cfg: HybridConfig
 
@@ -770,11 +876,23 @@ class HybridDecoder(nn.Module):
         for kind in KINDS:
             profiler.count_once(f"model:layers.{kind}",
                                 cfg.layers.count(kind))
-        profiler.count_once("model:layers.experts",
-                            cfg.ffns.count("experts"))
+        # Expert feed-forwards the model runs: the stack's, and the
+        # multi-token-prediction module's (the last layer's kind).
+        profiler.count_once("model:layers.experts", (
+            cfg.ffns + cfg.ffns[-1:] * cfg.mtp_layers).count("experts"))
         if cfg.heads_held:
             profiler.count_once("model:heads_held", cfg.heads_held)
             profiler.count_once("model:heads_total", cfg.n_heads)
+        second = None
+        if cfg.mtp_layers:
+            for name, fact in (("model:layers.mtp", cfg.mtp_layers),
+                               ("mtp:weight", cfg.mtp_weight),
+                               ("head:calls", 1 + cfg.mtp_layers)):
+                profiler.count_once(name, fact)
+            with jax.named_scope("embed"):
+                ahead = jnp.take(embed, jnp.roll(tokens, -1, axis=1),
+                                 axis=0).astype(cfg.dtype)
+            second = MTP(cfg, name="mtp")(x, ahead)
         x = _norm(cfg, "final_norm")(x)
         # Tied head: the same table, transposed.
         head = embed.T if cfg.tie_embeddings else self.param(
@@ -782,11 +900,20 @@ class HybridDecoder(nn.Module):
             (cfg.dim, cfg.vocab), jnp.float32)
         if cfg.xent_chunk and targets is not None:
             from tony_tpu.train import chunked_next_token_xent
+            if second is not None:
+                with jax.named_scope("mtp"):
+                    self.sow("losses", "mtp_loss", cfg.mtp_weight
+                             * chunked_next_token_xent(
+                                 second, head, targets, cfg.xent_chunk,
+                                 cfg.dtype, shift=2))
             return chunked_next_token_xent(x, head, targets,
                                            cfg.xent_chunk, cfg.dtype)
         with jax.named_scope("lm_head"):
-            return jnp.dot(x, head.astype(cfg.dtype),
-                           preferred_element_type=jnp.float32)
+            logits = lambda x: jnp.dot(x, head.astype(cfg.dtype),
+                                       preferred_element_type=jnp.float32)
+            if second is not None:
+                self.sow("intermediates", "mtp_logits", logits(second))
+            return logits(x)
 
 
 @register("hybrid-decoder")
@@ -875,3 +1002,44 @@ def olmo_hybrid_tiny(**kw) -> HybridDecoder:
         gdn_value_dim=24, kda_chunk=8, kda_keep=2, remat=False)
     defaults.update(kw)
     return olmo_hybrid(**defaults)
+
+
+# GLM-4.7-Flash's layers 0-4: the leading dense layer, then four of the
+# period of one.
+GLM_FLASH_CUT = ("mla",) * 5
+
+
+@register("glm-4.7-flash")
+def glm_flash(**kw) -> HybridDecoder:
+    """GLM-4.7-Flash's widths over its layers 0-4 by default (the leading
+    dense layer, then four expert layers) and its multi-token-prediction
+    module: hidden 2048, rotated MLA of 20 heads at 192 + 64 over 256-wide
+    values, the query through a 768-wide and key/value through a 512-wide
+    latent, theta 1e6; a dense SwiGLU of 10240, 4 of 64 sigmoid-routed
+    experts of 1536 and a shared one, gates scaled by 1.8; RMSNorm, an
+    untied head, ``L_LM + 0.3 L_MTP``. A deployment holds a range of the
+    experts (``moe_experts_held``) and a slice of the vocabulary."""
+    defaults = dict(
+        vocab=154880, dim=2048, layers=GLM_FLASH_CUT, norm="rmsnorm",
+        tie_embeddings=False, mla_heads=20, mla_kv_rank=512, mla_q_rank=768,
+        mla_nope_dim=192, mla_rope_dim=64, mla_v_dim=256,
+        mla_rope_theta=1e6, moe_experts=64, moe_top_k=4, moe_ffn=1536,
+        moe_route_scale=1.8, mtp_layers=1, mtp_weight=0.3)
+    defaults.update(kw)
+    defaults.setdefault("ffns", (("dense", 10240),) + ("experts",) * (
+        len(tuple(defaults["layers"])) - 1))
+    return hybrid_decoder(**defaults)
+
+
+@register("glm-4.7-flash-tiny")
+def glm_flash_tiny(**kw) -> HybridDecoder:
+    """Test scale: the same code path (the low-rank query, the rotation,
+    joined keys under values as wide, the dense and the expert
+    feed-forward, the second token's module) at toy widths."""
+    defaults = dict(
+        vocab=256, dim=64, layers=("mla", "mla"),
+        ffns=(("dense", 128), "experts"), mla_heads=2, mla_kv_rank=32,
+        mla_q_rank=24, mla_nope_dim=24, mla_rope_dim=8, mla_v_dim=32,
+        moe_experts=8, moe_top_k=2, moe_ffn=32, remat=False)
+    defaults.update(kw)
+    return glm_flash(**defaults)

@@ -140,12 +140,14 @@ _grad_in_forward_xent.defvjp(_grad_in_forward_xent_fwd,
 
 def chunked_next_token_xent(hidden: jax.Array, lm_head: jax.Array,
                             tokens: jax.Array, chunk: int,
-                            dtype=jnp.bfloat16) -> jax.Array:
+                            dtype=jnp.bfloat16, shift: int = 1) -> jax.Array:
     """Fused LM-head + causal cross entropy that never holds the [B, T, V]
     logits (float32: 16 GiB at 1 x 32768 over 131,136 columns). Rows go
     through a ``lax.scan`` in ``chunk``-sized steps: a chunk's logits in
     ``dtype`` on the MXU, float32 log-sum-exp minus the label logit, summed
-    into a carry.
+    into a carry. Row ``i`` is scored against token ``i + shift`` (2: the
+    second-next token a multi-token-prediction module predicts), a mean
+    over the ``T - shift`` rows that have one.
 
     Differentiated, the same pass takes the gradient while the chunk's
     logits are in hand: ``(softmax - onehot) * weight / r`` as autodiff
@@ -155,8 +157,14 @@ def chunked_next_token_xent(hidden: jax.Array, lm_head: jax.Array,
     arrays are the only residuals and the backward scales them by the
     cotangent: three products a chunk. At a cotangent of 1 (or any power of
     two) they are the gradients autodiff takes of the checkpointed scan bit
-    for bit; another cotangent scales what is already rounded to ``dtype``
-    and rounds again, where autodiff rounds once: one ulp of ``dtype``.
+    for bit; another cotangent (0.3, a second loss through the same head)
+    scales what is already rounded to ``dtype`` and rounds again, where
+    autodiff scales ``dlogits`` before its one rounding: the two differ by
+    roundings of ``dtype``, no more — read at 0.3 in bfloat16, 2 ulp at
+    the scale of a row of the rows' gradient and, the head's being a sum
+    over the chunks that either path rounds once a chunk, 5 ulp at the
+    scale of one of its columns over 8 chunks (an element that is a small
+    difference of large terms is off by the terms' ulp, as in any sum).
     The cast of ``lm_head``, the slice, the padding and a tied table's
     transpose stay outside, for autodiff to transpose.
 
@@ -174,8 +182,8 @@ def chunked_next_token_xent(hidden: jax.Array, lm_head: jax.Array,
     ``head:grad_in_forward`` (1 once the three-product pass was traced).
     """
     d = hidden.shape[-1]
-    rows = hidden[:, :-1].reshape(-1, d)
-    labels = tokens[:, 1:].reshape(-1)
+    rows = hidden[:, :-shift].reshape(-1, d)
+    labels = tokens[:, shift:].reshape(-1)
     r = rows.shape[0]
     n = -(-r // chunk)   # ceil: minimal whole-chunk cover
     pad = n * chunk - r
