@@ -210,13 +210,16 @@ def test_trains_through_the_normal_path():
                for k in ("swa", "full", "gmu", "cross"))
     assert counters["ssm:chunks"] == 4          # 32 steps in chunks of 8
     for kind in ("swa", "full", "cross"):
-        assert counters[f"attn:kv_blocks_visited.{kind}"] <= \
+        assert counters[f"attn:kv_blocks_fetched.{kind}"] <= \
+            counters[f"attn:kv_blocks_visited.{kind}"] <= \
             counters[f"attn:kv_blocks_total.{kind}"]
 
 
 @pytest.mark.parametrize("t,swa,causal", [
-    (4096, (512, 15, 64), (512, 36, 64)),       # K/V resident in VMEM
-    (8192, (512, 31, 256), (1024, 36, 64)),     # streamed: the cell
+    (4096, (512, 15, 64, 1), (512, 36, 64, 1)),     # K/V resident in VMEM
+    # streamed, the cell: a row under the window starts on the block the
+    # row before stood on, a causal row but the first on block 0
+    (8192, (512, 31, 256, 16), (1024, 36, 64, 35)),
 ])
 def test_counters_make_the_skip_countable(t, swa, causal):
     """At a length of several blocks the window visits fewer K/V blocks
@@ -230,10 +233,11 @@ def test_counters_make_the_skip_countable(t, swa, causal):
     jax.eval_shape(model.init, jax.random.PRNGKey(0),
                    jnp.zeros((1, t), jnp.int32))
     c = profiler.counters()
-    for kind, (side, visited, total) in (("swa", swa), ("full", causal),
-                                         ("cross", causal)):
+    for kind, (side, visited, total, fetched) in (
+            ("swa", swa), ("full", causal), ("cross", causal)):
         assert c[f"attn:kv_blocks_total.{kind}"] == total
         assert c[f"attn:kv_blocks_visited.{kind}"] == visited
+        assert c[f"attn:kv_blocks_fetched.{kind}"] == fetched
         for kernel in ("fwd", "dq", "dkv"):
             assert c[f"attn:block_q.{kernel}.{kind}"] == side
             assert c[f"attn:block_k.{kernel}.{kind}"] == side

@@ -226,7 +226,8 @@ def test_the_model_counts_its_layers_and_refuses_what_it_cannot_share():
             c["gdn:chunk"], c["gdn:chunks"], c["gdn:states_kept"],
             c["gdn:decay"]) == (2, 12, 24, 8, 8, 4, 1)
     assert "kda:table_rows" not in c        # a scalar decay takes no table
-    assert c["attn:kv_blocks_visited.attn"] >= 1
+    assert c["attn:kv_blocks_visited.attn"] >= \
+        c["attn:kv_blocks_fetched.attn"] >= 1
     assert c["attn:block_q.fwd.attn"] == c["attn:block_k.fwd.attn"]
     with pytest.raises(ValueError, match="one chip"):
         _model(mesh=object())

@@ -392,6 +392,7 @@ def test_submitted_jobs_timeline_reaches_tony_history(timeline_job):
     # for each of the three kernels, recorded once by the first trace
     assert {k: v for k, v in c.items() if k.startswith("attn:")} == {
         "attn:kv_blocks_visited.dense": 1, "attn:kv_blocks_total.dense": 1,
+        "attn:kv_blocks_fetched.dense": 1,
         **{f"attn:block_{side}.{kernel}.dense": 16 for side in "qk"
            for kernel in ("fwd", "dq", "dkv")}}
     assert any(b["kind"] in ("compile", "load") for b in timeline["builds"])
@@ -475,19 +476,25 @@ def test_history_show_says_how_much_of_the_start_is_under_no_span(
                      render_show(timeline_job))
 
 
-@pytest.mark.parametrize("kw,t,side,visited,total", [
-    # head size 128: the packed call; the Mistral cell's length
+@pytest.mark.parametrize("kw,t,side,visited,total,fetched", [
+    # head size 128: the packed call; the Mistral cell's length (K/V
+    # resident: one whole block a head)
     (dict(dim=256, n_heads=2, n_kv_heads=1, max_seq=2048), 2048, 512, 10,
-     16),
+     16, 1),
+    # the same past residency: streamed in blocks of 1024, and the map
+    # stands still above the diagonal
+    (dict(dim=256, n_heads=2, n_kv_heads=1, max_seq=8192), 8192, 1024, 36,
+     64, 35),
     # head size 16: the classic layout; one block of a short sequence
-    (dict(), 48, 48, 1, 1),
+    (dict(), 48, 48, 1, 1, 1),
 ])
 def test_dense_attention_records_its_blocks_once(kw, t, side, visited,
-                                                 total):
+                                                 total, fetched):
     """``Attention`` puts the tile shape the kernels' rule gave each of its
-    three flash kernels, and the K/V blocks its forward visits, on the
-    task's timeline at trace time — once, however often it is traced (the
-    scan traces the block, init and apply trace the model)."""
+    three flash kernels, and the K/V blocks its forward visits and
+    fetches, on the task's timeline at trace time — once, however often
+    it is traced (the scan traces the block, init and apply trace the
+    model)."""
     import jax
     import jax.numpy as jnp
 
@@ -502,6 +509,7 @@ def test_dense_attention_records_its_blocks_once(kw, t, side, visited,
     assert c == {
         "attn:kv_blocks_visited.dense": visited,
         "attn:kv_blocks_total.dense": total,
+        "attn:kv_blocks_fetched.dense": fetched,
         **{f"attn:block_{s}.{kernel}.dense": side for s in "qk"
            for kernel in ("fwd", "dq", "dkv")}}
 

@@ -89,10 +89,12 @@ def test_window_shrinks_the_streamed_grid():
     assert A._window_spans(4, 4, 32, 32, 16) == (2, 2)
     assert A._window_spans(4, 4, 32, 32, 33) == (2, 2)
     assert A._window_spans(4, 4, 32, 32, 34) == (3, 3)
-    nk, kmap = A._windowed_k(32, 32, 256, 256, 512)
+    nk, kmap = A._visible_k(32, 32, 256, 256, True, 512)
     assert nk == 3 and int(kmap(10, 0)) == 8 and int(kmap(10, 2)) == 10
-    assert int(kmap(0, 2)) == 2 and int(kmap(31, 2)) == 31
-    assert A._windowed_k(32, 32, 256, 256, None)[0] == 32
+    # q-block 0 sees its own block alone: steps 1 and 2 are skipped and
+    # the map stands on block 0 (before PR 46 it went on to 1 and 2)
+    assert int(kmap(0, 2)) == 0 and int(kmap(31, 2)) == 31
+    assert A._visible_k(32, 32, 256, 256, True, None)[0] == 32
     # at the cell's shape, blocks of 256: 93 of 1024 blocks against the
     # causal 528; at the rule's blocks (512 under the window, 1024
     # without): 31 of 256 against 36 of 64

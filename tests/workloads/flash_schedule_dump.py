@@ -5,7 +5,8 @@ dumps each kernel's final schedule, one file a kernel, under ``argv[1]``.
 where given, names another entry over the same shapes: ``window=N`` (the
 plain entry under a window), ``selected`` (``flash_attention_selected``)
 or ``mla=DS`` (``flash_attention_mla`` with a shared part ``DS`` wide; kv
-heads = heads). The dumper aborts
+heads = heads). Prints the forward grid's K/V blocks first (visited,
+fetched, scheduled: ``block_facts``). The dumper aborts
 the process after the compile (it lacks a report template); by then the
 kernels' files are written, so the parent reads them and ignores the exit
 code. ``LIBTPU_INIT_ARGS`` must be set before jax loads libtpu, hence a
@@ -45,6 +46,7 @@ def spec(*shape, dtype=jnp.bfloat16):
 
 
 q, kv = spec(b, t, h * d), spec(b, t, hkv * d)
+window = int(size) if entry == "window" else None
 if entry == "selected":
     args = (q, kv, kv, spec(b, -(-t // A.SEL_SPAN), t, A.SEL_LANES,
                             dtype=jnp.int32))
@@ -56,8 +58,12 @@ elif entry == "mla":
 else:
     args = (q, kv, kv)
     call = lambda q, k, v: A.flash_attention_packed(
-        q, k, v, h, causal=True, interpret=False,
-        window=int(size) if entry == "window" else None)
+        q, k, v, h, causal=True, interpret=False, window=window)
+facts = A.block_facts(t, t, *(A.selection_blocks(t, d).fwd
+                              if entry == "selected" else (None, None)),
+                      window=window, head_dim=d)
+print("KV_BLOCKS visited={kv_blocks_visited} fetched={kv_blocks_fetched} "
+      "scheduled={kv_blocks_total}".format(**facts), flush=True)
 grad = jax.grad(lambda *a: call(*a).astype(jnp.float32).sum(),
                 tuple(i for i, a in enumerate(args)
                       if a.dtype == jnp.bfloat16))

@@ -255,7 +255,8 @@ def _count_blocks(t: int, head_dim: int, itemsize: int) -> None:
     """The flash calls' trace-time facts on the task's timeline, once:
     ``attn:block_q.<fwd|dq|dkv>.dense``, ``attn:block_k.<...>.dense`` (the
     tile shape each kernel of this run gets from the kernels' rule) and
-    ``attn:kv_blocks_visited.dense`` / ``attn:kv_blocks_total.dense``."""
+    ``attn:kv_blocks_visited.dense`` / ``attn:kv_blocks_total.dense`` /
+    ``attn:kv_blocks_fetched.dense``."""
     from tony_tpu import profiler
     from tony_tpu.ops.attention import block_facts
 
@@ -269,11 +270,12 @@ def _count_selection(t: int, topk: int, head_dim: int, itemsize: int) -> None:
     ``attn:index_topk``, ``attn:selected_pairs`` / ``attn:causal_pairs``
     (pairs of one sequence: what the selection leaves of the triangle),
     ``attn:block_q.<fwd|dq|dkv>.sel`` / ``attn:block_k.<...>.sel`` and
-    ``attn:kv_blocks_visited.sel`` / ``attn:kv_blocks_total.sel`` (the
-    kernels visit every tile at or below the diagonal: none is skipped
-    for holding no selected key)."""
+    ``attn:kv_blocks_visited.sel`` / ``attn:kv_blocks_total.sel`` /
+    ``attn:kv_blocks_fetched.sel`` (the kernels visit every tile at or
+    below the diagonal: none is skipped for holding no selected key; they
+    fetch no K/V block of a tile above it)."""
     from tony_tpu import profiler
-    from tony_tpu.ops.attention import selection_blocks
+    from tony_tpu.ops.attention import selection_blocks, streamed_fetches
 
     k = min(topk, t)
     profiler.count_once("attn:index_topk", topk)
@@ -287,6 +289,8 @@ def _count_selection(t: int, topk: int, head_dim: int, itemsize: int) -> None:
     profiler.count_once("attn:kv_blocks_visited.sel", sum(
         ((qi + 1) * bq - 1) // bk + 1 for qi in range(t // bq)))
     profiler.count_once("attn:kv_blocks_total.sel", (t // bq) * (t // bk))
+    profiler.count_once("attn:kv_blocks_fetched.sel",
+                        streamed_fetches(t, t, bq, bk))
 
 
 class Attention(nn.Module):

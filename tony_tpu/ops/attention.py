@@ -7,9 +7,9 @@ normalizer l, accumulator acc — same math as
 chips* while these kernels run it *within* one), so HBM traffic is O(T·D)
 instead of O(T²) and the matmuls hit the MXU in bf16/f32 with f32
 accumulation. Causal runs skip entire k-blocks above the diagonal — the
-dominant win for long sequences — and a window those left of it. The
-backward is two more kernels (dq; dk/dv) that recompute p from the saved
-log-sum-exp rows (no saved T×T residuals).
+dominant win for long sequences — and a window those left of it: neither
+computed nor copied from HBM. The backward is two more kernels (dq; dk/dv)
+that recompute p from the saved log-sum-exp rows (no T×T residuals).
 
 Entries (Pallas on a TPU or under ``interpret=True``, a pure-JAX reference
 elsewhere): :func:`flash_attention` over ``[B, H, T, D]`` (any head size,
@@ -27,7 +27,9 @@ resident in VMEM) and ONE description of a grid — a layout, a variant,
 the call's statics — from which a streamed and a resident builder make
 every ``pallas_call``. What the entries decide they decide from the
 shapes: blocks (:func:`_pick_blocks`), padding (:func:`_plan_dispatch`),
-residency (:func:`_resident_fits`).
+residency (:func:`_resident_fits`); and from ``causal`` and ``window`` the
+span of blocks a row can see, outside which a streamed grid's map stands
+still and fetches nothing (:func:`_visible_k`, :func:`_visible_q`).
 """
 
 from __future__ import annotations
@@ -36,6 +38,8 @@ import functools
 import itertools
 import math
 from typing import Callable, NamedTuple, Optional
+
+import numpy as np
 
 from tony_tpu import profiler
 
@@ -103,12 +107,12 @@ def _scope(name: str, window) -> str:
     return name if window is None else f"{name}_win"
 
 
-def _first_kb(q0, block_k, window):
+def _first_kb(q0, block_k, window, xp=jnp):
     """First k-block seen by a run of queries starting at position ``q0``
     (may be traced) under a causal window of ``window`` keys (query t
     sees keys t-window+1 .. t): the block holding the earliest key of
     the first query."""
-    return jnp.maximum(q0 - (window - 1), 0) // block_k
+    return xp.maximum(q0 - (window - 1), 0) // block_k
 
 
 def _last_qb(kj, bq, block_k, window):
@@ -136,8 +140,8 @@ def kv_blocks(t: int, tk: int, block_q: Optional[int] = None,
     """(visited, total) K/V blocks of one head's forward grid at the
     blocks :func:`flash_attention` would pick for ``t`` x ``tk``: what
     the causal triangle and the window leave of the ``nq * nk`` square.
-    Pure arithmetic (the tests read it); the kernels skip exactly these
-    blocks."""
+    Pure arithmetic (the tests read it); the kernels compute exactly these
+    blocks and fetch no other (:func:`streamed_fetches`)."""
     facts = block_facts(t, tk, block_q, block_k, causal, window, head_dim,
                         itemsize)
     return facts["kv_blocks_visited"], facts["kv_blocks_total"]
@@ -149,9 +153,10 @@ def block_facts(t: int, tk: int, block_q: Optional[int] = None,
                 itemsize: int = 2) -> dict:
     """What a call of these shapes runs, as the counters a model records
     once at trace time (``attn:<key>.<kind>``): the blocks each of the
-    three kernels gets (``block_q.fwd`` ... ``block_k.dkv``) and
-    :func:`kv_blocks`' two counts. Pure arithmetic: the same plan the
-    entry points make."""
+    three kernels gets (``block_q.fwd`` ... ``block_k.dkv``),
+    :func:`kv_blocks`' two counts and ``kv_blocks_fetched`` (the forward's
+    K/V copies a head: :func:`streamed_fetches`; resident, the one whole
+    block). Pure arithmetic: the same plan the entry points make."""
     _, blocks, extra = _plan_dispatch(t, tk, block_q, block_k, causal,
                                       window, head_dim, itemsize)
     bq, bk = blocks.fwd
@@ -167,11 +172,28 @@ def block_facts(t: int, tk: int, block_q: Optional[int] = None,
         hi = min(nk - 1, ((qi + 1) * bq - 1) // bk) if causal else nk - 1
         lo = max(qi * bq - window + 1, 0) // bk if window else 0
         visited += hi - lo + 1
-    facts = {"kv_blocks_visited": visited, "kv_blocks_total": nq * nk}
+    fetched = 1 if _resident_fits(tk, head_dim, itemsize) else \
+        streamed_fetches(t, tk, bq, bk, causal, window)
+    facts = {"kv_blocks_visited": visited, "kv_blocks_total": nq * nk,
+             "kv_blocks_fetched": fetched}
     for kernel, (kq, kk) in blocks._asdict().items():
         facts[f"block_q.{kernel}"] = kq
         facts[f"block_k.{kernel}"] = kk
     return facts
+
+
+def streamed_fetches(t: int, tk: int, block_q: int, block_k: int,
+                     causal: bool = True,
+                     window: Optional[int] = None) -> int:
+    """K/V block copies one head's STREAMED forward grid issues: the
+    pipeline copies a block when its index changes, so the first block
+    plus the changes of the builder's own map (:func:`_visible_k`) along
+    the grid's order. The identity would give the ``nq * nk`` square."""
+    nq, nk = -(-t // block_q), -(-tk // block_k)
+    steps, kblk = _visible_k(nq, nk, block_q, block_k, causal, window, np)
+    walk = np.broadcast_to(
+        kblk(np.arange(nq)[:, None], np.arange(steps)[None, :]), (nq, steps))
+    return 1 + int(np.count_nonzero(np.diff(walk.ravel())))
 
 
 def _mask_s(s, q0, k0, causal, kv_len, window=None):
@@ -337,8 +359,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     state (running max m, normalizer l, accumulator acc) carries across
     k-steps in VMEM scratch. The q/o/lse blocks keep a constant index over
     the k axis, so they stay resident and o/lse flush once, written at the
-    last k-step. Causal q-blocks skip the compute (not the schedule) of
-    k-blocks above the diagonal via predication. Also writes the
+    last k-step. Causal q-blocks skip the compute of k-blocks above the
+    diagonal via predication: the steps stay scheduled, and copy nothing
+    (the caller's k-side map stands still: ``_visible_k``). Also writes the
     log-sum-exp rows the backward kernels reconstruct p from.
     ``qi_axis`` is which grid axis carries the q-block index (the k axis
     is ``qi_axis + 1``): the layout's ``axis``. Under a ``window`` the k
@@ -447,7 +470,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     """dk/dv, streamed: grid ``(..., kj, qx)`` with the q-side axis
     INNERMOST — q/do/o/lse arrive one block at a time while this k-block's
     dk/dv accumulate in VMEM scratch (dv += pᵀ·do; dk += dsᵀ·q·scale).
-    Causal k-blocks skip q-blocks strictly above the diagonal.
+    Causal k-blocks skip q-blocks strictly above the diagonal, a sweep's
+    first steps (the q-side map waits on the diagonal: ``_visible_q``).
 
     GQA: one kv head serves ``reps`` query heads, so the innermost axis is
     the FLATTENED (rep, q-block) index of size reps·nqb — the callers'
@@ -505,30 +529,44 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
             shared[2][:] = shared[3][:].astype(shared[2].dtype)
 
 
-
-
-def _windowed_k(nq, nk, bq, bk, window):
+def _visible_k(nq, nk, bq, bk, causal, window, xp=jnp):
     """(extent of the k axis, (q-block, step) -> k-block) of a q-major
-    streamed grid. Without a window the whole row of blocks and the
-    identity; with one, only the span a q-block can see, counted from its
-    first visible block (clamped at the array's end, where the kernel's
-    causal predicate has already switched the step off)."""
-    if window is None:
+    streamed grid (forward, dq). A causal q-block sees the k-blocks from
+    the first its window reaches (block 0 without one) to its diagonal's;
+    the axis holds the longest such span, counted from a row's first
+    block. Past the span, on the steps the bodies skip, the map stands
+    still on the NEXT row's first block: the pipeline copies a block only
+    when its index changes, so that block arrives under the diagonal
+    tile's compute and a skipped step fetches nothing. Not causal: the
+    identity. ``xp``: numpy walks the map untraced (``streamed_fetches``)."""
+    if not causal:
         return nk, lambda i, kb: kb
-    kspan, _ = _window_spans(nq, nk, bq, bk, window)
-    return kspan, lambda i, kb: jnp.minimum(
-        _first_kb(i * bq, bk, window) + kb, nk - 1)
-
-
-def _windowed_q(nq, nk, bq, bk, window):
-    """(per-head extent of the q sweep, (k-block, position) -> q-block)
-    of the k-major dk/dv grid. Without a window every q-block and the
-    identity; with one, from the diagonal to the last q-block that sees
-    the k-block."""
+    seen = lambda i, kb: kb * bk < (i + 1) * bq     # the bodies' contributes
     if window is None:
+        return nk, lambda i, kb: xp.where(seen(i, kb), kb, 0)
+    first = lambda i: _first_kb(i * bq, bk, window, xp)
+
+    def kblk(i, step):
+        kb = first(i) + step
+        return xp.where(seen(i, kb), kb, xp.minimum(first(i + 1), nk - 1))
+    return _window_spans(nq, nk, bq, bk, window)[0], kblk
+
+
+def _visible_q(nq, nk, bq, bk, causal, window, xp=jnp):
+    """(per-head extent of the q sweep, (k-block, position) -> q-block) of
+    the k-major dk/dv grid: a causal k-block is seen from the q-block on
+    its diagonal to the last its window reaches (the array's last without
+    one). Without a window the sweep holds every q-block and its skipped
+    steps come FIRST: the map stands on the diagonal block until the sweep
+    reaches it. With one the sweep starts there and stands on its last."""
+    if not causal:
         return nq, lambda j, x: x
-    _, qspan = _window_spans(nq, nk, bq, bk, window)
-    return qspan, lambda j, x: jnp.minimum((j * bk) // bq + x, nq - 1)
+    first = lambda j: xp.minimum((j * bk) // bq, nq - 1)
+    if window is None:
+        return nq, lambda j, x: xp.maximum(x, first(j))
+    last = lambda j: xp.minimum(_last_qb(j, bq, bk, window), nq - 1)
+    return (_window_spans(nq, nk, bq, bk, window)[1],
+            lambda j, x: xp.minimum(first(j) + x, last(j)))
 
 
 def _lane_of(reps: int):
@@ -870,9 +908,9 @@ def _like(x):
 def _q_major(g: _Grid, bq: int, bk: Optional[int] = None):
     """The q-major grid (forward, dq), each cell a pinned q-block of one
     query head: ``(grid, q-side spec, k-side spec, lse spec, sides)``.
-    Streamed, a last axis walks the k-blocks — under a window only the
-    span a q-block sees (``_windowed_k``). Resident (``bk=None``) there is
-    no such axis: the kv head's whole K/V is one block."""
+    Streamed, a last axis walks the k-blocks a q-block sees and stands
+    still on the steps it skips (``_visible_k``). Resident (``bk=None``)
+    there is no such axis: the kv head's whole K/V is one block."""
     lay = g.lay
     nq = pl.cdiv(lay.t, bq)
 
@@ -882,7 +920,8 @@ def _q_major(g: _Grid, bq: int, bk: Optional[int] = None):
     if bk is None:
         inner, krows, krow = (), lay.tk, _zero
     else:
-        nkw, kblk = _windowed_k(nq, pl.cdiv(lay.tk, bk), bq, bk, g.window)
+        nkw, kblk = _visible_k(nq, pl.cdiv(lay.tk, bk), bq, bk, g.causal,
+                               g.window)
         inner, krows = (nkw,), bk
 
         def krow(*ids):
@@ -937,12 +976,12 @@ def _streamed_backward(g: _Grid, q, k, v, do, o, lse, *extras):
 
     # dk/dv grid: (cells of kv heads, kj, qx) — the q-side walked
     # innermost. qx is the flattened (rep, q-block) sweep over every query
-    # head the kv head serves, under a window only the q-blocks that see
-    # the k-block; dk/dv accumulate across all of it. Without grouping or
-    # window the sweep is the q-blocks themselves and the maps stay free
-    # of div and mod.
+    # head the kv head serves, each rep's part mapped to the q-blocks that
+    # see the k-block (``_visible_q``); dk/dv accumulate across all of it.
+    # Without grouping or window the maps stay free of div and mod.
     bq, bk = g.blocks.dkv
     nq, nk = pl.cdiv(lay.t, bq), pl.cdiv(lay.tk, bk)
+    nqb, qblk = _visible_q(nq, nk, bq, bk, g.causal, g.window)
 
     def krow(*ids):
         return ids[lay.axis]
@@ -952,13 +991,13 @@ def _streamed_backward(g: _Grid, q, k, v, do, o, lse, *extras):
 
     plain_sweep = lay.reps == 1 and g.window is None
     if plain_sweep:
-        nqb, qhead, qrow = nq, lay.own, qx
+        qhead, at = lay.own, qx
     else:
-        nqb, qblk = _windowed_q(nq, nk, bq, bk, g.window)
         qhead = lay.q_head(lambda *ids: qx(*ids) // nqb)
+        at = lambda *ids: qx(*ids) % nqb
 
-        def qrow(*ids):
-            return qblk(krow(*ids), qx(*ids) % nqb)
+    def qrow(*ids):
+        return qblk(krow(*ids), at(*ids))
 
     sides = _Sides(bq, bk, qhead, qrow, krow)
     q_str = lay.rows(bq, qhead, qrow)
