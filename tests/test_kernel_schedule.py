@@ -30,7 +30,13 @@ other; do not run a dump by hand beside them.
 The same kernels under ONE decay a head (``gdn_chunk_fwd`` / ``_bwd``, PR
 40) at the Olmo Hybrid cell's head behind its zero lanes (96 x 192 as 128
 x 256): no levels and no table, so a cell is smaller than the per-channel
-route's though its state is twice as wide."""
+route's though its state is twice as wide.
+
+The fused convolution / SiLU / head-normalisation kernels
+(``tests/workloads/conv_schedule_dump.py``, PR 47) are pinned by the
+bundles ONE STRIP of their loop runs — 128 rows of 512 channels under the
+head's normalisation, 32 rows without — which is what a call's time is made
+of: strips a call x bundles a strip x ~1.06 ns (PERF.md section 6, PR 47)."""
 
 import re
 import subprocess
@@ -184,3 +190,44 @@ def test_gdn_bundles_a_cell(gdn_dump, kernel):
         f"{keep} x {loops}), ceiling {GDN_CEILING[kernel]}")
     # a scalar decay's cell is the smaller, at twice the value lanes
     assert GDN_CEILING[kernel] < KDA_CEILING[kernel.replace("gdn", "kda")]
+
+
+# --------------------------------------- convolution + SiLU + head norm
+
+# shape -> (tokens, heads, head size, unit), {kernel: bundles a strip at
+# most}: ~5% over what PR 47 reads. Kimi Linear's q and k: a strip is 128
+# rows x 512 channels, 64 float32 registers a value (910 / 1,756: 14 / 27
+# bundles a register, bounded by the vector slots — ~52 / ~101 operations a
+# register of 4 a bundle); its v, without the norm, 32 rows x 512 (131 /
+# 253).
+CONV = {"unit_32x128": ((4096, 32, 128, 1), {"delta_conv_fwd": 960,
+                                             "delta_conv_bwd": 1850}),
+        "plain_32x128": ((4096, 32, 128, 0), {"delta_conv_fwd": 140,
+                                              "delta_conv_bwd": 270})}
+
+
+@pytest.fixture(scope="module", params=sorted(CONV))
+def conv_dump(request, tmp_path_factory):
+    sizes, ceilings = CONV[request.param]
+    out = tmp_path_factory.mktemp("llo_conv_" + request.param)
+    child = Path(__file__).parent / "workloads" / "conv_schedule_dump.py"
+    proc = subprocess.run(
+        [sys.executable, str(child), str(out), *map(str, sizes)],
+        capture_output=True, text=True, timeout=600,
+        cwd=Path(__file__).resolve().parent.parent)
+    assert "Traceback" not in proc.stderr, proc.stderr[-2000:]
+    if "NO_TOPOLOGY" in proc.stdout:
+        pytest.skip(f"cannot describe a v5e topology here: {proc.stdout}")
+    return out, ceilings
+
+
+@pytest.mark.parametrize("kernel", ["delta_conv_fwd", "delta_conv_bwd"])
+def test_delta_conv_bundles_a_strip(conv_dump, kernel):
+    """The strips' loop is each kernel's one loop (the first strip, under
+    the halo block, stands before it); a body past its ceiling has started
+    to spill, or lost the shape rule's strip."""
+    dump, ceilings = conv_dump
+    _, loops = kda_bundles(dump, kernel)
+    assert len(loops) == 1, loops
+    assert loops[0] <= ceilings[kernel], (
+        f"{kernel}: {loops[0]} bundles a strip, ceiling {ceilings[kernel]}")

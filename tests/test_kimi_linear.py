@@ -18,8 +18,8 @@ import numpy as np
 import optax
 import pytest
 
-from benchmark import modelcfg, modelcfg_keyevl2, modelcfg_olmohybrid, \
-    modelcfg_phi4flash, modelcfg_zaya1
+from benchmark import modelcfg, modelcfg_glm47flash, modelcfg_keyevl2, \
+    modelcfg_olmohybrid, modelcfg_phi4flash, modelcfg_zaya1
 from benchmark import modelcfg_kimilinear as mc
 from benchmark import reference, reference_kimilinear as ref
 from benchmark import roofline_kimilinear, weights_kimilinear as wk
@@ -134,6 +134,27 @@ def test_two_adamw_steps_match_the_reference(dtype, rel):
         metrics["stats"]) if "moe_rows_held" in jax.tree_util.keystr(path)]
     assert len(rows) == CFG["ffns"].count("experts") and min(rows) > 0
     assert float(metrics["aux_loss"]) == 0.0      # L = L_LM
+
+
+@pytest.mark.parametrize("backend, fused", [("tpu", 3), ("cpu", 0)])
+def test_the_mixers_count_their_fused_conv_chains(backend, fused,
+                                                  monkeypatch):
+    """``kda:conv_fused`` is how many of a mixer's q, k and v chains run
+    ``ops.ssm``'s fused kernels — all three on a TPU, none on the CPU
+    (``ops.ssm.conv_plan``) — and ``kda:conv_block`` the steps a block of
+    q's holds; published once, however many layers are traced. The cell's
+    own model, abstractly (the tiny one's latent attention has no lane
+    blocks for a TPU's kernels)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    model = get_model("kimi-linear-48b-a3b", **mc.program_kwargs(
+        mc.load("kimi-linear-48b-a3b"), 32768))
+    profiler.reset_timeline()
+    jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                   jnp.zeros((1, 32768), jnp.int32))
+    c = profiler.timeline()["counters"]
+    profiler.reset_timeline()
+    assert c["kda:conv_fused"] == fused
+    assert c.get("kda:conv_block") == (1024 if fused else None)
 
 
 def test_rows_held_are_the_references():
@@ -456,11 +477,20 @@ PARENT_STEPS = {
     "zaya1.train-32k": ("c39e9c049f0ce4c8", lambda: (get_model(
         "zaya1-8b", **modelcfg_zaya1.program_kwargs(
             modelcfg_zaya1.load("zaya1-8b"), 32768)), 1, 32768)),
-    # These two read on d43341c (PR 42), before PR 43 touched the flash grids.
-    "kimilinear.train-32k": ("bf061bcfb035153c", lambda: (get_model(
+    # Read on 10716a2 (PR 46) and on PR 47's tree alike: the fifth cell
+    # without a delta-rule mixer.
+    "glm47flash.train-16k": ("63a2b771fb0a1c3e", lambda: (get_model(
+        "glm-4.7-flash", **modelcfg_glm47flash.program_kwargs(
+            modelcfg_glm47flash.load("glm-4.7-flash"), 16384)), 1, 16384)),
+    # These two read anew on PR 47's tree (parent 10716a2, PR 46), on
+    # purpose: their mixers' q, k, v chains became ``ops.ssm``'s fused
+    # kernels, the only change to either jaxpr (before: bf061bcfb035153c
+    # and c193f4a060c51298, read on d43341c, PR 42). The five above are
+    # the cells without a delta-rule mixer: the first four hold unedited.
+    "kimilinear.train-32k": ("da7f1e02469a2b63", lambda: (get_model(
         "kimi-linear-48b-a3b", **mc.program_kwargs(
             mc.load("kimi-linear-48b-a3b"), 32768)), 1, 32768)),
-    "olmohybrid.train-16k": ("c193f4a060c51298", lambda: (get_model(
+    "olmohybrid.train-16k": ("73ff26c6b00fdb8c", lambda: (get_model(
         "olmo-hybrid-7b", **modelcfg_olmohybrid.program_kwargs(
             modelcfg_olmohybrid.load("olmo-hybrid-7b"))), 1, 16384)),
 }

@@ -210,6 +210,22 @@ def test_a_half_builds_half_the_columns():
     assert half["layer_0"]["norm1"] == full["layer_0"]["norm1"]
 
 
+@pytest.mark.parametrize("backend, fused", [("tpu", 3), ("cpu", 0)])
+def test_the_mixers_count_their_fused_conv_chains(backend, fused,
+                                                  monkeypatch):
+    """``gdn:conv_fused`` is how many of a mixer's q, k and v chains run
+    ``ops.ssm``'s fused kernels — all three on a TPU, none on the CPU
+    (``ops.ssm.conv_plan``) — and ``gdn:conv_block`` the steps a block
+    of q's holds; published once, however many layers are traced."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    profiler.reset_timeline()
+    jax.eval_shape(_model().init, jax.random.PRNGKey(0), _tokens(6)[0])
+    c = profiler.timeline()["counters"]
+    profiler.reset_timeline()
+    assert c["gdn:conv_fused"] == fused
+    assert c.get("gdn:conv_block") == (128 if fused else None)
+
+
 # -- the configuration's switches and the facts counted ----------------------
 
 def test_the_model_counts_its_layers_and_refuses_what_it_cannot_share():

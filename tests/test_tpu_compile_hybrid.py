@@ -8,9 +8,12 @@ chunk kernels over 32 heads of 128 x 128; the latent-attention grids at a
 chunk kernels under one decay a head over 15 heads of 96 x 192, behind zero
 lanes; the packed flash kernels at 15 heads of 128) and
 ``glm47flash.train-16k`` runs (the packed flash kernels at 20 heads of
-256): as ``tests/test_tpu_compile.py``, a pass is a COMPILE for a
-chip that is not attached — what Mosaic refuses there (a block off the
-tiling, too much VMEM, an SMEM block it cannot place) is refused here."""
+256), and the fused convolution / SiLU / head-normalisation kernels of the
+two delta-rule cells (PR 47: ``[1, 32768, 4096]`` in heads of 128, ``[1,
+16384, 1440]`` in heads of 96, ``[1, 16384, 2880]`` without the norm): as
+``tests/test_tpu_compile.py``, a pass is a COMPILE for a chip that is not
+attached — what Mosaic refuses there (a block off the tiling, too much
+VMEM, an SMEM block it cannot place) is refused here."""
 
 import os
 
@@ -111,6 +114,25 @@ def _gdn(grad):
     return build
 
 
+def _delta_conv(t, heads, d, unit):
+    """A delta-rule mixer's operand chain, forward and backward: ``x`` and
+    the result's cotangent ``[1, t, heads x d]`` bfloat16, four float32
+    taps."""
+    from tony_tpu.ops import ssm
+
+    def both(x, w, dy):
+        y, vjp = jax.vjp(lambda x, w: ssm.conv_silu_unit(
+            x, w, heads=heads, unit=unit, scale=d ** -0.5, interpret=False),
+            x, w)
+        return (y, *vjp(dy))
+
+    def build(sh):
+        x = jax.ShapeDtypeStruct((1, t, heads * d), jnp.bfloat16, sharding=sh)
+        w = jax.ShapeDtypeStruct((4, heads * d), jnp.float32, sharding=sh)
+        return both, (x, w, x)
+    return build
+
+
 def _flash15(sh):
     from tony_tpu.ops import flash_attention_packed
 
@@ -142,6 +164,11 @@ def _flash20x256(sh):
 
 
 CASES = {
+    "delta_conv_fwd_bwd_unit_32x128_t32768": _delta_conv(T32K, KH, D, True),
+    "delta_conv_fwd_bwd_plain_32x128_t32768": _delta_conv(T32K, KH, D, False),
+    "delta_conv_fwd_bwd_unit_15x96_t16384": _delta_conv(T16K, OH, DK, True),
+    "delta_conv_fwd_bwd_plain_15x192_t16384": _delta_conv(T16K, OH, DV,
+                                                          False),
     "flash_packed_causal_20x256_t16384_fwd_bwd": _flash20x256,
     "gdn_chunk_fwd_15x96x192_t16384": _gdn(grad=False),
     "gdn_chunk_fwd_bwd_15x96x192_t16384": _gdn(grad=True),
@@ -173,6 +200,17 @@ def test_kernel_compiles_for_v5e(topo, case):
         # the [T, E, N] state is never a buffer of the program
         assert f"{T},{E},{N}]" not in text and f"{T},{N},{E}]" not in text
         assert "ssm_scan_fwd" in text
+    elif case.startswith("delta_conv"):
+        # heads of 128 (a lane tile) and of 96 / 192 over rows of 1440 /
+        # 2880, no multiple of 128, all reach the kernels (no fallback
+        # warning above); float32 stays in VMEM: the only float32 array of
+        # the program is the taps' gradient and its eight partial rows
+        assert "delta_conv_fwd" in text and "delta_conv_bwd" in text
+        t, e = (T32K, KH * D) if "32x128" in case else (
+            T16K, OH * (DK if "x96" in case else DV))
+        assert f"bf16[1,{t},{e}]" in text
+        assert f"f32[1,{t},{e}]" not in text and f"f32[{t},{e}]" not in text
+        assert f"f32[1,32,{e}]" in text
     elif case.startswith("kda"):
         assert "kda_chunk_fwd" in text
         assert ("kda_chunk_bwd" in text) == ("bwd" in case)
@@ -213,11 +251,13 @@ def test_olmo_hybrid_step_fits_with_its_second_forward_fenced(topo,
     of arguments) at the residual ladder's floor. Merged with its first
     forward a layer's second keeps every layer's temporaries (16.15 GB of
     15.75, refused, whatever the rung keeps), so the ladder's second walk
-    starts here, at the floor under ``prevent_cse``: 12.66 GiB held, 12.78
-    by ``remat.step_bytes`` (pinned loosely: it leaves the margin; the
-    rungs above it are ``tests/test_tpu_compile_remat.py``'s). Thirteen
+    starts here, at the floor under ``prevent_cse``: 11.83 GiB held, 11.95
+    by ``remat.step_bytes`` (12.66 / 12.78 before PR 47's fused chains;
+    pinned loosely: it leaves the margin; the rungs above it are
+    ``tests/test_tpu_compile_remat.py``'s). Forty
     kernel calls: a chunk forward, its remat's and a chunk backward a gdn
-    layer, the flash forward twice and its two backward kernels."""
+    layer, and since PR 47 as many for each of its q, k and v chains; the
+    flash forward twice and its two backward kernels."""
     import optax
     from flax.training.train_state import TrainState
 
@@ -245,8 +285,9 @@ def test_olmo_hybrid_step_fits_with_its_second_forward_fenced(topo,
     compiled = step.build(remat.Saved(prevent_cse=True)).lower(
         state, batch).compile()
     gib = 1 << 30
-    assert 12.4 < compiled.memory_analysis().peak_memory_in_bytes / gib < 12.9
+    assert 11.6 < compiled.memory_analysis().peak_memory_in_bytes / gib < 12.1
     assert remat.step_bytes(compiled) + remat.MARGIN < 15.748 * gib
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 13
+    assert text.count("tpu_custom_call") == 40
     assert "gdn_chunk_fwd" in text and "gdn_chunk_bwd" in text
+    assert "delta_conv_fwd" in text and "delta_conv_bwd" in text

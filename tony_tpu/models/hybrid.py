@@ -555,24 +555,35 @@ def _delta_qkv(mod, cfg, u, scope, heads, dk, dv, taps):
     proj`` and ``<scope>_conv``: ``q, k, v = silu(conv(u W))`` over
     ``heads`` of ``dk`` / ``dk`` / ``dv`` channels (the projections named
     for the residual ladder), ``q`` and ``k`` normalised a head, ``q``
-    times ``dk^-1/2``; ``[B, T, heads, d]`` in the compute dtype."""
+    times ``dk^-1/2``; ``[B, T, heads, d]`` in the compute dtype.
+    Counters ``<scope>:conv_fused`` (how many of the three chains run
+    ``ops.ssm``'s fused kernels) and ``<scope>:conv_block`` (the steps a
+    block of q's holds; absent where none does)."""
     b, t, _ = u.shape
-    widths = (("q", dk), ("k", dk), ("v", dv))
+    chains = (("q", dk, True, dk ** -0.5), ("k", dk, True, 1.0),
+              ("v", dv, False, 1.0))
     with jax.named_scope(scope + "_proj"):
         q, k, v = (remat.name(_dense(cfg, heads * d, "w" + n)(u), n)
-                   for n, d in widths)
+                   for n, d, _, _ in chains)
+    plans = [ssm.conv_plan(t, heads * d, heads, unit, taps,
+                           jnp.dtype(cfg.dtype).itemsize, cfg.interpret)
+             for _, d, unit, _ in chains]
+    profiler.count_once(scope + ":conv_fused",
+                        sum(p is not None for p in plans))
+    if plans[0]:
+        profiler.count_once(scope + ":conv_block", plans[0][2])
     with jax.named_scope(scope + "_conv"):
-        # float32 from the projections' outputs to the kernel's
-        # operands: four shifted products and a norm are one fused
-        # elementwise pass either way.
-        q, k, v = (nn.silu(ssm.causal_conv1d(
-            x.astype(jnp.float32), mod.param(
-                f"conv_{n}", _conv_init, (taps, heads * d), jnp.float32))
-            ).reshape(b, t, heads, d) for x, (n, d) in zip((q, k, v), widths))
-        unit = lambda x: x * jax.lax.rsqrt(
-            jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
-        return (unit(q) * dk ** -0.5).astype(cfg.dtype), \
-            unit(k).astype(cfg.dtype), v.astype(cfg.dtype)
+        # One pass each way from the projections' outputs to the chunk
+        # kernel's operands: float32 between the two casts, in VMEM on the
+        # chip (``delta_conv_fwd`` / ``delta_conv_bwd``) — as XLA's fusions
+        # the chain was 23 float32 passes a tensor a layer-step, 11.6% of
+        # the Kimi Linear cell's step (PERF.md section 6, PR 47).
+        return tuple(ssm.conv_silu_unit(
+            x, mod.param(f"conv_{n}", _conv_init, (taps, heads * d),
+                         jnp.float32),
+            heads=heads, unit=unit, scale=scale,
+            interpret=cfg.interpret).reshape(b, t, heads, d)
+            for x, (n, d, unit, scale) in zip((q, k, v), chains))
 
 
 def _delta_out(cfg, o, gate):
